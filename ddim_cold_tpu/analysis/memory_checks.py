@@ -38,7 +38,7 @@ comes near.
 from __future__ import annotations
 
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from ddim_cold_tpu.analysis.findings import Finding
 
@@ -140,7 +140,7 @@ def peak_live_bytes(closed) -> int:
     consts = sum(aval_bytes(getattr(c, "aval", c))
                  for c in getattr(closed, "consts", ()))
     jaxpr = closed.jaxpr
-    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "pjit":
+    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "jit":
         eqn = jaxpr.eqns[0]
         body = eqn.params["jaxpr"]
         don = eqn.params.get("donated_invars") or ()

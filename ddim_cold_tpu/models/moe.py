@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ddim_cold_tpu.models.init import trunc_normal
+from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
 
@@ -124,7 +125,7 @@ class SwitchMlp(nn.Module):
 
         h = jnp.einsum("becd,edh->bech", xe, w1.astype(self.dtype))
         h = h + b1.astype(self.dtype)[None, :, None, :]
-        h = nn.gelu(h, approximate=False)
+        h = gelu_exact(h)
         h = nn.Dropout(self.drop, deterministic=deterministic)(h)
         ye = jnp.einsum("bech,ehd->becd", h, w2.astype(self.dtype))
         ye = ye + b2.astype(self.dtype)[None, :, None, :]

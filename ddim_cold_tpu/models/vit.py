@@ -35,6 +35,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ddim_cold_tpu.models.init import torch_default_uniform, trunc_normal
+from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
 
@@ -154,7 +155,7 @@ class Mlp(nn.Module):
                 name=name,
             )
         x = dense(self.hidden_features, "fc1")(x)
-        x = nn.gelu(x, approximate=False)
+        x = gelu_exact(x)
         x = nn.Dropout(self.drop, deterministic=deterministic)(x)
         x = dense(self.out_features, "fc2")(x)
         x = nn.Dropout(self.drop, deterministic=deterministic)(x)
@@ -166,9 +167,10 @@ class Attention(nn.Module):
 
     Returns ``(x, attn)`` like the reference so the attention-probe path
     (Block.return_attention) stays expressible — EXCEPT when the Pallas
-    fused kernel runs (``use_flash`` on, ``need_weights=False``, attention
-    dropout inactive), which never materializes the weights and returns
-    ``(x, None)``. Callers that need the weights must pass
+    fused kernel runs (``use_flash`` on, ``need_weights=False``), which
+    never materializes the weights and returns ``(x, None)``; active
+    attention-dropout is an error there, not a switch back to the einsum.
+    Callers that need the weights must pass
     ``need_weights=True`` (Block does this for its probe path). Softmax runs
     in float32 regardless of compute dtype; the einsum layout keeps the two
     contractions as plain batched GEMMs for the MXU.
@@ -295,6 +297,15 @@ class Attention(nn.Module):
                 "sequence-parallel attention cannot apply attention-dropout "
                 f"(attn_drop={self.attn_drop} active in training); set "
                 "attn_drop_rate=0.0 on the model")
+        if self.use_flash and not need_weights and not weightless_ok:
+            # same rule without sp: a model built for the kernel must not
+            # train on the dense einsum with nobody told (at N=2501 that is
+            # the O(N²) matrix per layer the kernel exists to avoid)
+            raise ValueError(
+                f"use_flash={self.use_flash!r} cannot apply attention-dropout "
+                f"(attn_drop={self.attn_drop} active in training) — the "
+                "kernel never materializes the weights; set "
+                "attn_drop_rate=0.0 on the model (trainer.build_model does)")
         if self.seq_manual and not weightless_ok:
             # no dense fallback exists inside the manual region — a local
             # einsum would silently attend block-diagonally

@@ -104,8 +104,8 @@ def make_cold_prepare(size: int, max_step: int, chain: bool, *,
     """In-jit batch corruption for the device-side cold data path.
 
     The host ships only ``(base, t)`` — one clean image per sample instead of
-    the two degraded float copies (2× less host→device traffic, the dominant
-    step cost on network-attached TPU hosts) — and this hook (train/step.py
+    the two degraded float copies (2× less host→device traffic) — and this
+    hook (train/step.py
     ``prepare``) rebuilds the exact host contract ``(D(x,t), D(x,t−1)|x₀, t)``
     on device. The degradation is a pure gather (cold_degrade), so the result
     is bit-identical to the host/C++ pipeline. ``normalize_base`` additionally

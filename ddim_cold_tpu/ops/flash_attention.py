@@ -25,8 +25,9 @@ memory story for long sequences is bounded. (In-kernel, lse rides a
 the replication is sliced off / re-broadcast outside the kernels so the
 residual itself stays one lane. See _fwd_kernel._emit.)
 
-On non-TPU backends the kernels run in interpreter mode, so tests exercise
-the identical code paths on CPU (GPU falls back to the dense einsum).
+On the CPU backend the kernels run in interpreter mode, so tests exercise
+the identical code paths; any other non-TPU backend is an error — a caller
+that asked for the kernel never gets a different computation in its place.
 """
 
 from __future__ import annotations
@@ -37,23 +38,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from ddim_cold_tpu.ops import tiling
-from ddim_cold_tpu.utils import profiling
+from ddim_cold_tpu.utils import flops, profiling
 
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: last dim of VMEM tiles
 
-#: Pallas-TPU compiler params across jax versions (renamed from
-#: TPUCompilerParams to CompilerParams; same fields we use)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-#: kernel revision stamped into bench records (scripts/r05_stage_done.py keys
-#: re-measurement off it): "bf16-gemm-v2" = GEMMs in input dtype with f32 MXU
-#: accumulation (the r05 change); "fused-trunk-v3" adds the quant-aware fused
-#: trunk attention (qkv dequant-GEMM as in-kernel producer, proj GEMM as
-#: in-kernel consumer — see :func:`fused_trunk_attention`). The unfused
-#: kernels are untouched by v3: their numerics are bit-identical to v2.
+#: kernel revision stamped into bench records: "bf16-gemm-v2" = GEMMs in
+#: input dtype with f32 MXU accumulation; "fused-trunk-v3" adds the
+#: quant-aware fused trunk attention (qkv dequant-GEMM as in-kernel producer,
+#: proj GEMM as in-kernel consumer — see :func:`fused_trunk_attention`). The
+#: unfused kernels are untouched by v3: their numerics are bit-identical to v2.
 KERNEL_REV = "fused-trunk-v3"
 
 #: tuned (block_q, block_kv) for the N=2501 north-star flash leg: the r05
@@ -132,10 +129,7 @@ def _sds(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes type — needed
     when the kernel runs inside a ``shard_map`` (e.g. as Ulysses' local
     attention) where ``check_vma`` requires outputs to declare their vma."""
-    # jax.typeof (and vma-typed avals) only exist on newer jax; without them
-    # there is no vma checker to satisfy, so the plain struct is correct
-    typeof = getattr(jax, "typeof", None)
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -179,36 +173,52 @@ def flash_attention(
     return _flash_forward(q, k, v, scale, block_q, block_kv)[0]
 
 
-def _use_kernel() -> bool:
-    # Interpreter mode exists so CPU tests exercise the kernel path; on any
-    # other non-TPU backend (e.g. GPU) interpreting would be a silent
-    # orders-of-magnitude slowdown — use the dense einsum instead.
-    return jax.default_backend() in ("tpu", "cpu")
+def kernel_interpret() -> bool:
+    """``interpret=`` for every Pallas call in ops/: the TPU compiles the
+    kernel, the CPU interprets it (so tests run the identical code path), and
+    any other backend raises — computing the same maths some other way would
+    hide that the kernel the caller asked for never ran."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"the Pallas TPU kernels run on the tpu backend (compiled) or the cpu "
+        f"backend (interpreted), not on {backend!r} — build the model with "
+        "use_flash=False / quant='xla' there")
 
 
-def _flash_forward(q, k, v, scale, block_q, block_kv):
-    if not _use_kernel():
-        return _dense_attention_f32(q, k, v, scale)[1].astype(q.dtype), None
+def rows_spec(n_rows: int) -> P:
+    """Spec for an array whose leading dim is a batch of independent rows:
+    split over the ambient mesh's ``data`` axis when it divides, else whole."""
+    size = jax.sharding.get_abstract_mesh().shape.get("data", 1)
+    return P("data") if size > 1 and n_rows % size == 0 else P()
 
-    B, N, H, D = q.shape
-    qh, kh, vh = (_to_heads(x, B, N, H, D) for x in (q, k, v))
-    BH, Np, Dp = qh.shape
-    # pad-or-clamp the requested blocks to Mosaic-legal sizes for this
-    # dtype/N — min() alone produced illegal tiles at odd requests or
-    # sub-16 sublanes on bf16 (ops/tiling.py; N=2501 is the worst case)
-    bq = tiling.legal_block(block_q, Np, qh.dtype)
-    bkv = tiling.legal_block(block_kv, Np, qh.dtype)
-    qh = _pad_to(qh, 1, bq)
-    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
+
+def per_device(call, in_specs, out_specs):
+    """``call(*arrays)`` launches ONE Mosaic kernel. jit cannot partition such
+    a kernel over a mesh — lowering refuses it ("wrap the call in a
+    shard_map") — so under a multi-device ambient mesh (``jax.set_mesh``, which
+    the trainer, the samplers and the serving engine enter around their
+    programs) every device launches it on its own block per the specs. No
+    mesh, one device, or already inside a shard_map: ``call`` as it is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return call
+    return jax.shard_map(call, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
+
+
+def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, interpret):
+    BH, Nq, Dp = qh.shape
     n_kv = kh.shape[1] // bkv
-    grid = (BH, qh.shape[1] // bq, n_kv)
-
-    kernel = functools.partial(_fwd_kernel, scale=scale, n_valid=N,
+    kernel = functools.partial(_fwd_kernel, scale=scale, n_valid=n_valid,
                                block_kv=bkv, n_kv=n_kv)
     with profiling.scope("flash_attention/fwd"):
-        out, lse = pl.pallas_call(
+        return pl.pallas_call(
             kernel,
-            grid=grid,
+            grid=(BH, Nq // bq, n_kv),
             in_specs=[
                 pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0)),
@@ -219,19 +229,38 @@ def _flash_forward(q, k, v, scale, block_q, block_kv):
                 pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)),
             ],
             out_shape=[
-                _sds(qh.shape, q.dtype, qh),
-                _sds((*qh.shape[:2], _LANE), jnp.float32, qh),
+                _sds(qh.shape, qh.dtype, qh),
+                _sds((BH, Nq, _LANE), jnp.float32, qh),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, Dp), jnp.float32),    # output accumulator
                 pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
                 pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
-            interpret=jax.default_backend() == "cpu",
+            interpret=interpret,
         )(qh, kh, vh)
+
+
+def _flash_forward(q, k, v, scale, block_q, block_kv):
+    interpret = kernel_interpret()
+    B, N, H, D = q.shape
+    qh, kh, vh = (_to_heads(x, B, N, H, D) for x in (q, k, v))
+    BH, Np, Dp = qh.shape
+    # pad-or-clamp the requested blocks to Mosaic-legal sizes for this
+    # dtype/N — min() alone produced illegal tiles at odd requests or
+    # sub-16 sublanes on bf16 (ops/tiling.py; N=2501 is the worst case)
+    bq = tiling.legal_block(block_q, Np, qh.dtype)
+    bkv = tiling.legal_block(block_kv, Np, qh.dtype)
+    qh = _pad_to(qh, 1, bq)
+    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
+    rows = rows_spec(BH)
+    out, lse = per_device(
+        functools.partial(_fwd_call, scale=scale, n_valid=N, bq=bq, bkv=bkv,
+                          interpret=interpret),
+        (rows, rows, rows), (rows, rows))(qh, kh, vh)
 
     out = out[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
     # drop the lane replication before the lse becomes a VJP residual —
@@ -327,39 +356,71 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv):
-    B, N, H, D = q.shape
-    qh, kh, vh, oh, gh = (_to_heads(x, B, N, H, D) for x in (q, k, v, o, g))
-    BH, Np, Dp = qh.shape
-    bq = tiling.legal_block(block_q, Np, qh.dtype)
-    bkv = tiling.legal_block(block_kv, Np, qh.dtype)
-    qh, oh, gh = (_pad_to(x, 1, bq) for x in (qh, oh, gh))
-    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
-    n_q, n_kv = qh.shape[1] // bq, kh.shape[1] // bkv
-    # lse (BH, Nq⁺) and delta get lane-replicated to (…, LANE) blocks here —
-    # sublane-dim-1 (1, bq) row blocks don't lower on TPU (the (8, 128) tile
-    # rule); the broadcast is per-backward-call, so the residual stays O(N)
-    lse = _pad_to(lse, 1, bq)
-    lse = jnp.broadcast_to(lse[:, :, None], (*lse.shape, _LANE))
-    delta = jnp.sum(oh.astype(jnp.float32) * gh.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None], (*delta.shape, _LANE))
+#: the scoped VMEM ONE kernel's blocks, scratch and live temporaries must fit
+#: on the chip this repo targets — the compiler refuses the kernel otherwise
+#: ("exceeded scoped vmem limit"). Applied whatever the backend, so the CPU
+#: interpreter tiles exactly as the chip will.
+_SCOPED_VMEM_BYTES = flops.vmem_bytes("TPU v5 lite")
 
-    interpret = jax.default_backend() == "cpu"
+
+def _bwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
+    """Scoped VMEM the larger backward kernel needs at blocks (bq, bkv):
+    double-buffered in/out blocks, the f32 accumulators (dq: one (bq, D);
+    dk/dv: two (bkv, D)), the lane-replicated lse/delta rows and the live
+    (bq, bkv) temporaries (logits, p, dp, ds; narrower once cast to a 16-bit
+    GEMM feed). The temporaries' weight is calibrated against the v5e
+    compiler's accept/refuse frontier at N=2501 for f32 and bf16 —
+    tests/test_chip_compile.py compiles what this admits."""
+    rows = 2 * 2 * bq * _LANE * 4
+    live = bq * bkv * (2.4 + 1.4 * itemsize)
+    dq = bq * dp * (6 * itemsize + 4) + bkv * dp * 4 * itemsize
+    dkv = bq * dp * 4 * itemsize + bkv * dp * (8 * itemsize + 8)
+    return int(max(dq, dkv) + rows + live)
+
+
+def _bwd_blocks(bq: int, bkv: int, n_pad: int, dp: int, dtype) -> tuple:
+    """The backward's own (block_q, block_kv): the forward's, with the larger
+    side halved until both backward kernels fit the scoped VMEM. The budgets
+    differ — the dk/dv kernel carries two (bkv, D) accumulators and (bq, bkv)
+    temporaries the forward never holds — so forward-legal blocks (f32 at
+    ``NS_FLASH_BLOCKS``) can overflow it. The residuals are unpadded, so the
+    backward is free to tile differently from the forward."""
+    isz = jnp.dtype(dtype).itemsize
+    while _bwd_vmem_bytes(bq, bkv, dp, isz) > _SCOPED_VMEM_BYTES:
+        if bkv >= bq:
+            smaller = bq, tiling.legal_block(max(1, bkv // 2), n_pad, dtype)
+        else:
+            smaller = tiling.legal_block(max(1, bq // 2), n_pad, dtype), bkv
+        if smaller == (bq, bkv):
+            raise ValueError(
+                f"flash attention backward: no legal blocks fit "
+                f"{_SCOPED_VMEM_BYTES >> 20} MiB of VMEM at head dim {dp} "
+                f"({jnp.dtype(dtype).name}) — smallest tried {smaller}")
+        bq, bkv = smaller
+    return bq, bkv
+
+
+def _bwd_call(qh, kh, vh, gh, lse, delta, *, scale, n_valid, bq, bkv,
+              interpret):
+    """The two backward kernels on head-major operands: dq (grid like the
+    forward) and dk/dv (grid transposed)."""
+    BH, Nq, Dp = qh.shape
+    n_q, n_kv = Nq // bq, kh.shape[1] // bkv
     q_spec = pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0))
     kv_spec_dq = pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0))
     row_spec = pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0))
 
     with profiling.scope("flash_attention/dq"):
         dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, scale=scale, n_valid=N,
+            functools.partial(_bwd_dq_kernel, scale=scale, n_valid=n_valid,
                               block_q=bq, block_kv=bkv, n_kv=n_kv),
             grid=(BH, n_q, n_kv),
             in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec,
                       row_spec],
             out_specs=q_spec,
-            out_shape=_sds(qh.shape, q.dtype, qh),
+            out_shape=_sds(qh.shape, qh.dtype, qh),
             scratch_shapes=[pltpu.VMEM((bq, Dp), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(qh, kh, vh, gh, lse, delta)
@@ -370,20 +431,45 @@ def _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv):
     row_spec_t = pl.BlockSpec((1, bq, _LANE), lambda b, j, i: (b, i, 0))
     with profiling.scope("flash_attention/dkv"):
         dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, scale=scale, n_valid=N,
+            functools.partial(_bwd_dkv_kernel, scale=scale, n_valid=n_valid,
                               block_q=bq, block_kv=bkv, n_q=n_q),
             grid=(BH, n_kv, n_q),
             in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                       row_spec_t],
             out_specs=[kv_spec_t, kv_spec_t],
-            out_shape=[_sds(kh.shape, k.dtype, kh),
-                       _sds(vh.shape, v.dtype, vh)],
+            out_shape=[_sds(kh.shape, kh.dtype, kh),
+                       _sds(vh.shape, vh.dtype, vh)],
             scratch_shapes=[pltpu.VMEM((bkv, Dp), jnp.float32),
                             pltpu.VMEM((bkv, Dp), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(qh, kh, vh, gh, lse, delta)
+    return dq, dk, dv
+
+
+def _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv):
+    B, N, H, D = q.shape
+    qh, kh, vh, oh, gh = (_to_heads(x, B, N, H, D) for x in (q, k, v, o, g))
+    BH, Np, Dp = qh.shape
+    bq, bkv = _bwd_blocks(tiling.legal_block(block_q, Np, qh.dtype),
+                          tiling.legal_block(block_kv, Np, qh.dtype),
+                          Np, Dp, qh.dtype)
+    qh, oh, gh = (_pad_to(x, 1, bq) for x in (qh, oh, gh))
+    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
+    # lse (BH, Nq⁺) and delta get lane-replicated to (…, LANE) blocks here —
+    # sublane-dim-1 (1, bq) row blocks don't lower on TPU (the (8, 128) tile
+    # rule); the broadcast is per-backward-call, so the residual stays O(N)
+    lse = _pad_to(lse, 1, bq)
+    lse = jnp.broadcast_to(lse[:, :, None], (*lse.shape, _LANE))
+    delta = jnp.sum(oh.astype(jnp.float32) * gh.astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[:, :, None], (*delta.shape, _LANE))
+
+    rows = rows_spec(BH)
+    dq, dk, dv = per_device(
+        functools.partial(_bwd_call, scale=scale, n_valid=N, bq=bq, bkv=bkv,
+                          interpret=kernel_interpret()),
+        (rows,) * 6, (rows, rows, rows))(qh, kh, vh, gh, lse, delta)
 
     def from_heads(x):
         return x[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
@@ -453,23 +539,13 @@ def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512) -> jax.Array:
 
 
 def _dense_attention_f32(q, k, v, scale):
-    """XLA-einsum oracle/fallback path, f32 accumulation (ViT.py:110-114)."""
+    """XLA-einsum oracle the tests compare the kernels against, f32
+    accumulation (ViT.py:110-114)."""
     logits = jnp.einsum(
         "bnhd,bmhd->bhnm", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
     p = jax.nn.softmax(logits, axis=-1)
     return p, jnp.einsum("bhnm,bmhd->bnhd", p, v.astype(jnp.float32))
-
-
-def _dense_backward(q, k, v, g, scale):
-    p, _ = _dense_attention_f32(q, k, v, scale)
-    gf = g.astype(jnp.float32)
-    dv = jnp.einsum("bhnm,bnhd->bmhd", p, gf)
-    dp = jnp.einsum("bnhd,bmhd->bhnm", gf, v.astype(jnp.float32))
-    ds = p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))
-    dq = jnp.einsum("bhnm,bmhd->bnhd", ds, k.astype(jnp.float32)) * scale
-    dk = jnp.einsum("bhnm,bnhd->bmhd", ds, q.astype(jnp.float32)) * scale
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _flash_fwd(q, k, v, scale, block_q, block_kv):
@@ -479,10 +555,7 @@ def _flash_fwd(q, k, v, scale, block_q, block_kv):
 
 def _flash_bwd(scale, block_q, block_kv, residuals, g):
     q, k, v, o, lse = residuals
-    if lse is None:  # dense fallback path (non-TPU/CPU backends)
-        return _dense_backward(q, k, v, g, scale)
-    dq, dk, dv = _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv)
-    return dq, dk, dv
+    return _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -634,8 +707,8 @@ def fused_trunk_attention(x, w_qkv, s_qkv, b_qkv, w_proj, s_proj, b_proj, *,
     ops/quant.py codec); biases f32 or None. Returns (B, N, C) in ``x``'s
     dtype — the full QuantDense epilogue (scale, bias, cast) included.
     ``mode="w8a8"`` additionally quantizes the activations (per-tensor
-    dynamic scale, int8×int8 trunk GEMMs). Off TPU/CPU, falls back to the
-    unfused XLA composition, same policy as :func:`flash_attention`.
+    dynamic scale, int8×int8 trunk GEMMs). Backend policy as
+    :func:`kernel_interpret`.
     """
     from ddim_cold_tpu.ops import quant as _quant
 
@@ -647,17 +720,7 @@ def fused_trunk_attention(x, w_qkv, s_qkv, b_qkv, w_proj, s_proj, b_proj, *,
         raise ValueError(f"fused attention mode must be 'pallas' or 'w8a8', "
                          f"got {mode!r}")
     w8a8 = mode == "w8a8"
-    if not _use_kernel():
-        # unfused XLA composition (GPU etc.) — the same epilogues
-        xla_mode = "w8a8" if w8a8 else "xla"
-        qkv = _quant.dequant_matmul(x, w_qkv, s_qkv, bias=b_qkv,
-                                    mode=xla_mode)
-        qkv = qkv.astype(x.dtype).reshape(B, N, 3, num_heads, head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        out = _dense_attention_f32(q, k, v, scale)[1].astype(x.dtype)
-        y = _quant.dequant_matmul(out.reshape(B, N, C), w_proj, s_proj,
-                                  bias=b_proj, mode=xla_mode)
-        return y.astype(x.dtype)
+    interpret = kernel_interpret()
 
     if w8a8:
         xi, xs = _quant.quantize_act(x)
@@ -707,10 +770,10 @@ def fused_trunk_attention(x, w_qkv, s_qkv, b_qkv, w_proj, s_proj, b_proj, *,
                 pltpu.VMEM((num_heads, bq, _LANE), jnp.float32),  # running max
                 pltpu.VMEM((num_heads, bq, _LANE), jnp.float32),  # running den
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
-            interpret=jax.default_backend() == "cpu",
+            interpret=interpret,
         )(*inputs)
     with profiling.scope("flash_attention/fused_proj"):
         # scale + bias already applied in-kernel; only slice off the q-block

@@ -29,9 +29,10 @@ Pieces:
     scale multiply into the epilogue — no dequantized weight copy in HBM.
   - ``mode="pallas"``: a fused dequant-matmul kernel (grid over M/N tiles,
     K streamed innermost through a VMEM f32 accumulator, scale applied once
-    at emit). Same capability gating as ops/flash_attention.py: TPU runs
-    the kernel, CPU runs it in interpreter mode (tests exercise the real
-    code path), any other backend falls back to the XLA form.
+    at emit). Same backend policy as ops/flash_attention.py
+    (``kernel_interpret``): TPU compiles the kernel, CPU runs it in
+    interpreter mode (tests exercise the real code path), any other backend
+    raises — ``mode="xla"`` is the form to ask for there.
 
 * ``QuantDense`` — the flax module models/vit.py swaps in for ``nn.Dense``
   when ``model.quant`` is set; declares exactly the ``{w_int8, scale[, bias]}``
@@ -57,16 +58,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ddim_cold_tpu.ops import tiling
+from ddim_cold_tpu.ops.flash_attention import kernel_interpret
 from ddim_cold_tpu.utils import profiling
 
-#: Pallas-TPU compiler params across jax versions (same shim as
-#: ops/flash_attention.py — renamed TPUCompilerParams → CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-#: quantization revision stamped into bench records (mirrors KERNEL_REV:
-#: scripts/perf_tables.py renders it and stale-record protection keys
-#: re-measurement off it). "w8a16-pcq-v1" = per-output-channel symmetric
-#: int8 weights, [−127, 127] codes, f32-accumulated dequant matmul.
+#: quantization revision stamped into bench records (mirrors KERNEL_REV;
+#: scripts/perf_tables.py renders it). "w8a16-pcq-v1" = per-output-channel
+#: symmetric int8 weights, [−127, 127] codes, f32-accumulated dequant matmul.
 #: "w8a16-fused-v2" adds the fused trunk kernels (mlp_pallas here, the fused
 #: attention in ops/flash_attention.py) and the optional "w8a8" activation
 #: mode (per-tensor dynamic int8 activations, int32 MXU accumulation). The
@@ -265,13 +262,6 @@ def _dequant_matmul_w8a8(x: jax.Array, w_int8: jax.Array, scale: jax.Array,
 # w8a16 matmul — Pallas fused kernel
 # ---------------------------------------------------------------------------
 
-def _use_kernel() -> bool:
-    # same policy as ops/flash_attention.py: TPU compiles the kernel, CPU
-    # interprets it (tests exercise the identical code path), any other
-    # backend (GPU) takes the XLA form instead of a silent interpreter crawl
-    return jax.default_backend() in ("tpu", "cpu")
-
-
 def _mm_kernel(*refs, n_k: int, has_bias: bool):
     """One (m-tile, n-tile, k-chunk) program: dequantize this int8 weight
     chunk to the activation dtype in VMEM, fold its partial product into the
@@ -374,9 +364,9 @@ def _dequant_matmul_pallas(x2d: jax.Array, w_int8: jax.Array, scale: jax.Array,
             out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1]),
                                            jnp.float32),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=jax.default_backend() == "cpu",
+            interpret=kernel_interpret(),
         )(*inputs)
     return out[:M, :N]
 
@@ -394,8 +384,8 @@ def dequant_matmul(x: jax.Array, w_int8: jax.Array, scale: jax.Array,
     the kernel epilogue rather than added by the caller so the scale·acc+b
     contraction point is identical across the unfused and fused trunk paths
     (see ``_mm_kernel``). ``mode="pallas"`` runs the fused w8a16 kernel
-    where capability allows and silently takes the XLA form elsewhere,
-    exactly the flash-attention fallback policy. ``mode="w8a8"`` quantizes
+    (``kernel_interpret`` backend policy — never the XLA form in its
+    place). ``mode="w8a8"`` quantizes
     the activation too (per-tensor dynamic scale, int8×int8 GEMM) — the
     unfused reference for the fused w8a8 kernels."""
     if mode not in QUANT_MODES:
@@ -404,7 +394,7 @@ def dequant_matmul(x: jax.Array, w_int8: jax.Array, scale: jax.Array,
         raise ValueError(f"w_int8 must be int8, got {w_int8.dtype}")
     if mode == "w8a8":
         return _dequant_matmul_w8a8(x, w_int8, scale, bias)
-    if mode == "pallas" and _use_kernel():
+    if mode == "pallas":
         lead = x.shape[:-1]
         y = _dequant_matmul_pallas(x.reshape(-1, x.shape[-1]), w_int8,
                                    scale, bias)
@@ -415,6 +405,37 @@ def dequant_matmul(x: jax.Array, w_int8: jax.Array, scale: jax.Array,
 # ---------------------------------------------------------------------------
 # fused Mlp kernel (matmul → bias → exact GELU → matmul)
 # ---------------------------------------------------------------------------
+
+#: erf(x) ≈ x·P(x²)/Q(x²) on [−4, 4] — the float32 rational approximation
+#: Eigen and XLA evaluate (coefficients highest degree first; |error| < 5e-7,
+#: pinned in tests/test_quant.py)
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+          -7.37332916720468e-03, -1.42647390514189e-02)
+
+
+def _horner(coeffs, x):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def gelu_exact(x: jax.Array) -> jax.Array:
+    """Exact (erf) GELU, ``x/2 · (1 + erf(x/√2))``, computed in float32 and
+    returned in ``x``'s dtype — THE activation of every Mlp in the repo
+    (models/vit.py, models/moe.py, the fused kernel below), so the fused and
+    unfused trunks stay bitwise equal. erf is spelled out from mul/add/div
+    because the Pallas TPU lowering has neither ``erf`` nor the ``erfc``
+    that ``jax.nn.gelu(approximate=False)`` goes through."""
+    xf = x.astype(jnp.float32)
+    z = jnp.clip(xf * (0.5 ** 0.5), -4.0, 4.0)
+    z2 = z * z
+    erf = z * _horner(_ERF_P, z2) / _horner(_ERF_Q, z2)
+    return (0.5 * xf * (1.0 + erf)).astype(x.dtype)
+
 
 def _mlp_kernel(*refs, quant: bool, w8a8: bool, has_b2: bool, cdt):
     """One M-tile program of the fused Mlp: fc1 GEMM into the f32 scratch
@@ -452,7 +473,7 @@ def _mlp_kernel(*refs, quant: bool, w8a8: bool, has_b2: bool, cdt):
             x, w1_ref[...].astype(cdt), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
     acc_ref[...] = y1 + b1_ref[0]  # f32 accumulator, f32 bias epilogue
-    h = jax.nn.gelu(acc_ref[...].astype(cdt), approximate=False)
+    h = gelu_exact(acc_ref[...].astype(cdt))
     if w8a8:
         amax = jnp.max(jnp.abs(h.astype(jnp.float32)))
         hs = jnp.where(amax > 0, amax / 127.0, 1.0)
@@ -486,8 +507,8 @@ def mlp_pallas(x, w1, b1, w2, b2, *, scale1=None, scale2=None,
     ``mode=None``: float weights (``w1``/``w2`` are the dense kernels).
     ``mode="pallas"``: w8a16 — int8 weights with per-column f32 scales.
     ``mode="w8a8"``: int8 weights AND per-tensor dynamic int8 activations.
-    Returns ``x.dtype``, full bias epilogues included; off TPU/CPU takes the
-    unfused XLA composition (same fallback policy as flash/dequant)."""
+    Returns ``x.dtype``, full bias epilogues included; backend policy as
+    ``kernel_interpret``."""
     if mode not in (None, "pallas", "w8a8"):
         raise ValueError(f"mlp_pallas mode must be None, 'pallas' or "
                          f"'w8a8', got {mode!r}")
@@ -498,25 +519,7 @@ def mlp_pallas(x, w1, b1, w2, b2, *, scale1=None, scale2=None,
     cdt = x.dtype
     lead, K = x.shape[:-1], x.shape[-1]
     Hf, Nout = w1.shape[-1], w2.shape[-1]
-    if not _use_kernel():
-        # unfused XLA composition (GPU etc.) — the same epilogues
-        if mode is None:
-            y1 = jax.lax.dot_general(
-                x, w1.astype(cdt), (((x.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) + b1
-        else:
-            mm = _dequant_matmul_w8a8 if mode == "w8a8" else _dequant_matmul_xla
-            y1 = mm(x, w1, scale1, b1)
-        h = jax.nn.gelu(y1.astype(cdt), approximate=False)
-        if mode is None:
-            y2 = jax.lax.dot_general(
-                h, w2.astype(cdt), (((h.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if b2 is not None:
-                y2 = y2 + b2
-        else:
-            y2 = mm(h, w2, scale2, b2)
-        return y2.astype(cdt)
+    interpret = kernel_interpret()
 
     if mode == "w8a8":
         xi, xs = quantize_act(x)
@@ -556,9 +559,9 @@ def mlp_pallas(x, w1, b1, w2, b2, *, scale1=None, scale2=None,
             out_specs=pl.BlockSpec((bm, Nout), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((xp.shape[0], Nout), jnp.float32),
             scratch_shapes=[pltpu.VMEM((bm, Hf), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
-            interpret=jax.default_backend() == "cpu",
+            interpret=interpret,
         )(*inputs)
     return out[:M].astype(cdt).reshape(*lead, Nout)
 

@@ -25,7 +25,7 @@ is replaced by ``jax.profiler`` tracing (utils/profiling.py).
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import partial, wraps
 from typing import Optional
 
 import jax
@@ -34,6 +34,20 @@ import jax.numpy as jnp
 from ddim_cold_tpu.obs.device import StepTelemetry
 from ddim_cold_tpu.ops import schedule, step_cache
 from ddim_cold_tpu.utils import profiling
+
+
+def _on_mesh(sampler):
+    """Run a public sampler with its ``mesh=`` argument as the ambient mesh
+    (parallel/mesh.ambient), so the model's Pallas kernels launch per device
+    inside the program it traces."""
+    @wraps(sampler)
+    def run(*args, mesh=None, **kwargs):
+        from ddim_cold_tpu.parallel.mesh import ambient
+
+        with ambient(mesh):
+            return sampler(*args, mesh=mesh, **kwargs)
+
+    return run
 
 
 def forward_noise(rng: jax.Array, img: jax.Array, t_start: int, total_steps: int = 2000):
@@ -220,6 +234,7 @@ _ddim_scan_fewstep_cached_seq = jax.jit(
     _fewstep_cached_impl, static_argnames=_FEWSTEP_CACHED_STATICS)
 
 
+@_on_mesh
 def ddim_sample_fewstep(
     model,
     params,
@@ -526,6 +541,7 @@ def _shard_init(x_init: jax.Array, mesh) -> jax.Array:
     return jax.device_put(x_init, batch_sharding(mesh))
 
 
+@_on_mesh
 def ddim_sample(
     model,
     params,
@@ -818,6 +834,7 @@ _cold_scan_cached_seq = jax.jit(_cold_cached_impl,
                                 static_argnames=_COLD_CACHED_STATICS)
 
 
+@_on_mesh
 def cold_sample(
     model,
     params,

@@ -1,4 +1,5 @@
 from ddim_cold_tpu.parallel.mesh import (
+    ambient,
     batch_sharding,
     make_mesh,
     replicated,
@@ -13,6 +14,7 @@ from ddim_cold_tpu.parallel.ulysses import SeqParallelConfigError
 __all__ = [
     "SeqParallelConfigError",
     "make_mesh",
+    "ambient",
     "batch_sharding",
     "replicated",
     "shard_batch",

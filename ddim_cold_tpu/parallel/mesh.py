@@ -18,6 +18,7 @@ queries; each host then feeds its data shard (data/loader.py shard_index =
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -51,6 +52,16 @@ def make_mesh(shape: Optional[dict[str, int]] = None, devices=None) -> Mesh:
     if int(np.prod(sizes)) != devices.size:
         raise ValueError(f"mesh shape {shape} does not match {devices.size} devices")
     return Mesh(devices.reshape(sizes), tuple(shape.keys()))
+
+
+def ambient(mesh: Optional[Mesh]):
+    """``mesh`` as JAX's ambient mesh (``jax.set_mesh``) for the ``with``
+    block; no-op for ``None``. Whoever traces a program over a mesh enters
+    this around it: the Pallas kernel call sites read the ambient mesh to
+    launch one kernel per device (ops/flash_attention.per_device) — jit
+    cannot partition a Mosaic kernel itself and refuses to lower one over
+    several devices."""
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -40,9 +40,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ddim_cold_tpu.parallel import _compat
-from ddim_cold_tpu.parallel._compat import shard_map
 
 
 def pipeline_blocks(
@@ -203,7 +202,7 @@ def pipeline_blocks(
                 tok, a = apply_block(p, tok, rate, rngs)
                 return (tok, aux + a), None
 
-            aux0 = _compat.pcast(jnp.zeros((), jnp.float32), aux_axes,
+            aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), aux_axes,
                                  to="varying")
             (tok, aux), _ = jax.lax.scan(
                 body, (tok, aux0), (params_s, dpr_s, jnp.arange(bps)))
@@ -213,10 +212,10 @@ def pipeline_blocks(
         # accumulators must be typed varying over the pipe axis too (values
         # differ per stage via params/ppermute) for shard_map's vma loop
         # typing; zeros_like already inherits the data-varying from mb_all
-        vary = lambda z: _compat.pcast(z, (axis,), to="varying")
+        vary = lambda z: jax.lax.pcast(z, (axis,), to="varying")
         out_buf = vary(jnp.zeros_like(mb_all))
         buf = vary(jnp.zeros_like(mb_all[0]))
-        aux_acc = _compat.pcast(jnp.zeros((), jnp.float32), aux_axes,
+        aux_acc = jax.lax.pcast(jnp.zeros((), jnp.float32), aux_axes,
                                 to="varying")
 
         def step(carry, i):

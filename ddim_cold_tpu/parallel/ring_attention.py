@@ -23,9 +23,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ddim_cold_tpu.parallel import _compat
-from ddim_cold_tpu.parallel._compat import shard_map
+
 from ddim_cold_tpu.utils import profiling
 
 _NEG_INF = -1e30
@@ -55,7 +55,7 @@ def ring_attention(
     # running (output·denominator, denominator, max) accumulators, f32 —
     # marked varying over every axis the inputs vary on (the ring axis, plus
     # the batch axis on a composed dp×sp mesh) for shard_map's vma loop typing
-    vary = lambda x: _compat.pcast(x, varying_axes or (axis_name,), to="varying")
+    vary = lambda x: jax.lax.pcast(x, varying_axes or (axis_name,), to="varying")
     o = vary(jnp.zeros((B, H, n_loc, D), jnp.float32))
     l = vary(jnp.zeros((B, H, n_loc), jnp.float32))
     m = vary(jnp.full((B, H, n_loc), _NEG_INF, jnp.float32))

@@ -27,8 +27,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ddim_cold_tpu.parallel._compat import shard_map
+
 from ddim_cold_tpu.utils import profiling
 
 

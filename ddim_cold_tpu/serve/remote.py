@@ -638,6 +638,14 @@ def remote_factory(spec: dict, *, env: Optional[dict] = None,
     specs never leak across the fork; the two processes have independent
     fault registries by construction).
 
+    One process per chip: an ``"engine"`` child initialises its own backend,
+    and an accelerator belongs to the process that touched it first. A
+    parent that spawns ON-CHIP replicas therefore stays off JAX itself (no
+    ``jax.devices()``, no arrays); a parent that holds the chip gives its
+    children ``env={"JAX_PLATFORMS": "cpu"}``. A child that finds no device
+    exits with its traceback and the factory raises as soon as it is gone;
+    one that hangs in backend start-up is killed at ``spawn_timeout_s``.
+
     The factory spawns the child, hands it the ephemeral listener port, and
     blocks until the child connects and sends its hello (deadline
     ``spawn_timeout_s``). Spawn wall time lands on the handle as
@@ -651,7 +659,7 @@ def remote_factory(spec: dict, *, env: Optional[dict] = None,
         listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         listener.bind(("127.0.0.1", 0))
         listener.listen(1)
-        listener.settimeout(spawn_timeout_s)
+        listener.settimeout(0.2)  # accept() polls, so a dead child is seen
         port = listener.getsockname()[1]
         child_env = dict(os.environ)
         if env:
@@ -672,12 +680,23 @@ def remote_factory(spec: dict, *, env: Optional[dict] = None,
         t0 = time.perf_counter()
         proc = subprocess.Popen(argv, env=child_env)
         try:
-            conn, _ = listener.accept()
-        except socket.timeout:
-            proc.kill()
-            raise ReplicaUnreachableError(
-                f"replica {replica_id}: no connection within "
-                f"{spawn_timeout_s}s of spawn") from None
+            while True:
+                try:
+                    conn, _ = listener.accept()
+                    break
+                except socket.timeout:
+                    pass
+                if proc.poll() is not None:
+                    raise ReplicaUnreachableError(
+                        f"replica {replica_id}: child exited with code "
+                        f"{proc.returncode} before connecting — its stderr "
+                        "says why (an 'engine' replica needs a device of its "
+                        "own: a parent that has touched JAX holds the chip)")
+                if time.perf_counter() - t0 > spawn_timeout_s:
+                    proc.kill()
+                    raise ReplicaUnreachableError(
+                        f"replica {replica_id}: no connection within "
+                        f"{spawn_timeout_s}s of spawn")
         finally:
             listener.close()
         # The hello read spends what is LEFT of the spawn budget — a child

@@ -379,12 +379,15 @@ class ReplicaServer:
 
 def build_replica(replica_id: str, spec: dict):
     """Spec → ReplicaHandle. The persistent compile-cache dir rides in as
-    ``spec["cache_dir"]`` and lands in the environment BEFORE any engine
-    exists, so a spawned replacement warms from disk — the pre-warmed-spawn
-    half of the autoscaler contract."""
+    ``spec["cache_dir"]`` and is in place BEFORE any engine exists, so a
+    spawned replacement warms from disk — the pre-warmed-spawn half of the
+    autoscaler contract. ``JAX_COMPILATION_CACHE_DIR`` in the child's
+    environment wins over it (utils/platform.enable_compile_cache)."""
     cache_dir = spec.get("cache_dir")
     if cache_dir:
-        os.environ.setdefault("DDIM_COLD_COMPILE_CACHE", str(cache_dir))
+        from ddim_cold_tpu.utils.platform import enable_compile_cache
+
+        enable_compile_cache(str(cache_dir))
     if spec.get("backend", "stub") == "stub":
         return fleet.LocalReplica(
             StubEngine(replica_id=replica_id, **(spec.get("stub") or {})))

@@ -232,8 +232,8 @@ def save_checkpoint(path: str, tree) -> None:
 
     Single-host saves write beside the destination and swap in with two
     rename metadata ops — ``force=True`` straight onto ``path`` would delete
-    the PREVIOUS checkpoint before the (multi-second, on tunneled hosts)
-    write, so a crash mid-write would lose the only resume point. Multi-host
+    the PREVIOUS checkpoint before the (possibly multi-second) write, so a
+    crash mid-write would lose the only resume point. Multi-host
     saves go directly through orbax's own collective commit protocol (a
     per-process directory swap on a shared fs would race).
 

@@ -34,7 +34,7 @@ PEAK_BF16_TFLOPS = {
 PEAK_INT8_TOPS = {
     "TPU v6": 1836.0,  # Trillium
     "TPU v5p": 918.0,
-    "TPU v5 lite": 394.0,  # v5e
+    "TPU v5 lite": 393.0,  # v5e
     "TPU v5": 918.0,
     "TPU v4 lite": 138.0,
     "TPU v4": 275.0,
@@ -99,6 +99,18 @@ def _prefix_lookup(table: dict, device_kind: str) -> float | None:
 def peak_tflops(device_kind: str) -> float | None:
     """Longest-prefix match of the device kind; None when unknown (CPU etc.)."""
     return _prefix_lookup(PEAK_BF16_TFLOPS, device_kind)
+
+
+def require_peak_tflops(device_kind: str) -> float:
+    """:func:`peak_tflops` for a path that MEASURES: an accelerator with no
+    entry in the table is an error there, not an MFU of ``None``."""
+    peak = peak_tflops(device_kind)
+    if peak is None:
+        raise LookupError(
+            f"no peak TFLOP/s for device kind {device_kind!r} in "
+            "utils/flops.PEAK_BF16_TFLOPS — add the chip's published peaks "
+            "before measuring on it")
+    return peak
 
 
 def peak_int8_tops(device_kind: str) -> float | None:

@@ -1,8 +1,6 @@
-"""Bench-record parsing shared by bench.py's captured-earlier fallback and
-the recovery chain's idempotence oracle (scripts/r04_stage_done.py) — ONE
-policy for "what is the record in this file" and "was it captured on a real
-accelerator", so the chain and the bench can never disagree about whether a
-committed results file is a reusable TPU record."""
+"""Bench-record parsing and stamping shared by bench.py, obs/trend.py and
+scripts/perf_tables.py — ONE policy for "what is the record in this file" and
+"was it captured on a real accelerator"."""
 
 from __future__ import annotations
 

@@ -3,16 +3,16 @@
 
 The serving warmup (ddim_cold_tpu/serve/warmup.py) leans on the cache to
 make a process restart compile-free — this check proves the wiring on the
-running JAX, end to end:
+running JAX, end to end, in the directory the program itself uses
+(``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache`` or
+the directory given as the argument):
 
-1. ``enable_compile_cache`` points the cache at a temp (or given) directory;
-2. a jitted function compiles → the directory must gain an entry;
+1. ``enable_compile_cache`` turns the cache on;
+2. a jitted function compiles → the directory must hold an entry;
 3. the in-memory jit cache is cleared and the SAME function recompiles →
    the directory must NOT gain another entry (the disk hit served it).
 
-Exit codes: 0 = verified (or SKIP where this JAX lacks the cache config —
-capability-gated like parallel/_compat.py, never a false failure on old
-versions), 1 = the cache directory was not created or not used.
+Exit codes: 0 = verified, 1 = the cache directory was not used.
 
 Usage: ``python scripts/check_compile_cache.py [cache_dir]``
 """
@@ -32,71 +32,43 @@ def _entries(path):
 
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
-    import tempfile
 
     import jax
+    import jax.numpy as jnp
 
-    from ddim_cold_tpu.utils.platform import enable_compile_cache, honor_env_platform
+    from ddim_cold_tpu.utils.platform import enable_compile_cache
 
-    honor_env_platform()
+    active = enable_compile_cache(os.path.abspath(argv[0]) if argv else None)
+    # production keeps a 0.5 s floor so trivial compiles don't churn the
+    # disk; the check's probe compile IS trivial, so the floor must drop
+    # or the assertion below would test the floor, not the cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-    # capability gate: the persistent cache shipped gradually (the config
-    # names below). A JAX without them can't run this check — skip cleanly,
-    # matching the parallel/_compat.py stance on version spread.
-    for opt in ("jax_compilation_cache_dir",
-                "jax_persistent_cache_min_compile_time_secs"):
-        if not hasattr(jax.config, opt):
-            print(f"SKIP: this jax ({jax.__version__}) lacks {opt}; "
-                  "persistent compilation cache unavailable")
-            return 0
+    @jax.jit
+    def probe(x):
+        return jnp.sin(x) * jnp.arange(x.shape[0], dtype=x.dtype) + 3.0
 
-    tmp = None
-    if argv:
-        cache_dir = os.path.abspath(argv[0])
-    else:
-        tmp = tempfile.TemporaryDirectory(prefix="ddim_cold_cache_check_")
-        cache_dir = tmp.name
-    try:
-        active = enable_compile_cache(cache_dir)
-        if active is None:
-            print("SKIP: enable_compile_cache declined (disabled via "
-                  "DDIM_COLD_COMPILE_CACHE, or cache config rejected)")
-            return 0
-        # production keeps a 0.5 s floor so trivial compiles don't churn the
-        # disk; the check's probe compile IS trivial, so the floor must drop
-        # or the assertion below would test the floor, not the cache
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    probe(jnp.ones((16,))).block_until_ready()
+    after_first = _entries(active)
+    if not after_first:
+        print(f"FAIL: compile left no entry under {active} — the "
+              "persistent cache is configured but unused")
+        return 1
+    print(f"ok: after the first compile {active} holds {len(after_first)} "
+          f"entr{'y' if len(after_first) == 1 else 'ies'}")
 
-        import jax.numpy as jnp
-
-        @jax.jit
-        def probe(x):
-            return jnp.sin(x) * jnp.arange(x.shape[0], dtype=x.dtype) + 3.0
-
-        probe(jnp.ones((16,))).block_until_ready()
-        after_first = _entries(active)
-        if not after_first:
-            print(f"FAIL: compile wrote no entry under {active} — the "
-                  "persistent cache is configured but unused")
-            return 1
-        print(f"ok: first compile wrote {len(after_first)} cache "
-              f"entr{'y' if len(after_first) == 1 else 'ies'} under {active}")
-
-        probe.clear_cache()  # drop the in-memory executable, keep the disk
-        probe(jnp.ones((16,))).block_until_ready()
-        after_second = _entries(active)
-        if after_second != after_first:
-            print("FAIL: recompile after clear_cache changed the cache dir "
-                  f"({len(after_first)} → {len(after_second)} entries) — "
-                  "the disk entry was not reused")
-            return 1
-        print("ok: recompile after clear_cache reused the disk entry "
-              "(no new files)")
-        print(f"PASS: persistent compilation cache verified at {active}")
-        return 0
-    finally:
-        if tmp is not None:
-            tmp.cleanup()
+    probe.clear_cache()  # drop the in-memory executable, keep the disk
+    probe(jnp.ones((16,))).block_until_ready()
+    after_second = _entries(active)
+    if after_second != after_first:
+        print("FAIL: recompile after clear_cache changed the cache dir "
+              f"({len(after_first)} → {len(after_second)} entries) — "
+              "the disk entry was not reused")
+        return 1
+    print("ok: recompile after clear_cache reused the disk entry "
+          "(no new files)")
+    print(f"PASS: persistent compilation cache verified at {active}")
+    return 0
 
 
 if __name__ == "__main__":

@@ -44,10 +44,6 @@ def main(argv=None):
                     help="4 → 257 tokens: long enough that seq sharding is real")
     ap.add_argument("--embed", type=int, default=128)
     ap.add_argument("--heads", type=int, default=4)
-    ap.add_argument("--tpu", action="store_true",
-                    help="run on the real TPU backend (default: virtual CPU "
-                         "mesh — probing for a TPU can block when the chip "
-                         "is leased elsewhere)")
     args = ap.parse_args(argv)
 
     flags = os.environ.get("XLA_FLAGS", "")
@@ -56,13 +52,6 @@ def main(argv=None):
             f"{flags} --xla_force_host_platform_device_count={args.devices}"
         ).strip()
     import jax
-
-    from ddim_cold_tpu.utils.platform import honor_env_platform
-
-    if args.tpu:
-        honor_env_platform()
-    else:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 
@@ -127,7 +116,7 @@ def main(argv=None):
     # inverts the dp-vs-model-parallel ordering), and the bf16 tp-psum
     # inside the partially-manual pipelined shard_map CHECK-fails in XLA's
     # CPU AllReducePromotion pass outright (pipeline.py docstring).
-    amp = bool(args.tpu)
+    amp = jax.default_backend() == "tpu"
     results = {}
     for name, (mesh_shape, extra) in layouts.items():
         kw = dict(

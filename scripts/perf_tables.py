@@ -1,16 +1,13 @@
 #!/usr/bin/env python
-"""Render a bench record (results/bench_r*_tpu.json or BENCH_r*.json) into
-the PERF.md-style markdown tables — so the write-up after an evidence drop
-is a paste, not a transcription (and transcription errors can't creep into
-the round's perf claims).
+"""Render bench records (the JSON line ``bench.py`` prints, saved to a file)
+into PERF.md-style markdown tables — so a write-up is a paste, not a
+transcription.
 
-Usage: python scripts/perf_tables.py [record.json ...]
-Defaults to the newest results/bench_r*_tpu.json.
+Usage: python scripts/perf_tables.py record.json [...]
 """
 
 from __future__ import annotations
 
-import glob
 import os
 import sys
 
@@ -39,17 +36,11 @@ def render(path: str) -> str:
               f"({rec.get('vs_baseline')}× the 702 img/s 3090 baseline) · "
               f"{rec.get('ms_per_step')} ms/step · MFU {rec.get('mfu')}"
               + (f" · {revs}" if revs else ""), ""]
-    if rec.get("captured_earlier"):
-        ce = sub.get("captured_earlier", {})
-        lines += [f"> REUSED record ({ce.get('file')}"
-                  + (f", stale round {ce['stale_round']}" if "stale_round" in ce
-                     else "") + ") — not a fresh measurement", ""]
     rm = rec.get("run_meta")
     if rm:
         lines += [f"provenance: sha `{rm.get('git_sha')}` · jax "
                   f"{rm.get('jax')} / jaxlib {rm.get('jaxlib')} · ts "
-                  f"{rm.get('timestamp')}"
-                  + (" · replayed" if rm.get("replayed") else ""), ""]
+                  f"{rm.get('timestamp')}", ""]
 
     rows = sub.get("batch_scaling")
     if rows:
@@ -376,10 +367,8 @@ def render(path: str) -> str:
 def main(argv=None):
     paths = (argv or sys.argv)[1:]
     if not paths:
-        paths = sorted(glob.glob(os.path.join(REPO, "results", "bench_r*_tpu.json")))[-1:]
-        if not paths:
-            print("no bench records found", file=sys.stderr)
-            return 1
+        print(__doc__.strip().splitlines()[-1], file=sys.stderr)
+        return 2
     for p in paths:
         print(render(p))
         print()

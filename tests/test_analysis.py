@@ -544,7 +544,7 @@ def _sp_mesh():
 def _smap(fn, mesh):
     from jax.sharding import PartitionSpec as P
 
-    from ddim_cold_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     return shard_map(fn, mesh=mesh, in_specs=P("s"), out_specs=P("s"),
                      check_vma=False)

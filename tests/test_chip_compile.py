@@ -1,0 +1,230 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed with jaxlib and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). Interpret-mode
+tests cannot see what it refuses — more scoped VMEM than a kernel may use, a
+primitive the Pallas TPU lowering lacks, a Mosaic kernel inside a jit over
+several devices — so every kernel a legal model can reach at the 200px
+geometries is compiled here for ``TPU v5 lite``, at the real widths.
+
+A compile that passes is not a chip run: nothing executes, and nothing here
+says anything about results or speed. ``chip_smoke.py`` is the chip run.
+
+Skipped where the topology cannot be described (no libtpu, or another process
+holds it). The kernels gate on ``jax.default_backend()`` and the block table
+is keyed by the local device kind, both of which are the CPU's here, so the
+tests steer them — not an option of the program.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ddim_cold_tpu.models import MODEL_CONFIGS, DiffusionViT
+from ddim_cold_tpu.ops import flash_attention as fa
+from ddim_cold_tpu.ops import quant, tuning
+from ddim_cold_tpu.parallel import ambient
+
+KIND = "TPU v5 lite"
+ROWS = 16                      # the sampler's batch (analysis/entries.NS_ROWS)
+N, C, H, D = 2501, 256, 4, 64  # oxford_flower_200_p4 trunk
+P4 = MODEL_CONFIGS["oxford_flower_200_p4"]
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """The described v5e 2×2 host, with the kernels' backend gate steered to
+    ``tpu``, the persistent compile cache off (an entry compiled for a
+    described chip cannot be read back without one, and warns) and the
+    suite's ``float32`` matmul-precision pin lifted — the program runs at
+    JAX's default, and Mosaic refuses an fp32-precision matmul on bf16."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any reason it cannot be had
+        pytest.skip(f"no TPU topology description here: {e}")
+    assert topo.devices[0].device_kind == KIND
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    real_backend, real_kind = jax.default_backend, tuning._local_device_kind
+    jax.default_backend = lambda: "tpu"
+    tuning._local_device_kind = lambda: KIND
+    try:
+        yield topo.devices
+    finally:
+        jax.default_backend = real_backend
+        tuning._local_device_kind = real_kind
+        jax.config.update("jax_default_matmul_precision", precision)
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+def _struct(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _params(model, sds):
+    tree = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, *model.img_size, 3)),
+        jnp.zeros((1,), jnp.int32))["params"])
+    return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+
+# --- cases: name → builder(devices) → (fn, args, custom calls expected) -----
+
+def _flash_fwd(dtype, blocks):
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        q = sds((ROWS, N, H, D), dtype)
+        return (lambda q, k, v: fa.flash_attention(q, k, v, D ** -0.5, *blocks),
+                (q, q, q), 1)
+    return build
+
+
+def _flash_grad(dtype):
+    """Backward at NS_FLASH_BLOCKS: the f32 case only compiles because the
+    backward picks its own blocks (flash_attention._bwd_blocks)."""
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        q = sds((ROWS, N, H, D), dtype)
+        loss = lambda q, k, v: fa.flash_attention(  # noqa: E731
+            q, k, v, D ** -0.5, *fa.NS_FLASH_BLOCKS).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q), 3
+    return build
+
+
+def _dequant(n_out):
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        return (lambda x, w, s: quant.dequant_matmul(x, w, s, mode="pallas"),
+                (sds((ROWS * N, C), jnp.bfloat16), sds((C, n_out), jnp.int8),
+                 sds((n_out,), jnp.float32)), 1)
+    return build
+
+
+def _mlp(mode, dtype):
+    """The fused Mlp at the block_m the model would pick for this geometry."""
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        act = jnp.int8 if mode == "w8a8" else dtype
+        bm = tuning.mlp_block_m(C, C, act, quant=mode is not None,
+                                device_kind=KIND)
+        w = sds((C, C), jnp.float32 if mode is None else jnp.int8)
+        vec = sds((C,), jnp.float32)
+        scales = {} if mode is None else {"scale1": vec, "scale2": vec}
+        return (lambda x, w1, b1, w2, b2, **s: quant.mlp_pallas(
+                    x, w1, b1, w2, b2, mode=mode, block_m=bm, **s),
+                (sds((ROWS, N, C), dtype), w, vec, w, vec), 1, scales)
+    return build
+
+
+def _fused_attn(dtype_name, geometry):
+    """One committed TUNED_BLOCKS attention row at its own geometry."""
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        n, c, h = (int(part[1:]) for part in geometry.split("_")[1:])
+        blocks = tuning.TUNED_BLOCKS[(KIND, dtype_name, geometry)]
+        w8a8 = dtype_name == "int8"
+        cdt = jnp.float32 if w8a8 else jnp.dtype(dtype_name)
+        vec3, vec = sds((3 * c,), jnp.float32), sds((c,), jnp.float32)
+        return (lambda x, wq, sq, bq, wp, sp, bp: fa.fused_trunk_attention(
+                    x, wq, sq, bq, wp, sp, bp, num_heads=h,
+                    scale=(c // h) ** -0.5, block_q=blocks[0],
+                    block_kv=blocks[1], mode="w8a8" if w8a8 else "pallas"),
+                (sds((ROWS, n, c), cdt), sds((c, 3 * c), jnp.int8), vec3, vec3,
+                 sds((c, c), jnp.int8), vec, vec), 1)
+    return build
+
+
+def _forward(**kw):
+    """The whole 200px/p4 forward in bf16: one kernel per flash layer, plus
+    four dequant matmuls per block under w8a16 — or, fused, one attention and
+    one Mlp kernel per block at the committed TUNED_BLOCKS rows."""
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        model = DiffusionViT(dtype=jnp.bfloat16, **kw, **P4)
+        calls = (P4["depth"] if kw.get("use_flash") else 0) + (
+            4 * P4["depth"] if kw.get("quant") else 0)
+        if kw.get("fused"):
+            calls = 2 * P4["depth"]
+        return (lambda p, x, t: model.apply({"params": p}, x, t),
+                (_params(model, sds), sds((ROWS, 200, 200, 3), jnp.float32),
+                 sds((ROWS,), jnp.int32)), calls)
+    return build
+
+
+def _dp_train_step(devices):
+    """One data-parallel train step over the four chips, with the flash
+    kernels: the trunk geometry of the 200px/p4 model at depth 1 and without
+    dropout (whose random bits are what makes the full step's compile take
+    minutes). Each device must launch its own kernels — a jit over the mesh
+    cannot partition them — and the gradients must be all-reduced."""
+    from ddim_cold_tpu.train.step import create_train_state, make_train_step
+
+    mesh = Mesh(np.asarray(devices), ("data",))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    model = DiffusionViT(dtype=jnp.bfloat16, use_flash=True, drop_rate=0.0,
+                         attn_drop_rate=0.0, drop_path_rate=0.0,
+                         **dict(P4, depth=1))
+    img = jnp.zeros((2, 200, 200, 3))
+    state = jax.eval_shape(lambda: create_train_state(
+        model, jax.random.PRNGKey(0), 1e-3, 100,
+        (img, img, jnp.zeros((2,), jnp.int32))))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), state)
+    batch = tuple(jax.ShapeDtypeStruct(s, d, sharding=rows) for s, d in (
+        ((8, 200, 200, 3), jnp.float32), ((8, 200, 200, 3), jnp.float32),
+        ((8,), jnp.int32)))
+    scalar = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=rep)  # noqa: E731
+    with ambient(mesh):
+        return make_train_step(model).lower(
+            state, batch, scalar((2,), jnp.uint32), scalar((), jnp.float32)
+        ).compile().as_text()
+
+
+CASES = {
+    **{f"flash_fwd-{np.dtype(dt).name}-{bq}x{bkv}": _flash_fwd(dt, (bq, bkv))
+       for dt in (jnp.float32, jnp.bfloat16)
+       for bq, bkv in ((256, 512), fa.NS_FLASH_BLOCKS)},
+    **{f"flash_grad-{np.dtype(dt).name}": _flash_grad(dt)
+       for dt in (jnp.float32, jnp.bfloat16)},
+    **{f"dequant_matmul-n{n_out}": _dequant(n_out) for n_out in (3 * C, C)},
+    **{f"mlp_pallas-{mode or 'float'}-{np.dtype(dt).name}": _mlp(mode, dt)
+       for mode, dt in ((None, jnp.float32), (None, jnp.bfloat16),
+                        ("pallas", jnp.bfloat16), ("w8a8", jnp.bfloat16))},
+    **{f"fused_trunk_attention-{geom}-{dt}": _fused_attn(dt, geom)
+       for (kind, dt, geom) in tuning.TUNED_BLOCKS
+       if kind == KIND and geom.startswith("attn_")},
+    "forward-dense": _forward(),
+    "forward-flash": _forward(use_flash=True),
+    "forward-flash-w8a16": _forward(use_flash=True, quant="pallas"),
+    "forward-fused-w8a16": _forward(use_flash=True, quant="pallas",
+                                    fused=True),
+}
+
+
+@pytest.mark.parametrize("case", [*CASES, "dp4_train_step"])
+def test_compiles_for_v5e(case, chip):
+    if case == "dp4_train_step":
+        text = _dp_train_step(chip)
+        assert "all-reduce" in text, "no gradient all-reduce in the dp step"
+        # forward, dq and dk/dv kernels of the one layer, on local rows
+        assert text.count("tpu_custom_call") == 3
+        return
+    fn, args, want_calls, *kwargs = CASES[case](chip)
+    text = jax.jit(fn).lower(*args, **(kwargs[0] if kwargs else {})
+                             ).compile().as_text()
+    assert text.count("tpu_custom_call") == want_calls

@@ -2,8 +2,10 @@
 trustworthy or every reported MFU is fiction."""
 
 import numpy as np
+import pytest
 
 from ddim_cold_tpu.models import MODEL_CONFIGS
+from ddim_cold_tpu.ops import tuning
 from ddim_cold_tpu.utils import flops
 
 
@@ -49,3 +51,20 @@ def test_mfu_math():
                       1e12 / (0.01 * 197e12))
     assert flops.mfu(1e12, 0.0, "TPU v5 lite") is None
     assert flops.mfu(1e12, 0.01, "unknown-chip") is None
+
+
+@pytest.mark.parametrize("lookup", [
+    lambda kind: flops.require_peak_tflops(kind),
+    lambda kind: tuning.attn_candidates(2501, 256, 4, "bfloat16",
+                                        device_kind=kind),
+    lambda kind: tuning.mlp_candidates(40016, 256, 256, 256, "bfloat16",
+                                       device_kind=kind),
+    lambda kind: tuning.dequant_candidates(40016, 256, 768, "bfloat16",
+                                           device_kind=kind),
+], ids=["peak", "attn_vmem", "mlp_vmem", "dequant_vmem"])
+def test_unknown_device_kind_raises_on_measuring_paths(lookup):
+    """A chip the tables do not know is an error where something is measured
+    or tuned for it — never a default budget or an MFU of None."""
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        lookup("TPU v9 imaginary")
+    lookup("TPU v5 lite")  # the chip there is: no error

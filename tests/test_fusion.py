@@ -219,32 +219,42 @@ def test_tuned_lookup_and_fallbacks():
     back to NS_FLASH_BLOCKS / the kernel default — never None."""
     from ddim_cold_tpu.ops.flash_attention import NS_FLASH_BLOCKS
 
+    row = lambda dt, geom: tuning.TUNED_BLOCKS[("TPU v5 lite", dt, geom)]  # noqa: E731
     got = tuning.attn_blocks(2501, 256, 4, jnp.float32,
                              device_kind="TPU v5 lite core 1")
-    assert got == (1328, 1288)  # prefix match on the committed entry
+    assert got == row("float32", "attn_n2501_c256_h4")  # prefix match
     assert tuning.attn_blocks(2501, 256, 4, jnp.float32,
                               device_kind="cpu") == NS_FLASH_BLOCKS
-    assert tuning.mlp_block_m(256, 256, jnp.bfloat16,
-                              device_kind="TPU v5 lite") == 4016
-    assert tuning.mlp_block_m(256, 256, jnp.bfloat16, quant=False,
-                              device_kind="TPU v5 lite") == 3952
+    assert (tuning.mlp_block_m(256, 256, jnp.bfloat16,
+                               device_kind="TPU v5 lite"),
+            ) == row("bfloat16", "mlp_c256_h256")
+    assert (tuning.mlp_block_m(256, 256, jnp.bfloat16, quant=False,
+                               device_kind="TPU v5 lite"),
+            ) == row("bfloat16", "mlpf_c256_h256")
     assert tuning.mlp_block_m(99, 99, jnp.float32,
                               device_kind="TPU v5 lite") == 256  # default
 
 
 def test_static_picks_reproduce_committed_table():
     """`python -m ddim_cold_tpu.ops.tuning` provenance: the static model
-    re-derives the committed 200px/p4 entries exactly."""
-    for dt_name, (bq, bkv) in (("float32", (1328, 1288)),
-                               ("bfloat16", (1552, 2512)),
-                               ("int8", (1536, 2528))):
+    re-derives every committed attention and Mlp row exactly (whether the
+    chip's compiler takes them is tests/test_chip_compile.py's question)."""
+    rows = 16  # the sampler's batch the Mlp rows were picked at
+    for (kind, dt_name, geom), blocks in tuning.TUNED_BLOCKS.items():
         dt = _DT[dt_name]
-        cdt = jnp.float32 if dt == jnp.int8 else dt
-        assert tuning.pick_attn(2501, 256, 4, dt,
-                                compute_dtype=cdt) == (bq, bkv), dt_name
-    assert tuning.pick_mlp(16 * 2501, 256, 256, 256, jnp.bfloat16) == 4016
-    assert tuning.pick_mlp(16 * 2501, 256, 256, 256, jnp.bfloat16,
-                           quant=False) == 3952
+        m = re.fullmatch(r"attn_n(\d+)_c(\d+)_h(\d+)", geom)
+        if m:
+            n, c, h = map(int, m.groups())
+            cdt = jnp.float32 if dt == jnp.int8 else dt
+            assert tuning.pick_attn(n, c, h, dt,
+                                    compute_dtype=cdt) == blocks, (dt_name, geom)
+        m = re.fullmatch(r"(mlpf?)_c(\d+)_h(\d+)", geom)
+        if m:
+            c, h = int(m.group(2)), int(m.group(3))
+            n = {256: 2501, 384: 626}[c]
+            assert (tuning.pick_mlp(rows * n, c, h, c, dt,
+                                    quant=m.group(1) == "mlp"),
+                    ) == blocks, (dt_name, geom)
 
 
 # ------------------------------------------------------------- w8a8 quality

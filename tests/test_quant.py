@@ -335,3 +335,25 @@ def test_zero_compiles_mixed_quant_streams(model_and_params, warmed_quant):
     plans = plan_batches([Request(config=cfg_f, n=2),
                           Request(config=cfg_q, n=2)], (4,))
     assert len(plans) == 2  # quant and float programs differ — no sharing
+
+
+# ---------------------------------------------------------------- exact GELU
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-6),
+                                        (jnp.bfloat16, 2e-2)])
+def test_gelu_exact_is_the_erf_gelu(dtype, atol):
+    """``gelu_exact`` spells erf from mul/add/div (the Pallas TPU lowering has
+    neither erf nor erfc); it must still BE the exact GELU — against math.erf
+    in float64, and within f32 round-off of ``jax.nn.gelu(approximate=False)``
+    — nowhere near the tanh approximation's 1e-3 — in the input's dtype."""
+    import math
+
+    x = np.linspace(-6.0, 6.0, 4801, dtype=np.float32)
+    want = np.array([0.5 * v * (1.0 + math.erf(v / math.sqrt(2.0)))
+                     for v in x.astype(np.float64)])
+    got = quant.gelu_exact(jnp.asarray(x, dtype))
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=atol)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(
+            got, jax.nn.gelu(jnp.asarray(x), approximate=False), atol=2e-6)

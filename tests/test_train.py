@@ -414,6 +414,94 @@ def test_steps_per_dispatch_composes_with_grad_accum_and_ema():
     assert int(multi_state.step) == int(seq_state.step) == 2
 
 
+def test_run_refuses_more_devices_than_visible(tmp_path, synthetic_image_dir):
+    """num_gpus above the visible device count is an error, like an explicit
+    mesh that does not fit — training on fewer devices than asked for is a
+    different run (global batch, lr), not a degraded one."""
+    import jax
+
+    from ddim_cold_tpu.train import trainer
+
+    n = len(jax.devices()) + 1
+    cfg = load_config(_write_config(str(tmp_path), synthetic_image_dir,
+                                    num_gpus=n), "exp")
+    with pytest.raises(ValueError, match=f"num_gpus {n} needs {n} devices, "
+                                         f"only {n - 1} visible"):
+        trainer.run(cfg, str(tmp_path))
+
+
+def test_flash_config_trains_without_attention_dropout(tmp_path,
+                                                        synthetic_image_dir):
+    """``use_flash`` is a promise that the kernel runs: build_model zeroes the
+    attention-dropout the kernel cannot apply, and a model that still carries
+    it refuses to train rather than quietly attending dense."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddim_cold_tpu.models import DiffusionViT
+    from ddim_cold_tpu.train.trainer import build_model
+
+    cfg = load_config(_write_config(str(tmp_path), synthetic_image_dir,
+                                    use_flash=True), "exp")
+    assert build_model(cfg).attn_drop_rate == 0.0
+    cfg = load_config(_write_config(str(tmp_path), synthetic_image_dir), "exp")
+    assert build_model(cfg).attn_drop_rate == 0.1  # dense keeps the reference's
+
+    model = DiffusionViT(img_size=(16, 16), patch_size=8, embed_dim=32,
+                         depth=1, num_heads=2, use_flash=True)
+    x, t = jnp.zeros((2, 16, 16, 3)), jnp.zeros((2,), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), x, t)["params"]
+    model.apply({"params": params}, x, t)  # inference: dropout inactive
+    with pytest.raises(ValueError, match="cannot apply attention-dropout"):
+        model.apply({"params": params}, x, t, deterministic=False,
+                    rngs={"dropout": jax.random.PRNGKey(1)})
+
+
+def test_flash_train_step_splits_over_a_data_mesh():
+    """Data-parallel training with the flash kernels: under the trainer's
+    ambient mesh every device launches the kernels on its own rows
+    (ops/flash_attention.per_device — a jit over a mesh cannot partition a
+    Mosaic kernel itself), and the step matches the one-device step."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddim_cold_tpu.models import DiffusionViT
+    from ddim_cold_tpu.parallel import (ambient, make_mesh, shard_batch,
+                                        shard_train_state)
+    from ddim_cold_tpu.train.step import create_train_state, make_train_step
+
+    model = DiffusionViT(img_size=(16, 16), patch_size=4, embed_dim=32,
+                         depth=1, num_heads=2, use_flash=True,
+                         attn_drop_rate=0.0, drop_rate=0.0, drop_path_rate=0.0)
+    rng = np.random.RandomState(0)
+    batch = (jnp.asarray(rng.randn(8, 16, 16, 3), jnp.float32),
+             jnp.asarray(rng.randn(8, 16, 16, 3), jnp.float32),
+             jnp.asarray(rng.randint(1, 7, size=(8,)), jnp.int32))
+
+    def one_step(mesh):
+        with ambient(mesh):
+            placed = batch if mesh is None else shard_batch(batch, mesh)
+            state = create_train_state(model, jax.random.PRNGKey(0), 1e-3, 10,
+                                       placed)
+            if mesh is not None:
+                state = shard_train_state(state, mesh)
+            step = make_train_step(model)
+            text = step.lower(state, placed, jax.random.PRNGKey(1),
+                              jnp.float32(5.0)).as_text()
+            state, loss, _ = step(state, placed, jax.random.PRNGKey(1),
+                                  jnp.float32(5.0))
+            return float(loss), jax.device_get(state.params), text
+
+    loss_1, params_1, _ = one_step(None)
+    loss_4, params_4, text = one_step(make_mesh({"data": 4},
+                                                devices=jax.devices()[:4]))
+    assert "shard_map" in text or "manual" in text.lower()
+    assert loss_4 == pytest.approx(loss_1, rel=1e-5)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4,
+                                                         atol=2e-6),
+                 params_4, params_1)
+
+
 def test_steps_per_dispatch_validation(tmp_path, synthetic_image_dir):
     with pytest.raises(ValueError, match="steps_per_dispatch"):
         load_config(_write_config(str(tmp_path), synthetic_image_dir,
