@@ -29,6 +29,7 @@ releases the GIL; this replaces the reference's 8 worker processes).
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from concurrent.futures import ThreadPoolExecutor
@@ -36,6 +37,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from ddim_cold_tpu.obs import spans
 from ddim_cold_tpu.utils import faults
 
 
@@ -140,9 +142,16 @@ class ShardedLoader:
 
     def __iter__(self) -> Iterator:
         batches = self._batches()
+        epoch = self.epoch
         if self.num_threads <= 1:
-            for b in batches:
-                yield self._make_batch(b)
+            # no pipeline, the same span: a reader finds this loader's
+            # decode work under one name, threaded or not
+            trace = spans.new_trace_id()
+            for i, b in enumerate(batches):
+                with spans.layer("data/decode/work", trace_id=trace, batch=i,
+                                 epoch=epoch):
+                    batch = self._make_batch(b)
+                yield batch
             return
 
         # one producer thread decodes batch-by-batch (items fan out over the
@@ -150,18 +159,36 @@ class ShardedLoader:
         # an abandoned iterator stops decoding within one batch.
         with ThreadPoolExecutor(self.num_threads) as pool:
             yield from _background_map(
-                batches, lambda b: self._make_batch(b, pool), self.prefetch)
+                batches, lambda b: self._make_batch(b, pool), self.prefetch,
+                "decode", epoch=epoch)
 
 
-def _background_map(items, fn, depth: int):
+def _background_map(items, fn, depth: int, stage: Optional[str] = None,
+                    waits: bool = False, **attrs):
     """Yield ``fn(item)`` with the mapping running ``depth`` items ahead in a
     producer thread (bounded queue). Exceptions from ``fn`` or the iterator
     surface at the consuming ``next()``; abandoning the generator (break/
     close) stops the producer within one item. Shared machinery for the
-    decode pipeline (ShardedLoader) and the H2D overlap (device_prefetch).
+    decode pipeline (ShardedLoader, ``stage`` "decode"), the H2D overlap
+    (device_prefetch, "place") and the engine's batch assembly (no stage:
+    it has its own ``engine/assemble`` span).
+
+    One call is one pipeline. With a ``stage`` it records layer spans that
+    share one trace id on both threads (that of the layer span open where
+    the consumer starts it, else a new one): ``data/<stage>/work`` around
+    ``fn(item)`` and, with ``waits``, ``data/<stage>/get_wait`` while the
+    consumer is blocked on the empty queue (none when the item was ready).
+    ``batch`` is the item's index in this pipeline; ``attrs`` ride on every
+    span.
     """
     q: queue.Queue = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
+    cur = spans.current()
+    trace = cur.trace_id if cur else spans.new_trace_id()
+
+    def span(part: str, i: int):
+        return spans.layer(f"data/{stage}/{part}", trace_id=trace, batch=i,
+                           **attrs)
 
     def put(item) -> bool:
         while not stop.is_set():
@@ -172,20 +199,36 @@ def _background_map(items, fn, depth: int):
                 continue
         return False
 
+    def work(it, i: int):
+        if stage is None:
+            return fn(it)
+        with span("work", i):
+            return fn(it)
+
     def producer():
         try:
-            for it in items:
-                if stop.is_set() or not put(fn(it)):
+            for i, it in enumerate(items):
+                if stop.is_set() or not put(work(it, i)):
                     return
             put(None)
         except BaseException as e:  # noqa: BLE001 — worker thread: ANY error (incl. KeyboardInterrupt) must surface to the consumer
             put(e)
 
+    def get(i: int):
+        if not waits:
+            return q.get()
+        try:
+            return q.get_nowait()
+        except queue.Empty:
+            pass
+        with span("get_wait", i):
+            return q.get()
+
     thread = threading.Thread(target=producer, daemon=True)
     thread.start()
     try:
-        while True:
-            item = q.get()
+        for i in itertools.count():
+            item = get(i)
             if item is None:
                 break
             if isinstance(item, BaseException):
@@ -222,12 +265,22 @@ def group_batches(batches, n: int):
             buf = []
 
 
-def device_prefetch(batches, place, depth: int = 2):
+def device_prefetch(batches, place, depth: int = 2,
+                    stage: Optional[str] = "place"):
     """Yield ``place(batch)`` for each host batch, with the placement (the
     host→device copy) running ``depth`` batches ahead in a background thread.
 
     On network-attached TPU hosts ``jax.device_put`` blocks on the upload RPC,
     so an unprefetched loop serializes transfer and compute; this overlaps
     them (the JAX client is thread-safe for placement).
+
+    Recorded as the data layer's ``stage`` (``data/place/work`` a batch, and
+    ``data/place/get_wait`` while the consuming loop waits for one), with the
+    loader's ``epoch`` where ``batches`` is one. A caller whose ``place`` is
+    not a placement (the engine's batch assembly) passes ``stage=None`` and
+    records its own spans.
     """
-    return _background_map(batches, place, depth)
+    epoch = getattr(batches, "epoch", None)
+    attrs = {} if epoch is None else {"epoch": epoch}
+    return _background_map(batches, place, depth, stage,
+                           waits=stage is not None, **attrs)

@@ -1,10 +1,12 @@
-"""Observability: per-request trace spans, the process metrics registry,
-and on-device step telemetry decoding.
+"""Observability: the program's span recorder, the process metrics
+registry, and on-device step telemetry decoding.
 
-* :mod:`ddim_cold_tpu.obs.spans` — trace contexts created at
-  ``Router.submit`` / ``Engine.submit``, propagated plan → assemble →
-  dispatch → fetch → preview → finish and across hedges/failovers;
-  exported as Chrome trace-event JSON (``scripts/obs_report.py``).
+* :mod:`ddim_cold_tpu.obs.spans` — the one span recorder, on
+  ``time.perf_counter_ns``: layer spans (loader stages, sampler calls,
+  engine batch stages, JAX compiles) always on in a bounded ring and
+  mirrored into a live profiler session; per-request ticket traces
+  (``Router.submit`` / ``Engine.submit`` → plan → assemble → dispatch →
+  fetch → preview → finish, across hedges/failovers) opt-in.
 * :mod:`ddim_cold_tpu.obs.metrics` — named counters/gauges/histograms the
   serving layers emit into; ``Engine.health()`` / ``Router.health()`` are
   rendered from it.

@@ -46,6 +46,9 @@ METRICS = (
     ("engine.max_queue_depth", "gauge", "high-water admission queue depth"),
     ("engine.preview_frames", "counter", "streamed x̂0 preview frames"),
     ("engine.latency_s", "hist", "per-ticket submit→deliver latency"),
+    ("engine.queue_wait_s", "hist", "per-ticket submit→plan wait"),
+    ("engine.assemble_compiles", "counter",
+     "XLA programs compiled by batch assembly's eager ops"),
     ("engine.param_bytes", "gauge", "resident float param bytes"),
     ("engine.param_bytes_quant", "gauge", "resident int8 param bytes"),
     ("engine.retries", "counter", "transient dispatch retries"),
@@ -95,6 +98,10 @@ METRICS = (
     ("autoscale.scale_ups", "counter", "target increments issued"),
     ("autoscale.scale_downs", "counter", "target decrements issued"),
     ("autoscale.target", "gauge", "router replica target after last tick"),
+    # -- runtime (utils/profiling.py's compile listener); the readers under
+    #    benchmark/layer_metrics/ check the span ring against it ------------
+    ("runtime.compiles", "counter",
+     "XLA programs compiled or loaded from the persistent cache"),
     # -- fault injection --------------------------------------------------
     ("faults.injected", "counter", "realized fault injections (key: site)"),
     # -- attribution / trend (obs.attrib / obs.trend, host-side) ----------

@@ -241,6 +241,7 @@ def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, interpret):
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
+            name="fwd",
         )(qh, kh, vh)
 
 
@@ -423,6 +424,7 @@ def _bwd_call(qh, kh, vh, gh, lse, delta, *, scale, n_valid, bq, bkv,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
+            name="dq",
         )(qh, kh, vh, gh, lse, delta)
 
     # transposed grid: (head, kv block, q chunk innermost)
@@ -444,6 +446,7 @@ def _bwd_call(qh, kh, vh, gh, lse, delta, *, scale, n_valid, bq, bkv,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
+            name="dkv",
         )(qh, kh, vh, gh, lse, delta)
     return dq, dk, dv
 

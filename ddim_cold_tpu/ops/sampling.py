@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ddim_cold_tpu.obs import spans
 from ddim_cold_tpu.obs.device import StepTelemetry
 from ddim_cold_tpu.ops import schedule, step_cache
 from ddim_cold_tpu.utils import profiling
@@ -48,6 +49,45 @@ def _on_mesh(sampler):
             return sampler(*args, mesh=mesh, **kwargs)
 
     return run
+
+
+def _host_call(sampler: str, scan_steps: int, **attrs):
+    """The host side of one public sampler call, as layer spans: one
+    ``sampler/call`` (``n``, set by ``_start`` once the start batch exists,
+    ``k`` or ``steps``, ``scan_steps``) whose children the caller opens —
+    ``sampler/init`` (draw or copy of ``x_init``, its placement, the cache
+    carry) and ``sampler/dispatch`` (the jitted scan's call until it
+    returns; the device runs on after it)."""
+    return spans.layer("sampler/call", sampler=sampler,
+                       scan_steps=scan_steps, **attrs)
+
+
+def _start(call, model, rng, x_init, mesh, draw, *, copy: bool,
+           noise: bool = True, cache_mode: Optional[str] = None):
+    """The ``sampler/init`` of one call: the start batch — ``draw()`` for a
+    fresh one, else the caller's (through a private copy when ``copy``: the
+    last-only scans DONATE x_init, no HBM double-buffer, and a
+    caller-provided start must survive the call; the mesh path already
+    copies via device_put and the sequence scans do not donate) — placed on
+    the mesh, the per-step noise key and, for ``cache_mode``, the cache
+    carry. Returns ``(x_init, noise_rng, cache)`` and sets the call's
+    ``n``."""
+    with spans.layer("sampler/init"):
+        if x_init is None:
+            x_init = draw()
+        elif copy:
+            x_init = jnp.array(x_init, copy=True)
+        x_init = _shard_init(x_init, mesh)
+        noise_rng = None
+        if noise:
+            # distinct fold: with a fresh start, rng already produced x_init
+            # — the per-step noise must not be correlated with it
+            noise_rng = (jax.random.fold_in(rng, 0xD1F) if rng is not None
+                         else jax.random.PRNGKey(0))
+        cache = (_make_cache(model, x_init, mesh, cache_mode)
+                 if cache_mode is not None else None)
+    call.set(n=x_init.shape[0])
+    return x_init, noise_rng, cache
 
 
 def forward_noise(rng: jax.Array, img: jax.Array, t_start: int, total_steps: int = 2000):
@@ -271,32 +311,32 @@ def ddim_sample_fewstep(
     """
     if eta and rng is None:
         raise ValueError("eta > 0 draws per-step noise — pass rng")
-    if x_init is None:
-        if rng is None:
-            raise ValueError("ddim_sample_fewstep needs either rng or x_init")
+    if x_init is None and rng is None:
+        raise ValueError("ddim_sample_fewstep needs either rng or x_init")
+    cached = step_cache.enabled(cache_interval)
+    with _host_call("ddim_fewstep", steps, steps=steps) as call:
         H, W = model.img_size
-        x_init = jax.random.normal(rng, (n, H, W, model.in_chans), jnp.float32)
-    elif mesh is None and not return_sequence:
-        # last-only scans donate x_init — guided starts enter via a private
-        # copy, exactly like ddim_sample's guided path
-        x_init = jnp.array(x_init, copy=True)
-    x_init = _shard_init(x_init, mesh)
-    noise_rng = (jax.random.fold_in(rng, 0xD1F) if rng is not None
-                 else jax.random.PRNGKey(0))
-    if step_cache.enabled(cache_interval):
-        fn = (_ddim_scan_fewstep_cached_seq if return_sequence
-              else _ddim_scan_fewstep_cached)
-        out, _ = fn(
-            model, params, x_init, noise_rng,
-            _make_cache(model, x_init, mesh, cache_mode),
-            steps=steps, t_start=t_start, eta=eta,
-            cache_interval=cache_interval, cache_mode=cache_mode,
-            cache_threshold=cache_threshold, cache_tokens=cache_tokens,
-            sequence=return_sequence)
-        return out
-    fn = _ddim_scan_fewstep_seq if return_sequence else _ddim_scan_fewstep
-    return fn(model, params, x_init, noise_rng, steps=steps, t_start=t_start,
-              eta=eta, sequence=return_sequence)
+        x_init, noise_rng, cache = _start(
+            call, model, rng, x_init, mesh,
+            lambda: jax.random.normal(rng, (n, H, W, model.in_chans),
+                                      jnp.float32),
+            copy=mesh is None and not return_sequence,
+            cache_mode=cache_mode if cached else None)
+        with spans.layer("sampler/dispatch"):
+            if cached:
+                fn = (_ddim_scan_fewstep_cached_seq if return_sequence
+                      else _ddim_scan_fewstep_cached)
+                out, _ = fn(
+                    model, params, x_init, noise_rng, cache,
+                    steps=steps, t_start=t_start, eta=eta,
+                    cache_interval=cache_interval, cache_mode=cache_mode,
+                    cache_threshold=cache_threshold,
+                    cache_tokens=cache_tokens, sequence=return_sequence)
+                return out
+            fn = (_ddim_scan_fewstep_seq if return_sequence
+                  else _ddim_scan_fewstep)
+            return fn(model, params, x_init, noise_rng, steps=steps,
+                      t_start=t_start, eta=eta, sequence=return_sequence)
 
 
 def _cached_spec(model, n_steps: int, cache_interval: int, cache_mode: str,
@@ -619,50 +659,50 @@ def ddim_sample(
     """
     if eta and rng is None:
         raise ValueError("eta > 0 draws per-step noise — pass rng")
-    if x_init is None:
-        if rng is None:
-            raise ValueError("ddim_sample needs either rng or x_init")
-        H, W = model.img_size
-        x_init = jax.random.normal(rng, (n, H, W, model.in_chans), jnp.float32)
-    elif mesh is None and not return_sequence:
-        # the last-only scans DONATE x_init (no HBM double-buffer); a
-        # caller-provided start must survive the call, so it enters through a
-        # private copy. The mesh path already copies via device_put, and the
-        # sequence scan does not donate.
-        x_init = jnp.array(x_init, copy=True)
-    x_init = _shard_init(x_init, mesh)
-    # distinct fold: with a fresh start, rng already produced x_init — the
-    # per-step noise must not be correlated with it
-    noise_rng = (jax.random.fold_in(rng, 0xD1F) if rng is not None
-                 else jax.random.PRNGKey(0))
+    if x_init is None and rng is None:
+        raise ValueError("ddim_sample needs either rng or x_init")
+    cached = step_cache.enabled(cache_interval)
     if telemetry:
         if return_sequence:
             raise ValueError("telemetry=True is last-only — previews and "
                              "telemetry are separate products")
-        if not step_cache.enabled(cache_interval):
+        if not cached:
             raise ValueError("telemetry=True needs the cached sampler "
                              "(cache_interval > 1)")
-        out, _, (br, drift) = _ddim_scan_cached_tel(
-            model, params, x_init, noise_rng,
-            _make_cache(model, x_init, mesh, cache_mode),
-            k=k, t_start=t_start, eta=eta, cache_interval=cache_interval,
-            cache_mode=cache_mode, cache_threshold=cache_threshold,
-            cache_tokens=cache_tokens)
-        return out, StepTelemetry(branch=br, drift=drift)
-    if step_cache.enabled(cache_interval):
-        fn = _ddim_scan_cached_seq if return_sequence else _ddim_scan_cached
-        out, _ = fn(
-            model, params, x_init, noise_rng,
-            _make_cache(model, x_init, mesh, cache_mode),
-            k=k, t_start=t_start, eta=eta, cache_interval=cache_interval,
-            cache_mode=cache_mode, cache_threshold=cache_threshold,
-            cache_tokens=cache_tokens, sequence=return_sequence)
-        return out
-    if return_sequence:
-        return _ddim_scan_sequence(model, params, x_init, noise_rng,
+    scan_steps = len(schedule.ddim_time_sequence(model.total_steps, k,
+                                                 t_start))
+    with _host_call("ddim", scan_steps, k=k) as call:
+        H, W = model.img_size
+        x_init, noise_rng, cache = _start(
+            call, model, rng, x_init, mesh,
+            lambda: jax.random.normal(rng, (n, H, W, model.in_chans),
+                                      jnp.float32),
+            copy=mesh is None and not return_sequence,
+            cache_mode=cache_mode if cached else None)
+        with spans.layer("sampler/dispatch"):
+            if telemetry:
+                out, _, (br, drift) = _ddim_scan_cached_tel(
+                    model, params, x_init, noise_rng, cache,
+                    k=k, t_start=t_start, eta=eta,
+                    cache_interval=cache_interval, cache_mode=cache_mode,
+                    cache_threshold=cache_threshold,
+                    cache_tokens=cache_tokens)
+                return out, StepTelemetry(branch=br, drift=drift)
+            if cached:
+                fn = (_ddim_scan_cached_seq if return_sequence
+                      else _ddim_scan_cached)
+                out, _ = fn(
+                    model, params, x_init, noise_rng, cache,
+                    k=k, t_start=t_start, eta=eta,
+                    cache_interval=cache_interval, cache_mode=cache_mode,
+                    cache_threshold=cache_threshold,
+                    cache_tokens=cache_tokens, sequence=return_sequence)
+                return out
+            if return_sequence:
+                return _ddim_scan_sequence(model, params, x_init, noise_rng,
+                                           k=k, t_start=t_start, eta=eta)
+            return _ddim_scan_last(model, params, x_init, noise_rng,
                                    k=k, t_start=t_start, eta=eta)
-    return _ddim_scan_last(model, params, x_init, noise_rng,
-                           k=k, t_start=t_start, eta=eta)
 
 
 def sample_from(model, params, x_init: jax.Array, t_start: int, k: int = 10,
@@ -863,26 +903,31 @@ def cold_sample(
     ``cache_interval`` > 1 enables the feature-cached scan (see
     ``ddim_sample``); 1 is bit-for-bit the plain sampler.
     """
-    H, W = model.img_size
-    if x_init is None:
-        if rng is None:
-            raise ValueError("cold_sample needs either rng or x_init")
-        color = jax.random.normal(rng, (n, 1, 1, model.in_chans), jnp.float32)
-        x_init = jnp.broadcast_to(color, (n, H, W, model.in_chans))
-    elif mesh is None and not return_sequence:
-        # the last-only cold scans DONATE x_init — a caller-provided start
-        # must survive the call (same private copy as ddim_sample's guided
-        # path; the mesh path copies via device_put, sequence never donates).
-        x_init = jnp.array(x_init, copy=True)
-    x_init = _shard_init(x_init, mesh)
-    if step_cache.enabled(cache_interval):
-        fn = _cold_scan_cached_seq if return_sequence else _cold_scan_cached
-        out, _ = fn(
-            model, params, x_init, _make_cache(model, x_init, mesh, cache_mode),
-            levels=levels, return_sequence=return_sequence,
-            cache_interval=cache_interval, cache_mode=cache_mode,
-            cache_threshold=cache_threshold, cache_tokens=cache_tokens)
-        return out
-    fn = _cold_scan_seq if return_sequence else _cold_scan
-    return fn(model, params, x_init, levels=levels,
-              return_sequence=return_sequence)
+    if x_init is None and rng is None:
+        raise ValueError("cold_sample needs either rng or x_init")
+    cached = step_cache.enabled(cache_interval)
+    with _host_call("cold", levels, steps=levels) as call:
+        H, W = model.img_size
+
+        def color():  # one N(0,1) RGB colour a sample, over the image
+            c = jax.random.normal(rng, (n, 1, 1, model.in_chans), jnp.float32)
+            return jnp.broadcast_to(c, (n, H, W, model.in_chans))
+
+        x_init, _, cache = _start(
+            call, model, rng, x_init, mesh, color,
+            copy=mesh is None and not return_sequence, noise=False,
+            cache_mode=cache_mode if cached else None)
+        with spans.layer("sampler/dispatch"):
+            if cached:
+                fn = (_cold_scan_cached_seq if return_sequence
+                      else _cold_scan_cached)
+                out, _ = fn(
+                    model, params, x_init, cache,
+                    levels=levels, return_sequence=return_sequence,
+                    cache_interval=cache_interval, cache_mode=cache_mode,
+                    cache_threshold=cache_threshold,
+                    cache_tokens=cache_tokens)
+                return out
+            fn = _cold_scan_seq if return_sequence else _cold_scan
+            return fn(model, params, x_init, levels=levels,
+                      return_sequence=return_sequence)

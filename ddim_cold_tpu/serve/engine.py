@@ -110,7 +110,7 @@ from ddim_cold_tpu.utils import faults
 from ddim_cold_tpu.utils.platform import watchdog_stall_s
 from ddim_cold_tpu.workloads import preview as workload_preview
 from ddim_cold_tpu.workloads import tasks as workload_tasks
-from ddim_cold_tpu.utils.profiling import latency_summary
+from ddim_cold_tpu.utils.profiling import compile_count, latency_summary
 from ddim_cold_tpu.utils.watchdog import StallWatchdog
 
 #: per-task batch inputs that ride along with x through assembly — sliced
@@ -255,6 +255,8 @@ class Engine:
             "max_queue_depth": int(m.raw("engine.max_queue_depth") or 0),
             "preview_frames": m.value("engine.preview_frames"),
             "latencies_s": m.samples("engine.latency_s"),
+            "queue_waits_s": m.samples("engine.queue_wait_s"),
+            "assemble_compiles": m.value("engine.assemble_compiles"),
             "param_bytes": m.raw("engine.param_bytes"),
             "param_bytes_quant": m.raw("engine.param_bytes_quant"),
             "retries": m.value("engine.retries"),
@@ -736,8 +738,6 @@ class Engine:
         reduction is exactly what the direct unpadded call computes — the
         bitwise-vs-direct contract survives padding."""
         self._mark(f"assemble bucket={plan.bucket}")
-        t0 = spans.now() if spans.enabled() else 0.0
-        faults.fire("serve.assemble", tag=self._tag(plan))
         coupled = plan.config.batch_coupled
 
         def _pad(real_parts):
@@ -748,39 +748,57 @@ class Engine:
             return jnp.zeros((plan.padded_rows,) + first.shape[1:],
                              jnp.float32)
 
-        parts = [self._request_init(req)[lo:hi]
-                 for req, lo, hi, _ in plan.entries]
-        if plan.padded_rows:
-            parts.append(_pad(parts))
-        x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-        sharding = self._sharding_for(plan.config)
-        if sharding is not None:
-            x = jax.device_put(x, sharding)
-        xs = [x]
-        for name in _EXTRA_INPUTS.get(plan.config.task, ()):
-            cols = [jnp.asarray(req.extras[name][lo:hi], jnp.float32)
-                    for req, lo, hi, _ in plan.entries]
+        # the eager slices, pads and concatenates below compile one small
+        # XLA program for every new tuple of part shapes: counted, on this
+        # (the assembling) thread
+        compiles0 = compile_count()
+        with self._stage("assemble", plan) as stage:
+            faults.fire("serve.assemble", tag=self._tag(plan))
+            parts = [self._request_init(req)[lo:hi]
+                     for req, lo, hi, _ in plan.entries]
             if plan.padded_rows:
-                cols.append(_pad(cols))
-            e = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=0)
+                parts.append(_pad(parts))
+            x = (parts[0] if len(parts) == 1
+                 else jnp.concatenate(parts, axis=0))
+            sharding = self._sharding_for(plan.config)
             if sharding is not None:
-                e = jax.device_put(e, sharding)
-            xs.append(e)
-        self._record_stage(plan, "assemble", t0)
+                x = jax.device_put(x, sharding)
+            xs = [x]
+            for name in _EXTRA_INPUTS.get(plan.config.task, ()):
+                cols = [jnp.asarray(req.extras[name][lo:hi], jnp.float32)
+                        for req, lo, hi, _ in plan.entries]
+                if plan.padded_rows:
+                    cols.append(_pad(cols))
+                e = (cols[0] if len(cols) == 1
+                     else jnp.concatenate(cols, axis=0))
+                if sharding is not None:
+                    e = jax.device_put(e, sharding)
+                xs.append(e)
+        self.metrics.inc("engine.assemble_compiles",
+                         compile_count() - compiles0)
+        self._record_stage(plan, stage)
         return plan, tuple(xs)
 
-    def _record_stage(self, plan: BatchPlan, name: str, t0: float,
-                      **attrs) -> None:
+    @staticmethod
+    def _stage(name: str, plan: BatchPlan):
+        """One live layer span a batch and pipeline stage
+        (``engine/<name>``): always recorded, and the one the profiler's
+        timeline shows."""
+        return spans.layer("engine/" + name, bucket=plan.bucket)
+
+    @staticmethod
+    def _record_stage(plan: BatchPlan, stage, **attrs) -> None:
         """Attribute one per-batch pipeline stage to every request riding
-        the batch: a retroactive closed span (same measured window) under
-        each request's trace — so a split request's trace shows the stage
-        once per batch it rode, and a coalesced batch's window appears under
-        every participant. No-op with tracing disabled."""
+        the batch: a retroactive closed copy of the batch's ``stage`` span
+        (same measured window) under each request's trace — so a split
+        request's trace shows the stage once per batch it rode, and a
+        coalesced batch's window appears under every participant. No-op
+        with ticket traces disabled."""
         if not spans.enabled():
             return
-        t1 = spans.now()
+        name = stage.name.rpartition("/")[2]
         for req in {id(r): r for r, *_ in plan.entries}.values():
-            spans.record(req.ticket.span, name, t0, t1,
+            spans.record(req.ticket.span, name, stage.t0, stage.t1,
                          bucket=plan.bucket, **attrs)
 
     def _assemble_safe(self, plan: BatchPlan):
@@ -844,8 +862,18 @@ class Engine:
         prog = self.ensure_program(plan.config, plan.bucket)
         params = self._params_for(plan.config)
         self._mark(f"dispatch bucket={plan.bucket}")
-        t0 = spans.now() if spans.enabled() else 0.0
-        faults.fire("serve.dispatch", tag=self._tag(plan))
+        with self._stage("dispatch", plan) as stage:
+            faults.fire("serve.dispatch", tag=self._tag(plan))
+            out = self._call_program(plan, prog, params, xs)
+        self.metrics.inc("engine.dispatches")
+        self.metrics.inc("engine.rows", plan.rows)
+        self.metrics.inc("engine.padded_rows", plan.padded_rows)
+        self._record_stage(plan, stage)
+        return out
+
+    def _call_program(self, plan: BatchPlan, prog, params, xs):
+        """The batch's program with the arguments its task takes; cached
+        configs take a spare cache carry and hand back the one returned."""
         if plan.config.task == "inpaint":
             x, known, m = xs
             if plan.config.cached:
@@ -878,10 +906,6 @@ class Engine:
         else:
             x, = xs
             out = prog(params, x, self._key0)
-        self.metrics.inc("engine.dispatches")
-        self.metrics.inc("engine.rows", plan.rows)
-        self.metrics.inc("engine.padded_rows", plan.padded_rows)
-        self._record_stage(plan, "dispatch", t0)
         return out
 
     def _dispatch_retry(self, plan: BatchPlan, xs):
@@ -992,18 +1016,18 @@ class Engine:
         the static modes' aux is identical for every batchmate anyway."""
         try:
             self._mark(f"fetch bucket={plan.bucket}")
-            t0 = spans.now() if spans.enabled() else 0.0
-            aux = None
-            if plan.config.telemetry:
-                out, (br, dr) = out
-                aux = (np.asarray(br), np.asarray(dr))
-            host = np.asarray(out)
-            host = faults.fire("serve.fetch", tag=self._tag(plan),
-                               payload=host)
+            with self._stage("fetch", plan) as stage:
+                aux = None
+                if plan.config.telemetry:
+                    out, (br, dr) = out
+                    aux = (np.asarray(br), np.asarray(dr))
+                host = np.asarray(out)
+                host = faults.fire("serve.fetch", tag=self._tag(plan),
+                                   payload=host)
         except Exception as exc:  # noqa: BLE001 — isolated per batch
             self._fail_plan(plan, exc, "fetch")
             return
-        self._record_stage(plan, "fetch", t0)
+        self._record_stage(plan, stage)
         if aux is not None:
             cfg = plan.config
             summary = obs_device.summarize(
@@ -1019,19 +1043,20 @@ class Engine:
         every = plan.config.preview_every
         if every:
             try:
-                t0 = spans.now() if spans.enabled() else 0.0
-                faults.fire("serve.preview", tag=self._tag(plan))
-                steps = host.shape[0] - 1  # frame 0 is the init
-                for j in workload_preview.preview_indices(steps, every):
-                    frame = host[j]
-                    for req, lo, hi, offset in plan.entries:
-                        if req.ticket._preview(
-                                j, lo, hi, frame[offset:offset + (hi - lo)]):
-                            self.metrics.inc("engine.preview_frames")
+                with self._stage("preview", plan) as stage:
+                    faults.fire("serve.preview", tag=self._tag(plan))
+                    steps = host.shape[0] - 1  # frame 0 is the init
+                    for j in workload_preview.preview_indices(steps, every):
+                        frame = host[j]
+                        for req, lo, hi, offset in plan.entries:
+                            if req.ticket._preview(
+                                    j, lo, hi,
+                                    frame[offset:offset + (hi - lo)]):
+                                self.metrics.inc("engine.preview_frames")
             except Exception as exc:  # noqa: BLE001 — isolated per batch
                 self._fail_plan(plan, exc, "preview")
                 return
-            self._record_stage(plan, "preview", t0)
+            self._record_stage(plan, stage)
             host = host[-1]
         for req, lo, hi, offset in plan.entries:
             if req.ticket._deliver(lo, hi, host[offset:offset + (hi - lo)]):
@@ -1139,6 +1164,7 @@ class Engine:
         now = time.monotonic()
         s = self.stats
         lat = latency_summary(s["latencies_s"])
+        wait = latency_summary(s["queue_waits_s"])
         return {
             "replica": self.replica_id,
             "queue_depth": depth,
@@ -1148,6 +1174,11 @@ class Engine:
             "latency_p50_s": lat["p50_s"],
             "latency_p95_s": lat["p95_s"],
             "latency_p99_s": lat["p99_s"],
+            # submit→plan wait, and the eager assembly ops' compiles: the
+            # two host costs PR 23 found bounding the served rate
+            "queue_wait_p50_s": wait["p50_s"],
+            "queue_wait_p95_s": wait["p95_s"],
+            "assemble_compiles": s["assemble_compiles"],
             "max_queue": self.max_queue,
             "uptime_s": now - self._t0,
             "last_progress_s": now - mark_t,
@@ -1209,17 +1240,22 @@ class Engine:
                 if not live:
                     continue
                 self._mark(f"plan {len(live)} requests")
-                tp = spans.now() if spans.enabled() else 0.0
-                plans = plan_batches(live, self.buckets)
-                if spans.enabled():
-                    tp1 = spans.now()
-                    for req in live:
-                        spans.record(req.ticket.span, "plan", tp, tp1,
-                                     batches=len(plans))
+                with spans.layer("engine/plan", requests=len(live)) as stage:
+                    plans = plan_batches(live, self.buckets)
+                    stage.set(batches=len(plans))
+                for req in live:
+                    # submit → plan: the wait the queue added
+                    submit = int(req.ticket.submit_time * 1e9)
+                    self.metrics.observe("engine.queue_wait_s",
+                                         (stage.t0 - submit) / 1e9)
+                    spans.record(req.ticket.span, "queue_wait",
+                                 submit, stage.t0)
+                    spans.record(req.ticket.span, "plan", stage.t0, stage.t1,
+                                 batches=len(plans))
                 inflight: deque = deque()
                 for plan, xs, err in device_prefetch(
                         plans, self._assemble_safe,
-                        depth=self.prefetch_depth):
+                        depth=self.prefetch_depth, stage=None):
                     if self._stalled:
                         break
                     if err is not None:

@@ -25,6 +25,7 @@ import optax
 from flax.training import train_state
 
 from ddim_cold_tpu.ops.losses import smooth_l1
+from ddim_cold_tpu.utils import profiling  # noqa: F401 — its compile listener, before the first compile
 
 
 class EmaTrainState(train_state.TrainState):

@@ -6,7 +6,13 @@ ViT.py:222-235). Here the equivalents are structural:
 
 * ``trace(dir)`` — a ``jax.profiler`` trace context; view in TensorBoard or
   Perfetto. Wrap any train/sample region.
-* ``annotate(name)`` — named TraceAnnotation so steps show up labeled.
+* the mirror — while a profiler session is live, every layer span
+  ``obs/spans.py`` opens is also written into it as a ``ddim/<name>`` TraceAnnotation, on the
+  host plane of the same ``.xplane.pb`` as the device's ops.
+* the compile listener — every ``jax.monitoring`` compile and cache duration
+  event becomes a closed ``jax/<event>`` span under the span open on its
+  thread, and a backend compile (or cache load) counts into
+  ``runtime.compiles``.
 * ``enable_nan_checks()`` — ``jax_debug_nans`` (the SPMD replacement for the
   reference's commented TORCH_DISTRIBUTED_DEBUG, with actually-useful
   semantics: fail at the op that produced the NaN).
@@ -14,8 +20,13 @@ ViT.py:222-235). Here the equivalents are structural:
 
 from __future__ import annotations
 
+import threading
+import time
+
 import jax
 import numpy as np
+
+from ddim_cold_tpu.obs import metrics, spans
 
 
 def trace(log_dir: str, perfetto: bool = False):
@@ -45,9 +56,67 @@ def stop_trace() -> None:
     jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region inside a trace (shows up on the TPU timeline)."""
-    return jax.profiler.TraceAnnotation(name)
+def _mirror(name: str):
+    """The span recorder's sink: with a profiler session live, an entered
+    ``ddim/<name>`` annotation (the recorder exits it when the span ends);
+    with none, one ``is_enabled()`` read and nothing else."""
+    if not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    ann = jax.profiler.TraceAnnotation("ddim/" + name)
+    ann.__enter__()
+    return ann
+
+
+#: the duration events of one XLA program's way from Python to the device:
+#: trace, lower, then compile OR load from the persistent cache (that last
+#: one holds ``cache_retrieval_time_sec`` when it was a load)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+#: not a stretch of time but what a cache hit spared: recorded with no
+#: length, the seconds as an attribute
+_TIME_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_EVENT_ROOTS = ("/jax/core/compile/", "/jax/compilation_cache/")
+
+_runtime = metrics.scope("runtime")
+_compiles = threading.local()  # .n: backend compiles seen on this thread
+
+
+def _on_duration(event: str, seconds: float, **kwargs) -> None:
+    if not event.startswith(_EVENT_ROOTS):
+        return
+    attrs = {"event": event}
+    if "fun_name" in kwargs:
+        attrs["fun"] = kwargs["fun_name"]
+    if event == _TIME_SAVED:
+        attrs["saved_s"], seconds = seconds, 0.0
+    spans.event("jax/" + event.rsplit("/", 1)[-1], time.perf_counter_ns(),
+                int(seconds * 1e9), **attrs)
+    if event == _BACKEND_COMPILE:
+        _compiles.n = compile_count() + 1
+        _runtime.inc("runtime.compiles")
+
+
+def compile_count() -> int:
+    """XLA programs compiled (or loaded from the persistent cache) on the
+    calling thread so far: difference it around a block to count the block's
+    own compiles."""
+    return getattr(_compiles, "n", 0)
+
+
+def _install() -> None:
+    """Once a process: ``jax.monitoring`` keeps every listener it is given,
+    and a reload of this module (same globals) must not add a second."""
+    global _installed
+    if _installed:
+        return
+    import jax.monitoring as mon
+
+    mon.register_event_duration_secs_listener(_on_duration)
+    spans.set_sink(_mirror)
+    _installed = True
+
+
+_installed = globals().get("_installed", False)
+_install()
 
 
 def scope(name: str):

@@ -228,3 +228,36 @@ def test_compiles_for_v5e(case, chip):
     text = jax.jit(fn).lower(*args, **(kwargs[0] if kwargs else {})
                              ).compile().as_text()
     assert text.count("tpu_custom_call") == want_calls
+
+
+# --- the kernels' instruction names: what the benchmark's readers match -----
+
+def _forward_depth1(devices):
+    """The sampler's side: the 200px/p4 forward with the flash kernel, at
+    depth 1 (the name does not depend on the depth)."""
+    fn, args, _ = _forward(use_flash=True)(devices)
+    model = DiffusionViT(dtype=jnp.bfloat16, use_flash=True,
+                         **dict(P4, depth=1))
+    sds = _struct(SingleDeviceSharding(devices[0]))
+    return jax.jit(lambda p, x, t: model.apply({"params": p}, x, t)).lower(
+        _params(model, sds), *args[1:]).compile().as_text()
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("forward", {"fwd"}),
+    ("dp4_train_step", {"fwd", "dq", "dkv"}),
+])
+def test_flash_kernels_keep_their_instruction_names(program, kernels, chip):
+    """``benchmark/layer_metrics/flash_fwd_roofline.py`` finds the forward
+    kernel as the ``tpu_custom_call`` instruction ``%fwd`` and the breakdown
+    lists ``fwd``, ``dq``, ``dkv``: the names come from
+    ``pallas_call(name=...)`` and must stay, with or without XLA's numeric
+    suffix, whatever scope the kernels are launched under."""
+    import re
+
+    text = (_forward_depth1(chip) if program == "forward"
+            else _dp_train_step(chip))
+    names = {m.group(1) for m in re.finditer(
+        r"%(\w+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+    assert names == kernels
