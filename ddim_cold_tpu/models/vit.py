@@ -186,9 +186,10 @@ class Attention(nn.Module):
     # False = dense einsum; True = Pallas fused kernel; "xla" = pure-XLA
     # blockwise online-softmax (no kernel to reject, bounded memory)
     use_flash: "bool | str" = False
-    # Pallas kernel block sizes (block_q, block_kv); None = the kernel's
-    # defaults. A tuning knob for long-sequence configs — e.g. block_kv >= N
-    # makes K/V fully VMEM-resident (single-chunk, no online-softmax loop).
+    # Pallas kernel block sizes (block_q, block_kv); None = the kernel
+    # chooses from the shape (a head's K and V as one VMEM-resident chunk
+    # where that fits, else streamed: ops/flash_attention._fwd_blocks). An
+    # override for experiments — block_kv >= N asks for the resident form.
     # Applies to the plain flash path and ulysses' local flash attention;
     # ring sp has its own per-device chunking and ignores it.
     flash_blocks: Optional[tuple] = None

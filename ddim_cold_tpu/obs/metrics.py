@@ -102,6 +102,9 @@ METRICS = (
     #    benchmark/layer_metrics/ check the span ring against it ------------
     ("runtime.compiles", "counter",
      "XLA programs compiled or loaded from the persistent cache"),
+    # -- kernels (ops/flash_attention.py, counted once a trace) -----------
+    ("kernels.flash_fwd_schedule", "counter",
+     "flash forward traces by schedule (key: resident|streamed)"),
     # -- fault injection --------------------------------------------------
     ("faults.injected", "counter", "realized fault injections (key: site)"),
     # -- attribution / trend (obs.attrib / obs.trend, host-side) ----------

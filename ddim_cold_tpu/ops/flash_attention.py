@@ -3,27 +3,46 @@
 The reference's attention is three separate cuDNN GEMMs with an O(N²) f32
 attention matrix materialized in HBM (ViT.py:110-114). Here the whole
 ``softmax(q·kᵀ·scale)·v`` is one Pallas kernel: a grid over (batch·heads,
-query blocks, K/V blocks) where each program streams one K/V chunk through
-VMEM and folds it into a running (max, denominator, accumulator) triple —
-the classic flash-attention online softmax. VMEM usage is bounded by the
-block sizes, not the sequence length, so the kernel scales past the in-repo
-worst case (N=2501, the 200px/p4 model) to genuinely long sequences; the
-logits never round-trip to HBM and the MXU sees two GEMMs per chunk.
+query blocks, K/V blocks) where each program folds one K/V chunk into a
+running (max, denominator, accumulator) triple — the classic flash-attention
+online softmax; the logits never round-trip to HBM and the MXU sees two GEMMs
+per chunk. The K/V grid axis is innermost: TPU grids execute sequentially, so
+the VMEM scratch accumulators carry across the chunks of one (head, q-block)
+and are re-initialized when the chunk index wraps to 0.
 
-The K/V grid axis is innermost: TPU grids execute sequentially, so the VMEM
-scratch accumulators carry across the chunks of one (head, q-block) and are
-re-initialized when the chunk index wraps to 0.
+The forward picks its blocks from what it can see — padded sequence length,
+padded head size, dtype (:func:`_fwd_blocks`); no model name, no flag:
 
-Autodiff: the custom VJP is flash all the way through. The forward kernel
-additionally emits the per-row log-sum-exp; the backward runs two more Pallas
-kernels — dq (grid like the forward) and dk/dv (grid transposed: K/V blocks
-outer, q chunks streamed innermost) — that rebuild probabilities from the
-saved lse chunk by chunk, so the O(N²) matrix never exists in HBM in either
-direction. Residuals are (q, k, v, o, lse): O(N·D) — the whole train-step
-memory story for long sequences is bounded. (In-kernel, lse rides a
-128-lane-replicated layout because TPU tiling rejects (1, bq) row blocks;
-the replication is sliced off / re-broadcast outside the kernels so the
-residual itself stays one lane. See _fwd_kernel._emit.)
+* **K/V resident** — wherever one head's K and V and a (block_q, N) f32 score
+  tile fit the scoped VMEM (:func:`_fwd_vmem_bytes`; the 200px/p4 trunk's
+  2,501 tokens do, at block_q 512; in bf16 the rule holds to about 10,000
+  tokens at block_q 128) the whole padded sequence is ONE chunk. The K/V
+  block index then does not change across a head's q blocks, so each head's
+  K and V are fetched once, and a launch is batch·heads × query blocks
+  programs. On the v5e at 1,152 heads × 2,501 tokens × head size 64 in bf16
+  (one launch of the ``flower200_sample_k20`` cell) that is 5,760 programs
+  against the 57,600 of the (256, 512) it replaced as the default
+  (PERF.md section 6, PR 25, has each part's time on the chip).
+* **streamed** — explicit blocks with more than one K/V chunk, and every
+  sequence too long for the above, at (256, 512). VMEM is bounded by the
+  block sizes, not the sequence length.
+
+A power-of-two ``scale`` (head size 64) is folded into q, (block_q, D)
+multiplies in place of (block_q, block_kv), bit for bit the same scores.
+
+Autodiff: the custom VJP is flash all the way through. The VJP's forward
+additionally emits the per-row log-sum-exp (the undifferentiated call, which
+is all a sampler makes, launches the kernel without that result and saves
+its 1.5 GB write a launch at the sampler cell's shape); the backward runs
+two more Pallas kernels — dq (grid like the forward) and dk/dv (grid
+transposed: K/V blocks outer, q chunks streamed innermost) — that rebuild
+probabilities from the saved lse chunk by chunk, so the O(N²) matrix never
+exists in HBM in either direction. Residuals are (q, k, v, o, lse): O(N·D) —
+the whole train-step memory story for long sequences is bounded. (In-kernel,
+lse rides a 128-lane-replicated layout because TPU tiling rejects (1, bq) row
+blocks; the replication is sliced off / re-broadcast outside the kernels so
+the residual itself stays one lane. See _fwd_kernel._emit.) The backward
+kernels always stream, at (256, 512) unless blocks are given.
 
 On the CPU backend the kernels run in interpreter mode, so tests exercise
 the identical code paths; any other non-TPU backend is an error — a caller
@@ -33,6 +52,7 @@ that asked for the kernel never gets a different computation in its place.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +60,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import tiling
 from ddim_cold_tpu.utils import flops, profiling
 
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: last dim of VMEM tiles
+
+#: which forward schedule each trace engaged (``kernels.flash_fwd_schedule``)
+_kernels = metrics.scope("kernels")
 
 #: kernel revision stamped into bench records: "bf16-gemm-v2" = GEMMs in
 #: input dtype with f32 MXU accumulation; "fused-trunk-v3" adds the
@@ -53,13 +77,16 @@ _LANE = 128  # TPU lane width: last dim of VMEM tiles
 #: unfused kernels are untouched by v3: their numerics are bit-identical to v2.
 KERNEL_REV = "fused-trunk-v3"
 
-#: tuned (block_q, block_kv) for the N=2501 north-star flash leg: the r05
-#: on-chip sweep put full-sequence kv blocks ahead of streamed ones (512×4096:
-#: 7.48 img/s vs 5.78 at the 256×512 default, old f32-GEMM kernel). The
-#: kernel clamps block_kv to the padded sequence (2504 here) at runtime, so
-#: any ≥N entry is the same single-chunk config. Lives here (not bench.py)
-#: so the graftcheck kernels layer and the CPU tile-rule guard verify the
-#: EXACT geometry the bench dispatches — bench re-exports both names.
+#: (block_q, block_kv) the FUSED trunk path falls back to at 2,501 tokens
+#: (``tuning.attn_blocks``) and ``bench.py``'s sweep quotes; any block_kv ≥ N
+#: is clamped to the padded sequence, i.e. one chunk. It was the best row of
+#: the r05 on-chip sweep under the old f32-GEMM kernel (7.48 img/s against
+#: 5.78 at 256×512: a record of that kernel, not of this one). The unfused
+#: ``flash_attention`` no longer needs it: left to itself it now picks the
+#: same geometry from the shape (512 × whole sequence at N=2501: PERF.md
+#: section 6, PR 25). Lives here (not bench.py) so the graftcheck kernels
+#: layer and the CPU tile-rule guard verify the EXACT geometry the bench
+#: dispatches — bench re-exports both names.
 NS_FLASH_BLOCKS = (512, 4096)
 
 #: bench --flash-block-sweep configs for the 200px north-star kernel tuning;
@@ -73,10 +100,21 @@ FLASH_BLOCK_SWEEP = ((512, 512), (256, 1024), (256, 4096), (512, 4096))
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                scale: float, n_valid: int, block_kv: int, n_kv: int):
+def _scale_folds_into_q(scale: float) -> bool:
+    """A power-of-two ``scale`` (head size 64: 2⁻³) multiplies q exactly in
+    any float dtype, and every product and partial sum of q·kᵀ with it: the
+    (bq, D) multiply then gives bit for bit what scaling the (bq, bkv) f32
+    scores gives. Any other scale would round in q's dtype: scores scaled."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, n_valid: int,
+                block_kv: int, n_kv: int):
     """One (head, q-block, kv-block) program: fold this K/V chunk into the
-    running softmax state; emit o = acc/l and lse = m + log l on the last."""
+    running softmax state; emit o = acc/l and (where the launch has that
+    result: ``rest`` is then lse, acc, m, l) lse = m + log l on the last."""
+    lse_ref = rest[0] if len(rest) == 4 else None
+    acc_ref, m_ref, l_ref = rest[-3:]
     kv_i = pl.program_id(2)
 
     @pl.when(kv_i == 0)
@@ -92,9 +130,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     # explicit-upcast form. Softmax stays f32 either way.
     q = q_ref[0]  # (bq, D)
     k = k_ref[0]  # (bkv, D)
+    fold = _scale_folds_into_q(scale)
+    if fold:
+        q = q * scale  # (bq, D) multiplies instead of (bq, bkv)
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (bq, bkv) f32
+    )  # (bq, bkv) f32
+    if not fold:
+        logits = logits * scale
     col = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < n_valid, logits, _NEG_INF)
 
@@ -119,10 +162,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m = jnp.max(m_ref[...], axis=-1, keepdims=True)
         l = jnp.max(l_ref[...], axis=-1, keepdims=True)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        # lane-replicated (bq, LANE): a (1, bq) row block would violate the
-        # TPU (8, 128) tile rule — Mosaic rejects sublane-dim-1 blocks unless
-        # they equal the array dim (hit at N=2501 on real hardware)
-        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
+        if lse_ref is not None:
+            # lane-replicated (bq, LANE): a (1, bq) row block would violate
+            # the TPU (8, 128) tile rule — Mosaic rejects sublane-dim-1 blocks
+            # unless they equal the array dim (hit at N=2501 on real hardware)
+            lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
 
 
 def _sds(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
@@ -159,18 +203,24 @@ def flash_attention(
     k: jax.Array,
     v: jax.Array,
     scale: float,
-    block_q: int = 256,
-    block_kv: int = 512,
+    block_q: int | None = None,
+    block_kv: int | None = None,
 ) -> jax.Array:
     """Fused non-causal multi-head attention.
 
     q/k/v: ``(B, N, H, D)`` (the model's head layout, ViT.py:104-107);
     returns ``(B, N, H, D)`` in q's dtype. Softmax runs in float32 regardless
     of input dtype, matching the einsum path bit-for-bit up to GEMM precision.
-    VMEM per program ≈ (block_q + 2·block_kv)·D_padded input tiles plus the
-    f32 accumulator — independent of N, forward and backward alike.
+
+    Blocks left ``None`` are chosen from the shape (:func:`_fwd_blocks`): the
+    forward takes one head's whole K and V as a single VMEM-resident chunk
+    wherever that fits, and streams K/V chunks (VMEM ≈ (block_q +
+    2·block_kv)·D_padded input tiles plus the f32 accumulator, independent of
+    N) where it does not; the backward tiles at (256, 512). Explicit blocks are honoured, forward and backward. This
+    undifferentiated call writes no log-sum-exp; under ``jax.grad`` the
+    forward of the VJP does.
     """
-    return _flash_forward(q, k, v, scale, block_q, block_kv)[0]
+    return _flash_forward(q, k, v, scale, block_q, block_kv, with_lse=False)[0]
 
 
 def kernel_interpret() -> bool:
@@ -210,28 +260,28 @@ def per_device(call, in_specs, out_specs):
                          check_vma=False)
 
 
-def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, interpret):
+def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, with_lse, interpret):
+    """The forward launch on head-major operands: the context first, then
+    (``with_lse``) the lane-replicated log-sum-exp. With one K/V chunk the
+    K/V block index is the same for every q block of a head, so the pipeline
+    fetches a head's K and V once: that is the resident schedule."""
     BH, Nq, Dp = qh.shape
     n_kv = kh.shape[1] // bkv
     kernel = functools.partial(_fwd_kernel, scale=scale, n_valid=n_valid,
                                block_kv=bkv, n_kv=n_kv)
+    q_spec = pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0))
+    out_specs, out_shape = [q_spec], [_sds(qh.shape, qh.dtype, qh)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)))
+        out_shape.append(_sds((BH, Nq, _LANE), jnp.float32, qh))
     with profiling.scope("flash_attention/fwd"):
-        return pl.pallas_call(
+        return tuple(pl.pallas_call(
             kernel,
             grid=(BH, Nq // bq, n_kv),
-            in_specs=[
-                pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)),
-            ],
-            out_shape=[
-                _sds(qh.shape, qh.dtype, qh),
-                _sds((BH, Nq, _LANE), jnp.float32, qh),
-            ],
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=out_specs,
+            out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((bq, Dp), jnp.float32),    # output accumulator
                 pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
@@ -242,31 +292,29 @@ def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, interpret):
             ),
             interpret=interpret,
             name="fwd",
-        )(qh, kh, vh)
+        )(qh, kh, vh))
 
 
-def _flash_forward(q, k, v, scale, block_q, block_kv):
+def _flash_forward(q, k, v, scale, block_q, block_kv, *, with_lse):
+    """``(out, lse)``; ``lse`` is ``None`` unless ``with_lse`` (the VJP's
+    forward), and then one lane: O(N) across the backward, not O(N·128)."""
     interpret = kernel_interpret()
     B, N, H, D = q.shape
     qh, kh, vh = (_to_heads(x, B, N, H, D) for x in (q, k, v))
     BH, Np, Dp = qh.shape
-    # pad-or-clamp the requested blocks to Mosaic-legal sizes for this
-    # dtype/N — min() alone produced illegal tiles at odd requests or
-    # sub-16 sublanes on bf16 (ops/tiling.py; N=2501 is the worst case)
-    bq = tiling.legal_block(block_q, Np, qh.dtype)
-    bkv = tiling.legal_block(block_kv, Np, qh.dtype)
+    bq, bkv = _fwd_blocks(block_q, block_kv, Np, Dp, qh.dtype)
     qh = _pad_to(qh, 1, bq)
     kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
+    _kernels.inc("kernels.flash_fwd_schedule",
+                 key="resident" if kh.shape[1] == bkv else "streamed")
     rows = rows_spec(BH)
-    out, lse = per_device(
+    out, *lse = per_device(
         functools.partial(_fwd_call, scale=scale, n_valid=N, bq=bq, bkv=bkv,
-                          interpret=interpret),
-        (rows, rows, rows), (rows, rows))(qh, kh, vh)
+                          with_lse=with_lse, interpret=interpret),
+        (rows, rows, rows), (rows,) * (1 + with_lse))(qh, kh, vh)
 
     out = out[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
-    # drop the lane replication before the lse becomes a VJP residual —
-    # carrying all 128 lanes would hold O(N·128) f32 across the backward
-    return out, lse[:, :, 0]
+    return out, (lse[0][:, :, 0] if with_lse else None)
 
 
 # ---------------------------------------------------------------------------
@@ -379,6 +427,53 @@ def _bwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
     return int(max(dq, dkv) + rows + live)
 
 
+def _fwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
+    """Scoped VMEM the forward needs at blocks (bq, bkv = the whole padded
+    sequence): the double-buffered q, o, K and V blocks, four lane-replicated
+    f32 rows (the lse result double-buffered, the running max and
+    denominator) and ONE live (bq, bkv) f32 score tile (the compiler keeps the
+    mask, exp and the cast to the GEMM feed in place, and the accumulator
+    costs nothing measurable beside them). An upper bound, within 0.2 to
+    1.8 MiB, of the sizes the v5e compiler reports where it refuses (bf16 and
+    f32, bq 128 to 1024, 2,560 to 14,336 rows, with and without lse) —
+    tests/test_chip_compile.py compiles what this admits, at its edge too."""
+    blocks = 4 * (bq + bkv) * dp * itemsize + 4 * bq * _LANE * 4
+    return blocks + 4 * bq * bkv + (1 << 17)
+
+
+def _fwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype) -> tuple:
+    """The forward's (block_q, block_kv), Mosaic-legal for this dtype and
+    padded sequence (ops/tiling.py; min() alone produced illegal tiles at odd
+    requests or sub-16 sublanes on bf16, N=2501 is the worst case). Explicit
+    blocks win. With ``block_kv`` left ``None`` the whole sequence, padded to
+    the lane width, is one chunk — K and V resident, a lane-dense score tile —
+    at the largest ``block_q`` of 512, 256, 128 (or the one given) that the
+    VMEM model admits; where none fits, the streamed (256, 512). Lane width
+    and not the sublane minimum because of ONE shape: at 2,501 tokens it is
+    the 2,560 rows the backward pads K and V to as well, so the 200px training
+    step pads them once (2,512 rows made it pad twice and cost dp4 3 %:
+    PERF.md section 6, PR 25). At other lengths forward and backward still
+    pad K and V apart (1,025 tokens: 1,152 and 1,536 rows); that belongs to
+    the backward kernels' issue (ROADMAP Speed item 2b)."""
+    isz = jnp.dtype(dtype).itemsize
+    if block_kv is None:
+        whole = tiling.round_up(n_pad, _LANE)
+        for want in ((512, 256, 128) if block_q is None else (block_q,)):
+            bq = tiling.legal_block(want, n_pad, dtype)
+            if _fwd_vmem_bytes(bq, whole, dp, isz) <= _SCOPED_VMEM_BYTES:
+                return bq, whole
+    block_q, block_kv = _default_blocks(block_q, block_kv)
+    return (tiling.legal_block(block_q, n_pad, dtype),
+            tiling.legal_block(block_kv, n_pad, dtype))
+
+
+def _default_blocks(block_q, block_kv) -> tuple:
+    """The streamed schedule's and the backward's blocks where the caller
+    left them to the kernel."""
+    return (256 if block_q is None else block_q,
+            512 if block_kv is None else block_kv)
+
+
 def _bwd_blocks(bq: int, bkv: int, n_pad: int, dp: int, dtype) -> tuple:
     """The backward's own (block_q, block_kv): the forward's, with the larger
     side halved until both backward kernels fit the scoped VMEM. The budgets
@@ -455,6 +550,7 @@ def _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv):
     B, N, H, D = q.shape
     qh, kh, vh, oh, gh = (_to_heads(x, B, N, H, D) for x in (q, k, v, o, g))
     BH, Np, Dp = qh.shape
+    block_q, block_kv = _default_blocks(block_q, block_kv)
     bq, bkv = _bwd_blocks(tiling.legal_block(block_q, Np, qh.dtype),
                           tiling.legal_block(block_kv, Np, qh.dtype),
                           Np, Dp, qh.dtype)
@@ -552,7 +648,7 @@ def _dense_attention_f32(q, k, v, scale):
 
 
 def _flash_fwd(q, k, v, scale, block_q, block_kv):
-    out, lse = _flash_forward(q, k, v, scale, block_q, block_kv)
+    out, lse = _flash_forward(q, k, v, scale, block_q, block_kv, with_lse=True)
     return out, (q, k, v, out, lse)
 
 

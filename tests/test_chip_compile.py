@@ -85,13 +85,24 @@ def _params(model, sds):
 
 # --- cases: name → builder(devices) → (fn, args, custom calls expected) -----
 
-def _flash_fwd(dtype, blocks):
+def _flash_fwd(dtype, blocks, with_lse=False, n=N):
+    """The forward at explicit blocks, or (a ``None``) at those the kernel
+    picks from the shape; ``with_lse`` is the launch the VJP's forward makes."""
     def build(devices):
         sds = _struct(SingleDeviceSharding(devices[0]))
-        q = sds((ROWS, N, H, D), dtype)
-        return (lambda q, k, v: fa.flash_attention(q, k, v, D ** -0.5, *blocks),
+        q = sds((ROWS, n, H, D), dtype)
+        return (lambda q, k, v: fa._flash_forward(
+                    q, k, v, D ** -0.5, *blocks, with_lse=with_lse),
                 (q, q, q), 1)
     return build
+
+
+def _admitted(dtype, n=N):
+    """Every block_q the forward VMEM model admits with the whole padded
+    sequence as one K/V chunk (pure arithmetic: safe at import)."""
+    n_pad = -(-n // 8) * 8
+    return [bq for bq in (1024, 512, 256, 128)
+            if fa._fwd_blocks(bq, None, n_pad, 128, dtype)[1] >= n_pad]
 
 
 def _flash_grad(dtype):
@@ -199,6 +210,12 @@ CASES = {
     **{f"flash_fwd-{np.dtype(dt).name}-{bq}x{bkv}": _flash_fwd(dt, (bq, bkv))
        for dt in (jnp.float32, jnp.bfloat16)
        for bq, bkv in ((256, 512), fa.NS_FLASH_BLOCKS)},
+    **{f"flash_fwd-{np.dtype(dt).name}-auto{'-lse' * lse}":
+       _flash_fwd(dt, (None, None), with_lse=lse)
+       for dt in (jnp.float32, jnp.bfloat16) for lse in (False, True)},
+    **{f"flash_fwd-{np.dtype(dt).name}-{bq}xwhole": _flash_fwd(
+           dt, (bq, None), with_lse=True)
+       for dt in (jnp.float32, jnp.bfloat16) for bq in _admitted(dt)},
     **{f"flash_grad-{np.dtype(dt).name}": _flash_grad(dt)
        for dt in (jnp.float32, jnp.bfloat16)},
     **{f"dequant_matmul-n{n_out}": _dequant(n_out) for n_out in (3 * C, C)},
@@ -228,6 +245,22 @@ def test_compiles_for_v5e(case, chip):
     text = jax.jit(fn).lower(*args, **(kwargs[0] if kwargs else {})
                              ).compile().as_text()
     assert text.count("tpu_custom_call") == want_calls
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bq", [512, 256, 128])
+def test_fwd_vmem_model_admits_only_what_compiles(bq, dtype, chip):
+    """At the model's edge: the longest sequence (to 128 tokens) at which
+    ``_fwd_vmem_bytes`` still admits this block_q with K and V resident must
+    compile, lse and all — the model is fitted to this compiler's refusals,
+    so a drift shows here and not as a refused kernel on the chip."""
+    n = max(n for n in range(2560, 16384, 128)
+            if fa._fwd_blocks(bq, None, n, 128, dtype) == (bq, n))
+    assert fa._fwd_blocks(bq, None, n + 128, 128, dtype) == (bq, 512)
+    fn, args, _ = _flash_fwd(jnp.dtype(dtype), (bq, None), with_lse=True,
+                             n=n)(chip)
+    assert jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call") == 1
 
 
 # --- the kernels' instruction names: what the benchmark's readers match -----

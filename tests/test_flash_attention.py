@@ -57,6 +57,111 @@ def test_flash_long_sequence_bounded_vmem():
     np.testing.assert_allclose(ours, np.asarray(want), rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("N,H,D,bq,streamed", [
+    (65, 3, 32, None, (32, 32)),      # vit_tiny's tokens; scale not 2^k
+    (300, 2, 16, 64, (64, 128)),      # several q blocks, scale 2^-2 folded
+    (2501, 4, 64, None, (256, 512)),  # the 200px trunk: 512 x 2560, 2^-3
+])
+def test_resident_forward_matches_scaled_scores_streamed_and_dense(
+        N, H, D, bq, streamed, dtype, monkeypatch):
+    """The K/V-resident forward (blocks left to the kernel, or one chunk asked
+    for) against (a) the same launch with the f32 scores scaled, as the kernel
+    did before a power-of-two scale was folded into q: BITWISE, context and
+    lse; (b) the streamed kernel and (c) the dense f32 path, to this file's
+    tolerances. With and without the lse result."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    dt = jnp.dtype(dtype)
+    q, k, v = (x.astype(dt) for x in _rand_qkv(31, 1, N, H, D))
+    scale = D ** -0.5
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" else dict(
+        rtol=2e-2, atol=2e-2)
+
+    with_lse, lse = fa._flash_forward(q, k, v, scale, bq, None, with_lse=True)
+    without, none = fa._flash_forward(q, k, v, scale, bq, None,
+                                      with_lse=False)
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(with_lse, np.float32),
+                                  np.asarray(without, np.float32))
+    with monkeypatch.context() as patch:
+        patch.setattr(fa, "_scale_folds_into_q", lambda scale: False)
+        old, old_lse = fa._flash_forward(q, k, v, scale, bq, None,
+                                         with_lse=True)
+    np.testing.assert_array_equal(np.asarray(without, np.float32),
+                                  np.asarray(old, np.float32))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(old_lse))
+
+    chunked, chunked_lse = fa._flash_forward(q, k, v, scale, *streamed,
+                                             with_lse=True)
+    _, dense = _dense_attention_f32(q, k, v, scale)
+    for want in (chunked, dense):
+        np.testing.assert_allclose(np.asarray(without, np.float32),
+                                   np.asarray(want, np.float32), **tol)
+    # the residual keeps the q padding, which differs with block_q
+    np.testing.assert_allclose(np.asarray(lse[:, :N]),
+                               np.asarray(chunked_lse[:, :N]),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
+    """Blocks left ``None``: K/V resident at the 200px trunk (2,501 tokens,
+    head size 64), streamed at 32,768 tokens — asked of the choice alone,
+    nothing runs — and ``kernels.flash_fwd_schedule`` says which. Explicit
+    blocks are honoured, and the backward at ``None`` still tiles (256, 512)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    def traced(n, *blocks, dtype=jnp.bfloat16, grad=False):
+        """Trace (no kernel runs) → (schedule counted, {kernel: grid})."""
+        before = fa._kernels.by_key("kernels.flash_fwd_schedule")
+        grids = {}
+        real = fa.pl.pallas_call
+
+        def spy(kernel, **kw):
+            grids[kw.get("name")] = tuple(kw["grid"])
+            return real(kernel, **kw)
+
+        x = jax.ShapeDtypeStruct((1, n, 1, 64), dtype)
+        fn = lambda q, k, v: fa.flash_attention(  # noqa: E731
+            q, k, v, 0.125, *blocks).astype(jnp.float32).sum()
+        with monkeypatch.context() as patch:
+            patch.setattr(fa.pl, "pallas_call", spy)
+            jax.eval_shape(jax.grad(fn, argnums=(0, 1, 2)) if grad else fn,
+                           x, x, x)
+        after = fa._kernels.by_key("kernels.flash_fwd_schedule")
+        return ({key: after[key] - before.get(key, 0) for key in after
+                 if after[key] != before.get(key, 0)}, grids)
+
+    # the choice itself, from padded tokens / padded head size / dtype
+    assert fa._fwd_blocks(None, None, 2504, 128, jnp.bfloat16) == (512, 2560)
+    assert fa._fwd_blocks(None, None, 2504, 128, jnp.float32) == (512, 2560)
+    assert fa._fwd_blocks(None, None, 32768, 128, jnp.bfloat16) == (256, 512)
+    assert fa._fwd_blocks(256, 512, 2504, 128, jnp.bfloat16) == (256, 512)
+    assert fa._fwd_blocks(128, None, 2504, 128, jnp.bfloat16) == (128, 2560)
+    assert fa._fwd_blocks(None, 1024, 2504, 128, jnp.bfloat16) == (256, 1024)
+    # ... is one the VMEM model admits, and shrinks block_q before it streams
+    for n_pad, dt in ((2504, jnp.bfloat16), (6512, jnp.bfloat16),
+                      (10000, jnp.bfloat16), (5504, jnp.float32)):
+        bq, bkv = fa._fwd_blocks(None, None, n_pad, 128, dt)
+        assert bkv >= n_pad and fa._fwd_vmem_bytes(
+            bq, bkv, 128, jnp.dtype(dt).itemsize) <= fa._SCOPED_VMEM_BYTES
+    assert fa._fwd_blocks(None, None, 6512, 128, jnp.bfloat16)[0] == 256
+    assert fa._fwd_blocks(None, None, 10000, 128, jnp.bfloat16)[0] == 128
+
+    assert traced(2501) == ({"resident": 1}, {"fwd": (1, 5, 1)})
+    assert traced(2501, dtype=jnp.float32) == ({"resident": 1},
+                                               {"fwd": (1, 5, 1)})
+    assert traced(32768) == ({"streamed": 1}, {"fwd": (1, 128, 64)})
+    assert traced(2501, 256, 512) == ({"streamed": 1}, {"fwd": (1, 10, 5)})
+    assert traced(2501, *fa.NS_FLASH_BLOCKS) == ({"resident": 1},
+                                                 {"fwd": (1, 5, 1)})
+    assert traced(4096, 512, 512) == ({"streamed": 1}, {"fwd": (1, 8, 8)})
+    # under grad: the VJP's forward is resident, dq and dk/dv tile (256, 512)
+    assert traced(2501, grad=True) == (
+        {"resident": 1},
+        {"fwd": (1, 5, 1), "dq": (1, 10, 5), "dkv": (1, 5, 10)})
+
+
 def test_flash_bf16_inputs():
     q, k, v = _rand_qkv(2, 1, 64, 2, 8)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
