@@ -44,6 +44,9 @@ Rules:
   every literal ``SamplerConfig(...)`` call site in ``bench.py``
   constructs once non-literal kwargs are substituted from per-axis
   representatives.
+* **X004 hybrid refusals** — the hybrid state-space trunk
+  (``models/hybrid.py``) refuses by name every config class that reaches
+  into ``Block`` and admits the rest: it adds no program class.
 """
 
 from __future__ import annotations
@@ -532,6 +535,43 @@ def check_warmup_soundness(root=None, sweep=None) -> list:
     return findings
 
 
+_HYBRID_PATH = "ddim_cold_tpu/models/hybrid.py"
+
+#: what of ``Block``'s internals the hybrid trunk must refuse by name
+HYBRID_MUST_REFUSE = ("quant", "fused", "cache_mode", "scan_blocks",
+                      "num_experts", "sp_mode", "use_flash")
+
+
+def check_hybrid_refusals() -> list:
+    """X004: the hybrid trunk (models/hybrid.py) has ONE program class per
+    sampler family. Every legal config class that reaches into ``Block``
+    (cached, quant, fused, sp) is refused by ``sampler_config_refusal`` under
+    a name ``REFUSED`` explains; every other class is admitted. So the trunk
+    adds no class to the lattice and the sweep owes it no witness."""
+    from ddim_cold_tpu.models import hybrid
+
+    findings = [
+        Finding("GRAFT-X004", _HYBRID_PATH, f"unrefused:{name}", 0,
+                f"hybrid.REFUSED does not name {name!r}: an option that "
+                "assumes Block's internals would fail on a shape instead")
+        for name in HYBRID_MUST_REFUSE if name not in hybrid.REFUSED]
+    for cls, cfg in enumerate_lattice():
+        _, cached, _, _, _, quant, fused, sp_mode, sp_degree = cls
+        reaches_block = bool(cached or quant or fused or sp_mode != "none"
+                             or sp_degree != 1)
+        option = hybrid.sampler_config_refusal(cfg)
+        if reaches_block != (option is not None) or (
+                option is not None and option not in hybrid.REFUSED):
+            findings.append(Finding(
+                "GRAFT-X004", _HYBRID_PATH, _class_name(cls), 0,
+                f"config class {_class_name(cls)} "
+                + ("reaches into Block but the hybrid trunk admits it"
+                   if reaches_block else
+                   f"is refused by the hybrid trunk ({option!r}) though "
+                   "nothing in it assumes Block")))
+    return findings
+
+
 def run_config_checks(root=None) -> list:
     """The full X-layer."""
     if root is None:
@@ -543,4 +583,5 @@ def run_config_checks(root=None) -> list:
     findings += check_validation_consistency()
     findings += _scan_bypasses(root)
     findings += check_warmup_soundness(root)
+    findings += check_hybrid_refusals()
     return findings

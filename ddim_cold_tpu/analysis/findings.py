@@ -123,6 +123,9 @@ RULES = {
     "GRAFT-X003": "warm-set/bench config outside the legal lattice (or "
                   "warmed without a sweep witness) — serving would warm or "
                   "benchmark a program the lattice proofs never saw",
+    "GRAFT-X004": "the hybrid trunk admits a config class that reaches into "
+                  "Block (or refuses one that does not): it would fail on a "
+                  "shape, or lose a program class it has",
 }
 
 #: rule-family letter (GRAFT-<X>NNN) → the CLI layer that emits it. The

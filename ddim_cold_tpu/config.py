@@ -123,6 +123,12 @@ class ExperimentConfig:
     # dispatch (XLA-friendliest, O(N²·cf) activations); "index" =
     # sort/gather dispatch (O(N·cf·D)) for long-sequence configs
     moe_dispatch: str = "einsum"
+    # a hybrid state-space trunk (models/hybrid.py) in place of the ViT's
+    # blocks: a mapping under the keys of the source model's published
+    # config.json (model_type jamba). embed_dim, depth and head are then the
+    # trunk's own and the yaml's are not read; options that reach into Block
+    # are refused by name (hybrid.REFUSED)
+    trunk: Optional[dict] = None
 
     @property
     def effective_batch(self) -> int:
@@ -276,7 +282,7 @@ _KNOWN_KEYS = frozenset({
     "cache_images", "device_degrade", "async_checkpoint", "scan_blocks",
     "microbatches", "snapshot_epochs", "ema_decay", "num_experts",
     "moe_capacity_factor", "moe_aux_weight", "moe_dispatch", "grad_accum",
-    "steps_per_dispatch",
+    "steps_per_dispatch", "trunk",
 })
 
 
@@ -344,4 +350,5 @@ def load_config(yaml_path: str, exp_name: Optional[str] = None) -> ExperimentCon
         grad_accum=_check_grad_accum(int(raw.get("grad_accum", 1))),
         steps_per_dispatch=_check_steps_per_dispatch(
             int(raw.get("steps_per_dispatch", 1))),
+        trunk=raw.get("trunk"),
     )

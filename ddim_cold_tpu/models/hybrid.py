@@ -1,0 +1,334 @@
+"""HybridDenoiser — a Jamba-style hybrid trunk (Mamba-1 state-space layers
+with a causal grouped-query attention layer every ``attn_layer_period``,
+every layer followed by a gated SiLU MLP, RMSNorm throughout) as the x0
+denoiser: ``(x_t, t) → x̂0`` with the input and output stage of
+``DiffusionViT`` (``vit.embed_tokens`` / ``vit.pixel_head``: the same code,
+called by both).
+
+The trunk's sizes are read from ``trunk``, a mapping whose keys are those of
+the language model's published ``config.json`` (``model_type: jamba``),
+letter for letter, so a configuration file carries the source's own keys.
+With x ∈ R^{L×hidden_size}, ε = ``rms_norm_eps``, no bias unless said:
+
+* layer i: ``x += mixer_i(RMSNorm(x))``; ``x += W_down(SiLU(W_gate y) ⊙
+  W_up y)``, ``y = RMSNorm(x)``, width ``intermediate_size``; after the last
+  layer the final RMSNorm. ``mixer_i`` is attention where ``i %
+  attn_layer_period == attn_layer_offset``, else Mamba.
+* Mamba-1 mixer (d = ``mamba_expand``·hidden, s = ``mamba_d_state``,
+  k = ``mamba_d_conv``, r = ``mamba_dt_rank``): ``[u, z] = x W_in``;
+  ``u_t ← SiLU(b_c + Σ_j w_j ⊙ u_{t−k+1+j})`` (depthwise, causal);
+  ``[δ, B, C] = u W_x`` (r + s + s), each RMSNormed (the ``jamba`` modelling
+  code's ``dt_layernorm``, ``b_layernorm``, ``c_layernorm``);
+  ``Δ = softplus(δ W_dt + b_dt)``; ``A = −exp(A_log)``; then
+  ``ops.selective_scan`` and ``W_out``.
+* attention: ``num_attention_heads`` query heads on ``num_key_value_heads``
+  shared K/V heads, no position term, scale head_dim^−½, causal mask, softmax
+  in float32. Dense XLA attention (two layers of 28 in Jamba2-3B, 0.4 % of
+  the forward's FLOPs at 1,025 tokens); the causal shared-KV flash forward
+  is ROADMAP Reach's.
+
+Every layer keeps its published causality: the scan, the convolution and
+the mask run in raster order from the class token.
+
+Parameters are stored in ``param_dtype`` and computed in ``dtype``; handed a
+bfloat16 tree with ``dtype=bfloat16`` no program holds a float32 copy of it
+(2.87 B parameters at Jamba2-3B's widths: 5.75 GB against 11.5).
+
+What assumes ``Block``'s internals is refused by name (:data:`REFUSED`):
+``quant``, ``fused``, the step caches, ``scan_blocks``, ``num_experts`` > 1,
+sequence parallelism (``sp_mode``, ``seq_mesh``), ``use_flash``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Mapping, Sequence
+
+import flax
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ddim_cold_tpu.models import vit
+from ddim_cold_tpu.models.init import torch_default_uniform, trunc_normal
+from ddim_cold_tpu.ops.selective_scan import selective_scan
+
+Dtype = Any
+
+#: options of ``DiffusionViT``, ``SamplerConfig`` and the yaml that reach into
+#: ``Block``, and why this trunk has none of them
+REFUSED = {
+    "quant": "the int8 codec covers Block's qkv/proj/fc1/fc2 denses",
+    "fused": "the fused trunk kernels are Block's attention and Mlp",
+    "cache_mode": "the step caches skip and re-run Block ranges by index",
+    "scan_blocks": "the layer kind depends on the index: no one scanned body",
+    "num_experts": "every MLP of this trunk is dense",
+    "sp_mode": "the scan and the causal mask are sequential in the tokens",
+    "use_flash": "the flash kernels have no causal mask and no shared KV heads",
+}
+#: further spellings of the above, as the model, the sampler and the yaml have
+#: them, each mapped to the option it is refused under
+_ALIASES = {"flash_blocks": "use_flash", "seq_mesh": "sp_mode",
+            "seq_axis": "sp_mode", "sp_degree": "sp_mode",
+            "cache_interval": "cache_mode",
+            "capture_split": "cache_mode", "skip_blocks": "cache_mode",
+            "block_delta": "cache_mode", "capture_tokens": "cache_mode",
+            "token_cache": "cache_mode", "token_k": "cache_mode"}
+
+
+def refuse(option: str) -> ValueError:
+    name = _ALIASES.get(option, option)
+    return ValueError(
+        f"the hybrid trunk has no {name!r} ({option}): {REFUSED[name]}")
+
+
+def refuse_any(options: Mapping[str, Any]) -> None:
+    """Raise for the first of ``options`` (name → value) that is set and
+    that :data:`REFUSED` names, under any of its spellings."""
+    for option, value in options.items():
+        unset = (value is None or value is False or value == "none"
+                 or (option == "num_experts" and value == 1))
+        if _ALIASES.get(option, option) in REFUSED and not unset:
+            raise refuse(option)
+
+
+def sampler_config_refusal(config) -> str | None:
+    """The option of a ``serve.SamplerConfig`` this trunk refuses, or None."""
+    for option in ("quant", "fused"):
+        if getattr(config, option):
+            return option
+    if config.cached:
+        return "cache_mode"
+    if config.sp_mode != "none" or config.sp_degree != 1:
+        return "sp_mode"
+    return None
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (x.shape[-1],), self.param_dtype)
+        xf = x.astype(jnp.float32)
+        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
+        return (xf * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def _dt_bias_init(lo: float = 1e-3, hi: float = 1e-1):
+    """Mamba's published initialisation of ``dt_proj.bias``: the inverse
+    softplus of a Δ drawn log-uniformly in [lo, hi]."""
+
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                        math.log(lo), math.log(hi)))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    states = jnp.arange(1, shape[1] + 1, dtype=jnp.float32)
+    return jnp.broadcast_to(jnp.log(states), shape).astype(dtype)
+
+
+class MambaMixer(nn.Module):
+    trunk: Mapping[str, Any]
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.trunk
+        d = c["mamba_expand"] * c["hidden_size"]
+        s, k, r = c["mamba_d_state"], c["mamba_d_conv"], c["mamba_dt_rank"]
+        L = x.shape[1]
+        dense = lambda feats, bias, name: nn.Dense(
+            feats, use_bias=bias, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        norm = lambda name: RMSNorm(c["rms_norm_eps"], self.dtype,
+                                    self.param_dtype, name=name)
+        u, z = jnp.split(dense(2 * d, c["mamba_proj_bias"], "in_proj")(x), 2, -1)
+
+        w = self.param("conv1d_kernel", torch_default_uniform(k), (k, d),
+                       self.param_dtype).astype(jnp.float32)
+        past = jnp.pad(u.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+        conv = sum(w[j] * past[:, j:j + L] for j in range(k))
+        if c["mamba_conv_bias"]:
+            conv = conv + self.param(
+                "conv1d_bias", nn.initializers.zeros_init(), (d,),
+                self.param_dtype).astype(jnp.float32)
+        u = jax.nn.silu(conv).astype(self.dtype)
+
+        delta, B, C = jnp.split(dense(r + 2 * s, False, "x_proj")(u),
+                                (r, r + s), -1)
+        delta, B, C = (norm("dt_layernorm")(delta), norm("b_layernorm")(B),
+                       norm("c_layernorm")(C))
+        delta = nn.Dense(d, dtype=self.dtype, param_dtype=self.param_dtype,
+                         kernel_init=trunc_normal(std=0.02),
+                         bias_init=_dt_bias_init(), name="dt_proj")(delta)
+        delta = jax.nn.softplus(delta.astype(jnp.float32)).astype(self.dtype)
+        A = -jnp.exp(self.param("A_log", _a_log_init, (d, s),
+                                self.param_dtype).astype(jnp.float32))
+        D = self.param("D", nn.initializers.ones_init(), (d,), self.param_dtype)
+        y = selective_scan(u, delta, A, B, C, D, z)
+        return dense(c["hidden_size"], c["mamba_proj_bias"], "out_proj")(y)
+
+
+class CausalAttention(nn.Module):
+    trunk: Mapping[str, Any]
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.trunk
+        n, L, width = x.shape
+        heads, kv = c["num_attention_heads"], c["num_key_value_heads"]
+        hd = width // heads
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        # query head h = g·(heads/kv) + r reads K/V head g
+        q = dense(heads * hd, "q_proj")(x).reshape(n, L, kv, heads // kv, hd)
+        k = dense(kv * hd, "k_proj")(x).reshape(n, L, kv, hd)
+        v = dense(kv * hd, "v_proj")(x).reshape(n, L, kv, hd)
+        logits = jnp.einsum("bngrd,bmgd->bgrnm", q, k).astype(jnp.float32)
+        causal = jnp.tril(jnp.ones((L, L), bool))
+        logits = jnp.where(causal, logits * hd ** -0.5, -jnp.inf)
+        attn = jax.nn.softmax(logits, axis=-1).astype(self.dtype)
+        out = jnp.einsum("bgrnm,bmgd->bngrd", attn, v).reshape(n, L, heads * hd)
+        return dense(width, "o_proj")(out)
+
+
+class GatedMlp(nn.Module):
+    trunk: Mapping[str, Any]
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.trunk
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        hidden = (jax.nn.silu(dense(c["intermediate_size"], "gate_proj")(x))
+                  * dense(c["intermediate_size"], "up_proj")(x))
+        return dense(c["hidden_size"], "down_proj")(hidden)
+
+
+def is_attention_layer(trunk: Mapping[str, Any], i: int) -> bool:
+    return i % trunk["attn_layer_period"] == trunk["attn_layer_offset"]
+
+
+class HybridLayer(nn.Module):
+    trunk: Mapping[str, Any]
+    attention: bool
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kw = dict(trunk=self.trunk, dtype=self.dtype,
+                  param_dtype=self.param_dtype)
+        norm = lambda name: RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
+                                    self.param_dtype, name=name)
+        y = norm("input_layernorm")(x)
+        if self.attention:
+            with jax.named_scope("trunk/attn"):
+                x = x + CausalAttention(**kw, name="self_attn")(y)
+        else:
+            with jax.named_scope("trunk/mamba"):
+                x = x + MambaMixer(**kw, name="mamba")(y)
+        with jax.named_scope("trunk/mlp"):
+            return x + GatedMlp(**kw, name="feed_forward")(
+                norm("pre_ff_layernorm")(x))
+
+
+class HybridDenoiser(nn.Module):
+    """``(x_t, t) → x̂0``; NHWC in [−1, 1], ``t`` int32 per sample, as
+    ``DiffusionViT``. Exposes what the samplers and the engine read of a
+    model: ``apply``, ``img_size``, ``in_chans``, ``total_steps``,
+    ``num_patches``, ``embed_dim``, ``num_heads``, ``depth``, ``dtype``,
+    ``clone``."""
+
+    trunk: Mapping[str, Any]
+    img_size: Sequence[int] = (64, 64)
+    patch_size: int = 8
+    in_chans: int = 3
+    total_steps: int = 2000
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    def __post_init__(self):
+        # hashable, as jit's static ``model`` argument has to be
+        c = flax.core.FrozenDict(self.trunk)
+        object.__setattr__(self, "trunk", c)
+        if c.get("num_experts", 1) != 1:
+            raise refuse("num_experts")
+        if c.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act {c['hidden_act']!r}: this trunk's "
+                             "MLP and mixers are written for 'silu'")
+        if c.get("sliding_window") is not None:
+            raise ValueError("sliding_window: the attention layers here "
+                             "attend to every earlier token")
+        if c["hidden_size"] % c["num_attention_heads"] or (
+                c["num_attention_heads"] % c["num_key_value_heads"]):
+            raise ValueError(
+                "hidden_size must divide into num_attention_heads, and those "
+                "into num_key_value_heads")
+        super().__post_init__()
+
+    @property
+    def embed_dim(self) -> int:
+        return self.trunk["hidden_size"]
+
+    @property
+    def num_heads(self) -> int:
+        return self.trunk["num_attention_heads"]
+
+    @property
+    def depth(self) -> int:
+        return self.trunk["num_hidden_layers"]
+
+    @property
+    def num_patches(self) -> int:
+        return ((self.img_size[0] // self.patch_size)
+                * (self.img_size[1] // self.patch_size))
+
+    def refuse_sampler_config(self, config) -> None:
+        """``serve.Engine`` asks before it queues or compiles ``config``."""
+        option = sampler_config_refusal(config)
+        if option is not None:
+            raise refuse(option)
+
+    def clone(self, **updates):
+        refuse_any(updates)
+        return super().clone(**updates)
+
+    @nn.compact
+    def __call__(self, x: jax.Array, t: jax.Array, deterministic: bool = True,
+                 **hooks) -> jax.Array:
+        """``deterministic`` is accepted for the callers that pass it: this
+        trunk has no dropout. ``hooks``: ``DiffusionViT``'s cache, probe and
+        pipeline-stage arguments, none of which exists here."""
+        refuse_any(hooks)
+        if hooks:
+            raise ValueError(f"the hybrid trunk takes no {sorted(hooks)}: "
+                             "the probe and the pipeline stages are Block's")
+        tokens = vit.embed_tokens(self, x, t, drop_rate=0.0,
+                                  deterministic=True,
+                                  param_dtype=self.param_dtype)
+        for i in range(self.depth):
+            tokens = HybridLayer(
+                self.trunk, is_attention_layer(self.trunk, i), self.dtype,
+                self.param_dtype, name=f"layers_{i}")(tokens)
+        tokens = RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
+                         self.param_dtype, name="final_layernorm")(tokens)
+        return vit.pixel_head(self, tokens, param_dtype=self.param_dtype)

@@ -562,6 +562,7 @@ class PatchEmbed(nn.Module):
     embed_dim: int
     in_chans: int = 3
     dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -575,11 +576,92 @@ class PatchEmbed(nn.Module):
         x = nn.Dense(
             self.embed_dim,
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
             kernel_init=torch_default_uniform(fan_in),
             bias_init=torch_default_uniform(fan_in),
             name="proj",
         )(x)
         return x
+
+
+def embed_tokens(mod: nn.Module, x: jax.Array, t: jax.Array, *,
+                 drop_rate: float, deterministic: bool,
+                 use_sincos_pos: bool = False,
+                 param_dtype: Dtype = jnp.float32) -> jax.Array:
+    """The denoisers' input stage, declared in ``mod``'s scope (call it from
+    ``mod``'s compact ``__call__``): ``patch_embed``, ``cls_token``,
+    ``time_embed``, ``pos_embed``, ``pos_drop`` — one definition for every
+    trunk (``DiffusionViT`` here, ``models/hybrid.py``). ``mod`` supplies
+    ``patch_size``, ``embed_dim``, ``in_chans``, ``num_patches``,
+    ``total_steps`` and ``dtype``."""
+    B, E, N = x.shape[0], mod.embed_dim, mod.num_patches
+    x = x.astype(mod.dtype)
+    tokens = PatchEmbed(
+        patch_size=mod.patch_size,
+        embed_dim=E,
+        in_chans=mod.in_chans,
+        dtype=mod.dtype,
+        param_dtype=param_dtype,
+        name="patch_embed",
+    )(x)
+
+    cls_token = mod.param("cls_token", trunc_normal(std=0.02), (1, 1, E),
+                          param_dtype)
+    tokens = jnp.concatenate(
+        [jnp.broadcast_to(cls_token.astype(mod.dtype), (B, 1, E)), tokens], axis=1
+    )
+
+    # time conditioning: one learned row per step, added to EVERY token
+    # (cls included) together with the positional embedding (ViT.py:204-205).
+    time_embed = nn.Embed(
+        mod.total_steps,
+        E,
+        embedding_init=trunc_normal(std=0.02),
+        dtype=mod.dtype,
+        param_dtype=param_dtype,
+        name="time_embed",
+    )(t.astype(jnp.int32))[:, None, :]
+
+    if use_sincos_pos:
+        pos_embed = jnp.asarray(positionalencoding1d(E, N + 1))[None]
+    else:
+        pos_embed = mod.param("pos_embed", trunc_normal(std=0.02),
+                              (1, N + 1, E), param_dtype)
+    tokens = tokens + pos_embed.astype(mod.dtype) + time_embed
+    return nn.Dropout(drop_rate, deterministic=deterministic,
+                      name="pos_drop")(tokens)
+
+
+def pixel_head(mod: nn.Module, tokens: jax.Array, *,
+               param_dtype: Dtype = jnp.float32) -> jax.Array:
+    """The denoisers' output stage on normed tokens, in ``mod``'s scope:
+    ``head`` (width → C·p²), the class token dropped, un-patchified, float32."""
+    tokens = nn.Dense(
+        mod.in_chans * mod.patch_size**2,
+        dtype=mod.dtype,
+        param_dtype=param_dtype,
+        kernel_init=trunc_normal(std=0.02),
+        bias_init=nn.initializers.zeros_init(),
+        name="head",
+    )(tokens)
+    return unpatchify(tokens[:, 1:, :], mod.img_size, mod.patch_size,
+                      mod.in_chans).astype(jnp.float32)
+
+
+def unpatchify(x: jax.Array, img_size, patch_size: int,
+               in_chans: int) -> jax.Array:
+    """(B, N, p²C) → (B, H, W, C), exact reference pixel mapping.
+
+    The torch path (ViT.py:214-217) views the feature dim as (p, p, C)
+    with C fastest, then permute(0,5,1,3,2,4): pixel (i·p+a, j·p+b, c) ←
+    feature a·pC + b·C + c of patch (i, j). NHWC equivalent below.
+    """
+    p, C = patch_size, in_chans
+    H, W = img_size
+    B = x.shape[0]
+    x = x.reshape(B, H // p, W // p, p, p, C)
+    x = x.transpose(0, 1, 3, 2, 4, 5)  # (B, H/p, p, W/p, p, C)
+    return x.reshape(B, H, W, C)
 
 
 class DiffusionViT(nn.Module):
@@ -791,45 +873,11 @@ class DiffusionViT(nn.Module):
             if tokens is None:
                 raise ValueError('stage="head" requires tokens')
             tokens = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm")(tokens)
-            tokens = nn.Dense(
-                self.in_chans * self.patch_size**2,
-                dtype=self.dtype,
-                kernel_init=trunc_normal(std=0.02),
-                bias_init=nn.initializers.zeros_init(),
-                name="head",
-            )(tokens)
-            return self.unpatchify(tokens[:, 1:, :]).astype(jnp.float32)
+            return pixel_head(self, tokens)
 
-        x = x.astype(self.dtype)
-        tokens = PatchEmbed(
-            patch_size=self.patch_size,
-            embed_dim=E,
-            in_chans=self.in_chans,
-            dtype=self.dtype,
-            name="patch_embed",
-        )(x)
-
-        cls_token = self.param("cls_token", trunc_normal(std=0.02), (1, 1, E))
-        tokens = jnp.concatenate(
-            [jnp.broadcast_to(cls_token.astype(self.dtype), (B, 1, E)), tokens], axis=1
-        )
-
-        # time conditioning: one learned row per step, added to EVERY token
-        # (cls included) together with the positional embedding (ViT.py:204-205).
-        time_embed = nn.Embed(
-            self.total_steps,
-            E,
-            embedding_init=trunc_normal(std=0.02),
-            dtype=self.dtype,
-            name="time_embed",
-        )(t.astype(jnp.int32))[:, None, :]
-
-        if self.use_sincos_pos:
-            pos_embed = jnp.asarray(positionalencoding1d(E, N + 1))[None]
-        else:
-            pos_embed = self.param("pos_embed", trunc_normal(std=0.02), (1, N + 1, E))
-        tokens = tokens + pos_embed.astype(self.dtype) + time_embed
-        tokens = nn.Dropout(self.drop_rate, deterministic=deterministic, name="pos_drop")(tokens)
+        tokens = embed_tokens(self, x, t, drop_rate=self.drop_rate,
+                              deterministic=deterministic,
+                              use_sincos_pos=self.use_sincos_pos)
         if stage == "embed":
             return tokens
 
@@ -963,14 +1011,7 @@ class DiffusionViT(nn.Module):
 
         trunk_out = tokens  # pre-norm trunk output — the delta reference point
         tokens = nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name="norm")(tokens)
-        tokens = nn.Dense(
-            self.in_chans * self.patch_size**2,
-            dtype=self.dtype,
-            kernel_init=trunc_normal(std=0.02),
-            bias_init=nn.initializers.zeros_init(),
-            name="head",
-        )(tokens)
-        out = self.unpatchify(tokens[:, 1:, :]).astype(jnp.float32)
+        out = pixel_head(self, tokens)
         if capture_split is not None:
             return out, (tokens_mid - tokens_in, trunk_out - tokens_mid)
         if capture_tokens:
@@ -980,19 +1021,8 @@ class DiffusionViT(nn.Module):
         return out
 
     def unpatchify(self, x: jax.Array) -> jax.Array:
-        """(B, N, p²C) → (B, H, W, C), exact reference pixel mapping.
-
-        The torch path (ViT.py:214-217) views the feature dim as (p, p, C)
-        with C fastest, then permute(0,5,1,3,2,4): pixel (i·p+a, j·p+b, c) ←
-        feature a·pC + b·C + c of patch (i, j). NHWC equivalent below.
-        """
-        p = self.patch_size
-        C = self.in_chans
-        H, W = self.img_size
-        B = x.shape[0]
-        x = x.reshape(B, H // p, W // p, p, p, C)
-        x = x.transpose(0, 1, 3, 2, 4, 5)  # (B, H/p, p, W/p, p, C)
-        return x.reshape(B, H, W, C)
+        """(B, N, p²C) → (B, H, W, C): :func:`unpatchify` at this model's sizes."""
+        return unpatchify(x, self.img_size, self.patch_size, self.in_chans)
 
 
 def sp_clone(model: DiffusionViT, mesh, *, sp_mode: str = "ulysses",

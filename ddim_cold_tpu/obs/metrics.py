@@ -105,6 +105,9 @@ METRICS = (
     # -- kernels (ops/flash_attention.py, counted once a trace) -----------
     ("kernels.flash_fwd_schedule", "counter",
      "flash forward traces by schedule (key: resident|streamed)"),
+    # -- kernels (ops/selective_scan.py, counted once a trace) ------------
+    ("kernels.ssm_scan_schedule", "counter",
+     "selective-scan traces by path (key: kernel|xla)"),
     # -- fault injection --------------------------------------------------
     ("faults.injected", "counter", "realized fault injections (key: site)"),
     # -- attribution / trend (obs.attrib / obs.trend, host-side) ----------

@@ -311,6 +311,7 @@ class Engine:
             config = SamplerConfig(**kwargs)
         elif kwargs:
             raise ValueError(f"pass config OR keyword options, not both: {kwargs}")
+        self._check_config(config)
         task = config.task
         if mask is not None and task != "inpaint":
             raise ValueError(
@@ -408,6 +409,7 @@ class Engine:
         key = (config, bucket)
         prog = self._programs.get(key)
         if prog is None:
+            self._check_config(config)
             if config.sp_degree > 1:
                 shards = data_axis_size(self._sp_mesh(config.sp_degree))
                 if bucket % shards:
@@ -436,6 +438,14 @@ class Engine:
 
     def _n_devices(self) -> int:
         return len(self._devices())
+
+    def _check_config(self, config: SamplerConfig) -> None:
+        """A model whose trunk has no path for a sampler option refuses it by
+        name (``models/hybrid.py``: quant, fused, the step caches, sp), at
+        submit and before any compile, instead of failing on a shape."""
+        refuse = getattr(self.model, "refuse_sampler_config", None)
+        if refuse is not None:
+            refuse(config)
 
     def _sp_mesh(self, degree: int):
         """The (data, seq) mesh for one sp_degree — built once, shared by

@@ -190,7 +190,27 @@ def _build_dataset(config: ExperimentConfig, root: str):
     raise ValueError(f"unknown dataset kind {config.dataset!r}")
 
 
-def build_model(config: ExperimentConfig, mesh=None) -> DiffusionViT:
+def _build_hybrid(config: ExperimentConfig, mesh):
+    """``trunk:`` in the yaml: the hybrid state-space denoiser at the yaml's
+    image, patch and step sizes. What reaches into ``Block`` is refused by
+    name, a mesh axis that would split tokens or layers included."""
+    from ddim_cold_tpu.models import hybrid
+
+    hybrid.refuse_any({
+        "use_flash": config.use_flash, "flash_blocks": config.flash_blocks,
+        "scan_blocks": config.scan_blocks, "num_experts": config.num_experts})
+    mesh_shape = getattr(mesh, "shape", {}) if mesh is not None else {}
+    for axis, option in (("seq", "sp_mode"), ("pipe", "scan_blocks"),
+                         ("expert", "num_experts")):
+        if int(mesh_shape.get(axis, 1)) > 1:
+            raise hybrid.refuse(option)
+    return hybrid.HybridDenoiser(
+        trunk=dict(config.trunk), img_size=tuple(config.image_size),
+        patch_size=config.patch_size, total_steps=config.total_steps,
+        dtype=jnp.bfloat16 if config.amp else jnp.float32)
+
+
+def build_model(config: ExperimentConfig, mesh=None):
     """Model from config. With a mesh carrying a ``seq`` axis, attention runs
     as ring attention sharded over it (sequence parallelism); attention-
     dropout is zeroed then — the ring path never materializes the weights, and
@@ -198,6 +218,8 @@ def build_model(config: ExperimentConfig, mesh=None) -> DiffusionViT:
     holds for ``use_flash``: the kernel has no weights to drop, so a flash
     config trains without attention-dropout. A ``pipe`` axis forces the
     stacked scan_blocks layout (the pipeline's substrate)."""
+    if config.trunk is not None:
+        return _build_hybrid(config, mesh)
     kwargs = dict(config.model_kwargs())
     if config.use_flash:
         kwargs["attn_drop_rate"] = 0.0

@@ -792,7 +792,7 @@ def test_rule_table_covers_all_emitted_rules():
         "GRAFT-M001", "GRAFT-M002",
         "GRAFT-R001", "GRAFT-R002", "GRAFT-R003", "GRAFT-R004",
         "GRAFT-R005",
-        "GRAFT-X001", "GRAFT-X002", "GRAFT-X003"}
+        "GRAFT-X001", "GRAFT-X002", "GRAFT-X003", "GRAFT-X004"}
     assert {rule_layer(r) for r in RULES} == set(cli.LAYERS)
 
 

@@ -294,3 +294,32 @@ def test_flash_kernels_keep_their_instruction_names(program, kernels, chip):
         r"%(\w+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
         text)}
     assert names == kernels
+
+
+# --- the selective scan at Jamba2-3B's published shape ----------------------
+
+SSM = dict(n=4, L=1025, d=5120, s=16)  # images, tokens, d_inner, states
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_ssm_scan_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip):
+    """``ops/selective_scan.py`` at 4 x 1,025 tokens x 5,120 channels x 16
+    states, blocks from the shape: ONE ``tpu_custom_call``, named
+    ``%ssm_scan`` (``benchmark/layer_metrics/ssm_scan_roofline.py`` matches
+    it by that name), result ``[images, tokens, channels]``."""
+    import re
+
+    from benchmark.layer_metrics import ssm_scan_roofline
+    from ddim_cold_tpu.ops import selective_scan as ss
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, d, s = (SSM[k] for k in "nLds")
+    act, col = sds((n, L, d), dtype), sds((n, L, s), dtype)
+    text = jax.jit(ss.selective_scan).lower(
+        act, act, sds((d, s), jnp.float32), col, col, sds((d,), jnp.float32),
+        act).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert re.match(r"%ssm_scan(\.\d+)* = ", calls[0])
+    assert int(ssm_scan_roofline.NAME.match(calls[0]).group(2)) == n
