@@ -17,7 +17,9 @@ dims must be a multiple of the dtype's minimum tile (sublane × lane: f32
 (8, 128), bf16/f16 (16, 128), int8 (32, 128)) or span the whole array dim;
 the array dim must additionally be a multiple of the block (the in-tree
 pad-to-block-multiple policy — the exact invariant whose violation killed
-r04). The dequant matmul's dual-dtype K constraint (activation lane dim AND
+r04), except on the sublane axis of the kernels named in
+``RAGGED_SUBLANE_OK``, which are written for a token axis that ends inside
+the last block. The dequant matmul's dual-dtype K constraint (activation lane dim AND
 int8 weight sublane dim at once) needs no special case: the shared K block
 size appears in two block mappings, each checked against its own dtype.
 P001 also demands a fully STATIC grid: a ``np.int64`` grid entry silently
@@ -67,6 +69,17 @@ PIPELINE_BUFFERS = 2
 #: worst case (3072/2501 at bkv=1024) 1.228 — real geometry sits well
 #: under; a careless 2048-block at N=2501 (4096/2501 = 1.64) trips it.
 WASTE_THRESHOLD = 1.25
+
+
+#: kernels written for a token axis that ends INSIDE the last block. Mosaic
+#: accepts a partial final block whose shape is tile-legal: what a program
+#: reads past the edge is unspecified and what it writes there is dropped
+#: (compiled for TPU v5 lite and run on the chip at 2,501 tokens: PERF.md
+#: section 6, PR 27). The body has to make the unspecified part harmless —
+#: the flash forward masks K's columns and zeroes V's rows past the sequence,
+#: and a q row past it feeds only its own, dropped, output row. Every other
+#: kernel keeps the pad-to-block-multiple policy, on both axes.
+RAGGED_SUBLANE_OK = frozenset({"fwd"})
 
 
 def _round_up(n: int, m: int) -> int:
@@ -194,7 +207,9 @@ def check_tile_legality(call: KernelCall, entry: str,
                     f"{axis} block {blk} is neither a multiple of the "
                     f"{b.dtype} min-tile unit {unit} nor the whole array "
                     f"dim {arr}")
-            if blk and arr % blk:
+            ragged_ok = (axis == "sublane" and blk % unit == 0
+                         and call.name in RAGGED_SUBLANE_OK)
+            if blk and arr % blk and not ragged_ok:
                 problems.append(
                     f"{axis} array dim {arr} is not a multiple of block "
                     f"{blk} — a partial final block (the caller must pad "

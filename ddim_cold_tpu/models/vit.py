@@ -282,10 +282,15 @@ class Attention(nn.Module):
                 bias_init=nn.initializers.zeros_init(),
                 name=name,
             )
-        qkv = dense(3 * self.dim, self.qkv_bias, "qkv")(x)
+        packed = dense(3 * self.dim, self.qkv_bias, "qkv")(x)  # (B, N, 3C)
         # unpack order (3, heads, head_dim) matches the torch reshape
-        # (B,N,3,H,hd) so converted checkpoints line up slice-for-slice.
-        qkv = qkv.reshape(B, N, 3, self.num_heads, head_dim)
+        # (B,N,3,H,hd) so converted checkpoints line up slice-for-slice. The
+        # flash kernel relies on the same order: it reads q, k, v out of
+        # ``packed`` where the GEMM wrote them, at column offsets 0, C and 2C
+        # with head h of each at columns [h·hd, (h+1)·hd)
+        # (ops/flash_attention.flash_attention_qkv); the slices below are
+        # for the paths that want q, k, v apart.
+        qkv = packed.reshape(B, N, 3, self.num_heads, head_dim)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (B, N, H, hd)
 
         if seq_parallel and not need_weights and not weightless_ok:
@@ -380,11 +385,14 @@ class Attention(nn.Module):
                     *((self.flash_blocks[1],) if self.flash_blocks else ())
                 ).astype(self.dtype)
             else:
-                from ddim_cold_tpu.ops.flash_attention import flash_attention
+                from ddim_cold_tpu.ops.flash_attention import (
+                    flash_attention_qkv,
+                )
 
                 # None defers to the kernel's own defaults — one source of truth
-                out = flash_attention(
-                    q, k, v, scale, *(self.flash_blocks or ())).astype(self.dtype)
+                out = flash_attention_qkv(
+                    packed, self.num_heads, scale,
+                    *(self.flash_blocks or ())).astype(self.dtype)
             attn = None
         else:
             logits = jnp.einsum("bnhd,bmhd->bhnm", q, k) * scale

@@ -105,6 +105,8 @@ METRICS = (
     # -- kernels (ops/flash_attention.py, counted once a trace) -----------
     ("kernels.flash_fwd_schedule", "counter",
      "flash forward traces by schedule (key: resident|streamed)"),
+    ("kernels.flash_fwd_layout", "counter",
+     "flash forward traces by operand layout (key: in_place|head_major)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

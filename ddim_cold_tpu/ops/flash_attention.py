@@ -2,47 +2,71 @@
 
 The reference's attention is three separate cuDNN GEMMs with an O(N²) f32
 attention matrix materialized in HBM (ViT.py:110-114). Here the whole
-``softmax(q·kᵀ·scale)·v`` is one Pallas kernel: a grid over (batch·heads,
-query blocks, K/V blocks) where each program folds one K/V chunk into a
-running (max, denominator, accumulator) triple — the classic flash-attention
-online softmax; the logits never round-trip to HBM and the MXU sees two GEMMs
-per chunk. The K/V grid axis is innermost: TPU grids execute sequentially, so
-the VMEM scratch accumulators carry across the chunks of one (head, q-block)
-and are re-initialized when the chunk index wraps to 0.
+``softmax(q·kᵀ·scale)·v`` is one Pallas kernel: each program folds one K/V
+chunk into a running (max, denominator, accumulator) triple — the classic
+flash-attention online softmax; the logits never round-trip to HBM and the MXU
+sees two GEMMs per chunk and head. The K/V grid axis is innermost: TPU grids
+execute sequentially, so the VMEM scratch accumulators carry across the chunks
+of one q block and are re-initialized when the chunk index wraps to 0.
+
+**Layout: the forward reads its operands where the model's GEMMs wrote them.**
+The model holds q, k, v token-major — ``(B, N, H·D)``, or all three as the one
+``(B, N, 3·H·D)`` result of its qkv GEMM — and wants the context as
+``(B, N, H·D)`` for ``proj``. The forward's ``BlockSpec``s address exactly
+that: a block is ``(1, block, 128)`` at column block ``offset + g``, where
+``g`` is a *lane group* of ``128 // D`` whole heads (two at head size 64, one
+at 128, four at 32) and ``offset`` is 0, C/128, 2C/128 for q, k, v inside the
+packed projection; grid ``(B, H·D/128, q blocks, K/V chunks)``. Inside a
+program the heads of the group are told apart by lane masks, not lane slices
+(:func:`_fwd_kernel`), and the context goes back through the same blocks, so
+nothing is transposed, padded or sliced in HBM on either side of the kernel.
+The token axis is not padded either: it ends inside the last block, whose
+stale K columns are masked and V rows zeroed in the kernel (what a program
+reads past the edge is unspecified, what it writes there is dropped). Which
+shapes take this path is decided from the shape alone
+(:func:`_heads_per_lane_group`): ``128 % D == 0`` and ``H·D % 128 == 0``. Any
+other (head size 80 or 256; a single local head of 64 under Ulysses) is first
+laid out head-major by XLA — ``(B·H, N⁺, D⁺)``, one grid row a head, head size
+zero-padded to the lanes (:func:`_to_heads`) — and runs the SAME launch with
+one head a group, bit for bit the same context. ``kernels.flash_fwd_layout``
+counts which, ``in_place`` or ``head_major``, once a trace. The backward
+kernels still take head-major operands for every shape.
 
 The forward picks its blocks from what it can see — padded sequence length,
-padded head size, dtype (:func:`_fwd_blocks`); no model name, no flag:
+lanes, dtype, heads a lane group (:func:`_fwd_blocks`); no model name, no flag:
 
-* **K/V resident** — wherever one head's K and V and a (block_q, N) f32 score
-  tile fit the scoped VMEM (:func:`_fwd_vmem_bytes`; the 200px/p4 trunk's
-  2,501 tokens do, at block_q 512; in bf16 the rule holds to about 10,000
-  tokens at block_q 128) the whole padded sequence is ONE chunk. The K/V
-  block index then does not change across a head's q blocks, so each head's
-  K and V are fetched once, and a launch is batch·heads × query blocks
-  programs. On the v5e at 1,152 heads × 2,501 tokens × head size 64 in bf16
-  (one launch of the ``flower200_sample_k20`` cell) that is 5,760 programs
-  against the 57,600 of the (256, 512) it replaced as the default
-  (PERF.md section 6, PR 25, has each part's time on the chip).
+* **K/V resident** — wherever a lane group's K and V and its (block_q, N) f32
+  score tiles fit the scoped VMEM (:func:`_fwd_vmem_bytes`; the 200px/p4
+  trunk's 2,501 tokens do, at block_q 512 in bf16) the whole padded sequence
+  is ONE chunk. The K/V block index then does not change across a lane
+  group's q blocks, so its K and V are fetched once, and a launch is
+  images × lane groups × query blocks programs. On the v5e at 288 images × 4
+  heads × 2,501 tokens × head size 64 in bf16 (one launch of the
+  ``flower200_sample_k20`` cell) that is 2,880 programs of two heads each
+  (PERF.md section 6, PR 25 and PR 27, has each part's time on the chip).
 * **streamed** — explicit blocks with more than one K/V chunk, and every
   sequence too long for the above, at (256, 512). VMEM is bounded by the
   block sizes, not the sequence length.
 
-A power-of-two ``scale`` (head size 64) is folded into q, (block_q, D)
+A power-of-two ``scale`` (head size 64) is folded into q, (block_q, 128)
 multiplies in place of (block_q, block_kv), bit for bit the same scores.
 
-Autodiff: the custom VJP is flash all the way through. The VJP's forward
-additionally emits the per-row log-sum-exp (the undifferentiated call, which
-is all a sampler makes, launches the kernel without that result and saves
-its 1.5 GB write a launch at the sampler cell's shape); the backward runs
-two more Pallas kernels — dq (grid like the forward) and dk/dv (grid
-transposed: K/V blocks outer, q chunks streamed innermost) — that rebuild
-probabilities from the saved lse chunk by chunk, so the O(N²) matrix never
-exists in HBM in either direction. Residuals are (q, k, v, o, lse): O(N·D) —
-the whole train-step memory story for long sequences is bounded. (In-kernel,
-lse rides a 128-lane-replicated layout because TPU tiling rejects (1, bq) row
-blocks; the replication is sliced off / re-broadcast outside the kernels so
-the residual itself stays one lane. See _fwd_kernel._emit.) The backward
-kernels always stream, at (256, 512) unless blocks are given.
+Autodiff: ONE custom VJP (:func:`_attention`) under both entries,
+:func:`flash_attention` (q, k, v apart) and :func:`flash_attention_qkv` (the
+packed projection), flash all the way through. The VJP's forward additionally
+emits the per-row log-sum-exp (the undifferentiated call, which is all a
+sampler makes, launches the kernel without that result and its write); the
+backward runs two more Pallas kernels — dq (grid like the head-major forward)
+and dk/dv (grid transposed: K/V blocks outer, q chunks streamed innermost) —
+that rebuild probabilities from the saved lse chunk by chunk, so the O(N²)
+matrix never exists in HBM in either direction. Residuals are the operands as
+the forward read them (the packed projection stays packed), the context and
+lse: O(N·D) — the whole train-step memory story for long sequences is bounded.
+(In-kernel, lse rides a 128-lane-replicated layout because TPU tiling rejects
+(1, bq) row blocks; the replication is sliced off / re-broadcast outside the
+kernels so the residual itself stays one lane a row and head. See
+_fwd_kernel._emit.) The backward kernels always stream, at (256, 512) unless
+blocks are given.
 
 On the CPU backend the kernels run in interpreter mode, so tests exercise
 the identical code paths; any other non-TPU backend is an error — a caller
@@ -67,7 +91,8 @@ from ddim_cold_tpu.utils import flops, profiling
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: last dim of VMEM tiles
 
-#: which forward schedule each trace engaged (``kernels.flash_fwd_schedule``)
+#: which schedule and which operand layout each trace of the forward engaged
+#: (``kernels.flash_fwd_schedule``, ``kernels.flash_fwd_layout``)
 _kernels = metrics.scope("kernels")
 
 #: kernel revision stamped into bench records: "bf16-gemm-v2" = GEMMs in
@@ -109,13 +134,22 @@ def _scale_folds_into_q(scale: float) -> bool:
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, n_valid: int,
-                block_kv: int, n_kv: int):
-    """One (head, q-block, kv-block) program: fold this K/V chunk into the
-    running softmax state; emit o = acc/l and (where the launch has that
-    result: ``rest`` is then lse, acc, m, l) lse = m + log l on the last."""
+                block_kv: int, n_kv: int, heads: int, zero_v_tail: bool):
+    """One (row, lane group, q-block, kv-block) program: fold this K/V chunk
+    into the running softmax state of each of the ``heads`` heads that share
+    the block's lanes (one head on the head-major path); emit o = acc/l and
+    (where the launch has that result: ``rest`` is then lse, acc, m, l)
+    lse = m + log l on the last chunk.
+
+    Heads of a lane group are told apart without slicing lanes: head ``h``'s
+    q tile has the other heads' lanes zeroed, so the contraction over all the
+    lanes adds exact zeros to its scores (as the head-major path's zero
+    padding does), and ``p_h · v`` over the whole V tile holds head ``h``'s
+    context in its own lanes, which alone are selected into the accumulator.
+    One (bq, bkv) f32 score tile is live at a time, heads in sequence."""
     lse_ref = rest[0] if len(rest) == 4 else None
     acc_ref, m_ref, l_ref = rest[-3:]
-    kv_i = pl.program_id(2)
+    kv_i = pl.program_id(3)
 
     @pl.when(kv_i == 0)
     def _init():
@@ -128,45 +162,71 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, n_valid: int,
     # path (an explicit f32 upcast here costs ~4× MXU throughput on v5e and
     # doubles VMEM traffic); for f32 inputs it is bit-identical to the old
     # explicit-upcast form. Softmax stays f32 either way.
-    q = q_ref[0]  # (bq, D)
-    k = k_ref[0]  # (bkv, D)
+    q = q_ref[0]  # (bq, lanes)
+    k = k_ref[0]  # (bkv, lanes)
+    v = v_ref[0]
     fold = _scale_folds_into_q(scale)
     if fold:
-        q = q * scale  # (bq, D) multiplies instead of (bq, bkv)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (bq, bkv) f32
-    if not fold:
-        logits = logits * scale
-    col = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    logits = jnp.where(col < n_valid, logits, _NEG_INF)
+        q = q * scale  # (bq, lanes) multiplies instead of (bq, bkv)
+    if zero_v_tail:
+        # a ragged last chunk: rows past the sequence hold whatever the
+        # buffer held (K's are masked below; p there is an exact 0, and
+        # 0 × garbage is NaN)
+        row = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(row < n_valid, v, jnp.zeros_like(v))
+    head_dim = q.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
 
-    # online softmax update (the same math the ring-attention steps use,
-    # parallel/ring_attention.py:62-71, here per VMEM chunk)
-    m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (bq, 1) replicated
-    l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)  # (bq, bkv)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    # p rounds to v's dtype for the MXU (f32 accumulate); exact for f32 v,
-    # ≤1 bf16 ulp per product for bf16 v — inside the model's own precision
-    pv = jnp.dot(p.astype(v_ref.dtype), v_ref[0],
-                 preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    def own(h):  # the lanes of head h, (bq, lanes)
+        return (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+
+    for h in range(heads):
+        q_h = q if heads == 1 else jnp.where(own(h), q, jnp.zeros_like(q))
+        logits = jax.lax.dot_general(
+            q_h, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bq, bkv) f32
+        if not fold:
+            logits = logits * scale
+        col = kv_i * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        logits = jnp.where(col < n_valid, logits, _NEG_INF)
+
+        # online softmax update (the same math the ring-attention steps use,
+        # parallel/ring_attention.py:62-71, here per VMEM chunk)
+        m_prev = jnp.max(m_ref[h], axis=-1, keepdims=True)  # (bq, 1) replicated
+        l_prev = jnp.max(l_ref[h], axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)  # (bq, bkv)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        # p rounds to v's dtype for the MXU (f32 accumulate); exact for f32
+        # v, ≤1 bf16 ulp per product for bf16 v — inside the model's own
+        # precision
+        acc = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc if heads == 1 else jnp.where(own(h), acc,
+                                                        acc_ref[...])
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(kv_i == n_kv - 1)
     def _emit():
-        m = jnp.max(m_ref[...], axis=-1, keepdims=True)
-        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        def per_lane(stat):  # head h's (bq, 1) statistic on head h's lanes
+            tile = jnp.broadcast_to(stat(0), lane.shape)
+            for h in range(1, heads):
+                tile = jnp.where(own(h), stat(h), tile)
+            return tile
+
+        m = lambda h: jnp.max(m_ref[h], axis=-1, keepdims=True)  # noqa: E731
+        l = lambda h: jnp.max(l_ref[h], axis=-1, keepdims=True)  # noqa: E731
+        o_ref[0] = (acc_ref[...] / per_lane(l)).astype(o_ref.dtype)
         if lse_ref is not None:
-            # lane-replicated (bq, LANE): a (1, bq) row block would violate
-            # the TPU (8, 128) tile rule — Mosaic rejects sublane-dim-1 blocks
-            # unless they equal the array dim (hit at N=2501 on real hardware)
-            lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
+            # lane-replicated (bq, LANE), head h's value on head h's lanes
+            # (the lanes of an accumulator wider than LANE hold one head):
+            # a (1, bq) row block would violate the TPU (8, 128) tile rule —
+            # Mosaic rejects sublane-dim-1 blocks unless they equal the
+            # array dim (hit at N=2501 on real hardware)
+            lse_ref[0, 0] = per_lane(lambda h: m(h) + jnp.log(l(h)))[:, :_LANE]
 
 
 def _sds(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
@@ -191,13 +251,47 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 def _to_heads(x, B, N, H, D):
     """(B, N, H, D) → (B·H, N⁺, D⁺): one grid row per head's sequence,
     lane-aligned head dim (zero columns are inert in q·kᵀ and produce zero
-    output columns, sliced off at the end), sublane-aligned N."""
+    output columns, sliced off at the end), sublane-aligned N. The backward
+    kernels' layout, and the forward's for the shapes it cannot address in
+    place (:func:`_heads_per_lane_group`): every call is a transpose and a
+    pad in HBM."""
     x = x.transpose(0, 2, 1, 3).reshape(B * H, N, D)
     x = _pad_to(x, 2, _LANE)
     return _pad_to(x, 1, 8)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _heads_per_lane_group(num_heads: int, head_dim: int) -> int | None:
+    """How many heads share one 128-lane column block of the token-major
+    ``(B, N, H·D)`` layout, where the forward can address q, k, v and the
+    context in place: whole heads fill the lanes (head sizes 32, 64, 128) and
+    the heads fill whole column blocks. ``None`` for every other shape (head
+    size 80 or 256; one local head of 64 under Ulysses): those are laid out
+    head-major first (:func:`_to_heads`)."""
+    if _LANE % head_dim == 0 and (num_heads * head_dim) % _LANE == 0:
+        return _LANE // head_dim
+    return None
+
+
+def _unpack(operands, num_heads: int):
+    """The ``(B, N, H, D)`` views of q, k, v: of three ``(B, N, H·D)`` arrays,
+    or of one ``(B, N, 3·H·D)`` projection packed ``(3, heads, head_dim)``
+    along its columns (models/vit.Attention's qkv)."""
+    if len(operands) == 1:
+        B, N, W = operands[0].shape
+        qkv = operands[0].reshape(B, N, 3, num_heads, W // (3 * num_heads))
+        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    return tuple(x.reshape(*x.shape[:2], num_heads, -1) for x in operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _attention(operands, num_heads, scale, block_q, block_kv):
+    """The one differentiable attention both entries call: ``operands`` is
+    ``(q, k, v)``, each ``(B, N, H·D)``, or the packed ``(qkv,)``; the
+    context comes back ``(B, N, H·D)``."""
+    return _flash_forward(operands, num_heads, scale, block_q, block_kv,
+                          with_lse=False)[0]
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -208,19 +302,48 @@ def flash_attention(
 ) -> jax.Array:
     """Fused non-causal multi-head attention.
 
-    q/k/v: ``(B, N, H, D)`` (the model's head layout, ViT.py:104-107);
-    returns ``(B, N, H, D)`` in q's dtype. Softmax runs in float32 regardless
-    of input dtype, matching the einsum path bit-for-bit up to GEMM precision.
+    q/k/v: ``(B, N, H, D)`` (the model's head layout, ViT.py:104-107), three
+    arrays of their own; returns ``(B, N, H, D)`` in q's dtype. Softmax runs
+    in float32 regardless of input dtype, matching the einsum path
+    bit-for-bit up to GEMM precision. A ``(B, N, H, D)`` array IS the
+    token-major ``(B, N, H·D)`` the forward reads in place (head sizes 32,
+    64, 128 with ``H·D`` a multiple of 128); any other shape is transposed
+    and padded to head-major first, with the same result. A caller that
+    holds q, k, v as one projection ``(B, N, 3·H·D)`` calls
+    :func:`flash_attention_qkv` and never slices it.
 
     Blocks left ``None`` are chosen from the shape (:func:`_fwd_blocks`): the
     forward takes one head's whole K and V as a single VMEM-resident chunk
     wherever that fits, and streams K/V chunks (VMEM ≈ (block_q +
-    2·block_kv)·D_padded input tiles plus the f32 accumulator, independent of
-    N) where it does not; the backward tiles at (256, 512). Explicit blocks are honoured, forward and backward. This
-    undifferentiated call writes no log-sum-exp; under ``jax.grad`` the
-    forward of the VJP does.
+    2·block_kv)·128-lane input tiles plus the f32 accumulator, independent of
+    N) where it does not; the backward tiles at (256, 512). Explicit blocks
+    are honoured, forward and backward. This undifferentiated call writes no
+    log-sum-exp; under ``jax.grad`` the forward of the VJP does.
     """
-    return _flash_forward(q, k, v, scale, block_q, block_kv, with_lse=False)[0]
+    B, N, H, D = q.shape
+    out = _attention(tuple(x.reshape(B, N, H * D) for x in (q, k, v)), H,
+                     scale, block_q, block_kv)
+    return out.reshape(B, N, H, D)
+
+
+def flash_attention_qkv(
+    qkv: jax.Array,
+    num_heads: int,
+    scale: float,
+    block_q: int | None = None,
+    block_kv: int | None = None,
+) -> jax.Array:
+    """:func:`flash_attention` on the projection as the qkv GEMM wrote it.
+
+    qkv: ``(B, N, 3·C)`` whose columns unpack as ``(3, heads, head_dim)``
+    (q's heads, then k's, then v's: models/vit.Attention); returns the
+    context ``(B, N, C)``, which is what ``proj`` reads. Where the forward
+    addresses its operands in place it is handed the one array three times
+    with column-block offsets 0, C/128 and 2C/128, so q, k and v are never
+    cut out of it; the same values, blocks and VJP as :func:`flash_attention`
+    on the three slices.
+    """
+    return _attention((qkv,), num_heads, scale, block_q, block_kv)
 
 
 def kernel_interpret() -> bool:
@@ -260,61 +383,105 @@ def per_device(call, in_specs, out_specs):
                          check_vma=False)
 
 
-def _fwd_call(qh, kh, vh, *, scale, n_valid, bq, bkv, with_lse, interpret):
-    """The forward launch on head-major operands: the context first, then
-    (``with_lse``) the lane-replicated log-sum-exp. With one K/V chunk the
-    K/V block index is the same for every q block of a head, so the pipeline
-    fetches a head's K and V once: that is the resident schedule."""
-    BH, Nq, Dp = qh.shape
-    n_kv = kh.shape[1] // bkv
-    kernel = functools.partial(_fwd_kernel, scale=scale, n_valid=n_valid,
-                               block_kv=bkv, n_kv=n_kv)
-    q_spec = pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0))
-    out_specs, out_shape = [q_spec], [_sds(qh.shape, qh.dtype, qh)]
+def _fwd_call(q, k, v, *, offsets, groups, heads, lanes, out_tokens, scale,
+              n_valid, bq, bkv, with_lse, interpret):
+    """The forward launch. q, k, v are ``(rows, tokens, columns)`` arrays (one
+    array handed over three times when the projection is packed), read in
+    ``(1, block, lanes)`` blocks at column block ``offsets[i] + g`` for lane
+    group ``g`` of ``groups``, each holding ``heads`` heads; the token axis
+    may end inside the last block. Results: the context ``(rows, out_tokens,
+    groups·lanes)``, written through the same blocks, then (``with_lse``) the
+    log-sum-exp ``(rows, groups, padded tokens, 128)``, head ``h`` of the
+    group on its own lanes. With one K/V chunk the K/V block index is the
+    same for every q block of a lane group, so the pipeline fetches its K and
+    V once: that is the resident schedule."""
+    rows = q.shape[0]
+    n_q, n_kv = pl.cdiv(out_tokens, bq), pl.cdiv(k.shape[1], bkv)
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, n_valid=n_valid, block_kv=bkv, n_kv=n_kv,
+        heads=heads, zero_v_tail=k.shape[1] % bkv != 0)
+    q_off, k_off, v_off = offsets
+    out_specs = [pl.BlockSpec((1, bq, lanes), lambda b, g, i, j: (b, i, g))]
+    out_shape = [_sds((rows, out_tokens, groups * lanes), q.dtype, q)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)))
-        out_shape.append(_sds((BH, Nq, _LANE), jnp.float32, qh))
+        out_specs.append(pl.BlockSpec((1, 1, bq, _LANE),
+                                      lambda b, g, i, j: (b, g, i, 0)))
+        out_shape.append(_sds((rows, groups, n_q * bq, _LANE), jnp.float32, q))
     with profiling.scope("flash_attention/fwd"):
         return tuple(pl.pallas_call(
             kernel,
-            grid=(BH, Nq // bq, n_kv),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            grid=(rows, groups, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, bq, lanes),
+                             lambda b, g, i, j: (b, i, q_off + g)),
+                pl.BlockSpec((1, bkv, lanes),
+                             lambda b, g, i, j: (b, j, k_off + g)),
+                pl.BlockSpec((1, bkv, lanes),
+                             lambda b, g, i, j: (b, j, v_off + g)),
+            ],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
-                pltpu.VMEM((bq, Dp), jnp.float32),    # output accumulator
-                pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
-                pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
+                pltpu.VMEM((bq, lanes), jnp.float32),         # output accumulator
+                pltpu.VMEM((heads, bq, _LANE), jnp.float32),  # running max
+                pltpu.VMEM((heads, bq, _LANE), jnp.float32),  # running denominator
             ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
             ),
             interpret=interpret,
             name="fwd",
-        )(qh, kh, vh))
+        )(q, k, v))
 
 
-def _flash_forward(q, k, v, scale, block_q, block_kv, *, with_lse):
-    """``(out, lse)``; ``lse`` is ``None`` unless ``with_lse`` (the VJP's
-    forward), and then one lane: O(N) across the backward, not O(N·128)."""
+def _flash_forward(operands, num_heads, scale, block_q, block_kv, *, with_lse):
+    """``(context (B, N, H·D), lse)`` of ``operands`` as :func:`_attention`
+    takes them; ``lse`` is ``None`` unless ``with_lse`` (the VJP's forward),
+    and then ``(B·H, padded tokens)``, one lane: O(N) across the backward,
+    not O(N·128)."""
     interpret = kernel_interpret()
-    B, N, H, D = q.shape
-    qh, kh, vh = (_to_heads(x, B, N, H, D) for x in (q, k, v))
-    BH, Np, Dp = qh.shape
-    bq, bkv = _fwd_blocks(block_q, block_kv, Np, Dp, qh.dtype)
-    qh = _pad_to(qh, 1, bq)
-    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
+    packed = len(operands) == 1
+    B, N, W = operands[0].shape
+    C = W // 3 if packed else W
+    H, D = num_heads, C // num_heads
+    in_place = _heads_per_lane_group(H, D)
+    _kernels.inc("kernels.flash_fwd_layout",
+                 key="in_place" if in_place else "head_major")
+    if in_place:
+        # where the projection wrote them: nothing is moved, and the token
+        # axis ends inside the last block. Blocks as the head-major path
+        # picks them, so the two are bit for bit each other's
+        arrays = operands * 3 if packed else operands
+        offsets = tuple(i * C // _LANE for i in range(3)) if packed else (0,) * 3
+        rows, groups, heads, lanes, out_tokens = B, C // _LANE, in_place, _LANE, N
+        bq, bkv = _fwd_blocks(block_q, block_kv, tiling.round_up(N, 8), lanes,
+                              arrays[0].dtype, heads)
+    else:
+        qh, kh, vh = (_to_heads(x, B, N, H, D) for x in _unpack(operands, H))
+        bq, bkv = _fwd_blocks(block_q, block_kv, *qh.shape[1:], qh.dtype)
+        arrays = (_pad_to(qh, 1, bq), _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv))
+        offsets = (0,) * 3
+        rows, groups, heads = B * H, 1, 1
+        out_tokens, lanes = arrays[0].shape[1:]
     _kernels.inc("kernels.flash_fwd_schedule",
-                 key="resident" if kh.shape[1] == bkv else "streamed")
-    rows = rows_spec(BH)
+                 key="resident" if N <= bkv else "streamed")
+    spec = rows_spec(rows)
     out, *lse = per_device(
-        functools.partial(_fwd_call, scale=scale, n_valid=N, bq=bq, bkv=bkv,
-                          with_lse=with_lse, interpret=interpret),
-        (rows, rows, rows), (rows,) * (1 + with_lse))(qh, kh, vh)
+        functools.partial(
+            _fwd_call, offsets=offsets, groups=groups, heads=heads,
+            lanes=lanes, out_tokens=out_tokens, scale=scale, n_valid=N, bq=bq,
+            bkv=bkv, with_lse=with_lse, interpret=interpret),
+        (spec, spec, spec), (spec,) * (1 + with_lse))(*arrays)
 
-    out = out[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
-    return out, (lse[0][:, :, 0] if with_lse else None)
+    if not in_place:
+        out = out[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
+        out = out.reshape(B, N, C)
+    if with_lse:
+        # (rows, groups, tokens⁺, 128), head h on its lanes → (B·H, tokens⁺)
+        lse = lse[0][..., ::_LANE // heads].transpose(0, 1, 3, 2)
+        return out, lse.reshape(B * H, -1)
+    return out, None
 
 
 # ---------------------------------------------------------------------------
@@ -427,44 +594,62 @@ def _bwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
     return int(max(dq, dkv) + rows + live)
 
 
-def _fwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
+def _fwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int,
+                    heads: int = 1) -> int:
     """Scoped VMEM the forward needs at blocks (bq, bkv = the whole padded
-    sequence): the double-buffered q, o, K and V blocks, four lane-replicated
-    f32 rows (the lse result double-buffered, the running max and
-    denominator) and ONE live (bq, bkv) f32 score tile (the compiler keeps the
-    mask, exp and the cast to the GEMM feed in place, and the accumulator
-    costs nothing measurable beside them). An upper bound, within 0.2 to
-    1.8 MiB, of the sizes the v5e compiler reports where it refuses (bf16 and
-    f32, bq 128 to 1024, 2,560 to 14,336 rows, with and without lse) —
-    tests/test_chip_compile.py compiles what this admits, at its edge too."""
-    blocks = 4 * (bq + bkv) * dp * itemsize + 4 * bq * _LANE * 4
-    return blocks + 4 * bq * bkv + (1 << 17)
+    sequence) with ``heads`` heads on the block's lanes: the double-buffered
+    q, o, K and V blocks, the lane-replicated f32 rows (the lse result
+    double-buffered, each head's running max and denominator) and one live
+    (bq, bkv) f32 score tile A HEAD (for one head the compiler keeps the mask,
+    exp and the cast to the GEMM feed in place, and the accumulator costs
+    nothing measurable beside them; the heads of a lane group it interleaves,
+    the next one's score GEMM under this one's softmax, and then holds 2.0 of
+    2 and 3.6 of 4 tiles). An upper bound, within 0.2 to 2.2 MiB (4.4 at four
+    heads), of the sizes the v5e compiler reports where it refuses (bf16 and
+    f32, bq 128 to 1024, 2,560 to 14,336 rows, one, two and four heads, with
+    and without lse) — tests/test_chip_compile.py compiles what this admits,
+    at its edge too."""
+    blocks = 4 * (bq + bkv) * dp * itemsize + (2 + 2 * heads) * bq * _LANE * 4
+    return blocks + heads * 4 * bq * bkv + (1 << 17)
 
 
-def _fwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype) -> tuple:
+def _fwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
+                heads: int = 1) -> tuple:
     """The forward's (block_q, block_kv), Mosaic-legal for this dtype and
     padded sequence (ops/tiling.py; min() alone produced illegal tiles at odd
-    requests or sub-16 sublanes on bf16, N=2501 is the worst case). Explicit
-    blocks win. With ``block_kv`` left ``None`` the whole sequence, padded to
-    the lane width, is one chunk — K and V resident, a lane-dense score tile —
-    at the largest ``block_q`` of 512, 256, 128 (or the one given) that the
-    VMEM model admits; where none fits, the streamed (256, 512). Lane width
-    and not the sublane minimum because of ONE shape: at 2,501 tokens it is
-    the 2,560 rows the backward pads K and V to as well, so the 200px training
-    step pads them once (2,512 rows made it pad twice and cost dp4 3 %:
-    PERF.md section 6, PR 25). At other lengths forward and backward still
-    pad K and V apart (1,025 tokens: 1,152 and 1,536 rows); that belongs to
-    the backward kernels' issue (ROADMAP Speed item 2b)."""
+    requests or sub-16 sublanes on bf16, N=2501 is the worst case), for
+    ``heads`` heads on the block's lanes. Explicit blocks win. With
+    ``block_kv`` left ``None`` the whole sequence, padded to the lane width, is
+    one chunk — K and V resident, a lane-dense score tile — at the largest
+    ``block_q`` of 512, 256, 128 (or the one given) that the VMEM model
+    admits; where none fits, the streamed (256, 512). An explicit ``block_kv``
+    that covers the sequence asks for the same schedule, and ``block_q`` is
+    then halved until the model admits it (float32 with two heads on the lanes
+    at 2,501 tokens: 512 → 256). Lane width and not the sublane minimum
+    because of ONE shape: at 2,501 tokens it is the 2,560 rows the backward
+    pads K and V to as well, so where the forward still pads (the head-major
+    layout) the 200px training step pads them once (2,512 rows made it pad
+    twice and cost dp4 3 %: PERF.md section 6, PR 25). At other lengths
+    forward and backward still pad K and V apart (1,025 tokens: 1,152 and
+    1,536 rows); that belongs to the backward kernels' issue (ROADMAP Speed
+    item 2b)."""
     isz = jnp.dtype(dtype).itemsize
+
+    def fits(bq, bkv):
+        return _fwd_vmem_bytes(bq, bkv, dp, isz, heads) <= _SCOPED_VMEM_BYTES
+
     if block_kv is None:
         whole = tiling.round_up(n_pad, _LANE)
         for want in ((512, 256, 128) if block_q is None else (block_q,)):
             bq = tiling.legal_block(want, n_pad, dtype)
-            if _fwd_vmem_bytes(bq, whole, dp, isz) <= _SCOPED_VMEM_BYTES:
+            if fits(bq, whole):
                 return bq, whole
     block_q, block_kv = _default_blocks(block_q, block_kv)
-    return (tiling.legal_block(block_q, n_pad, dtype),
-            tiling.legal_block(block_kv, n_pad, dtype))
+    bq = tiling.legal_block(block_q, n_pad, dtype)
+    bkv = tiling.legal_block(block_kv, n_pad, dtype)
+    while bkv >= n_pad and not fits(bq, bkv) and bq > tiling.sublane_unit(dtype):
+        bq = tiling.legal_block(bq // 2, n_pad, dtype)
+    return bq, bkv
 
 
 def _default_blocks(block_q, block_kv) -> tuple:
@@ -647,17 +832,26 @@ def _dense_attention_f32(q, k, v, scale):
     return p, jnp.einsum("bhnm,bmhd->bnhd", p, v.astype(jnp.float32))
 
 
-def _flash_fwd(q, k, v, scale, block_q, block_kv):
-    out, lse = _flash_forward(q, k, v, scale, block_q, block_kv, with_lse=True)
-    return out, (q, k, v, out, lse)
+def _attention_fwd(operands, num_heads, scale, block_q, block_kv):
+    out, lse = _flash_forward(operands, num_heads, scale, block_q, block_kv,
+                              with_lse=True)
+    return out, (operands, out, lse)
 
 
-def _flash_bwd(scale, block_q, block_kv, residuals, g):
-    q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv)
+def _attention_bwd(num_heads, scale, block_q, block_kv, residuals, g):
+    """The residuals stay as the forward read them (the packed projection is
+    kept packed): the backward lays q, k, v out head-major once, from them."""
+    operands, o, lse = residuals
+    B, N, C = o.shape
+    split = lambda x: x.reshape(B, N, num_heads, -1)  # noqa: E731
+    grads = _flash_backward(*_unpack(operands, num_heads), split(o), lse,
+                            split(g), scale, block_q, block_kv)
+    if len(operands) == 1:  # (B, N, 3, H, D) is the projection's column order
+        return ((jnp.stack(grads, axis=2).reshape(operands[0].shape),),)
+    return (tuple(x.reshape(B, N, C) for x in grads),)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 # ---------------------------------------------------------------------------

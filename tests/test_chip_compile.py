@@ -85,16 +85,24 @@ def _params(model, sds):
 
 # --- cases: name → builder(devices) → (fn, args, custom calls expected) -----
 
-def _flash_fwd(dtype, blocks, with_lse=False, n=N):
+def _flash_fwd(dtype, blocks, with_lse=False, n=N, packed=False):
     """The forward at explicit blocks, or (a ``None``) at those the kernel
-    picks from the shape; ``with_lse`` is the launch the VJP's forward makes."""
+    picks from the shape; ``with_lse`` is the launch the VJP's forward makes.
+    4 heads of 64: q, k, v are read in place, two heads a lane group, from
+    three ``(rows, n, 256)`` arrays or (``packed``) from the one
+    ``(rows, n, 768)`` projection, and the token axis ends inside the last
+    block."""
     def build(devices):
         sds = _struct(SingleDeviceSharding(devices[0]))
-        q = sds((ROWS, n, H, D), dtype)
-        return (lambda q, k, v: fa._flash_forward(
-                    q, k, v, D ** -0.5, *blocks, with_lse=with_lse),
-                (q, q, q), 1)
+        operands = ((sds((ROWS, n, 3 * C), dtype),) if packed
+                    else (sds((ROWS, n, C), dtype),) * 3)
+        return (lambda *operands: fa._flash_forward(
+                    operands, H, D ** -0.5, *blocks, with_lse=with_lse),
+                operands, 1)
     return build
+
+
+LANE_GROUP = fa._heads_per_lane_group(H, D)  # 2: pure arithmetic
 
 
 def _admitted(dtype, n=N):
@@ -102,7 +110,8 @@ def _admitted(dtype, n=N):
     sequence as one K/V chunk (pure arithmetic: safe at import)."""
     n_pad = -(-n // 8) * 8
     return [bq for bq in (1024, 512, 256, 128)
-            if fa._fwd_blocks(bq, None, n_pad, 128, dtype)[1] >= n_pad]
+            if fa._fwd_blocks(bq, None, n_pad, 128, dtype,
+                              LANE_GROUP)[1] >= n_pad]
 
 
 def _flash_grad(dtype):
@@ -210,9 +219,10 @@ CASES = {
     **{f"flash_fwd-{np.dtype(dt).name}-{bq}x{bkv}": _flash_fwd(dt, (bq, bkv))
        for dt in (jnp.float32, jnp.bfloat16)
        for bq, bkv in ((256, 512), fa.NS_FLASH_BLOCKS)},
-    **{f"flash_fwd-{np.dtype(dt).name}-auto{'-lse' * lse}":
-       _flash_fwd(dt, (None, None), with_lse=lse)
-       for dt in (jnp.float32, jnp.bfloat16) for lse in (False, True)},
+    **{f"flash_fwd-{np.dtype(dt).name}-auto{'-lse' * lse}{'-packed' * pk}":
+       _flash_fwd(dt, (None, None), with_lse=lse, packed=pk)
+       for dt in (jnp.float32, jnp.bfloat16) for lse in (False, True)
+       for pk in (False, True)},
     **{f"flash_fwd-{np.dtype(dt).name}-{bq}xwhole": _flash_fwd(
            dt, (bq, None), with_lse=True)
        for dt in (jnp.float32, jnp.bfloat16) for bq in _admitted(dt)},
@@ -254,9 +264,10 @@ def test_fwd_vmem_model_admits_only_what_compiles(bq, dtype, chip):
     ``_fwd_vmem_bytes`` still admits this block_q with K and V resident must
     compile, lse and all — the model is fitted to this compiler's refusals,
     so a drift shows here and not as a refused kernel on the chip."""
-    n = max(n for n in range(2560, 16384, 128)
-            if fa._fwd_blocks(bq, None, n, 128, dtype) == (bq, n))
-    assert fa._fwd_blocks(bq, None, n + 128, 128, dtype) == (bq, 512)
+    n = max(n for n in range(1024, 16384, 128)
+            if fa._fwd_blocks(bq, None, n, 128, dtype, LANE_GROUP) == (bq, n))
+    assert fa._fwd_blocks(bq, None, n + 128, 128, dtype,
+                          LANE_GROUP) == (bq, 512)
     fn, args, _ = _flash_fwd(jnp.dtype(dtype), (bq, None), with_lse=True,
                              n=n)(chip)
     assert jax.jit(fn).lower(*args).compile().as_text().count(
@@ -294,6 +305,35 @@ def test_flash_kernels_keep_their_instruction_names(program, kernels, chip):
         r"%(\w+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
         text)}
     assert names == kernels
+
+
+def test_forward_leaves_attention_operands_where_the_gemms_wrote_them(chip):
+    """What the in-place forward is for, read off the compiled depth-1 200px
+    forward: the kernel's operands are the qkv GEMM's ``[images, 2501, 768]``
+    result and its first result is the ``[images, 2501, 256]`` context that
+    ``proj`` reads, and NO instruction beside it produces a head-major or
+    head-split array — ``[images·heads, tokens, 64 or 128]``, ``[images,
+    heads, tokens, 64]``, ``[images, tokens, heads, 64]`` — as the ``copy``,
+    ``pad`` and ``slice`` instructions of the head-major layout did (42 % of
+    the sampler cell's device time: PERF.md section 6, PR 27)."""
+    import re
+
+    text = _forward_depth1(chip)
+    results = re.findall(
+        r"^\s*(?:ROOT )?%([\w.-]+) = \(?\w+\[([\d,]+)\]", text, re.M)
+    tokens = range(N, 2560 + 1)  # true to lane-padded
+
+    found = {name: dims for name, dims in results  # a head's columns last
+             if int(dims.split(",")[-1]) in (D, 128)
+             and any(int(d) in tokens for d in dims.split(",")[:-1])}
+    assert not found, found
+    fwd = re.search(r"%fwd(?:\.\d+)* = \(?(\w+)\[([\d,]+)\][^\n]*?"
+                    r"custom-call\(([^)]*)\)", text)
+    assert fwd.group(2) == f"{ROWS},{N},{C}"
+    operands = {op.strip() for op in fwd.group(3).split(",")}
+    assert len(operands) == 1, operands  # the projection, three times
+    assert re.search(re.escape(operands.pop())
+                     + rf" = \w+\[{ROWS},{N},{3 * C}\]", text)
 
 
 # --- the selective scan at Jamba2-3B's published shape ----------------------

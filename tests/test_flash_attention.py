@@ -16,6 +16,22 @@ def _rand_qkv(rng, B, N, H, D):
     return tuple(jax.random.normal(k, (B, N, H, D), jnp.float32) for k in ks)
 
 
+def _forward(q, k, v, scale, bq, bkv, *, with_lse, packed=False):
+    """``flash_attention._flash_forward`` on ``(B, N, H, D)`` q, k, v, handed
+    over as three token-major arrays or (``packed``) as the one projection the
+    model's qkv GEMM writes: → (context ``(B, N, H, D)``, lse)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B, N, H, D = q.shape
+    if packed:
+        operands = (jnp.stack([q, k, v], axis=2).reshape(B, N, 3 * H * D),)
+    else:
+        operands = tuple(x.reshape(B, N, H * D) for x in (q, k, v))
+    out, lse = fa._flash_forward(operands, H, scale, bq, bkv,
+                                 with_lse=with_lse)
+    return out.reshape(B, N, H, D), lse
+
+
 @pytest.mark.parametrize("N", [8, 257, 320])
 def test_flash_matches_dense(N):
     """257 = the OxfordFlower-64 sequence (odd, needs padding); 320 aligned."""
@@ -78,22 +94,20 @@ def test_resident_forward_matches_scaled_scores_streamed_and_dense(
     tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" else dict(
         rtol=2e-2, atol=2e-2)
 
-    with_lse, lse = fa._flash_forward(q, k, v, scale, bq, None, with_lse=True)
-    without, none = fa._flash_forward(q, k, v, scale, bq, None,
-                                      with_lse=False)
+    with_lse, lse = _forward(q, k, v, scale, bq, None, with_lse=True)
+    without, none = _forward(q, k, v, scale, bq, None, with_lse=False)
     assert none is None
     np.testing.assert_array_equal(np.asarray(with_lse, np.float32),
                                   np.asarray(without, np.float32))
     with monkeypatch.context() as patch:
         patch.setattr(fa, "_scale_folds_into_q", lambda scale: False)
-        old, old_lse = fa._flash_forward(q, k, v, scale, bq, None,
-                                         with_lse=True)
+        old, old_lse = _forward(q, k, v, scale, bq, None, with_lse=True)
     np.testing.assert_array_equal(np.asarray(without, np.float32),
                                   np.asarray(old, np.float32))
     np.testing.assert_array_equal(np.asarray(lse), np.asarray(old_lse))
 
-    chunked, chunked_lse = fa._flash_forward(q, k, v, scale, *streamed,
-                                             with_lse=True)
+    chunked, chunked_lse = _forward(q, k, v, scale, *streamed,
+                                    with_lse=True)
     _, dense = _dense_attention_f32(q, k, v, scale)
     for want in (chunked, dense):
         np.testing.assert_allclose(np.asarray(without, np.float32),
@@ -148,18 +162,154 @@ def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
     assert fa._fwd_blocks(None, None, 6512, 128, jnp.bfloat16)[0] == 256
     assert fa._fwd_blocks(None, None, 10000, 128, jnp.bfloat16)[0] == 128
 
-    assert traced(2501) == ({"resident": 1}, {"fwd": (1, 5, 1)})
+    assert traced(2501) == ({"resident": 1}, {"fwd": (1, 1, 5, 1)})
     assert traced(2501, dtype=jnp.float32) == ({"resident": 1},
-                                               {"fwd": (1, 5, 1)})
-    assert traced(32768) == ({"streamed": 1}, {"fwd": (1, 128, 64)})
-    assert traced(2501, 256, 512) == ({"streamed": 1}, {"fwd": (1, 10, 5)})
+                                               {"fwd": (1, 1, 5, 1)})
+    assert traced(32768) == ({"streamed": 1}, {"fwd": (1, 1, 128, 64)})
+    assert traced(2501, 256, 512) == ({"streamed": 1}, {"fwd": (1, 1, 10, 5)})
     assert traced(2501, *fa.NS_FLASH_BLOCKS) == ({"resident": 1},
-                                                 {"fwd": (1, 5, 1)})
-    assert traced(4096, 512, 512) == ({"streamed": 1}, {"fwd": (1, 8, 8)})
+                                                 {"fwd": (1, 1, 5, 1)})
+    assert traced(4096, 512, 512) == ({"streamed": 1}, {"fwd": (1, 1, 8, 8)})
     # under grad: the VJP's forward is resident, dq and dk/dv tile (256, 512)
     assert traced(2501, grad=True) == (
         {"resident": 1},
-        {"fwd": (1, 5, 1), "dq": (1, 10, 5), "dkv": (1, 5, 10)})
+        {"fwd": (1, 1, 5, 1), "dq": (1, 10, 5), "dkv": (1, 5, 10)})
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["primal", "lse"])
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+@pytest.mark.parametrize("blocks", [(None, None), (64, 128)],
+                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("N", [8, 257, 300])
+@pytest.mark.parametrize("D", [32, 64, 128])
+def test_in_place_forward_is_bitwise_the_head_major_forward(
+        D, N, dtype, blocks, packed, with_lse, monkeypatch):
+    """The forward reading q, k, v where the projection wrote them — several
+    heads on the 128 lanes, the token axis ending inside the last block (the
+    interpreter fills what lies past it with NaN) — against the same shape
+    transposed and zero-padded to head-major first: the context BITWISE, and
+    (``with_lse``) the log-sum-exp of every true row. Handed over as three
+    arrays (``flash_attention``'s form) or as the one packed projection
+    (``flash_attention_qkv``'s)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    H = 2 * 128 // D  # two lane groups
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(41, 2, N, H, D))
+    scale = D ** -0.5
+    before = fa._kernels.by_key("kernels.flash_fwd_layout")
+    ours, lse = _forward(q, k, v, scale, *blocks, with_lse=with_lse,
+                         packed=packed)
+    after = fa._kernels.by_key("kernels.flash_fwd_layout")
+    assert after["in_place"] - before.get("in_place", 0) == 1
+    assert after.get("head_major", 0) == before.get("head_major", 0)
+    with monkeypatch.context() as patch:  # the rule says no: as before
+        patch.setattr(fa, "_heads_per_lane_group", lambda heads, head_dim: None)
+        want, want_lse = _forward(q, k, v, scale, *blocks, with_lse=with_lse)
+    assert fa._kernels.by_key("kernels.flash_fwd_layout")["head_major"] == (
+        before.get("head_major", 0) + 1)
+    assert ours.dtype == q.dtype and np.isfinite(
+        np.asarray(ours, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(ours, np.float32),
+                                  np.asarray(want, np.float32))
+    if with_lse:
+        assert lse.shape == want_lse.shape == (2 * H, lse.shape[1])
+        np.testing.assert_array_equal(np.asarray(lse[:, :N]),
+                                      np.asarray(want_lse[:, :N]))
+    else:
+        assert lse is None and want_lse is None
+
+
+@pytest.mark.parametrize("H,D,layout", [
+    (4, 64, "in_place"),     # the 200px trunk: two heads a lane group
+    (12, 32, "in_place"),    # vit_tiny: four heads a lane group
+    (2, 128, "in_place"),    # one head a lane group
+    (1, 64, "head_major"),   # one local head under Ulysses: H·D = 64
+    (3, 32, "head_major"),   # heads do not fill the last column block
+    (4, 80, "head_major"),   # 128 % 80
+    (2, 256, "head_major"),  # a head wider than the lanes
+])
+def test_forward_layout_is_chosen_from_the_shape(H, D, layout, monkeypatch):
+    """``128 % D == 0`` and ``H·D % 128 == 0``: q, k, v and the context are
+    addressed where they lie, grid (images, lane groups, q blocks, chunks);
+    anything else is laid out head-major, grid (images·heads, 1, q blocks,
+    chunks); 300 tokens are one q block and one resident chunk. Asked
+    of the trace alone — nothing runs — for both entries, primal and VJP, and
+    ``kernels.flash_fwd_layout`` says which."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B, N = 2, 300
+    grids, real = [], fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        if kw.get("name") == "fwd":
+            grids.append(tuple(kw["grid"]))
+        return real(kernel, **kw)
+
+    x = jax.ShapeDtypeStruct((B, N, H, D), jnp.bfloat16)
+    packed = jax.ShapeDtypeStruct((B, N, 3 * H * D), jnp.bfloat16)
+    apart = lambda q, k, v: fa.flash_attention(  # noqa: E731
+        q, k, v, 0.125).astype(jnp.float32).sum()
+    one = lambda qkv: fa.flash_attention_qkv(  # noqa: E731
+        qkv, H, 0.125).astype(jnp.float32).sum()
+    before = fa._kernels.by_key("kernels.flash_fwd_layout")
+    with monkeypatch.context() as patch:
+        patch.setattr(fa.pl, "pallas_call", spy)
+        jax.eval_shape(apart, x, x, x)
+        jax.eval_shape(one, packed)
+        jax.eval_shape(jax.grad(apart, argnums=(0, 1, 2)), x, x, x)
+        assert jax.eval_shape(jax.grad(one), packed).shape == packed.shape
+    after = fa._kernels.by_key("kernels.flash_fwd_layout")
+    assert {key: after[key] - before.get(key, 0) for key in after
+            if after[key] != before.get(key, 0)} == {layout: 4}
+    want = (B, H * D // 128, 1, 1) if layout == "in_place" else (
+        B * H, 1, 1, 1)
+    assert grids == [want] * 4
+
+
+@pytest.mark.parametrize("dtype,tol", [
+    ("float32", dict(rtol=1e-4, atol=1e-5)),
+    ("bfloat16", dict(rtol=2e-2, atol=2e-2)),
+])
+@pytest.mark.parametrize("N,blocks", [(33, (None, None)), (300, (64, 128))])
+def test_packed_entry_gradient_matches_dense(N, blocks, dtype, tol):
+    """``flash_attention_qkv`` under ``jax.grad`` — the in-place forward with
+    its lse, the head-major backward kernels reading q, k, v out of the packed
+    residual, the three gradients stacked back into the projection's column
+    order — against autodiff through the dense einsum on the same slices, at
+    the tolerances of the (q, k, v) entry's gradient tests; and the (q, k, v)
+    entry's own gradients BITWISE the packed entry's columns."""
+    from ddim_cold_tpu.ops.flash_attention import flash_attention_qkv
+
+    B, H, D = 1, 4, 32
+    scale = D ** -0.5
+    qkv = jnp.stack(_rand_qkv(43, B, N, H, D), axis=2).reshape(
+        B, N, 3 * H * D).astype(dtype)
+    unpack = lambda x: [x.reshape(B, N, 3, H, D)[:, :, i]  # noqa: E731
+                        for i in range(3)]
+
+    def loss_packed(qkv):
+        out = flash_attention_qkv(qkv, H, scale, *blocks)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def loss_apart(qkv):
+        out = flash_attention(*unpack(qkv), scale, *blocks)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def loss_dense(qkv):
+        out = _dense_attention_f32(*unpack(qkv), scale)[1]
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    ours = jax.grad(loss_packed)(qkv)
+    assert ours.shape == qkv.shape and ours.dtype == qkv.dtype
+    np.testing.assert_array_equal(
+        np.asarray(ours, np.float32),
+        np.asarray(jax.grad(loss_apart)(qkv), np.float32))
+    want = jax.grad(loss_dense)(qkv)
+    for name, got, ref in zip("qkv", unpack(ours), unpack(want)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   err_msg=f"d{name}", **tol)
 
 
 def test_flash_bf16_inputs():

@@ -86,6 +86,31 @@ def test_p001_whole_dim_span_is_legal():
     assert _check(closed) == []
 
 
+@pytest.mark.parametrize("name,shape,block,grid,rules", [
+    # the flash forward's token axis ends inside the last block: legal
+    ("fwd", (2501, 128), (512, 128), (5,), []),
+    ("fwd", (2501, 128), (2560, 128), (1,), []),
+    # ... only as a tile-multiple block, only on the sublane axis
+    ("fwd", (2501, 128), (100, 128), (26,), ["GRAFT-P001"]),
+    ("fwd", (2504, 320), (8, 128), (313,), ["GRAFT-P001"]),
+    # ... and only for the kernels whose bodies mask the ragged tail
+    ("dq", (2501, 128), (512, 128), (5,), ["GRAFT-P001"]),
+    (None, (2501, 128), (512, 128), (5,), ["GRAFT-P001"]),
+])
+def test_p001_partial_final_block_only_where_the_kernel_masks_it(
+        name, shape, block, grid, rules):
+    def f(x):
+        return pl.pallas_call(
+            _copy_kernel, out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+            grid=grid, in_specs=[pl.BlockSpec(block, lambda i: (i, 0))],
+            out_specs=pl.BlockSpec(block, lambda i: (i, 0)),
+            **({"name": name} if name else {}))(x)
+
+    closed = jax.make_jaxpr(f)(jax.ShapeDtypeStruct(shape, jnp.float32))
+    assert _rules_of(_check(closed)) == rules
+    assert "fwd" in kernel_checks.RAGGED_SUBLANE_OK
+
+
 # --------------------------------------------------------------- P002
 
 
