@@ -43,7 +43,7 @@ MODEL_CONFIG = "oxford_flower_200_p4"             # the MODEL_CONFIGS entry it i
 K = 100                          # sampler stride: 2000 / 100 = 20 steps
 TRAIN_STEPS = 8                  # optimizer steps in the one smoke epoch
 VAL_BATCHES = 2
-SERVE_BUCKETS = (8, 32)          # bench.py's off-smoke serving buckets
+SERVE_BUCKETS = (8, 32)          # a small and a large bucket
 #: the serving contract is bitwise equality with direct sampling. Where the
 #: chip does not give that, rows may differ by at most four bfloat16 ulps of
 #: the model's [−1, 1] output, i.e. 4 · 2⁻⁸ / 2 in the [0, 1] images compared.

@@ -64,12 +64,6 @@ HOST_ONLY_MODULES = ("ddim_cold_tpu/serve/batching.py",
                      "ddim_cold_tpu/obs/metrics.py",
                      "ddim_cold_tpu/obs/spans.py",
                      "ddim_cold_tpu/obs/device.py",
-                     # trace attribution + the trend gate parse artifacts
-                     # after the fact — often in CI or on a laptop that
-                     # never saw the device; importing jax there would drag
-                     # a backend init into every report render
-                     "ddim_cold_tpu/obs/attrib.py",
-                     "ddim_cold_tpu/obs/trend.py",
                      # the process boundary: the parent-side RPC handle and
                      # autoscaler never touch a device, and the replica
                      # server must boot to its hello without one — engine

@@ -41,9 +41,9 @@ Rules:
 * **X003 warmup-set soundness** — the configs serving actually warms are
   inside the lattice: every ``workloads.default_edit_configs`` member (at
   preview 0 and 2) constructs AND its D1 projection is sweep-witnessed;
-  every literal ``SamplerConfig(...)`` call site in ``bench.py``
-  constructs once non-literal kwargs are substituted from per-axis
-  representatives.
+  every literal ``SamplerConfig(...)`` call site in the files outside the
+  package that construct one (``CONFIG_SITE_FILES``) constructs once
+  non-literal kwargs are substituted from per-axis representatives.
 * **X004 hybrid refusals** — the hybrid state-space trunk
   (``models/hybrid.py``) refuses by name every config class that reaches
   into ``Block`` and admits the rest: it adds no program class.
@@ -85,22 +85,25 @@ _CACHE_POINTS = (
 #: (param-routing only: same program, so it adds no D1 class)
 _STEP_POINTS = ((0, False), (1, False), (4, False), (2, True))
 
+#: the files outside the package that construct a ``SamplerConfig(...)``
+#: (read, never edited by the checks); X003(b) evaluates their sites
+CONFIG_SITE_FILES = (
+    "chip_smoke.py",
+    "benchmark/drivers/serve_open.py",
+)
+
 #: modules X002c scans for frozen-config bypasses
 _BYPASS_SCAN = (
     "ddim_cold_tpu/serve",
     "ddim_cold_tpu/workloads",
     "ddim_cold_tpu/train",
-    "bench.py",
+    *CONFIG_SITE_FILES,
 )
 
-#: substitutes for non-literal kwargs at bench.py SamplerConfig sites —
-#: one in-lattice representative per axis (X003's constant-blind quotient:
-#: WHICH value a sweep variable takes never changes legality)
-_BENCH_REPRESENTATIVES = {
-    "k": 10, "t_start": 999, "levels": 4, "cache_interval": 2,
-    "cache_threshold": 0.05, "cache_tokens": 3, "steps": 2,
-    "sp_degree": 2, "preview_every": 2,
-}
+#: substitutes for the kwargs those sites leave non-literal — one
+#: in-lattice representative per axis (X003's constant-blind quotient:
+#: WHICH value a variable takes never changes legality)
+_SITE_REPRESENTATIVES = {"k": 10}
 
 
 def _sampler_config():
@@ -447,10 +450,11 @@ def _literal(node):
     return False, None
 
 
-def _bench_config_sites(source: str) -> list:
+def _config_sites(source: str) -> list:
     """(lineno, kwargs) for each evaluable ``SamplerConfig(...)`` call:
-    literal kwargs kept, known sweep variables substituted from
-    representatives, sites with splats/positional args skipped."""
+    literal kwargs kept, known variables substituted from representatives,
+    sites with splats/positional args skipped (``serve_open.py`` builds its
+    config from the traffic file: nothing to evaluate statically)."""
     sites = []
     for node in ast.walk(ast.parse(source)):
         if not isinstance(node, ast.Call):
@@ -468,8 +472,8 @@ def _bench_config_sites(source: str) -> list:
             lit, value = _literal(kw.value)
             if lit:
                 kwargs[kw.arg] = value
-            elif kw.arg in _BENCH_REPRESENTATIVES:
-                kwargs[kw.arg] = _BENCH_REPRESENTATIVES[kw.arg]
+            elif kw.arg in _SITE_REPRESENTATIVES:
+                kwargs[kw.arg] = _SITE_REPRESENTATIVES[kw.arg]
             else:
                 ok = False
                 break
@@ -479,8 +483,9 @@ def _bench_config_sites(source: str) -> list:
 
 
 def check_warmup_soundness(root=None, sweep=None) -> list:
-    """X003: everything serving warms or bench constructs is in-lattice
-    (and, for the edit set, sweep-witnessed on the D1 plane)."""
+    """X003: everything serving warms, and every config a file of
+    ``CONFIG_SITE_FILES`` constructs, is in-lattice (and, for the edit set,
+    sweep-witnessed on the D1 plane)."""
     if root is None:
         from ddim_cold_tpu.analysis.cli import repo_root
 
@@ -517,21 +522,21 @@ def check_warmup_soundness(root=None, sweep=None) -> list:
                     f"preview_every={preview} but its program class "
                     f"{proj} has no sweep witness"))
 
-    # (b) bench.py literal construction sites all build in-lattice
-    # configs (excluded-quant/fused/sp sites still CONSTRUCT — only
-    # their trace coverage lives elsewhere, so no coverage demand here)
-    bench = os.path.join(root, "bench.py")
-    if os.path.isfile(bench):
-        with open(bench) as f:
-            sites = _bench_config_sites(f.read())
+    # (b) construction sites outside the package all build in-lattice
+    # configs (they only have to CONSTRUCT: no coverage demand here)
+    for rel in CONFIG_SITE_FILES:
+        path = os.path.join(root, rel)
+        if not os.path.isfile(path):
+            continue
+        with open(path) as f:
+            sites = _config_sites(f.read())
         for lineno, kwargs in sites:
             if try_config(**kwargs) is None:
                 findings.append(Finding(
-                    "GRAFT-X003", "bench.py", f"bench.py:{lineno}",
-                    lineno,
-                    f"bench.py SamplerConfig site at line {lineno} "
+                    "GRAFT-X003", rel, f"{rel}:{lineno}", lineno,
+                    f"{rel} SamplerConfig site at line {lineno} "
                     f"(kwargs {kwargs}) is rejected by the validation "
-                    "gate — the benchmark constructs an illegal config"))
+                    "gate — it constructs an illegal config"))
     return findings
 
 

@@ -495,7 +495,7 @@ def serve_signatures(ctx: Context, findings: list | None = None,
 #: the north-star model the kernels/memory layers prove statically
 NS_MODEL = "oxford_flower_200_p4"
 NS_TOKENS = 2501   # (200/4)² patches + cls — the N Mosaic rejected on r04
-NS_ROWS = 16       # the bench's north-star batch
+NS_ROWS = 16       # the 200px batch the reference ships
 NS_K = 20          # the north-star DDIM step count
 
 _FLASH_PATH = "ddim_cold_tpu/ops/flash_attention.py"
@@ -504,10 +504,10 @@ _QUANT_PATH = "ddim_cold_tpu/ops/quant.py"
 
 def kernel_entries() -> list[Entry]:
     """First-class 200px entries (N=2501; f32, bf16, w8a16): the full
-    sampler scans the bench's north-star legs dispatch — every in-tree
+    sampler scans at 200px — every in-tree
     pallas_call at the EXACT geometry that crashed r04 — plus standalone
     flash forward/grad traces per (dtype, block config) covering the
-    backward dq/dkv kernels and every ``--flash-block-sweep`` row, and the
+    backward dq/dkv kernels and every ``FLASH_BLOCK_SWEEP`` row, and the
     dequant-pallas kernel at the 200px trunk GEMM shapes. The TINY serve
     sweep contains zero pallas_calls (it serves quant="xla" only), so
     these entries ARE the kernels layer's real coverage.
@@ -528,7 +528,7 @@ def kernel_entries() -> list[Entry]:
     # these feed BOTH layers (P over their pallas_calls, M over the scan).
     # The fused variants dispatch the trunk megakernels (fused attention +
     # fused Mlp, ops/flash_attention.py + ops/quant.py) so P001–P003/
-    # M001–M002 certify the exact programs bench --fusion runs.
+    # M001–M002 certify the fused programs too.
     base = DiffusionViT(dtype=jnp.bfloat16, use_flash=True,
                         flash_blocks=NS_FLASH_BLOCKS, **cfg)
     H, W = base.img_size
@@ -553,7 +553,7 @@ def kernel_entries() -> list[Entry]:
             meta=dict(mem)))
 
     # few-step distilled serving at the north star (ISSUE 17): the k=4
-    # student program the --fewstep bench leg dispatches — 3-trip schedule
+    # student program — 3-trip schedule
     # scan + the final jump-to-clean forward — so the P-rules certify its
     # pallas calls and the M-rules its peak-HBM at the 200px geometry
     entries.append(Entry(
@@ -660,8 +660,7 @@ def kernel_entries() -> list[Entry]:
 
 def kernel_traces() -> dict:
     """``name → (entry, closed_jaxpr)`` for the 200px registry — the
-    shared input of the kernels/memory layers and bench's static
-    memory-budget leg."""
+    shared input of the kernels/memory layers."""
     return {e.name: (e, e.trace()) for e in kernel_entries()}
 
 

@@ -120,7 +120,7 @@ RULES = {
                   "program-build gates disagree, a distill-producible "
                   "student count is unservable, or a frozen config is "
                   "mutated past the gate via object.__setattr__",
-    "GRAFT-X003": "warm-set/bench config outside the legal lattice (or "
+    "GRAFT-X003": "warm-set/entry-point config outside the legal lattice (or "
                   "warmed without a sweep witness) — serving would warm or "
                   "benchmark a program the lattice proofs never saw",
     "GRAFT-X004": "the hybrid trunk admits a config class that reaches into "

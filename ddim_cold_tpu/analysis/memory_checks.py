@@ -264,9 +264,8 @@ def run_memory_checks(serve_traces: dict | None = None,
 
 def budget_report(kernel_traces: dict | None = None,
                   device_kind: str = DEVICE_KIND) -> dict:
-    """JSON-ready static budget summary for bench's ``submetrics.memory``:
-    per-200px-program peak HBM GiB and per-kernel VMEM MiB, worst-case
-    rollups first so obs/trend.py can band them."""
+    """JSON-ready static budget summary: per-200px-program peak HBM GiB and
+    per-kernel VMEM MiB, worst-case rollups first."""
     from ddim_cold_tpu.analysis import entries, kernel_checks
     from ddim_cold_tpu.utils import flops
 

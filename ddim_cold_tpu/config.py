@@ -69,9 +69,8 @@ class ExperimentConfig:
     honor_diff_step: bool = False
     mesh: Optional[dict[str, int]] = None
     use_flash: "bool | str" = False  # False | True (Pallas) | "xla" (blockwise)
-    # Pallas kernel (block_q, block_kv) override; None = kernel defaults.
-    # The bench's --flash-block-sweep measures candidates — pin its winner
-    # here (e.g. ``flash_blocks: [512, 1024]`` in the 200px yaml).
+    # Pallas kernel (block_q, block_kv) override, e.g. ``flash_blocks:
+    # [512, 1024]`` in the 200px yaml; None = the kernel picks from the shape.
     flash_blocks: Optional[tuple] = None
     use_sincos_pos: bool = False
     sp_mode: str = "ring"  # seq-parallel strategy: ring | ulysses

@@ -251,7 +251,7 @@ def cached_sampler_guard(
 
     Returns a dict with ``fid_exact_vs_cached``, ``max_abs_pixel_delta``
     (worst per-pixel divergence across every paired batch) and the sampler
-    configuration, ready to land in a bench record.
+    configuration.
     """
     from ddim_cold_tpu.ops import sampling
 
@@ -470,8 +470,7 @@ def superres_consistency_guard(outputs, low_res) -> dict:
     whole convention stack end to end: the nearest-index math, the value
     mapping, and (served) that every row was projected against ITS OWN
     request's input — a row swap, a bucket-padding leak, or a resampled
-    index table all break bit-exactness. ``bench.py --edit`` rides this and
-    raises when ``bit_exact`` is False.
+    index table all break bit-exactness.
 
     Returns ``{"bit_exact", "max_abs_delta", "anchor_pixels"}`` —
     ``max_abs_delta`` is also a useful RAW-output quality metric (how far
@@ -494,4 +493,107 @@ def superres_consistency_guard(outputs, low_res) -> dict:
         "bit_exact": bool(np.array_equal(down, target)),
         "max_abs_delta": round(float(np.max(np.abs(down - target))), 6),
         "anchor_pixels": int(down[0, ..., 0].size),
+    }
+
+
+# ---------------------------------------------------------------------------
+# trend series (scripts/fid_trend.py): thinning, per-point deltas, provenance
+# ---------------------------------------------------------------------------
+
+#: a point-to-point FID change inside this relative band is read as noise
+REL_FLOOR = 0.1
+#: band = max(REL_FLOOR, BAND_K · median |successive relative delta|)
+BAND_K = 3.0
+
+
+def thin(seq, max_points: int) -> list:
+    """Evenly thin to ≤ ``max_points``, always keeping first and last."""
+    seq = list(seq)
+    if max_points <= 0 or len(seq) <= max_points:
+        return seq
+    if max_points == 1:
+        return [seq[0]]
+    step = (len(seq) - 1) / (max_points - 1)
+    idx = sorted({round(i * step) for i in range(max_points)})
+    return [seq[i] for i in idx]
+
+
+def _noise_band(prior_values) -> float:
+    """Relative band for "is the newest delta noise": ``BAND_K`` × the median
+    absolute successive relative delta over the prior series, floored at
+    ``REL_FLOOR`` (a 1–2 point history has no measurable spread)."""
+    deltas = [abs((b - a) / a) for a, b in zip(prior_values,
+                                               prior_values[1:]) if a]
+    if not deltas:
+        return REL_FLOOR
+    return max(REL_FLOOR, BAND_K * float(np.median(deltas)))
+
+
+def annotate_deltas(rows, value_key: str, lower_is_better: bool = False) -> list:
+    """Copy ``rows`` (dicts carrying ``value_key``) with per-point
+    ``delta_rel`` / ``band`` / ``in_band`` annotations: a point is out of
+    band when it is worse than its predecessor by more than the noise band
+    of the points before it."""
+    out = []
+    vals: list = []
+    for row in rows:
+        row = dict(row)
+        v = row.get(value_key)
+        if isinstance(v, (int, float)) and vals:
+            band = _noise_band(vals)
+            prev = vals[-1]
+            delta = (float(v) - prev) / abs(prev) if prev else 0.0
+            worse = delta > band if lower_is_better else delta < -band
+            row.update(delta_rel=round(delta, 4), band=round(band, 4),
+                       in_band=not worse)
+        if isinstance(v, (int, float)):
+            vals.append(float(v))
+        out.append(row)
+    return out
+
+
+def run_metadata(chip=None) -> dict:
+    """The provenance stamp a trend artifact carries (``run_meta``): git
+    sha, device kind, jax/jaxlib versions, round, and an EXTERNALLY-supplied
+    timestamp.
+
+    The timestamp comes from ``DDIM_COLD_RUN_TS`` (seconds since epoch) or
+    ``SOURCE_DATE_EPOCH``, never from the wall clock here — an unstamped
+    environment yields ``None`` rather than a value that would make re-runs
+    nondeterministic."""
+    import os
+    import subprocess
+    from importlib.metadata import PackageNotFoundError, version
+
+    here = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    try:
+        sha = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
+                             cwd=here, capture_output=True, text=True,
+                             timeout=10).stdout.strip() or None
+    except (OSError, subprocess.SubprocessError):  # no git / not a checkout
+        sha = None
+
+    def _version(dist):
+        try:
+            return version(dist)
+        except PackageNotFoundError:
+            return None
+
+    ts = None
+    raw_ts = (os.environ.get("DDIM_COLD_RUN_TS")
+              or os.environ.get("SOURCE_DATE_EPOCH") or "").strip()
+    if raw_ts:
+        try:
+            ts = float(raw_ts)
+        except ValueError:
+            ts = raw_ts  # ISO strings still order lexicographically
+    rnd = os.environ.get("DDIM_COLD_ROUND", "").strip()
+    return {
+        "git_sha": sha,
+        "device_kind": chip,
+        "jax": _version("jax"),
+        "jaxlib": _version("jaxlib"),
+        "timestamp": ts,
+        "round": int(rnd) if rnd.isdigit() else None,
     }

@@ -112,14 +112,6 @@ METRICS = (
      "selective-scan traces by path (key: kernel|xla)"),
     # -- fault injection --------------------------------------------------
     ("faults.injected", "counter", "realized fault injections (key: site)"),
-    # -- attribution / trend (obs.attrib / obs.trend, host-side) ----------
-    ("attrib.traces", "counter", "profiler traces attributed"),
-    ("attrib.coverage_pct", "gauge",
-     "device-busy % attributed to registered scopes (last trace)"),
-    ("attrib.device_busy_s", "gauge",
-     "device-busy seconds in the last attributed trace"),
-    ("trend.points", "gauge", "series points loaded by the trend gate"),
-    ("trend.checks", "counter", "trend-gate checks by outcome (key: status)"),
 )
 
 _KINDS = {name: kind for name, kind, _ in METRICS}

@@ -33,7 +33,7 @@ Ids come from ``itertools.count`` — the same run produces the same ids.
 
 Export: :func:`export_chrome` renders the ticket traces as Chrome
 trace-event JSON (microseconds), :func:`export_jsonl` as one JSON object
-per line (seconds). ``scripts/obs_report.py`` is the CLI over both.
+per line (seconds).
 """
 
 from __future__ import annotations
@@ -302,7 +302,7 @@ class Recorder:
 
     def export_jsonl(self, path: Optional[str] = None) -> list:
         """One row per ticket-trace span, ``t0``/``t1`` in seconds of the
-        recorder's clock (what ``scripts/obs_report.py`` reads)."""
+        recorder's clock."""
         rows = [{
             "trace_id": s.trace_id, "span_id": s.span_id,
             "parent_id": s.parent_id, "name": s.name,

@@ -86,7 +86,7 @@ def _batch_constrain(mesh, batch_axis):
     zero communication, but left to the partitioner's cost model under a
     dp×tp×sp mesh it can pick a W-sharded layout for the gather and then hit
     "Involuntary full rematerialization" resharding into the attention layout
-    (replicate-the-tensor fallback — MULTICHIP_r02 tail). Identity when no
+    (the replicate-the-tensor fallback). Identity when no
     mesh is given or the axis isn't in it (single-chip callers)."""
     if mesh is None or batch_axis not in getattr(mesh, "axis_names", ()):
         return lambda a: a

@@ -95,29 +95,21 @@ _LANE = 128  # TPU lane width: last dim of VMEM tiles
 #: (``kernels.flash_fwd_schedule``, ``kernels.flash_fwd_layout``)
 _kernels = metrics.scope("kernels")
 
-#: kernel revision stamped into bench records: "bf16-gemm-v2" = GEMMs in
-#: input dtype with f32 MXU accumulation; "fused-trunk-v3" adds the
-#: quant-aware fused trunk attention (qkv dequant-GEMM as in-kernel producer,
-#: proj GEMM as in-kernel consumer — see :func:`fused_trunk_attention`). The
-#: unfused kernels are untouched by v3: their numerics are bit-identical to v2.
+#: names the GEMM dtype contract of the kernels (operands in the input dtype,
+#: f32 MXU accumulation; v3 added :func:`fused_trunk_attention`):
+#: ``tests/test_flash_attention.py`` pins that contract to this string, so a
+#: change of contract has to change both
 KERNEL_REV = "fused-trunk-v3"
 
-#: (block_q, block_kv) the FUSED trunk path falls back to at 2,501 tokens
-#: (``tuning.attn_blocks``) and ``bench.py``'s sweep quotes; any block_kv ≥ N
-#: is clamped to the padded sequence, i.e. one chunk. It was the best row of
-#: the r05 on-chip sweep under the old f32-GEMM kernel (7.48 img/s against
-#: 5.78 at 256×512: a record of that kernel, not of this one). The unfused
-#: ``flash_attention`` no longer needs it: left to itself it now picks the
-#: same geometry from the shape (512 × whole sequence at N=2501: PERF.md
-#: section 6, PR 25). Lives here (not bench.py) so the graftcheck kernels
-#: layer and the CPU tile-rule guard verify the EXACT geometry the bench
-#: dispatches — bench re-exports both names.
+#: (block_q, block_kv) the fused trunk path falls back to where
+#: ``tuning.attn_blocks`` has no tuned row; a block_kv ≥ N is clamped to the
+#: padded sequence, i.e. one chunk. The unfused ``flash_attention`` picks its
+#: blocks from the shape (``_fwd_blocks``) and does not read it
 NS_FLASH_BLOCKS = (512, 4096)
 
-#: bench --flash-block-sweep configs for the 200px north-star kernel tuning;
-#: tests/test_flash_attention.py and the graftcheck kernels layer pre-check
-#: every entry against Mosaic's tile rules before it can burn a slot in the
-#: one hardware window
+#: the block geometries ``analysis/entries.kernel_entries`` and
+#: ``tests/test_flash_attention.py`` pre-check against Mosaic's tile rules
+#: at 2,501 tokens
 FLASH_BLOCK_SWEEP = ((512, 512), (256, 1024), (256, 4096), (512, 4096))
 
 

@@ -61,8 +61,8 @@ from ddim_cold_tpu.ops import tiling
 from ddim_cold_tpu.ops.flash_attention import kernel_interpret
 from ddim_cold_tpu.utils import profiling
 
-#: quantization revision stamped into bench records (mirrors KERNEL_REV;
-#: scripts/perf_tables.py renders it). "w8a16-pcq-v1" = per-output-channel
+#: quantization revision, reported by ``eval/fid.quantized_sampler_guard``
+#: (``quant_rev``). "w8a16-pcq-v1" = per-output-channel
 #: symmetric int8 weights, [−127, 127] codes, f32-accumulated dequant matmul.
 #: "w8a16-fused-v2" adds the fused trunk kernels (mlp_pallas here, the fused
 #: attention in ops/flash_attention.py) and the optional "w8a8" activation

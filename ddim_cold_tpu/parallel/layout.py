@@ -1,5 +1,4 @@
-"""Mesh-axis → parameter-layout/apply-fn selection, shared by the trainer
-and the parallelism bench so they always measure the same wiring.
+"""Mesh-axis → parameter-layout/apply-fn selection for the trainer.
 
 * a ``pipe`` axis: stacked-blocks params sharded stage-per-device +
   the GPipe pipelined apply_fn (parallel/pipeline.py);

@@ -29,8 +29,8 @@ ulysses all-to-all pair, per the model's ``sp_mode`` (pipe×sp).
 
 Known backend quirk: a BF16 tp-psum inside this partially-manual shard_map
 CHECK-fails in XLA's *CPU* AllReducePromotion pass (process abort) — f32
-runs fine everywhere, and TPU handles bf16 all-reduce natively; the
-virtual-CPU parallelism bench pins amp off for its pipe×tp row.
+runs fine everywhere, and TPU handles bf16 all-reduce natively; a
+virtual-CPU run of pipe×tp has to keep amp off.
 """
 
 from __future__ import annotations

@@ -650,7 +650,7 @@ def remote_factory(spec: dict, *, env: Optional[dict] = None,
     blocks until the child connects and sends its hello (deadline
     ``spawn_timeout_s``). Spawn wall time lands on the handle as
     ``spawn_s`` and in ``health()``; ``on_spawn(replica_id, spawn_s)`` is
-    the bench's hook for the warm-vs-cold spawn table.
+    the caller's hook for the same number.
     """
     spec = dict(spec)
 

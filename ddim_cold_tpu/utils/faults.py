@@ -4,8 +4,8 @@ robustness layer.
 The serving engine (serve/engine.py), the checkpoint writer
 (utils/checkpoint.py) and the data loader (data/loader.py) each call
 :func:`fire` at their named fault sites. With nothing armed, ``fire`` is a
-flag check and a dict read — the fast path executes byte-identical device
-code and the bench's faults-disarmed leg pins zero throughput overhead.
+flag check and a dict read, and the fast path executes byte-identical
+device code.
 Armed (a scoped :func:`inject` context or the ``DDIM_COLD_FAULTS`` env var),
 each matching spec draws from its OWN seeded RNG on a per-site call counter,
 so a chaos run's injection sequence is a pure function of (specs, call

@@ -8,9 +8,7 @@ those ride the VPU and are fused into the GEMM pipeline; standard MFU practice
 counts MXU FLOPs only.
 
 Peak numbers are per-chip bf16 dense (not sparse) from published TPU specs,
-keyed by ``jax.devices()[0].device_kind`` so the bench JSON can name the
-hardware it ran on (BENCH vs_baseline is otherwise cross-hardware
-apples-to-oranges — VERDICT round 1).
+keyed by ``jax.devices()[0].device_kind``.
 """
 
 from __future__ import annotations
@@ -210,76 +208,3 @@ def mfu(flops_per_step: float, step_seconds: float, device_kind: str,
     if peak is None or step_seconds <= 0:
         return None
     return flops_per_step / (step_seconds * peak * 1e12 * n_devices)
-
-
-def vit_scope_costs(*, img_size=(64, 64), patch_size=8, embed_dim=384,
-                    depth=7, num_heads=12, mlp_ratio=1.0, in_chans=3,
-                    flash=False, quant=False, fused=False) -> dict:
-    """FLOP + HBM-byte estimates for ONE image's forward pass, split by the
-    named scopes profiling.scope plants (obs/attrib.py joins these against
-    per-scope device time → achieved TFLOP/s, MFU, roofline class).
-
-    Each entry is the scope's INCLUSIVE cost — ``sampler/model`` carries the
-    whole forward, matching attribution's rollup time (an event inside
-    ``flash_attention/fwd`` counts toward both). Byte estimates are the
-    minimal HBM traffic: weights once per call, activations read+written at
-    layer boundaries, and — for the flash path — q/k/v/out streamed without
-    materializing the N² score matrix. Elementwise traffic rides along with
-    the GEMMs it fuses into, same convention as the FLOP side.
-
-    ``fused=True`` models the fused sampler-trunk programs (models/vit.py
-    ``fused``): the attention scope becomes ``flash_attention/fused_qkv``
-    (the one kernel carrying qkv dequant-GEMM + online softmax + proj GEMM;
-    the qkv/context activations never touch HBM, so its byte estimate is
-    x-in twice + out once + weights) with the epilogue cast under
-    ``flash_attention/fused_proj``, and the Mlp scope becomes ``mlp/pallas``
-    (hidden activation VMEM-resident). ``flash_attention/fwd`` and
-    ``dequant_matmul/pallas`` never fire in a fused-quant program and are
-    omitted; fused without quant keeps the plain flash scope.
-    """
-    H, W = img_size
-    n = (H // patch_size) * (W // patch_size) + 1
-    d = embed_dim
-    act_b = 2  # bf16 activations
-    w_b = 1 if quant else 2  # int8 trunk weights under w8a16
-    attn_flops = 2.0 * depth * 2 * n * n * d
-    qkv_proj_flops = 2.0 * depth * (3 * n * d * d + n * d * d)
-    mlp_flops = 2.0 * depth * 2 * n * d * d * mlp_ratio
-    dense_flops = qkv_proj_flops + mlp_flops
-    patch_flops = 2.0 * 2 * n * (patch_size * patch_size * in_chans) * d
-    # bytes: flash attention streams q, k, v in and the context out once per
-    # layer; trunk denses read their weights plus in/out activations for the
-    # qkv, proj and two MLP GEMMs; patch/head move the pixel-space tensors
-    # and their (shared-shape) weight once each.
-    attn_bytes = float(depth * 4 * n * d * act_b)
-    dense_bytes = float(depth * ((4 + 2 * mlp_ratio) * d * d * w_b
-                                 + 8 * n * d * act_b))
-    patch_bytes = float(2 * n * (patch_size * patch_size * in_chans) * act_b
-                        + 2 * (patch_size * patch_size * in_chans) * d * 2)
-    costs = {"sampler/model": {
-        "flops": attn_flops + dense_flops + patch_flops,
-        "bytes": attn_bytes + dense_bytes + patch_bytes}}
-    if fused:
-        costs["mlp/pallas"] = {
-            "flops": mlp_flops,
-            "bytes": float(depth * (2 * mlp_ratio * d * d * w_b
-                                    + 2 * n * d * act_b))}
-        if quant:
-            costs["flash_attention/fused_qkv"] = {
-                "flops": attn_flops + qkv_proj_flops,
-                "bytes": float(depth * (4 * d * d * w_b
-                                        + 3 * n * d * act_b))}
-            costs["flash_attention/fused_proj"] = {
-                "flops": 0.0,  # the f32→compute-dtype epilogue cast only
-                "bytes": float(depth * 2 * n * d * act_b)}
-        elif flash:
-            costs["flash_attention/fwd"] = {"flops": attn_flops,
-                                            "bytes": attn_bytes}
-        return costs
-    if flash:
-        costs["flash_attention/fwd"] = {"flops": attn_flops,
-                                        "bytes": attn_bytes}
-    if quant:
-        costs["dequant_matmul/pallas"] = {"flops": dense_flops,
-                                          "bytes": dense_bytes}
-    return costs

@@ -55,7 +55,7 @@ def enable_compile_cache(path: str | None = None) -> str:
 
 def effective_platforms() -> str:
     """The platform list JAX is configured with, without touching the
-    backend: ``jax.config.jax_platforms`` (which ``bench.py --cpu`` writes)
+    backend: ``jax.config.jax_platforms`` (which a script may have written)
     else the ``JAX_PLATFORMS`` env var. Empty string when nothing is
     configured (JAX then auto-detects)."""
     import jax

@@ -29,20 +29,10 @@ import numpy as np
 from ddim_cold_tpu.obs import metrics, spans
 
 
-def trace(log_dir: str, perfetto: bool = False):
-    """Capture a device trace into ``log_dir`` — ``jax.profiler.trace`` is
-    already a context manager with stop-in-finally semantics; pass through so
-    upstream improvements (perfetto links, etc.) come for free.
-
-    ``perfetto=True`` additionally writes the trace-event JSON dump
-    (``plugins/profile/<run>/perfetto_trace.json.gz``) that
-    ``obs/attrib.py`` parses — without it the capture is xplane-only and
-    attribution has nothing to read. Guarded for older jax signatures."""
-    if perfetto:
-        try:
-            return jax.profiler.trace(log_dir, create_perfetto_trace=True)
-        except TypeError:  # jax predating create_perfetto_trace
-            pass
+def trace(log_dir: str):
+    """Capture a device trace (``.xplane.pb``) into ``log_dir`` —
+    ``jax.profiler.trace`` is already a context manager with stop-in-finally
+    semantics; pass through."""
     return jax.profiler.trace(log_dir)
 
 
@@ -129,19 +119,17 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
-def span_trace(log_dir: str, span=None, perfetto: bool = False):
+def span_trace(log_dir: str, span=None):
     """A ``jax.profiler`` trace session keyed to an obs span: the capture
     lands in ``log_dir/trace_<trace_id>_<span_id>`` (or ``log_dir`` when no
     span / tracing disabled), so a slow request's profiler timeline is
-    findable from its span ids — the span→profiler workflow for the MFU
-    push (PERF.md "Observability"). ``perfetto=True`` adds the trace-event
-    JSON dump ``obs/attrib.py`` attributes (see :func:`trace`)."""
+    findable from its span ids."""
     import os
 
     ctx = getattr(span, "ctx", None)
     if ctx is not None:
         log_dir = os.path.join(log_dir, f"trace_{ctx.trace_id}_{ctx.span_id}")
-    return trace(log_dir, perfetto=perfetto)
+    return trace(log_dir)
 
 
 def enable_nan_checks(enable: bool = True) -> None:
@@ -151,7 +139,7 @@ def enable_nan_checks(enable: bool = True) -> None:
 
 def latency_summary(samples_s) -> dict:
     """Order statistics over a list of latencies in seconds — the serving
-    engine's per-request report (bench --serving, serve.Engine.stats)."""
+    engine's per-request report (serve.Engine.stats)."""
     arr = np.asarray(list(samples_s), dtype=np.float64)
     if arr.size == 0:
         return {"n": 0, "count": 0, "p50_s": 0.0, "p95_s": 0.0, "p99_s": 0.0,

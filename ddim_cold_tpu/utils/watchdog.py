@@ -5,7 +5,7 @@ parked in such a native call ignores cooperative shutdown. Callers
 :meth:`~StallWatchdog.mark` before every potentially-silent device
 interaction; a watchdog thread acts when no mark lands within the stall
 budget. The serving engine uses the SOFT mode (fail the waiting tickets, keep
-the process); one-shot scripts (bench.py, scripts/fid_trend.py,
+the process); one-shot scripts (scripts/fid_trend.py,
 scripts/publish_run.py) use the hard mode: ``on_abort`` writes the partial
 artifact, then ``os._exit(exit_code)`` — deliberate, because the main thread
 is the one that is parked.

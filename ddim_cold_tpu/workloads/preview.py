@@ -6,8 +6,8 @@ engine dispatch the SEQUENCE variant of the config's scan, which returns the
 prediction after step j, frame ``steps`` the final result. The engine
 delivers every ``m``-th intermediate prediction through
 ``Ticket.previews()`` before the final rows land — this module pins WHICH
-frames those are, so the engine, the bench's latency-to-first-frame metric,
-and the bitwise-prefix test can never disagree about the schedule.
+frames those are, so the engine and the bitwise-prefix test can never
+disagree about the schedule.
 
 Host-only on purpose (plain ints — no jax): the selection runs on the
 delivery path of every preview batch.

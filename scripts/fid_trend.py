@@ -36,9 +36,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def collect_points(run_dir: str, max_points: int):
     """→ ordered [(label, epoch|None, ckpt_path|None)] trend points: the
     random-init anchor, evenly-thinned snapshot epochs (first and last always
-    kept — obs.trend.thin, the one thinning rule for trend series), then the
-    run's best checkpoint."""
-    from ddim_cold_tpu.obs import trend
+    kept: ``eval.fid.thin``), then the run's best checkpoint."""
+    from ddim_cold_tpu.eval.fid import thin
 
     points = [("random", -1, None)]  # anchor: params as-initialized
     snap_dir = os.path.join(run_dir, "snapshots")
@@ -49,7 +48,7 @@ def collect_points(run_dir: str, max_points: int):
             if m:
                 snaps.append((int(m.group(1)), os.path.join(snap_dir, name)))
         snaps.sort()
-        snaps = trend.thin(snaps, max_points)
+        snaps = thin(snaps, max_points)
         points += [(f"epoch_{ep}", ep, path) for ep, path in snaps]
     best = os.path.join(run_dir, "bestloss.ckpt")
     if os.path.isdir(best):
@@ -188,17 +187,12 @@ def main(argv=None):
         print(f"[fid-trend] {label}: {value:.2f}", file=sys.stderr)
 
     wd.done()
-    # the output speaks the regression gate's language: per-point deltas
-    # under obs.trend's one noise-band policy (FID: lower is better), plus
-    # the run_meta provenance stamp every bench artifact now carries
-    from ddim_cold_tpu.obs import trend
-    from ddim_cold_tpu.utils.record import run_metadata
-
+    # per-point deltas against the noise band of the points before
+    # (FID: lower is better), plus the run_meta provenance stamp
     out = {
         "metric": "fid_trend_cold",
-        "points": trend.annotate_deltas(results, "fid",
-                                        lower_is_better=True),
-        "run_meta": run_metadata(chip=str(jax.devices()[0].device_kind)),
+        "points": fid.annotate_deltas(results, "fid", lower_is_better=True),
+        "run_meta": fid.run_metadata(chip=str(jax.devices()[0].device_kind)),
         "n_samples": args.n_samples,
         "n_real": n_real_seen,
         "extractor": (f"seeded random init (PRNGKey({args.inception_seed})) — "

@@ -1,6 +1,6 @@
 """X-layer self-tests: the lattice quotient itself, one violating fixture
 per rule (delete-a-sweep-entry for X001, an inconsistent build gate and a
-frozen-config bypass for X002, an unswept warm set and an illegal bench
+frozen-config bypass for X002, an unswept warm set and an illegal config
 site for X003), the R/X partial --fix-baseline churn contract, and the
 clean-tree run (the committed sweep fully covers the committed lattice)."""
 
@@ -143,8 +143,8 @@ def test_x003_unswept_edit_class_fires_once():
     assert f.subject == "edit-unswept:superres:pv2"
 
 
-def test_x003_illegal_bench_site_fires(tmp_path):
-    (tmp_path / "bench.py").write_text(textwrap.dedent("""\
+def test_x003_illegal_config_site_fires(tmp_path):
+    (tmp_path / "chip_smoke.py").write_text(textwrap.dedent("""\
         from ddim_cold_tpu.serve.batching import SamplerConfig
 
         GOOD = SamplerConfig(k=10, cache_interval=2)
@@ -155,18 +155,19 @@ def test_x003_illegal_bench_site_fires(tmp_path):
     assert len(fs) == 1
     f = fs[0]
     assert f.rule == "GRAFT-X003"
-    assert f.subject == "bench.py:4"
+    assert f.subject == "chip_smoke.py:4"
     assert f.line == 4
 
 
-def test_x003_bench_sites_substitute_sweep_variables():
-    sites = X._bench_config_sites(textwrap.dedent("""\
+def test_x003_config_sites_substitute_variables():
+    sites = X._config_sites(textwrap.dedent("""\
         a = SamplerConfig(k=K, cache_interval=2)
-        b = SamplerConfig(steps=n_steps)
+        b = SamplerConfig(steps=2)
         c = SamplerConfig(quant=mode_from_somewhere)
+        d = SamplerConfig(**from_a_file)
     """))
-    # a and b substitute representatives for K/steps; c's dynamic kwarg
-    # has no representative, so the site is skipped (not a false alarm)
+    # a substitutes the representative for K; c's dynamic kwarg has no
+    # representative and d is a splat, so both are skipped (no false alarm)
     assert [line for line, _ in sites] == [1, 2]
     assert sites[0][1] == {"k": 10, "cache_interval": 2}
 

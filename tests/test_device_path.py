@@ -58,7 +58,7 @@ def test_cold_prepare_pins_batch_sharding_under_mesh():
     """Under a dp×tp×sp mesh the degrade gathers must stay batch-sharded —
     left to the partitioner they can land W-sharded and trigger XLA's
     "Involuntary full rematerialization" replicate-all fallback on the
-    reshard into the attention layout (MULTICHIP_r02 tail)."""
+    reshard into the attention layout."""
     from ddim_cold_tpu.parallel import make_mesh
 
     if len(jax.devices()) < 8:

@@ -192,3 +192,46 @@ def test_random_extractor_features_do_not_collapse(rng):
     imgs = rng.rand(4, 32, 32, 3).astype(np.float32)
     feats = np.asarray(feature_fn(jnp.asarray(imgs)))
     assert feats.std() > 0.05, f"collapsed features: std={feats.std()}"
+
+
+# ---------------------------------------------------------------------------
+# trend-series helpers (scripts/fid_trend.py)
+# ---------------------------------------------------------------------------
+
+def test_thin_keeps_first_and_last():
+    seq = list(range(25))
+    out = fid.thin(seq, 10)
+    assert len(out) == 10 and out[0] == 0 and out[-1] == 24
+    assert out == sorted(out)
+    assert fid.thin(seq, 100) == seq
+    assert fid.thin(seq, 1) == [0]
+    assert fid.thin([], 5) == []
+
+
+def test_annotate_deltas_lower_is_better():
+    rows = [{"ckpt": "random", "fid": 400.0},
+            {"ckpt": "epoch_1", "fid": 120.0},
+            {"ckpt": "best", "fid": 118.0},
+            {"ckpt": "drift", "fid": 250.0}]
+    out = fid.annotate_deltas(rows, "fid", lower_is_better=True)
+    assert "delta_rel" not in out[0]  # first point has no predecessor
+    assert out[1]["in_band"]  # improvement is always in band
+    assert out[2]["in_band"]
+    assert not out[3]["in_band"]  # +112% FID: out of band, flagged
+    assert rows[1].keys() == {"ckpt", "fid"}  # input rows untouched
+
+
+def test_run_metadata_stamp(monkeypatch):
+    monkeypatch.setenv("DDIM_COLD_RUN_TS", "1754400000")
+    monkeypatch.setenv("DDIM_COLD_ROUND", "6")
+    meta = fid.run_metadata(chip="TPU v5 lite")
+    assert meta["timestamp"] == 1754400000.0
+    assert meta["round"] == 6
+    assert meta["device_kind"] == "TPU v5 lite"
+    assert meta["jax"]  # installed in every supported environment
+    monkeypatch.delenv("DDIM_COLD_RUN_TS")
+    monkeypatch.delenv("DDIM_COLD_ROUND")
+    monkeypatch.delenv("SOURCE_DATE_EPOCH", raising=False)
+    meta = fid.run_metadata()
+    assert meta["timestamp"] is None  # never the wall clock
+    assert meta["round"] is None

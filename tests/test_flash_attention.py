@@ -392,11 +392,10 @@ def test_flash_bf16_gradient_north_star_shape_matches_dense():
     f32 oracle on the same bf16 inputs. The 200px training stage runs this
     exact backward in bf16, and the bf16-gemm-v2 kernel routes its backward
     GEMMs through the input dtype — a path the f32 gradient tests above
-    never touch (ADVICE r5 item 1: the bf16 backward GEMM path had zero
-    numerics coverage). Tolerances follow the bf16 forward tests (~2e-2):
+    never touch. Tolerances follow the bf16 forward tests (~2e-2):
     the comparison isolates kernel-vs-einsum error on identical bf16
     operands, not bf16-vs-f32 rounding."""
-    from bench import NS_FLASH_BLOCKS
+    from ddim_cold_tpu.ops.flash_attention import NS_FLASH_BLOCKS
 
     q32, k32, v32 = _rand_qkv(19, 1, 2501, 4, 64)
     q, k, v = (x.astype(jnp.bfloat16) for x in (q32, k32, v32))
@@ -420,12 +419,12 @@ def test_flash_bf16_gradient_north_star_shape_matches_dense():
 
 
 def test_flash_bf16_north_star_headline_config_matches_dense():
-    """The EXACT path bench_v2 measures on chip: bf16 inputs, N=2501, H=4,
-    D=64, the tuned NS_FLASH_BLOCKS single-chunk config — against the dense
+    """The 200px sampler's shape: bf16 inputs, N=2501, H=4,
+    D=64, the NS_FLASH_BLOCKS single-chunk config — against the dense
     f32 oracle on the same bf16 inputs. The bf16-gemm-v2 kernel runs its
     GEMMs in bf16 here (input dtype), so this pins the numerics of the
     production sampler configuration, not just the f32 test shapes."""
-    from bench import NS_FLASH_BLOCKS
+    from ddim_cold_tpu.ops.flash_attention import NS_FLASH_BLOCKS
 
     q32, k32, v32 = _rand_qkv(17, 1, 2501, 4, 64)
     q, k, v = (x.astype(jnp.bfloat16) for x in (q32, k32, v32))
@@ -556,20 +555,18 @@ def _tile_rule_spy(monkeypatch, fa):
 
 
 def test_block_sweep_configs_satisfy_tpu_tile_rule(monkeypatch):
-    """The bench's --flash-block-sweep configs at the exact 200px shape
-    (N=2501) must pass the same tile rule — a sweep entry that Mosaic
-    rejects on chip would burn its slot in the one hardware window."""
+    """The FLASH_BLOCK_SWEEP geometries at the exact 200px shape (N=2501)
+    must pass the same tile rule — an entry Mosaic rejects would only be
+    found on the chip."""
     from ddim_cold_tpu.ops import flash_attention as fa
-
-    from bench import FLASH_BLOCK_SWEEP
 
     calls = _tile_rule_spy(monkeypatch, fa)
     q, k, v = _rand_qkv(11, 1, 2501, 1, 64)  # 1 head: forward-only sweep
-    for bq, bkv in FLASH_BLOCK_SWEEP:
+    for bq, bkv in fa.FLASH_BLOCK_SWEEP:
         out = flash_attention(q, k, v, 64**-0.5, bq, bkv)
         assert np.isfinite(np.asarray(out)).all(), (bq, bkv)
-    assert calls.count("_fwd_kernel") == len(FLASH_BLOCK_SWEEP), calls
-    assert len(calls) == len(FLASH_BLOCK_SWEEP), calls
+    assert calls.count("_fwd_kernel") == len(fa.FLASH_BLOCK_SWEEP), calls
+    assert len(calls) == len(fa.FLASH_BLOCK_SWEEP), calls
 
 
 def test_block_specs_satisfy_tpu_tile_rule(monkeypatch):

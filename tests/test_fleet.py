@@ -16,6 +16,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+import promise
 import pytest
 
 from ddim_cold_tpu import serve
@@ -66,13 +67,23 @@ def _direct(model, params, seed, n):
         model, params, jax.random.PRNGKey(seed), k=K, n=n))
 
 
+def _assert_served(how, got, model, params, seed, n):
+    """``got`` against direct sampling as ``tests/promise.py`` states it.
+    Which replica, which batchmates and which of the two buckets served a
+    row is a matter of timing here, so ``same_bucket`` admits either."""
+    assert got.shape[0] == n
+    promise.assert_sample_served(how, got, model, params, seed, K, (4, 8))
+
+
 # ------------------------------------------------------------ clean routing
 
 
-def test_router_bitwise_and_zero_compiles(model_and_params):
+@pytest.mark.parametrize("how", promise.HOWS)
+def test_router_bitwise_and_zero_compiles(model_and_params, how):
     """The inherited engine contract at fleet scope: mixed-size requests
-    spread over two replicas all come back bitwise equal to direct
-    sampling, with zero program builds after warmup anywhere."""
+    spread over two replicas all come back as direct sampling gives them
+    (bitwise at a bucket's batch size, to the tolerance at their own n),
+    with zero program builds after warmup anywhere."""
     model, params = model_and_params
     router = _router(model_and_params, replicas=2)
     sizes = [(41, 5), (42, 4), (43, 3), (44, 1)]
@@ -80,7 +91,7 @@ def test_router_bitwise_and_zero_compiles(model_and_params):
     for s, n in sizes:
         got = tickets[s].result(timeout=60)
         assert got.shape == (n, 16, 16, 3)
-        np.testing.assert_array_equal(got, _direct(model, params, s, n))
+        _assert_served(how, got, model, params, s, n)
     h = router.drain(timeout=10)
     assert h["compiles_after_warmup"] == 0
     assert h["completed"] == len(sizes) and h["failed"] == 0
@@ -129,7 +140,7 @@ def test_hedged_replacement_is_bitwise(model_and_params):
     with faults.inject(spec) as plan:
         t = router.submit(seed=51, n=3, config=CFG)
         got = t.result(timeout=60)
-    np.testing.assert_array_equal(got, _direct(model, params, 51, 3))
+    _assert_served("same_bucket", got, model, params, 51, 3)
     assert len(plan.realized) == 1
     assert router.stats["hedges"] == 1
     h = router.drain(timeout=10)
@@ -165,10 +176,11 @@ def test_quarantined_request_is_never_hedged(model_and_params):
     assert h["replicas_spawned"] >= 3  # 2 initial + the replacement
 
 
-def test_fleet_chaos_contract(model_and_params):
+@pytest.mark.parametrize("how", promise.HOWS)
+def test_fleet_chaos_contract(model_and_params, how):
     """ISSUE 6 acceptance: seeded schedule kills r0's dispatch outright
     (permanent) and injects 20–25% transients at assembly and placement.
-    Every surviving ticket is bitwise-equal to direct sampling, every
+    Every surviving ticket is what direct sampling gives (``how``), every
     failed ticket carries a typed cause naming its replica, r0 is drained
     and replaced, and compiles-after-warmup is 0 across ALL replicas —
     replacement included."""
@@ -189,6 +201,15 @@ def test_fleet_chaos_contract(model_and_params):
         tickets = {s: router.submit(seed=s, n=n, config=CFG)
                    for s, n in sizes}
         outcomes = {s: tickets[s].exception(timeout=120) for s, _ in sizes}
+        # which replica a request lands on is a matter of timing: where the
+        # schedule sent r0 fewer requests than its quarantine limit, probes
+        # make up the count (an idle fleet places on r0 first: least loaded,
+        # id tiebreak; past the limit they land on r1 and succeed)
+        for probe in range(4):
+            if router.health()["retired_replicas"] >= 1:
+                break
+            router.submit(seed=70 + probe, n=1,
+                          config=CFG).exception(timeout=120)
         # wait for supervision to finish the lifecycle: r0 retired and the
         # fleet back at target size
         deadline = time.time() + 30
@@ -203,8 +224,7 @@ def test_fleet_chaos_contract(model_and_params):
         exc = outcomes[s]
         if exc is None:
             survivors += 1
-            np.testing.assert_array_equal(tickets[s].result(0),
-                                          _direct(model, params, s, n))
+            _assert_served(how, tickets[s].result(0), model, params, s, n)
         else:
             failures += 1
             # typed, and the message names the replica it died on

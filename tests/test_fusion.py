@@ -2,10 +2,15 @@
 + ops/quant.mlp_pallas + ops/tuning.py + the vit/serve wiring).
 
 The contract ladder, strictest first:
-* the fused program is BITWISE the unfused ``QuantDense → flash → QuantDense``
-  + ``Dense → gelu → Dense`` composition at f32 — through the serving engine,
-  at two buckets, composed with the step cache, and (for the fused Mlp, the
-  part that survives the sp gate) under sp_degree=2;
+* the engine serves the fused program as it serves any other
+  (``tests/promise.py``: bitwise the direct fused sampler at the bucket's
+  batch size, to a tolerance at the request's own n), at two buckets and
+  composed with the step cache;
+* the fused program agrees with the unfused ``QuantDense → flash →
+  QuantDense`` + ``Dense → gelu → Dense`` composition at f32 to
+  ``FUSED_ATOL`` (they are two XLA programs: the same sums in another
+  order), and bitwise for the fused Mlp alone, the part that survives the
+  sp gate, under sp_degree=2;
 * ``fused=True`` + ``quant='xla'`` is refused at config construction AND at
   model call — 'xla' explicitly opts out of Pallas;
 * every committed TUNED_BLOCKS entry is legal under exactly the rules
@@ -21,6 +26,7 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
+import promise
 import pytest
 
 from ddim_cold_tpu import serve
@@ -29,12 +35,15 @@ from ddim_cold_tpu.ops import quant, sampling, tiling, tuning
 from ddim_cold_tpu.utils import flops as flops_util
 
 # flash + explicit blocks: both the fused and unfused clones inherit the SAME
-# kv-chunk boundaries, which is what makes the f32 oracle bitwise (dense
-# einsum attention would differ from the online softmax in round-off)
+# kv-chunk boundaries (dense einsum attention would differ from the online
+# softmax in round-off by far more than FUSED_ATOL)
 TINY = dict(img_size=(32, 32), patch_size=8, embed_dim=64, depth=2,
             num_heads=4, total_steps=2000, use_flash=True,
             flash_blocks=(32, 32))
 K = 500  # 4 reverse steps (tests/test_serve.py's budget)
+#: fused against unfused, float32 on the CPU, [0, 1] images after 4 steps:
+#: 1.19e-07 seen (6.0e-08 after one forward), in a quarter of the values
+FUSED_ATOL = 5e-7
 
 
 @pytest.fixture(scope="module")
@@ -67,36 +76,48 @@ def _drain(eng, cfg, seeds_and_ns):
     return [np.asarray(t.result(timeout=30)) for t in tickets]
 
 
-def test_engine_fused_bitwise_two_buckets(warmed_fused):
-    """Acceptance: the fused program serves BITWISE-identical images to the
-    unfused w8a16 program at both warmed buckets, with zero compiles after
+def _assert_served_fused(how, got, model, params, seed, bucket, **cache):
+    """The engine's rows for a fused request against the direct fused
+    sampler on the same start state (``tests/promise.py``)."""
+    promise.assert_sample_served(
+        how, got, model.clone(quant="pallas", fused=True),
+        quant.quantize_params(params), seed, K, (bucket,), **cache)
+
+
+@pytest.mark.parametrize("how", promise.HOWS)
+def test_engine_fused_bitwise_two_buckets(warmed_fused, how):
+    """Acceptance: the fused program is served as the direct fused sampler
+    computes it (``how``) from both warmed buckets, and its images are the
+    unfused w8a16 program's to ``FUSED_ATOL``, with zero compiles after
     warmup — same param tree, same rng, different compiled program."""
     eng, cfg_u, cfg_f = warmed_fused
     compiles = eng.stats["compiles"]
-    reqs = [(201, 4), (202, 2)]
-    got_u = _drain(eng, cfg_u, reqs)
-    got_f = _drain(eng, cfg_f, reqs)
+    # 3 rows are one batch of bucket 4; then 1 row is one batch of bucket 2
+    for seed, n, bucket in ((201, 3, 4), (202, 1, 2)):
+        (u,) = _drain(eng, cfg_u, [(seed, n)])
+        (f,) = _drain(eng, cfg_f, [(seed, n)])
+        _assert_served_fused(how, f, eng.model, eng.params, seed, bucket)
+        np.testing.assert_allclose(f, u, rtol=0, atol=FUSED_ATOL)
+        assert np.isfinite(f).all()
     assert eng.stats["compiles"] == compiles
-    for a, b in zip(got_u, got_f):
-        np.testing.assert_array_equal(a, b)
-        assert np.isfinite(a).all()
 
 
-def test_engine_fused_cached_composition(model_and_params):
-    """fused × step-cache composes bitwise: the cache is a trunk-structure
-    hook (block-delta capture), independent of how each block computes."""
+@pytest.mark.parametrize("how", promise.HOWS)
+def test_engine_fused_cached_composition(model_and_params, how):
+    """fused × step-cache composes: the cache is a trunk-structure hook
+    (block-delta capture), independent of how each block computes."""
     model, params = model_and_params
     eng = serve.Engine(model, params, buckets=(2,))
-    cfg_u = serve.SamplerConfig(k=K, quant="pallas", cache_interval=2,
-                                cache_mode="full")
-    cfg_f = serve.SamplerConfig(k=K, quant="pallas", cache_interval=2,
-                                cache_mode="full", fused=True)
+    cache = dict(cache_interval=2, cache_mode="full")
+    cfg_u = serve.SamplerConfig(k=K, quant="pallas", **cache)
+    cfg_f = serve.SamplerConfig(k=K, quant="pallas", fused=True, **cache)
     serve.warmup(eng, [cfg_u, cfg_f], persistent_cache=False)
     compiles = eng.stats["compiles"]
-    (a,) = _drain(eng, cfg_u, [(211, 2)])
-    (b,) = _drain(eng, cfg_f, [(211, 2)])
+    (u,) = _drain(eng, cfg_u, [(211, 1)])
+    (f,) = _drain(eng, cfg_f, [(211, 1)])
     assert eng.stats["compiles"] == compiles
-    np.testing.assert_array_equal(a, b)
+    _assert_served_fused(how, f, model, params, 211, 2, **cache)
+    np.testing.assert_allclose(f, u, rtol=0, atol=FUSED_ATOL)
 
 
 @pytest.mark.skipif(jax.device_count() % 2 != 0,

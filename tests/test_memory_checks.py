@@ -176,8 +176,8 @@ def test_clean_in_tree_memory(kernel_traces):
 
 
 def test_budget_report_rollups(kernel_traces):
-    """bench's memory_budget section consumes exactly this shape, and
-    obs/trend.py bands the two rollup keys — pin them."""
+    """The report's shape: the two worst-case rollups, every program and
+    kernel entry, no finding."""
     report = memory_checks.budget_report(kernel_traces=kernel_traces)
     assert report["findings"] == []
     assert 0 < report["peak_hbm_gb"] <= report["hbm_budget_gib"]

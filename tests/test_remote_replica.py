@@ -4,7 +4,7 @@ The wire protocol and exception codec are tested in-process; the process
 tests spawn the STUB backend (serve/replica_main.py's StubEngine — the full
 warmup/submit/drain surface minus jax, deterministic rows per seed) so a
 child boots in well under a second and the whole file fits the tier-1
-budget. The chaos recipes mirror bench --fleet-proc: ``replica.kill`` is a
+budget. The chaos recipes: ``replica.kill`` is a
 real SIGKILL inside the child, ``replica.hang`` wedges its reader thread
 (heartbeat-loss retire), ``rpc.drop`` eats frames on the parent side.
 """

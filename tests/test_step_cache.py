@@ -180,8 +180,8 @@ def test_interval_one_is_bitwise_exact(model_and_params):
 @pytest.mark.parametrize("mode", ["delta", "full"])
 def test_cached_ddim_close_to_exact(model_and_params, mode):
     """interval=2 on a tiny random-init model: the cached sampler must stay
-    in range and near the exact one (the quantitative FID bound is bench's
-    cached_quality section; here we pin basic sanity + determinism)."""
+    in range and near the exact one (the quantitative FID bound is
+    ``eval/fid.cached_sampler_guard``; here we pin basic sanity + determinism)."""
     model, params = model_and_params
     rng = jax.random.PRNGKey(6)
     exact = np.asarray(sampling.ddim_sample(model, params, rng, k=200, n=2))
