@@ -75,11 +75,15 @@ WASTE_THRESHOLD = 1.25
 #: accepts a partial final block whose shape is tile-legal: what a program
 #: reads past the edge is unspecified and what it writes there is dropped
 #: (compiled for TPU v5 lite and run on the chip at 2,501 tokens: PERF.md
-#: section 6, PR 27). The body has to make the unspecified part harmless —
-#: the flash forward masks K's columns and zeroes V's rows past the sequence,
-#: and a q row past it feeds only its own, dropped, output row. Every other
-#: kernel keeps the pad-to-block-multiple policy, on both axes.
-RAGGED_SUBLANE_OK = frozenset({"fwd"})
+#: section 6, PR 27 and PR 29). The body has to make the unspecified part
+#: harmless — the flash forward masks K's columns and zeroes V's rows past
+#: the sequence, and a q row past it feeds only its own, dropped, output row;
+#: ``dq`` selects the ds columns of K/V rows past it to 0 and zeroes those K
+#: rows, ``dkv`` zeroes the q and do rows past it and selects their lse and
+#: delta (rows of arrays padded to the block, like every lane axis, which
+#: ``dq`` fills only as far as its own q blocks reach) to 1e30 and 0. Every
+#: other kernel keeps the pad-to-block-multiple policy, on both axes.
+RAGGED_SUBLANE_OK = frozenset({"fwd", "dq", "dkv"})
 
 
 def _round_up(n: int, m: int) -> int:

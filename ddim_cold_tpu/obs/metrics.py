@@ -107,6 +107,10 @@ METRICS = (
      "flash forward traces by schedule (key: resident|streamed)"),
     ("kernels.flash_fwd_layout", "counter",
      "flash forward traces by operand layout (key: in_place|head_major)"),
+    ("kernels.flash_bwd_schedule", "counter",
+     "flash backward traces by dq's K/V schedule (key: resident|streamed)"),
+    ("kernels.flash_bwd_layout", "counter",
+     "flash backward traces by operand layout (key: in_place|head_major)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

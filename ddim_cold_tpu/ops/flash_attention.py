@@ -9,7 +9,8 @@ sees two GEMMs per chunk and head. The K/V grid axis is innermost: TPU grids
 execute sequentially, so the VMEM scratch accumulators carry across the chunks
 of one q block and are re-initialized when the chunk index wraps to 0.
 
-**Layout: the forward reads its operands where the model's GEMMs wrote them.**
+**Layout: the kernels read their operands where the model's GEMMs wrote them,
+forward and backward.**
 The model holds q, k, v token-major — ``(B, N, H·D)``, or all three as the one
 ``(B, N, 3·H·D)`` result of its qkv GEMM — and wants the context as
 ``(B, N, H·D)`` for ``proj``. The forward's ``BlockSpec``s address exactly
@@ -30,7 +31,13 @@ laid out head-major by XLA — ``(B·H, N⁺, D⁺)``, one grid row a head, head
 zero-padded to the lanes (:func:`_to_heads`) — and runs the SAME launch with
 one head a group, bit for bit the same context. ``kernels.flash_fwd_layout``
 counts which, ``in_place`` or ``head_major``, once a trace. The backward
-kernels still take head-major operands for every shape.
+launches (``dq``, ``dkv``) take the same addressing by the same rule
+(``kernels.flash_bwd_layout``): q, k, v, the context and the cotangent
+``(B, N, H·D)`` are read in those blocks and dq, dk, dv written back through
+them, into three ``(B, N, H·D)`` arrays or — the packed entry — into the one
+``(B, N, 3·H·D)`` gradient the qkv GEMM's backward reads, which ``dq`` begins
+(column blocks of q) and ``dkv``, taking it aliased to its own result,
+completes: no transpose, pad, slice, broadcast or concatenate on either side.
 
 The forward picks its blocks from what it can see — padded sequence length,
 lanes, dtype, heads a lane group (:func:`_fwd_blocks`); no model name, no flag:
@@ -56,17 +63,26 @@ Autodiff: ONE custom VJP (:func:`_attention`) under both entries,
 packed projection), flash all the way through. The VJP's forward additionally
 emits the per-row log-sum-exp (the undifferentiated call, which is all a
 sampler makes, launches the kernel without that result and its write); the
-backward runs two more Pallas kernels — dq (grid like the head-major forward)
-and dk/dv (grid transposed: K/V blocks outer, q chunks streamed innermost) —
-that rebuild probabilities from the saved lse chunk by chunk, so the O(N²)
-matrix never exists in HBM in either direction. Residuals are the operands as
-the forward read them (the packed projection stays packed), the context and
-lse: O(N·D) — the whole train-step memory story for long sequences is bounded.
-(In-kernel, lse rides a 128-lane-replicated layout because TPU tiling rejects
-(1, bq) row blocks; the replication is sliced off / re-broadcast outside the
-kernels so the residual itself stays one lane a row and head. See
-_fwd_kernel._emit.) The backward kernels always stream, at (256, 512) unless
-blocks are given.
+backward runs two more Pallas kernels — dq (grid like the forward) and dk/dv
+(grid transposed: K/V blocks outer, q chunks innermost, and the scores
+computed transposed, ``k·qᵀ``, so that ``pᵀ·do`` and ``dsᵀ·q`` need no
+(bq, bkv) transpose) — that rebuild probabilities from the saved lse chunk by
+chunk, so the O(N²) matrix never exists in HBM in either direction. Residuals
+are the operands as the forward read them (the packed projection stays
+packed), the context and lse: O(N·D) — the whole train-step memory story for
+long sequences is bounded. (The forward emits lse 128-lane-replicated because
+TPU tiling rejects (1, bq) row blocks, and the replication is cut off outside
+the kernel so the residual stays one lane a row and head. The backward reads
+that residual as it is: rows of ``(8, bq)`` blocks, a head a sublane, tokens
+on the lanes — :func:`_lse_rows` — which dkv's transposed tiles broadcast
+directly and dq turns into columns in VMEM; delta = Σ o·do is computed by dq
+from the context and cotangent blocks it holds and handed to dkv in the same
+form. Nothing is spread over 128 lanes in HBM.) Each backward kernel picks
+its blocks from the shape as the forward does (:func:`_bwd_blocks`): dq keeps
+K and V, dkv keeps q and do, as ONE resident chunk wherever its VMEM model
+admits that — (512, 2560) and (2560, 512) at the 200px trunk in bf16 — and
+both stream at (256, 512) where nothing fits; ``kernels.flash_bwd_schedule``
+counts which. Explicit blocks are honoured.
 
 On the CPU backend the kernels run in interpreter mode, so tests exercise
 the identical code paths; any other non-TPU backend is an error — a caller
@@ -91,8 +107,10 @@ from ddim_cold_tpu.utils import flops, profiling
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: last dim of VMEM tiles
 
-#: which schedule and which operand layout each trace of the forward engaged
-#: (``kernels.flash_fwd_schedule``, ``kernels.flash_fwd_layout``)
+#: which schedule and which operand layout each trace of the forward and of
+#: the backward engaged (``kernels.flash_fwd_schedule``,
+#: ``kernels.flash_fwd_layout``, ``kernels.flash_bwd_schedule``,
+#: ``kernels.flash_bwd_layout``)
 _kernels = metrics.scope("kernels")
 
 #: names the GEMM dtype contract of the kernels (operands in the input dtype,
@@ -243,10 +261,10 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 def _to_heads(x, B, N, H, D):
     """(B, N, H, D) → (B·H, N⁺, D⁺): one grid row per head's sequence,
     lane-aligned head dim (zero columns are inert in q·kᵀ and produce zero
-    output columns, sliced off at the end), sublane-aligned N. The backward
-    kernels' layout, and the forward's for the shapes it cannot address in
-    place (:func:`_heads_per_lane_group`): every call is a transpose and a
-    pad in HBM."""
+    output columns, sliced off at the end), sublane-aligned N. The layout of
+    the shapes the kernels cannot address in place
+    (:func:`_heads_per_lane_group`), forward and backward: every call is a
+    transpose and a pad in HBM."""
     x = x.transpose(0, 2, 1, 3).reshape(B * H, N, D)
     x = _pad_to(x, 2, _LANE)
     return _pad_to(x, 1, 8)
@@ -254,11 +272,12 @@ def _to_heads(x, B, N, H, D):
 
 def _heads_per_lane_group(num_heads: int, head_dim: int) -> int | None:
     """How many heads share one 128-lane column block of the token-major
-    ``(B, N, H·D)`` layout, where the forward can address q, k, v and the
-    context in place: whole heads fill the lanes (head sizes 32, 64, 128) and
-    the heads fill whole column blocks. ``None`` for every other shape (head
-    size 80 or 256; one local head of 64 under Ulysses): those are laid out
-    head-major first (:func:`_to_heads`)."""
+    ``(B, N, H·D)`` layout, where the kernels can address q, k, v, the context
+    and their gradients in place: whole heads fill the lanes (head sizes 32,
+    64, 128) and the heads fill whole column blocks. ``None`` for every other
+    shape (head size 80 or 256; one local head of 64 under Ulysses): those
+    are laid out head-major first (:func:`_to_heads`), forward and
+    backward."""
     if _LANE % head_dim == 0 and (num_heads * head_dim) % _LANE == 0:
         return _LANE // head_dim
     return None
@@ -308,8 +327,10 @@ def flash_attention(
     forward takes one head's whole K and V as a single VMEM-resident chunk
     wherever that fits, and streams K/V chunks (VMEM ≈ (block_q +
     2·block_kv)·128-lane input tiles plus the f32 accumulator, independent of
-    N) where it does not; the backward tiles at (256, 512). Explicit blocks
-    are honoured, forward and backward. This undifferentiated call writes no
+    N) where it does not; the backward's two kernels choose theirs by the
+    same rule (:func:`_bwd_blocks`: K and V resident for dq, q and the
+    cotangent resident for dkv, else (256, 512)). Explicit blocks are
+    honoured, forward and backward. This undifferentiated call writes no
     log-sum-exp; under ``jax.grad`` the forward of the VJP does.
     """
     B, N, H, D = q.shape
@@ -333,7 +354,8 @@ def flash_attention_qkv(
     addresses its operands in place it is handed the one array three times
     with column-block offsets 0, C/128 and 2C/128, so q, k and v are never
     cut out of it; the same values, blocks and VJP as :func:`flash_attention`
-    on the three slices.
+    on the three slices, and the gradient comes back as one ``(B, N, 3·C)``
+    array that the two backward launches fill in place.
     """
     return _attention((qkv,), num_heads, scale, block_q, block_kv)
 
@@ -480,88 +502,201 @@ def _flash_forward(operands, num_heads, scale, block_q, block_kv, *, with_lse):
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, scale: float, n_valid: int, block_q: int,
-                   block_kv: int, n_kv: int):
-    """dq_i = Σ_j ds_ij·k_j·scale, K/V chunks streamed innermost."""
-    kv_i = pl.program_id(2)
+#: sublanes of the row-statistics blocks (lse, delta): a head a sublane,
+#: rounded up to the f32 tile
+_STAT_ROWS = 8
+
+#: lse of a token past the sequence in the row statistics: exp(s − this) is
+#: an exact 0 for every finite score
+_LSE_PAST_END = 1e30
+
+
+def _head_picker(shape: tuple, heads: int):
+    """``pick(h, x, other=None)`` for (rows, lanes) tiles of ``shape`` holding
+    ``heads`` heads side by side on the lanes: ``x`` on head ``h``'s lanes and
+    ``other`` (zeros if ``None``) on the rest; ``x`` itself where one head
+    has the lanes to itself."""
+    if heads == 1:
+        return lambda h, x, other=None: x
+    head_dim = shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def pick(h, x, other=None):
+        own = (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+        return jnp.where(own, x, jnp.zeros_like(x) if other is None else other)
+
+    return pick
+
+
+def _bwd_dq_kernel(lse_ref, q_ref, k_ref, v_ref, o_ref, do_ref, dq_ref,
+                   delta_ref, acc_ref, *, scale: float, n_valid: int,
+                   block_kv: int, n_kv: int, heads: int, ragged_kv: bool):
+    """One (row, lane group, q-block, kv-block) program of
+    dq_i = scale · Σ_j ds_ij·k_j, K/V chunks innermost (one chunk: K and V
+    resident across the lane group's q blocks, as in the forward).
+
+    The ``heads`` heads on the block's lanes are told apart as in
+    :func:`_fwd_kernel`: head ``h``'s q and do tiles have the other heads'
+    lanes zeroed, so both score-shaped GEMMs add exact zeros for them, and
+    ``ds_h · k`` over the whole K tile holds head ``h``'s dq in its own
+    lanes, which alone are selected into the accumulator. lse arrives as rows
+    (:func:`_lse_rows`); the (8, bq) block is turned once a program into the
+    columns the (bq, bkv) tiles need. delta_i = Σ_d o_id·do_id is a head's
+    lane sum of the o and do blocks the program holds, and goes out as rows,
+    ``(8, bq)`` with head ``h`` on sublane ``h``, for the dkv launch. A q row
+    past the sequence feeds only its own, dropped, dq row and its delta,
+    which the dkv launch does not take (it selects what it reads there);
+    K/V rows past it (``ragged_kv``) hold whatever the buffer held, so their
+    ds columns are selected to an exact 0 and their K rows zeroed (0 ×
+    garbage is NaN)."""
+    kv_i = pl.program_id(3)
 
     @pl.when(kv_i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # input-dtype GEMMs, f32 accumulation — see _fwd_kernel
-    q = q_ref[0]    # (bq, D)
-    k = k_ref[0]    # (bkv, D)
+    q = q_ref[0]    # (bq, lanes)
+    k = k_ref[0]    # (bkv, lanes)
     v = v_ref[0]
-    do = do_ref[0]  # (bq, D)
-    lse = lse_ref[0][:, :1]             # (bq, 1), lane-replicated block
-    delta = delta_ref[0][:, :1]         # (bq, 1)
+    do = do_ref[0]  # (bq, lanes)
+    fold = _scale_folds_into_q(scale)
+    if fold:
+        q = q * scale  # (bq, lanes) multiplies instead of (bq, bkv)
+    if ragged_kv:
+        row = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
+        k = jnp.where(row < n_valid, k, jnp.zeros_like(k))
+        col_ok = kv_i * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (q.shape[0], k.shape[0]), 1) < n_valid
+    lse = lse_ref[0, 0].T  # (bq, 8): head h's in column h
+    o_do = o_ref[0].astype(jnp.float32) * do.astype(jnp.float32)
+    pick = _head_picker(acc_ref.shape, heads)  # on (bq, lanes) tiles
 
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (bq, bkv) f32
-    # zero both padded kv columns (zero-filled k would contribute exp(−lse))
-    # and padded q rows (their lse ≈ −inf would blow up exp)
-    col = kv_i * block_kv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    row = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, logits.shape, 0)
-    p = jnp.where((col < n_valid) & (row < n_valid),
-                  jnp.exp(logits - lse), 0.0)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)  # (bq, bkv) f32
-    ds = p * (dp - delta)
-    acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
-                            preferred_element_type=jnp.float32) * scale
+    deltas = []
+    for h in range(heads):  # unrolled: the compiler overlaps the heads
+        deltas.append(jnp.sum(pick(h, o_do), axis=-1, keepdims=True))  # (bq, 1)
+        logits = jax.lax.dot_general(
+            pick(h, q), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bq, bkv) f32
+        if not fold:
+            logits = logits * scale
+        p = jnp.exp(logits - lse[:, h:h + 1])
+        dp = jax.lax.dot_general(
+            pick(h, do), v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bq, bkv) f32
+        ds = p * (dp - deltas[h])
+        if ragged_kv:
+            ds = jnp.where(col_ok, ds, 0.0)
+        acc = acc_ref[...] + jnp.dot(ds.astype(k.dtype), k,
+                                     preferred_element_type=jnp.float32)
+        acc_ref[...] = pick(h, acc, acc_ref[...])
+
+    @pl.when(kv_i == 0)
+    def _emit_delta():  # columns → the rows dkv reads: head h on sublane h
+        at = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], _LANE), 1)
+        tile = jnp.zeros(at.shape, jnp.float32)
+        for h in range(heads):
+            tile = jnp.where(at == h, deltas[h], tile)
+        delta_ref[0, 0] = tile.T[:_STAT_ROWS]
 
     @pl.when(kv_i == n_kv - 1)
     def _emit():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    n_valid: int, block_q: int, block_kv: int, n_q: int):
-    """dv_j = Σ_i p_ijᵀ·do_i and dk_j = Σ_i ds_ijᵀ·q_i·scale — grid
-    transposed: one K/V block per (outer) program, q chunks streamed
-    innermost."""
-    q_i = pl.program_id(2)
+def _bwd_dkv_kernel(lse_ref, delta_ref, q_ref, k_ref, v_ref, do_ref, *rest,
+                    scale: float, n_valid: int, block_q: int, n_q: int,
+                    heads: int, ragged_q: bool, packed: bool):
+    """One (row, lane group, kv-block, q-chunk) program of
+    dv_j = Σ_i p_ij·do_i and dk_j = scale · Σ_i ds_ij·q_i — grid transposed:
+    one K/V block per (outer) program, q chunks innermost (one chunk: q and
+    do resident across the lane group's K/V blocks).
+
+    The scores are computed TRANSPOSED, ``sᵀ = k·qᵀ`` (bkv, bq), so that
+    ``pᵀ·do`` and ``dsᵀ·q`` contract over the last axis of their left
+    operand — no (bq, bkv) transposes — and lse and delta broadcast as the
+    (1, bq) rows they arrive as. Heads as in :func:`_bwd_dq_kernel`, with K
+    and V the masked side. A K/V row past the sequence feeds only its own,
+    dropped, dk and dv rows; q and do rows past it (``ragged_q``) are zeroed
+    and their statistics selected to lse ``_LSE_PAST_END`` and delta 0 — here,
+    where they are read: the dq launch tiles the tokens with its own q block
+    and writes delta only as far as that reaches, so part of this chunk's may
+    be memory nobody wrote — and p and ds there are an exact 0.
+
+    ``packed``: dk and dv are two column blocks of ONE array, the projection's
+    gradient that the dq launch began (``rest[0]``, aliased to the result and
+    never read). A program can hold one block of a result, so the innermost
+    axis has one step more: the result's block is dk's through the q chunks
+    and dv's at the extra step, where nothing is computed; the pipeline
+    writes each back when the block index moves on."""
+    if packed:
+        _, dk_ref, dk_acc, dv_acc = rest
+        dv_ref = dk_ref
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
+    q_i = pl.program_id(3)
 
     @pl.when(q_i == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # input-dtype GEMMs, f32 accumulation — see _fwd_kernel
-    q = q_ref[0]    # (bq, D)
-    k = k_ref[0]    # (bkv, D)
-    v = v_ref[0]
-    do = do_ref[0]  # (bq, D)
-    lse = lse_ref[0][:, :1]             # (bq, 1), lane-replicated block
-    delta = delta_ref[0][:, :1]         # (bq, 1)
+    def _fold_chunk():
+        # input-dtype GEMMs, f32 accumulation — see _fwd_kernel
+        q = q_ref[0]    # (bq, lanes)
+        k = k_ref[0]    # (bkv, lanes)
+        v = v_ref[0]
+        do = do_ref[0]  # (bq, lanes)
+        fold = _scale_folds_into_q(scale)
+        if fold:
+            k = k * scale  # the same products as q · scale, bit for bit
+        if ragged_q:
+            row = q_i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, q.shape, 0)
+            q = jnp.where(row < n_valid, q, jnp.zeros_like(q))
+            do = jnp.where(row < n_valid, do, jnp.zeros_like(do))
+        lse = lse_ref[0, 0]      # (8, bq): head h's on sublane h
+        delta = delta_ref[0, 0]  # (8, bq)
+        if ragged_q:
+            tok = q_i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, lse.shape, 1)
+            lse = jnp.where(tok < n_valid, lse, _LSE_PAST_END)
+            delta = jnp.where(tok < n_valid, delta, 0.0)
+        pick = _head_picker(dk_acc.shape, heads)  # on (bkv, lanes) tiles
 
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (bq, bkv) f32
-    # a padded q row's garbage lse would poison VALID kv columns through the
-    # column-sum — masking rows here is correctness, not hygiene
-    row = q_i * block_q + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-    col = pl.program_id(1) * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, logits.shape, 1)
-    p = jnp.where((row < n_valid) & (col < n_valid),
-                  jnp.exp(logits - lse), 0.0)
-    dv_acc[...] += jax.lax.dot_general(  # pᵀ·do: (bkv, D)
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)  # (bq, bkv) f32
-    ds = p * (dp - delta)
-    dk_acc[...] += jax.lax.dot_general(  # dsᵀ·q: (bkv, D)
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+        for h in range(heads):  # unrolled: the compiler overlaps the heads
+            logits = jax.lax.dot_general(
+                pick(h, k), q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (bkv, bq) f32
+            if not fold:
+                logits = logits * scale
+            p = jnp.exp(logits - lse[h:h + 1])
+            dv = dv_acc[...] + jnp.dot(p.astype(do.dtype), do,
+                                       preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                pick(h, v), do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (bkv, bq) f32
+            ds = p * (dp - delta[h:h + 1])
+            dk = dk_acc[...] + jnp.dot(ds.astype(q.dtype), q,
+                                       preferred_element_type=jnp.float32)
+            dv_acc[...] = pick(h, dv, dv_acc[...])
+            dk_acc[...] = pick(h, dk, dk_acc[...])
+
+    if packed:
+        pl.when(q_i < n_q)(_fold_chunk)
+    else:
+        _fold_chunk()
 
     @pl.when(q_i == n_q - 1)
     def _emit():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        if not packed:
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if packed:
+        @pl.when(q_i == n_q)
+        def _emit_dv():
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 #: the scoped VMEM ONE kernel's blocks, scratch and live temporaries must fit
@@ -571,19 +706,33 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _SCOPED_VMEM_BYTES = flops.vmem_bytes("TPU v5 lite")
 
 
-def _bwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int) -> int:
-    """Scoped VMEM the larger backward kernel needs at blocks (bq, bkv):
-    double-buffered in/out blocks, the f32 accumulators (dq: one (bq, D);
-    dk/dv: two (bkv, D)), the lane-replicated lse/delta rows and the live
-    (bq, bkv) temporaries (logits, p, dp, ds; narrower once cast to a 16-bit
-    GEMM feed). The temporaries' weight is calibrated against the v5e
-    compiler's accept/refuse frontier at N=2501 for f32 and bf16 —
-    tests/test_chip_compile.py compiles what this admits."""
-    rows = 2 * 2 * bq * _LANE * 4
-    live = bq * bkv * (2.4 + 1.4 * itemsize)
-    dq = bq * dp * (6 * itemsize + 4) + bkv * dp * 4 * itemsize
-    dkv = bq * dp * 4 * itemsize + bkv * dp * (8 * itemsize + 8)
-    return int(max(dq, dkv) + rows + live)
+def _bwd_vmem_bytes(kernel: str, bq: int, bkv: int, dp: int, itemsize: int,
+                    heads: int = 1) -> int:
+    """Scoped VMEM the backward ``kernel`` (``"dq"`` or ``"dkv"``) needs at
+    blocks (bq, bkv) with ``heads`` heads on the block's lanes: the (bq, bkv)
+    tiles the compiler keeps alive (scores, p, dp, ds and the cast to the
+    GEMM feed come to 2 × itemsize bytes an element: one f32 tile in bf16,
+    two in f32, and no more for the other heads of a lane group), what
+    scales with the q rows (dq: the double-buffered q, o, do and dq blocks,
+    the f32 accumulator, o·do and the delta tile, the transposed lse and
+    every head's masked q and do; dkv: q and do, twice, and their zeroed
+    copies) and what scales with the K/V rows (dq: K and V, twice, and K
+    zeroed; dkv: K, V, dk and dv, twice, two f32 accumulators and a head's
+    masked K and V). An upper bound fitted on the sizes the v5e compiler
+    reports where it refuses — bf16 and f32, 256 to 2,560 rows a side, one,
+    two and four heads a lane group, 2,501 tokens: never under a reported
+    size, within 4.5 MiB of it in bf16 at two and four heads near the limit
+    and further over elsewhere (one head; float32, which the compiler packs
+    tighter than linearly) — tests/test_chip_compile.py compiles what this
+    admits, at its edge too."""
+    tiles = 2 * itemsize * bq * bkv
+    if kernel == "dq":
+        rows = (bq * (dp * ((3 + 2 * heads) * itemsize + 36) + 64)
+                + bkv * dp * 5 * itemsize)
+    else:
+        rows = (bq * (dp * (6 * itemsize + 2) + 64)
+                + bkv * dp * (10 * itemsize + 8))
+    return tiles + rows + (1 << 19)
 
 
 def _fwd_vmem_bytes(bq: int, bkv: int, dp: int, itemsize: int,
@@ -617,14 +766,12 @@ def _fwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
     admits; where none fits, the streamed (256, 512). An explicit ``block_kv``
     that covers the sequence asks for the same schedule, and ``block_q`` is
     then halved until the model admits it (float32 with two heads on the lanes
-    at 2,501 tokens: 512 → 256). Lane width and not the sublane minimum
-    because of ONE shape: at 2,501 tokens it is the 2,560 rows the backward
-    pads K and V to as well, so where the forward still pads (the head-major
-    layout) the 200px training step pads them once (2,512 rows made it pad
-    twice and cost dp4 3 %: PERF.md section 6, PR 25). At other lengths
-    forward and backward still pad K and V apart (1,025 tokens: 1,152 and
-    1,536 rows); that belongs to the backward kernels' issue (ROADMAP Speed
-    item 2b)."""
+    at 2,501 tokens: 512 → 256). Lane width and not the sublane minimum: a
+    lane-dense score tile, and the length the backward's resident chunk takes
+    too (2,560 rows at 2,501 tokens). The backward no longer pads K and V at
+    all, so the old cost of padding them twice (PERF.md section 6, PR 25)
+    is gone on both layouts: the head-major backward pads tokens to the
+    sublane tile only and lets the last block end past the array."""
     isz = jnp.dtype(dtype).itemsize
 
     def fits(bq, bkv):
@@ -645,112 +792,229 @@ def _fwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
 
 
 def _default_blocks(block_q, block_kv) -> tuple:
-    """The streamed schedule's and the backward's blocks where the caller
-    left them to the kernel."""
+    """The streamed schedule's blocks where the caller left them to the
+    kernel, forward and backward."""
     return (256 if block_q is None else block_q,
             512 if block_kv is None else block_kv)
 
 
-def _bwd_blocks(bq: int, bkv: int, n_pad: int, dp: int, dtype) -> tuple:
-    """The backward's own (block_q, block_kv): the forward's, with the larger
-    side halved until both backward kernels fit the scoped VMEM. The budgets
-    differ — the dk/dv kernel carries two (bkv, D) accumulators and (bq, bkv)
-    temporaries the forward never holds — so forward-legal blocks (f32 at
-    ``NS_FLASH_BLOCKS``) can overflow it. The residuals are unpadded, so the
-    backward is free to tile differently from the forward."""
+def _bwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
+                heads: int = 1) -> tuple:
+    """``((block_q, block_kv) of dq, (block_q, block_kv) of dkv)``, each
+    kernel's own, Mosaic-legal for this dtype and padded sequence, from what
+    the forward's choice looks at too: padded length, lanes, dtype, heads a
+    lane group. A q block is also the lane dim of the statistics' block, so
+    it is a multiple of 128 (or the whole padded sequence).
+
+    Left to the kernel, each takes the side it streams as ONE chunk wherever
+    :func:`_bwd_vmem_bytes` admits that — dq keeps a lane group's K and V
+    resident across its q blocks, dkv keeps q and do resident across its K/V
+    blocks — at the largest other block of 512, 256, 128 (or the one given):
+    (512, 2560) and (2560, 512) at the 200px trunk's 2,501 tokens in bf16,
+    where a launch is 4.31 and 6.26 ms on the v5e against 5.94 and 8.04 ms
+    at (256, 512) (PERF.md section 6, PR 29). Where nothing fits, and where
+    both blocks are given, the streamed (256, 512) or the given pair, with
+    the larger side halved until the kernel fits: its budget differs from the
+    forward's, so forward-legal blocks (f32 at ``NS_FLASH_BLOCKS``) can
+    overflow it."""
     isz = jnp.dtype(dtype).itemsize
-    while _bwd_vmem_bytes(bq, bkv, dp, isz) > _SCOPED_VMEM_BYTES:
-        if bkv >= bq:
-            smaller = bq, tiling.legal_block(max(1, bkv // 2), n_pad, dtype)
-        else:
-            smaller = tiling.legal_block(max(1, bq // 2), n_pad, dtype), bkv
-        if smaller == (bq, bkv):
-            raise ValueError(
-                f"flash attention backward: no legal blocks fit "
-                f"{_SCOPED_VMEM_BYTES >> 20} MiB of VMEM at head dim {dp} "
-                f"({jnp.dtype(dtype).name}) — smallest tried {smaller}")
-        bq, bkv = smaller
-    return bq, bkv
+    whole = tiling.round_up(n_pad, _LANE)
+
+    def q_block(want):
+        return tiling.legal_block(want, n_pad, dtype, min_unit=_LANE)
+
+    def kv_block(want):
+        return tiling.legal_block(want, n_pad, dtype)
+
+    def fits(kernel, bq, bkv):
+        return _bwd_vmem_bytes(kernel, bq, bkv, dp, isz,
+                               heads) <= _SCOPED_VMEM_BYTES
+
+    def streamed(kernel):
+        bq, bkv = _default_blocks(block_q, block_kv)
+        bq, bkv = q_block(bq), kv_block(bkv)
+        while not fits(kernel, bq, bkv):
+            smaller = ((bq, kv_block(max(1, bkv // 2))) if bkv >= bq
+                       else (q_block(max(1, bq // 2)), bkv))
+            if smaller == (bq, bkv):
+                raise ValueError(
+                    f"flash attention backward: no legal blocks fit "
+                    f"{_SCOPED_VMEM_BYTES >> 20} MiB of VMEM at {dp} lanes "
+                    f"({jnp.dtype(dtype).name}) — smallest tried {smaller}")
+            bq, bkv = smaller
+        return bq, bkv
+
+    def resident(kernel, given):
+        if (block_kv if kernel == "dq" else block_q) is not None:
+            return None  # the streamed side was asked for in chunks
+        other = q_block if kernel == "dq" else kv_block
+        for want in ((512, 256, 128) if given is None else (given,)):
+            pair = ((other(want), whole) if kernel == "dq"
+                    else (whole, other(want)))
+            if fits(kernel, *pair):
+                return pair
+        return None
+
+    return (resident("dq", block_q) or streamed("dq"),
+            resident("dkv", block_kv) or streamed("dkv"))
 
 
-def _bwd_call(qh, kh, vh, gh, lse, delta, *, scale, n_valid, bq, bkv,
-              interpret):
-    """The two backward kernels on head-major operands: dq (grid like the
-    forward) and dk/dv (grid transposed)."""
-    BH, Nq, Dp = qh.shape
-    n_q, n_kv = Nq // bq, kh.shape[1] // bkv
-    q_spec = pl.BlockSpec((1, bq, Dp), lambda b, i, j: (b, i, 0))
-    kv_spec_dq = pl.BlockSpec((1, bkv, Dp), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0))
+def _lse_rows(lse, n_valid: int, tokens: int):
+    """The log-sum-exp as both backward kernels read it, ``(rows, groups, 8,
+    tokens)`` f32: head ``h`` of the lane group on sublane ``h``, tokens on
+    the lanes — 32 bytes a token and lane group where the lane-replicated
+    columns the kernels once took were 512 a head, and the one-lane residual
+    ``(rows, groups, heads, ≥ n_valid)`` as it is but for the padding. What
+    lies past the sequence is padding and means nothing (the residual holds
+    there whatever the forward computed for its stale q rows): dq's rows
+    there are dropped, and dkv selects what it reads there."""
+    heads = lse.shape[2]
+    return jnp.pad(lse[..., :n_valid], (
+        (0, 0), (0, 0), (0, _STAT_ROWS - heads), (0, tokens - n_valid)))
 
+
+def _bwd_call(lse, q, k, v, o, do, *, offsets, groups, heads, lanes, packed,
+              scale, n_valid, dq_blocks, dkv_blocks, interpret):
+    """The two backward launches. q, k, v, the context ``o`` and the
+    cotangent ``do`` are addressed as :func:`_fwd_call` addresses q, k, v —
+    ``(rows, tokens, columns)`` arrays read in ``(1, block, lanes)`` blocks at
+    column block ``offsets[i] + g`` (``o`` and ``do`` at ``g``), the token
+    axis free to end inside the last block — and the gradients go back
+    through the same blocks: three ``(rows, tokens, groups·lanes)`` arrays,
+    or (``packed``) ONE ``(rows, tokens, 3·groups·lanes)`` array that the dq
+    launch creates and the dkv launch, taking it aliased to its own result,
+    completes. ``lse``: :func:`_lse_rows`; the dq launch writes delta in the
+    same form for the dkv launch."""
+    rows, n_tok = do.shape[:2]
+    q_off, k_off, v_off = offsets
+    width = groups * lanes
+    semantics = pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+    bq, bkv = dq_blocks
+    n_q, n_kv = pl.cdiv(n_tok, bq), pl.cdiv(n_tok, bkv)
+    stat_spec = pl.BlockSpec((1, 1, _STAT_ROWS, bq),
+                             lambda b, g, i, j: (b, g, 0, i))
+    q_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, bq, lanes), lambda b, g, i, j: (b, i, off + g))
+    kv_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, bkv, lanes), lambda b, g, i, j: (b, j, off + g))
     with profiling.scope("flash_attention/dq"):
-        dq = pl.pallas_call(
+        dq, delta = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, n_valid=n_valid,
-                              block_q=bq, block_kv=bkv, n_kv=n_kv),
-            grid=(BH, n_q, n_kv),
-            in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec,
-                      row_spec],
-            out_specs=q_spec,
-            out_shape=_sds(qh.shape, qh.dtype, qh),
-            scratch_shapes=[pltpu.VMEM((bq, Dp), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                              block_kv=bkv, n_kv=n_kv, heads=heads,
+                              ragged_kv=n_valid % bkv != 0),
+            grid=(rows, groups, n_q, n_kv),
+            in_specs=[stat_spec, q_spec(q_off), kv_spec(k_off),
+                      kv_spec(v_off), q_spec(0), q_spec(0)],
+            out_specs=[q_spec(q_off), stat_spec],
+            out_shape=[_sds((rows, n_tok, (3 if packed else 1) * width),
+                            q.dtype, q),
+                       _sds(lse.shape, jnp.float32, q)],
+            scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)],
+            compiler_params=semantics,
             interpret=interpret,
             name="dq",
-        )(qh, kh, vh, gh, lse, delta)
+        )(lse, q, k, v, o, do)
 
-    # transposed grid: (head, kv block, q chunk innermost)
-    q_spec_t = pl.BlockSpec((1, bq, Dp), lambda b, j, i: (b, i, 0))
-    kv_spec_t = pl.BlockSpec((1, bkv, Dp), lambda b, j, i: (b, j, 0))
-    row_spec_t = pl.BlockSpec((1, bq, _LANE), lambda b, j, i: (b, i, 0))
+    # transposed grid: K/V blocks outer, q chunks innermost; packed: one
+    # step more, at which the result's block moves from dk's column block to
+    # dv's (see _bwd_dkv_kernel) and the q chunk stays
+    bq, bkv = dkv_blocks
+    n_q, n_kv = pl.cdiv(n_tok, bq), pl.cdiv(n_tok, bkv)
+    last = n_q - 1
+
+    def chunk(i):
+        return jnp.minimum(i, last) if packed else i
+
+    stat_spec = pl.BlockSpec((1, 1, _STAT_ROWS, bq),
+                             lambda b, g, j, i: (b, g, 0, chunk(i)))
+    q_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, bq, lanes), lambda b, g, j, i: (b, chunk(i), off + g))
+    kv_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, bkv, lanes), lambda b, g, j, i: (b, j, off + g))
+    operands = (lse, delta, q, k, v, do)
+    in_specs = [stat_spec, stat_spec, q_spec(q_off), kv_spec(k_off),
+                kv_spec(v_off), q_spec(0)]
+    if packed:
+        operands += (dq,)
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        out_specs = pl.BlockSpec(
+            (1, bkv, lanes), lambda b, g, j, i: (
+                b, j, jnp.where(i < n_q, k_off, v_off) + g))
+        out_shape = _sds(dq.shape, dq.dtype, q)
+    else:
+        out_specs = [kv_spec(0), kv_spec(0)]
+        out_shape = [_sds((rows, n_tok, width), k.dtype, q)] * 2
     with profiling.scope("flash_attention/dkv"):
-        dk, dv = pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, n_valid=n_valid,
-                              block_q=bq, block_kv=bkv, n_q=n_q),
-            grid=(BH, n_kv, n_q),
-            in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                      row_spec_t],
-            out_specs=[kv_spec_t, kv_spec_t],
-            out_shape=[_sds(kh.shape, kh.dtype, kh),
-                       _sds(vh.shape, vh.dtype, vh)],
-            scratch_shapes=[pltpu.VMEM((bkv, Dp), jnp.float32),
-                            pltpu.VMEM((bkv, Dp), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                              block_q=bq, n_q=n_q, heads=heads,
+                              ragged_q=n_valid % bq != 0, packed=packed),
+            grid=(rows, groups, n_kv, n_q + packed),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            input_output_aliases={len(operands) - 1: 0} if packed else {},
+            scratch_shapes=[pltpu.VMEM((bkv, lanes), jnp.float32),
+                            pltpu.VMEM((bkv, lanes), jnp.float32)],
+            compiler_params=semantics,
             interpret=interpret,
             name="dkv",
-        )(qh, kh, vh, gh, lse, delta)
-    return dq, dk, dv
+        )(*operands)
+    return (out,) if packed else (dq, *out)
 
 
-def _flash_backward(q, k, v, o, lse, g, scale, block_q, block_kv):
-    B, N, H, D = q.shape
-    qh, kh, vh, oh, gh = (_to_heads(x, B, N, H, D) for x in (q, k, v, o, g))
-    BH, Np, Dp = qh.shape
-    block_q, block_kv = _default_blocks(block_q, block_kv)
-    bq, bkv = _bwd_blocks(tiling.legal_block(block_q, Np, qh.dtype),
-                          tiling.legal_block(block_kv, Np, qh.dtype),
-                          Np, Dp, qh.dtype)
-    qh, oh, gh = (_pad_to(x, 1, bq) for x in (qh, oh, gh))
-    kh, vh = _pad_to(kh, 1, bkv), _pad_to(vh, 1, bkv)
-    # lse (BH, Nq⁺) and delta get lane-replicated to (…, LANE) blocks here —
-    # sublane-dim-1 (1, bq) row blocks don't lower on TPU (the (8, 128) tile
-    # rule); the broadcast is per-backward-call, so the residual stays O(N)
-    lse = _pad_to(lse, 1, bq)
-    lse = jnp.broadcast_to(lse[:, :, None], (*lse.shape, _LANE))
-    delta = jnp.sum(oh.astype(jnp.float32) * gh.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None], (*delta.shape, _LANE))
-
-    rows = rows_spec(BH)
-    dq, dk, dv = per_device(
-        functools.partial(_bwd_call, scale=scale, n_valid=N, bq=bq, bkv=bkv,
-                          interpret=kernel_interpret()),
-        (rows,) * 6, (rows, rows, rows))(qh, kh, vh, gh, lse, delta)
-
-    def from_heads(x):
-        return x[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
-
-    return from_heads(dq), from_heads(dk), from_heads(dv)
+def _flash_backward(operands, o, lse, g, num_heads, scale, block_q, block_kv):
+    """The gradients of ``operands`` as :func:`_attention` takes them, in
+    their form: three ``(B, N, H·D)`` arrays or the one packed ``(B, N,
+    3·H·D)``. ``o``, ``g``: the context and its cotangent ``(B, N, H·D)``;
+    ``lse``: the VJP forward's ``(B·H, padded tokens)``. Layout as the
+    forward's (:func:`_heads_per_lane_group`), counted by
+    ``kernels.flash_bwd_layout``; ``kernels.flash_bwd_schedule`` says whether
+    the dq launch holds K and V as one chunk (``resident``) or streams them
+    (the dkv launch chooses for q and do by the same rule)."""
+    packed = len(operands) == 1
+    B, N, C = o.shape
+    H, D = num_heads, C // num_heads
+    in_place = _heads_per_lane_group(H, D)
+    _kernels.inc("kernels.flash_bwd_layout",
+                 key="in_place" if in_place else "head_major")
+    if in_place:
+        # where the model holds them: nothing is moved
+        arrays = (*(operands * 3 if packed else operands), o, g)
+        offsets = tuple(i * C // _LANE for i in range(3)) if packed else (0,) * 3
+        rows, groups, heads, lanes = B, C // _LANE, in_place, _LANE
+        n_pad = tiling.round_up(N, 8)
+    else:
+        arrays = tuple(_to_heads(x, B, N, H, D) for x in (
+            *_unpack(operands, H), *_unpack((o, g), H)))
+        offsets = (0,) * 3
+        rows, groups, heads = B * H, 1, 1
+        n_pad, lanes = arrays[0].shape[1:]
+    dq_blocks, dkv_blocks = _bwd_blocks(block_q, block_kv, n_pad, lanes,
+                                        arrays[0].dtype, heads)
+    _kernels.inc("kernels.flash_bwd_schedule",
+                 key="resident" if N <= dq_blocks[1] else "streamed")
+    # a lane axis takes no partial block: whole q blocks of either kernel
+    lse = _lse_rows(lse.reshape(rows, groups, heads, -1), N, tiling.round_up(
+        N, math.lcm(dq_blocks[0], dkv_blocks[0])))
+    in_place_packed = bool(in_place) and packed
+    spec = rows_spec(rows)
+    grads = per_device(
+        functools.partial(
+            _bwd_call, offsets=offsets, groups=groups, heads=heads,
+            lanes=lanes, packed=in_place_packed, scale=scale, n_valid=N,
+            dq_blocks=dq_blocks, dkv_blocks=dkv_blocks,
+            interpret=kernel_interpret()),
+        (spec,) * 6, (spec,) * (1 if in_place_packed else 3))(lse, *arrays)
+    if in_place:
+        return grads
+    grads = [x[:, :N, :D].reshape(B, H, N, D).transpose(0, 2, 1, 3)
+             for x in grads]
+    if packed:  # (B, N, 3, H, D) is the projection's column order
+        return (jnp.stack(grads, axis=2).reshape(operands[0].shape),)
+    return tuple(x.reshape(B, N, C) for x in grads)
 
 
 def online_softmax_update(o, l, m, logits, v_blk):
@@ -831,16 +1095,12 @@ def _attention_fwd(operands, num_heads, scale, block_q, block_kv):
 
 
 def _attention_bwd(num_heads, scale, block_q, block_kv, residuals, g):
-    """The residuals stay as the forward read them (the packed projection is
-    kept packed): the backward lays q, k, v out head-major once, from them."""
+    """The residuals are the operands as the forward read them (the packed
+    projection stays packed), the context and one lane of lse: the backward
+    reads all of them where they lie (:func:`_flash_backward`)."""
     operands, o, lse = residuals
-    B, N, C = o.shape
-    split = lambda x: x.reshape(B, N, num_heads, -1)  # noqa: E731
-    grads = _flash_backward(*_unpack(operands, num_heads), split(o), lse,
-                            split(g), scale, block_q, block_kv)
-    if len(operands) == 1:  # (B, N, 3, H, D) is the projection's column order
-        return ((jnp.stack(grads, axis=2).reshape(operands[0].shape),),)
-    return (tuple(x.reshape(B, N, C) for x in grads),)
+    return (_flash_backward(operands, o, lse, g, num_heads, scale, block_q,
+                            block_kv),)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)

@@ -114,9 +114,43 @@ def _admitted(dtype, n=N):
                               LANE_GROUP)[1] >= n_pad]
 
 
+def _flash_bwd(dtype, blocks, packed=True, n=N):
+    """``dq`` and ``dkv`` alone, at explicit blocks or (a ``None``) at those
+    each picks from the shape: 4 heads of 64, two heads a lane group, q, k, v
+    read in place from the packed ``(rows, n, 768)`` projection (or three
+    ``(rows, n, 256)`` arrays), the context and the cotangent ``(rows, n,
+    256)``, the token axis ending inside the last block; the packed gradient
+    is begun by ``dq`` and completed by ``dkv``."""
+    def build(devices):
+        sds = _struct(SingleDeviceSharding(devices[0]))
+        operands = ((sds((ROWS, n, 3 * C), dtype),) if packed
+                    else (sds((ROWS, n, C), dtype),) * 3)
+        ctx = sds((ROWS, n, C), dtype)
+        lse = sds((ROWS * H, -(-n // 128) * 128), jnp.float32)
+        return (lambda operands, o, lse, g: fa._flash_backward(
+                    operands, o, lse, g, H, D ** -0.5, *blocks),
+                (operands, ctx, lse, ctx), 2)
+    return build
+
+
+def _bwd_admitted(kernel, dtype, n=N):
+    """Every block of 1024, 512, 256, 128 at which the backward VMEM model
+    admits ``kernel`` with its streamed side whole (pure arithmetic: safe at
+    import) — as the explicit (block_q, block_kv) that asks for it."""
+    n_pad = -(-n // 8) * 8
+    asks = [(b, None) if kernel == "dq" else (None, b)
+            for b in (1024, 512, 256, 128)]
+    def streamed_side(ask):
+        dq, dkv = fa._bwd_blocks(*ask, n_pad, 128, dtype, LANE_GROUP)
+        return dq[1] if kernel == "dq" else dkv[0]
+
+    return [ask for ask in asks if streamed_side(ask) >= n_pad]
+
+
 def _flash_grad(dtype):
     """Backward at NS_FLASH_BLOCKS: the f32 case only compiles because the
-    backward picks its own blocks (flash_attention._bwd_blocks)."""
+    backward shrinks the blocks it is given to its own budget
+    (flash_attention._bwd_blocks)."""
     def build(devices):
         sds = _struct(SingleDeviceSharding(devices[0]))
         q = sds((ROWS, N, H, D), dtype)
@@ -228,6 +262,20 @@ CASES = {
        for dt in (jnp.float32, jnp.bfloat16) for bq in _admitted(dt)},
     **{f"flash_grad-{np.dtype(dt).name}": _flash_grad(dt)
        for dt in (jnp.float32, jnp.bfloat16)},
+    **{f"flash_bwd-{np.dtype(dt).name}-auto{'-packed' * pk}": _flash_bwd(
+           dt, (None, None), packed=pk)
+       for dt in (jnp.float32, jnp.bfloat16) for pk in (False, True)},
+    **{f"flash_bwd-{np.dtype(dt).name}-256x512": _flash_bwd(dt, (256, 512))
+       for dt in (jnp.float32, jnp.bfloat16)},
+    **{f"flash_bwd-{np.dtype(dt).name}-{kernel}-{bq}x{bkv}": _flash_bwd(
+           dt, (bq, bkv))
+       for dt in (jnp.float32, jnp.bfloat16) for kernel in ("dq", "dkv")
+       for bq, bkv in _bwd_admitted(kernel, dt)},
+    # dq at block_q 128 (K/V whole) against dkv streaming q at 256, on a
+    # length neither divides: the statistics' blocks of the two differ
+    **{f"flash_bwd-{np.dtype(dt).name}-auto-n{n}": _flash_bwd(
+           dt, (None, None), n=n)
+       for dt, n in ((jnp.float32, 4097), (jnp.bfloat16, 8000))},
     **{f"dequant_matmul-n{n_out}": _dequant(n_out) for n_out in (3 * C, C)},
     **{f"mlp_pallas-{mode or 'float'}-{np.dtype(dt).name}": _mlp(mode, dt)
        for mode, dt in ((None, jnp.float32), (None, jnp.bfloat16),
@@ -272,6 +320,67 @@ def test_fwd_vmem_model_admits_only_what_compiles(bq, dtype, chip):
                              n=n)(chip)
     assert jax.jit(fn).lower(*args).compile().as_text().count(
         "tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kernel,block", [
+    ("dq", 512), ("dq", 256), ("dq", 128),
+    ("dkv", 512), ("dkv", 256), ("dkv", 128)])
+def test_bwd_vmem_model_admits_only_what_compiles(kernel, block, dtype, chip):
+    """At the model's edge: the longest sequence (to 128 tokens) at which
+    ``_bwd_vmem_bytes`` still admits ``kernel`` at this block with its
+    streamed side whole — K and V for dq, q and do for dkv — must compile
+    (both launches do: the other kernel takes what it picks there). The model
+    is fitted to this compiler's refusals, so a drift shows here and not as a
+    refused kernel on the chip."""
+    ask = (block, None) if kernel == "dq" else (None, block)
+    which = kernel == "dkv"
+
+    def whole(n):
+        return fa._bwd_blocks(*ask, n, 128, dtype, LANE_GROUP)[which] == (
+            (block, n) if kernel == "dq" else (n, block))
+
+    n = max(n for n in range(1024, 32768, 128) if whole(n))
+    assert not whole(n + 128)
+    fn, args, calls = _flash_bwd(jnp.dtype(dtype), ask, n=n)(chip)
+    assert jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call") == calls
+
+
+def test_backward_reads_and_writes_where_the_gemms_do(chip):
+    """What the in-place backward is for, read off the compiled depth-1 200px
+    dp train step: ``dq`` takes the qkv GEMM's ``[images, 2501, 768]`` result
+    three times, the context and the cotangent ``[images, 2501, 256]``, and
+    its first result is the projection's whole ``[images, 2501, 768]``
+    gradient, which ``dkv`` takes (aliased) and returns complete — and NO
+    instruction beside them produces a head-major or head-split array or a
+    lane-replicated ``[.., tokens, 128]`` f32 spread of lse or delta, as the
+    ``copy``, ``pad``, ``slice``, ``broadcast`` and ``concatenate``
+    instructions of the head-major backward did (14 % of the dp4 cell's step:
+    PERF.md section 6, PR 29). (The forward's own lane-replicated lse result,
+    ``[images, 2, 2560, 128]``, and the instructions that cut it to one lane
+    are exempt, by that shape.)"""
+    import re
+
+    text = _dp_train_step(chip)
+    images = 2  # 8 over four chips
+    results = re.findall(
+        r"^\s*(?:ROOT )?%([\w.-]+) = \(?\w+\[([\d,]+)\]", text, re.M)
+    tokens = range(N, 2560 + 1)  # true to lane-padded
+    fwd_lse = f"{images},{C // 128},2560,128"
+    found = {name: dims for name, dims in results  # a head's columns last
+             if int(dims.split(",")[-1]) in (D, 128)
+             and any(int(d) in tokens for d in dims.split(",")[:-1])
+             and dims != fwd_lse}
+    assert not found, found
+    dq = re.search(r"%dq(?:\.\d+)* = \((\w+)\[([\d,]+)\][^\n]*?"
+                   r"custom-call\(([^)]*)\)", text)
+    assert dq.group(2) == f"{images},{N},{3 * C}"
+    operands = [op.strip() for op in dq.group(3).split(",")]
+    assert operands[1] == operands[2] == operands[3]  # the projection, 3 times
+    dkv = re.search(r"%dkv(?:\.\d+)* = (\w+)\[([\d,]+)\]", text)
+    assert dkv.group(2) == f"{images},{N},{3 * C}"
+    assert "concatenate(" not in text
 
 
 # --- the kernels' instruction names: what the benchmark's readers match -----

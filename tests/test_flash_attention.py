@@ -122,7 +122,7 @@ def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
     """Blocks left ``None``: K/V resident at the 200px trunk (2,501 tokens,
     head size 64), streamed at 32,768 tokens — asked of the choice alone,
     nothing runs — and ``kernels.flash_fwd_schedule`` says which. Explicit
-    blocks are honoured, and the backward at ``None`` still tiles (256, 512)."""
+    blocks are honoured."""
     from ddim_cold_tpu.ops import flash_attention as fa
 
     def traced(n, *blocks, dtype=jnp.bfloat16, grad=False):
@@ -170,10 +170,11 @@ def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
     assert traced(2501, *fa.NS_FLASH_BLOCKS) == ({"resident": 1},
                                                  {"fwd": (1, 1, 5, 1)})
     assert traced(4096, 512, 512) == ({"streamed": 1}, {"fwd": (1, 1, 8, 8)})
-    # under grad: the VJP's forward is resident, dq and dk/dv tile (256, 512)
-    assert traced(2501, grad=True) == (
-        {"resident": 1},
-        {"fwd": (1, 1, 5, 1), "dq": (1, 10, 5), "dkv": (1, 5, 10)})
+    # under grad: the VJP's forward is resident; the backward picks its own
+    # blocks (test_backward_layout_and_schedule_are_chosen_from_the_shape)
+    schedule, grids = traced(2501, grad=True)
+    assert schedule == {"resident": 1} and grids["fwd"] == (1, 1, 5, 1)
+    assert set(grids) == {"fwd", "dq", "dkv"}
 
 
 @pytest.mark.parametrize("with_lse", [False, True], ids=["primal", "lse"])
@@ -310,6 +311,286 @@ def test_packed_entry_gradient_matches_dense(N, blocks, dtype, tol):
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(ref, np.float32),
                                    err_msg=f"d{name}", **tol)
+
+
+def _backward(q, k, v, g, scale, bq, bkv, *, packed, lse_tail=None):
+    """``flash_attention._flash_backward`` on ``(B, N, H, D)`` q, k, v and
+    cotangent ``g``, residuals from the VJP's own forward at the same blocks:
+    → dq, dk, dv stacked ``(3, B, N, H·D)``. ``lse_tail`` overwrites the
+    residual's entries past token N (the forward's stale q rows)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B, N, H, D = q.shape
+    if packed:
+        operands = (jnp.stack([q, k, v], axis=2).reshape(B, N, 3 * H * D),)
+    else:
+        operands = tuple(x.reshape(B, N, H * D) for x in (q, k, v))
+    out, lse = fa._flash_forward(operands, H, scale, bq, bkv, with_lse=True)
+    if lse_tail is not None:
+        lse = lse.at[:, N:].set(lse_tail)
+    grads = fa._flash_backward(operands, out, lse, g.reshape(B, N, H * D), H,
+                               scale, bq, bkv)
+    if packed:
+        assert grads[0].shape == operands[0].shape
+        assert grads[0].dtype == operands[0].dtype
+        return grads[0].reshape(B, N, 3, H * D).transpose(2, 0, 1, 3)
+    return jnp.stack(grads)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+@pytest.mark.parametrize("blocks", [(None, None), (64, 128)],
+                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("N", [8, 257, 300])
+@pytest.mark.parametrize("D", [32, 64, 128])
+def test_in_place_backward_is_bitwise_the_head_major_backward(
+        D, N, dtype, blocks, packed, monkeypatch):
+    """``dq`` and ``dkv`` reading q, k, v and the cotangent where the model
+    holds them and writing the gradients where the qkv GEMM's backward reads
+    them — several heads on the 128 lanes, the token axis ending inside the
+    last block (the interpreter fills what lies past it with NaN), the packed
+    gradient begun by one launch and completed by the other — against the
+    same shape transposed and zero-padded to head-major first, at equal
+    blocks: dq, dk and dv BITWISE."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    H = 2 * 128 // D  # two lane groups
+    q, k, v, g = (x.astype(dtype) for x in (
+        *_rand_qkv(47, 2, N, H, D), _rand_qkv(48, 2, N, H, D)[0]))
+    scale = D ** -0.5
+    chosen, real = [], fa._bwd_blocks
+
+    def spy(*args):
+        chosen.append(real(*args))
+        return chosen[-1]
+
+    monkeypatch.setattr(fa, "_bwd_blocks", spy)
+    before = fa._kernels.by_key("kernels.flash_bwd_layout")
+    ours = _backward(q, k, v, g, scale, *blocks, packed=packed)
+    after = fa._kernels.by_key("kernels.flash_bwd_layout")
+    assert after["in_place"] - before.get("in_place", 0) == 1
+    assert after.get("head_major", 0) == before.get("head_major", 0)
+    with monkeypatch.context() as patch:  # the rule says no: laid out first
+        patch.setattr(fa, "_heads_per_lane_group", lambda heads, head_dim: None)
+        want = _backward(q, k, v, g, scale, *blocks, packed=packed)
+    assert fa._kernels.by_key("kernels.flash_bwd_layout")["head_major"] == (
+        before.get("head_major", 0) + 1)
+    assert chosen[0] == chosen[1], chosen  # equal blocks, or nothing is shown
+    streamed = blocks[0] is not None and N > 128
+    assert (chosen[0][0][1] < N) == streamed  # dq: K/V chunks
+    assert (chosen[0][1][0] < N) == streamed  # dkv: q chunks
+    assert ours.dtype == q.dtype and np.isfinite(
+        np.asarray(ours, np.float32)).all()
+    for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,tol", [
+    ("float32", dict(rtol=1e-4, atol=1e-5)),
+    ("bfloat16", dict(rtol=2e-2, atol=2e-2)),
+])
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+@pytest.mark.parametrize("blocks", [(None, None), (128, 128)],
+                         ids=["resident", "streamed"])
+def test_backward_ragged_edge_is_finite_and_matches_dense(blocks, packed,
+                                                          dtype, tol):
+    """300 tokens, two heads of 64 on the lanes: every block of q, k, v, the
+    cotangent and the gradients ends past the arrays (the interpreter fills
+    what a program reads there with NaN), and the lse residual's tail — what
+    the forward computed for its stale q rows — is poisoned with NaN on top.
+    Nothing of it may reach a gradient: finite, and autodiff through the
+    dense einsum to the tolerances of the gradient tests."""
+    B, N, H, D = 1, 300, 2, 64
+    scale = D ** -0.5
+    q, k, v, g = (x.astype(dtype) for x in (
+        *_rand_qkv(51, B, N, H, D), _rand_qkv(52, B, N, H, D)[0]))
+    ours = _backward(q, k, v, g, scale, *blocks, packed=packed,
+                     lse_tail=jnp.nan)
+    assert np.isfinite(np.asarray(ours, np.float32)).all()
+    _, vjp = jax.vjp(
+        lambda q, k, v: _dense_attention_f32(q, k, v, scale)[1], q, k, v)
+    want = vjp(g.astype(jnp.float32))
+    for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32).reshape(B, N, H, D),
+            np.asarray(ref, np.float32), err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+@pytest.mark.parametrize("dtype,budget,tol", [
+    ("float32", 2_800_000, dict(rtol=1e-4, atol=1e-5)),
+    ("bfloat16", 2_400_000, dict(rtol=2e-2, atol=2e-2)),
+])
+def test_backward_unequal_q_blocks_on_a_ragged_length_match_dense(
+        dtype, budget, tol, packed, monkeypatch):
+    """``dq`` and ``dkv`` choose their q blocks apart, so the tokens their
+    last q blocks cover can differ: with the VMEM budget cut so that 600
+    tokens stream, ``dq`` runs at block_q 128 (it writes delta for 640
+    tokens) and ``dkv`` at 256 (it reads 768). What ``dkv`` reads past the
+    sequence (delta nobody wrote, the lse residual's poisoned tail) may not
+    reach dk or dv: finite, and dense's to the gradient tests' tolerances."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B, N, H, D = 1, 600, 2, 64
+    scale = D ** -0.5
+    monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", budget)
+    (dq_q, _), (dkv_q, _) = fa._bwd_blocks(None, None, N, 128, dtype, 2)
+    assert (dq_q, dkv_q) == (128, 256)  # or the case below shows nothing
+    assert fa.tiling.round_up(N, dq_q) < fa.tiling.round_up(N, dkv_q)
+    q, k, v, g = (x.astype(dtype) for x in (
+        *_rand_qkv(53, B, N, H, D), _rand_qkv(54, B, N, H, D)[0]))
+    ours = _backward(q, k, v, g, scale, None, None, packed=packed,
+                     lse_tail=jnp.nan)
+    assert np.isfinite(np.asarray(ours, np.float32)).all()
+    _, vjp = jax.vjp(
+        lambda q, k, v: _dense_attention_f32(q, k, v, scale)[1], q, k, v)
+    want = vjp(g.astype(jnp.float32))
+    for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32).reshape(B, N, H, D),
+            np.asarray(ref, np.float32), err_msg=name, **tol)
+
+
+def test_gradient_head_of_256_at_1841_tokens_is_finite_and_matches_dense():
+    """The public entry at the chip's own budget where the two kernels' q
+    blocks differ on a ragged length: float32, one head of 256, 1,841 tokens
+    — ``dq`` holds K and V whole at block_q 128 (1,920 tokens of delta),
+    ``dkv`` streams q at 256 (2,048 read)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B, N, H, D = 1, 1841, 1, 256
+    scale = D ** -0.5
+    assert fa._bwd_blocks(None, None, fa.tiling.round_up(N, 8), D,
+                          jnp.float32) == ((128, 1920), (256, 512))
+    q, k, v = _rand_qkv(55, B, N, H, D)
+    g = _rand_qkv(56, B, N, H, D)[0]
+    ours = jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, scale) * g), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        _dense_attention_f32(q, k, v, scale)[1] * g), argnums=(0, 1, 2))(
+            q, k, v)
+    for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   err_msg=name, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("N,H,D,layout,schedule,grids", [
+    # the 200px trunk: two heads a lane group; K/V resident for dq at block_q
+    # 512, q/do resident for dkv at block_kv 512 (packed: dkv's extra step)
+    (2501, 4, 64, "in_place", "resident",
+     {"dq": (2, 2, 5, 1), "dkv": (2, 2, 5, 1), "dkv_packed": (2, 2, 5, 2)}),
+    (2501, 12, 32, "in_place", "resident",
+     {"dq": (2, 3, 5, 1), "dkv": (2, 3, 5, 1), "dkv_packed": (2, 3, 5, 2)}),
+    # one local head of 64 under Ulysses, head sizes 80 and 256: head-major,
+    # one head a group, the same two launches
+    (2501, 1, 64, "head_major", "resident",
+     {"dq": (2, 1, 5, 1), "dkv": (2, 1, 5, 1), "dkv_packed": (2, 1, 5, 1)}),
+    (2501, 4, 80, "head_major", "resident",
+     {"dq": (8, 1, 5, 1), "dkv": (8, 1, 5, 1), "dkv_packed": (8, 1, 5, 1)}),
+    (2501, 2, 256, "head_major", "resident",
+     {"dq": (4, 1, 10, 1), "dkv": (4, 1, 10, 1), "dkv_packed": (4, 1, 10, 1)}),
+    # dq still holds K and V whole at block_q 128; dkv streams already
+    (8192, 4, 64, "in_place", "resident",
+     {"dq": (2, 2, 64, 1), "dkv": (2, 2, 16, 32),
+      "dkv_packed": (2, 2, 16, 33)}),
+    # too long to be resident: both stream at (256, 512)
+    (32768, 4, 64, "in_place", "streamed",
+     {"dq": (2, 2, 128, 64), "dkv": (2, 2, 64, 128),
+      "dkv_packed": (2, 2, 64, 129)}),
+])
+def test_backward_layout_and_schedule_are_chosen_from_the_shape(
+        N, H, D, layout, schedule, grids, monkeypatch):
+    """The backward's addressing follows the forward's rule (``128 % D == 0``
+    and ``H·D % 128 == 0``: in place, grid (images, lane groups, outer
+    blocks, inner chunks); anything else head-major, grid (images·heads, 1,
+    ...)) and its blocks the shape, each kernel's own. Asked of the trace
+    alone — nothing runs — for both entries; ``kernels.flash_bwd_layout`` and
+    ``kernels.flash_bwd_schedule`` (dq's K/V chunking) say which."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    B = 2
+    seen, real = {}, fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen[kw.get("name")] = tuple(kw["grid"])
+        return real(kernel, **kw)
+
+    def counted(before):
+        return {name: {key: n - before[name].get(key, 0) for key, n in
+                       fa._kernels.by_key(name).items()
+                       if n != before[name].get(key, 0)}
+                for name in before}
+
+    names = ("kernels.flash_bwd_layout", "kernels.flash_bwd_schedule")
+    before = {name: fa._kernels.by_key(name) for name in names}
+    x = jax.ShapeDtypeStruct((B, N, H, D), jnp.bfloat16)
+    packed = jax.ShapeDtypeStruct((B, N, 3 * H * D), jnp.bfloat16)
+    scale = D ** -0.5
+    with monkeypatch.context() as patch:
+        patch.setattr(fa.pl, "pallas_call", spy)
+        jax.eval_shape(jax.grad(lambda q, k, v: fa.flash_attention(
+            q, k, v, scale).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+            x, x, x)
+        apart = dict(seen)
+        assert jax.eval_shape(jax.grad(lambda qkv: fa.flash_attention_qkv(
+            qkv, H, scale).astype(jnp.float32).sum()), packed
+            ).shape == packed.shape
+    assert counted(before) == {names[0]: {layout: 2}, names[1]: {schedule: 2}}
+    assert (apart["dq"], apart["dkv"]) == (grids["dq"], grids["dkv"])
+    assert (seen["dq"], seen["dkv"]) == (grids["dq"], grids["dkv_packed"])
+
+    # the choice itself: explicit blocks are honoured by both kernels (a q
+    # block is a multiple of 128, the statistics' lane tile); one side given
+    # leaves the other to the kernel
+    blocks = lambda *a: fa._bwd_blocks(*a, 2504, 128, jnp.bfloat16, 2)  # noqa: E731
+    assert blocks(None, None) == ((512, 2560), (2560, 512))
+    assert blocks(256, 512) == ((256, 512), (256, 512))
+    assert blocks(300, 500) == ((384, 512), (384, 512))
+    assert blocks(128, None) == ((128, 2560), (128, 512))
+    assert blocks(None, 256) == ((256, 256), (2560, 256))
+    assert blocks(None, 1024) == ((256, 1024), (256, 1024))  # no room whole
+    assert fa._bwd_blocks(None, None, 2504, 128, jnp.float32, 2) == (
+        (256, 2560), (2560, 256))
+    for kernel, pair in zip(("dq", "dkv"), blocks(None, None)):
+        assert fa._bwd_vmem_bytes(kernel, *pair, 128, 2,
+                                  2) <= fa._SCOPED_VMEM_BYTES
+
+
+@pytest.mark.parametrize("config,use_flash,traces", [
+    ("oxford_flower_200_p4", True, 6),  # the flower200_train_dp4 cell's
+    ("vit_tiny", False, 0),             # the vit_tiny64_train_loader cell's
+])
+def test_trainer_traces_the_backward_in_place_and_resident(config, use_flash,
+                                                           traces):
+    """The trainer's own step (``train.step.make_train_step``), traced at the
+    benchmark's widths and depth, nothing run: each flash layer's backward is
+    counted once, ``in_place`` and ``resident``, and a model with ``use_flash``
+    off counts nothing."""
+    from ddim_cold_tpu.models import MODEL_CONFIGS
+    from ddim_cold_tpu.ops import flash_attention as fa
+    from ddim_cold_tpu.train.step import create_train_state, make_train_step
+
+    cfg = MODEL_CONFIGS[config]
+    model = DiffusionViT(dtype=jnp.bfloat16, use_flash=use_flash,
+                         drop_rate=0.0, attn_drop_rate=0.0,
+                         drop_path_rate=0.0, **cfg)
+    img = jax.ShapeDtypeStruct((2, *cfg["img_size"], 3), jnp.float32)
+    t = jax.ShapeDtypeStruct((2,), jnp.int32)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, jax.random.PRNGKey(0), 1e-3, 100,
+        (jnp.zeros(img.shape), jnp.zeros(img.shape), jnp.zeros((2,), jnp.int32))))
+    names = ("kernels.flash_bwd_layout", "kernels.flash_bwd_schedule")
+    before = [fa._kernels.by_key(name) for name in names]
+    jax.eval_shape(make_train_step(model), state, (img, img, t),
+                   jax.ShapeDtypeStruct((2,), jnp.uint32),
+                   jax.ShapeDtypeStruct((), jnp.float32))
+    for name, was, key in zip(names, before, ("in_place", "resident")):
+        now = fa._kernels.by_key(name)
+        assert {k: n - was.get(k, 0) for k, n in now.items()
+                if n != was.get(k, 0)} == ({key: traces} if traces else {})
 
 
 def test_flash_bf16_inputs():
@@ -539,7 +820,8 @@ def _tile_rule_spy(monkeypatch, fa):
             calls.append(name)
             in_specs = kw["in_specs"]
             for i, (spec, op) in enumerate(zip(in_specs, ops)):
-                check(spec.block_shape, op, op.dtype, f"{name} in[{i}]")
+                if spec.block_shape is not None:  # else: left in HBM, no block
+                    check(spec.block_shape, op, op.dtype, f"{name} in[{i}]")
             outs = kw["out_shape"]
             outs = outs if isinstance(outs, (list, tuple)) else [outs]
             specs = kw["out_specs"]

@@ -93,8 +93,14 @@ def test_p001_whole_dim_span_is_legal():
     # ... only as a tile-multiple block, only on the sublane axis
     ("fwd", (2501, 128), (100, 128), (26,), ["GRAFT-P001"]),
     ("fwd", (2504, 320), (8, 128), (313,), ["GRAFT-P001"]),
+    # the flash backward's too (dq: K/V resident; dkv: q/do resident)
+    ("dq", (2501, 128), (512, 128), (5,), []),
+    ("dq", (2501, 128), (2560, 128), (1,), []),
+    ("dkv", (2501, 128), (512, 128), (5,), []),
+    ("dkv", (2501, 128), (2560, 128), (1,), []),
+    ("dkv", (2501, 128), (100, 128), (26,), ["GRAFT-P001"]),
     # ... and only for the kernels whose bodies mask the ragged tail
-    ("dq", (2501, 128), (512, 128), (5,), ["GRAFT-P001"]),
+    ("mlp", (2501, 128), (512, 128), (5,), ["GRAFT-P001"]),
     (None, (2501, 128), (512, 128), (5,), ["GRAFT-P001"]),
 ])
 def test_p001_partial_final_block_only_where_the_kernel_masks_it(
@@ -108,7 +114,7 @@ def test_p001_partial_final_block_only_where_the_kernel_masks_it(
 
     closed = jax.make_jaxpr(f)(jax.ShapeDtypeStruct(shape, jnp.float32))
     assert _rules_of(_check(closed)) == rules
-    assert "fwd" in kernel_checks.RAGGED_SUBLANE_OK
+    assert kernel_checks.RAGGED_SUBLANE_OK == {"fwd", "dq", "dkv"}
 
 
 # --------------------------------------------------------------- P002
