@@ -545,11 +545,17 @@ _HYBRID_PATH = "ddim_cold_tpu/models/hybrid.py"
 #: what of ``Block``'s internals the hybrid trunk must refuse by name
 HYBRID_MUST_REFUSE = ("quant", "fused", "cache_mode", "scan_blocks",
                       "num_experts", "sp_mode", "use_flash")
+#: further spellings of the same options in the yaml, the model and the
+#: sampler, each of which must resolve to a name in ``REFUSED`` — for every
+#: layer stack alike (jamba, laguna): the refusals are the wrapper's
+HYBRID_MUST_REFUSE_SPELLINGS = HYBRID_MUST_REFUSE + (
+    "moe_dispatch", "seq_mesh", "seq_axis", "sp_degree", "flash_blocks",
+    "cache_interval", "capture_split", "skip_blocks", "token_cache")
 
 
 def check_hybrid_refusals() -> list:
-    """X004: the hybrid trunk (models/hybrid.py) has ONE program class per
-    sampler family. Every legal config class that reaches into ``Block``
+    """X004: the hybrid trunk (models/hybrid.py; whichever layer stack its
+    ``model_type`` chooses) has ONE program class per sampler family. Every legal config class that reaches into ``Block``
     (cached, quant, fused, sp) is refused by ``sampler_config_refusal`` under
     a name ``REFUSED`` explains; every other class is admitted. So the trunk
     adds no class to the lattice and the sweep owes it no witness."""
@@ -559,7 +565,8 @@ def check_hybrid_refusals() -> list:
         Finding("GRAFT-X004", _HYBRID_PATH, f"unrefused:{name}", 0,
                 f"hybrid.REFUSED does not name {name!r}: an option that "
                 "assumes Block's internals would fail on a shape instead")
-        for name in HYBRID_MUST_REFUSE if name not in hybrid.REFUSED]
+        for name in HYBRID_MUST_REFUSE_SPELLINGS
+        if hybrid._ALIASES.get(name, name) not in hybrid.REFUSED]
     for cls, cfg in enumerate_lattice():
         _, cached, _, _, _, quant, fused, sp_mode, sp_degree = cls
         reaches_block = bool(cached or quant or fused or sp_mode != "none"

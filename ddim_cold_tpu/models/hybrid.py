@@ -1,14 +1,22 @@
-"""HybridDenoiser — a Jamba-style hybrid trunk (Mamba-1 state-space layers
-with a causal grouped-query attention layer every ``attn_layer_period``,
-every layer followed by a gated SiLU MLP, RMSNorm throughout) as the x0
-denoiser: ``(x_t, t) → x̂0`` with the input and output stage of
-``DiffusionViT`` (``vit.embed_tokens`` / ``vit.pixel_head``: the same code,
-called by both).
+"""HybridDenoiser — a language model's decoder stack as the x0 denoiser:
+``(x_t, t) → x̂0`` with the input and output stage of ``DiffusionViT``
+(``vit.embed_tokens`` / ``vit.pixel_head``: the same code, called by both),
+the stack's layers and the final RMSNorm between them.
 
 The trunk's sizes are read from ``trunk``, a mapping whose keys are those of
-the language model's published ``config.json`` (``model_type: jamba``),
-letter for letter, so a configuration file carries the source's own keys.
-With x ∈ R^{L×hidden_size}, ε = ``rms_norm_eps``, no bias unless said:
+the language model's published ``config.json``, letter for letter, so a
+configuration file carries the source's own keys. Its ``model_type`` chooses
+the layer stack (:func:`stack_of`): ``jamba`` (the default; this module) or
+``laguna`` (``models/laguna.py``: window and full attention on shared K/V
+heads with rotary positions and per-head gates, a dense MLP or top-k routed
+experts of which this chip holds a share). One wrapper serves both: what the
+samplers and the engine read of a model, ``clone``, the refusals and
+``__call__`` below.
+
+The ``jamba`` stack: Mamba-1 state-space layers with a causal grouped-query
+attention layer every ``attn_layer_period``, every layer followed by a gated
+SiLU MLP, RMSNorm throughout. With x ∈ R^{L×hidden_size}, ε =
+``rms_norm_eps``, no bias unless said:
 
 * layer i: ``x += mixer_i(RMSNorm(x))``; ``x += W_down(SiLU(W_gate y) ⊙
   W_up y)``, ``y = RMSNorm(x)``, width ``intermediate_size``; after the last
@@ -24,8 +32,9 @@ With x ∈ R^{L×hidden_size}, ε = ``rms_norm_eps``, no bias unless said:
 * attention: ``num_attention_heads`` query heads on ``num_key_value_heads``
   shared K/V heads, no position term, scale head_dim^−½, causal mask, softmax
   in float32. Dense XLA attention (two layers of 28 in Jamba2-3B, 0.4 % of
-  the forward's FLOPs at 1,025 tokens); the causal shared-KV flash forward
-  is ROADMAP Reach's.
+  the forward's FLOPs at 1,025 tokens); moving them onto the masked flash
+  forward (``ops.flash_attention.masked_attention``, which the ``laguna``
+  stack runs) changes this stack's program and is ROADMAP Reach's.
 
 Every layer keeps its published causality: the scan, the convolution and
 the mask run in raster order from the class token.
@@ -34,9 +43,10 @@ Parameters are stored in ``param_dtype`` and computed in ``dtype``; handed a
 bfloat16 tree with ``dtype=bfloat16`` no program holds a float32 copy of it
 (2.87 B parameters at Jamba2-3B's widths: 5.75 GB against 11.5).
 
-What assumes ``Block``'s internals is refused by name (:data:`REFUSED`):
-``quant``, ``fused``, the step caches, ``scan_blocks``, ``num_experts`` > 1,
-sequence parallelism (``sp_mode``, ``seq_mesh``), ``use_flash``.
+What assumes ``Block``'s internals is refused by name (:data:`REFUSED`),
+whatever the stack: ``quant``, ``fused``, the step caches, ``scan_blocks``,
+``DiffusionViT``'s ``num_experts`` and ``moe_dispatch``, sequence parallelism
+(``sp_mode``, ``seq_mesh``), ``use_flash``.
 """
 
 from __future__ import annotations
@@ -62,13 +72,17 @@ REFUSED = {
     "fused": "the fused trunk kernels are Block's attention and Mlp",
     "cache_mode": "the step caches skip and re-run Block ranges by index",
     "scan_blocks": "the layer kind depends on the index: no one scanned body",
-    "num_experts": "every MLP of this trunk is dense",
-    "sp_mode": "the scan and the causal mask are sequential in the tokens",
-    "use_flash": "the flash kernels have no causal mask and no shared KV heads",
+    "num_experts": "num_experts and moe_dispatch put SwitchMlp into Block; "
+                   "a trunk's experts are its own (trunk: num_experts)",
+    "sp_mode": "the scan and the causal masks are sequential in the tokens",
+    "use_flash": "a stack picks its attention itself: the jamba stack's two "
+                 "layers are dense XLA attention, the laguna stack runs the "
+                 "masked flash forward wherever the backend is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
-_ALIASES = {"flash_blocks": "use_flash", "seq_mesh": "sp_mode",
+_ALIASES = {"flash_blocks": "use_flash", "moe_dispatch": "num_experts",
+            "seq_mesh": "sp_mode",
             "seq_axis": "sp_mode", "sp_degree": "sp_mode",
             "cache_interval": "cache_mode",
             "capture_split": "cache_mode", "skip_blocks": "cache_mode",
@@ -87,7 +101,8 @@ def refuse_any(options: Mapping[str, Any]) -> None:
     that :data:`REFUSED` names, under any of its spellings."""
     for option, value in options.items():
         unset = (value is None or value is False or value == "none"
-                 or (option == "num_experts" and value == 1))
+                 or (option == "num_experts" and value == 1)
+                 or (option == "moe_dispatch" and value == "einsum"))
         if _ALIASES.get(option, option) in REFUSED and not unset:
             raise refuse(option)
 
@@ -251,6 +266,56 @@ class HybridLayer(nn.Module):
                 norm("pre_ff_layernorm")(x))
 
 
+def check_trunk(c: Mapping[str, Any]) -> None:
+    """What the ``jamba`` stack cannot run, refused at construction."""
+    if c.get("num_experts", 1) != 1:
+        raise ValueError(
+            f"the jamba stack has no 'num_experts' ({c['num_experts']}): "
+            "every MLP of this stack is dense")
+    if c.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {c['hidden_act']!r}: this trunk's "
+                         "MLP and mixers are written for 'silu'")
+    if c.get("sliding_window") is not None:
+        raise ValueError("sliding_window: the attention layers here "
+                         "attend to every earlier token")
+    if c["hidden_size"] % c["num_attention_heads"] or (
+            c["num_attention_heads"] % c["num_key_value_heads"]):
+        raise ValueError(
+            "hidden_size must divide into num_attention_heads, and those "
+            "into num_key_value_heads")
+
+
+def layer(trunk, i: int, dtype, param_dtype, name: str) -> nn.Module:
+    """Layer ``i`` of the ``jamba`` stack."""
+    return HybridLayer(trunk, is_attention_layer(trunk, i), dtype, param_dtype,
+                       name=name)
+
+
+def stack_of(trunk: Mapping[str, Any]) -> tuple:
+    """``(check_trunk, layer)`` of ``trunk``'s layer stack, by the published
+    ``model_type``."""
+    model_type = trunk.get("model_type", "jamba")
+    if model_type == "jamba":
+        return check_trunk, layer
+    if model_type == "laguna":
+        from ddim_cold_tpu.models import laguna
+
+        return laguna.check_trunk, laguna.layer
+    raise ValueError(f"no layer stack for model_type {model_type!r}: "
+                     "'jamba' and 'laguna' are written")
+
+
+def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:
+    """``trunk`` hashable, as jit's static ``model`` argument has to be: the
+    published per-layer lists as tuples, nested groups frozen."""
+    def freeze(v):
+        if isinstance(v, Mapping):
+            return flax.core.FrozenDict({k: freeze(x) for k, x in v.items()})
+        return tuple(freeze(x) for x in v) if isinstance(v, (list, tuple)) else v
+
+    return freeze(trunk)
+
+
 class HybridDenoiser(nn.Module):
     """``(x_t, t) → x̂0``; NHWC in [−1, 1], ``t`` int32 per sample, as
     ``DiffusionViT``. Exposes what the samplers and the engine read of a
@@ -267,22 +332,10 @@ class HybridDenoiser(nn.Module):
     param_dtype: Dtype = jnp.float32
 
     def __post_init__(self):
-        # hashable, as jit's static ``model`` argument has to be
-        c = flax.core.FrozenDict(self.trunk)
+        c = _frozen(self.trunk)
         object.__setattr__(self, "trunk", c)
-        if c.get("num_experts", 1) != 1:
-            raise refuse("num_experts")
-        if c.get("hidden_act", "silu") != "silu":
-            raise ValueError(f"hidden_act {c['hidden_act']!r}: this trunk's "
-                             "MLP and mixers are written for 'silu'")
-        if c.get("sliding_window") is not None:
-            raise ValueError("sliding_window: the attention layers here "
-                             "attend to every earlier token")
-        if c["hidden_size"] % c["num_attention_heads"] or (
-                c["num_attention_heads"] % c["num_key_value_heads"]):
-            raise ValueError(
-                "hidden_size must divide into num_attention_heads, and those "
-                "into num_key_value_heads")
+        check, _ = stack_of(c)
+        check(c)
         super().__post_init__()
 
     @property
@@ -325,10 +378,10 @@ class HybridDenoiser(nn.Module):
         tokens = vit.embed_tokens(self, x, t, drop_rate=0.0,
                                   deterministic=True,
                                   param_dtype=self.param_dtype)
+        _, layer_of = stack_of(self.trunk)
         for i in range(self.depth):
-            tokens = HybridLayer(
-                self.trunk, is_attention_layer(self.trunk, i), self.dtype,
-                self.param_dtype, name=f"layers_{i}")(tokens)
+            tokens = layer_of(self.trunk, i, self.dtype, self.param_dtype,
+                              name=f"layers_{i}")(tokens)
         tokens = RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
                          self.param_dtype, name="final_layernorm")(tokens)
         return vit.pixel_head(self, tokens, param_dtype=self.param_dtype)

@@ -1,0 +1,233 @@
+"""The ``laguna`` layer stack of ``HybridDenoiser`` (``models/hybrid.py``
+chooses it by the trunk's ``model_type``): a decoder whose layers mix causal
+*window* and causal *full* attention on shared K/V heads, with rotary
+positions and a per-head sigmoid gate, and whose MLPs are dense in the
+leading layers and top-k routed experts plus a shared one after them. The
+wrapper, the input and output stage, ``RMSNorm`` and ``GatedMlp`` are
+``hybrid``'s.
+
+Sizes come from ``trunk``, a mapping under the keys of the published
+``config.json`` (``model_type: laguna``), letter for letter; the four
+per-layer lists (``layer_types``, ``mlp_layer_types``,
+``num_attention_heads_per_layer``, ``gating_types``) may be longer than
+``num_hidden_layers``: layer i reads entry i. With x ∈ R^{L×hidden_size},
+ε = ``rms_norm_eps``, no bias:
+
+* layer i: ``x += Attn_i(RMSNorm(x))``; ``x += FFN_i(RMSNorm(x))``.
+* ``Attn_i``, H = ``num_attention_heads_per_layer[i]`` query heads on
+  ``num_key_value_heads`` K/V heads of ``head_dim``: ``q = y W_q``, ``k = y
+  W_k``, ``v = y W_v``, ``g = sigmoid(y W_g)`` (one gate a head, from the
+  layer's normed input). Rotary positions 0 (class token), 1, … in raster
+  order on q and k, by ``rope_parameters[layer_types[i]]``
+  (:func:`rotary_frequencies`: ``default``, or ``yarn`` over the first
+  ``partial_rotary_factor`` of the dims, ``rotate_half`` pairing). Query head
+  h reads K/V head ``h // (H / num_key_value_heads)``; scores ``q k^T ·
+  head_dim^−½`` under the mask j ≤ t (``full_attention``) or t −
+  ``sliding_window`` < j ≤ t (``sliding_attention``), softmax in float32;
+  ``o_h = g_h · Σ_j p_hj v_j``; out ``= concat_h(o_h) W_o``.
+  ``ops.flash_attention.masked_attention`` computes it: the ``fwd_masked``
+  kernel on the TPU, blockwise XLA elsewhere.
+* ``FFN_i``: ``mlp_layer_types[i] == "dense"``: the gated SiLU MLP at
+  ``intermediate_size``; ``"sparse"``: ``moe.HeldExpertsMlp``, softmax router
+  over ``num_experts_routed`` outputs, ``num_experts_per_tok`` a token,
+  weights renormalised (``norm_topk_prob``) and scaled by
+  ``moe_routed_scaling_factor``, experts and the shared expert at
+  ``moe_intermediate_size`` / ``shared_expert_intermediate_size``.
+
+**The share.** ``num_experts`` is how many experts THIS chip holds,
+``experts_held_from`` (default 0) the first of them, ``num_experts_routed``
+(default ``num_experts``: all held) the router's published width. What the
+experts held elsewhere would add is left out, and that partial result goes on
+to the next layer; nothing stands in for the other chips or their exchange.
+
+On the TPU the two kernels (``fwd_masked``, ``moe_gmm``) have no backward yet
+and say so by name; off the TPU every path is plain JAX and differentiates.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Mapping
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
+from ddim_cold_tpu.models.init import trunc_normal
+from ddim_cold_tpu.models.moe import HeldExpertsMlp
+from ddim_cold_tpu.ops.flash_attention import masked_attention
+
+Dtype = Any
+
+_LAYER_TYPES = ("full_attention", "sliding_attention")
+_PER_LAYER = ("layer_types", "mlp_layer_types",
+              "num_attention_heads_per_layer", "gating_types")
+
+
+def check_trunk(c: Mapping[str, Any]) -> None:
+    """What this stack cannot run, refused at construction."""
+    depth = c["num_hidden_layers"]
+    for key in _PER_LAYER:
+        if len(c[key]) < depth:
+            raise ValueError(f"{key} has {len(c[key])} entries for "
+                             f"{depth} layers")
+    unknown = (set(c["layer_types"][:depth]) - set(_LAYER_TYPES)
+               | set(c["mlp_layer_types"][:depth]) - {"dense", "sparse"}
+               | set(c["gating_types"][:depth]) - {"per_head"})
+    if unknown:
+        raise ValueError(f"layer kinds {sorted(unknown)}: this stack has "
+                         f"{_LAYER_TYPES}, dense | sparse MLPs and a "
+                         "per_head gate")
+    for key, want in (("attention_bias", False),
+                      ("moe_router_logit_softcapping", 0),
+                      ("moe_apply_router_weight_on_input", False)):
+        if c.get(key, want) != want:
+            raise ValueError(f"{key} {c[key]!r}: this stack is written for "
+                             f"{want!r}")
+    if any(h % c["num_key_value_heads"]
+           for h in c["num_attention_heads_per_layer"][:depth]):
+        raise ValueError("every layer's query heads must divide into "
+                         "num_key_value_heads")
+    for kind in set(c["layer_types"][:depth]):
+        rotary_frequencies(c["rope_parameters"][kind], c["head_dim"])
+    routed = c.get("num_experts_routed", c["num_experts"])
+    first = c.get("experts_held_from", 0)
+    if not 0 <= first <= routed - c["num_experts"]:
+        raise ValueError(
+            f"experts {first}..{first + c['num_experts'] - 1} held of "
+            f"{routed} routed")
+
+
+def rotary_frequencies(rope: Mapping[str, Any], head_dim: int) -> tuple:
+    """(inverse frequencies of the rotated pairs, float64 ``(rot / 2,)``;
+    the factor on cos and sin) for one entry of ``rope_parameters``. ``rot =
+    head_dim · partial_rotary_factor`` leading dims are rotated.
+
+    ``default``: ``θ^(−2j/rot)``, factor 1. ``yarn`` (the arithmetic of
+    ``transformers``' ``_compute_yarn_parameters``): with ``d(β) = rot ·
+    ln(original_max_position_embeddings / (2πβ)) / (2 ln θ)``, ``low =
+    max(⌊d(beta_fast)⌋, 0)``, ``high = min(⌈d(beta_slow)⌉, rot − 1)``,
+    ``ramp_j = clip((j − low) / (high − low), 0, 1)``: ``inv_j / factor ·
+    ramp_j + inv_j · (1 − ramp_j)``; cos and sin times ``attention_factor``
+    (``0.1 ln factor + 1`` where the config gives none)."""
+    rot = int(head_dim * rope.get("partial_rotary_factor", 1.0))
+    theta = float(rope["rope_theta"])
+    inv = theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return inv, 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: 'default' and 'yarn' are "
+                         "written")
+    factor = float(rope["factor"])
+    reach = rope["original_max_position_embeddings"]
+
+    def dim_of(turns):
+        return rot * math.log(reach / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim_of(rope["beta_fast"])), 0)
+    high = min(math.ceil(dim_of(rope["beta_slow"])), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2) - low) / (high - low), 0.0, 1.0)
+    scale = rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv / factor * ramp + inv * (1.0 - ramp), float(scale)
+
+
+def apply_rotary(x, heads: int, inv_freq, scale: float):
+    """Rotate the leading ``2 · len(inv_freq)`` dims of every head of ``x``
+    ``(n, L, heads · head_dim)`` by token position, ``rotate_half`` pairing
+    (dim j with dim j + rot/2), in float32; the rest pass through. Written on
+    the token-major array as the projection left it — per-lane tables and two
+    lane rolls, no ``(n, L, heads, head_dim)`` view — so that q and k reach the
+    attention kernel in the layout it reads (a 4-d view costs a copy of q on
+    each side of the rotation: 0.6 GB in float32 at 4 x 4,097 x 9,216)."""
+    n, L, W = x.shape
+    hd, half = W // heads, len(inv_freq)
+    angle = (jnp.arange(L, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32))  # (L, half)
+    cos, sin = scale * jnp.cos(angle), scale * jnp.sin(angle)
+    rest = jnp.ones((L, hd - 2 * half), jnp.float32)
+    cos = jnp.tile(jnp.concatenate([cos, cos, rest], axis=-1), (1, heads))
+    sin = jnp.tile(jnp.concatenate([-sin, sin, 0 * rest], axis=-1), (1, heads))
+    xf = x.astype(jnp.float32)
+    # dim j < rot/2 takes its partner from the right, the partner from the left
+    first = (jnp.arange(W) % hd) < half
+    partner = jnp.where(first, jnp.roll(xf, -half, axis=-1),
+                        jnp.roll(xf, half, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+class GatedAttention(nn.Module):
+    trunk: Mapping[str, Any]
+    layer_type: str
+    heads: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.trunk
+        n, L, width = x.shape
+        heads, kv, hd = self.heads, c["num_key_value_heads"], c["head_dim"]
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        rope = rotary_frequencies(c["rope_parameters"][self.layer_type], hd)
+        q = apply_rotary(dense(heads * hd, "q_proj")(x), heads, *rope)
+        k = apply_rotary(dense(kv * hd, "k_proj")(x), kv, *rope)
+        v = dense(kv * hd, "v_proj")(x)
+        gate = jax.nn.sigmoid(dense(heads, "g_proj")(x).astype(jnp.float32))
+        window = (c["sliding_window"]
+                  if self.layer_type == "sliding_attention" else None)
+        out = masked_attention(
+            q.reshape(n, L, heads, hd), k.reshape(n, L, kv, hd),
+            v.reshape(n, L, kv, hd), hd ** -0.5, causal=True, window=window)
+        # a head's gate on each of its lanes, token-major as the context is
+        out = (out.reshape(n, L, heads * hd).astype(jnp.float32)
+               * jnp.repeat(gate, hd, axis=-1)).astype(self.dtype)
+        return dense(width, "o_proj")(out)
+
+
+class LagunaLayer(nn.Module):
+    trunk: Mapping[str, Any]
+    index: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, i = self.trunk, self.index
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
+        layer_type = c["layer_types"][i]
+        scope = ("trunk/attn_full" if layer_type == "full_attention"
+                 else "trunk/attn_window")
+        with jax.named_scope(scope):
+            x = x + GatedAttention(
+                c, layer_type, c["num_attention_heads_per_layer"][i],
+                name="self_attn", **kw)(norm("input_layernorm")(x))
+        y = norm("post_attention_layernorm")(x)
+        if c["mlp_layer_types"][i] == "dense":
+            with jax.named_scope("trunk/mlp"):
+                return x + GatedMlp(c, name="mlp", **kw)(y)
+        with jax.named_scope("trunk/moe"):
+            return x + HeldExpertsMlp(
+                num_routed=c.get("num_experts_routed", c["num_experts"]),
+                top_k=c["num_experts_per_tok"],
+                first_held=c.get("experts_held_from", 0),
+                num_held=c["num_experts"],
+                hidden_features=c["moe_intermediate_size"],
+                shared_features=c["shared_expert_intermediate_size"],
+                scaling=c.get("moe_routed_scaling_factor", 1.0),
+                norm_topk=c.get("norm_topk_prob", True),
+                name="mlp", **kw)(y)
+
+
+def layer(trunk, i: int, dtype, param_dtype, name: str) -> nn.Module:
+    """Layer ``i`` of this stack."""
+    return LagunaLayer(trunk, i, dtype, param_dtype, name=name)

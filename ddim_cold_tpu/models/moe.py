@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ddim_cold_tpu.models.init import trunc_normal
+from ddim_cold_tpu.ops import tiling
+from ddim_cold_tpu.ops.grouped_matmul import grouped_matmul
 from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
@@ -156,3 +158,97 @@ class SwitchMlp(nn.Module):
         mean_prob = probs.mean(axis=(0, 1))  # (E,)
         self.sow("losses", "moe_aux", E * jnp.sum(frac * mean_prob))
         return y
+
+
+class HeldExpertsMlp(nn.Module):
+    """Top-k routed gated-SiLU experts plus a shared one, computed by a chip
+    that is TOLD WHICH EXPERTS IT HOLDS: of ``num_routed`` experts, the
+    ``num_held`` from ``first_held`` on. The expert-parallel share of a layer,
+    without its exchange.
+
+    With y ``(rows, hidden)``: ``r = softmax_f32(y W_r)`` over all
+    ``num_routed`` router outputs; ``S`` = the ``top_k`` largest (ties to the
+    lower index); ``w_e = scaling · r_e / Σ_{e'∈S} r_e'`` (``norm_topk``; else
+    ``scaling · r_e``); out ``= Shared(y) + Σ_{e ∈ S ∩ held} w_e E_e(y)``,
+    ``Shared`` and every ``E_e`` the MLP ``W_down(SiLU(W_gate y) ⊙ W_up y)``
+    at width ``hidden_features``. What the experts held elsewhere would add is
+    left out: the shares of all the chips, the shared expert counted once,
+    sum to the whole layer (tested).
+
+    No capacity, no dropped assignment: every (row, expert, weight) triple of
+    the ``rows · top_k`` routed is kept in a buffer of exactly that many rows
+    (the worst case, every row routed to held experts only), sorted by expert
+    with the assignments to experts held elsewhere last, in a null group that
+    has no weights and costs no product: ``ops.grouped_matmul`` skips the row
+    tiles past the last held group and writes them as zeros. The sorted rows
+    are gathered once, go through ``grouped_matmul`` for gate and up, SiLU ⊙,
+    and again for down, and each row adds up its own ``top_k`` results by
+    position (a gather by the inverse permutation, weighted and summed in
+    float32: no scatter).
+
+    Parameters: ``router (hidden, num_routed)``; ``gate_proj``,
+    ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
+    width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp``."""
+
+    num_routed: int
+    top_k: int
+    first_held: int
+    num_held: int
+    hidden_features: int
+    shared_features: int
+    scaling: float = 1.0
+    norm_topk: bool = True
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from ddim_cold_tpu.models.hybrid import GatedMlp
+
+        *lead, D = x.shape
+        y = x.reshape(-1, D)
+        T, k, G, F = y.shape[0], self.top_k, self.num_held, self.hidden_features
+        if not 0 <= self.first_held <= self.num_routed - G or k > self.num_routed:
+            raise ValueError(
+                f"experts {self.first_held}..{self.first_held + G - 1} held, "
+                f"{k} a token, of {self.num_routed} routed")
+        shared = GatedMlp(
+            {"hidden_size": D, "intermediate_size": self.shared_features},
+            self.dtype, self.param_dtype, name="shared_expert")(y)
+
+        param = lambda name, shape: self.param(
+            name, trunc_normal(std=0.02), shape, self.param_dtype
+        ).astype(self.dtype)
+        logits = jnp.dot(y, param("router", (D, self.num_routed)),
+                         preferred_element_type=jnp.float32)
+        r = jax.nn.softmax(logits, axis=-1)
+        top_r, top_e = jax.lax.top_k(r, k)  # (T, k); ties to the lower index
+        if self.norm_topk:
+            top_r = top_r / jnp.sum(top_r, axis=-1, keepdims=True)
+        weight = self.scaling * top_r
+
+        # assignment a = (row a // k, its (a % k)-th expert); key: the held
+        # expert's index here, or G for an expert held elsewhere
+        local = top_e - self.first_held
+        held = (local >= 0) & (local < G)
+        key = jnp.where(held, local, G).reshape(T * k).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)
+        bounds = jnp.searchsorted(key[order], jnp.arange(G + 1, dtype=jnp.int32))
+        group_sizes = jnp.diff(bounds)
+        # whole row tiles, so that the product pads nothing; rows past the
+        # assignments read row 0 and lie past every group
+        M = tiling.round_up(T * k, 128)
+        source = jnp.pad(order // k, (0, M - T * k))
+        rows = y[source]
+
+        gate = grouped_matmul(rows, param("gate_proj", (G, D, F)), group_sizes)
+        up = grouped_matmul(rows, param("up_proj", (G, D, F)), group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up,
+                             param("down_proj", (G, F, D)), group_sizes)
+
+        where = jnp.argsort(order).reshape(T, k)  # a's place among the sorted
+        weight = jnp.where(held, weight, 0.0)
+        total = shared.astype(jnp.float32)
+        for j in range(k):
+            total += weight[:, j, None] * out[where[:, j]].astype(jnp.float32)
+        return total.astype(self.dtype).reshape(*lead, D)

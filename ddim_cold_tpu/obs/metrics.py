@@ -111,6 +111,11 @@ METRICS = (
      "flash backward traces by dq's K/V schedule (key: resident|streamed)"),
     ("kernels.flash_bwd_layout", "counter",
      "flash backward traces by operand layout (key: in_place|head_major)"),
+    ("kernels.flash_fwd_mask", "counter",
+     "flash forward traces by mask (key: none|causal|window)"),
+    # -- kernels (ops/grouped_matmul.py, counted once a trace) ------------
+    ("kernels.moe_gmm_schedule", "counter",
+     "grouped expert matmul traces by path (key: kernel|xla)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

@@ -310,8 +310,13 @@ def flash_attention(
     scale: float,
     block_q: int | None = None,
     block_kv: int | None = None,
+    *,
+    causal: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
-    """Fused non-causal multi-head attention.
+    """Fused multi-head attention; non-causal, one K/V head a query head,
+    unless said (``causal``, ``window``, fewer heads in ``k`` and ``v`` than
+    in ``q``: :func:`flash_attention_masked`, another launch).
 
     q/k/v: ``(B, N, H, D)`` (the model's head layout, ViT.py:104-107), three
     arrays of their own; returns ``(B, N, H, D)`` in q's dtype. Softmax runs
@@ -334,6 +339,12 @@ def flash_attention(
     log-sum-exp; under ``jax.grad`` the forward of the VJP does.
     """
     B, N, H, D = q.shape
+    if causal or window is not None or k.shape[2] != H:
+        if block_q is not None or block_kv is not None:
+            raise ValueError("the masked forward picks its blocks from the "
+                             "shape: leave block_q and block_kv unset")
+        return flash_attention_masked(q, k, v, scale, causal=causal,
+                                      window=window)
     out = _attention(tuple(x.reshape(B, N, H * D) for x in (q, k, v)), H,
                      scale, block_q, block_kv)
     return out.reshape(B, N, H, D)
@@ -480,6 +491,7 @@ def _flash_forward(operands, num_heads, scale, block_q, block_kv, *, with_lse):
         out_tokens, lanes = arrays[0].shape[1:]
     _kernels.inc("kernels.flash_fwd_schedule",
                  key="resident" if N <= bkv else "streamed")
+    _kernels.inc("kernels.flash_fwd_mask", key="none")
     spec = rows_spec(rows)
     out, *lse = per_device(
         functools.partial(
@@ -1034,7 +1046,9 @@ def online_softmax_update(o, l, m, logits, v_blk):
     return o, l, m_new
 
 
-def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512) -> jax.Array:
+def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512, *,
+                            causal: bool = False,
+                            window: int | None = None) -> jax.Array:
     """Pure-XLA blockwise softmax attention — the Mosaic-free middle path.
 
     Same online-softmax math as the Pallas kernel (and the ring steps,
@@ -1048,8 +1062,16 @@ def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512) -> jax.Array:
     strictly better than dense in HBM traffic at long N.
 
     q/k/v ``(B, N, H, D)`` → ``(B, N, H, D)`` in q's dtype, f32 softmax.
+    ``causal`` (token t sees j ≤ t), ``window`` (and only t − window < j) and
+    fewer heads in k and v than in q (query head h reads K/V head
+    ``h // (H/KV)``) as :func:`flash_attention_masked` has them — whose
+    differentiable stand-in off the TPU and second oracle this then is; every
+    chunk is computed and masked, none skipped.
     """
     B, N, H, D = q.shape
+    kind = mask_kind(causal, window)
+    if k.shape[2] != H:  # each K/V head, repeated for the query heads on it
+        k, v = (jnp.repeat(x, H // x.shape[2], axis=2) for x in (k, v))
     qf = q.astype(jnp.float32).transpose(0, 2, 1, 3)  # (B, H, N, D)
     kf = k.astype(jnp.float32).transpose(0, 2, 1, 3)
     vf = v.astype(jnp.float32).transpose(0, 2, 1, 3)
@@ -1061,7 +1083,14 @@ def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512) -> jax.Array:
     nb = kf.shape[2] // block_kv
     kb = kf.reshape(B, H, nb, block_kv, D).transpose(2, 0, 1, 3, 4)
     vb = vf.reshape(B, H, nb, block_kv, D).transpose(2, 0, 1, 3, 4)
-    valid = (jnp.arange(nb * block_kv) < N).reshape(nb, block_kv)
+    col = jnp.arange(nb * block_kv)
+    valid = (col < N).reshape(nb, block_kv)
+    if kind != "none":  # (nb, N, block_kv): what row t sees of each chunk
+        row = jnp.arange(N)[:, None]
+        sees = (col < N) & (col <= row)
+        if window is not None:
+            sees &= col > row - window
+        valid = sees.reshape(N, nb, block_kv).transpose(1, 0, 2)
 
     o = jnp.zeros((B, H, N, D), jnp.float32)
     l = jnp.zeros((B, H, N), jnp.float32)
@@ -1071,7 +1100,8 @@ def blockwise_attention_xla(q, k, v, scale, block_kv: int = 512) -> jax.Array:
         o, l, m = carry
         k_b, v_b, val = blk
         logits = jnp.einsum("bhqd,bhkd->bhqk", qf, k_b) * scale
-        logits = jnp.where(val[None, None, None, :], logits, _NEG_INF)
+        val = val[None, None] if val.ndim == 2 else val[None, None, None, :]
+        logits = jnp.where(val, logits, _NEG_INF)
         return online_softmax_update(o, l, m, logits, v_b), None
 
     (o, l, _), _ = jax.lax.scan(body, (o, l, m), (kb, vb, valid))
@@ -1104,6 +1134,235 @@ def _attention_bwd(num_heads, scale, block_q, block_kv, residuals, g):
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+# ---------------------------------------------------------------------------
+# masked forward on shared K/V heads
+# ---------------------------------------------------------------------------
+
+def mask_kind(causal: bool, window: int | None) -> str:
+    """The key of ``kernels.flash_fwd_mask``: ``none``, ``causal``, ``window``
+    (a causal window: token t sees tokens t − window < j ≤ t)."""
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal window of at least one "
+                             f"token: causal={causal}, window={window}")
+        return "window"
+    return "causal" if causal else "none"
+
+
+def _visible_chunks(i, *, bq: int, bkv: int, n_valid: int, causal: bool,
+                    window: int | None, lib=jnp):
+    """(first, last) K/V chunk that any token of query block ``i`` may see;
+    ``i`` a traced scalar (``lib=jnp``) or a Python int (``lib=_Ints``: the
+    builtins under ``jnp``'s names, for the static grid size)."""
+    last_row = lib.minimum(i * bq + bq - 1, n_valid - 1)
+    hi = (last_row if causal else n_valid - 1) // bkv
+    lo = (lib.maximum(i * bq - (window - 1), 0) // bkv if window is not None
+          else 0)
+    return lo, hi
+
+
+class _Ints:
+    """``minimum``/``maximum`` on Python ints, for the static grid size."""
+    minimum, maximum = staticmethod(min), staticmethod(max)
+
+
+def _fwd_masked_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       scale: float, n_valid: int, bq: int, bkv: int,
+                       n_kv: int, causal: bool, window: int | None):
+    """One (image, query head, q block, visited chunk) program of the masked
+    forward: one head on the block's lanes. Grid step ``j`` folds chunk
+    ``first + j`` of the chunks the mask lets this q block see
+    (:func:`_visible_chunks`; the K/V index maps address the same chunk) and
+    does nothing once past the last of them. The element mask is built only
+    in a chunk the mask's edge (the diagonal, the window's far edge, the end
+    of the sequence) crosses; a chunk every token of the block sees whole
+    takes the unmasked fold.
+
+    A row whose first visited chunk is wholly masked for it (a window's far
+    chunk, for the block's last rows) holds m = −1e30 and garbage l, acc until
+    its diagonal chunk — always visited, and later — scales them by
+    exp(−1e30 − m) = 0."""
+    i, j = pl.program_id(2), pl.program_id(3)
+    lo, hi = _visible_chunks(i, bq=bq, bkv=bkv, n_valid=n_valid,
+                             causal=causal, window=window)
+    c = lo + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    fold_scale = _scale_folds_into_q(scale)
+
+    def fold(masked: bool):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        if fold_scale:
+            q = q * scale
+        if masked and n_valid % bkv:
+            # rows of a ragged last chunk hold whatever the buffer held; their
+            # p is an exact 0, and 0 × garbage is NaN
+            vrow = c * bkv + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(vrow < n_valid, v, jnp.zeros_like(v))
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bq, bkv)
+        if not fold_scale:
+            logits = logits * scale
+        if masked:
+            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+            col = c * bkv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            keep = col < n_valid
+            if causal:
+                keep &= col <= row
+            if window is not None:
+                keep &= col > row - window
+            logits = jnp.where(keep, logits, _NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (bq, 1)
+        l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    # every token of the block sees the whole chunk
+    whole = (c + 1) * bkv <= n_valid
+    if causal:
+        whole &= (c + 1) * bkv - 1 <= i * bq
+    if window is not None:
+        whole &= c * bkv > i * bq + bq - 1 - window
+    pl.when((c <= hi) & whole)(lambda: fold(False))
+    pl.when((c <= hi) & jnp.logical_not(whole))(lambda: fold(True))
+
+    @pl.when(j == n_kv - 1)
+    def _emit():
+        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _masked_blocks(n_tokens: int, dtype) -> tuple:
+    """(block_q, block_kv) of the masked forward: K/V streamed in chunks of
+    512 (a window of 512 then lies in two chunks of a 512-row q block), both
+    clamped to a short sequence; VMEM is a few MB whatever the length."""
+    block = tiling.legal_block(512, tiling.round_up(n_tokens, 8), dtype)
+    return block, block
+
+
+def _fwd_masked_call(q, k, v, *, rep, lanes, scale, n_valid, bq, bkv, causal,
+                     window, interpret):
+    """The masked launch. ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
+    ``(rows, tokens, H/rep·lanes)``, query head ``h`` reading K/V column
+    block ``h // rep``; all three where the projections wrote them, the token
+    axis ending inside the last block. Grid ``(rows, H, q blocks, visited
+    chunks)``: the last axis is as long as the most chunks any q block sees
+    (two for a window of 512 at these blocks; all of them under a causal
+    mask), and a block that sees fewer re-addresses its last chunk, which is
+    not fetched again, and skips the fold."""
+    rows, tokens, width = q.shape
+    n_q = pl.cdiv(tokens, bq)
+    geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
+                    window=window)
+    spans = [_visible_chunks(i, lib=_Ints, **geometry) for i in range(n_q)]
+    n_kv = max(hi - lo + 1 for lo, hi in spans)
+
+    def kv_map(b, h, i, j):
+        lo, hi = _visible_chunks(i, **geometry)
+        return (b, jnp.minimum(lo + j, hi), h // rep)
+
+    q_spec = pl.BlockSpec((1, bq, lanes), lambda b, h, i, j: (b, i, h))
+    kv_spec = pl.BlockSpec((1, bkv, lanes), kv_map)
+    with profiling.scope("flash_attention/fwd_masked"):
+        return pl.pallas_call(
+            functools.partial(_fwd_masked_kernel, scale=scale, n_kv=n_kv,
+                              **geometry),
+            grid=(rows, width // lanes, n_q, n_kv),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            out_shape=_sds(q.shape, q.dtype, q),
+            scratch_shapes=[
+                pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
+                pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
+                pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+            ),
+            interpret=interpret,
+            name="fwd_masked",
+        )(q, k, v)
+
+
+def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
+                           window: int | None = None) -> jax.Array:
+    """The forward under a mask and on shared K/V heads, as its own launch
+    (``pallas_call(name="fwd_masked")``, ``%fwd_masked`` in a device trace, so
+    that what reads ``%fwd`` keeps reading the unmasked kernel).
+
+    q ``(B, N, H, D)``; k, v ``(B, N, KV, D)`` with ``H % KV == 0``: query head
+    ``h`` reads K/V head ``h // (H/KV)``. ``causal``: token t sees j ≤ t;
+    ``window``: and only t − window < j. Returns ``(B, N, H, D)`` in q's
+    dtype, softmax in float32. At a head size that fills whole lanes (128,
+    256) the three arrays are read in place, token-major, one head a lane
+    group, and the context written where the output projection reads it; any
+    other head size is zero-padded to the lanes first (a copy in HBM on each
+    side). K/V chunks that lie wholly outside the mask of a q block are
+    neither fetched nor computed (:func:`_fwd_masked_call`). Blocks come from
+    the shape. No backward yet: the VJP raises by name (ROADMAP Reach)."""
+    B, N, H, D = q.shape
+    KV = k.shape[2]
+    if H % KV or k.shape != v.shape or k.shape[:2] != (B, N) or k.shape[3] != D:
+        raise ValueError(f"q {q.shape} cannot share k {k.shape}, v {v.shape}: "
+                         "query heads must divide into the K/V heads")
+    _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, window))
+    lanes = tiling.round_up(D, _LANE)
+    if lanes != D:
+        q, k, v = (_pad_to(x, 3, _LANE) for x in (q, k, v))
+    bq, bkv = _masked_blocks(N, q.dtype)
+    spec = rows_spec(B)
+    out = per_device(
+        functools.partial(
+            _fwd_masked_call, rep=H // KV, lanes=lanes, scale=scale, n_valid=N,
+            bq=bq, bkv=bkv, causal=causal, window=window,
+            interpret=kernel_interpret()),
+        (spec, spec, spec), spec,
+    )(q.reshape(B, N, H * lanes), k.reshape(B, N, KV * lanes),
+      v.reshape(B, N, KV * lanes))
+    return out.reshape(B, N, H, lanes)[..., :D]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _masked_no_vjp(q, k, v, scale, causal, window):
+    return flash_attention_masked(q, k, v, scale, causal=causal, window=window)
+
+
+def _masked_no_vjp_fwd(*args):
+    raise NotImplementedError(
+        "the fwd_masked kernel has no backward yet (ROADMAP Reach: the masks "
+        "and shared K/V heads in dq/dkv): differentiate "
+        "ops.flash_attention.blockwise_attention_xla, which is what "
+        "masked_attention runs off the TPU")
+
+
+_masked_no_vjp.defvjp(_masked_no_vjp_fwd, lambda *a: None)
+
+
+def masked_attention(q, k, v, scale: float, *, causal: bool = True,
+                     window: int | None = None) -> jax.Array:
+    """Attention under a causal mask or a causal window on shared K/V heads,
+    shapes as :func:`flash_attention_masked`; the backend decides what runs:
+    that kernel on the TPU, :func:`blockwise_attention_xla` (plain JAX,
+    differentiable) anywhere else."""
+    if jax.default_backend() == "tpu":
+        return _masked_no_vjp(q, k, v, scale, causal, window)
+    return blockwise_attention_xla(q, k, v, scale, causal=causal,
+                                   window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -191,14 +191,16 @@ def _build_dataset(config: ExperimentConfig, root: str):
 
 
 def _build_hybrid(config: ExperimentConfig, mesh):
-    """``trunk:`` in the yaml: the hybrid state-space denoiser at the yaml's
-    image, patch and step sizes. What reaches into ``Block`` is refused by
+    """``trunk:`` in the yaml: ``HybridDenoiser`` over the layer stack its
+    ``model_type`` names (jamba, laguna) at the yaml's image, patch and step
+    sizes. What reaches into ``Block`` is refused by
     name, a mesh axis that would split tokens or layers included."""
     from ddim_cold_tpu.models import hybrid
 
     hybrid.refuse_any({
         "use_flash": config.use_flash, "flash_blocks": config.flash_blocks,
-        "scan_blocks": config.scan_blocks, "num_experts": config.num_experts})
+        "scan_blocks": config.scan_blocks, "num_experts": config.num_experts,
+        "moe_dispatch": config.moe_dispatch})
     mesh_shape = getattr(mesh, "shape", {}) if mesh is not None else {}
     for axis, option in (("seq", "sp_mode"), ("pipe", "scan_blocks"),
                          ("expert", "num_experts")):

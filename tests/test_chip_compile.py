@@ -472,3 +472,59 @@ def test_ssm_scan_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip):
     assert len(calls) == 1
     assert re.match(r"%ssm_scan(\.\d+)* = ", calls[0])
     assert int(ssm_scan_roofline.NAME.match(calls[0]).group(2)) == n
+
+
+# --- the masked forward and the grouped product at Laguna-S-2.1's shapes ----
+
+LAGUNA = dict(n=4, L=4097, kv=8, hd=128, window=512)
+
+
+@pytest.mark.parametrize("heads,window", [(72, LAGUNA["window"]), (48, None)])
+def test_fwd_masked_lowers_at_the_published_shapes_and_keeps_its_name(
+        heads, window, chip):
+    """``ops/flash_attention.py``'s masked forward at 4 x 4,097 tokens, 72
+    (window 512) and 48 (full) query heads of 128 on 8 K/V heads, blocks from
+    the shape: ONE ``tpu_custom_call``, named ``%fwd_masked``
+    (``benchmark/layer_metrics/flash_masked_fwd_roofline.py`` matches it by
+    that name and reads the head count off its width), which the ``%fwd``
+    reader does not match."""
+    from benchmark.layer_metrics import flash_fwd_roofline
+    from benchmark.layer_metrics import flash_masked_fwd_roofline as reader
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, kv, hd = (LAGUNA[k] for k in ("n", "L", "kv", "hd"))
+    text = jax.jit(lambda q, k, v: fa.masked_attention(
+        q, k, v, hd ** -0.5, causal=True, window=window)).lower(
+        sds((n, L, heads, hd), jnp.bfloat16), sds((n, L, kv, hd), jnp.bfloat16),
+        sds((n, L, kv, hd), jnp.bfloat16)).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and [int(g) for g in m.groups()[1:]] == [n, L, heads * hd]
+    assert not flash_fwd_roofline.NAME.match(calls[0])
+
+
+@pytest.mark.parametrize("rows,K,N", [
+    (81940, 3072, 1024),    # the rows routed here on average: gate, up
+    (163968, 3072, 1024),   # the expert layer's buffer: every assignment
+    (163968, 1024, 3072),   # down
+])
+def test_moe_gmm_lowers_at_the_published_shapes_and_keeps_its_name(
+        rows, K, N, chip):
+    """``ops/grouped_matmul.py`` over 128 groups of Laguna-S-2.1's expert
+    width, tiles from the shape: ONE ``tpu_custom_call``, named ``%moe_gmm``
+    (``benchmark/layer_metrics/moe_gmm_roofline.py`` matches it by that name),
+    result ``[rows in whole tiles, N]``."""
+    from benchmark.layer_metrics import moe_gmm_roofline as reader
+    from ddim_cold_tpu.ops import grouped_matmul as gm
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    text = jax.jit(gm.grouped_matmul).lower(
+        sds((rows, K), jnp.bfloat16), sds((128, K, N), jnp.bfloat16),
+        sds((128,), jnp.int32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and [int(g) for g in m.groups()[1:]] == [-(-rows // 128) * 128, N]
