@@ -500,6 +500,7 @@ NS_K = 20          # the north-star DDIM step count
 
 _FLASH_PATH = "ddim_cold_tpu/ops/flash_attention.py"
 _QUANT_PATH = "ddim_cold_tpu/ops/quant.py"
+_BLOCK_PATH = "ddim_cold_tpu/ops/block_kernels.py"
 
 
 def kernel_entries() -> list[Entry]:
@@ -507,8 +508,9 @@ def kernel_entries() -> list[Entry]:
     sampler scans at 200px — every in-tree
     pallas_call at the EXACT geometry that crashed r04 — plus standalone
     flash forward/grad traces per (dtype, block config) covering the
-    backward dq/dkv kernels and every ``FLASH_BLOCK_SWEEP`` row, and the
-    dequant-pallas kernel at the 200px trunk GEMM shapes. The TINY serve
+    backward dq/dkv kernels and every ``FLASH_BLOCK_SWEEP`` row, the
+    dequant-pallas kernel at the 200px trunk GEMM shapes, and the float
+    trunk's token-wise kernels (``ln_qkv``, ``block_tail``). The TINY serve
     sweep contains zero pallas_calls (it serves quant="xla" only), so
     these entries ARE the kernels layer's real coverage.
 
@@ -655,6 +657,28 @@ def kernel_entries() -> list[Entry]:
              jax.ShapeDtypeStruct((E, E), w_dt), b1,
              jax.ShapeDtypeStruct((E, E), w_dt), b2,
              *( (sp_, sp_) if mode else () ))))
+
+    # the float trunk's token-wise kernels (ops/block_kernels.py) alone, at
+    # the sampler's rows and the row block the shape gives them — the scan
+    # entries above trace them too, two a block; the token axis stays ragged
+    from ddim_cold_tpu.ops import block_kernels
+
+    vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.float32)  # noqa: E731
+    mat = lambda k, n: jax.ShapeDtypeStruct((k, n), jnp.float32)  # noqa: E731
+    for dt_label, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
+        act = jax.ShapeDtypeStruct((NS_ROWS, NS_TOKENS, E), dtype)
+        rows = block_kernels.row_block(NS_TOKENS, E, E, dtype)
+        entries.append(Entry(
+            f"tokenwise200_ln_qkv_{dt_label}", _BLOCK_PATH,
+            block_kernels.ln_qkv,
+            (act, vec(E), vec(E), mat(E, 3 * E), vec(3 * E)),
+            kwargs=dict(eps=1e-5, rows=rows), meta=dict(tokens=NS_TOKENS)))
+        entries.append(Entry(
+            f"tokenwise200_block_tail_{dt_label}", _BLOCK_PATH,
+            block_kernels.block_tail,
+            (act, act, mat(E, E), vec(E), vec(E), vec(E), mat(E, E), vec(E),
+             mat(E, E), vec(E)),
+            kwargs=dict(eps=1e-5, rows=rows), meta=dict(tokens=NS_TOKENS)))
     return entries
 
 

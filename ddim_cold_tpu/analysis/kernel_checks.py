@@ -81,9 +81,12 @@ WASTE_THRESHOLD = 1.25
 #: ``dq`` selects the ds columns of K/V rows past it to 0 and zeroes those K
 #: rows, ``dkv`` zeroes the q and do rows past it and selects their lse and
 #: delta (rows of arrays padded to the block, like every lane axis, which
-#: ``dq`` fills only as far as its own q blocks reach) to 1e30 and 0. Every
-#: other kernel keeps the pad-to-block-multiple policy, on both axes.
-RAGGED_SUBLANE_OK = frozenset({"fwd", "dq", "dkv"})
+#: ``dq`` fills only as far as its own q blocks reach) to 1e30 and 0;
+#: ``ln_qkv`` and ``block_tail`` (ops/block_kernels.py) are row-wise from end
+#: to end, so what a row past the image holds stays in that row and is
+#: dropped with it (PERF.md section 6, PR 32). Every other kernel keeps the
+#: pad-to-block-multiple policy, on both axes.
+RAGGED_SUBLANE_OK = frozenset({"fwd", "dq", "dkv", "ln_qkv", "block_tail"})
 
 
 def _round_up(n: int, m: int) -> int:

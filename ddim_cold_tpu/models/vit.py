@@ -35,9 +35,14 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ddim_cold_tpu.models.init import torch_default_uniform, trunc_normal
+from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
+
+#: which path each trace of a :class:`Block` took for its token-wise half
+#: (``kernels.block_tokenwise``, keyed ``kernel`` / ``xla``)
+_kernels = metrics.scope("kernels")
 
 #: Model configurations appearing in the reference (SURVEY.md §2 table).
 MODEL_CONFIGS = {
@@ -86,14 +91,50 @@ class _DenseParams(nn.Module):
     interchangeable on one param tree."""
 
     features: int
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, in_features: int):
         kernel = self.param("kernel", trunc_normal(std=0.02),
                             (in_features, self.features))
-        bias = self.param("bias", nn.initializers.zeros_init(),
-                          (self.features,))
+        bias = (self.param("bias", nn.initializers.zeros_init(),
+                           (self.features,)) if self.use_bias else None)
         return kernel, bias
+
+
+class _NormParams(nn.Module):
+    """Declares an ``nn.LayerNorm``'s ``{scale, bias}`` leaves, as
+    :class:`_DenseParams` does a Dense's."""
+
+    @nn.compact
+    def __call__(self, features: int):
+        return (self.param("scale", nn.initializers.ones_init(), (features,)),
+                self.param("bias", nn.initializers.zeros_init(), (features,)))
+
+
+class _AttnParams(nn.Module):
+    """Declares :class:`Attention`'s two Denses under this module's name."""
+
+    dim: int
+    qkv_bias: bool
+
+    @nn.compact
+    def __call__(self):
+        return (_DenseParams(3 * self.dim, use_bias=self.qkv_bias,
+                             name="qkv")(self.dim),
+                _DenseParams(self.dim, name="proj")(self.dim))
+
+
+class _MlpParams(nn.Module):
+    """Declares :class:`Mlp`'s two Denses under this module's name."""
+
+    hidden: int
+    dim: int
+
+    @nn.compact
+    def __call__(self):
+        return (_DenseParams(self.hidden, name="fc1")(self.dim),
+                _DenseParams(self.dim, name="fc2")(self.hidden))
 
 
 class Mlp(nn.Module):
@@ -406,8 +447,46 @@ class Attention(nn.Module):
         return out, attn
 
 
+def _attend_packed(packed: jax.Array, num_heads: int, scale: float,
+                   use_flash, flash_blocks, dtype) -> jax.Array:
+    """:class:`Attention`'s three weightless inference paths over the packed
+    ``(B, N, 3C)`` projection → the context ``(B, N, C)``: the flash kernel,
+    the blockwise XLA path, the dense einsum — what ``Attention.__call__``
+    runs between its two Denses when nothing is dropped, probed or sharded
+    along the sequence. A second copy of those branches, kept in step by
+    hand: ``Attention`` computes q, k, v apart before it branches, dead code
+    on the flash path that ``analysis/memory_checks``' liveness walk counts,
+    so folding the two moves the 200px programs' peaks and the guard on them
+    (PERF.md section 7, PR 32)."""
+    B, N, C = packed.shape[0], packed.shape[1], packed.shape[2] // 3
+    if use_flash and use_flash != "xla":
+        from ddim_cold_tpu.ops.flash_attention import flash_attention_qkv
+
+        return flash_attention_qkv(
+            packed, num_heads, scale, *(flash_blocks or ())).astype(dtype)
+    qkv = packed.reshape(B, N, 3, num_heads, C // num_heads)
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    if use_flash == "xla":
+        from ddim_cold_tpu.ops.flash_attention import blockwise_attention_xla
+
+        out = blockwise_attention_xla(
+            q, k, v, scale,
+            *((flash_blocks[1],) if flash_blocks else ())).astype(dtype)
+    else:
+        logits = jnp.einsum("bnhd,bmhd->bhnm", q, k) * scale
+        attn = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(dtype)
+        out = jnp.einsum("bhnm,bmhd->bnhd", attn, v)
+    return out.reshape(B, N, C)
+
+
 class Block(nn.Module):
-    """Pre-LN transformer block with stochastic-depth residuals (reference ViT.py:120-138)."""
+    """Pre-LN transformer block with stochastic-depth residuals (reference
+    ViT.py:120-138). On the inference path (``deterministic``, nothing probed,
+    quantised, routed or sharded along the sequence, ``dim`` in whole lanes)
+    the token-wise half — everything but attention — runs as the two
+    weight-resident kernels of ``ops/block_kernels.py`` on the same parameter
+    tree; anything else, training and ``init`` included, runs the composition
+    below. ``kernels.block_tokenwise`` counts which, once a trace."""
 
     dim: int
     num_heads: int
@@ -443,6 +522,33 @@ class Block(nn.Module):
             raise ValueError(
                 "quant covers the dense trunk only — the Switch-MoE expert "
                 "banks have no quantized path (set num_experts=1)")
+        hidden = int(self.dim * self.mlp_ratio)
+        rows = None
+        if (deterministic and not return_attention and self.quant is None
+                and self.num_experts == 1 and self.seq_mesh is None
+                and not self.seq_manual and x.dtype == self.dtype
+                and not self.is_initializing()):
+            from ddim_cold_tpu.ops import block_kernels
+
+            rows = block_kernels.row_block(
+                x.shape[1], self.dim, hidden, self.dtype)
+        _kernels.inc("kernels.block_tokenwise",
+                     key="xla" if rows is None else "kernel")
+        if rows is not None:
+            (w_qkv, b_qkv), (w_proj, b_proj) = _AttnParams(
+                self.dim, self.qkv_bias, name="attn")()
+            (w_fc1, b_fc1), (w_fc2, b_fc2) = _MlpParams(
+                hidden, self.dim, name="mlp")()
+            packed = block_kernels.ln_qkv(
+                x, *_NormParams(name="norm1")(self.dim), w_qkv, b_qkv,
+                1e-5, rows)
+            ctx = _attend_packed(
+                packed, self.num_heads,
+                self.qk_scale or (self.dim // self.num_heads) ** -0.5,
+                self.use_flash, self.flash_blocks, self.dtype)
+            return block_kernels.block_tail(
+                ctx, x, w_proj, b_proj, *_NormParams(name="norm2")(self.dim),
+                w_fc1, b_fc1, w_fc2, b_fc2, 1e-5, rows)
         ln = lambda name: nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name=name)
         y, attn = Attention(
             dim=self.dim,

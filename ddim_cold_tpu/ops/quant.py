@@ -437,6 +437,24 @@ def gelu_exact(x: jax.Array) -> jax.Array:
     return (0.5 * xf * (1.0 + erf)).astype(x.dtype)
 
 
+def gelu_exact_newton(x: jax.Array) -> jax.Array:
+    """:func:`gelu_exact` for a Pallas body that sits on its vector work: the
+    same rational erf, float32 inside, the input's dtype out, with the
+    quotient taken as the EUP's reciprocal refined by two Newton steps (exact
+    to float32 rounding whatever the seed: the interpreter's is a bfloat16
+    reciprocal). The VPU's float32 divide costs ``block_tail``
+    (ops/block_kernels.py) 0.30 ms of 2.46 a launch at the 200px sampler
+    cell's shape (PERF.md section 6, PR 32)."""
+    xf = x.astype(jnp.float32)
+    z = jnp.clip(xf * (0.5 ** 0.5), -4.0, 4.0)
+    z2 = z * z
+    q = _horner(_ERF_Q, z2)
+    r = pl.reciprocal(q, approx=True)
+    r = r * (2.0 - q * r)
+    r = r * (2.0 - q * r)
+    return (0.5 * xf * (1.0 + z * _horner(_ERF_P, z2) * r)).astype(x.dtype)
+
+
 def _mlp_kernel(*refs, quant: bool, w8a8: bool, has_b2: bool, cdt):
     """One M-tile program of the fused Mlp: fc1 GEMM into the f32 scratch
     accumulator, bias + exact (erf) GELU in VMEM, fc2 GEMM straight out —

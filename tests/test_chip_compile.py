@@ -205,13 +205,15 @@ def _fused_attn(dtype_name, geometry):
 
 def _forward(**kw):
     """The whole 200px/p4 forward in bf16: one kernel per flash layer, plus
-    four dequant matmuls per block under w8a16 — or, fused, one attention and
-    one Mlp kernel per block at the committed TUNED_BLOCKS rows."""
+    the float trunk's two token-wise kernels per block (``ln_qkv``,
+    ``block_tail``) or four dequant matmuls per block under w8a16 — or,
+    fused, one attention and one Mlp kernel per block at the committed
+    TUNED_BLOCKS rows."""
     def build(devices):
         sds = _struct(SingleDeviceSharding(devices[0]))
         model = DiffusionViT(dtype=jnp.bfloat16, **kw, **P4)
         calls = (P4["depth"] if kw.get("use_flash") else 0) + (
-            4 * P4["depth"] if kw.get("quant") else 0)
+            4 if kw.get("quant") else 2) * P4["depth"]
         if kw.get("fused"):
             calls = 2 * P4["depth"]
         return (lambda p, x, t: model.apply({"params": p}, x, t),
@@ -397,15 +399,18 @@ def _forward_depth1(devices):
 
 
 @pytest.mark.parametrize("program,kernels", [
-    ("forward", {"fwd"}),
+    ("forward", {"fwd", "ln_qkv", "block_tail"}),
     ("dp4_train_step", {"fwd", "dq", "dkv"}),
 ])
 def test_flash_kernels_keep_their_instruction_names(program, kernels, chip):
     """``benchmark/layer_metrics/flash_fwd_roofline.py`` finds the forward
     kernel as the ``tpu_custom_call`` instruction ``%fwd`` and the breakdown
-    lists ``fwd``, ``dq``, ``dkv``: the names come from
-    ``pallas_call(name=...)`` and must stay, with or without XLA's numeric
-    suffix, whatever scope the kernels are launched under."""
+    lists ``fwd``, ``dq``, ``dkv`` and, for the sampler, ``ln_qkv`` and
+    ``block_tail``: the names come from ``pallas_call(name=...)`` and must
+    stay, with or without XLA's numeric suffix, whatever scope the kernels
+    are launched under. The training step holds neither token-wise kernel:
+    it traces ``deterministic=False`` (ops/block_kernels.py is inference
+    only)."""
     import re
 
     text = (_forward_depth1(chip) if program == "forward"
@@ -441,8 +446,102 @@ def test_forward_leaves_attention_operands_where_the_gemms_wrote_them(chip):
     assert fwd.group(2) == f"{ROWS},{N},{C}"
     operands = {op.strip() for op in fwd.group(3).split(",")}
     assert len(operands) == 1, operands  # the projection, three times
-    assert re.search(re.escape(operands.pop())
-                     + rf" = \w+\[{ROWS},{N},{3 * C}\]", text)
+    # ... which ``ln_qkv`` wrote, and ``block_tail`` reads the context: no
+    # instruction stands between the three launches of a block
+    assert re.search(re.escape(operands.pop()) + rf"(?:\.\d+)* = \w+\[{ROWS},"
+                     rf"{N},{3 * C}\][^\n]*custom-call", text)
+    tail = re.search(r"%block_tail(?:\.\d+)* = [^\n]*?custom-call\(([^)]*)\)",
+                     text)
+    assert re.search(r"%fwd(?:\.\d+)*\b", tail.group(1).split(",")[0])
+
+
+# --- the token-wise kernels at the sampler cell's shape ---------------------
+
+@pytest.mark.parametrize("images,tokens,width,hidden,dtype", [
+    (288, N, C, C, jnp.bfloat16),         # flower200_sample_k20: 720,288 rows
+    (ROWS, 577, 384, 384, jnp.float32),   # width 384, one ragged block
+    (4, 16384, 256, 1024, jnp.bfloat16),  # the VMEM model's edge, mlp_ratio 4
+    (4, 16384, 384, 1536, jnp.float32),
+])
+def test_tokenwise_kernels_lower_and_keep_their_names(images, tokens, width,
+                                                      hidden, dtype, chip):
+    """``ops/block_kernels.py`` at the row block the shape gives it: each is
+    ONE ``tpu_custom_call``, named ``%ln_qkv`` / ``%block_tail`` (the ledger's
+    breakdown lists them by that name), reading and writing the ``(images,
+    tokens, width)`` arrays as they lie — no pad, no reshape of the ragged
+    token axis."""
+    import re
+
+    from ddim_cold_tpu.ops import block_kernels as bk
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    rows = bk.row_block(tokens, width, hidden, dtype)
+    assert rows is not None
+    act = sds((images, tokens, width), dtype)
+    vec = lambda n: sds((n,), jnp.float32)  # noqa: E731
+    mat = lambda k, n: sds((k, n), jnp.float32)  # noqa: E731
+    for name, fn, args, out_width in (
+            ("ln_qkv", bk.ln_qkv,
+             (act, vec(width), vec(width), mat(width, 3 * width),
+              vec(3 * width)), 3 * width),
+            ("block_tail", bk.block_tail,
+             (act, act, mat(width, width), vec(width), vec(width), vec(width),
+              mat(width, hidden), vec(hidden), mat(hidden, width),
+              vec(width)), width)):
+        text = jax.jit(lambda *a, _fn=fn: _fn(*a, 1e-5, rows)).lower(
+            *args).compile().as_text()
+        calls = [line.strip().removeprefix("ROOT ")
+                 for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 1
+        assert re.match(rf"%{name}(\.\d+)* = \w+\[{images},{tokens},"
+                        rf"{out_width}\]", calls[0]), calls[0][:120]
+        kind = {"bfloat16": "bf16", "float32": "f32"}[np.dtype(dtype).name]
+        assert (f"operand_layout_constraints={{{kind}[{images},{tokens},"
+                f"{width}]{{2,1,0}}" in calls[0])
+        assert " pad(" not in text
+
+
+@pytest.mark.parametrize("axes,pipelined", [
+    ({"pipe": 2, "model": 2}, True),
+    ({"pipe": 2, "model": 1}, True),   # an idle 'model' axis is enough
+    ({"data": 2, "model": 2}, False),  # plain tensor parallelism
+], ids=["pipe2_model2", "pipe2_idle_model", "data2_model2"])
+def test_tokenwise_kernels_leave_tensor_parallel_meshes_to_gspmd(
+        axes, pipelined, chip):
+    """The 200px/p4 eval forward at sampler length where GSPMD partitions the
+    trunk: through ``make_pipelined_apply``, whose shard_map is manual over
+    ``pipe`` alone so that a ``model`` axis stays automatic, and over a plain
+    data × model mesh with Megatron-sharded weights. jit cannot partition a
+    Mosaic launch (``Mosaic kernels cannot be automatically partitioned``,
+    with one device on ``model`` too), and per-device launches would gather
+    the sharded weights whole — so these meshes keep the composition
+    (``block_kernels._mesh_admits``): the program compiles, holds no custom
+    call, and still reduces over the model axis where it has devices."""
+    from ddim_cold_tpu.parallel import (
+        make_pipelined_apply, param_partition_specs, pipeline_param_specs)
+
+    shape = tuple(axes.values())
+    mesh = Mesh(np.asarray(chip[:int(np.prod(shape))]).reshape(shape),
+                tuple(axes))
+    model = DiffusionViT(dtype=jnp.bfloat16, scan_blocks=pipelined, **P4)
+    tree = _params(model, jax.ShapeDtypeStruct)
+    specs = (pipeline_param_specs(tree, tensor_axes=("model",)) if pipelined
+             else param_partition_specs(tree, axes=("model",)))
+    sds = lambda spec: _struct(NamedSharding(mesh, spec))  # noqa: E731
+    params = jax.tree.map(lambda s, spec: sds(spec)(s.shape, s.dtype),
+                          tree, specs)
+    rows = P() if pipelined else P("data")
+    apply = (make_pipelined_apply(model, mesh, batch_axis=None, seq_axis=None,
+                                  n_microbatch=2) if pipelined
+             else model.apply)
+    with ambient(mesh):
+        text = jax.jit(apply).lower(
+            {"params": params}, sds(rows)((4, 200, 200, 3), jnp.float32),
+            sds(rows)((4,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    if axes["model"] > 1:
+        assert "all-reduce" in text
 
 
 # --- the selective scan at Jamba2-3B's published shape ----------------------

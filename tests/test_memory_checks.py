@@ -150,7 +150,22 @@ def test_m_finding_keys_round_trip(tmp_path):
     assert load_baseline(str(base)) == {f_.key for f_ in fs}
 
 
-def test_clean_in_tree_memory(kernel_traces):
+def _peak_through_composition(entry, monkeypatch) -> int:
+    """``entry``'s peak with the float trunk's token-wise kernels turned
+    away (``row_block`` answers None, as it does for a shape they do not
+    take). The sampler's jit caches its trace by model and shapes, which the
+    patch does not change, so the caches are dropped around the trace."""
+    from ddim_cold_tpu.ops import block_kernels
+
+    with monkeypatch.context() as m:
+        m.setattr(block_kernels, "row_block", lambda *a, **k: None)
+        jax.clear_caches()
+        peak = memory_checks.peak_live_bytes(entry.trace())
+    jax.clear_caches()
+    return peak
+
+
+def test_clean_in_tree_memory(kernel_traces, monkeypatch):
     """The acceptance gate: every 200px sampler program's donation-aware
     peak fits the v5e HBM budget and carries no over-threshold padding,
     and the peaks are sane (params + a 200px batch land well under a GiB
@@ -166,8 +181,16 @@ def test_clean_in_tree_memory(kernel_traces):
                           "ns200_fewstep4_bf16"}
     for name, peak in peaks.items():
         assert 10 * 2**20 < peak < 2**31, (name, peak)
-    # quantized weights must not peak above the f32 build
-    assert peaks["ns200_w8a16"] < peaks["ns200_f32"]
+    # quantized weights must not peak above the f32 build, like against
+    # like. The float trunk's token-wise half runs as two kernels
+    # (ops/block_kernels.py) whose intermediates live in VMEM and never in
+    # this walk, while the unfused quantised trunk keeps the XLA composition:
+    # so the unfused one is held against the f32 build forced through the
+    # same composition (the guard as it stood before the kernels, whatever
+    # path the float build takes), and the fused one against the kernels
+    assert peaks["ns200_w8a16"] < _peak_through_composition(
+        kernel_traces["ns200_f32"][0], monkeypatch)
+    assert peaks["ns200_w8a16_fused"] < peaks["ns200_f32"]
     # fusing deletes intermediates; it must not grow the liveness peak
     assert peaks["ns200_w8a16_fused"] <= peaks["ns200_w8a16"] * 1.05
     # the few-step scan holds one sampler state, not k of them — its peak
