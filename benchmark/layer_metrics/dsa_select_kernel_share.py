@@ -1,0 +1,19 @@
+"""Share of the key selection's traces in this process that took the two
+Pallas launches (no sort) and not the ``lax.top_k`` path: 100 on the chip.
+Layer: kernels. Source: program counter ``kernels.dsa_select_schedule`` (keys
+``kernel``, ``xla``; +1 a trace). It is what shows a later change that drops
+a shape to the XLA path."""
+
+from ddim_cold_tpu.obs import metrics
+
+
+def read(view):
+    by_key: dict = {}
+    for series in metrics.snapshot().values():
+        for key, count in series.get("kernels.dsa_select_schedule/by_key",
+                                     {}).items():
+            by_key[key] = by_key.get(key, 0) + count
+    total = sum(by_key.values())
+    if not total:
+        return None
+    return 100.0 * by_key.get("kernel", 0) / total

@@ -547,7 +547,7 @@ HYBRID_MUST_REFUSE = ("quant", "fused", "cache_mode", "scan_blocks",
                       "num_experts", "sp_mode", "use_flash")
 #: further spellings of the same options in the yaml, the model and the
 #: sampler, each of which must resolve to a name in ``REFUSED`` — for every
-#: layer stack alike (jamba, laguna): the refusals are the wrapper's
+#: layer stack alike (jamba, laguna, glm_moe_dsa): the refusals are the wrapper's
 HYBRID_MUST_REFUSE_SPELLINGS = HYBRID_MUST_REFUSE + (
     "moe_dispatch", "seq_mesh", "seq_axis", "sp_degree", "flash_blocks",
     "cache_interval", "capture_split", "skip_blocks", "token_cache")

@@ -1,0 +1,252 @@
+"""The ``glm_moe_dsa`` layer stack of ``HybridDenoiser`` (``models/hybrid.py``
+chooses it by the trunk's ``model_type``): multi-head *latent* attention
+(low-rank query and key/value paths) under a *learned sparse selection* (a
+per-layer indexer picks, for every query, the ``index_topk`` keys it attends
+to; layers without an indexer borrow the selection of the nearest one before
+them), and MLPs that are dense in the leading layers and sigmoid-scored top-k
+routed experts plus a shared one after them. The wrapper, the input and output
+stage, ``RMSNorm`` and ``GatedMlp`` are ``hybrid``'s; the rotary tables and
+their application ``laguna``'s; the expert layer ``moe.HeldExpertsMlp``.
+
+Sizes come from ``trunk``, a mapping under the keys of the published
+``config.json`` (``model_type: glm_moe_dsa``), letter for letter. The stack
+may be a SLICE of the published one: layer i here is published layer
+``layers_from + i`` and reads that entry of the per-layer lists
+``indexer_types`` and ``mlp_layer_types``. With x ∈ R^{L×hidden_size}, ε =
+``rms_norm_eps``, y = RMSNorm(x), no bias but the indexer's LayerNorm,
+positions 0 (class token), 1, … in raster order:
+
+* layer i: ``x += Attn_i(RMSNorm(x))``; ``x += FFN_i(RMSNorm(x))``.
+* ``Attn_i`` (H = ``num_attention_heads``): ``c_q = RMSNorm(y W_qa)`` ∈
+  R^``q_lora_rank``; ``q_h = c_q W_qb`` → H heads of ``[q_nope
+  (qk_nope_head_dim), q_rope (qk_rope_head_dim)]``. ``[c_kv, k_r] = y W_kva``
+  ∈ R^(``kv_lora_rank`` + ``qk_rope_head_dim``); ``c_kv = RMSNorm(c_kv)``;
+  ``[k_nope_h, v_h (v_head_dim)] = c_kv W_kvb`` for each head. Rotary
+  (``rope_parameters``; ``rope_interleave``: dims 2j, 2j + 1 pair) on every
+  ``q_rope_h`` and on the one ``k_r``, which all the heads share: ``k_h =
+  [k_nope_h, k_r]``. Scores ``q_h · k_h · qk_head_dim^−½`` over **s ∈ S_t
+  only**, softmax in float32, ``o_h = Σ_s p_s v_h,s``, out ``= concat_h(o_h)
+  W_o``. Computed PER HEAD (``ops.flash_attention.selected_attention``, head
+  size 256 on both sides of the product): the sampler keeps no cache, so the
+  latent is a factorisation here, and multiplying the absorbed latent
+  (576/512 dims a pair against 256/256) would cost 2.1x the operations.
+* the indexer, where ``indexer_types[layer] == "full"`` (J =
+  ``index_n_heads`` heads of D = ``index_head_dim``): ``q^I = c_q W^I_q``;
+  ``k^I = LayerNorm(y W^I_k)`` (one head); rotary on the first
+  ``qk_rope_head_dim`` dims of both (``indexer_rope_interleave``); ``w = y
+  W^I_w · J^−½ · D^−½``; ``I_ts = Σ_j w_tj · ReLU(q^I_tj · k^I_s)``; S_t = the
+  ``index_topk`` best visible keys of query t, exact ties at the threshold all
+  kept (``ops.sparse_select.select``). ``"shared"``: no indexer parameters,
+  the S of the nearest ``full`` layer before it — handed from layer to layer
+  as the second item of what a layer returns.
+* ``FFN_i``: ``mlp_layer_types[layer] == "dense"``: the gated SiLU MLP at
+  ``intermediate_size``; ``"sparse"``: ``moe.HeldExpertsMlp`` with
+  ``score="sigmoid"`` and the selection bias (``topk_method: noaux_tc``;
+  ``n_group`` = ``topk_group`` = 1, so no group limit), ``num_experts_per_tok``
+  a token, weights renormalised (``norm_topk_prob``) and scaled by
+  ``routed_scaling_factor``, experts at ``moe_intermediate_size``, the shared
+  one at ``n_shared_experts`` times that.
+
+**The share**, as the ``laguna`` stack has it: ``n_routed_experts`` is how
+many experts THIS chip holds, ``experts_held_from`` (default 0) the first of
+them, ``n_experts_routed`` (default: all held) the router's published width.
+
+On the TPU the kernels (``dsa_index``, ``dsa_select``, ``fwd_selected``,
+``moe_gmm``) have no backward yet and say so by name; off the TPU every path
+is plain JAX and differentiates (the selection itself is piecewise constant).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Mapping
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
+from ddim_cold_tpu.models.init import trunc_normal
+from ddim_cold_tpu.models.laguna import apply_rotary, rotary_frequencies
+from ddim_cold_tpu.models.moe import HeldExpertsMlp
+from ddim_cold_tpu.obs import metrics
+from ddim_cold_tpu.ops.flash_attention import selected_attention
+from ddim_cold_tpu.ops.sparse_select import select
+
+Dtype = Any
+
+#: the indexer's LayerNorm, as the public inference code has it
+INDEX_NORM_EPS = 1e-6
+#: which indexer kind each traced layer had (``kernels.dsa_indexer_layers``)
+_kernels = metrics.scope("kernels")
+
+_PER_LAYER = ("indexer_types", "mlp_layer_types")
+
+
+def published_index(c: Mapping[str, Any], i: int) -> int:
+    return c.get("layers_from", 0) + i
+
+
+def check_trunk(c: Mapping[str, Any]) -> None:
+    """What this stack cannot run, refused at construction."""
+    first, depth = published_index(c, 0), c["num_hidden_layers"]
+    for key in _PER_LAYER:
+        if len(c[key]) < first + depth:
+            raise ValueError(f"{key} has {len(c[key])} entries for layers "
+                             f"{first}..{first + depth - 1}")
+    unknown = (set(c["indexer_types"][first:first + depth]) - {"full", "shared"}
+               | set(c["mlp_layer_types"][first:first + depth])
+               - {"dense", "sparse"})
+    if unknown:
+        raise ValueError(f"layer kinds {sorted(unknown)}: this stack has full "
+                         "| shared indexers and dense | sparse MLPs")
+    if c["indexer_types"][first] != "full":
+        raise ValueError(
+            f"layer {first} shares the key selection of a layer before it: a "
+            "slice of the stack starts at a layer with a 'full' indexer")
+    for key, want in (("attention_bias", False), ("hidden_act", "silu"),
+                      ("scoring_func", "sigmoid"), ("topk_method", "noaux_tc"),
+                      ("n_group", 1), ("topk_group", 1)):
+        if c.get(key, want) != want:
+            raise ValueError(f"{key} {c[key]!r}: this stack is written for "
+                             f"{want!r}")
+    if c["qk_head_dim"] != c["qk_nope_head_dim"] + c["qk_rope_head_dim"]:
+        raise ValueError("qk_head_dim must be qk_nope_head_dim + "
+                         "qk_rope_head_dim")
+    if c["v_head_dim"] != c["qk_head_dim"]:
+        raise ValueError(
+            f"v_head_dim {c['v_head_dim']} against qk_head_dim "
+            f"{c['qk_head_dim']}: the attention kernels multiply heads of one "
+            "size on both sides")
+    if c["index_head_dim"] < c["qk_rope_head_dim"]:
+        raise ValueError("the indexer rotates its first qk_rope_head_dim dims")
+    if c["rope_parameters"].get("rope_type", "default") != "default":
+        raise ValueError("rope_type: this stack is written for 'default'")
+    rotary_frequencies(c["rope_parameters"], c["qk_rope_head_dim"])
+    routed = c.get("n_experts_routed", c["n_routed_experts"])
+    held_from = c.get("experts_held_from", 0)
+    if not 0 <= held_from <= routed - c["n_routed_experts"]:
+        raise ValueError(
+            f"experts {held_from}..{held_from + c['n_routed_experts'] - 1} "
+            f"held of {routed} routed")
+
+
+def _pairing(interleave: bool) -> str:
+    return "interleave" if interleave else "rotate_half"
+
+
+def _dense(feats: int, name: str, dtype, param_dtype) -> nn.Dense:
+    return nn.Dense(feats, use_bias=False, dtype=dtype, param_dtype=param_dtype,
+                    kernel_init=trunc_normal(std=0.02), name=name)
+
+
+class Indexer(nn.Module):
+    """S of a ``full`` layer, as the mask ``selected_attention`` reads, from
+    the layer's normed input ``y`` and the query latent ``c_q``."""
+
+    trunk: Mapping[str, Any]
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, c_q):
+        c = self.trunk
+        n, L, _ = y.shape
+        J, D = c["index_n_heads"], c["index_head_dim"]
+        dense = lambda feats, name: _dense(feats, name, self.dtype,
+                                           self.param_dtype)
+        rope = rotary_frequencies(c["rope_parameters"], c["qk_rope_head_dim"])
+        pairing = _pairing(c.get("indexer_rope_interleave", False))
+        q = apply_rotary(dense(J * D, "wq_b")(c_q), J, *rope, pairing=pairing)
+        k = nn.LayerNorm(epsilon=INDEX_NORM_EPS, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="k_norm")(
+            dense(D, "wk")(y))
+        k = apply_rotary(k, 1, *rope, pairing=pairing)
+        w = (dense(J, "weights_proj")(y).astype(jnp.float32)
+             * (J ** -0.5 * D ** -0.5))
+        return select(q.reshape(n, L, J, D), k, w, c["index_topk"])
+
+
+class LatentAttention(nn.Module):
+    trunk: Mapping[str, Any]
+    indexer: bool
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, keep):
+        """(the attention's result, the selection it attended over)."""
+        c = self.trunk
+        n, L, width = y.shape
+        H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
+                        c["qk_rope_head_dim"])
+        hd, vd, rank = c["qk_head_dim"], c["v_head_dim"], c["kv_lora_rank"]
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        dense = lambda feats, name: _dense(feats, name, **kw)
+        norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
+        rope = rotary_frequencies(c["rope_parameters"], rot)
+        pairing = _pairing(c.get("rope_interleave", False))
+
+        c_q = norm("q_a_layernorm")(dense(c["q_lora_rank"], "q_a_proj")(y))
+        q = apply_rotary(dense(H * hd, "q_b_proj")(c_q), H, *rope,
+                         pairing=pairing, first=nope)
+        kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
+        k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
+        kv = dense(H * (nope + vd), "kv_b_proj")(
+            norm("kv_a_layernorm")(kv_a[..., :rank])).reshape(n, L, H, nope + vd)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_r[:, :, None, :], (n, L, H, rot))], axis=-1)
+        if self.indexer:
+            with jax.named_scope("trunk/dsa_index"):
+                keep = Indexer(c, name="indexer", **kw)(y, c_q)
+        out = selected_attention(q.reshape(n, L, H, hd), k, kv[..., nope:],
+                                 hd ** -0.5, keep)
+        return dense(width, "o_proj")(out.reshape(n, L, H * vd)), keep
+
+
+class GlmLayer(nn.Module):
+    """``(x, keep) → (x, keep)``: the second item is the key selection the
+    layer attended over, its own (``full``) or the one it was handed
+    (``shared``), for the next layer."""
+
+    trunk: Mapping[str, Any]
+    index: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, keep=None):
+        c, layer = self.trunk, published_index(self.trunk, self.index)
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
+        kind = c["indexer_types"][layer]
+        if kind == "shared" and keep is None:
+            raise ValueError(f"layer {layer} shares a key selection and was "
+                             "handed none")
+        _kernels.inc("kernels.dsa_indexer_layers", key=kind)
+        with jax.named_scope("trunk/mla"):
+            out, keep = LatentAttention(c, kind == "full", name="self_attn",
+                                        **kw)(norm("input_layernorm")(x), keep)
+            x = x + out
+        y = norm("post_attention_layernorm")(x)
+        if c["mlp_layer_types"][layer] == "dense":
+            with jax.named_scope("trunk/mlp"):
+                return x + GatedMlp(c, name="mlp", **kw)(y), keep
+        with jax.named_scope("trunk/moe"):
+            return x + HeldExpertsMlp(
+                num_routed=c.get("n_experts_routed", c["n_routed_experts"]),
+                top_k=c["num_experts_per_tok"],
+                first_held=c.get("experts_held_from", 0),
+                num_held=c["n_routed_experts"],
+                hidden_features=c["moe_intermediate_size"],
+                shared_features=(c["n_shared_experts"]
+                                 * c["moe_intermediate_size"]),
+                scaling=c.get("routed_scaling_factor", 1.0),
+                norm_topk=c.get("norm_topk_prob", True),
+                score="sigmoid", selection_bias=True,
+                name="mlp", **kw)(y), keep
+
+
+def layer(trunk, i: int, dtype, param_dtype, name: str) -> nn.Module:
+    """Layer ``i`` of this stack."""
+    return GlmLayer(trunk, i, dtype, param_dtype, name=name)

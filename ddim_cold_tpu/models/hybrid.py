@@ -6,12 +6,14 @@ the stack's layers and the final RMSNorm between them.
 The trunk's sizes are read from ``trunk``, a mapping whose keys are those of
 the language model's published ``config.json``, letter for letter, so a
 configuration file carries the source's own keys. Its ``model_type`` chooses
-the layer stack (:func:`stack_of`): ``jamba`` (the default; this module) or
+the layer stack (:func:`stack_of`): ``jamba`` (the default; this module),
 ``laguna`` (``models/laguna.py``: window and full attention on shared K/V
 heads with rotary positions and per-head gates, a dense MLP or top-k routed
-experts of which this chip holds a share). One wrapper serves both: what the
-samplers and the engine read of a model, ``clone``, the refusals and
-``__call__`` below.
+experts of which this chip holds a share) or ``glm_moe_dsa``
+(``models/glm.py``: latent attention over a learned per-query selection of
+keys that some layers compute and the others borrow, sigmoid-scored
+experts). One wrapper serves all: what the samplers and the engine read of a
+model, ``clone``, the refusals and ``__call__`` below.
 
 The ``jamba`` stack: Mamba-1 state-space layers with a causal grouped-query
 attention layer every ``attn_layer_period``, every layer followed by a gated
@@ -76,8 +78,9 @@ REFUSED = {
                    "a trunk's experts are its own (trunk: num_experts)",
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
-                 "layers are dense XLA attention, the laguna stack runs the "
-                 "masked flash forward wherever the backend is a TPU",
+                 "layers are dense XLA attention, the laguna and glm_moe_dsa "
+                 "stacks run the masked flash forward wherever the backend "
+                 "is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -301,8 +304,12 @@ def stack_of(trunk: Mapping[str, Any]) -> tuple:
         from ddim_cold_tpu.models import laguna
 
         return laguna.check_trunk, laguna.layer
+    if model_type == "glm_moe_dsa":
+        from ddim_cold_tpu.models import glm
+
+        return glm.check_trunk, glm.layer
     raise ValueError(f"no layer stack for model_type {model_type!r}: "
-                     "'jamba' and 'laguna' are written")
+                     "'jamba', 'laguna' and 'glm_moe_dsa' are written")
 
 
 def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:
@@ -379,9 +386,14 @@ class HybridDenoiser(nn.Module):
                                   deterministic=True,
                                   param_dtype=self.param_dtype)
         _, layer_of = stack_of(self.trunk)
+        # a layer that returns a pair hands its second item to the next one
+        # (glm_moe_dsa: the key selection a layer without an indexer borrows)
+        handed = ()
         for i in range(self.depth):
-            tokens = layer_of(self.trunk, i, self.dtype, self.param_dtype,
-                              name=f"layers_{i}")(tokens)
+            out = layer_of(self.trunk, i, self.dtype, self.param_dtype,
+                           name=f"layers_{i}")(tokens, *handed)
+            tokens, handed = ((out[0], out[1:]) if isinstance(out, tuple)
+                              else (out, ()))
         tokens = RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
                          self.param_dtype, name="final_layernorm")(tokens)
         return vit.pixel_head(self, tokens, param_dtype=self.param_dtype)

@@ -167,8 +167,11 @@ class HeldExpertsMlp(nn.Module):
     without its exchange.
 
     With y ``(rows, hidden)``: ``r = softmax_f32(y W_r)`` over all
-    ``num_routed`` router outputs; ``S`` = the ``top_k`` largest (ties to the
-    lower index); ``w_e = scaling · r_e / Σ_{e'∈S} r_e'`` (``norm_topk``; else
+    ``num_routed`` router outputs (``score="sigmoid"``: ``sigmoid_f32``, each
+    output by itself); ``S`` = the ``top_k`` largest (ties to the lower
+    index) of r, or with ``selection_bias`` of ``r + b``, b a per-expert
+    ``e_score_correction_bias`` that chooses and never weighs; ``w_e =
+    scaling · r_e / Σ_{e'∈S} r_e'`` (``norm_topk``; else
     ``scaling · r_e``); out ``= Shared(y) + Σ_{e ∈ S ∩ held} w_e E_e(y)``,
     ``Shared`` and every ``E_e`` the MLP ``W_down(SiLU(W_gate y) ⊙ W_up y)``
     at width ``hidden_features``. What the experts held elsewhere would add is
@@ -188,7 +191,8 @@ class HeldExpertsMlp(nn.Module):
 
     Parameters: ``router (hidden, num_routed)``; ``gate_proj``,
     ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
-    width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp``."""
+    width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp``;
+    ``e_score_correction_bias (num_routed,)`` with ``selection_bias``."""
 
     num_routed: int
     top_k: int
@@ -198,6 +202,8 @@ class HeldExpertsMlp(nn.Module):
     shared_features: int
     scaling: float = 1.0
     norm_topk: bool = True
+    score: str = "softmax"
+    selection_bias: bool = False
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -205,6 +211,9 @@ class HeldExpertsMlp(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         from ddim_cold_tpu.models.hybrid import GatedMlp
 
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {self.score!r}: 'softmax' and 'sigmoid' "
+                             "are written")
         *lead, D = x.shape
         y = x.reshape(-1, D)
         T, k, G, F = y.shape[0], self.top_k, self.num_held, self.hidden_features
@@ -221,8 +230,16 @@ class HeldExpertsMlp(nn.Module):
         ).astype(self.dtype)
         logits = jnp.dot(y, param("router", (D, self.num_routed)),
                          preferred_element_type=jnp.float32)
-        r = jax.nn.softmax(logits, axis=-1)
-        top_r, top_e = jax.lax.top_k(r, k)  # (T, k); ties to the lower index
+        r = (jax.nn.softmax(logits, axis=-1) if self.score == "softmax"
+             else jax.nn.sigmoid(logits))
+        if self.selection_bias:
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros_init(),
+                              (self.num_routed,), self.param_dtype)
+            _, top_e = jax.lax.top_k(r + bias.astype(jnp.float32), k)
+            top_r = jnp.take_along_axis(r, top_e, axis=-1)
+        else:
+            top_r, top_e = jax.lax.top_k(r, k)  # (T, k); ties to the lower index
         if self.norm_topk:
             top_r = top_r / jnp.sum(top_r, axis=-1, keepdims=True)
         weight = self.scaling * top_r

@@ -112,7 +112,12 @@ METRICS = (
     ("kernels.flash_bwd_layout", "counter",
      "flash backward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_fwd_mask", "counter",
-     "flash forward traces by mask (key: none|causal|window)"),
+     "flash forward traces by mask (key: none|causal|window|selected)"),
+    # -- kernels (ops/sparse_select.py, counted once a trace) -------------
+    ("kernels.dsa_select_schedule", "counter",
+     "sparse-attention key selection traces by path (key: kernel|xla)"),
+    ("kernels.dsa_indexer_layers", "counter",
+     "glm stack layers traced by indexer kind (key: full|shared)"),
     # -- kernels (ops/grouped_matmul.py, counted once a trace) ------------
     ("kernels.moe_gmm_schedule", "counter",
      "grouped expert matmul traces by path (key: kernel|xla)"),

@@ -1168,11 +1168,15 @@ class _Ints:
     minimum, maximum = staticmethod(min), staticmethod(max)
 
 
-def _fwd_masked_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                       scale: float, n_valid: int, bq: int, bkv: int,
-                       n_kv: int, causal: bool, window: int | None):
+def _fwd_masked_kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_valid: int,
+                       bq: int, bkv: int, n_kv: int, causal: bool,
+                       window: int | None, selected: bool = False):
     """One (image, query head, q block, visited chunk) program of the masked
-    forward: one head on the block's lanes. Grid step ``j`` folds chunk
+    forward: one head on the block's lanes. ``selected``: a fourth operand,
+    the ``(bq, bkv)`` int8 tile of a per-query key selection
+    (``ops/sparse_select.py``), comes before the result; a pair is then kept
+    where the tile is not 0 AND the mask lets it through, so every visited
+    chunk takes the masked fold. Grid step ``j`` folds chunk
     ``first + j`` of the chunks the mask lets this q block see
     (:func:`_visible_chunks`; the K/V index maps address the same chunk) and
     does nothing once past the last of them. The element mask is built only
@@ -1184,6 +1188,8 @@ def _fwd_masked_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     chunk, for the block's last rows) holds m = −1e30 and garbage l, acc until
     its diagonal chunk — always visited, and later — scales them by
     exp(−1e30 − m) = 0."""
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, acc_ref, m_ref, l_ref = rest
     i, j = pl.program_id(2), pl.program_id(3)
     lo, hi = _visible_chunks(i, bq=bq, bkv=bkv, n_valid=n_valid,
                              causal=causal, window=window)
@@ -1219,6 +1225,8 @@ def _fwd_masked_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                 keep &= col <= row
             if window is not None:
                 keep &= col > row - window
+            if selected:
+                keep &= keep_ref[0].astype(jnp.int32) != 0
             logits = jnp.where(keep, logits, _NEG_INF)
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (bq, 1)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
@@ -1237,6 +1245,8 @@ def _fwd_masked_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         whole &= (c + 1) * bkv - 1 <= i * bq
     if window is not None:
         whole &= c * bkv > i * bq + bq - 1 - window
+    if selected:
+        whole = False
     pl.when((c <= hi) & whole)(lambda: fold(False))
     pl.when((c <= hi) & jnp.logical_not(whole))(lambda: fold(True))
 
@@ -1254,9 +1264,12 @@ def _masked_blocks(n_tokens: int, dtype) -> tuple:
     return block, block
 
 
-def _fwd_masked_call(q, k, v, *, rep, lanes, scale, n_valid, bq, bkv, causal,
-                     window, interpret):
-    """The masked launch. ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
+def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
+                     bkv, causal, window, interpret):
+    """The masked launch (``fwd_masked``), or with ``keep`` — an int8 ``(rows,
+    tokens⁺, tokens⁺)`` selection in whole ``(bq, bkv)`` tiles, one for all
+    the heads — the selected one (``fwd_selected``: the same body, a name of
+    its own in a device trace). ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
     ``(rows, tokens, H/rep·lanes)``, query head ``h`` reading K/V column
     block ``h // rep``; all three where the projections wrote them, the token
     axis ending inside the last block. Grid ``(rows, H, q blocks, visited
@@ -1277,12 +1290,17 @@ def _fwd_masked_call(q, k, v, *, rep, lanes, scale, n_valid, bq, bkv, causal,
 
     q_spec = pl.BlockSpec((1, bq, lanes), lambda b, h, i, j: (b, i, h))
     kv_spec = pl.BlockSpec((1, bkv, lanes), kv_map)
-    with profiling.scope("flash_attention/fwd_masked"):
+    name, operands, in_specs = "fwd_masked", (q, k, v), [q_spec, kv_spec, kv_spec]
+    if keep is not None:
+        name, operands = "fwd_selected", (q, k, v, keep)
+        in_specs.append(pl.BlockSpec(
+            (1, bq, bkv), lambda b, h, i, j: (b, i, kv_map(b, h, i, j)[1])))
+    with profiling.scope(f"flash_attention/{name}"):
         return pl.pallas_call(
             functools.partial(_fwd_masked_kernel, scale=scale, n_kv=n_kv,
-                              **geometry),
+                              selected=keep is not None, **geometry),
             grid=(rows, width // lanes, n_q, n_kv),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=in_specs,
             out_specs=q_spec,
             out_shape=_sds(q.shape, q.dtype, q),
             scratch_shapes=[
@@ -1295,8 +1313,8 @@ def _fwd_masked_call(q, k, v, *, rep, lanes, scale, n_valid, bq, bkv, causal,
                                      "arbitrary"),
             ),
             interpret=interpret,
-            name="fwd_masked",
-        )(q, k, v)
+            name=name,
+        )(*operands)
 
 
 def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
@@ -1315,25 +1333,42 @@ def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
     side). K/V chunks that lie wholly outside the mask of a q block are
     neither fetched nor computed (:func:`_fwd_masked_call`). Blocks come from
     the shape. No backward yet: the VJP raises by name (ROADMAP Reach)."""
+    _check_shared_heads(q, k, v)
+    _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, window))
+    return _masked_forward(q, k, v, None, scale, causal, window)
+
+
+def _check_shared_heads(q, k, v) -> None:
     B, N, H, D = q.shape
-    KV = k.shape[2]
-    if H % KV or k.shape != v.shape or k.shape[:2] != (B, N) or k.shape[3] != D:
+    if (H % k.shape[2] or k.shape != v.shape or k.shape[:2] != (B, N)
+            or k.shape[3] != D):
         raise ValueError(f"q {q.shape} cannot share k {k.shape}, v {v.shape}: "
                          "query heads must divide into the K/V heads")
-    _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, window))
+
+
+def _masked_forward(q, k, v, keep, scale, causal, window):
+    """The launch of :func:`flash_attention_masked` (``keep`` None) or
+    :func:`flash_attention_selected` on ``(B, N, heads, D)`` operands: heads
+    zero-padded to whole lanes where ``D`` does not fill them, blocks from
+    the shape, one launch a device under a mesh."""
+    B, N, H, D = q.shape
+    KV = k.shape[2]
     lanes = tiling.round_up(D, _LANE)
     if lanes != D:
         q, k, v = (_pad_to(x, 3, _LANE) for x in (q, k, v))
     bq, bkv = _masked_blocks(N, q.dtype)
     spec = rows_spec(B)
+    operands = (q.reshape(B, N, H * lanes), k.reshape(B, N, KV * lanes),
+                v.reshape(B, N, KV * lanes))
+    if keep is not None:
+        operands += (keep,)
     out = per_device(
         functools.partial(
             _fwd_masked_call, rep=H // KV, lanes=lanes, scale=scale, n_valid=N,
             bq=bq, bkv=bkv, causal=causal, window=window,
             interpret=kernel_interpret()),
-        (spec, spec, spec), spec,
-    )(q.reshape(B, N, H * lanes), k.reshape(B, N, KV * lanes),
-      v.reshape(B, N, KV * lanes))
+        (spec,) * len(operands), spec,
+    )(*operands)
     return out.reshape(B, N, H, lanes)[..., :D]
 
 
@@ -1363,6 +1398,73 @@ def masked_attention(q, k, v, scale: float, *, causal: bool = True,
         return _masked_no_vjp(q, k, v, scale, causal, window)
     return blockwise_attention_xla(q, k, v, scale, causal=causal,
                                    window=window)
+
+
+def flash_attention_selected(q, k, v, scale: float, keep) -> jax.Array:
+    """The causal forward over a per-query SET of keys, as its own launch
+    (``pallas_call(name="fwd_selected")``, ``%fwd_selected`` in a device
+    trace; ``%fwd_masked`` keeps reading the launch without a selection).
+
+    q, k, v as :func:`flash_attention_masked`; ``keep`` int8 ``(B, N⁺, N⁺)``,
+    N⁺ the token count in whole blocks (``ops.sparse_select.mask_length``),
+    not 0 where query t attends to key s — one selection for all the heads.
+    Token t attends to ``{s ≤ t : keep[t, s]}``, which must not be empty (the
+    ``top`` best of the visible keys never is). Every chunk at or below the
+    diagonal is multiplied and masked by its tile: with a scattered set there
+    is no chunk to skip, and a gather a row is 2,048 descriptors a query. No
+    backward yet: the VJP raises by name."""
+    _check_shared_heads(q, k, v)
+    B, N = q.shape[:2]
+    bq, bkv = _masked_blocks(N, q.dtype)
+    want = (B, tiling.round_up(N, bq), tiling.round_up(N, bkv))
+    if keep.shape != want or keep.dtype != jnp.int8:
+        raise ValueError(f"keep {keep.dtype}{keep.shape}: the selection of "
+                         f"{N} tokens is int8{want}")
+    _kernels.inc("kernels.flash_fwd_mask", key="selected")
+    return _masked_forward(q, k, v, keep, scale, True, None)
+
+
+def selected_attention_xla(q, k, v, scale: float, keep) -> jax.Array:
+    """:func:`flash_attention_selected` in plain ``jax.numpy``: the whole
+    score matrix under the selection, softmax in float32. Differentiable in
+    q, k, v; the oracle of the kernel and what runs off the TPU, at sizes
+    whose ``(B, H, N, N)`` scores fit."""
+    B, N, H, D = q.shape
+    if k.shape[2] != H:
+        k, v = (jnp.repeat(x, H // x.shape[2], axis=2) for x in (k, v))
+    sees = (jnp.arange(N)[None, :] <= jnp.arange(N)[:, None])
+    sees = sees & (keep[:, :N, :N] != 0)
+    logits = jnp.einsum("bnhd,bmhd->bhnm", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(sees[:, None], logits, _NEG_INF), axis=-1)
+    return jnp.einsum("bhnm,bmhd->bnhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _selected_no_vjp(q, k, v, keep, scale):
+    return flash_attention_selected(q, k, v, scale, keep)
+
+
+def _selected_no_vjp_fwd(*args):
+    raise NotImplementedError(
+        "the fwd_selected kernel has no backward yet (ROADMAP Reach: the "
+        "selection in dq/dkv): differentiate "
+        "ops.flash_attention.selected_attention_xla, which is what "
+        "selected_attention runs off the TPU")
+
+
+_selected_no_vjp.defvjp(_selected_no_vjp_fwd, lambda *a: None)
+
+
+def selected_attention(q, k, v, scale: float, keep) -> jax.Array:
+    """Causal attention over the per-query key sets ``keep``
+    (``ops.sparse_select.select``), shapes as
+    :func:`flash_attention_selected`; the backend decides what runs: that
+    kernel on the TPU, :func:`selected_attention_xla` anywhere else."""
+    if jax.default_backend() == "tpu":
+        return _selected_no_vjp(q, k, v, keep, scale)
+    return selected_attention_xla(q, k, v, scale, keep)
 
 
 # ---------------------------------------------------------------------------

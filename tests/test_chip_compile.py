@@ -17,6 +17,7 @@ tests steer them — not an option of the program.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -627,3 +628,62 @@ def test_moe_gmm_lowers_at_the_published_shapes_and_keeps_its_name(
     assert len(calls) == 1
     m = reader.NAME.match(calls[0])
     assert m and [int(g) for g in m.groups()[1:]] == [-(-rows // 128) * 128, N]
+
+
+# --- the selection and the selected forward at GLM-5.2's shapes -------------
+
+GLM = dict(n=1, L=9217, heads=64, hd=256, index_heads=32, index_dim=128,
+           top=2048)
+
+
+def test_the_selection_lowers_at_the_published_shapes_without_a_sort(chip):
+    """``ops/sparse_select.py`` at 9,217 tokens, 32 index heads of 128, the
+    2,048 best: TWO ``tpu_custom_call``s, ``%dsa_index`` (float32 scores in
+    whole blocks) and ``%dsa_select`` (their int8 selection), as the readers
+    under ``benchmark/layer_metrics`` match them by name — and no ``sort`` or
+    ``topk`` in the program."""
+    from benchmark.layer_metrics import dsa_index_roofline, dsa_select_roofline
+    from ddim_cold_tpu.ops import sparse_select as ss
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, J, D = (GLM[k] for k in ("n", "L", "index_heads", "index_dim"))
+    text = jax.jit(lambda q, k, w: ss.select(q, k, w, GLM["top"])).lower(
+        sds((n, L, J, D), jnp.bfloat16), sds((n, L, D), jnp.bfloat16),
+        sds((n, L, J), jnp.float32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    length = ss.mask_length(L, jnp.bfloat16)
+    for reader, call, dtype in ((dsa_index_roofline, calls[0], "f32"),
+                                (dsa_select_roofline, calls[1], "s8")):
+        m = reader.NAME.match(call)
+        assert m and [int(g) for g in m.groups()[1:]] == [n, length, length]
+        assert f" = {dtype}[" in call
+    assert not re.search(r"\bsort\(|\btopk\(|TopK", text)
+
+
+def test_fwd_selected_lowers_at_the_published_shapes_and_keeps_its_name(chip):
+    """The attention forward over the selection at 9,217 tokens, 64 heads of
+    256 read in place: ONE ``tpu_custom_call``, named ``%fwd_selected``
+    (``benchmark/layer_metrics/flash_selected_fwd_roofline.py`` matches it by
+    that name), which the ``%fwd_masked`` and ``%fwd`` readers do not match."""
+    from benchmark.layer_metrics import flash_fwd_roofline
+    from benchmark.layer_metrics import flash_masked_fwd_roofline
+    from benchmark.layer_metrics import flash_selected_fwd_roofline as reader
+    from ddim_cold_tpu.ops import sparse_select as ss
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, H, hd = (GLM[k] for k in ("n", "L", "heads", "hd"))
+    length = ss.mask_length(L, jnp.bfloat16)
+    head = sds((n, L, H, hd), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, keep: fa.selected_attention(
+        q, k, v, hd ** -0.5, keep)).lower(
+        head, head, head, sds((n, length, length), jnp.int8)
+    ).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and [int(g) for g in m.groups()[1:]] == [n, L, H * hd]
+    assert not flash_masked_fwd_roofline.NAME.match(calls[0])
+    assert not flash_fwd_roofline.NAME.match(calls[0])

@@ -107,11 +107,11 @@ def test_forward_in_bfloat16_is_within_a_tolerance_the_float8_control_fails():
 
 # ------------------------------------------------------- the expert layer
 
-def _expert_layer(first, held, dtype=jnp.float32):
+def _expert_layer(first, held, dtype=jnp.float32, **router):
     return moe.HeldExpertsMlp(
         num_routed=16, top_k=3, first_held=first, num_held=held,
         hidden_features=32, shared_features=32, scaling=2.5, dtype=dtype,
-        param_dtype=dtype)
+        param_dtype=dtype, **router)
 
 
 def _uncut_tree(seed=3):
@@ -125,26 +125,61 @@ def _share(tree, first, held):
     return dict(tree, **banks)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Experts 0-7 on one chip and 8-15 on the other, the shared expert
-    computed by both and counted once, against the reference's uncut layer."""
+def _softmax_router():
+    """(uncut tree, the reference's layer given a share, the program's)."""
     tree = _uncut_tree()
+    whole = lambda share, y: ref.sparse_mlp(
+        _share(tree, *share), y,
+        dict(TRUNK, experts_held_from=share[0], num_experts=share[1]))
+    return tree, whole, _expert_layer
+
+
+def _sigmoid_router_with_a_selection_bias():
+    """GLM-5.2's router on the same banks: a sigmoid a router output, the top
+    3 of score + bias chosen, weighed by the score alone."""
+    from benchmark.reference import glm as glm_ref
+
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (16,))
+    tree = dict(_uncut_tree(), e_score_correction_bias=bias)
+    cfg = dict(num_experts_per_tok=3, norm_topk_prob=True,
+               routed_scaling_factor=2.5)
+    whole = lambda share, y: glm_ref.sparse_mlp(
+        _share(tree, *share), y,
+        dict(cfg, experts_held_from=share[0], n_routed_experts=share[1]))
+    layer = lambda first, held: _expert_layer(
+        first, held, score="sigmoid", selection_bias=True)
+    # the bias does choose: without it other experts are taken
+    y = jax.random.normal(jax.random.PRNGKey(2), (34, 64))
+    unbiased = glm_ref.sparse_mlp(
+        dict(tree, e_score_correction_bias=0 * bias), y,
+        dict(cfg, n_routed_experts=16))
+    assert float(jnp.abs(unbiased - whole((0, 16), y)).max()) > 1e-3
+    return tree, whole, layer
+
+
+@pytest.mark.parametrize("router,chips", [
+    (_softmax_router, 2), (_sigmoid_router_with_a_selection_bias, 16)])
+def test_the_shares_add_up_to_the_uncut_layer(router, chips):
+    """The 16 experts over ``chips`` chips (0-7 and 8-15; or one each), the
+    shared expert computed by all and counted once, against the reference's
+    uncut layer."""
+    tree, reference, layer = router()
+    held = 16 // chips
     y = jax.random.normal(jax.random.PRNGKey(2), (2, 17, 64))
-    uncut = dict(TRUNK, num_experts=16)
-    want = ref.sparse_mlp(tree, y.reshape(-1, 64), uncut).reshape(y.shape)
-    shares = [_expert_layer(first, 8).apply(
-        {"params": _share(tree, first, 8)}, y) for first in (0, 8)]
+    want = reference((0, 16), y.reshape(-1, 64)).reshape(y.shape)
+    shares = [layer(first, held).apply(
+        {"params": _share(tree, first, held)}, y)
+        for first in range(0, 16, held)]
     shared = hybrid.GatedMlp(
         {"hidden_size": 64, "intermediate_size": 32}).apply(
         {"params": tree["shared_expert"]}, y)
-    np.testing.assert_allclose(shares[0] + shares[1] - shared, want,
-                               rtol=1e-4, atol=1e-6)
-    # each share alone is the reference's share, and neither is the whole
-    for first, got in zip((0, 8), shares):
-        cut = dict(TRUNK, experts_held_from=first)
+    np.testing.assert_allclose(sum(shares) - (chips - 1) * shared, want,
+                               rtol=1e-4, atol=2e-6)
+    # each share alone is the reference's share, and none is the whole
+    for first, got in zip(range(0, 16, held), shares):
         np.testing.assert_allclose(
-            got, ref.sparse_mlp(_share(tree, first, 8), y.reshape(-1, 64),
-                                cut).reshape(y.shape), rtol=1e-4, atol=1e-6)
+            got, reference((first, held), y.reshape(-1, 64)).reshape(y.shape),
+            rtol=1e-4, atol=1e-6)
         assert float(jnp.abs(got - want).max()) > 1e-3
 
 
