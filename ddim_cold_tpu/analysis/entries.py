@@ -508,7 +508,8 @@ def kernel_entries() -> list[Entry]:
     sampler scans at 200px — every in-tree
     pallas_call at the EXACT geometry that crashed r04 — plus standalone
     flash forward/grad traces per (dtype, block config) covering the
-    backward dq/dkv kernels and every ``FLASH_BLOCK_SWEEP`` row, the
+    backward kernels (``dq`` + ``dkv`` at explicit blocks, the one ``dqkv``
+    launch where they are left to it) and every ``FLASH_BLOCK_SWEEP`` row, the
     dequant-pallas kernel at the 200px trunk GEMM shapes, and the float
     trunk's token-wise kernels (``ln_qkv``, ``block_tail``). The TINY serve
     sweep contains zero pallas_calls (it serves quant="xla" only), so
@@ -565,14 +566,17 @@ def kernel_entries() -> list[Entry]:
         meta=dict(mem)))
 
     # standalone flash kernels per (dtype, blocks): forward for every
-    # sweep row, grad (the backward dq/dkv kernels) at the default and
-    # tuned configs. scale matches the model's head_dim=64.
+    # sweep row, grad at the streamed default and the tuned config (the
+    # backward dq/dkv kernels) and with the blocks left to the kernels (the
+    # resident forward, the one dqkv launch). scale matches the model's
+    # head_dim=64.
     qkv = jax.ShapeDtypeStruct((2, NS_TOKENS, cfg["num_heads"],
                                 cfg["embed_dim"] // cfg["num_heads"]),
                                jnp.float32)
     scale = (cfg["embed_dim"] // cfg["num_heads"]) ** -0.5
     configs = []
-    for bq, bkv in ((256, 512), NS_FLASH_BLOCKS, *FLASH_BLOCK_SWEEP):
+    for bq, bkv in ((256, 512), NS_FLASH_BLOCKS, *FLASH_BLOCK_SWEEP,
+                    (None, None)):
         if (bq, bkv) not in configs:
             configs.append((bq, bkv))
     for dt_label, dtype in (("f32", jnp.float32), ("bf16", jnp.bfloat16)):
@@ -581,15 +585,16 @@ def kernel_entries() -> list[Entry]:
             def fwd(qq, kk, vv, _bq=bq, _bkv=bkv):
                 return flash_attention(qq, kk, vv, scale, _bq, _bkv)
 
+            blocks = "auto" if bq is None else f"{bq}x{bkv}"
             entries.append(Entry(
-                f"flash200_{dt_label}_{bq}x{bkv}", _FLASH_PATH, fwd,
+                f"flash200_{dt_label}_{blocks}", _FLASH_PATH, fwd,
                 (q, q, q), meta=dict(tokens=NS_TOKENS)))
-            if (bq, bkv) in ((256, 512), NS_FLASH_BLOCKS):
+            if (bq, bkv) in ((256, 512), NS_FLASH_BLOCKS, (None, None)):
                 def loss(qq, kk, vv, _f=fwd):
                     return jnp.sum(_f(qq, kk, vv).astype(jnp.float32))
 
                 entries.append(Entry(
-                    f"flash200_grad_{dt_label}_{bq}x{bkv}", _FLASH_PATH,
+                    f"flash200_grad_{dt_label}_{blocks}", _FLASH_PATH,
                     jax.grad(loss, argnums=(0, 1, 2)), (q, q, q),
                     meta=dict(tokens=NS_TOKENS)))
 

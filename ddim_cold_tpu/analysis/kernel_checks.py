@@ -82,11 +82,15 @@ WASTE_THRESHOLD = 1.25
 #: rows, ``dkv`` zeroes the q and do rows past it and selects their lse and
 #: delta (rows of arrays padded to the block, like every lane axis, which
 #: ``dq`` fills only as far as its own q blocks reach) to 1e30 and 0;
+#: ``dqkv``, the one launch of both where the sequence is resident for it,
+#: does what ``dkv`` does on the same transposed tiles and zeroes the delta it
+#: computes itself past the sequence (PERF.md section 6, PR 34);
 #: ``ln_qkv`` and ``block_tail`` (ops/block_kernels.py) are row-wise from end
 #: to end, so what a row past the image holds stays in that row and is
 #: dropped with it (PERF.md section 6, PR 32). Every other kernel keeps the
 #: pad-to-block-multiple policy, on both axes.
-RAGGED_SUBLANE_OK = frozenset({"fwd", "dq", "dkv", "ln_qkv", "block_tail"})
+RAGGED_SUBLANE_OK = frozenset({"fwd", "dq", "dkv", "dqkv", "ln_qkv",
+                               "block_tail"})
 
 
 def _round_up(n: int, m: int) -> int:

@@ -108,7 +108,8 @@ METRICS = (
     ("kernels.flash_fwd_layout", "counter",
      "flash forward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_bwd_schedule", "counter",
-     "flash backward traces by dq's K/V schedule (key: resident|streamed)"),
+     "flash backward traces by schedule (key: fused, the one dqkv launch; "
+     "else dq + dkv by dq's K/V chunking: resident|streamed)"),
     ("kernels.flash_bwd_layout", "counter",
      "flash backward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_fwd_mask", "counter",

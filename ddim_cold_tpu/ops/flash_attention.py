@@ -63,11 +63,16 @@ Autodiff: ONE custom VJP (:func:`_attention`) under both entries,
 packed projection), flash all the way through. The VJP's forward additionally
 emits the per-row log-sum-exp (the undifferentiated call, which is all a
 sampler makes, launches the kernel without that result and its write); the
-backward runs two more Pallas kernels — dq (grid like the forward) and dk/dv
-(grid transposed: K/V blocks outer, q chunks innermost, and the scores
-computed transposed, ``k·qᵀ``, so that ``pᵀ·do`` and ``dsᵀ·q`` need no
-(bq, bkv) transpose) — that rebuild probabilities from the saved lse chunk by
-chunk, so the O(N²) matrix never exists in HBM in either direction. Residuals
+backward rebuilds probabilities from the saved lse tile by tile, so the
+O(N²) matrix never exists in HBM in either direction: ONE more Pallas kernel,
+``dqkv``, wherever the sequence is resident for it — K/V blocks along the
+steps, q, the context, the cotangent and the statistics one chunk in VMEM,
+each tile's scores, p, dp and ds formed once and all three gradients taken
+from them, five GEMMs a head — and two where it is too long for that, dq (grid
+like the forward) and dk/dv (grid transposed: K/V blocks outer, q chunks
+innermost), seven GEMMs a head. The scores of ``dqkv`` and dk/dv are computed
+transposed, ``k·qᵀ``, so that ``pᵀ·do`` and ``dsᵀ·q`` need no (bq, bkv)
+transpose. Residuals
 are the operands as the forward read them (the packed projection stays
 packed), the context and lse: O(N·D) — the whole train-step memory story for
 long sequences is bounded. (The forward emits lse 128-lane-replicated because
@@ -75,14 +80,17 @@ TPU tiling rejects (1, bq) row blocks, and the replication is cut off outside
 the kernel so the residual stays one lane a row and head. The backward reads
 that residual as it is: rows of ``(8, bq)`` blocks, a head a sublane, tokens
 on the lanes — :func:`_lse_rows` — which dkv's transposed tiles broadcast
-directly and dq turns into columns in VMEM; delta = Σ o·do is computed by dq
-from the context and cotangent blocks it holds and handed to dkv in the same
-form. Nothing is spread over 128 lanes in HBM.) Each backward kernel picks
-its blocks from the shape as the forward does (:func:`_bwd_blocks`): dq keeps
-K and V, dkv keeps q and do, as ONE resident chunk wherever its VMEM model
-admits that — (512, 2560) and (2560, 512) at the 200px trunk in bf16 — and
-both stream at (256, 512) where nothing fits; ``kernels.flash_bwd_schedule``
-counts which. Explicit blocks are honoured.
+directly and dq turns into columns in VMEM; delta = Σ o·do is computed
+inside the kernels from the context and cotangent blocks they hold — by
+``dqkv`` into VMEM scratch, never written to HBM; by dq, handed to dkv in the
+same form as lse. Nothing is spread over 128 lanes in HBM.) The backward picks
+its launches and blocks from the shape as the forward does
+(:func:`_bwd_blocks`): ``dqkv`` wherever its row of the VMEM model admits the
+whole padded sequence — (2560, 512) at the 200px trunk in bf16 — else dq
+keeping K and V, dkv keeping q and do, as ONE resident chunk wherever theirs
+do, and both streaming at (256, 512) where nothing fits;
+``kernels.flash_bwd_schedule`` counts which. Explicit blocks are honoured,
+and ask for dq and dk/dv.
 
 On the CPU backend the kernels run in interpreter mode, so tests exercise
 the identical code paths; any other non-TPU backend is an error — a caller
@@ -711,6 +719,129 @@ def _bwd_dkv_kernel(lse_ref, delta_ref, q_ref, k_ref, v_ref, do_ref, *rest,
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_dqkv_kernel(lse_ref, q_ref, k_ref, v_ref, o_ref, do_ref, *rest,
+                     scale: float, n_valid: int, block_kv: int, n_kv: int,
+                     heads: int, ragged_q: bool, ragged_kv: bool, packed: bool):
+    """One (row, lane group, step) program of ALL THREE gradients: K/V blocks
+    along the steps; q, the context, the cotangent and the statistics
+    resident as ONE chunk, the whole lane-padded sequence. Per head and K/V
+    block the scores are multiplied once, TRANSPOSED as in
+    :func:`_bwd_dkv_kernel` (``sᵀ = k·qᵀ``, (bkv, whole)), ``p = exp(sᵀ −
+    lse)`` and ``ds = p·(dp − delta)`` are formed once, and five GEMMs take
+    everything from them where dq + dkv run seven: sᵀ, dpᵀ = v·doᵀ,
+    dv_j = pᵀ·do and dk_j = scale · dsᵀ·q as dkv takes them, and
+    dq += scale · ds·k_j — the one product that contracts over the tile's
+    FIRST axis — taken the other way round, ``dqᵀ += k_jᵀ·dsᵀ``: the small
+    operand is transposed, a (lanes, bkv) tile a step, the product is a plain
+    GEMM into a transposed (lanes, whole) f32 accumulator that lives in VMEM
+    across the steps, and dq's rows are transposed back block by block as
+    they are written, once a program (the (bkv, whole) tile itself is never
+    transposed: PERF.md section 6, PR 34, has what each way costs). Head
+    ``h``'s dq is head ``h``'s ROWS of that accumulator, so its product takes
+    those rows of ``k_jᵀ`` alone — (head_dim, bkv)·(bkv, whole), no lanes
+    wasted on the other heads and nothing to select.
+
+    delta_i = Σ_d o_id·do_id is formed at the first step from the resident
+    context and cotangent, a head a sublane like lse, into VMEM scratch: it
+    never reaches HBM. Ragged edges as in :func:`_bwd_dkv_kernel`: q and do
+    rows past the sequence (``ragged_q``) zeroed, their lse selected to
+    ``_LSE_PAST_END`` and their delta to 0, so p and ds there are an exact 0;
+    K/V rows past it (``ragged_kv``) zeroed, since they feed dq (0 × garbage
+    is NaN), and their own dk and dv rows are dropped.
+
+    Steps: K/V block j is folded at step j and its dk and dv written; then
+    dq's rows go out block by block, ``n_kv`` steps more. ``packed``: the
+    three gradients are column blocks of ONE array and a program holds one
+    block of a result, so a K/V block takes two steps — dk_j's column block
+    at 2j, dv_j's (kept in scratch meanwhile) at 2j + 1, where nothing is
+    computed — and dq's follow."""
+    if packed:
+        dk_ref, dq_acc, dv_acc, delta_ref = rest
+        dq_ref = dv_ref = dk_ref
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, delta_ref = rest
+    t = pl.program_id(2)
+    whole = q_ref.shape[1]
+    fold = _scale_folds_into_q(scale)
+
+    def past_end(shape, axis, start=0):
+        return start + jax.lax.broadcasted_iota(jnp.int32, shape, axis) >= n_valid
+
+    @pl.when(t == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        o_do = o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32)
+        pick = _head_picker(o_do.shape, heads)
+        at = jax.lax.broadcasted_iota(jnp.int32, (whole, _LANE), 1)
+        tile = jnp.zeros(at.shape, jnp.float32)
+        for h in range(heads):
+            tile = jnp.where(at == h, jnp.sum(pick(h, o_do), axis=-1,
+                                              keepdims=True), tile)
+        delta = tile.T[:_STAT_ROWS]  # columns → rows: head h on sublane h
+        if ragged_q:
+            delta = jnp.where(past_end(delta.shape, 1), 0.0, delta)
+        delta_ref[...] = delta
+
+    def _fold_tile(kv_i):
+        # input-dtype GEMMs, f32 accumulation — see _fwd_kernel
+        q = q_ref[0]    # (whole, lanes)
+        do = do_ref[0]
+        k = k_ref[0]    # (bkv, lanes)
+        v = v_ref[0]
+        if fold:
+            k = k * scale  # the same products as q · scale, bit for bit
+        lse = lse_ref[0, 0]  # (8, whole): head h's on sublane h
+        if ragged_q:
+            q = jnp.where(past_end(q.shape, 0), jnp.zeros_like(q), q)
+            do = jnp.where(past_end(do.shape, 0), jnp.zeros_like(do), do)
+            lse = jnp.where(past_end(lse.shape, 1), _LSE_PAST_END, lse)
+        if ragged_kv:
+            stale = past_end(k.shape, 0, kv_i * block_kv)
+            k = jnp.where(stale, jnp.zeros_like(k), k)
+            v = jnp.where(stale, jnp.zeros_like(v), v)
+        delta = delta_ref[...]
+        pick = _head_picker(k.shape, heads)  # on (bkv, lanes) tiles
+        k_t = k.T  # (lanes, bkv): head h's head_dim rows are its own
+        head_dim = k_t.shape[0] // heads
+        dk = dv = None
+        for h in range(heads):  # unrolled: the compiler overlaps the heads
+            logits = jax.lax.dot_general(
+                pick(h, k), q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (bkv, whole) f32
+            if not fold:
+                logits = logits * scale
+            p = jnp.exp(logits - lse[h:h + 1])
+            dv = pick(h, jnp.dot(p.astype(do.dtype), do,
+                                 preferred_element_type=jnp.float32), dv)
+            dp = jax.lax.dot_general(
+                pick(h, v), do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (bkv, whole) f32
+            ds = (p * (dp - delta[h:h + 1])).astype(q.dtype)
+            dk = pick(h, jnp.dot(ds, q, preferred_element_type=jnp.float32),
+                      dk)
+            own = slice(h * head_dim, (h + 1) * head_dim)
+            dq_acc[own, :whole] += jnp.dot(
+                k_t[own], ds, preferred_element_type=jnp.float32)
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        if packed:
+            dv_acc[...] = dv
+        else:
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    per = 2 if packed else 1  # steps a K/V block
+    pl.when((t < per * n_kv) & (t % per == 0))(lambda: _fold_tile(t // per))
+    if packed:
+        @pl.when((t < 2 * n_kv) & (t % 2 == 1))
+        def _emit_dv():
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(t >= per * n_kv)
+    def _emit_dq():
+        start = pl.multiple_of((t - per * n_kv) * block_kv, block_kv)
+        rows = dq_acc[:, pl.ds(start, block_kv)].T
+        dq_ref[0] = (rows if fold else rows * scale).astype(dq_ref.dtype)
+
+
 #: the scoped VMEM ONE kernel's blocks, scratch and live temporaries must fit
 #: on the chip this repo targets — the compiler refuses the kernel otherwise
 #: ("exceeded scoped vmem limit"). Applied whatever the backend, so the CPU
@@ -720,8 +851,9 @@ _SCOPED_VMEM_BYTES = flops.vmem_bytes("TPU v5 lite")
 
 def _bwd_vmem_bytes(kernel: str, bq: int, bkv: int, dp: int, itemsize: int,
                     heads: int = 1) -> int:
-    """Scoped VMEM the backward ``kernel`` (``"dq"`` or ``"dkv"``) needs at
-    blocks (bq, bkv) with ``heads`` heads on the block's lanes: the (bq, bkv)
+    """Scoped VMEM the backward ``kernel`` (``"dq"``, ``"dkv"`` or
+    ``"dqkv"``) needs at blocks (bq, bkv) with ``heads`` heads on the block's
+    lanes. ``"dq"`` and ``"dkv"``: the (bq, bkv)
     tiles the compiler keeps alive (scores, p, dp, ds and the cast to the
     GEMM feed come to 2 × itemsize bytes an element: one f32 tile in bf16,
     two in f32, and no more for the other heads of a lane group), what
@@ -736,7 +868,27 @@ def _bwd_vmem_bytes(kernel: str, bq: int, bkv: int, dp: int, itemsize: int,
     size, within 4.5 MiB of it in bf16 at two and four heads near the limit
     and further over elsewhere (one head; float32, which the compiler packs
     tighter than linearly) — tests/test_chip_compile.py compiles what this
-    admits, at its edge too."""
+    admits, at its edge too.
+
+    ``"dqkv"`` (bq = the whole padded sequence, resident): the (bkv, bq) tiles
+    at 2 × itemsize + 1 bytes an element (dkv's, and ds's cast alive for its
+    two products; a K/V block narrower than the heads' lanes together does
+    not make them smaller), what scales with the sequence (q, o and do
+    double-buffered, the f32 dqᵀ accumulator, lse and delta rows) and what
+    scales with the K/V block (K and V double-buffered, the result blocks —
+    three of them where q, k, v are apart — a head's masked K, the transposed
+    K, dv's scratch) plus 1 MiB. Fitted the same way on the v5e compiler's
+    refusals — both dtypes, one, two and four heads a lane group, K/V blocks
+    of 512, 256 and 128, packed and apart, the sequence raised by 128 rows
+    until it refuses, 1,536 to 6,400 rows: never under a reported size, 1.8
+    to 3.8 MiB over it in bf16 and 0.2 to 9.3 in f32 (most at four heads and
+    small blocks), so it admits 2,944 rows at block 512 in bf16 (14.1 MiB at
+    the trunk's 2,560) where the compiler takes 3,584 (3,328 at four
+    heads)."""
+    if kernel == "dqkv":
+        tiles = (2 * itemsize + 1) * bq * max(bkv, heads * _LANE)
+        return (tiles + bq * (dp * (6 * itemsize + 4) + 96)
+                + bkv * dp * (11 * itemsize + 4) + (1 << 20))
     tiles = 2 * itemsize * bq * bkv
     if kernel == "dq":
         rows = (bq * (dp * ((3 + 2 * heads) * itemsize + 36) + 64)
@@ -811,14 +963,26 @@ def _default_blocks(block_q, block_kv) -> tuple:
 
 
 def _bwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
-                heads: int = 1) -> tuple:
-    """``((block_q, block_kv) of dq, (block_q, block_kv) of dkv)``, each
-    kernel's own, Mosaic-legal for this dtype and padded sequence, from what
-    the forward's choice looks at too: padded length, lanes, dtype, heads a
-    lane group. A q block is also the lane dim of the statistics' block, so
-    it is a multiple of 128 (or the whole padded sequence).
+                heads: int = 1) -> dict:
+    """The backward's launches and the (block_q, block_kv) of each,
+    ``{"dqkv": ...}`` or ``{"dq": ..., "dkv": ...}``, Mosaic-legal for this
+    dtype and padded sequence, from what the forward's choice looks at too:
+    padded length, lanes, dtype, heads a lane group. A q block is also the
+    lane dim of the statistics' block, so it is a multiple of 128 (or the
+    whole padded sequence).
 
-    Left to the kernel, each takes the side it streams as ONE chunk wherever
+    With both blocks left to the kernels, ONE launch (``dqkv``: q, o, do and
+    the whole f32 dq resident, K/V blocks along the steps) wherever the
+    ``"dqkv"`` row of :func:`_bwd_vmem_bytes` admits the whole padded
+    sequence, at the largest K/V block of 512, 256, 128 it admits: (2560, 512)
+    at the 200px trunk's 2,501 tokens in bf16, where the launch alone is
+    8.26 ms on the v5e (40 images, two lane groups of two heads) against
+    11.69 ms for dq + dkv, 8.69 ms at (2560, 256) and 9.49 at (2560, 1024)
+    with the scoped limit raised for it (PERF.md section 6, PR 34). Float32
+    at two heads a lane group there, and sequences past 2,944 tokens (bf16),
+    are not admitted and take the two launches, as explicit blocks do.
+
+    Of the two launches, each takes the side it streams as ONE chunk wherever
     :func:`_bwd_vmem_bytes` admits that — dq keeps a lane group's K and V
     resident across its q blocks, dkv keeps q and do resident across its K/V
     blocks — at the largest other block of 512, 256, 128 (or the one given):
@@ -867,43 +1031,116 @@ def _bwd_blocks(block_q, block_kv, n_pad: int, dp: int, dtype,
                 return pair
         return None
 
-    return (resident("dq", block_q) or streamed("dq"),
-            resident("dkv", block_kv) or streamed("dkv"))
+    if block_q is None and block_kv is None:
+        for bkv in map(kv_block, (512, 256, 128)):
+            if fits("dqkv", whole, bkv):
+                return {"dqkv": (whole, bkv)}
+    return {"dq": resident("dq", block_q) or streamed("dq"),
+            "dkv": resident("dkv", block_kv) or streamed("dkv")}
 
 
 def _lse_rows(lse, n_valid: int, tokens: int):
-    """The log-sum-exp as both backward kernels read it, ``(rows, groups, 8,
+    """The log-sum-exp as the backward kernels read it, ``(rows, groups, 8,
     tokens)`` f32: head ``h`` of the lane group on sublane ``h``, tokens on
     the lanes — 32 bytes a token and lane group where the lane-replicated
     columns the kernels once took were 512 a head, and the one-lane residual
     ``(rows, groups, heads, ≥ n_valid)`` as it is but for the padding. What
     lies past the sequence is padding and means nothing (the residual holds
     there whatever the forward computed for its stale q rows): dq's rows
-    there are dropped, and dkv selects what it reads there."""
+    there are dropped, and dkv and dqkv select what they read there."""
     heads = lse.shape[2]
     return jnp.pad(lse[..., :n_valid], (
         (0, 0), (0, 0), (0, _STAT_ROWS - heads), (0, tokens - n_valid)))
 
 
+def _bwd_dqkv_call(lse, q, k, v, o, do, *, offsets, groups, heads, lanes,
+                   packed, scale, n_valid, whole, bkv, interpret):
+    """The one launch of all three gradients (:func:`_bwd_dqkv_kernel`),
+    operands and results as :func:`_bwd_call` says: grid (rows, lane groups,
+    steps), q, o, do and lse as ``whole``-row blocks that stay put across a
+    lane group's steps, K and V in blocks of ``bkv``; each result block is
+    visited once — dk's and dv's K/V block by K/V block (``packed``: one after
+    the other, two steps a K/V block), then dq's row blocks."""
+    rows, n_tok = do.shape[:2]
+    q_off, k_off, v_off = offsets
+    width = groups * lanes
+    n_kv = pl.cdiv(n_tok, bkv)
+    per = 2 if packed else 1  # steps a K/V block
+
+    def kv_block(t):  # stays at the last one while dq's rows go out
+        return jnp.minimum(t // per, n_kv - 1)
+
+    res_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, whole, lanes), lambda b, g, t: (b, 0, off + g))
+    kv_spec = lambda off: pl.BlockSpec(  # noqa: E731
+        (1, bkv, lanes), lambda b, g, t: (b, kv_block(t), off + g))
+    # dqᵀ as far as dq's last row block reads; (packed) dv_j until its step;
+    # delta as rows
+    scratch = [pltpu.VMEM((lanes, max(whole, n_kv * bkv)), jnp.float32),
+               *([pltpu.VMEM((bkv, lanes), jnp.float32)] if packed else []),
+               pltpu.VMEM((_STAT_ROWS, whole), jnp.float32)]
+    if packed:
+        def out_at(b, g, t):
+            tile = t < 2 * n_kv
+            return (b, jnp.where(tile, t // 2, t - 2 * n_kv),
+                    jnp.where(tile, jnp.where(t % 2 == 0, k_off, v_off),
+                              q_off) + g)
+
+        out_specs = pl.BlockSpec((1, bkv, lanes), out_at)
+        out_shape = _sds((rows, n_tok, 3 * width), q.dtype, q)
+    else:
+        out_specs = [pl.BlockSpec((1, bkv, lanes), lambda b, g, t: (
+            b, jnp.maximum(t - n_kv, 0), g)), kv_spec(0), kv_spec(0)]
+        out_shape = [_sds((rows, n_tok, width), q.dtype, q)] * 3
+    with profiling.scope("flash_attention/dqkv"):
+        out = pl.pallas_call(
+            functools.partial(
+                _bwd_dqkv_kernel, scale=scale, n_valid=n_valid, block_kv=bkv,
+                n_kv=n_kv, heads=heads, ragged_q=n_valid != whole,
+                ragged_kv=n_valid % bkv != 0, packed=packed),
+            grid=(rows, groups, (per + 1) * n_kv),
+            in_specs=[pl.BlockSpec((1, 1, _STAT_ROWS, whole),
+                                   lambda b, g, t: (b, g, 0, 0)),
+                      res_spec(q_off), kv_spec(k_off), kv_spec(v_off),
+                      res_spec(0), res_spec(0)],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="dqkv",
+        )(lse, q, k, v, o, do)
+    return (out,) if packed else tuple(out)
+
+
 def _bwd_call(lse, q, k, v, o, do, *, offsets, groups, heads, lanes, packed,
-              scale, n_valid, dq_blocks, dkv_blocks, interpret):
-    """The two backward launches. q, k, v, the context ``o`` and the
-    cotangent ``do`` are addressed as :func:`_fwd_call` addresses q, k, v —
-    ``(rows, tokens, columns)`` arrays read in ``(1, block, lanes)`` blocks at
-    column block ``offsets[i] + g`` (``o`` and ``do`` at ``g``), the token
-    axis free to end inside the last block — and the gradients go back
-    through the same blocks: three ``(rows, tokens, groups·lanes)`` arrays,
-    or (``packed``) ONE ``(rows, tokens, 3·groups·lanes)`` array that the dq
-    launch creates and the dkv launch, taking it aliased to its own result,
-    completes. ``lse``: :func:`_lse_rows`; the dq launch writes delta in the
-    same form for the dkv launch."""
+              scale, n_valid, blocks, interpret):
+    """The backward's launches as ``blocks`` names them
+    (:func:`_bwd_blocks`): ``dqkv``, or ``dq`` then ``dkv``. q, k, v, the
+    context ``o`` and the cotangent ``do`` are addressed as :func:`_fwd_call`
+    addresses q, k, v — ``(rows, tokens, columns)`` arrays read in
+    ``(1, block, lanes)`` blocks at column block ``offsets[i] + g`` (``o`` and
+    ``do`` at ``g``), the token axis free to end inside the last block — and
+    the gradients go back through the same blocks: three ``(rows, tokens,
+    groups·lanes)`` arrays, or (``packed``) ONE ``(rows, tokens,
+    3·groups·lanes)`` array, which ``dqkv`` writes whole and which otherwise
+    the dq launch creates and the dkv launch, taking it aliased to its own
+    result, completes. ``lse``: :func:`_lse_rows`; the dq launch writes delta
+    in the same form for the dkv launch, ``dqkv`` keeps its own in VMEM."""
+    if "dqkv" in blocks:
+        whole, bkv = blocks["dqkv"]
+        return _bwd_dqkv_call(
+            lse, q, k, v, o, do, offsets=offsets, groups=groups, heads=heads,
+            lanes=lanes, packed=packed, scale=scale, n_valid=n_valid,
+            whole=whole, bkv=bkv, interpret=interpret)
     rows, n_tok = do.shape[:2]
     q_off, k_off, v_off = offsets
     width = groups * lanes
     semantics = pltpu.CompilerParams(dimension_semantics=(
         "parallel", "parallel", "parallel", "arbitrary"))
 
-    bq, bkv = dq_blocks
+    bq, bkv = blocks["dq"]
     n_q, n_kv = pl.cdiv(n_tok, bq), pl.cdiv(n_tok, bkv)
     stat_spec = pl.BlockSpec((1, 1, _STAT_ROWS, bq),
                              lambda b, g, i, j: (b, g, 0, i))
@@ -932,7 +1169,7 @@ def _bwd_call(lse, q, k, v, o, do, *, offsets, groups, heads, lanes, packed,
     # transposed grid: K/V blocks outer, q chunks innermost; packed: one
     # step more, at which the result's block moves from dk's column block to
     # dv's (see _bwd_dkv_kernel) and the q chunk stays
-    bq, bkv = dkv_blocks
+    bq, bkv = blocks["dkv"]
     n_q, n_kv = pl.cdiv(n_tok, bq), pl.cdiv(n_tok, bkv)
     last = n_q - 1
 
@@ -983,9 +1220,11 @@ def _flash_backward(operands, o, lse, g, num_heads, scale, block_q, block_kv):
     3·H·D)``. ``o``, ``g``: the context and its cotangent ``(B, N, H·D)``;
     ``lse``: the VJP forward's ``(B·H, padded tokens)``. Layout as the
     forward's (:func:`_heads_per_lane_group`), counted by
-    ``kernels.flash_bwd_layout``; ``kernels.flash_bwd_schedule`` says whether
-    the dq launch holds K and V as one chunk (``resident``) or streams them
-    (the dkv launch chooses for q and do by the same rule)."""
+    ``kernels.flash_bwd_layout``; ``kernels.flash_bwd_schedule`` says which
+    launches :func:`_bwd_blocks` chose: the one ``dqkv`` (``fused``), or dq
+    and dkv with dq holding K and V as one chunk (``resident``) or streaming
+    them (``streamed``; the dkv launch chooses for q and do by the same
+    rule)."""
     packed = len(operands) == 1
     B, N, C = o.shape
     H, D = num_heads, C // num_heads
@@ -1004,21 +1243,21 @@ def _flash_backward(operands, o, lse, g, num_heads, scale, block_q, block_kv):
         offsets = (0,) * 3
         rows, groups, heads = B * H, 1, 1
         n_pad, lanes = arrays[0].shape[1:]
-    dq_blocks, dkv_blocks = _bwd_blocks(block_q, block_kv, n_pad, lanes,
-                                        arrays[0].dtype, heads)
-    _kernels.inc("kernels.flash_bwd_schedule",
-                 key="resident" if N <= dq_blocks[1] else "streamed")
-    # a lane axis takes no partial block: whole q blocks of either kernel
+    blocks = _bwd_blocks(block_q, block_kv, n_pad, lanes, arrays[0].dtype,
+                         heads)
+    _kernels.inc("kernels.flash_bwd_schedule", key=(
+        "fused" if "dqkv" in blocks
+        else "resident" if N <= blocks["dq"][1] else "streamed"))
+    # a lane axis takes no partial block: whole q blocks of every kernel
     lse = _lse_rows(lse.reshape(rows, groups, heads, -1), N, tiling.round_up(
-        N, math.lcm(dq_blocks[0], dkv_blocks[0])))
+        N, math.lcm(*(bq for bq, _ in blocks.values()))))
     in_place_packed = bool(in_place) and packed
     spec = rows_spec(rows)
     grads = per_device(
         functools.partial(
             _bwd_call, offsets=offsets, groups=groups, heads=heads,
             lanes=lanes, packed=in_place_packed, scale=scale, n_valid=N,
-            dq_blocks=dq_blocks, dkv_blocks=dkv_blocks,
-            interpret=kernel_interpret()),
+            blocks=blocks, interpret=kernel_interpret()),
         (spec,) * 6, (spec,) * (1 if in_place_packed else 3))(lse, *arrays)
     if in_place:
         return grads

@@ -290,7 +290,7 @@ def test_training_step_never_reaches_the_kernels():
     """``train.step.make_train_step`` at ``flower200_p4``'s widths and depth,
     state from ``create_train_state`` (``init``), traced in a fresh
     interpreter: every block of both traces counts ``xla``, the step launches
-    ``fwd``, ``dq`` and ``dkv`` and neither of the two kernels, and ``ops.block_kernels`` was never imported — the
+    ``fwd`` and ``dqkv`` and neither of the two kernels, and ``ops.block_kernels`` was never imported — the
     training programs are the parent's by construction."""
     script = textwrap.dedent("""
         import re, sys
@@ -312,7 +312,7 @@ def test_training_step_never_reaches_the_kernels():
             state, (img, img, t), jax.ShapeDtypeStruct((2,), jnp.uint32),
             jax.ShapeDtypeStruct((), jnp.float32))
         launched = set(re.findall(r"name=(\\w+)", str(traced.jaxpr)))
-        assert {"fwd", "dq", "dkv"} <= launched
+        assert {"fwd", "dqkv"} <= launched
         assert not {"ln_qkv", "block_tail"} & launched
         assert _kernels.by_key("kernels.block_tokenwise") == {
             "xla": 2 * cfg["depth"]}

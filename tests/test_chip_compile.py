@@ -116,12 +116,16 @@ def _admitted(dtype, n=N):
 
 
 def _flash_bwd(dtype, blocks, packed=True, n=N):
-    """``dq`` and ``dkv`` alone, at explicit blocks or (a ``None``) at those
-    each picks from the shape: 4 heads of 64, two heads a lane group, q, k, v
-    read in place from the packed ``(rows, n, 768)`` projection (or three
-    ``(rows, n, 256)`` arrays), the context and the cotangent ``(rows, n,
-    256)``, the token axis ending inside the last block; the packed gradient
-    is begun by ``dq`` and completed by ``dkv``."""
+    """The backward alone — ``dq`` and ``dkv`` at explicit blocks; both
+    ``None``: the one ``dqkv`` launch where its VMEM row admits the shape
+    (2,501 tokens: K/V blocks of 512 in bf16, 256 in f32), else ``dq`` and
+    ``dkv`` at the blocks each picks: 4 heads of 64, two heads a lane group,
+    q, k, v read in place from the packed ``(rows, n, 768)`` projection (or
+    three ``(rows, n, 256)`` arrays), the context and the cotangent ``(rows,
+    n, 256)``, the token axis ending inside the last block; the packed
+    gradient is written whole by ``dqkv``, or begun by ``dq`` and completed
+    by ``dkv``. Expects as many custom calls as ``_bwd_blocks`` names
+    launches."""
     def build(devices):
         sds = _struct(SingleDeviceSharding(devices[0]))
         operands = ((sds((ROWS, n, 3 * C), dtype),) if packed
@@ -130,7 +134,9 @@ def _flash_bwd(dtype, blocks, packed=True, n=N):
         lse = sds((ROWS * H, -(-n // 128) * 128), jnp.float32)
         return (lambda operands, o, lse, g: fa._flash_backward(
                     operands, o, lse, g, H, D ** -0.5, *blocks),
-                (operands, ctx, lse, ctx), 2)
+                (operands, ctx, lse, ctx),
+                len(fa._bwd_blocks(*blocks, -(-n // 8) * 8, 128, dtype,
+                                   LANE_GROUP)))
     return build
 
 
@@ -142,8 +148,8 @@ def _bwd_admitted(kernel, dtype, n=N):
     asks = [(b, None) if kernel == "dq" else (None, b)
             for b in (1024, 512, 256, 128)]
     def streamed_side(ask):
-        dq, dkv = fa._bwd_blocks(*ask, n_pad, 128, dtype, LANE_GROUP)
-        return dq[1] if kernel == "dq" else dkv[0]
+        blocks = fa._bwd_blocks(*ask, n_pad, 128, dtype, LANE_GROUP)
+        return blocks["dq"][1] if kernel == "dq" else blocks["dkv"][0]
 
     return [ask for ask in asks if streamed_side(ask) >= n_pad]
 
@@ -299,8 +305,8 @@ def test_compiles_for_v5e(case, chip):
     if case == "dp4_train_step":
         text = _dp_train_step(chip)
         assert "all-reduce" in text, "no gradient all-reduce in the dp step"
-        # forward, dq and dk/dv kernels of the one layer, on local rows
-        assert text.count("tpu_custom_call") == 3
+        # forward and dqkv kernels of the one layer, on local rows
+        assert text.count("tpu_custom_call") == 2
         return
     fn, args, want_calls, *kwargs = CASES[case](chip)
     text = jax.jit(fn).lower(*args, **(kwargs[0] if kwargs else {})
@@ -328,35 +334,51 @@ def test_fwd_vmem_model_admits_only_what_compiles(bq, dtype, chip):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("kernel,block", [
     ("dq", 512), ("dq", 256), ("dq", 128),
-    ("dkv", 512), ("dkv", 256), ("dkv", 128)])
-def test_bwd_vmem_model_admits_only_what_compiles(kernel, block, dtype, chip):
+    ("dkv", 512), ("dkv", 256), ("dkv", 128),
+    ("dqkv", 512), ("dqkv", 256), ("dqkv", 128)])
+def test_bwd_vmem_model_admits_only_what_compiles(kernel, block, dtype, chip,
+                                                  monkeypatch):
     """At the model's edge: the longest sequence (to 128 tokens) at which
     ``_bwd_vmem_bytes`` still admits ``kernel`` at this block with its
-    streamed side whole — K and V for dq, q and do for dkv — must compile
-    (both launches do: the other kernel takes what it picks there). The model
-    is fitted to this compiler's refusals, so a drift shows here and not as a
-    refused kernel on the chip."""
-    ask = (block, None) if kernel == "dq" else (None, block)
-    which = kernel == "dkv"
+    streamed side whole — K and V for dq, q and do for dkv, q, o, do and the
+    whole f32 dq for dqkv — must compile (both launches do: the other kernel
+    takes what it picks there; the one ``dqkv`` launch is asked for at this
+    block, where left alone it would take the largest of 512, 256, 128 its
+    row admits). The model is fitted to this compiler's refusals, so a drift
+    shows here and not as a refused kernel on the chip."""
+    if kernel == "dqkv":
+        isz = jnp.dtype(dtype).itemsize
 
-    def whole(n):
-        return fa._bwd_blocks(*ask, n, 128, dtype, LANE_GROUP)[which] == (
-            (block, n) if kernel == "dq" else (n, block))
+        def whole(n):
+            return fa._bwd_vmem_bytes("dqkv", n, block, 128, isz,
+                                      LANE_GROUP) <= fa._SCOPED_VMEM_BYTES
+
+        ask = (None, None)
+    else:
+        ask = (block, None) if kernel == "dq" else (None, block)
+
+        def whole(n):
+            return fa._bwd_blocks(*ask, n, 128, dtype, LANE_GROUP)[kernel] == (
+                (block, n) if kernel == "dq" else (n, block))
 
     n = max(n for n in range(1024, 32768, 128) if whole(n))
     assert not whole(n + 128)
+    if kernel == "dqkv":
+        monkeypatch.setattr(fa, "_bwd_blocks",
+                            lambda *a: {"dqkv": (n, block)})
     fn, args, calls = _flash_bwd(jnp.dtype(dtype), ask, n=n)(chip)
-    assert jax.jit(fn).lower(*args).compile().as_text().count(
-        "tpu_custom_call") == calls
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert ("%dqkv" in text) == (kernel == "dqkv")
 
 
 def test_backward_reads_and_writes_where_the_gemms_do(chip):
     """What the in-place backward is for, read off the compiled depth-1 200px
-    dp train step: ``dq`` takes the qkv GEMM's ``[images, 2501, 768]`` result
-    three times, the context and the cotangent ``[images, 2501, 256]``, and
-    its first result is the projection's whole ``[images, 2501, 768]``
-    gradient, which ``dkv`` takes (aliased) and returns complete — and NO
-    instruction beside them produces a head-major or head-split array or a
+    dp train step: ``dqkv`` takes the qkv GEMM's ``[images, 2501, 768]``
+    result three times, the context and the cotangent ``[images, 2501,
+    256]``, and its ONE result is the projection's whole ``[images, 2501,
+    768]`` gradient (no second result: delta never leaves the kernel) — and
+    NO instruction beside it produces a head-major or head-split array or a
     lane-replicated ``[.., tokens, 128]`` f32 spread of lse or delta, as the
     ``copy``, ``pad``, ``slice``, ``broadcast`` and ``concatenate``
     instructions of the head-major backward did (14 % of the dp4 cell's step:
@@ -376,13 +398,12 @@ def test_backward_reads_and_writes_where_the_gemms_do(chip):
              and any(int(d) in tokens for d in dims.split(",")[:-1])
              and dims != fwd_lse}
     assert not found, found
-    dq = re.search(r"%dq(?:\.\d+)* = \((\w+)\[([\d,]+)\][^\n]*?"
-                   r"custom-call\(([^)]*)\)", text)
-    assert dq.group(2) == f"{images},{N},{3 * C}"
-    operands = [op.strip() for op in dq.group(3).split(",")]
+    dqkv = re.search(r"%dqkv(?:\.\d+)* = (\w+)\[([\d,]+)\][^\n]*?"
+                     r"custom-call\(([^)]*)\)", text)
+    assert dqkv.group(2) == f"{images},{N},{3 * C}"
+    operands = [op.strip() for op in dqkv.group(3).split(",")]
     assert operands[1] == operands[2] == operands[3]  # the projection, 3 times
-    dkv = re.search(r"%dkv(?:\.\d+)* = (\w+)\[([\d,]+)\]", text)
-    assert dkv.group(2) == f"{images},{N},{3 * C}"
+    assert len(operands) == 6  # lse rows, the projection, context, cotangent
     assert "concatenate(" not in text
 
 
@@ -401,12 +422,12 @@ def _forward_depth1(devices):
 
 @pytest.mark.parametrize("program,kernels", [
     ("forward", {"fwd", "ln_qkv", "block_tail"}),
-    ("dp4_train_step", {"fwd", "dq", "dkv"}),
+    ("dp4_train_step", {"fwd", "dqkv"}),
 ])
 def test_flash_kernels_keep_their_instruction_names(program, kernels, chip):
     """``benchmark/layer_metrics/flash_fwd_roofline.py`` finds the forward
     kernel as the ``tpu_custom_call`` instruction ``%fwd`` and the breakdown
-    lists ``fwd``, ``dq``, ``dkv`` and, for the sampler, ``ln_qkv`` and
+    lists ``fwd``, ``dqkv`` and, for the sampler, ``ln_qkv`` and
     ``block_tail``: the names come from ``pallas_call(name=...)`` and must
     stay, with or without XLA's numeric suffix, whatever scope the kernels
     are launched under. The training step holds neither token-wise kernel:

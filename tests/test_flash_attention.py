@@ -174,7 +174,7 @@ def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
     # blocks (test_backward_layout_and_schedule_are_chosen_from_the_shape)
     schedule, grids = traced(2501, grad=True)
     assert schedule == {"resident": 1} and grids["fwd"] == (1, 1, 5, 1)
-    assert set(grids) == {"fwd", "dq", "dkv"}
+    assert set(grids) == {"fwd", "dqkv"}
 
 
 @pytest.mark.parametrize("with_lse", [False, True], ids=["primal", "lse"])
@@ -272,14 +272,17 @@ def test_forward_layout_is_chosen_from_the_shape(H, D, layout, monkeypatch):
     ("float32", dict(rtol=1e-4, atol=1e-5)),
     ("bfloat16", dict(rtol=2e-2, atol=2e-2)),
 ])
-@pytest.mark.parametrize("N,blocks", [(33, (None, None)), (300, (64, 128))])
+@pytest.mark.parametrize("N,blocks", [
+    (33, (None, None)), (300, (None, None)), (33, (64, 64)), (300, (64, 128))],
+    ids=["fused-33", "fused-300", "one_chunk-33", "streamed-300"])
 def test_packed_entry_gradient_matches_dense(N, blocks, dtype, tol):
     """``flash_attention_qkv`` under ``jax.grad`` — the in-place forward with
-    its lse, the head-major backward kernels reading q, k, v out of the packed
-    residual, the three gradients stacked back into the projection's column
-    order — against autodiff through the dense einsum on the same slices, at
-    the tolerances of the (q, k, v) entry's gradient tests; and the (q, k, v)
-    entry's own gradients BITWISE the packed entry's columns."""
+    its lse, the backward (``dqkv`` where the blocks are left to it, ``dq`` +
+    ``dkv`` at explicit blocks) writing the three gradients into the
+    projection's column order — against autodiff through the dense einsum on
+    the same slices, at the tolerances of the (q, k, v) entry's gradient
+    tests; and the (q, k, v) entry's own gradients BITWISE the packed entry's
+    columns."""
     from ddim_cold_tpu.ops.flash_attention import flash_attention_qkv
 
     B, H, D = 1, 4, 32
@@ -337,21 +340,35 @@ def _backward(q, k, v, g, scale, bq, bkv, *, packed, lse_tail=None):
     return jnp.stack(grads)
 
 
+def _two_launches(monkeypatch):
+    """The shape is one the ``"dqkv"`` row of the VMEM model does not admit:
+    blocks left to the kernels give today's ``dq`` + ``dkv`` (what explicit
+    blocks force too, where a test can say what it needs with them)."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    real = fa._bwd_vmem_bytes
+    monkeypatch.setattr(fa, "_bwd_vmem_bytes", lambda kernel, *a: (
+        1 << 40 if kernel == "dqkv" else real(kernel, *a)))
+
+
 @pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
-@pytest.mark.parametrize("blocks", [(None, None), (64, 128)],
-                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("blocks", [(None, None), (512, 512), (64, 128)],
+                         ids=["fused", "resident", "streamed"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("N", [8, 257, 300])
 @pytest.mark.parametrize("D", [32, 64, 128])
 def test_in_place_backward_is_bitwise_the_head_major_backward(
         D, N, dtype, blocks, packed, monkeypatch):
-    """``dq`` and ``dkv`` reading q, k, v and the cotangent where the model
-    holds them and writing the gradients where the qkv GEMM's backward reads
-    them — several heads on the 128 lanes, the token axis ending inside the
-    last block (the interpreter fills what lies past it with NaN), the packed
-    gradient begun by one launch and completed by the other — against the
-    same shape transposed and zero-padded to head-major first, at equal
-    blocks: dq, dk and dv BITWISE."""
+    """The backward — the one ``dqkv`` launch where the blocks are left to it,
+    ``dq`` and ``dkv`` where they are given — reading q, k, v and the
+    cotangent where the model holds them and writing the gradients where the
+    qkv GEMM's backward reads them — several heads on the 128 lanes, the
+    token axis ending inside the last block (the interpreter fills what lies
+    past it with NaN), the packed gradient written block by block by the one
+    launch, or begun by ``dq`` and completed by ``dkv`` — against the same
+    shape transposed and zero-padded to head-major first, at equal blocks:
+    dq, dk and dv BITWISE (``dqkv``'s dq, where heads share the lanes, to the
+    order of an f32 sum)."""
     from ddim_cold_tpu.ops import flash_attention as fa
 
     H = 2 * 128 // D  # two lane groups
@@ -376,15 +393,25 @@ def test_in_place_backward_is_bitwise_the_head_major_backward(
     assert fa._kernels.by_key("kernels.flash_bwd_layout")["head_major"] == (
         before.get("head_major", 0) + 1)
     assert chosen[0] == chosen[1], chosen  # equal blocks, or nothing is shown
-    streamed = blocks[0] is not None and N > 128
-    assert (chosen[0][0][1] < N) == streamed  # dq: K/V chunks
-    assert (chosen[0][1][0] < N) == streamed  # dkv: q chunks
+    if blocks[0] is None:
+        assert set(chosen[0]) == {"dqkv"}
+    else:
+        streamed = blocks[0] < N and N > 128
+        assert (chosen[0]["dq"][1] < N) == streamed  # dq: K/V chunks
+        assert (chosen[0]["dkv"][0] < N) == streamed  # dkv: q chunks
     assert ours.dtype == q.dtype and np.isfinite(
         np.asarray(ours, np.float32)).all()
     for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
-        np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                      np.asarray(ref, np.float32),
-                                      err_msg=name)
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        if name == "dq" and blocks[0] is None and D < 128:
+            # dqkv multiplies a head's OWN head_dim rows of kᵀ into its rows
+            # of dqᵀ; head-major, padded to the lanes, all 128: the same
+            # products, which the CPU's dot sums in another order
+            np.testing.assert_allclose(got, ref, err_msg=name, **(
+                dict(rtol=0, atol=2e-6) if dtype == "float32"
+                else dict(rtol=2 ** -7, atol=1e-6)))
+        else:
+            np.testing.assert_array_equal(got, ref, err_msg=name)
 
 
 @pytest.mark.parametrize("dtype,tol", [
@@ -392,8 +419,8 @@ def test_in_place_backward_is_bitwise_the_head_major_backward(
     ("bfloat16", dict(rtol=2e-2, atol=2e-2)),
 ])
 @pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
-@pytest.mark.parametrize("blocks", [(None, None), (128, 128)],
-                         ids=["resident", "streamed"])
+@pytest.mark.parametrize("blocks", [(None, None), (512, 512), (128, 128)],
+                         ids=["fused", "resident", "streamed"])
 def test_backward_ragged_edge_is_finite_and_matches_dense(blocks, packed,
                                                           dtype, tol):
     """300 tokens, two heads of 64 on the lanes: every block of q, k, v, the
@@ -422,7 +449,10 @@ def test_backward_ragged_edge_is_finite_and_matches_dense(blocks, packed,
 @pytest.mark.parametrize("dtype,budget,tol", [
     ("float32", 2_800_000, dict(rtol=1e-4, atol=1e-5)),
     ("bfloat16", 2_400_000, dict(rtol=2e-2, atol=2e-2)),
-])
+    ("float32", None, dict(rtol=1e-4, atol=1e-5)),
+    ("bfloat16", None, dict(rtol=2e-2, atol=2e-2)),
+], ids=["float32-cut_budget", "bfloat16-cut_budget", "float32-fused",
+        "bfloat16-fused"])
 def test_backward_unequal_q_blocks_on_a_ragged_length_match_dense(
         dtype, budget, tol, packed, monkeypatch):
     """``dq`` and ``dkv`` choose their q blocks apart, so the tokens their
@@ -430,15 +460,23 @@ def test_backward_unequal_q_blocks_on_a_ragged_length_match_dense(
     tokens stream, ``dq`` runs at block_q 128 (it writes delta for 640
     tokens) and ``dkv`` at 256 (it reads 768). What ``dkv`` reads past the
     sequence (delta nobody wrote, the lse residual's poisoned tail) may not
-    reach dk or dv: finite, and dense's to the gradient tests' tolerances."""
+    reach dk or dv: finite, and dense's to the gradient tests' tolerances.
+    At the chip's own budget (``fused``) the same 600 tokens are one ``dqkv``
+    launch — q, do and the statistics 640 rows, two K/V blocks of 512, the
+    second ragged — held to the same."""
     from ddim_cold_tpu.ops import flash_attention as fa
 
     B, N, H, D = 1, 600, 2, 64
     scale = D ** -0.5
-    monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", budget)
-    (dq_q, _), (dkv_q, _) = fa._bwd_blocks(None, None, N, 128, dtype, 2)
-    assert (dq_q, dkv_q) == (128, 256)  # or the case below shows nothing
-    assert fa.tiling.round_up(N, dq_q) < fa.tiling.round_up(N, dkv_q)
+    if budget is None:
+        assert fa._bwd_blocks(None, None, N, 128, dtype, 2) == {
+            "dqkv": (640, 512)}
+    else:
+        monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES", budget)
+        blocks = fa._bwd_blocks(None, None, N, 128, dtype, 2)
+        (dq_q, _), (dkv_q, _) = blocks["dq"], blocks["dkv"]
+        assert (dq_q, dkv_q) == (128, 256)  # or the case below shows nothing
+        assert fa.tiling.round_up(N, dq_q) < fa.tiling.round_up(N, dkv_q)
     q, k, v, g = (x.astype(dtype) for x in (
         *_rand_qkv(53, B, N, H, D), _rand_qkv(54, B, N, H, D)[0]))
     ours = _backward(q, k, v, g, scale, None, None, packed=packed,
@@ -453,17 +491,88 @@ def test_backward_unequal_q_blocks_on_a_ragged_length_match_dense(
             np.asarray(ref, np.float32), err_msg=name, **tol)
 
 
-def test_gradient_head_of_256_at_1841_tokens_is_finite_and_matches_dense():
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+@pytest.mark.parametrize("H,D", [(4, 64), (8, 32)],
+                         ids=["two_heads_a_group", "four_heads_a_group"])
+@pytest.mark.parametrize("N", [2501, 600])
+def test_fused_backward_is_the_two_launches_to_summation_order(
+        N, H, D, packed, monkeypatch):
+    """The one ``dqkv`` launch against ``dq`` + ``dkv`` (the same shape with
+    the ``"dqkv"`` row refusing it) on the same bf16 operands, residuals and
+    cotangent: dk and dv are the same sums in the same order — BITWISE — and
+    dq differs by the order of its f32 sum alone (K/V blocks of 512 where
+    ``dq`` folds one 2,560-row chunk), so by at most two roundings of the
+    bf16 result."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    q, k, v, g = (x.astype(jnp.bfloat16) for x in (
+        *_rand_qkv(61, 1, N, H, D), _rand_qkv(62, 1, N, H, D)[0]))
+    scale = D ** -0.5
+    n_pad, whole = fa.tiling.round_up(N, 8), fa.tiling.round_up(N, 128)
+    assert fa._bwd_blocks(None, None, n_pad, 128, jnp.bfloat16,
+                          128 // D) == {"dqkv": (whole, 512)}
+    fused = np.asarray(_backward(q, k, v, g, scale, None, None,
+                                 packed=packed), np.float32)
+    with monkeypatch.context() as patch:
+        _two_launches(patch)
+        blocks = fa._bwd_blocks(None, None, n_pad, 128, jnp.bfloat16, 128 // D)
+        assert blocks == {"dq": (512, whole), "dkv": (whole, 512)}
+        two = np.asarray(_backward(q, k, v, g, scale, None, None,
+                                   packed=packed), np.float32)
+    np.testing.assert_array_equal(fused[1], two[1], err_msg="dk")
+    np.testing.assert_array_equal(fused[2], two[2], err_msg="dv")
+    np.testing.assert_allclose(fused[0], two[0], rtol=2 ** -7, atol=1e-6,
+                               err_msg="dq")
+    assert (fused[0] != two[0]).mean() < 0.2
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
+def test_a_shape_the_fused_row_refuses_runs_the_launches_explicit_blocks_ask_for(
+        packed, monkeypatch):
+    """Where the ``"dqkv"`` row does not admit the sequence, blocks left to
+    the kernels are ``dq`` + ``dkv`` at the blocks each picks, counted
+    ``resident`` — the program explicit blocks of the same sizes ask for:
+    dq, dk and dv BITWISE."""
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    N, H, D = 384, 4, 64
+    q, k, v, g = (x.astype(jnp.bfloat16) for x in (
+        *_rand_qkv(63, 1, N, H, D), _rand_qkv(64, 1, N, H, D)[0]))
+    given = np.asarray(_backward(q, k, v, g, D ** -0.5, N, N, packed=packed),
+                       np.float32)
+    _two_launches(monkeypatch)
+    assert fa._bwd_blocks(None, None, N, 128, jnp.bfloat16, 2) == {
+        "dq": (N, N), "dkv": (N, N)}
+    before = fa._kernels.by_key("kernels.flash_bwd_schedule")
+    left = np.asarray(_backward(q, k, v, g, D ** -0.5, None, None,
+                                packed=packed), np.float32)
+    now = fa._kernels.by_key("kernels.flash_bwd_schedule")
+    assert {key: n - before.get(key, 0) for key, n in now.items()
+            if n != before.get(key, 0)} == {"resident": 1}
+    np.testing.assert_array_equal(left, given)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["dq_dkv", "dqkv"])
+def test_gradient_head_of_256_at_1841_tokens_is_finite_and_matches_dense(
+        fused, monkeypatch):
     """The public entry at the chip's own budget where the two kernels' q
     blocks differ on a ragged length: float32, one head of 256, 1,841 tokens
     — ``dq`` holds K and V whole at block_q 128 (1,920 tokens of delta),
-    ``dkv`` streams q at 256 (2,048 read)."""
+    ``dkv`` streams q at 256 (2,048 read): the ``"dqkv"`` row of the VMEM
+    model does not admit the shape. With four times the budget it does
+    (``dqkv``): the one launch on 256 lanes, q, do and the statistics 1,920
+    rows, four K/V blocks of 512, the last ragged."""
     from ddim_cold_tpu.ops import flash_attention as fa
 
     B, N, H, D = 1, 1841, 1, 256
     scale = D ** -0.5
+    if fused:
+        monkeypatch.setattr(fa, "_SCOPED_VMEM_BYTES",
+                            4 * fa._SCOPED_VMEM_BYTES)
     assert fa._bwd_blocks(None, None, fa.tiling.round_up(N, 8), D,
-                          jnp.float32) == ((128, 1920), (256, 512))
+                          jnp.float32) == (
+        {"dqkv": (1920, 512)} if fused
+        else {"dq": (128, 1920), "dkv": (256, 512)})
     q, k, v = _rand_qkv(55, B, N, H, D)
     g = _rand_qkv(56, B, N, H, D)[0]
     ours = jax.grad(lambda q, k, v: jnp.sum(
@@ -478,37 +587,42 @@ def test_gradient_head_of_256_at_1841_tokens_is_finite_and_matches_dense():
 
 
 @pytest.mark.parametrize("N,H,D,layout,schedule,grids", [
-    # the 200px trunk: two heads a lane group; K/V resident for dq at block_q
-    # 512, q/do resident for dkv at block_kv 512 (packed: dkv's extra step)
-    (2501, 4, 64, "in_place", "resident",
-     {"dq": (2, 2, 5, 1), "dkv": (2, 2, 5, 1), "dkv_packed": (2, 2, 5, 2)}),
-    (2501, 12, 32, "in_place", "resident",
-     {"dq": (2, 3, 5, 1), "dkv": (2, 3, 5, 1), "dkv_packed": (2, 3, 5, 2)}),
+    # the 200px trunk: two heads a lane group; ONE launch, q, do and the
+    # statistics resident, five K/V blocks of 512 and then dq's five row
+    # blocks (packed: a step more a K/V block for dv's column block)
+    (2501, 4, 64, "in_place", "fused",
+     {"dqkv": (2, 2, 10), "dqkv_packed": (2, 2, 15)}),
+    (2501, 12, 32, "in_place", "fused",
+     {"dqkv": (2, 3, 10), "dqkv_packed": (2, 3, 15)}),
     # one local head of 64 under Ulysses, head sizes 80 and 256: head-major,
-    # one head a group, the same two launches
-    (2501, 1, 64, "head_major", "resident",
-     {"dq": (2, 1, 5, 1), "dkv": (2, 1, 5, 1), "dkv_packed": (2, 1, 5, 1)}),
-    (2501, 4, 80, "head_major", "resident",
-     {"dq": (8, 1, 5, 1), "dkv": (8, 1, 5, 1), "dkv_packed": (8, 1, 5, 1)}),
-    (2501, 2, 256, "head_major", "resident",
-     {"dq": (4, 1, 10, 1), "dkv": (4, 1, 10, 1), "dkv_packed": (4, 1, 10, 1)}),
-    # dq still holds K and V whole at block_q 128; dkv streams already
+    # one head a group, the same launch (three results either way)
+    (2501, 1, 64, "head_major", "fused",
+     {"dqkv": (2, 1, 10), "dqkv_packed": (2, 1, 10)}),
+    (2501, 4, 80, "head_major", "fused",
+     {"dqkv": (8, 1, 10), "dqkv_packed": (8, 1, 10)}),
+    (2501, 2, 256, "head_major", "fused",
+     {"dqkv": (4, 1, 20), "dqkv_packed": (4, 1, 20)}),
+    # too long for the one launch: dq still holds K and V whole at block_q
+    # 128; dkv streams already
     (8192, 4, 64, "in_place", "resident",
-     {"dq": (2, 2, 64, 1), "dkv": (2, 2, 16, 32),
-      "dkv_packed": (2, 2, 16, 33)}),
+     {"dq": (2, 2, 64, 1), "dq_packed": (2, 2, 64, 1),
+      "dkv": (2, 2, 16, 32), "dkv_packed": (2, 2, 16, 33)}),
     # too long to be resident: both stream at (256, 512)
     (32768, 4, 64, "in_place", "streamed",
-     {"dq": (2, 2, 128, 64), "dkv": (2, 2, 64, 128),
-      "dkv_packed": (2, 2, 64, 129)}),
+     {"dq": (2, 2, 128, 64), "dq_packed": (2, 2, 128, 64),
+      "dkv": (2, 2, 64, 128), "dkv_packed": (2, 2, 64, 129)}),
 ])
 def test_backward_layout_and_schedule_are_chosen_from_the_shape(
         N, H, D, layout, schedule, grids, monkeypatch):
     """The backward's addressing follows the forward's rule (``128 % D == 0``
     and ``H·D % 128 == 0``: in place, grid (images, lane groups, outer
     blocks, inner chunks); anything else head-major, grid (images·heads, 1,
-    ...)) and its blocks the shape, each kernel's own. Asked of the trace
-    alone — nothing runs — for both entries; ``kernels.flash_bwd_layout`` and
-    ``kernels.flash_bwd_schedule`` (dq's K/V chunking) say which."""
+    ...)) and its blocks the shape: ONE launch (``dqkv``, ``fused``, grid
+    (rows, lane groups, steps)) where the sequence is resident for it, else
+    ``dq`` and ``dkv``, each kernel's own blocks (``resident`` or
+    ``streamed`` by dq's K/V chunking). Asked of the trace alone — nothing
+    runs — for both entries; ``kernels.flash_bwd_layout`` and
+    ``kernels.flash_bwd_schedule`` say which."""
     from ddim_cold_tpu.ops import flash_attention as fa
 
     B = 2
@@ -539,24 +653,37 @@ def test_backward_layout_and_schedule_are_chosen_from_the_shape(
             qkv, H, scale).astype(jnp.float32).sum()), packed
             ).shape == packed.shape
     assert counted(before) == {names[0]: {layout: 2}, names[1]: {schedule: 2}}
-    assert (apart["dq"], apart["dkv"]) == (grids["dq"], grids["dkv"])
-    assert (seen["dq"], seen["dkv"]) == (grids["dq"], grids["dkv_packed"])
+    assert set(seen) - {"fwd"} == {name.removesuffix("_packed")
+                                   for name in grids}
+    for name in set(seen) - {"fwd"}:
+        assert (apart[name], seen[name]) == (grids[name],
+                                             grids[name + "_packed"])
 
     # the choice itself: explicit blocks are honoured by both kernels (a q
     # block is a multiple of 128, the statistics' lane tile); one side given
     # leaves the other to the kernel
     blocks = lambda *a: fa._bwd_blocks(*a, 2504, 128, jnp.bfloat16, 2)  # noqa: E731
-    assert blocks(None, None) == ((512, 2560), (2560, 512))
-    assert blocks(256, 512) == ((256, 512), (256, 512))
-    assert blocks(300, 500) == ((384, 512), (384, 512))
-    assert blocks(128, None) == ((128, 2560), (128, 512))
-    assert blocks(None, 256) == ((256, 256), (2560, 256))
-    assert blocks(None, 1024) == ((256, 1024), (256, 1024))  # no room whole
-    assert fa._bwd_blocks(None, None, 2504, 128, jnp.float32, 2) == (
+    two = lambda dq, dkv: {"dq": dq, "dkv": dkv}  # noqa: E731
+    assert blocks(None, None) == {"dqkv": (2560, 512)}
+    assert blocks(256, 512) == two((256, 512), (256, 512))
+    assert blocks(300, 500) == two((384, 512), (384, 512))
+    assert blocks(128, None) == two((128, 2560), (128, 512))
+    assert blocks(None, 256) == two((256, 256), (2560, 256))
+    assert blocks(None, 1024) == two((256, 1024), (256, 1024))  # no room whole
+    # float32 with two heads on the lanes: the "dqkv" row refuses 2,560 rows
+    # at every block; one head a lane group of 256 lanes fits at block 256
+    assert fa._bwd_blocks(None, None, 2504, 128, jnp.float32, 2) == two(
         (256, 2560), (2560, 256))
-    for kernel, pair in zip(("dq", "dkv"), blocks(None, None)):
-        assert fa._bwd_vmem_bytes(kernel, *pair, 128, 2,
-                                  2) <= fa._SCOPED_VMEM_BYTES
+    assert fa._bwd_blocks(None, None, 2504, 256, jnp.bfloat16) == {
+        "dqkv": (2560, 256)}
+    with monkeypatch.context() as patch:  # were the row to refuse the trunk
+        _two_launches(patch)
+        assert blocks(None, None) == two((512, 2560), (2560, 512))
+        for kernel, pair in blocks(None, None).items():
+            assert fa._bwd_vmem_bytes(kernel, *pair, 128, 2,
+                                      2) <= fa._SCOPED_VMEM_BYTES
+    assert fa._bwd_vmem_bytes("dqkv", 2560, 512, 128, 2,
+                              2) <= fa._SCOPED_VMEM_BYTES
 
 
 @pytest.mark.parametrize("config,use_flash,traces", [
@@ -567,8 +694,8 @@ def test_trainer_traces_the_backward_in_place_and_resident(config, use_flash,
                                                            traces):
     """The trainer's own step (``train.step.make_train_step``), traced at the
     benchmark's widths and depth, nothing run: each flash layer's backward is
-    counted once, ``in_place`` and ``resident``, and a model with ``use_flash``
-    off counts nothing."""
+    counted once, ``in_place`` and ``fused`` (one ``dqkv`` launch a layer),
+    and a model with ``use_flash`` off counts nothing."""
     from ddim_cold_tpu.models import MODEL_CONFIGS
     from ddim_cold_tpu.ops import flash_attention as fa
     from ddim_cold_tpu.train.step import create_train_state, make_train_step
@@ -587,7 +714,7 @@ def test_trainer_traces_the_backward_in_place_and_resident(config, use_flash,
     jax.eval_shape(make_train_step(model), state, (img, img, t),
                    jax.ShapeDtypeStruct((2,), jnp.uint32),
                    jax.ShapeDtypeStruct((), jnp.float32))
-    for name, was, key in zip(names, before, ("in_place", "resident")):
+    for name, was, key in zip(names, before, ("in_place", "fused")):
         now = fa._kernels.by_key(name)
         assert {k: n - was.get(k, 0) for k, n in now.items()
                 if n != was.get(k, 0)} == ({key: traces} if traces else {})
@@ -643,9 +770,12 @@ def test_flash_gradient_blocked_matches_dense(N, bq, bkv):
                                    rtol=1e-4, atol=1e-5, err_msg=f"d{name}")
 
 
-def test_flash_gradient_north_star_shape_matches_dense():
+@pytest.mark.parametrize("blocks", [(None, None), (256, 2560)],
+                         ids=["fused", "dq_dkv"])
+def test_flash_gradient_north_star_shape_matches_dense(blocks):
     """The Pallas BACKWARD at the exact north-star shape — N=2501 tokens
-    (200px, patch 4, +1 time token), H=4, D=64, production default blocks —
+    (200px, patch 4, +1 time token), H=4, D=64, production default blocks
+    (the one ``dqkv`` launch) and the two launches explicit blocks ask for —
     against autodiff through the dense einsum (VERDICT r4 item 9: forward
     was exercised at this length, the 200px training stage runs the
     backward, and Mosaic has rejected this kernel family on hardware once;
@@ -655,7 +785,7 @@ def test_flash_gradient_north_star_shape_matches_dense():
     scale = 64**-0.5
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, scale) ** 2)
+        return jnp.sum(flash_attention(q, k, v, scale, *blocks) ** 2)
 
     def loss_dense(q, k, v):
         return jnp.sum(_dense_attention_f32(q, k, v, scale)[1] ** 2)
@@ -667,9 +797,13 @@ def test_flash_gradient_north_star_shape_matches_dense():
                                    rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
 
 
-def test_flash_bf16_gradient_north_star_shape_matches_dense():
+@pytest.mark.parametrize("blocks", [(None, None), "NS_FLASH_BLOCKS"],
+                         ids=["fused", "dq_dkv"])
+def test_flash_bf16_gradient_north_star_shape_matches_dense(blocks):
     """The Pallas BACKWARD on bf16 inputs at the north-star shape (N=2501,
-    H=4, D=64, tuned NS_FLASH_BLOCKS) — against autodiff through the dense
+    H=4, D=64; the blocks left to the kernels, which is the one ``dqkv``
+    launch the dp4 cell runs, and the tuned NS_FLASH_BLOCKS, which ask for
+    ``dq`` + ``dkv``) — against autodiff through the dense
     f32 oracle on the same bf16 inputs. The 200px training stage runs this
     exact backward in bf16, and the bf16-gemm-v2 kernel routes its backward
     GEMMs through the input dtype — a path the f32 gradient tests above
@@ -678,12 +812,14 @@ def test_flash_bf16_gradient_north_star_shape_matches_dense():
     operands, not bf16-vs-f32 rounding."""
     from ddim_cold_tpu.ops.flash_attention import NS_FLASH_BLOCKS
 
+    if blocks == "NS_FLASH_BLOCKS":
+        blocks = NS_FLASH_BLOCKS
     q32, k32, v32 = _rand_qkv(19, 1, 2501, 4, 64)
     q, k, v = (x.astype(jnp.bfloat16) for x in (q32, k32, v32))
     scale = 64**-0.5
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, scale, *NS_FLASH_BLOCKS)
+        out = flash_attention(q, k, v, scale, *blocks)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     def loss_dense(q, k, v):
@@ -870,8 +1006,17 @@ def test_block_specs_satisfy_tpu_tile_rule(monkeypatch):
         assert np.isfinite(np.asarray(out)).all()
         g = jax.grad(lambda q: flash_attention(q, k, v, scale).sum())(q)
         assert np.isfinite(np.asarray(g)).all()
-    # per shape: primal fwd + vjp fwd + dq + dkv
-    assert calls.count("_fwd_kernel") == 6 and len(calls) == 12, calls
+    # per shape: primal fwd + vjp fwd + the backward: dqkv, and at 2,501
+    # tokens in float32, which its VMEM row refuses, dq + dkv
+    assert calls.count("_fwd_kernel") == 6 and len(calls) == 10, calls
+    assert calls.count("_bwd_dqkv_kernel") == 2, calls
+    assert calls[-2:] == ["_bwd_dq_kernel", "_bwd_dkv_kernel"]
+    # the trunk's own dtype at 2,501 tokens: vjp fwd + dqkv at (2560, 512)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    g = jax.grad(lambda q: flash_attention(q, k, v, scale).astype(
+        jnp.float32).sum())(q)
+    assert np.isfinite(np.asarray(g, np.float32)).all()
+    assert calls[10:] == ["_fwd_kernel", "_bwd_dqkv_kernel"]
 
 
 def test_odd_requested_blocks_legalized_at_200px(monkeypatch):
@@ -982,10 +1127,17 @@ def test_kernel_gemms_run_in_input_dtype_with_f32_accumulation(dtype):
         lambda q, k, v: jnp.sum(
             flash_attention(q, k, v, scale).astype(jnp.float32) ** 2),
         argnums=(0, 1, 2)))(q, k, v)
-    # fwd rerun (2) + dq kernel (logits, dp, ds·k) + dkv kernel
-    # (logits, pᵀ·do, dp, dsᵀ·q) = 9; ≥ 7 tolerates residual-sharing tweaks
+    # fwd rerun (2) + the dqkv kernel's five a head (scores, pᵀ·do, dp,
+    # dsᵀ·q, ds·k) = 7; at explicit blocks dq (scores, dp, ds·k) + dkv
+    # (scores, pᵀ·do, dp, dsᵀ·q) make it 9
     bwd_dots = _kernel_dot_eqns(bwd.jaxpr)
-    assert len(bwd_dots) >= 7, bwd_dots
+    assert len(bwd_dots) == 7, bwd_dots
+    two = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, scale, 64, 64).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    assert len(_kernel_dot_eqns(two.jaxpr)) == 9
+    bwd_dots += _kernel_dot_eqns(two.jaxpr)
 
     for eqn in fwd_dots + bwd_dots:
         pref = eqn.params.get("preferred_element_type")

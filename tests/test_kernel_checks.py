@@ -99,6 +99,10 @@ def test_p001_whole_dim_span_is_legal():
     ("dkv", (2501, 128), (512, 128), (5,), []),
     ("dkv", (2501, 128), (2560, 128), (1,), []),
     ("dkv", (2501, 128), (100, 128), (26,), ["GRAFT-P001"]),
+    # ... and the one launch of both (K/V blocks; q, o, do resident)
+    ("dqkv", (2501, 128), (512, 128), (5,), []),
+    ("dqkv", (2501, 128), (2560, 128), (1,), []),
+    ("dqkv", (2501, 128), (100, 128), (26,), ["GRAFT-P001"]),
     # the two token-wise kernels: row-wise, so the tail stays in its rows
     ("ln_qkv", (2501, 256), (1264, 256), (2,), []),
     ("block_tail", (2501, 256), (1264, 256), (2,), []),
@@ -118,8 +122,8 @@ def test_p001_partial_final_block_only_where_the_kernel_masks_it(
 
     closed = jax.make_jaxpr(f)(jax.ShapeDtypeStruct(shape, jnp.float32))
     assert _rules_of(_check(closed)) == rules
-    assert kernel_checks.RAGGED_SUBLANE_OK == {"fwd", "dq", "dkv", "ln_qkv",
-                                               "block_tail"}
+    assert kernel_checks.RAGGED_SUBLANE_OK == {"fwd", "dq", "dkv", "dqkv",
+                                               "ln_qkv", "block_tail"}
 
 
 # --------------------------------------------------------------- P002
