@@ -37,6 +37,7 @@ import numpy as np
 from PIL import Image
 
 from ddim_cold_tpu.data import native, resize
+from ddim_cold_tpu.obs import spans
 
 _IMG_EXTS = {".jpg", ".jpeg", ".png", ".bmp", ".webp"}
 
@@ -101,6 +102,17 @@ class _BaseCache:
                 if not all(pool.map(ok, self.imgList[lo:lo + 1024])):
                     return False
         return True
+
+    def _open(self, cache_images: Optional[bool]) -> None:
+        """What a constructor does on disk — the file listing, the header
+        probe (which loads, and on a checkout's first use builds, the native
+        decoder) and the cache's reservation — as one ``data/dataset/open``
+        layer span (``images``, ``cached``) an object."""
+        with spans.layer("data/dataset/open") as span:
+            self.imgList = _list_images(self.root,
+                                        hint_size=int(self.img_size[0]))
+            self._init_cache(cache_images, len(self.imgList), self.img_size)
+            span.set(images=len(self.imgList), cached=self.cache_images)
 
     def _init_cache(self, cache_images: Optional[bool], n_items: int,
                     img_size: Sequence[int]) -> None:
@@ -317,8 +329,7 @@ class DiffusionDataset(_BaseCache):
         self.seed = seed
         self.use_native = use_native
         self.epoch = 0
-        self.imgList = _list_images(root, hint_size=int(self.img_size[0]))
-        self._init_cache(cache_images, len(self.imgList), self.img_size)
+        self._open(cache_images)
 
     def set_epoch(self, epoch: int) -> None:
         self.epoch = epoch
@@ -407,8 +418,7 @@ class ColdDownSampleDataset(_BaseCache):
         self.seed = seed
         self.use_native = use_native
         self.epoch = 0
-        self.imgList = _list_images(root, hint_size=int(self.img_size[0]))
-        self._init_cache(cache_images, len(self.imgList), self.img_size)
+        self._open(cache_images)
 
     def set_epoch(self, epoch: int) -> None:
         self.epoch = epoch

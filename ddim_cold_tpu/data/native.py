@@ -22,6 +22,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ddim_cold_tpu.obs import spans
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libddim_data.so")
@@ -35,6 +37,8 @@ _lib_failed = False
 
 
 def _build() -> bool:
+    """Compile ``native/ddim_data.cc`` (≈ 10 s), as a ``data/native/build``
+    layer span (``ok``): it exists only in a process that ran the build."""
     src = os.path.join(_NATIVE_DIR, "ddim_data.cc")
     if not os.path.isfile(src):
         return False
@@ -42,45 +46,42 @@ def _build() -> bool:
     # processes (multi-host on a shared fs, pytest-xdist) must never dlopen a
     # half-written .so.
     tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-fPIC", "-std=c++17", "-ffp-contract=off", "-shared",
-             src, "-o", tmp, "-ljpeg", "-lpng", "-lpthread"],
-            check=True, capture_output=True, timeout=120,
-        )
-        os.replace(tmp, _SO_PATH)
-        return True
-    except (subprocess.SubprocessError, OSError):  # compile failed / no g++
+    with spans.layer("data/native/build") as span:
         try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return False
+            subprocess.run(
+                ["g++", "-O3", "-fPIC", "-std=c++17", "-ffp-contract=off", "-shared",
+                 src, "-o", tmp, "-ljpeg", "-lpng", "-lpthread"],
+                check=True, capture_output=True, timeout=120,
+            )
+            os.replace(tmp, _SO_PATH)
+            ok = True
+        except (subprocess.SubprocessError, OSError):  # compile failed / no g++
+            try:
+                os.unlink(tmp)
+            except OSError:
+                pass
+            ok = False
+        span.set(ok=ok)
+    return ok
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
-        return _lib
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        if os.environ.get("DDIM_COLD_NO_NATIVE"):
-            _lib_failed = True
-            return None
+def _open_library() -> Optional[ctypes.CDLL]:
+    """dlopen the decoder, building it first when it is missing or older than
+    its source, as one ``data/native/load`` layer span (``built``) with the
+    build's span inside it; child of whatever span is open on the thread —
+    the dataset's ``data/dataset/open``, or a process's first decode."""
+    with spans.layer("data/native/load") as span:
         src = os.path.join(_NATIVE_DIR, "ddim_data.cc")
         stale = (os.path.isfile(_SO_PATH) and os.path.isfile(src)
                  and os.path.getmtime(src) > os.path.getmtime(_SO_PATH))
-        if (not os.path.isfile(_SO_PATH) or stale) and not _build():
-            # a stale-but-present .so still loads (new entry points are
-            # hasattr-guarded); only a missing library is fatal here
-            if not os.path.isfile(_SO_PATH):
-                _lib_failed = True
-                return None
+        span.set(built=(not os.path.isfile(_SO_PATH) or stale) and _build())
+        # a stale-but-present .so still loads (new entry points are
+        # hasattr-guarded); only a missing library is fatal here
+        if not os.path.isfile(_SO_PATH):
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError:
-            _lib_failed = True
             return None
         f32p = ctypes.POINTER(ctypes.c_float)
         i32p = ctypes.POINTER(ctypes.c_int32)
@@ -114,7 +115,22 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.ddim_decode_batch.restype = ctypes.c_int
         except AttributeError:  # stale .so from before this entry point
             pass
-        _lib = lib
+        return lib
+
+
+def _load() -> Optional[ctypes.CDLL]:
+    """The library, opened once a process (``_open_library``: the
+    ``data/native/load`` span), or ``None`` when it is switched off or
+    cannot be built or opened."""
+    global _lib, _lib_failed
+    if _lib is not None or _lib_failed:
+        return _lib
+    with _lock:
+        if _lib is not None or _lib_failed:
+            return _lib
+        if not os.environ.get("DDIM_COLD_NO_NATIVE"):
+            _lib = _open_library()
+        _lib_failed = _lib is None
         return _lib
 
 

@@ -25,6 +25,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ddim_cold_tpu.obs import spans
+
 
 def initialize_distributed(coordinator: Optional[str] = None, num_processes: Optional[int] = None,
                            process_id: Optional[int] = None) -> None:
@@ -124,21 +126,30 @@ def shard_train_state(state, mesh: Mesh, specs=None):
     inherit the param shardings — and restored/initial values are then placed
     leaf-by-leaf onto that layout. Keeps Adam's mu/nu from silently living
     replicated next to tensor-sharded params (2× HBM + a reshard per step).
+
+    Recorded as one ``parallel/place_state`` layer span (``bytes``, ``leaves``
+    of everything placed, ``devices`` of the mesh). The span is the host's
+    side of the placement: ``device_put`` returns before a transfer is done,
+    and the one wait inside it is the host copy of each moment
+    (``np.asarray``) that the placement always made.
     """
-    params = shard_params(state.params, mesh, specs)
-    layout = state.tx.init(params)
-    mesh_devices = set(mesh.devices.flat)
+    with spans.layer("parallel/place_state", devices=mesh.devices.size) as span:
+        params = shard_params(state.params, mesh, specs)
+        layout = state.tx.init(params)
+        mesh_devices = set(mesh.devices.flat)
 
-    def place(value, ref):
-        sharding = ref.sharding
-        if getattr(sharding, "device_set", None) != mesh_devices:
-            sharding = replicated(mesh)  # scalars (e.g. adam count) from init
-        return jax.device_put(np.asarray(value), sharding)
+        def place(value, ref):
+            sharding = ref.sharding
+            if getattr(sharding, "device_set", None) != mesh_devices:
+                sharding = replicated(mesh)  # scalars (e.g. adam count) from init
+            return jax.device_put(np.asarray(value), sharding)
 
-    opt_state = jax.tree.map(place, state.opt_state, layout)
-    extra = {}
-    if getattr(state, "ema_params", None) is not None:
-        # the EMA shadow mirrors the params' tree and must mirror their
-        # sharding too (elementwise update: no resharding in the step)
-        extra["ema_params"] = shard_params(state.ema_params, mesh, specs)
+        opt_state = jax.tree.map(place, state.opt_state, layout)
+        extra = {}
+        if getattr(state, "ema_params", None) is not None:
+            # the EMA shadow mirrors the params' tree and must mirror their
+            # sharding too (elementwise update: no resharding in the step)
+            extra["ema_params"] = shard_params(state.ema_params, mesh, specs)
+        placed = jax.tree.leaves((params, opt_state, extra))
+        span.set(bytes=sum(x.nbytes for x in placed), leaves=len(placed))
     return state.replace(params=params, opt_state=opt_state, **extra)

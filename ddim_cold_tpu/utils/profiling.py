@@ -10,9 +10,24 @@ ViT.py:222-235). Here the equivalents are structural:
   ``obs/spans.py`` opens is also written into it as a ``ddim/<name>`` TraceAnnotation, on the
   host plane of the same ``.xplane.pb`` as the device's ops.
 * the compile listener — every ``jax.monitoring`` compile and cache duration
-  event becomes a closed ``jax/<event>`` span under the span open on its
-  thread, and a backend compile (or cache load) counts into
-  ``runtime.compiles``.
+  event becomes a closed ``jax/<event>`` span (``event``, ``fun``) under the
+  span open on its thread, and a backend compile (or cache load) counts into
+  ``runtime.compiles``. These events are the record of set-up — with
+  ``parallel/place_state`` (``parallel/mesh.py``), ``data/dataset/open``
+  (``data/datasets.py``), ``data/native/load|build`` (``data/native.py``)
+  and the loader's and samplers' spans, all on the one recorder — and the
+  readers under ``benchmark/layer_metrics/`` put every second of ``setup_s``
+  down to a stage from them (PERF.md section 3): ``jaxpr_trace_duration``
+  and ``jaxpr_to_mlir_module_duration`` are ``setup_trace_lower_s``;
+  ``cache_retrieval_time_sec`` (a persistent-cache hit: read, deserialize,
+  load; inside the backend event) is ``setup_cache_load_s``; all of them in
+  union are ``setup_compile_s``; ``backend_compile_duration`` inside a window
+  is ``compiles_in_window`` and, against ``runtime.compiles``, the check that
+  the ring has dropped none; ``compile_time_saved_sec`` has no length
+  (``saved_s``). What no span or event covers is ``setup_start_s``,
+  ``setup_first_run_wait_s`` or ``setup_unattributed_s``. The listener adds
+  no wait: it is called on the compiling thread, after the event, with the
+  event's own duration.
 * ``enable_nan_checks()`` — ``jax_debug_nans`` (the SPMD replacement for the
   reference's commented TORCH_DISTRIBUTED_DEBUG, with actually-useful
   semantics: fail at the op that produced the NaN).
