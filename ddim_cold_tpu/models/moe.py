@@ -49,7 +49,7 @@ from flax import linen as nn
 
 from ddim_cold_tpu.models.init import trunc_normal
 from ddim_cold_tpu.ops import tiling
-from ddim_cold_tpu.ops.grouped_matmul import grouped_matmul
+from ddim_cold_tpu.ops.grouped_matmul import grouped_mlp
 from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
@@ -184,10 +184,11 @@ class HeldExpertsMlp(nn.Module):
     with the assignments to experts held elsewhere last, in a null group that
     has no weights and costs no product: ``ops.grouped_matmul`` skips the row
     tiles past the last held group and writes them as zeros. The sorted rows
-    are gathered once, go through ``grouped_matmul`` for gate and up, SiLU ⊙,
-    and again for down, and each row adds up its own ``top_k`` results by
-    position (a gather by the inverse permutation, weighted and summed in
-    float32: no scatter).
+    are gathered once and go through ``ops.grouped_matmul.grouped_mlp``: two
+    launches on the TPU, one for gate, up and ``SiLU(g) ⊙ u`` (both products
+    and the SiLU in float32, one rounding), one for down. Each row then adds
+    up its own ``top_k`` results by position (a gather by the inverse
+    permutation, weighted and summed in float32: no scatter).
 
     Parameters: ``router (hidden, num_routed)``; ``gate_proj``,
     ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
@@ -258,10 +259,9 @@ class HeldExpertsMlp(nn.Module):
         source = jnp.pad(order // k, (0, M - T * k))
         rows = y[source]
 
-        gate = grouped_matmul(rows, param("gate_proj", (G, D, F)), group_sizes)
-        up = grouped_matmul(rows, param("up_proj", (G, D, F)), group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up,
-                             param("down_proj", (G, F, D)), group_sizes)
+        out = grouped_mlp(rows, param("gate_proj", (G, D, F)),
+                          param("up_proj", (G, D, F)),
+                          param("down_proj", (G, F, D)), group_sizes)
 
         where = jnp.argsort(order).reshape(T, k)  # a's place among the sorted
         weight = jnp.where(held, weight, 0.0)

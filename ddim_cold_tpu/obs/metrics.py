@@ -121,7 +121,11 @@ METRICS = (
      "glm stack layers traced by indexer kind (key: full|shared)"),
     # -- kernels (ops/grouped_matmul.py, counted once a trace) ------------
     ("kernels.moe_gmm_schedule", "counter",
-     "grouped expert matmul traces by path (key: kernel|xla)"),
+     "grouped expert matmul PRODUCTS traced, by path (key: kernel|xla): the "
+     "gate-up launch counts its two"),
+    ("kernels.moe_gate_up_schedule", "counter",
+     "expert MLP first-half traces by path (key: fused, the one gate-up "
+     "launch; xla, two ragged_dots and the product)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

@@ -1,12 +1,20 @@
-"""The grouped matrix product of an expert layer: rows sorted by expert, each
+"""The grouped matrix products of an expert layer: rows sorted by expert, each
 group of rows multiplied by its own expert's weights,
 
     out[r] = rows[r] @ w[g]      for  starts[g] ≤ r < starts[g] + group_sizes[g]
     out[r] = 0                   for  r ≥ Σ group_sizes
 
-which is ``jax.lax.ragged_dot``'s contract. One function,
-:func:`grouped_matmul`; the backend decides what runs. On the TPU a Pallas
-kernel (``pallas_call(name="moe_gmm")``, ``%moe_gmm`` in a device trace);
+which is ``jax.lax.ragged_dot``'s contract (:func:`grouped_matmul`), and the
+first half of the experts' gated MLP as ONE such pass over the rows
+(:func:`grouped_gate_up`),
+
+    h[r] = SiLU(rows[r] @ w_gate[g]) * (rows[r] @ w_up[g])     (0 past the groups)
+
+both products accumulated, and the SiLU and the product between them taken, in
+float32: one rounding, on the store. :func:`grouped_mlp` is the whole MLP,
+``h`` then ``h @ w_down[g]``. The backend decides what runs. On the TPU a
+Pallas kernel (``pallas_call(name="moe_gmm")``, ``%moe_gmm`` in a device
+trace: one launch a product, ONE for gate and up, so two an expert layer);
 anywhere else, and as the tests' oracle, ``ragged_dot`` itself (plain JAX,
 differentiable).
 
@@ -17,22 +25,32 @@ share a row, in row order, then one for every row tile past the last group.
 Grid ``(column tiles, work items)``, items innermost:
 
 * an item multiplies its ``(tile_m, K)`` row tile by its group's ``(K,
-  tile_n)`` weight tile — the whole contraction, float32 accumulation — and
-  stores only the rows that belong to the group; a tile two groups share is
-  visited once by each, the rows of the other kept (the output block stays in
-  VMEM between consecutive items on the same tile, and its first visitor
+  tile_n)`` weight tile — the whole contraction, float32 accumulation; in the
+  gate-up launch by the gate tile AND the up tile while the rows sit in VMEM —
+  and stores only the rows that belong to the group; a tile two groups share
+  is visited once by each, the rows of the other kept (the output block stays
+  in VMEM between consecutive items on the same tile, and its first visitor
   starts it from zeros);
 * consecutive items of one group address the same weight block, so an
   expert's weight tile is fetched once a column tile however many row tiles
   the expert has;
 * tiles past the last group are written as zeros without a product, and the
   list's unused tail (it is as long as the worst case, row tiles + groups)
-  re-addresses the last item's blocks and does nothing.
+  re-addresses the last item's blocks and does nothing. Inside
+  :func:`grouped_mlp`, where ``h`` has one reader that never looks past the
+  last group's row tile, the gate-up launch leaves those tiles unwritten: its
+  tail items address the last real item's output block as well.
 
-Tiles come from the shape (:func:`_tiles`): 128 rows (a group's ragged edge
-costs at most one more tile of that height), all of K, and the widest column
-tile whose double-buffered weight block fits three eighths of the scoped
-VMEM. No tile argument in any config.
+Tiles come from the shape, no tile argument in any config: 128 rows (a
+group's ragged edge costs at most one more tile of that height), all of K,
+and for one product (:func:`_tiles`) the widest column tile whose
+double-buffered weight block fits three eighths of the VMEM a kernel gets
+unasked. The gate-up launch (:func:`_gate_up_tiles`) takes the widest column
+tile whose whole working set fits half of the chip's VMEM and asks for that
+much (``vmem_limit_bytes``; at an equal tile the raised limit costs nothing,
+PERF.md section 6, PR 38): with two weight tiles a step, a wider tile means
+fewer walks of the work list and fewer reads of the rows — at
+Laguna-S-2.1's shape the whole width, the rows streamed once.
 
 The kernel has no backward yet (ROADMAP Reach) and says so when asked.
 """
@@ -51,10 +69,14 @@ from ddim_cold_tpu.ops import tiling
 from ddim_cold_tpu.ops.flash_attention import (
     _SCOPED_VMEM_BYTES, kernel_interpret)
 
-#: which path each trace of the product took (``kernels.moe_gmm_schedule``)
+#: which path each traced product took (``kernels.moe_gmm_schedule``) and
+#: each first half (``kernels.moe_gate_up_schedule``)
 _kernels = metrics.scope("kernels")
 
 _TILE_M = 128
+#: the most scoped VMEM a launch asks for (``vmem_limit_bytes``): half of the
+#: 128 MiB a v5e core has; ``_SCOPED_VMEM_BYTES`` is what it gets unasked
+_VMEM_CEILING_BYTES = 64 << 20
 
 
 def grouped_matmul_xla(rows, w, group_sizes):
@@ -63,6 +85,15 @@ def grouped_matmul_xla(rows, w, group_sizes):
     return jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
                               preferred_element_type=jnp.float32
                               ).astype(rows.dtype)
+
+
+def grouped_gate_up_xla(rows, w_gate, w_up, group_sizes):
+    """Two ``ragged_dot``s and ``SiLU(g) * u`` between them in float32, one
+    rounding to ``rows``' dtype. ``w_gate``, ``w_up``: ``(G, K, F)``."""
+    g, u = (jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
+                               preferred_element_type=jnp.float32)
+            for w in (w_gate, w_up))
+    return (jax.nn.silu(g) * u).astype(rows.dtype)
 
 
 def _tiles(M: int, K: int, N: int, dtype) -> tuple:
@@ -85,6 +116,30 @@ def _tiles(M: int, K: int, N: int, dtype) -> tuple:
             f"VMEM: {need} bytes at the narrowest column tile, more than the "
             f"{_SCOPED_VMEM_BYTES} a kernel may use")
     return tm, tn
+
+
+def _gate_up_tiles(M: int, K: int, F: int, dtype) -> tuple:
+    """(tile_m, tile_n, scoped VMEM to ask for) of the gate-up launch; see
+    the module docstring for the rule."""
+    isz = jnp.dtype(dtype).itemsize
+    tm = tiling.legal_block(_TILE_M, M, dtype)
+
+    def limit(tn):  # both weight blocks, rows and result double-buffered,
+        # the two float32 products and the epilogue's temporaries; an eighth
+        # for what the compiler keeps besides
+        need = (2 * 2 * K * tn * isz + 2 * tm * K * isz + 2 * tm * tn * isz
+                + 4 * tm * tn * 4)
+        return tiling.round_up(need * 9 // 8, 1 << 20)
+
+    widths = range(F, 0, -tiling.LANE) if F % tiling.LANE == 0 else (F,)
+    for tn in widths:
+        ask = limit(tn)
+        if F % tn == 0 and ask <= _VMEM_CEILING_BYTES:
+            return tm, tn, ask if ask > _SCOPED_VMEM_BYTES else None
+    raise NotImplementedError(
+        f"moe_gmm keeps the whole contraction ({K}) of a gate and an up "
+        f"weight tile in VMEM: {limit(widths[-1])} bytes at the narrowest "
+        f"column tile, more than the {_VMEM_CEILING_BYTES} a launch asks for")
 
 
 def _work_items(group_sizes, *, n_rows: int, tile_m: int):
@@ -113,9 +168,12 @@ def _work_items(group_sizes, *, n_rows: int, tile_m: int):
 
 
 def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
-                x_ref, w_ref, o_ref, *, n_groups: int):
-    """One (column tile, work item) program; see the module docstring."""
+                x_ref, *refs, n_groups: int, zero_tail: bool):
+    """One (column tile, work item) program; see the module docstring. One
+    weight operand: the product. Two, gate then up: ``SiLU(g) * u`` of the two
+    float32 products."""
     del read_ref  # the index maps' business
+    *w_refs, o_ref = refs
     item = pl.program_id(1)
 
     @pl.when(item < used_ref[0])
@@ -129,66 +187,108 @@ def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
 
         @pl.when(g < n_groups)
         def _product():
-            acc = jnp.dot(x_ref[...], w_ref[0],
-                          preferred_element_type=jnp.float32)
+            acc, *up = [jnp.dot(x_ref[...], w_ref[0],
+                                preferred_element_type=jnp.float32)
+                        for w_ref in w_refs]
+            if up:
+                acc = jax.nn.silu(acc) * up[0]
             o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), kept)
 
-        @pl.when(g >= n_groups)
-        def _tail():
-            o_ref[...] = jnp.where(mine, jnp.zeros_like(kept), kept)
+        if zero_tail:
+            @pl.when(g >= n_groups)
+            def _tail():
+                o_ref[...] = jnp.where(mine, jnp.zeros_like(kept), kept)
 
 
-def grouped_matmul_kernel(rows, w, group_sizes, *, tiles=None, interpret=None):
-    """The Pallas path. ``tiles`` (tile_m, tile_n) and ``interpret`` are for
-    the tests and a sweep on the chip; the program leaves both to the shape
-    and the backend. Rows are padded to whole tiles when they are not (the
-    expert layer sizes its buffer so that they are)."""
+def _launch(rows, ws, group_sizes, *, tiles, vmem_limit=None, zero_tail=True,
+            interpret=None):
+    """``pallas_call(name="moe_gmm")`` of ``rows (M, K)`` against the weight
+    operands ``ws``, each ``(G, K, N)``, at ``tiles`` (tile_m, tile_n). Rows
+    are padded to whole tiles when they are not (the expert layer sizes its
+    buffer so that they are). ``zero_tail=False``: the tail's items address
+    the last real item's OUTPUT block too and do nothing, so the row tiles
+    past the last group are never written."""
     M, K = rows.shape
-    G, _, N = w.shape
-    tm, tn = tiles or _tiles(M, K, N, rows.dtype)
+    G, _, N = ws[0].shape
+    tm, tn = tiles
     if interpret is None:
         interpret = kernel_interpret()
     m_pad = tiling.round_up(M, tm)
     if m_pad != M:
         rows = jnp.pad(rows, ((0, m_pad - M), (0, 0)))
     scalars = _work_items(group_sizes, n_rows=m_pad, tile_m=tm)
+    weights = pl.BlockSpec(
+        (1, K, tn), lambda n, i, grp, *_: (jnp.minimum(grp[i], G - 1), 0, n))
     out = pl.pallas_call(
-        functools.partial(_gmm_kernel, n_groups=G),
+        functools.partial(_gmm_kernel, n_groups=G, zero_tail=zero_tail),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(N // tn, m_pad // tm + G),
             in_specs=[
                 pl.BlockSpec((tm, K),
                              lambda n, i, grp, tile, read, *_: (read[i], 0)),
-                pl.BlockSpec((1, K, tn),
-                             lambda n, i, grp, *_: (
-                                 jnp.minimum(grp[i], G - 1), 0, n)),
+                *[weights] * len(ws),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn), lambda n, i, grp, tile, *_: (tile[i], n)),
+                (tm, tn), lambda n, i, grp, tile, read, *_: (
+                    (tile if zero_tail else read)[i], n)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, N), rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="moe_gmm",
-    )(*scalars, rows, w)
+    )(*scalars, rows, *ws)
     return out[:M] if m_pad != M else out
 
 
-@jax.custom_vjp
-def _kernel_no_vjp(rows, w, group_sizes):
-    return grouped_matmul_kernel(rows, w, group_sizes)
+def grouped_matmul_kernel(rows, w, group_sizes, *, tiles=None, interpret=None):
+    """The Pallas path. ``tiles`` (tile_m, tile_n) and ``interpret`` are for
+    the tests and a sweep on the chip; the program leaves both to the shape
+    and the backend."""
+    M, K = rows.shape
+    tiles = tiles or _tiles(M, K, w.shape[2], rows.dtype)
+    return _launch(rows, (w,), group_sizes, tiles=tiles, interpret=interpret)
 
 
-def _no_vjp_fwd(*args):
-    raise NotImplementedError(
-        "the moe_gmm kernel has no backward yet (ROADMAP Reach): "
-        "differentiate ops.grouped_matmul.grouped_matmul_xla "
-        "(jax.lax.ragged_dot), which is what grouped_matmul runs off the TPU")
+def grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, *, zero_tail=True,
+                           tiles=None, vmem_limit=None, interpret=None):
+    """The Pallas path of :func:`grouped_gate_up`: ONE launch, both products
+    over the row tile while it sits in VMEM. ``zero_tail=False`` leaves the
+    row tiles past the last group unwritten (:func:`grouped_mlp`). ``tiles``,
+    ``vmem_limit`` and ``interpret`` are for the tests and a sweep on the
+    chip; the program leaves them to the shape and the backend."""
+    if tiles is None:
+        *tiles, vmem_limit = _gate_up_tiles(*rows.shape, w_gate.shape[2],
+                                            rows.dtype)
+    return _launch(rows, (w_gate, w_up), group_sizes, tiles=tiles,
+                   vmem_limit=vmem_limit, zero_tail=zero_tail,
+                   interpret=interpret)
 
 
-_kernel_no_vjp.defvjp(_no_vjp_fwd, lambda res, g: None)
+def _no_vjp(kernel, differentiable: str):
+    """``kernel`` as a function that says by name why it will not
+    differentiate."""
+    @jax.custom_vjp
+    def launch(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        raise NotImplementedError(
+            "the moe_gmm kernel has no backward yet (ROADMAP Reach): "
+            f"differentiate ops.grouped_matmul.{differentiable} "
+            "(jax.lax.ragged_dot), which is what runs off the TPU")
+
+    launch.defvjp(fwd, lambda res, g: None)
+    return launch
+
+
+_kernel_no_vjp = _no_vjp(grouped_matmul_kernel, "grouped_matmul_xla")
+_gate_up_no_vjp = _no_vjp(grouped_gate_up_kernel, "grouped_gate_up_xla")
+_gate_up_open_tail_no_vjp = _no_vjp(
+    functools.partial(grouped_gate_up_kernel, zero_tail=False),
+    "grouped_gate_up_xla")
 
 
 def grouped_matmul(rows, w, group_sizes):
@@ -201,3 +301,34 @@ def grouped_matmul(rows, w, group_sizes):
         with jax.named_scope("moe_gmm"):
             return _kernel_no_vjp(rows, w, group_sizes)
     return grouped_matmul_xla(rows, w, group_sizes)
+
+
+def _first_half(launch, rows, w_gate, w_up, group_sizes):
+    """``launch`` on the TPU, the XLA composition elsewhere; counted once as
+    a first half and twice as a product."""
+    use_kernel = jax.default_backend() == "tpu"
+    _kernels.inc("kernels.moe_gate_up_schedule",
+                 key="fused" if use_kernel else "xla")
+    _kernels.inc("kernels.moe_gmm_schedule", 2,
+                 key="kernel" if use_kernel else "xla")
+    if use_kernel:
+        with jax.named_scope("moe_gmm"):
+            return launch(rows, w_gate, w_up, group_sizes)
+    return grouped_gate_up_xla(rows, w_gate, w_up, group_sizes)
+
+
+def grouped_gate_up(rows, w_gate, w_up, group_sizes):
+    """``h`` of the module docstring's contract, ``(M, F)`` in ``rows``'
+    dtype: float32 accumulation, SiLU and product on either path, one launch
+    on the TPU."""
+    return _first_half(_gate_up_no_vjp, rows, w_gate, w_up, group_sizes)
+
+
+def grouped_mlp(rows, w_gate, w_up, w_down, group_sizes):
+    """The experts' whole MLP, ``out[r] = h[r] @ w_down[g]`` with ``h`` of
+    the module docstring's contract: ``(M, K)``, zero past the last group.
+    Two launches on the TPU, and because ``h`` lives only between them, the
+    first leaves the row tiles past the last group unwritten: the second
+    reads no row tile beyond the last group's (:func:`_work_items`)."""
+    h = _first_half(_gate_up_open_tail_no_vjp, rows, w_gate, w_up, group_sizes)
+    return grouped_matmul(h, w_down, group_sizes)

@@ -651,6 +651,34 @@ def test_moe_gmm_lowers_at_the_published_shapes_and_keeps_its_name(
     assert m and [int(g) for g in m.groups()[1:]] == [-(-rows // 128) * 128, N]
 
 
+@pytest.mark.parametrize("rows,K,F,groups,whole", [
+    (163968, 3072, 1024, 128, False),  # Laguna-S-2.1: 4 x 4,097 tokens x 10
+    (73856, 6144, 2048, 16, False),    # GLM-5.2: 9,217 tokens x 8, 16 held
+    (163968, 3072, 1024, 128, True),   # the layer's two launches, open tail
+])
+def test_moe_gate_up_lowers_to_one_launch_of_the_same_name(
+        rows, K, F, groups, whole, chip):
+    """The expert MLP's first half at both expert cells' shapes, tiles and
+    scoped VMEM from the shape: ONE ``tpu_custom_call`` for gate, up and
+    ``SiLU(g) * u``, still named ``%moe_gmm`` with result ``[buffer rows,
+    F]``, as ``moe_gmm_roofline`` matches it; the ``whole`` MLP is that launch
+    and down's, two where it was three."""
+    from benchmark.layer_metrics import moe_gmm_roofline as reader
+    from ddim_cold_tpu.ops import grouped_matmul as gm
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    bank = sds((groups, K, F), jnp.bfloat16)
+    down = [sds((groups, F, K), jnp.bfloat16)] if whole else []
+    text = jax.jit(gm.grouped_mlp if whole else gm.grouped_gate_up).lower(
+        sds((rows, K), jnp.bfloat16), bank, bank, *down,
+        sds((groups,), jnp.int32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    shapes = [[int(g) for g in reader.NAME.match(call).groups()[1:]]
+              for call in calls]
+    assert shapes == [[rows, F]] + [[rows, K]] * whole
+
+
 # --- the selection and the selected forward at GLM-5.2's shapes -------------
 
 GLM = dict(n=1, L=9217, heads=64, hd=256, index_heads=32, index_dim=128,

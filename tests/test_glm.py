@@ -263,13 +263,15 @@ def test_the_named_scopes_and_counters_of_a_trace():
     by_key = {}
     for series in metrics.snapshot().values():
         for name in ("kernels.dsa_indexer_layers", "kernels.dsa_select_schedule",
-                     "kernels.moe_gmm_schedule"):
+                     "kernels.moe_gmm_schedule",
+                     "kernels.moe_gate_up_schedule"):
             for key, count in series.get(name + "/by_key", {}).items():
                 by_key[name, key] = by_key.get((name, key), 0) + count
     assert by_key == {("kernels.dsa_indexer_layers", "full"): 2,
                       ("kernels.dsa_indexer_layers", "shared"): 3,
                       ("kernels.dsa_select_schedule", "xla"): 2,
-                      ("kernels.moe_gmm_schedule", "xla"): 12}
+                      ("kernels.moe_gmm_schedule", "xla"): 12,
+                      ("kernels.moe_gate_up_schedule", "xla"): 4}
     metrics.reset()
 
 

@@ -1,7 +1,8 @@
 """ops/grouped_matmul.py: the ``moe_gmm`` kernel in interpreter mode against
 ``jax.lax.ragged_dot`` — empty, one-row and tile-straddling groups, rows past
-the last group — its work list, its tiles, its counter and its refusal to
-differentiate."""
+the last group — as the one product and as the expert MLP's first half (gate
+and up in one launch, ``SiLU(g) * u``); its work list, its tiles, its counters
+and its refusal to differentiate."""
 
 import jax
 import jax.numpy as jnp
@@ -11,39 +12,85 @@ import pytest
 from ddim_cold_tpu.ops import grouped_matmul as gm
 
 
-def _operands(M, sizes, K=32, N=64, dtype=jnp.float32, seed=1):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+def _operands(M, sizes, K=32, N=64, dtype=jnp.float32, seed=1, banks=1):
+    """``rows, *weight banks, group_sizes``; two banks are gate and up."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 1 + banks)
     return (jax.random.normal(ks[0], (M, K), dtype),
-            jax.random.normal(ks[1], (len(sizes), K, N), dtype),
+            *(jax.random.normal(k, (len(sizes), K, N), dtype) for k in ks[1:]),
             jnp.array(sizes, jnp.int32))
 
 
-@pytest.mark.parametrize("sizes,M,tiles", [
+def _first_half(rows, w_gate, w_up, group_sizes, dtype=jnp.float32):
+    """``SiLU(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)``, each step
+    rounded to ``dtype``: in bfloat16 the three-step composition the expert
+    layer ran before the launch was fused."""
+    g, u = (jax.lax.ragged_dot(rows.astype(dtype), w.astype(dtype), group_sizes,
+                               preferred_element_type=jnp.float32).astype(dtype)
+            for w in (w_gate, w_up))
+    return jax.nn.silu(g) * u
+
+
+GROUPS = [
     ([5, 0, 1, 20, 7], 40, None),            # empty, one-row, rows left over
     ([0, 0, 0], 16, None),                   # nothing routed here at all
     ([300, 0, 1, 127, 128, 3], 600, None),   # groups across tiles of 128
     ([16, 16], 32, (16, 64)),                # groups that end on tile edges
     ([3, 9, 30], 64, (8, 64)),               # a tile three groups share
     ([0, 130], 256, None),                   # a leading empty group
-])
-def test_kernel_matches_ragged_dot(sizes, M, tiles):
-    rows, w, group_sizes = _operands(M, sizes)
-    want = gm.grouped_matmul_xla(rows, w, group_sizes)
-    got = gm.grouped_matmul_kernel(rows, w, group_sizes, tiles=tiles)
+    ([20, 4], 64, (8, 64)),                  # a tail of whole tiles
+]
+
+
+@pytest.mark.parametrize("launch", ["product", "gate_up"])
+@pytest.mark.parametrize("sizes,M,tiles", GROUPS)
+def test_kernel_matches_ragged_dot(sizes, M, tiles, launch):
+    if launch == "product":
+        rows, w, group_sizes = _operands(M, sizes)
+        want = gm.grouped_matmul_xla(rows, w, group_sizes)
+        got = gm.grouped_matmul_kernel(rows, w, group_sizes, tiles=tiles)
+        tol = 1e-5
+    else:
+        rows, w_gate, w_up, group_sizes = _operands(M, sizes, banks=2)
+        rows = rows * 0.25  # |g|, |u| of order one: 1e-6 is then a few ulps
+        want = _first_half(rows, w_gate, w_up, group_sizes)
+        got = gm.grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes,
+                                        tiles=tiles or gm._tiles(
+                                            M, 32, 64, jnp.float32))
+        np.testing.assert_array_equal(
+            gm.grouped_gate_up_xla(rows, w_gate, w_up, group_sizes), want)
+        tol = 1e-6
     assert got.shape == want.shape == (M, 64)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
     # rows past the last group: zeros, as ragged_dot leaves them
     assert not np.asarray(got[sum(sizes):]).any()
 
 
-def test_kernel_in_bfloat16_accumulates_in_float32():
-    rows, w, group_sizes = _operands(256, [100, 28, 60], K=256, N=128,
-                                     dtype=jnp.bfloat16)
-    got = gm.grouped_matmul_kernel(rows, w, group_sizes)
-    want = gm.grouped_matmul_xla(rows, w, group_sizes)
-    assert got.dtype == jnp.bfloat16
-    np.testing.assert_allclose(got.astype(jnp.float32),
-                               want.astype(jnp.float32), rtol=2e-2, atol=2e-2)
+@pytest.mark.parametrize("launch", ["product", "gate_up"])
+def test_kernel_in_bfloat16_accumulates_in_float32(launch):
+    bf16 = jnp.bfloat16
+    if launch == "product":
+        rows, w, group_sizes = _operands(256, [100, 28, 60], K=256, N=128,
+                                         dtype=bf16)
+        got = gm.grouped_matmul_kernel(rows, w, group_sizes)
+        want = gm.grouped_matmul_xla(rows, w, group_sizes)
+        assert got.dtype == bf16
+        np.testing.assert_allclose(got.astype(jnp.float32),
+                                   want.astype(jnp.float32), rtol=2e-2, atol=2e-2)
+        return
+    rows, w_gate, w_up, group_sizes = _operands(
+        256, [100, 28, 60], K=256, N=128, dtype=bf16, banks=2)
+    rows = rows * 0.0625
+    got = gm.grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes,
+                                    tiles=(128, 128))
+    assert got.dtype == bf16
+    # g and u reach the SiLU unrounded: one rounding where the three steps
+    # make three, so the launch lies closer to the float32 answer than they do
+    exact = _first_half(rows, w_gate, w_up, group_sizes)
+    steps = _first_half(rows, w_gate, w_up, group_sizes, bf16)
+    err = lambda h: float(jnp.sqrt(jnp.mean((h.astype(jnp.float32) - exact) ** 2)))
+    assert err(got) < 0.75 * err(steps)
+    np.testing.assert_allclose(got.astype(jnp.float32), exact,
+                               rtol=1e-2, atol=1e-2)
 
 
 def test_work_list_visits_a_shared_tile_once_a_group_and_the_tail_once():
@@ -71,27 +118,110 @@ def test_tiles_come_from_the_shape():
         gm._tiles(1024, 65536, 1024, bf16)
 
 
-def test_counter_says_which_path_a_trace_took_and_the_cpu_takes_ragged_dot():
+@pytest.mark.parametrize("M,K,F,groups", [
+    (163968, 3072, 1024, 128),   # Laguna-S-2.1's expert layer on this chip
+    (73856, 6144, 2048, 16),     # GLM-5.2's
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gate_up_tiles_come_from_the_shape_with_the_vmem_they_need(
+        M, K, F, groups, dtype):
+    """The widest column tile whose working set — both double-buffered weight
+    blocks, the rows, the result, the two float32 products — fits the scoped
+    VMEM the launch then asks for, which stays under the ceiling; and never
+    more grid steps than ONE of the products it replaces."""
+    isz = jnp.dtype(dtype).itemsize
+    tm, tn, limit = gm._gate_up_tiles(M, K, F, dtype)
+    assert tm == 128 and F % tn == 0 and tn % 128 == 0
+    need = (2 * 2 * K * tn * isz + 2 * tm * K * isz + 2 * tm * tn * isz
+            + 2 * tm * tn * 4)
+    assert gm._SCOPED_VMEM_BYTES < need <= limit <= gm._VMEM_CEILING_BYTES
+    assert 2 * tn > F or 2 * 2 * K * 2 * tn * isz > gm._VMEM_CEILING_BYTES
+    one_product = (F // gm._tiles(M, K, F, dtype)[1]) * (M // 128 + groups)
+    assert (F // tn) * (M // tm + groups) <= one_product
+    if dtype == jnp.bfloat16:
+        assert tn == 1024  # Laguna: the whole width, rows streamed once
+
+
+def test_gate_up_tiles_of_a_toy_and_of_a_contraction_too_long():
+    assert gm._gate_up_tiles(40, 32, 64, jnp.float32) == (40, 64, None)
+    assert gm._gate_up_tiles(256, 256, 128, jnp.bfloat16) == (128, 128, None)
+    with pytest.raises(NotImplementedError, match="whole contraction"):
+        gm._gate_up_tiles(1024, 65536, 1024, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("sizes,M,tiles", GROUPS)
+def test_the_two_launches_do_not_read_what_the_first_left_unwritten(
+        sizes, M, tiles):
+    """``grouped_mlp``'s first launch leaves ``h``'s row tiles past the last
+    group unwritten (the interpreter leaves NaN there, the chip whatever lay
+    in HBM); its second launch's result is the float32 composition's all the
+    same, and does not move when those tiles are overwritten."""
+    rows, w_gate, w_up, group_sizes = _operands(M, sizes, banks=2)
+    rows = rows * 0.25
+    w_down = jax.random.normal(jax.random.PRNGKey(5), (len(sizes), 64, 32)) / 8
+    tiles = tiles or gm._tiles(M, 32, 64, jnp.float32)
+    h = gm.grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, tiles=tiles,
+                                  zero_tail=False)
+    whole = gm.grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, tiles=tiles)
+    written = -(-sum(sizes) // tiles[0]) * tiles[0]  # to the last real tile's end
+    np.testing.assert_array_equal(h[:written], whole[:written])
+    if written < M and sum(sizes):
+        assert np.isnan(np.asarray(h[written:])).all()  # really left alone
+    down = lambda h: gm.grouped_matmul_kernel(h, w_down, group_sizes,
+                                              tiles=(tiles[0], 32))
+    got = down(h)
+    np.testing.assert_array_equal(got, down(whole))
+    np.testing.assert_array_equal(got, down(h.at[written:].set(-7.0)))
+    want = gm.grouped_matmul_xla(_first_half(rows, w_gate, w_up, group_sizes),
+                                 w_down, group_sizes)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[sum(sizes):]).any()
+
+
+@pytest.mark.parametrize("banks,counted", [
+    (1, {"kernels.moe_gmm_schedule": {"xla": 1}}),
+    # the first half counts the two PRODUCTS it holds, and its own path
+    (2, {"kernels.moe_gmm_schedule": {"xla": 2},
+         "kernels.moe_gate_up_schedule": {"xla": 1}}),
+    (3, {"kernels.moe_gmm_schedule": {"xla": 3},   # the whole MLP: + down
+         "kernels.moe_gate_up_schedule": {"xla": 1}}),
+])
+def test_counter_says_which_path_a_trace_took_and_the_cpu_takes_ragged_dot(
+        banks, counted):
     from ddim_cold_tpu.obs import metrics
 
     metrics.reset()
-    rows, w, group_sizes = _operands(40, [5, 0, 1, 20, 7])
-    got = gm.grouped_matmul(rows, w, group_sizes)
-    np.testing.assert_array_equal(got, gm.grouped_matmul_xla(rows, w, group_sizes))
+    rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], N=32, banks=banks)
+    if banks == 1:
+        got = gm.grouped_matmul(rows, *ws, group_sizes)
+        want = gm.grouped_matmul_xla(rows, *ws, group_sizes)
+    elif banks == 2:
+        got = gm.grouped_gate_up(rows, *ws, group_sizes)
+        want = gm.grouped_gate_up_xla(rows, *ws, group_sizes)
+    else:
+        got = gm.grouped_mlp(rows, *ws, group_sizes)
+        want = gm.grouped_matmul_xla(
+            gm.grouped_gate_up_xla(rows, *ws[:2], group_sizes), ws[2], group_sizes)
+    np.testing.assert_array_equal(got, want)
     by_key = {}
     for series in metrics.snapshot().values():
-        by_key.update(series.get("kernels.moe_gmm_schedule/by_key", {}))
-    assert by_key == {"xla": 1}
+        for name in ("kernels.moe_gmm_schedule", "kernels.moe_gate_up_schedule"):
+            if name + "/by_key" in series:
+                by_key[name] = series[name + "/by_key"]
+    assert by_key == counted
     metrics.reset()
 
 
-def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot():
-    rows, w, group_sizes = _operands(40, [5, 0, 1, 20, 7])
-    grads = jax.grad(lambda r, w: jnp.sum(gm.grouped_matmul(r, w, group_sizes) ** 2),
-                     argnums=(0, 1))(rows, w)
+@pytest.mark.parametrize("banks", [1, 2])
+def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot(banks):
+    rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], banks=banks)
+    public, launch = ((gm.grouped_matmul, gm._kernel_no_vjp) if banks == 1
+                      else (gm.grouped_gate_up, gm._gate_up_no_vjp))
+    grads = jax.grad(lambda r, *ws: jnp.sum(public(r, *ws, group_sizes) ** 2),
+                     argnums=tuple(range(1 + banks)))(rows, *ws)
     assert all(np.isfinite(np.asarray(g)).all() and np.asarray(g).any()
                for g in grads)
     assert not np.asarray(grads[0][33:]).any()  # rows no group holds
     with pytest.raises(NotImplementedError, match="moe_gmm kernel has no "
                                                   "backward"):
-        jax.grad(lambda r: jnp.sum(gm._kernel_no_vjp(r, w, group_sizes)))(rows)
+        jax.grad(lambda r: jnp.sum(launch(r, *ws, group_sizes)))(rows)

@@ -506,8 +506,10 @@ def test_the_named_scopes_and_counters_of_a_trace():
     for scope in ("trunk/attn_full", "trunk/attn_window", "trunk/moe",
                   "trunk/mlp"):
         assert scope in text, scope
-    by_key = {}
+    by_key, first_half = {}, {}
     for series in metrics.snapshot().values():
         by_key.update(series.get("kernels.moe_gmm_schedule/by_key", {}))
+        first_half.update(series.get("kernels.moe_gate_up_schedule/by_key", {}))
     assert by_key == {"xla": 12}  # 4 sparse layers x gate, up, down
+    assert first_half == {"xla": 4}  # gate and up: one call a layer
     metrics.reset()
