@@ -63,6 +63,7 @@ from typing import Any, Mapping
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
 from ddim_cold_tpu.models.init import trunc_normal
@@ -166,6 +167,65 @@ class Indexer(nn.Module):
         return select(q.reshape(n, L, J, D), k, w, c["index_topk"])
 
 
+class _DenseByColumnSets(nn.Module):
+    """A bias-free dense map whose ONE kernel ``(in, Σ widths)`` is multiplied
+    a column set at a time: one result array a set, each written where its
+    reader takes it, and no slice of an activation after."""
+
+    widths: tuple
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", trunc_normal(std=0.02),
+                            (x.shape[-1], sum(self.widths)), self.param_dtype)
+        x, kernel = x.astype(self.dtype), kernel.astype(self.dtype)
+        return [jnp.dot(x, columns) for columns in jnp.split(
+            kernel, np.cumsum(self.widths)[:-1], axis=1)]
+
+
+def latent_projections(c: Mapping[str, Any], y, rope, pairing: str, dtype,
+                       param_dtype, *, apart: bool = False):
+    """The low-rank query and key/value paths of latent attention, for every
+    stack that has them, called inside the attention module's ``__call__``
+    (the parameters are that module's: ``q_a_proj``, ``q_a_layernorm``,
+    ``q_b_proj``, ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``):
+    ``(c_q, q, k_r, kv)`` of the layer's normed input ``y (n, L, hidden)``,
+    q's rotated parts and the ONE shared ``k_r (n, L, rot)`` rotated by
+    ``rope`` (:func:`laguna.rotary_frequencies`) under ``pairing``.
+
+    The two up-projections come in the column order their reader wants.
+    Published (``apart`` false): a head's parts side by side, ``q (n, L,
+    H·(nope + rot))`` and ``kv (n, L, H·(nope + vd))``. ``apart``: every part
+    an array of its own, the columns of ``q_b_proj`` as all the heads' nope
+    parts then all their rotated parts and those of ``kv_b_proj`` as all the
+    k_nope then all the v — ``q = (q_nope (n, L, H·nope), q_r (n, L,
+    H·rot))``, ``kv = (k_nope, v (n, L, H·vd))`` — so that each lies on whole
+    lanes where a kernel reads it; only q_r is touched by the rotation."""
+    H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
+                    c["qk_rope_head_dim"])
+    vd, rank = c["v_head_dim"], c["kv_lora_rank"]
+    kw = dict(dtype=dtype, param_dtype=param_dtype)
+    dense = lambda feats, name: _dense(feats, name, **kw)
+    norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
+    sets = lambda name, *widths: _DenseByColumnSets(widths, name=name, **kw)
+
+    c_q = norm("q_a_layernorm")(dense(c["q_lora_rank"], "q_a_proj")(y))
+    if apart:
+        q_nope, q_r = sets("q_b_proj", H * nope, H * rot)(c_q)
+        q = q_nope, apply_rotary(q_r, H, *rope, pairing=pairing)
+    else:
+        q = apply_rotary(dense(H * (nope + rot), "q_b_proj")(c_q), H, *rope,
+                         pairing=pairing, first=nope)
+    kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
+    k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
+    c_kv = norm("kv_a_layernorm")(kv_a[..., :rank])
+    kv = (tuple(sets("kv_b_proj", H * nope, H * vd)(c_kv)) if apart
+          else dense(H * (nope + vd), "kv_b_proj")(c_kv))
+    return c_q, q, k_r, kv
+
+
 class LatentAttention(nn.Module):
     trunk: Mapping[str, Any]
     indexer: bool
@@ -179,20 +239,13 @@ class LatentAttention(nn.Module):
         n, L, width = y.shape
         H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
                         c["qk_rope_head_dim"])
-        hd, vd, rank = c["qk_head_dim"], c["v_head_dim"], c["kv_lora_rank"]
+        hd, vd = c["qk_head_dim"], c["v_head_dim"]
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         dense = lambda feats, name: _dense(feats, name, **kw)
-        norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
-        rope = rotary_frequencies(c["rope_parameters"], rot)
-        pairing = _pairing(c.get("rope_interleave", False))
-
-        c_q = norm("q_a_layernorm")(dense(c["q_lora_rank"], "q_a_proj")(y))
-        q = apply_rotary(dense(H * hd, "q_b_proj")(c_q), H, *rope,
-                         pairing=pairing, first=nope)
-        kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
-        k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
-        kv = dense(H * (nope + vd), "kv_b_proj")(
-            norm("kv_a_layernorm")(kv_a[..., :rank])).reshape(n, L, H, nope + vd)
+        c_q, q, k_r, kv = latent_projections(
+            c, y, rotary_frequencies(c["rope_parameters"], rot),
+            _pairing(c.get("rope_interleave", False)), **kw)
+        kv = kv.reshape(n, L, H, nope + vd)
         k = jnp.concatenate(
             [kv[..., :nope],
              jnp.broadcast_to(k_r[:, :, None, :], (n, L, H, rot))], axis=-1)

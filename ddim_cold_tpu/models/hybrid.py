@@ -12,7 +12,10 @@ heads with rotary positions and per-head gates, a dense MLP or top-k routed
 experts of which this chip holds a share) or ``glm_moe_dsa``
 (``models/glm.py``: latent attention over a learned per-query selection of
 keys that some layers compute and the others borrow, sigmoid-scored
-experts). One wrapper serves all: what the samplers and the engine read of a
+experts) or ``pangu_ultra_moe`` (``models/pangu.py``: the same latent
+projections attending to every causal pair, value heads of another size than
+the query/key heads, a second norm on every sub-layer's result). One wrapper
+serves all: what the samplers and the engine read of a
 model, ``clone``, the refusals and ``__call__`` below.
 
 The ``jamba`` stack: Mamba-1 state-space layers with a causal grouped-query
@@ -78,9 +81,9 @@ REFUSED = {
                    "a trunk's experts are its own (trunk: num_experts)",
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
-                 "layers are dense XLA attention, the laguna and glm_moe_dsa "
-                 "stacks run the masked flash forward wherever the backend "
-                 "is a TPU",
+                 "layers are dense XLA attention, the laguna, glm_moe_dsa "
+                 "and pangu_ultra_moe stacks run their flash forwards "
+                 "wherever the backend is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -308,8 +311,13 @@ def stack_of(trunk: Mapping[str, Any]) -> tuple:
         from ddim_cold_tpu.models import glm
 
         return glm.check_trunk, glm.layer
-    raise ValueError(f"no layer stack for model_type {model_type!r}: "
-                     "'jamba', 'laguna' and 'glm_moe_dsa' are written")
+    if model_type == "pangu_ultra_moe":
+        from ddim_cold_tpu.models import pangu
+
+        return pangu.check_trunk, pangu.layer
+    raise ValueError(f"no layer stack for model_type {model_type!r}: 'jamba', "
+                     "'laguna', 'glm_moe_dsa' and 'pangu_ultra_moe' are "
+                     "written")
 
 
 def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:

@@ -114,6 +114,8 @@ METRICS = (
      "flash backward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_fwd_mask", "counter",
      "flash forward traces by mask (key: none|causal|window|selected)"),
+    ("kernels.flash_latent_schedule", "counter",
+     "latent (two-part score) attention traces by path (key: kernel|xla)"),
     # -- kernels (ops/sparse_select.py, counted once a trace) -------------
     ("kernels.dsa_select_schedule", "counter",
      "sparse-attention key selection traces by path (key: kernel|xla)"),

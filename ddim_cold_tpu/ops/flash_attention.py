@@ -92,6 +92,15 @@ do, and both streaming at (256, 512) where nothing fits;
 ``kernels.flash_bwd_schedule`` counts which. Explicit blocks are honoured,
 and ask for dq and dk/dv.
 
+Beside the unmasked pair above, three forward launches of their own, for the
+decoder stacks under ``models/hybrid.py`` (sampled only: none has a backward
+yet, and each says so by name): ``fwd_masked`` (causal and window masks on
+shared K/V heads, the chunks outside a q block's mask skipped in the grid),
+``fwd_selected`` (the same body under a per-query key selection) and
+``fwd_latent`` (the same body over a score that is the SUM of two products,
+the second over a key part all the heads share, with the value head at its
+own width: :func:`flash_attention_latent`).
+
 On the CPU backend the kernels run in interpreter mode, so tests exercise
 the identical code paths; any other non-TPU backend is an error — a caller
 that asked for the kernel never gets a different computation in its place.
@@ -1407,11 +1416,16 @@ class _Ints:
     minimum, maximum = staticmethod(min), staticmethod(max)
 
 
-def _fwd_masked_kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_valid: int,
-                       bq: int, bkv: int, n_kv: int, causal: bool,
-                       window: int | None, selected: bool = False):
+def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
+                       n_kv: int, causal: bool, window: int | None,
+                       selected: bool = False, parts: int = 1):
     """One (image, query head, q block, visited chunk) program of the masked
-    forward: one head on the block's lanes. ``selected``: a fourth operand,
+    forward: one head on the block's lanes. ``parts``: the score is the sum of
+    that many products, ``parts`` q blocks then ``parts`` k blocks before v,
+    laid side by side on the lanes into ONE contraction (the latent forward's
+    ``q_nope·k_nope + q_r·k_r``: :func:`_fwd_latent_kernel`); the value head,
+    and with it the accumulator and the result, has its own width.
+    ``selected``: one more operand after v,
     the ``(bq, bkv)`` int8 tile of a per-query key selection
     (``ops/sparse_select.py``), comes before the result; a pair is then kept
     where the tile is not 0 AND the mask lets it through, so every visited
@@ -1427,6 +1441,8 @@ def _fwd_masked_kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_valid: int,
     chunk, for the block's last rows) holds m = −1e30 and garbage l, acc until
     its diagonal chunk — always visited, and later — scales them by
     exp(−1e30 − m) = 0."""
+    q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
+    rest = refs[2 * parts + 1:]
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     o_ref, acc_ref, m_ref, l_ref = rest
     i, j = pl.program_id(2), pl.program_id(3)
@@ -1443,9 +1459,11 @@ def _fwd_masked_kernel(q_ref, k_ref, v_ref, *rest, scale: float, n_valid: int,
     fold_scale = _scale_folds_into_q(scale)
 
     def fold(masked: bool):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        qs, ks, v = [r[0] for r in q_refs], [r[0] for r in k_refs], v_ref[0]
         if fold_scale:
-            q = q * scale
+            qs = [q * scale for q in qs]
+        q, k = (x[0] if parts == 1 else jnp.concatenate(x, axis=1)
+                for x in (qs, ks))
         if masked and n_valid % bkv:
             # rows of a ragged last chunk hold whatever the buffer held; their
             # p is an exact 0, and 0 × garbage is NaN
@@ -1503,6 +1521,38 @@ def _masked_blocks(n_tokens: int, dtype) -> tuple:
     return block, block
 
 
+def _chunk_walk(tokens: int, geometry: dict) -> tuple:
+    """(q blocks, steps of the last grid axis, ``chunk(i, j)``) of a launch
+    that walks only the K/V chunks a q block's mask lets it see: the axis is
+    as long as the most chunks any q block sees, and ``chunk(i, j)`` — for the
+    index maps — is the ``j``-th chunk q block ``i`` sees, or its last one
+    again once past them (not fetched again; the kernel skips the fold)."""
+    n_q = pl.cdiv(tokens, geometry["bq"])
+    spans = [_visible_chunks(i, lib=_Ints, **geometry) for i in range(n_q)]
+    n_kv = max(hi - lo + 1 for lo, hi in spans)
+
+    def chunk(i, j):
+        lo, hi = _visible_chunks(i, **geometry)
+        return jnp.minimum(lo + j, hi)
+
+    return n_q, n_kv, chunk
+
+
+def _walk_scratch(bq: int, lanes: int) -> list:
+    """The online softmax's state across a q block's visited chunks."""
+    return [
+        pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
+        pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
+        pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
+    ]
+
+
+#: grid (rows, heads, q blocks, visited chunks): the state is carried along
+#: the last axis alone
+_WALK_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
 def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
                      bkv, causal, window, interpret):
     """The masked launch (``fwd_masked``), or with ``keep`` — an int8 ``(rows,
@@ -1517,15 +1567,12 @@ def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
     mask), and a block that sees fewer re-addresses its last chunk, which is
     not fetched again, and skips the fold."""
     rows, tokens, width = q.shape
-    n_q = pl.cdiv(tokens, bq)
     geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
                     window=window)
-    spans = [_visible_chunks(i, lib=_Ints, **geometry) for i in range(n_q)]
-    n_kv = max(hi - lo + 1 for lo, hi in spans)
+    n_q, n_kv, chunk = _chunk_walk(tokens, geometry)
 
     def kv_map(b, h, i, j):
-        lo, hi = _visible_chunks(i, **geometry)
-        return (b, jnp.minimum(lo + j, hi), h // rep)
+        return (b, chunk(i, j), h // rep)
 
     q_spec = pl.BlockSpec((1, bq, lanes), lambda b, h, i, j: (b, i, h))
     kv_spec = pl.BlockSpec((1, bkv, lanes), kv_map)
@@ -1542,15 +1589,8 @@ def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
             in_specs=in_specs,
             out_specs=q_spec,
             out_shape=_sds(q.shape, q.dtype, q),
-            scratch_shapes=[
-                pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
-                pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
-                pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary"),
-            ),
+            scratch_shapes=_walk_scratch(bq, lanes),
+            compiler_params=_WALK_PARAMS,
             interpret=interpret,
             name=name,
         )(*operands)
@@ -1704,6 +1744,222 @@ def selected_attention(q, k, v, scale: float, keep) -> jax.Array:
     if jax.default_backend() == "tpu":
         return _selected_no_vjp(q, k, v, keep, scale)
     return selected_attention_xla(q, k, v, scale, keep)
+
+
+# ---------------------------------------------------------------------------
+# latent forward: a score in two parts, the second over a key part all the
+# heads share, the value head at its own width
+# ---------------------------------------------------------------------------
+
+def latent_sizes(q_nope, q_r, k_nope, k_r, v) -> tuple:
+    """``(B, N, H, nope, rot, vd)`` of the latent forward's operands, or a
+    ``ValueError`` that names what the launch cannot address: the operands
+    are read where the projections wrote them, token-major, so ``nope`` and
+    ``vd`` are whole lane groups each (any number, each its own) and ``rot``
+    is half a group or one (64: two heads' rotated parts share a group's
+    lanes; 128)."""
+    B, N, H, nope = q_nope.shape
+    rot, vd = k_r.shape[-1], v.shape[-1]
+    if (q_r.shape != (B, N, H, rot) or k_nope.shape != q_nope.shape
+            or k_r.shape != (B, N, rot) or v.shape != (B, N, H, vd)):
+        raise ValueError(
+            f"q_nope {q_nope.shape}, q_r {q_r.shape}, k_nope {k_nope.shape}, "
+            f"k_r {k_r.shape}, v {v.shape}: the latent forward takes q_nope "
+            "and k_nope (B, N, H, nope), q_r (B, N, H, rot), ONE k_r (B, N, "
+            "rot) for all the heads and v (B, N, H, vd)")
+    if nope % _LANE or vd % _LANE or rot not in (_LANE // 2, _LANE) or (
+            H * rot) % _LANE:
+        raise ValueError(
+            f"head sizes (nope {nope}, rot {rot}, vd {vd}) on {H} heads: the "
+            f"fwd_latent launch reads nope and vd in whole groups of {_LANE} "
+            f"lanes and rot of {_LANE // 2} (an even number of heads) or "
+            f"{_LANE}")
+    return B, N, H, nope, rot, vd
+
+
+def _fwd_latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
+                       m_ref, l_ref, *mine, **geometry):
+    """One (image, head, q block, visited chunk) program of the latent
+    forward: :func:`_fwd_masked_kernel`'s body — its chunk skipping, masks and
+    online softmax — over a score in two parts. At ``rot`` 64 the q_r block
+    holds TWO heads' rotated parts on its 128 lanes and the shared k_r comes
+    twice over on as many, so a head's half is told apart by a lane mask (no
+    lane shift), once a q block, into the scratch ``mine``."""
+    if mine:
+        (mine_ref,) = mine
+        upper = pl.program_id(1) % 2 == 1
+
+        @pl.when(pl.program_id(3) == 0)
+        def _keep_this_heads_half():
+            lane = jax.lax.broadcasted_iota(jnp.int32, mine_ref.shape, 2)
+            q_r = qr_ref[...]
+            mine_ref[...] = jnp.where((lane >= _LANE // 2) == upper, q_r,
+                                      jnp.zeros_like(q_r))
+
+        qr_ref = mine_ref
+    _fwd_masked_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
+                       m_ref, l_ref, parts=2, **geometry)
+
+
+def _latent_vmem_bytes(bq: int, bkv: int, nope: int, vd: int,
+                       itemsize: int) -> int:
+    """Scoped VMEM the latent forward needs at blocks (bq, bkv): the
+    double-buffered q_nope, q_r, result and k_nope, k_r, v blocks (the rotated
+    parts a lane group each), both sides of the one contraction laid side by
+    side, the float32 accumulator and the two lane-replicated statistics, a
+    head's half of q_r, and three live (bq, bkv) float32 tiles (scores, p and
+    the mask's select). An upper bound by the masked forward's count of
+    tiles; tests/test_chip_compile.py compiles what it admits."""
+    q_side = bq * (nope + _LANE + vd) * itemsize
+    k_side = bkv * (nope + _LANE + vd) * itemsize
+    joined = (bq + bkv) * (nope + _LANE) * itemsize
+    scratch = bq * (4 * vd + 8 * _LANE + _LANE * itemsize)
+    return 2 * (q_side + k_side) + joined + scratch + 12 * bq * bkv + (1 << 19)
+
+
+def _latent_blocks(n_tokens: int, nope: int, vd: int, dtype) -> tuple:
+    """(block_q, block_kv) of the latent forward: K/V streamed in the masked
+    forward's chunks of 512, the q block the largest of 1,024 and 512 rows
+    that the VMEM row admits (a head's K/V chunks below the diagonal are
+    fetched once a q block, so twice the rows is half that traffic), both
+    clamped to a short sequence."""
+    _, bkv = _masked_blocks(n_tokens, dtype)
+    isz = jnp.dtype(dtype).itemsize
+    for want in (1024, 512):
+        bq = tiling.legal_block(want, tiling.round_up(n_tokens, 8), dtype)
+        if _latent_vmem_bytes(bq, bkv, nope, vd, isz) <= _SCOPED_VMEM_BYTES:
+            break
+    return bq, bkv
+
+
+def _fwd_latent_call(q_nope, q_r, k_nope, k_r, v, *, heads, scale, n_valid,
+                     bq, bkv, causal, interpret):
+    """The latent launch (``fwd_latent``). ``q_nope``, ``k_nope``: ``(rows,
+    tokens, H·nope)``; ``v``: ``(rows, tokens, H·vd)``; ``q_r``: ``(rows,
+    tokens, H·rot)``; ``k_r``: ``(rows, tokens, 128)``, the one rotated key
+    part of all the heads (twice over at ``rot`` 64); every one where its
+    projection wrote it, the token axis ending inside the last block. Grid
+    and chunk walk as :func:`_fwd_masked_call`'s: head ``h`` reads column
+    block ``h`` of q_nope, k_nope and v, the lane group of q_r its rotated
+    part lies in, and column block 0 of k_r."""
+    rows, tokens, _ = q_nope.shape
+    nope, vd = q_nope.shape[2] // heads, v.shape[2] // heads
+    share = heads * _LANE // q_r.shape[2]  # heads a lane group of q_r
+    geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
+                    window=None)
+    n_q, n_kv, chunk = _chunk_walk(tokens, geometry)
+    q_spec = lambda lanes, per=1: pl.BlockSpec(
+        (1, bq, lanes), lambda b, h, i, j: (b, i, h // per))
+    k_spec = lambda lanes, one=False: pl.BlockSpec(
+        (1, bkv, lanes),
+        lambda b, h, i, j: (b, chunk(i, j), 0 if one else h))
+    scratch = _walk_scratch(bq, vd)
+    if share > 1:  # this head's half of the q_r block
+        scratch.append(pltpu.VMEM((1, bq, _LANE), q_r.dtype))
+    with profiling.scope("flash_attention/fwd_latent"):
+        return pl.pallas_call(
+            functools.partial(_fwd_latent_kernel, scale=scale, n_kv=n_kv,
+                              **geometry),
+            grid=(rows, heads, n_q, n_kv),
+            in_specs=[q_spec(nope), q_spec(_LANE, share), k_spec(nope),
+                      k_spec(_LANE, one=True), k_spec(vd)],
+            out_specs=q_spec(vd),
+            out_shape=_sds(v.shape, v.dtype, v),
+            scratch_shapes=scratch,
+            compiler_params=_WALK_PARAMS,
+            interpret=interpret,
+            name="fwd_latent",
+        )(q_nope, q_r, k_nope, k_r, v)
+
+
+def flash_attention_latent(q_nope, q_r, k_nope, k_r, v, scale: float, *,
+                           causal: bool = True) -> jax.Array:
+    """Attention whose score is the SUM of two products, the second over a
+    key part that all the heads share, with the value head at its own width,
+    as its own launch (``pallas_call(name="fwd_latent")``, ``%fwd_latent`` in
+    a device trace): ``s_ts = (q_nope_h,t · k_nope_h,s + q_r_h,t · k_r,s) ·
+    scale``, softmax in float32 over s ≤ t (``causal``; else every s), ``o_h =
+    Σ_s p_ts v_h,s``.
+
+    q_nope, k_nope ``(B, N, H, nope)``; q_r ``(B, N, H, rot)``; k_r ``(B, N,
+    rot)``; v ``(B, N, H, vd)``; returns ``(B, N, H, vd)`` in v's dtype. Every
+    operand is read token-major where its projection wrote it and the context
+    written where the output projection reads it: no per-head ``[k_nope,
+    k_r]`` array, no head padded to another's size, nothing sliced or
+    transposed in HBM. The one thing made on the way is k_r twice over on 128
+    lanes at ``rot`` 64 (2.4 MB at 9,217 tokens against a head-wise key of
+    453 MB; it folds into whatever wrote k_r), so that the two heads of a
+    q_r lane group meet it by a lane mask. Head sizes the launch cannot
+    address are refused by name (:func:`latent_sizes`). K/V chunks above
+    the diagonal are neither fetched nor multiplied
+    (:func:`_fwd_masked_call`'s walk); blocks from the shape and a VMEM row
+    (:func:`_latent_blocks`). No backward yet: the VJP raises by name."""
+    B, N, H, nope, rot, vd = latent_sizes(q_nope, q_r, k_nope, k_r, v)
+    _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, None))
+    if rot < _LANE:
+        k_r = jnp.concatenate([k_r, k_r], axis=-1)
+    bq, bkv = _latent_blocks(N, nope, vd, v.dtype)
+    spec = rows_spec(B)
+    out = per_device(
+        functools.partial(
+            _fwd_latent_call, heads=H, scale=scale, n_valid=N, bq=bq, bkv=bkv,
+            causal=causal, interpret=kernel_interpret()),
+        (spec,) * 5, spec,
+    )(q_nope.reshape(B, N, H * nope), q_r.reshape(B, N, H * rot),
+      k_nope.reshape(B, N, H * nope), k_r, v.reshape(B, N, H * vd))
+    return out.reshape(B, N, H, vd)
+
+
+def latent_attention_xla(q_nope, q_r, k_nope, k_r, v, scale: float, *,
+                         causal: bool = True) -> jax.Array:
+    """:func:`flash_attention_latent` in plain ``jax.numpy``: the whole score
+    matrix from its two products, softmax in float32. Differentiable; the
+    oracle of the kernel and what runs off the TPU, at sizes whose ``(B, H,
+    N, N)`` scores fit. Any head sizes."""
+    N = q_nope.shape[1]
+    logits = (jnp.einsum("bnhd,bmhd->bhnm", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bnhd,bmd->bhnm", q_r, k_r,
+                           preferred_element_type=jnp.float32)) * scale
+    if causal:
+        sees = jnp.arange(N)[None, :] <= jnp.arange(N)[:, None]
+        logits = jnp.where(sees, logits, _NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhnm,bmhd->bnhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _latent_no_vjp(q_nope, q_r, k_nope, k_r, v, scale, causal):
+    return flash_attention_latent(q_nope, q_r, k_nope, k_r, v, scale,
+                                  causal=causal)
+
+
+def _latent_no_vjp_fwd(*args):
+    raise NotImplementedError(
+        "the fwd_latent kernel has no backward yet (ROADMAP Reach: a score in "
+        "two parts in dq/dkv): differentiate "
+        "ops.flash_attention.latent_attention_xla, which is what "
+        "latent_attention runs off the TPU")
+
+
+_latent_no_vjp.defvjp(_latent_no_vjp_fwd, lambda *a: None)
+
+
+def latent_attention(q_nope, q_r, k_nope, k_r, v, scale: float, *,
+                     causal: bool = True) -> jax.Array:
+    """Attention over a two-part score with a key part shared by all the
+    heads, shapes as :func:`flash_attention_latent`; the backend decides what
+    runs (``kernels.flash_latent_schedule``, ``kernel`` | ``xla``, +1 a
+    trace): that kernel on the TPU, :func:`latent_attention_xla` (plain JAX,
+    differentiable, any head sizes) anywhere else."""
+    on_chip = jax.default_backend() == "tpu"
+    _kernels.inc("kernels.flash_latent_schedule",
+                 key="kernel" if on_chip else "xla")
+    if on_chip:
+        return _latent_no_vjp(q_nope, q_r, k_nope, k_r, v, scale, causal)
+    return latent_attention_xla(q_nope, q_r, k_nope, k_r, v, scale,
+                                causal=causal)
 
 
 # ---------------------------------------------------------------------------

@@ -55,7 +55,10 @@ def create_train_state(model, rng: jax.Array, lr: float, total_steps: int,
     unnecessary) and wrap them with the optimizer. ``ema_decay`` > 0 also
     seeds an EMA shadow of the params (see :class:`EmaTrainState`)."""
     noisy, _, t = sample_batch
-    params = model.init(rng, jnp.asarray(noisy), jnp.asarray(t))["params"]
+    # one program: eagerly every initializer compiles its handful of ops once
+    # a parameter shape (19 s of a cold start at toy size, bitwise the same)
+    params = jax.jit(model.init)(rng, jnp.asarray(noisy),
+                                 jnp.asarray(t))["params"]
     state = EmaTrainState.create(
         apply_fn=model.apply, params=params, tx=make_optimizer(lr, total_steps),
         ema_params=jax.tree.map(jnp.copy, params) if ema_decay else None,

@@ -736,3 +736,44 @@ def test_fwd_selected_lowers_at_the_published_shapes_and_keeps_its_name(chip):
     assert m and [int(g) for g in m.groups()[1:]] == [n, L, H * hd]
     assert not flash_masked_fwd_roofline.NAME.match(calls[0])
     assert not flash_fwd_roofline.NAME.match(calls[0])
+
+
+# --- the latent forward at openPangu-Ultra-MoE's shapes ----------------------
+
+PANGU = dict(n=1, L=9217, heads=128, nope=128, rot=64, vd=128)
+
+
+def test_fwd_latent_lowers_at_the_published_shapes_and_keeps_its_name(chip):
+    """The two-part-score forward at 9,217 tokens, 128 heads of 128 + 64
+    query/key dims (the rotated 64 ONE row a token for all the heads) against
+    128 value dims, bf16, blocks from the shape and the VMEM row: ONE
+    ``tpu_custom_call``, named ``%fwd_latent``
+    (``benchmark/layer_metrics/flash_latent_fwd_roofline.py`` matches it by
+    that name), which the other three forwards' readers do not match; and
+    nothing beside it but k_r laid twice over: no pad, no slice, no transpose
+    of q, k or v."""
+    from benchmark.layer_metrics import flash_fwd_roofline
+    from benchmark.layer_metrics import flash_latent_fwd_roofline as reader
+    from benchmark.layer_metrics import flash_masked_fwd_roofline
+    from benchmark.layer_metrics import flash_selected_fwd_roofline
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, H, nope, rot, vd = (PANGU[k] for k in
+                              ("n", "L", "heads", "nope", "rot", "vd"))
+    bf = jnp.bfloat16
+    text = jax.jit(lambda qn, qr, kn, kr, v: fa.latent_attention(
+        qn, qr, kn, kr, v, (nope + rot) ** -0.5)).lower(
+        sds((n, L, H, nope), bf), sds((n, L, H, rot), bf),
+        sds((n, L, H, nope), bf), sds((n, L, rot), bf), sds((n, L, H, vd), bf)
+    ).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and [int(g) for g in m.groups()[1:]] == [n, L, H * vd]
+    for other in (flash_fwd_roofline, flash_masked_fwd_roofline,
+                  flash_selected_fwd_roofline):
+        assert not other.NAME.match(calls[0])
+    # every array the program makes beside the result is k_r's size
+    big = re.findall(r"= bf16\[1,9217,(\d+)\]", text)
+    assert sorted(set(map(int, big)) - {H * nope, H * rot, H * vd, rot}) == [128]

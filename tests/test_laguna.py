@@ -157,12 +157,34 @@ def _sigmoid_router_with_a_selection_bias():
     return tree, whole, layer
 
 
+def _sigmoid_router_without_a_bias():
+    """openPangu-Ultra-MoE's router on the same banks: a sigmoid a router
+    output, the top 3 of the scores chosen and weighed by them, x 2.5; the
+    uncut layer is ``reference/pangu.py``'s."""
+    from benchmark.reference import pangu as pangu_ref
+
+    tree = _uncut_tree()
+    cfg = dict(num_experts_per_tok=3, norm_topk_prob=True,
+               routed_scaling_factor=2.5)
+    whole = lambda share, y: pangu_ref.sparse_mlp(
+        _share(tree, *share), y,
+        dict(cfg, experts_held_from=share[0], n_routed_experts=share[1]))
+    layer = lambda first, held: _expert_layer(first, held, score="sigmoid")
+    # and it is not the softmax router under another name: the same experts
+    # are chosen, and weighed a tenth otherwise (outputs of 2e-3)
+    y = jax.random.normal(jax.random.PRNGKey(2), (34, 64))
+    softmax = ref.sparse_mlp(tree, y, dict(TRUNK, num_experts=16))
+    assert float(jnp.abs(softmax - whole((0, 16), y)).max()) > 1e-4
+    return tree, whole, layer
+
+
 @pytest.mark.parametrize("router,chips", [
-    (_softmax_router, 2), (_sigmoid_router_with_a_selection_bias, 16)])
+    (_softmax_router, 2), (_sigmoid_router_with_a_selection_bias, 16),
+    (_sigmoid_router_without_a_bias, 4)])
 def test_the_shares_add_up_to_the_uncut_layer(router, chips):
-    """The 16 experts over ``chips`` chips (0-7 and 8-15; or one each), the
-    shared expert computed by all and counted once, against the reference's
-    uncut layer."""
+    """The 16 experts over ``chips`` chips (0-7 and 8-15; one each; or four
+    each), the shared expert computed by all and counted once, against the
+    reference's uncut layer."""
     tree, reference, layer = router()
     held = 16 // chips
     y = jax.random.normal(jax.random.PRNGKey(2), (2, 17, 64))
