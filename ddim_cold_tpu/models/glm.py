@@ -30,6 +30,16 @@ positions 0 (class token), 1, … in raster order:
   size 256 on both sides of the product): the sampler keeps no cache, so the
   latent is a factorisation here, and multiplying the absorbed latent
   (576/512 dims a pair against 256/256) would cost 2.1x the operations.
+  k and v are each written ONCE, by ``kv_b_proj``'s own GEMMs, as the launch
+  reads them — ``(n, L, H·256)``, a head on two whole lane groups — and
+  nothing touches them after (:class:`_KeysAndValuesInPlace`): v is ``c_kv``
+  times the kernel's v columns; k is ``[c_kv, k_r]`` times the kernel's
+  k_nope columns over *placement rows*, an identity under every head's last
+  64 columns, which put the shared ``k_r`` there. The kernel keeps the
+  published column order. Not the score in two parts of the ``pangu`` stack
+  (``fwd_latent``): 192 is a lane group and a half, and widened to 256 beside
+  a 128-lane rotated group the score would take three passes of the 128-deep
+  MXU a tile where the 256 of ``[k_nope, k_r]`` take two.
 * the indexer, where ``indexer_types[layer] == "full"`` (J =
   ``index_n_heads`` heads of D = ``index_head_dim``): ``q^I = c_q W^I_q``;
   ``k^I = LayerNorm(y W^I_k)`` (one head); rotary on the first
@@ -185,45 +195,99 @@ class _DenseByColumnSets(nn.Module):
             kernel, np.cumsum(self.widths)[:-1], axis=1)]
 
 
-def latent_projections(c: Mapping[str, Any], y, rope, pairing: str, dtype,
-                       param_dtype, *, apart: bool = False):
-    """The low-rank query and key/value paths of latent attention, for every
-    stack that has them, called inside the attention module's ``__call__``
-    (the parameters are that module's: ``q_a_proj``, ``q_a_layernorm``,
-    ``q_b_proj``, ``kv_a_proj_with_mqa``, ``kv_a_layernorm``, ``kv_b_proj``):
-    ``(c_q, q, k_r, kv)`` of the layer's normed input ``y (n, L, hidden)``,
-    q's rotated parts and the ONE shared ``k_r (n, L, rot)`` rotated by
-    ``rope`` (:func:`laguna.rotary_frequencies`) under ``pairing``.
+def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
+                 param_dtype, *, apart: bool = False):
+    """The low-rank paths of latent attention up to the key/value latent, for
+    every stack that has them, called inside the attention module's
+    ``__call__`` (the parameters are that module's: ``q_a_proj``,
+    ``q_a_layernorm``, ``q_b_proj``, ``kv_a_proj_with_mqa``,
+    ``kv_a_layernorm``): ``(c_q, q, k_r, c_kv)`` of the layer's normed input
+    ``y (n, L, hidden)``, q's rotated parts and the ONE shared ``k_r (n, L,
+    rot)`` rotated by ``rope`` (:func:`laguna.rotary_frequencies`) under
+    ``pairing``, ``c_kv (n, L, kv_lora_rank)`` normed.
 
-    The two up-projections come in the column order their reader wants.
-    Published (``apart`` false): a head's parts side by side, ``q (n, L,
-    H·(nope + rot))`` and ``kv (n, L, H·(nope + vd))``. ``apart``: every part
-    an array of its own, the columns of ``q_b_proj`` as all the heads' nope
-    parts then all their rotated parts and those of ``kv_b_proj`` as all the
-    k_nope then all the v — ``q = (q_nope (n, L, H·nope), q_r (n, L,
-    H·rot))``, ``kv = (k_nope, v (n, L, H·vd))`` — so that each lies on whole
-    lanes where a kernel reads it; only q_r is touched by the rotation."""
+    q comes in the column order its reader wants. Published (``apart``
+    false): a head's parts side by side, ``q (n, L, H·(nope + rot))``.
+    ``apart``: the columns of ``q_b_proj`` as all the heads' nope parts then
+    all their rotated parts, ``q = (q_nope (n, L, H·nope), q_r (n, L,
+    H·rot))``, each on whole lanes where a kernel reads it; only q_r is
+    touched by the rotation."""
     H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
                     c["qk_rope_head_dim"])
-    vd, rank = c["v_head_dim"], c["kv_lora_rank"]
+    rank = c["kv_lora_rank"]
     kw = dict(dtype=dtype, param_dtype=param_dtype)
     dense = lambda feats, name: _dense(feats, name, **kw)
     norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
-    sets = lambda name, *widths: _DenseByColumnSets(widths, name=name, **kw)
 
     c_q = norm("q_a_layernorm")(dense(c["q_lora_rank"], "q_a_proj")(y))
     if apart:
-        q_nope, q_r = sets("q_b_proj", H * nope, H * rot)(c_q)
+        q_nope, q_r = _DenseByColumnSets((H * nope, H * rot), name="q_b_proj",
+                                         **kw)(c_q)
         q = q_nope, apply_rotary(q_r, H, *rope, pairing=pairing)
     else:
         q = apply_rotary(dense(H * (nope + rot), "q_b_proj")(c_q), H, *rope,
                          pairing=pairing, first=nope)
     kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
     k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
-    c_kv = norm("kv_a_layernorm")(kv_a[..., :rank])
-    kv = (tuple(sets("kv_b_proj", H * nope, H * vd)(c_kv)) if apart
-          else dense(H * (nope + vd), "kv_b_proj")(c_kv))
+    return c_q, q, k_r, norm("kv_a_layernorm")(kv_a[..., :rank])
+
+
+def latent_projections(c: Mapping[str, Any], y, rope, pairing: str, dtype,
+                       param_dtype, *, apart: bool = False):
+    """:func:`latent_paths` and the key/value up-projection ``kv_b_proj`` in
+    the same column order: ``(c_q, q, k_r, kv)``. Published (``apart``
+    false): ``kv (n, L, H·(nope + vd))``, a head's ``[k_nope, v]`` side by
+    side. ``apart``: the kernel's columns as all the k_nope then all the v,
+    ``kv = (k_nope (n, L, H·nope), v (n, L, H·vd))``, an array each."""
+    H, nope, vd = (c["num_attention_heads"], c["qk_nope_head_dim"],
+                   c["v_head_dim"])
+    kw = dict(dtype=dtype, param_dtype=param_dtype)
+    c_q, q, k_r, c_kv = latent_paths(c, y, rope, pairing, apart=apart, **kw)
+    kv = (tuple(_DenseByColumnSets((H * nope, H * vd), name="kv_b_proj",
+                                   **kw)(c_kv)) if apart
+          else _dense(H * (nope + vd), "kv_b_proj", **kw)(c_kv))
     return c_q, q, k_r, kv
+
+
+class _KeysAndValuesInPlace(nn.Module):
+    """``kv_b_proj`` of a stack whose key head is ``[k_nope, k_r]`` on whole
+    lane groups (192 + 64): ``(k (n, L, H·(nope + rot)), v (n, L, H·vd))`` of
+    ``c_kv (n, L, rank)`` and the shared, rotated ``k_r (n, L, rot)``, each
+    written ONCE, by its own GEMM, where the attention launch reads it. The
+    ONE kernel ``(rank, H·(nope + vd))`` stays in the published column order
+    (a head's ``[k_nope, v]`` side by side); its column sets are taken on the
+    weight, every call.
+
+    v is ``c_kv`` times the heads' v columns. k is ``[c_kv, k_r]`` times a
+    weight of ``rank + rot`` rows: the first ``rank`` hold each head's k_nope
+    columns and ``rot`` columns of zeros; the last ``rot`` are *placement
+    rows*, for every head zeros under its nope columns and the identity under
+    its last ``rot``. Column block h of the product is then ``[c_kv W_nope,h,
+    k_r]`` = k_h, bit for bit what a concatenate of the two would hold (a
+    product with 1 and sums with exact zeros in the accumulator), for ``rot``
+    more rows of contraction and ``rot`` more columns a head on the MXU."""
+
+    heads: int
+    nope: int
+    vd: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, c_kv, k_r):
+        H, nope, vd = self.heads, self.nope, self.vd
+        rank, rot = c_kv.shape[-1], k_r.shape[-1]
+        kernel = self.param("kernel", trunc_normal(std=0.02),
+                            (rank, H * (nope + vd)), self.param_dtype)
+        kernel = kernel.astype(self.dtype).reshape(rank, H, nope + vd)
+        place = jnp.eye(rot, nope + rot, k=nope, dtype=self.dtype)
+        w_k = jnp.concatenate(
+            [jnp.pad(kernel[..., :nope], ((0, 0), (0, 0), (0, rot))),
+             jnp.broadcast_to(place[:, None], (rot, H, nope + rot))])
+        c_kv, k_r = c_kv.astype(self.dtype), k_r.astype(self.dtype)
+        k = jnp.dot(jnp.concatenate([c_kv, k_r], axis=-1),
+                    w_k.reshape(rank + rot, H * (nope + rot)))
+        return k, jnp.dot(c_kv, kernel[..., nope:].reshape(rank, H * vd))
 
 
 class LatentAttention(nn.Module):
@@ -242,18 +306,16 @@ class LatentAttention(nn.Module):
         hd, vd = c["qk_head_dim"], c["v_head_dim"]
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         dense = lambda feats, name: _dense(feats, name, **kw)
-        c_q, q, k_r, kv = latent_projections(
+        c_q, q, k_r, c_kv = latent_paths(
             c, y, rotary_frequencies(c["rope_parameters"], rot),
             _pairing(c.get("rope_interleave", False)), **kw)
-        kv = kv.reshape(n, L, H, nope + vd)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_r[:, :, None, :], (n, L, H, rot))], axis=-1)
+        k, v = _KeysAndValuesInPlace(H, nope, vd, name="kv_b_proj", **kw)(
+            c_kv, k_r)
         if self.indexer:
             with jax.named_scope("trunk/dsa_index"):
                 keep = Indexer(c, name="indexer", **kw)(y, c_q)
-        out = selected_attention(q.reshape(n, L, H, hd), k, kv[..., nope:],
-                                 hd ** -0.5, keep)
+        out = selected_attention(q.reshape(n, L, H, hd), k.reshape(n, L, H, hd),
+                                 v.reshape(n, L, H, vd), hd ** -0.5, keep)
         return dense(width, "o_proj")(out.reshape(n, L, H * vd)), keep
 
 

@@ -6,9 +6,12 @@ top-3 under a sigmoid with a selection bias, experts 0-7 held; 16x16 px patch
 4) on seeded weights, against the plain reference
 (``benchmark/reference/glm.py``, which imports nothing of the program): the
 forward, borrowed selections, the interleaved rotary pairing, the DDIM
-trajectory, causality, serving, refusals, scopes and counters."""
+trajectory, causality, serving, refusals, scopes and counters; and how k and
+v reach the attention launch: written once each by ``kv_b_proj``'s own GEMMs,
+bit for bit the concatenate and the slice they replace."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -136,6 +139,132 @@ def test_a_full_layer_selects_for_itself_whatever_it_is_handed():
     assert (own == own2).all()
     assert np.asarray(keep).sum(-1).tolist() == [
         [min(t + 1, 8) for t in range(17)]]
+
+
+def _parents_keys_and_values(kernel, c_kv, k_r, H, nope, vd):
+    """k and v as the stack assembled them before: the published product, a
+    head's ``[k_nope, v]`` side by side, then ``k_h = [k_nope_h, k_r]`` by a
+    concatenate with k_r broadcast to every head, and v by a slice."""
+    n, L, rot = k_r.shape
+    kv = jnp.dot(c_kv, kernel).reshape(n, L, H, nope + vd)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r[:, :, None, :], (n, L, H, rot))],
+        axis=-1)
+    return k, kv[..., nope:]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("H,nope,rot,vd,rank", [
+    (2, 192, 64, 256, 512),  # the published split: a head on two lane groups
+    (5, 24, 8, 16, 12)])
+def test_k_and_v_leave_the_gemms_bitwise_what_concatenate_and_slice_built(
+        H, nope, rot, vd, rank, dtype):
+    """The placement rows pass k_r, as drawn, through a product with 1 and add
+    exact zeros to k_nope. c_kv and the kernel lie on a grid of quarters, so
+    every sum is exact and the comparison does not hang on the order in which
+    the CPU's GEMM adds at one shape or another."""
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    quarters = lambda key, shape: (jnp.round(4 * jax.random.normal(key, shape))
+                                   / 4).astype(dtype)
+    kernel = quarters(keys[0], (rank, H * (nope + vd)))
+    c_kv = quarters(keys[1], (2, 7, rank))
+    k_r = jax.random.normal(keys[2], (2, 7, rot), dtype)
+    k, v = glm._KeysAndValuesInPlace(H, nope, vd, dtype, dtype).apply(
+        {"params": {"kernel": kernel}}, c_kv, k_r)
+    assert k.dtype == v.dtype == dtype
+    assert k.shape == (2, 7, H * (nope + rot)) and v.shape == (2, 7, H * vd)
+    want_k, want_v = _parents_keys_and_values(kernel, c_kv, k_r, H, nope, vd)
+    for got, want in ((k, want_k), (v, want_v)):
+        assert float(jnp.abs(want.astype(jnp.float32)).min(axis=(0, 1)).max()) > 0
+        np.testing.assert_array_equal(
+            np.asarray(got.reshape(want.shape), np.float32),
+            np.asarray(want, np.float32))
+
+
+def _equations_before(jaxpr, wanted):
+    """Every equation of ``jaxpr`` (and of the closed jaxprs inside those)
+    that the variables ``wanted`` are computed from."""
+    found = []
+    for eqn in reversed(jaxpr.eqns):
+        if not any(v in wanted for v in eqn.outvars):
+            continue
+        found.append(eqn)
+        wanted |= {v for v in eqn.invars
+                   if not isinstance(v, jax.extend.core.Literal)}
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _equations_before(inner, set(inner.outvars))
+    return found
+
+
+def test_nothing_of_a_head_wise_keys_size_is_moved_between_kv_b_proj_and_the_launch(
+        monkeypatch):
+    """What stands in for a counter (the layout has no second path to count):
+    in the jaxpr of one layer, nothing that k and v are computed from is a
+    ``concatenate``, ``broadcast_in_dim``, ``slice`` or ``pad`` of ``n·L·H·
+    nope`` elements or more — the two GEMMs write what the launch reads."""
+    model, params = model_and_params("float32")
+
+    @jax.jit
+    def the_launch(q, k, v, keep):
+        return q + k + v
+
+    monkeypatch.setattr(glm, "selected_attention",
+                        lambda q, k, v, scale, keep: the_launch(q, k, v, keep))
+    layer = glm.GlmLayer(model.trunk, 1)  # published layer 3: shared, sparse
+    n, L, H, nope = 3, 17, 4, 24
+    x = jnp.zeros((n, L, 64))
+    jaxpr = jax.make_jaxpr(lambda p, x, keep: layer.apply({"params": p}, x, keep))(
+        params["layers_1"], x, jnp.ones((n, 24, 24), jnp.int8)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.params.get("name") == "the_launch"]
+    q, k, v, _ = call.invars
+    assert k.aval.shape == v.aval.shape == (n, L, H, 32)
+    before = _equations_before(jaxpr, {k, v})
+    names = {e.primitive.name for e in before}
+    assert {"dot_general", "concatenate"} <= names  # the walk saw the GEMMs
+    moved = [(e.primitive.name, var.aval.shape) for e in before
+             if e.primitive.name in ("concatenate", "broadcast_in_dim", "slice",
+                                     "pad", "gather", "dynamic_slice")
+             for var in (*e.invars, *e.outvars)
+             if np.prod(var.aval.shape) >= n * L * H * nope]
+    assert not moved, moved
+    # the walk does tell: q, rotated as a whole array, is rolled by slices
+    rolled = [e for e in _equations_before(jaxpr, {q})
+              if e.primitive.name in ("slice", "concatenate")
+              and np.prod(e.outvars[0].aval.shape) >= n * L * H * nope]
+    assert rolled
+
+
+def test_the_parameter_tree_keeps_the_published_names_shapes_and_column_order():
+    """``kv_b_proj/kernel`` is ``(rank, H·(nope + vd))`` and column
+    ``h·(nope + vd) + j`` is what output j of head h reads: k_nope for j <
+    nope, v after."""
+    model, params = model_and_params("float32")
+    x, t = inputs(1)
+    made = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), x, t))
+    shapes = lambda tree: {jax.tree_util.keystr(k): v.shape for k, v in
+                           jax.tree_util.tree_leaves_with_path(tree)}
+    assert shapes(made["params"]) == shapes(params)
+    attn = params["layers_1"]["self_attn"]
+    assert sorted(attn) == ["kv_a_layernorm", "kv_a_proj_with_mqa", "kv_b_proj",
+                            "o_proj", "q_a_layernorm", "q_a_proj", "q_b_proj"]
+    H, nope, rot, vd, rank = 4, 24, 8, 32, 16
+    assert attn["kv_b_proj"]["kernel"].shape == (rank, H * (nope + vd))
+    assert attn["q_b_proj"]["kernel"].shape == (32, H * (nope + rot))
+    kernel = attn["kv_b_proj"]["kernel"]
+    c_kv = jax.random.normal(jax.random.PRNGKey(2), (1, 5, rank))
+    k_r = jax.random.normal(jax.random.PRNGKey(3), (1, 5, rot))
+    run = lambda kernel: glm._KeysAndValuesInPlace(H, nope, vd).apply(
+        {"params": {"kernel": kernel}}, c_kv, k_r)
+    k, v = (a.reshape(1, 5, H, 32) for a in run(kernel))
+    for h, j in ((0, 0), (2, 23), (1, 24), (3, 55)):
+        column = h * (nope + vd) + j
+        k2, v2 = (a.reshape(1, 5, H, 32) for a in run(
+            kernel.at[:, column].add(1.0)))
+        moved = (np.abs(np.asarray(k2 - k)).max((0, 1)) > 0,
+                 np.abs(np.asarray(v2 - v)).max((0, 1)) > 0)
+        want = np.zeros((2, H, 32), bool)
+        want[(0, h, j) if j < nope else (1, h, j - nope)] = True
+        assert (np.stack(moved) == want).all(), (h, j)
 
 
 @pytest.mark.parametrize("heads,first,pairing", [
