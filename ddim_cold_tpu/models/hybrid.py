@@ -14,7 +14,11 @@ experts of which this chip holds a share) or ``glm_moe_dsa``
 keys that some layers compute and the others borrow, sigmoid-scored
 experts) or ``pangu_ultra_moe`` (``models/pangu.py``: the same latent
 projections attending to every causal pair, value heads of another size than
-the query/key heads, a second norm on every sub-layer's result). One wrapper
+the query/key heads, a second norm on every sub-layer's result) or
+``nemotron_h`` (``models/nemotron.py``: one sub-layer a layer, its kind read
+from a pattern string: Mamba-2 mixers whose matrix state is scanned over
+chunks, attention without a position term, ungated experts in a latent of
+their own width). One wrapper
 serves all: what the samplers and the engine read of a
 model, ``clone``, the refusals and ``__call__`` below.
 
@@ -81,9 +85,9 @@ REFUSED = {
                    "a trunk's experts are its own (trunk: num_experts)",
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
-                 "layers are dense XLA attention, the laguna, glm_moe_dsa "
-                 "and pangu_ultra_moe stacks run their flash forwards "
-                 "wherever the backend is a TPU",
+                 "layers are dense XLA attention, the laguna, glm_moe_dsa, "
+                 "pangu_ultra_moe and nemotron_h stacks run their flash "
+                 "forwards wherever the backend is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -139,13 +143,15 @@ class RMSNorm(nn.Module):
         return (xf * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def _dt_bias_init(lo: float = 1e-3, hi: float = 1e-1):
+def _dt_bias_init(lo: float = 1e-3, hi: float = 1e-1, floor: float = 0.0):
     """Mamba's published initialisation of ``dt_proj.bias``: the inverse
-    softplus of a Δ drawn log-uniformly in [lo, hi]."""
+    softplus of a Δ drawn log-uniformly in [lo, hi], no smaller than
+    ``floor``."""
 
     def init(key, shape, dtype=jnp.float32):
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
                                         math.log(lo), math.log(hi)))
+        dt = jnp.maximum(dt, floor)
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
     return init
@@ -154,6 +160,24 @@ def _dt_bias_init(lo: float = 1e-3, hi: float = 1e-1):
 def _a_log_init(key, shape, dtype=jnp.float32):
     states = jnp.arange(1, shape[1] + 1, dtype=jnp.float32)
     return jnp.broadcast_to(jnp.log(states), shape).astype(dtype)
+
+
+def causal_conv_silu(module: nn.Module, u, taps: int, bias: bool):
+    """``u_t ← SiLU(b + Σ_j w_j ⊙ u_{t−taps+1+j})`` over ``u (n, L, d)``:
+    the depthwise causal convolution of a Mamba mixer, in float32, result in
+    the module's ``dtype``; ``conv1d_kernel (taps, d)`` and, with ``bias``,
+    ``conv1d_bias (d,)`` are ``module``'s parameters (called from its
+    compact ``__call__``)."""
+    d, L = u.shape[-1], u.shape[1]
+    w = module.param("conv1d_kernel", torch_default_uniform(taps), (taps, d),
+                     module.param_dtype).astype(jnp.float32)
+    past = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    conv = sum(w[j] * past[:, j:j + L] for j in range(taps))
+    if bias:
+        conv = conv + module.param(
+            "conv1d_bias", nn.initializers.zeros_init(), (d,),
+            module.param_dtype).astype(jnp.float32)
+    return jax.nn.silu(conv).astype(module.dtype)
 
 
 class MambaMixer(nn.Module):
@@ -166,7 +190,6 @@ class MambaMixer(nn.Module):
         c = self.trunk
         d = c["mamba_expand"] * c["hidden_size"]
         s, k, r = c["mamba_d_state"], c["mamba_d_conv"], c["mamba_dt_rank"]
-        L = x.shape[1]
         dense = lambda feats, bias, name: nn.Dense(
             feats, use_bias=bias, dtype=self.dtype,
             param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
@@ -175,15 +198,7 @@ class MambaMixer(nn.Module):
                                     self.param_dtype, name=name)
         u, z = jnp.split(dense(2 * d, c["mamba_proj_bias"], "in_proj")(x), 2, -1)
 
-        w = self.param("conv1d_kernel", torch_default_uniform(k), (k, d),
-                       self.param_dtype).astype(jnp.float32)
-        past = jnp.pad(u.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-        conv = sum(w[j] * past[:, j:j + L] for j in range(k))
-        if c["mamba_conv_bias"]:
-            conv = conv + self.param(
-                "conv1d_bias", nn.initializers.zeros_init(), (d,),
-                self.param_dtype).astype(jnp.float32)
-        u = jax.nn.silu(conv).astype(self.dtype)
+        u = causal_conv_silu(self, u, k, c["mamba_conv_bias"])
 
         delta, B, C = jnp.split(dense(r + 2 * s, False, "x_proj")(u),
                                 (r, r + s), -1)
@@ -244,6 +259,26 @@ class GatedMlp(nn.Module):
         return dense(c["hidden_size"], "down_proj")(hidden)
 
 
+class SquaredReluMlp(nn.Module):
+    """The ungated MLP ``W_down relu(W_up x)²`` (``mlp_hidden_act: relu2``),
+    sized as :class:`GatedMlp` is."""
+
+    trunk: Mapping[str, Any]
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.trunk
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        hidden = jnp.square(jax.nn.relu(
+            dense(c["intermediate_size"], "up_proj")(x)))
+        return dense(c["hidden_size"], "down_proj")(hidden)
+
+
 def is_attention_layer(trunk: Mapping[str, Any], i: int) -> bool:
     return i % trunk["attn_layer_period"] == trunk["attn_layer_offset"]
 
@@ -297,6 +332,13 @@ def layer(trunk, i: int, dtype, param_dtype, name: str) -> nn.Module:
                        name=name)
 
 
+def norm_eps(trunk: Mapping[str, Any]) -> float:
+    """ε of the stack's RMSNorms under the key its ``config.json`` has:
+    ``rms_norm_eps``, or ``nemotron_h``'s ``layer_norm_epsilon``."""
+    return (trunk["rms_norm_eps"] if "rms_norm_eps" in trunk
+            else trunk["layer_norm_epsilon"])
+
+
 def stack_of(trunk: Mapping[str, Any]) -> tuple:
     """``(check_trunk, layer)`` of ``trunk``'s layer stack, by the published
     ``model_type``."""
@@ -315,9 +357,13 @@ def stack_of(trunk: Mapping[str, Any]) -> tuple:
         from ddim_cold_tpu.models import pangu
 
         return pangu.check_trunk, pangu.layer
+    if model_type == "nemotron_h":
+        from ddim_cold_tpu.models import nemotron
+
+        return nemotron.check_trunk, nemotron.layer
     raise ValueError(f"no layer stack for model_type {model_type!r}: 'jamba', "
-                     "'laguna', 'glm_moe_dsa' and 'pangu_ultra_moe' are "
-                     "written")
+                     "'laguna', 'glm_moe_dsa', 'pangu_ultra_moe' and "
+                     "'nemotron_h' are written")
 
 
 def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:
@@ -402,6 +448,6 @@ class HybridDenoiser(nn.Module):
                            name=f"layers_{i}")(tokens, *handed)
             tokens, handed = ((out[0], out[1:]) if isinstance(out, tuple)
                               else (out, ()))
-        tokens = RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
+        tokens = RMSNorm(norm_eps(self.trunk), self.dtype,
                          self.param_dtype, name="final_layernorm")(tokens)
         return vit.pixel_head(self, tokens, param_dtype=self.param_dtype)

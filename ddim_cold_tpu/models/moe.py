@@ -161,7 +161,9 @@ class SwitchMlp(nn.Module):
 
 
 class HeldExpertsMlp(nn.Module):
-    """Top-k routed gated-SiLU experts plus a shared one, computed by a chip
+    """Top-k routed experts (gated SiLU, or ungated squared ReLU) plus a
+    shared one, at the residual width or in a latent of their own, computed
+    by a chip
     that is TOLD WHICH EXPERTS IT HOLDS: of ``num_routed`` experts, the
     ``num_held`` from ``first_held`` on. The expert-parallel share of a layer,
     without its exchange.
@@ -178,6 +180,14 @@ class HeldExpertsMlp(nn.Module):
     left out: the shares of all the chips, the shared expert counted once,
     sum to the whole layer (tested).
 
+    ``hidden_act="relu2"``: ``Shared`` and every ``E_e`` the UNGATED MLP
+    ``W_down relu(W_up ·)²``. ``latent_features``: the routed experts live in
+    a latent of that width, narrower than the residual stream: ``ℓ = y W_ℓin``
+    once a layer, ``E_e`` on ℓ at ``latent_features → hidden_features →
+    latent_features``, and out ``= Shared(y) + (Σ_{e ∈ S ∩ held} w_e E_e(ℓ))
+    W_ℓout``; the router and the shared expert stay on the full width.
+    ``W_ℓout`` has no bias, so the shares still sum to the whole layer.
+
     No capacity, no dropped assignment: every (row, expert, weight) triple of
     the ``rows · top_k`` routed is kept in a buffer of exactly that many rows
     (the worst case, every row routed to held experts only), sorted by expert
@@ -186,14 +196,18 @@ class HeldExpertsMlp(nn.Module):
     tiles past the last held group and writes them as zeros. The sorted rows
     are gathered once and go through ``ops.grouped_matmul.grouped_mlp``: two
     launches on the TPU, one for gate, up and ``SiLU(g) ⊙ u`` (both products
-    and the SiLU in float32, one rounding), one for down. Each row then adds
+    and the SiLU in float32, one rounding; ungated: up and ``relu(u)²``), one
+    for down. Each row then adds
     up its own ``top_k`` results by position (a gather by the inverse
     permutation, weighted and summed in float32: no scatter).
 
     Parameters: ``router (hidden, num_routed)``; ``gate_proj``,
     ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
     width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp``;
-    ``e_score_correction_bias (num_routed,)`` with ``selection_bias``."""
+    ``e_score_correction_bias (num_routed,)`` with ``selection_bias``.
+    Ungated: no ``gate_proj``, ``shared_expert`` a ``hybrid.SquaredReluMlp``.
+    In a latent: ``fc1_latent_proj``, ``fc2_latent_proj`` (a Dense each), and
+    the banks' ``hidden`` is ``latent_features``."""
 
     num_routed: int
     top_k: int
@@ -205,16 +219,22 @@ class HeldExpertsMlp(nn.Module):
     norm_topk: bool = True
     score: str = "softmax"
     selection_bias: bool = False
+    hidden_act: str = "silu"
+    latent_features: int | None = None
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        from ddim_cold_tpu.models.hybrid import GatedMlp
+        from ddim_cold_tpu.models.hybrid import GatedMlp, SquaredReluMlp
 
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {self.score!r}: 'softmax' and 'sigmoid' "
                              "are written")
+        if self.hidden_act not in ("silu", "relu2"):
+            raise ValueError(f"hidden_act {self.hidden_act!r}: 'silu' (gated) "
+                             "and 'relu2' (ungated) are written")
+        gated = self.hidden_act == "silu"
         *lead, D = x.shape
         y = x.reshape(-1, D)
         T, k, G, F = y.shape[0], self.top_k, self.num_held, self.hidden_features
@@ -222,7 +242,7 @@ class HeldExpertsMlp(nn.Module):
             raise ValueError(
                 f"experts {self.first_held}..{self.first_held + G - 1} held, "
                 f"{k} a token, of {self.num_routed} routed")
-        shared = GatedMlp(
+        shared = (GatedMlp if gated else SquaredReluMlp)(
             {"hidden_size": D, "intermediate_size": self.shared_features},
             self.dtype, self.param_dtype, name="shared_expert")(y)
 
@@ -257,15 +277,27 @@ class HeldExpertsMlp(nn.Module):
         # assignments read row 0 and lie past every group
         M = tiling.round_up(T * k, 128)
         source = jnp.pad(order // k, (0, M - T * k))
-        rows = y[source]
+        latent = self.latent_features is not None
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
+            name=name)
+        # what the experts read, K wide: the rows, or their latent
+        z = dense(self.latent_features, "fc1_latent_proj")(y) if latent else y
+        K = z.shape[-1]
+        rows = z[source]
 
-        out = grouped_mlp(rows, param("gate_proj", (G, D, F)),
-                          param("up_proj", (G, D, F)),
-                          param("down_proj", (G, F, D)), group_sizes)
+        out = grouped_mlp(rows, param("gate_proj", (G, K, F)) if gated else None,
+                          param("up_proj", (G, K, F)),
+                          param("down_proj", (G, F, K)), group_sizes)
 
         where = jnp.argsort(order).reshape(T, k)  # a's place among the sorted
         weight = jnp.where(held, weight, 0.0)
-        total = shared.astype(jnp.float32)
+        total = (jnp.zeros((T, K), jnp.float32) if latent
+                 else shared.astype(jnp.float32))
         for j in range(k):
             total += weight[:, j, None] * out[where[:, j]].astype(jnp.float32)
+        if latent:
+            total = shared.astype(jnp.float32) + dense(D, "fc2_latent_proj")(
+                total.astype(self.dtype)).astype(jnp.float32)
         return total.astype(self.dtype).reshape(*lead, D)

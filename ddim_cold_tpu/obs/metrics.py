@@ -131,6 +131,10 @@ METRICS = (
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),
+    # -- kernels (ops/ssd.py, counted once a trace) -------------------------
+    ("kernels.ssd_schedule", "counter",
+     "state-space-dual (chunked matrix-state) scan traces by path (key: "
+     "kernel|xla)"),
     # -- kernels (models/vit.Block, counted once a trace) -----------------
     ("kernels.block_tokenwise", "counter",
      "ViT block traces by the path of the token-wise half (key: kernel|xla)"),

@@ -11,10 +11,16 @@ first half of the experts' gated MLP as ONE such pass over the rows
     h[r] = SiLU(rows[r] @ w_gate[g]) * (rows[r] @ w_up[g])     (0 past the groups)
 
 both products accumulated, and the SiLU and the product between them taken, in
-float32: one rounding, on the store. :func:`grouped_mlp` is the whole MLP,
-``h`` then ``h @ w_down[g]``. The backend decides what runs. On the TPU a
-Pallas kernel (``pallas_call(name="moe_gmm")``, ``%moe_gmm`` in a device
-trace: one launch a product, ONE for gate and up, so two an expert layer);
+float32: one rounding, on the store. An UNGATED expert's first half
+(:func:`grouped_relu2`) is the one product under a squared ReLU,
+
+    h[r] = relu(rows[r] @ w_up[g])²                            (0 past the groups)
+
+in the same launch, the square taken of the float32 product.
+:func:`grouped_mlp` is the whole MLP, ``h`` then ``h @ w_down[g]``. The
+backend decides what runs. On the TPU a Pallas kernel
+(``pallas_call(name="moe_gmm")``, ``%moe_gmm`` in a device trace: one launch
+a product, ONE for gate and up, so two an expert layer);
 anywhere else, and as the tests' oracle, ``ragged_dot`` itself (plain JAX,
 differentiable).
 
@@ -96,6 +102,14 @@ def grouped_gate_up_xla(rows, w_gate, w_up, group_sizes):
     return (jax.nn.silu(g) * u).astype(rows.dtype)
 
 
+def grouped_relu2_xla(rows, w_up, group_sizes):
+    """One ``ragged_dot`` and the square of its ReLU in float32, one rounding
+    to ``rows``' dtype. ``w_up``: ``(G, K, F)``."""
+    u = jax.lax.ragged_dot(rows, w_up, group_sizes.astype(jnp.int32),
+                           preferred_element_type=jnp.float32)
+    return jnp.square(jax.nn.relu(u)).astype(rows.dtype)
+
+
 def _tiles(M: int, K: int, N: int, dtype) -> tuple:
     """(tile_m, tile_n) for ``(M, K) @ (G, K, N)`` of ``dtype``; see the
     module docstring for the rule."""
@@ -168,10 +182,10 @@ def _work_items(group_sizes, *, n_rows: int, tile_m: int):
 
 
 def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
-                x_ref, *refs, n_groups: int, zero_tail: bool):
+                x_ref, *refs, n_groups: int, zero_tail: bool, relu2: bool):
     """One (column tile, work item) program; see the module docstring. One
-    weight operand: the product. Two, gate then up: ``SiLU(g) * u`` of the two
-    float32 products."""
+    weight operand: the product, or with ``relu2`` the square of its ReLU.
+    Two, gate then up: ``SiLU(g) * u`` of the two float32 products."""
     del read_ref  # the index maps' business
     *w_refs, o_ref = refs
     item = pl.program_id(1)
@@ -192,6 +206,8 @@ def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
                         for w_ref in w_refs]
             if up:
                 acc = jax.nn.silu(acc) * up[0]
+            elif relu2:
+                acc = jnp.square(jnp.maximum(acc, 0.0))
             o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), kept)
 
         if zero_tail:
@@ -201,7 +217,7 @@ def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
 
 
 def _launch(rows, ws, group_sizes, *, tiles, vmem_limit=None, zero_tail=True,
-            interpret=None):
+            relu2=False, interpret=None):
     """``pallas_call(name="moe_gmm")`` of ``rows (M, K)`` against the weight
     operands ``ws``, each ``(G, K, N)``, at ``tiles`` (tile_m, tile_n). Rows
     are padded to whole tiles when they are not (the expert layer sizes its
@@ -220,7 +236,8 @@ def _launch(rows, ws, group_sizes, *, tiles, vmem_limit=None, zero_tail=True,
     weights = pl.BlockSpec(
         (1, K, tn), lambda n, i, grp, *_: (jnp.minimum(grp[i], G - 1), 0, n))
     out = pl.pallas_call(
-        functools.partial(_gmm_kernel, n_groups=G, zero_tail=zero_tail),
+        functools.partial(_gmm_kernel, n_groups=G, zero_tail=zero_tail,
+                          relu2=relu2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(N // tn, m_pad // tm + G),
@@ -267,6 +284,15 @@ def grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, *, zero_tail=True,
                    interpret=interpret)
 
 
+def grouped_relu2_kernel(rows, w_up, group_sizes, *, zero_tail=True,
+                         tiles=None, interpret=None):
+    """The Pallas path of :func:`grouped_relu2`: the product's launch with the
+    squared ReLU on its float32 result, at the product's tiles."""
+    tiles = tiles or _tiles(*rows.shape, w_up.shape[2], rows.dtype)
+    return _launch(rows, (w_up,), group_sizes, tiles=tiles,
+                   zero_tail=zero_tail, relu2=True, interpret=interpret)
+
+
 def _no_vjp(kernel, differentiable: str):
     """``kernel`` as a function that says by name why it will not
     differentiate."""
@@ -289,6 +315,10 @@ _gate_up_no_vjp = _no_vjp(grouped_gate_up_kernel, "grouped_gate_up_xla")
 _gate_up_open_tail_no_vjp = _no_vjp(
     functools.partial(grouped_gate_up_kernel, zero_tail=False),
     "grouped_gate_up_xla")
+_relu2_no_vjp = _no_vjp(grouped_relu2_kernel, "grouped_relu2_xla")
+_relu2_open_tail_no_vjp = _no_vjp(
+    functools.partial(grouped_relu2_kernel, zero_tail=False),
+    "grouped_relu2_xla")
 
 
 def grouped_matmul(rows, w, group_sizes):
@@ -303,32 +333,47 @@ def grouped_matmul(rows, w, group_sizes):
     return grouped_matmul_xla(rows, w, group_sizes)
 
 
-def _first_half(launch, rows, w_gate, w_up, group_sizes):
-    """``launch`` on the TPU, the XLA composition elsewhere; counted once as
-    a first half and twice as a product."""
+def _first_half(launch, rows, ws, group_sizes):
+    """``launch`` on the TPU, the XLA composition elsewhere, of the weight
+    operands ``ws``: (gate, up), counted once as a gated first half and twice
+    as a product, or (up,) alone, the ungated half: one product."""
     use_kernel = jax.default_backend() == "tpu"
-    _kernels.inc("kernels.moe_gate_up_schedule",
-                 key="fused" if use_kernel else "xla")
-    _kernels.inc("kernels.moe_gmm_schedule", 2,
+    if len(ws) == 2:
+        _kernels.inc("kernels.moe_gate_up_schedule",
+                     key="fused" if use_kernel else "xla")
+    _kernels.inc("kernels.moe_gmm_schedule", len(ws),
                  key="kernel" if use_kernel else "xla")
     if use_kernel:
         with jax.named_scope("moe_gmm"):
-            return launch(rows, w_gate, w_up, group_sizes)
-    return grouped_gate_up_xla(rows, w_gate, w_up, group_sizes)
+            return launch(rows, *ws, group_sizes)
+    xla = grouped_gate_up_xla if len(ws) == 2 else grouped_relu2_xla
+    return xla(rows, *ws, group_sizes)
 
 
 def grouped_gate_up(rows, w_gate, w_up, group_sizes):
     """``h`` of the module docstring's contract, ``(M, F)`` in ``rows``'
     dtype: float32 accumulation, SiLU and product on either path, one launch
     on the TPU."""
-    return _first_half(_gate_up_no_vjp, rows, w_gate, w_up, group_sizes)
+    return _first_half(_gate_up_no_vjp, rows, (w_gate, w_up), group_sizes)
+
+
+def grouped_relu2(rows, w_up, group_sizes):
+    """The ungated ``h`` of the module docstring's contract, ``(M, F)`` in
+    ``rows``' dtype: float32 accumulation, ReLU and square on either path, one
+    launch on the TPU."""
+    return _first_half(_relu2_no_vjp, rows, (w_up,), group_sizes)
 
 
 def grouped_mlp(rows, w_gate, w_up, w_down, group_sizes):
     """The experts' whole MLP, ``out[r] = h[r] @ w_down[g]`` with ``h`` of
-    the module docstring's contract: ``(M, K)``, zero past the last group.
-    Two launches on the TPU, and because ``h`` lives only between them, the
-    first leaves the row tiles past the last group unwritten: the second
-    reads no row tile beyond the last group's (:func:`_work_items`)."""
-    h = _first_half(_gate_up_open_tail_no_vjp, rows, w_gate, w_up, group_sizes)
+    the module docstring's contract, the gated one or, where ``w_gate`` is
+    None, the ungated: ``(M, K)``, zero past the last group. Two launches on
+    the TPU, and because ``h`` lives only between them, the first leaves the
+    row tiles past the last group unwritten: the second reads no row tile
+    beyond the last group's (:func:`_work_items`)."""
+    if w_gate is None:
+        h = _first_half(_relu2_open_tail_no_vjp, rows, (w_up,), group_sizes)
+    else:
+        h = _first_half(_gate_up_open_tail_no_vjp, rows, (w_gate, w_up),
+                        group_sizes)
     return grouped_matmul(h, w_down, group_sizes)

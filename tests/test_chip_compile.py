@@ -18,6 +18,7 @@ tests steer them — not an option of the program.
 
 import os
 import re
+import types
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -777,3 +778,77 @@ def test_fwd_latent_lowers_at_the_published_shapes_and_keeps_its_name(chip):
     # every array the program makes beside the result is k_r's size
     big = re.findall(r"= bf16\[1,9217,(\d+)\]", text)
     assert sorted(set(map(int, big)) - {H * nope, H * rot, H * vd, rot}) == [128]
+
+
+# --- the chunked scan and the latent experts at Nemotron-3-Super's shapes ----
+
+NEMOTRON = dict(n=1, L=16385, heads=128, head_dim=64, states=128, groups=8,
+                chunk=128, held=128, latent=1024, width=2688, top=22)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_ssd_chunk_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip):
+    """``ops/ssd.py`` at 16,385 tokens (128 chunks of 128 and one token: the
+    sequence ends inside the last block, nothing is padded), 128 heads of 64
+    channels x 128 states in 8 groups: ONE ``tpu_custom_call``, named
+    ``%ssd_chunk`` (``benchmark/layer_metrics/ssd_chunk_roofline.py`` matches
+    it by that name and tells a launch that applies the gate by its operands:
+    this one has six), result ``[images, tokens, heads x channels]``; x, B and
+    C reach it as they are, no copy of their size beside it."""
+    from benchmark.layer_metrics import ssd_chunk_roofline as reader
+    from ddim_cold_tpu.ops import ssd
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, H, P_, N, G, Q = (NEMOTRON[k] for k in (
+        "n", "L", "heads", "head_dim", "states", "groups", "chunk"))
+    wide, shared = sds((n, L, H * P_), dtype), sds((n, L, G * N), dtype)
+    per_head = sds((H,), jnp.float32)
+    text = jax.jit(lambda *a: ssd.ssd_scan(*a, groups=G, chunk=Q)).lower(
+        wide, sds((n, L, H), jnp.float32), per_head, shared, shared, per_head
+    ).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and int(m.group(2)) == n
+    assert f"[{n},{L},{H * P_}]" in calls[0].split(" custom-call(")[0]
+    trace = types.SimpleNamespace(devices={0: {"ops": [(0, 1000, calls[0])]}})
+    (images, gated, _), = reader.events(types.SimpleNamespace(trace=trace))
+    assert (images, gated) == (n, False)
+
+
+@pytest.mark.parametrize("K,N,whole", [
+    (1024, 2688, False),   # up, under its squared ReLU
+    (2688, 1024, False),   # down
+    (1024, 2688, True),    # the layer's two launches, open tail
+])
+def test_moe_gmm_lowers_at_the_latent_experts_shapes_and_keeps_its_name(
+        K, N, whole, chip):
+    """The ungated expert MLP over 128 held experts in a 1,024-wide latent,
+    the buffer of every assignment of 16,385 tokens x 22 (360,576 rows in
+    whole tiles), tiles from the shape: ONE ``tpu_custom_call`` a product,
+    named ``%moe_gmm`` with result ``[buffer rows, N]``, as
+    ``moe_gmm_roofline``'s events (which ``moe_gmm_latent_roofline`` reads)
+    match it."""
+    from benchmark.layer_metrics import moe_gmm_roofline as reader
+    from ddim_cold_tpu.ops import grouped_matmul as gm
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    held, top, L = (NEMOTRON[k] for k in ("held", "top", "L"))
+    rows = -(-L * top // 128) * 128
+    assert rows == 360576
+    bank = sds((held, K, N), jnp.bfloat16)
+    sizes = sds((held,), jnp.int32)
+    if whole:
+        fn = lambda r, up, down, s: gm.grouped_mlp(r, None, up, down, s)
+        args = (sds((rows, K), jnp.bfloat16), bank,
+                sds((held, N, K), jnp.bfloat16), sizes)
+    else:
+        fn = gm.grouped_relu2 if K < N else gm.grouped_matmul
+        args = (sds((rows, K), jnp.bfloat16), bank, sizes)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    shapes = [[int(g) for g in reader.NAME.match(call).groups()[1:]]
+              for call in calls]
+    assert shapes == [[rows, N]] + [[rows, K]] * whole

@@ -363,7 +363,7 @@ def test_gradients_flow_off_the_chip():
     (dict(indexer_types=["full", "full", "full", "local"] + ["shared"] * 4),
      "local"),
     (dict(experts_held_from=9), "held of 16 routed"),
-    (dict(model_type="llama"), "'jamba', 'laguna', 'glm_moe_dsa' and"),
+    (dict(model_type="llama"), "'jamba', 'laguna', 'glm_moe_dsa', 'pangu"),
 ])
 def test_what_the_stack_cannot_run_is_refused_at_construction(change, match):
     with pytest.raises(ValueError, match=match):

@@ -1,8 +1,9 @@
 """ops/grouped_matmul.py: the ``moe_gmm`` kernel in interpreter mode against
 ``jax.lax.ragged_dot`` — empty, one-row and tile-straddling groups, rows past
 the last group — as the one product and as the expert MLP's first half (gate
-and up in one launch, ``SiLU(g) * u``); its work list, its tiles, its counters
-and its refusal to differentiate."""
+and up in one launch, ``SiLU(g) * u``; or ungated, ``relu(u)²`` of the one
+product); its work list, its tiles, its counters and its refusal to
+differentiate."""
 
 import jax
 import jax.numpy as jnp
@@ -41,13 +42,24 @@ GROUPS = [
 ]
 
 
-@pytest.mark.parametrize("launch", ["product", "gate_up"])
+@pytest.mark.parametrize("launch", ["product", "gate_up", "relu2"])
 @pytest.mark.parametrize("sizes,M,tiles", GROUPS)
 def test_kernel_matches_ragged_dot(sizes, M, tiles, launch):
     if launch == "product":
         rows, w, group_sizes = _operands(M, sizes)
         want = gm.grouped_matmul_xla(rows, w, group_sizes)
         got = gm.grouped_matmul_kernel(rows, w, group_sizes, tiles=tiles)
+        tol = 1e-5
+    elif launch == "relu2":
+        # the ungated first half against its XLA form, written out
+        rows, w_up, group_sizes = _operands(M, sizes)
+        rows = rows * 0.25
+        u = jax.lax.ragged_dot(rows, w_up, group_sizes)
+        want = jnp.where(u > 0, u * u, 0.0)
+        np.testing.assert_array_equal(
+            gm.grouped_relu2_xla(rows, w_up, group_sizes), want)
+        got = gm.grouped_relu2_kernel(rows, w_up, group_sizes, tiles=tiles)
+        assert float(jnp.abs(want - u).max() if sum(sizes) else 1.0) > 0.1
         tol = 1e-5
     else:
         rows, w_gate, w_up, group_sizes = _operands(M, sizes, banks=2)
@@ -65,9 +77,27 @@ def test_kernel_matches_ragged_dot(sizes, M, tiles, launch):
     assert not np.asarray(got[sum(sizes):]).any()
 
 
-@pytest.mark.parametrize("launch", ["product", "gate_up"])
+@pytest.mark.parametrize("launch", ["product", "gate_up", "relu2"])
 def test_kernel_in_bfloat16_accumulates_in_float32(launch):
     bf16 = jnp.bfloat16
+    if launch == "relu2":
+        rows, w_up, group_sizes = _operands(256, [100, 28, 60], K=256, N=128,
+                                            dtype=bf16)
+        rows = rows * 0.0625
+        got = gm.grouped_relu2_kernel(rows, w_up, group_sizes)
+        assert got.dtype == bf16
+        # the square is taken of the float32 product: one rounding, where
+        # squaring a rounded product makes two
+        exact = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+            rows.astype(jnp.float32), w_up.astype(jnp.float32), group_sizes)))
+        u = gm.grouped_matmul_xla(rows, w_up, group_sizes)
+        steps = jnp.square(jax.nn.relu(u))
+        err = lambda h: float(jnp.sqrt(jnp.mean(
+            (h.astype(jnp.float32) - exact) ** 2)))
+        assert err(got) < 0.75 * err(steps)
+        np.testing.assert_allclose(got.astype(jnp.float32), exact,
+                                   rtol=1e-2, atol=1e-2)
+        return
     if launch == "product":
         rows, w, group_sizes = _operands(256, [100, 28, 60], K=256, N=128,
                                          dtype=bf16)
@@ -185,14 +215,21 @@ def test_the_two_launches_do_not_read_what_the_first_left_unwritten(
          "kernels.moe_gate_up_schedule": {"xla": 1}}),
     (3, {"kernels.moe_gmm_schedule": {"xla": 3},   # the whole MLP: + down
          "kernels.moe_gate_up_schedule": {"xla": 1}}),
+    # the ungated MLP: up under its squared ReLU and down, no gated half
+    (0, {"kernels.moe_gmm_schedule": {"xla": 2}}),
 ])
 def test_counter_says_which_path_a_trace_took_and_the_cpu_takes_ragged_dot(
         banks, counted):
     from ddim_cold_tpu.obs import metrics
 
     metrics.reset()
-    rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], N=32, banks=banks)
-    if banks == 1:
+    rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], N=32,
+                                       banks=banks or 2)
+    if banks == 0:
+        got = gm.grouped_mlp(rows, None, *ws, group_sizes)
+        want = gm.grouped_matmul_xla(
+            gm.grouped_relu2_xla(rows, ws[0], group_sizes), ws[1], group_sizes)
+    elif banks == 1:
         got = gm.grouped_matmul(rows, *ws, group_sizes)
         want = gm.grouped_matmul_xla(rows, *ws, group_sizes)
     elif banks == 2:
@@ -212,10 +249,12 @@ def test_counter_says_which_path_a_trace_took_and_the_cpu_takes_ragged_dot(
     metrics.reset()
 
 
-@pytest.mark.parametrize("banks", [1, 2])
-def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot(banks):
+@pytest.mark.parametrize("banks,ungated", [(1, False), (2, False), (1, True)])
+def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot(
+        banks, ungated):
     rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], banks=banks)
-    public, launch = ((gm.grouped_matmul, gm._kernel_no_vjp) if banks == 1
+    public, launch = ((gm.grouped_relu2, gm._relu2_no_vjp) if ungated
+                      else (gm.grouped_matmul, gm._kernel_no_vjp) if banks == 1
                       else (gm.grouped_gate_up, gm._gate_up_no_vjp))
     grads = jax.grad(lambda r, *ws: jnp.sum(public(r, *ws, group_sizes) ** 2),
                      argnums=tuple(range(1 + banks)))(rows, *ws)

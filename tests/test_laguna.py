@@ -121,7 +121,7 @@ def _uncut_tree(seed=3):
 
 
 def _share(tree, first, held):
-    banks = {k: tree[k][first:first + held] for k in ref.BANKS}
+    banks = {k: tree[k][first:first + held] for k in ref.BANKS if k in tree}
     return dict(tree, **banks)
 
 
@@ -178,13 +178,52 @@ def _sigmoid_router_without_a_bias():
     return tree, whole, layer
 
 
+def _ungated_experts_in_a_latent():
+    """Nemotron-3-Super's ``E`` layer (the benchmark's toy configuration with
+    all 16 experts held): a sigmoid a router output, the top 3 of score + bias
+    chosen, ungated squared-ReLU experts 32 -> 24 -> 32 in a latent between
+    one projection in and one out, the shared expert ungated on the full
+    width; the uncut layer is ``reference/nemotron.py``'s."""
+    import json
+    import os
+
+    from benchmark import weights_nemotron
+    from benchmark.reference import nemotron as nemotron_ref
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "tests", "fixtures_nemotron", "benchmark",
+                           "configs", "toy_nemotron.json")) as f:
+        toy = dict(json.load(f), precision="float32", n_routed_experts=16)
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (16,))
+    tree = dict(weights_nemotron.make(toy, 3)["layers_1"]["mixer"],
+                e_score_correction_bias=bias)
+    # at widths of 24 and 32 weights of std 0.02 leave the routed part at
+    # 1e-5 beside a shared expert of 1e-3: louder, so that the sum is tested
+    tree = dict(tree, up_proj=4 * tree["up_proj"],
+                down_proj=4 * tree["down_proj"], fc1_latent_proj={
+                    "kernel": 2 * tree["fc1_latent_proj"]["kernel"]})
+    cfg = weights_nemotron.trunk_of(toy)
+    whole = lambda share, y: nemotron_ref.latent_experts(
+        _share(tree, *share), y,
+        dict(cfg, experts_held_from=share[0], n_routed_experts=share[1]))
+    layer = lambda first, held: moe.HeldExpertsMlp(
+        num_routed=16, top_k=3, first_held=first, num_held=held,
+        hidden_features=24, shared_features=48, scaling=5, score="sigmoid",
+        selection_bias=True, hidden_act="relu2", latent_features=32)
+    # the latent's way out is linear and has no bias: what it maps is the sum
+    y = jax.random.normal(jax.random.PRNGKey(2), (34, 64))
+    assert tree["up_proj"].shape == (16, 32, 24) and "gate_proj" not in tree
+    assert float(jnp.abs(whole((0, 16), y) - whole((0, 4), y)).max()) > 1e-3
+    return tree, whole, layer
+
+
 @pytest.mark.parametrize("router,chips", [
     (_softmax_router, 2), (_sigmoid_router_with_a_selection_bias, 16),
-    (_sigmoid_router_without_a_bias, 4)])
+    (_sigmoid_router_without_a_bias, 4), (_ungated_experts_in_a_latent, 4)])
 def test_the_shares_add_up_to_the_uncut_layer(router, chips):
     """The 16 experts over ``chips`` chips (0-7 and 8-15; one each; or four
-    each), the shared expert computed by all and counted once, against the
-    reference's uncut layer."""
+    each), the shared expert — and nothing else — computed by all and counted
+    once, against the reference's uncut layer."""
     tree, reference, layer = router()
     held = 16 // chips
     y = jax.random.normal(jax.random.PRNGKey(2), (2, 17, 64))
@@ -192,8 +231,10 @@ def test_the_shares_add_up_to_the_uncut_layer(router, chips):
     shares = [layer(first, held).apply(
         {"params": _share(tree, first, held)}, y)
         for first in range(0, 16, held)]
-    shared = hybrid.GatedMlp(
-        {"hidden_size": 64, "intermediate_size": 32}).apply(
+    gated = "gate_proj" in tree["shared_expert"]
+    shared = (hybrid.GatedMlp if gated else hybrid.SquaredReluMlp)(
+        {"hidden_size": 64, "intermediate_size":
+         tree["shared_expert"]["up_proj"]["kernel"].shape[1]}).apply(
         {"params": tree["shared_expert"]}, y)
     np.testing.assert_allclose(sum(shares) - (chips - 1) * shared, want,
                                rtol=1e-4, atol=2e-6)
