@@ -30,6 +30,9 @@ positions 0 (class token), 1, … in raster order:
   size 256 on both sides of the product): the sampler keeps no cache, so the
   latent is a factorisation here, and multiplying the absorbed latent
   (576/512 dims a pair against 256/256) would cost 2.1x the operations.
+  q reaches the launch UNTURNED and is turned there, on the q block it holds
+  (``ops.rotary.Rotary``; by ``apply_rotary`` off the TPU): of a head's 256
+  columns 64 turn, and no float32 pass crosses the other 192 in HBM.
   k and v are each written ONCE, by ``kv_b_proj``'s own GEMMs, as the launch
   reads them — ``(n, L, H·256)``, a head on two whole lane groups — and
   nothing touches them after (:class:`_KeysAndValuesInPlace`): v is ``c_kv``
@@ -77,10 +80,11 @@ import numpy as np
 
 from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
 from ddim_cold_tpu.models.init import trunc_normal
-from ddim_cold_tpu.models.laguna import apply_rotary, rotary_frequencies
+from ddim_cold_tpu.models.laguna import rotary_frequencies
 from ddim_cold_tpu.models.moe import HeldExpertsMlp
 from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops.flash_attention import selected_attention
+from ddim_cold_tpu.ops.rotary import Rotary, apply_rotary
 from ddim_cold_tpu.ops.sparse_select import select
 
 Dtype = Any
@@ -202,16 +206,19 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
     ``__call__`` (the parameters are that module's: ``q_a_proj``,
     ``q_a_layernorm``, ``q_b_proj``, ``kv_a_proj_with_mqa``,
     ``kv_a_layernorm``): ``(c_q, q, k_r, c_kv)`` of the layer's normed input
-    ``y (n, L, hidden)``, q's rotated parts and the ONE shared ``k_r (n, L,
-    rot)`` rotated by ``rope`` (:func:`laguna.rotary_frequencies`) under
-    ``pairing``, ``c_kv (n, L, kv_lora_rank)`` normed.
+    ``y (n, L, hidden)``, the ONE shared ``k_r (n, L, rot)`` rotated by
+    ``rope`` (:func:`laguna.rotary_frequencies`) under ``pairing``, ``c_kv
+    (n, L, kv_lora_rank)`` normed.
 
     q comes in the column order its reader wants. Published (``apart``
-    false): a head's parts side by side, ``q (n, L, H·(nope + rot))``.
-    ``apart``: the columns of ``q_b_proj`` as all the heads' nope parts then
-    all their rotated parts, ``q = (q_nope (n, L, H·nope), q_r (n, L,
-    H·rot))``, each on whole lanes where a kernel reads it; only q_r is
-    touched by the rotation."""
+    false): a head's parts side by side, ``q (n, L, H·(nope + rot))``, as
+    ``q_b_proj`` wrote it, UNTURNED: a quarter of its columns turn, and its
+    reader hands the rotation on to the one launch that reads q
+    (``Rotary(*rope, pairing, nope)`` to ``selected_attention``), which
+    turns the block it holds. ``apart``: the columns of ``q_b_proj`` as all
+    the heads' nope parts then all their rotated parts, ``q = (q_nope (n, L,
+    H·nope), q_r (n, L, H·rot))``, each on whole lanes where a kernel reads
+    it, q_r TURNED here: the rotation touches nothing else."""
     H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
                     c["qk_rope_head_dim"])
     rank = c["kv_lora_rank"]
@@ -225,8 +232,7 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
                                          **kw)(c_q)
         q = q_nope, apply_rotary(q_r, H, *rope, pairing=pairing)
     else:
-        q = apply_rotary(dense(H * (nope + rot), "q_b_proj")(c_q), H, *rope,
-                         pairing=pairing, first=nope)
+        q = dense(H * (nope + rot), "q_b_proj")(c_q)
     kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
     k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
     return c_q, q, k_r, norm("kv_a_layernorm")(kv_a[..., :rank])
@@ -306,16 +312,17 @@ class LatentAttention(nn.Module):
         hd, vd = c["qk_head_dim"], c["v_head_dim"]
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         dense = lambda feats, name: _dense(feats, name, **kw)
-        c_q, q, k_r, c_kv = latent_paths(
-            c, y, rotary_frequencies(c["rope_parameters"], rot),
-            _pairing(c.get("rope_interleave", False)), **kw)
+        rope = rotary_frequencies(c["rope_parameters"], rot)
+        pairing = _pairing(c.get("rope_interleave", False))
+        c_q, q, k_r, c_kv = latent_paths(c, y, rope, pairing, **kw)
         k, v = _KeysAndValuesInPlace(H, nope, vd, name="kv_b_proj", **kw)(
             c_kv, k_r)
         if self.indexer:
             with jax.named_scope("trunk/dsa_index"):
                 keep = Indexer(c, name="indexer", **kw)(y, c_q)
         out = selected_attention(q.reshape(n, L, H, hd), k.reshape(n, L, H, hd),
-                                 v.reshape(n, L, H, vd), hd ** -0.5, keep)
+                                 v.reshape(n, L, H, vd), hd ** -0.5, keep,
+                                 Rotary(*rope, pairing, nope))
         return dense(width, "o_proj")(out.reshape(n, L, H * vd)), keep
 
 

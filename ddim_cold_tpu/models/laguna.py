@@ -58,6 +58,7 @@ from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
 from ddim_cold_tpu.models.init import trunc_normal
 from ddim_cold_tpu.models.moe import HeldExpertsMlp
 from ddim_cold_tpu.ops.flash_attention import masked_attention
+from ddim_cold_tpu.ops.rotary import apply_rotary
 
 Dtype = Any
 
@@ -135,46 +136,6 @@ def rotary_frequencies(rope: Mapping[str, Any], head_dim: int) -> tuple:
     ramp = np.clip((np.arange(rot // 2) - low) / (high - low), 0.0, 1.0)
     scale = rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0
     return inv / factor * ramp + inv * (1.0 - ramp), float(scale)
-
-
-def apply_rotary(x, heads: int, inv_freq, scale: float, *,
-                 pairing: str = "rotate_half", first: int = 0):
-    """Rotate ``2 · len(inv_freq)`` dims of every head of ``x`` ``(n, L,
-    heads · head_dim)``, from dim ``first`` of the head on, by token position,
-    in float32; the rest pass through. ``pairing``: ``rotate_half`` (dim j
-    with dim j + rot/2) or ``interleave`` (dim 2j with dim 2j + 1). Written on
-    the token-major array as the projection left it — per-lane tables and two
-    lane rolls, no ``(n, L, heads, head_dim)`` view — so that q and k reach the
-    attention kernel in the layout it reads (a 4-d view costs a copy of q on
-    each side of the rotation: 0.6 GB in float32 at 4 x 4,097 x 9,216)."""
-    n, L, W = x.shape
-    hd, half = W // heads, len(inv_freq)
-    angle = (jnp.arange(L, dtype=jnp.float32)[:, None]
-             * jnp.asarray(inv_freq, jnp.float32))  # (L, half)
-    cos, sin = scale * jnp.cos(angle), scale * jnp.sin(angle)
-    rest = jnp.ones((L, hd - first - 2 * half), jnp.float32)
-    lead = [jnp.ones((L, first), jnp.float32)] if first else []
-    if pairing not in ("rotate_half", "interleave"):
-        raise ValueError(f"pairing {pairing!r}: 'rotate_half' and "
-                         "'interleave' are written")
-    halves = pairing == "rotate_half"
-    reach = half if halves else 1
-    cos = [cos, cos] if halves else [jnp.repeat(cos, 2, axis=-1)]
-    cos = jnp.tile(jnp.concatenate(lead + cos + [rest], axis=-1), (1, heads))
-    sin = ([-sin, sin] if halves
-           else [jnp.stack([-sin, sin], axis=-1).reshape(L, 2 * half)])
-    sin = jnp.tile(jnp.concatenate([0 * t for t in lead] + sin + [0 * rest],
-                                   axis=-1), (1, heads))
-    xf = x.astype(jnp.float32)
-    # the first of a pair takes its partner from the right, the partner from
-    # the left
-    dim = jnp.arange(W) % hd
-    if first:
-        dim = dim - first
-    first_of_pair = dim < half if halves else dim % 2 == 0
-    partner = jnp.where(first_of_pair, jnp.roll(xf, -reach, axis=-1),
-                        jnp.roll(xf, reach, axis=-1))
-    return (xf * cos + partner * sin).astype(x.dtype)
 
 
 class GatedAttention(nn.Module):

@@ -114,6 +114,10 @@ METRICS = (
      "flash backward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_fwd_mask", "counter",
      "flash forward traces by mask (key: none|causal|window|selected)"),
+    ("kernels.flash_fwd_rotary", "counter",
+     "selected-forward traces handed an unturned q, by where its rotation "
+     "runs (key: kernel, in the launch on the q block it holds; xla, "
+     "apply_rotary before it)"),
     ("kernels.flash_latent_schedule", "counter",
      "latent (two-part score) attention traces by path (key: kernel|xla)"),
     # -- kernels (ops/sparse_select.py, counted once a trace) -------------

@@ -119,6 +119,7 @@ from jax.sharding import PartitionSpec as P
 
 from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import tiling
+from ddim_cold_tpu.ops.rotary import Rotary, rotary_tables
 from ddim_cold_tpu.utils import flops, profiling
 
 _NEG_INF = -1e30
@@ -1418,7 +1419,8 @@ class _Ints:
 
 def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
                        n_kv: int, causal: bool, window: int | None,
-                       selected: bool = False, parts: int = 1):
+                       selected: bool = False, parts: int = 1,
+                       turn: tuple | None = None):
     """One (image, query head, q block, visited chunk) program of the masked
     forward: one head on the block's lanes. ``parts``: the score is the sum of
     that many products, ``parts`` q blocks then ``parts`` k blocks before v,
@@ -1435,7 +1437,12 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     does nothing once past the last of them. The element mask is built only
     in a chunk the mask's edge (the diagonal, the window's far edge, the end
     of the sequence) crosses; a chunk every token of the block sees whole
-    takes the unmasked fold.
+    takes the unmasked fold. ``turn`` (:func:`_turn_geometry`; one part): q
+    comes as its projection wrote it and two more operands before the result,
+    the ``(bq, 128)`` float32 cos and sin tables of the lane group that holds
+    the head's rotated dims, turn it HERE, once a q block, into one more
+    scratch after the softmax's (:func:`_turn_q_block`), which every fold then
+    reads in place of the q block.
 
     A row whose first visited chunk is wholly masked for it (a window's far
     chunk, for the block's last rows) holds m = −1e30 and garbage l, acc until
@@ -1444,23 +1451,31 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
     rest = refs[2 * parts + 1:]
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    if turn is not None:
+        cos_ref, sin_ref, *rest, turned_ref = rest
     o_ref, acc_ref, m_ref, l_ref = rest
-    i, j = pl.program_id(2), pl.program_id(3)
+    # the grid of a launch that turns q has the q blocks outside the heads
+    i, j = pl.program_id(2 if turn is None else 1), pl.program_id(3)
     lo, hi = _visible_chunks(i, bq=bq, bkv=bkv, n_valid=n_valid,
                              causal=causal, window=window)
     c = lo + j
+
+    fold_scale = _scale_folds_into_q(scale)
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    fold_scale = _scale_folds_into_q(scale)
+        if turn is not None:
+            _turn_q_block(q_refs[0], cos_ref, sin_ref, turned_ref,
+                          scale if fold_scale else None, *turn)
 
     def fold(masked: bool):
         qs, ks, v = [r[0] for r in q_refs], [r[0] for r in k_refs], v_ref[0]
-        if fold_scale:
+        if turn is not None:  # turned, and scaled where the scale folds
+            qs = [turned_ref[...]]
+        elif fold_scale:
             qs = [q * scale for q in qs]
         q, k = (x[0] if parts == 1 else jnp.concatenate(x, axis=1)
                 for x in (qs, ks))
@@ -1513,6 +1528,44 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _turn_geometry(rotary: Rotary) -> tuple | None:
+    """``(lane group of the head, first rotated lane in it, pairs, by
+    halves)`` where the launch can turn q itself — the head's rotated dims lie
+    inside ONE group of 128 lanes, so every partner is a lane roll away
+    within the group — else None: :meth:`Rotary.apply` turns q in XLA."""
+    group, lane = divmod(rotary.first, _LANE)
+    half = len(rotary.inv_freq)
+    if lane + 2 * half > _LANE:
+        return None
+    return group, lane, half, rotary.pairing == "rotate_half"
+
+
+def _turn_q_block(q_ref, cos_ref, sin_ref, out_ref, scale: float | None,
+                  group: int, lane0: int, half: int, halves: bool):
+    """``out_ref (bq, lanes)`` = the q block with its rotated dims turned and
+    the whole of it times ``scale`` (None: the scores are scaled), as
+    ``ops.rotary.apply_rotary`` then the fold of the scale would leave it:
+    the lane group that holds the rotated dims through float32 — ``x · cos +
+    partner · sin`` by the tables (1 and 0 on the lanes that pass through),
+    the partner a lane roll away, the first of a pair taking it from the
+    right — and ONE rounding to q's dtype; the other groups copied."""
+    lo, hi = group * _LANE, (group + 1) * _LANE
+    x = q_ref[0, :, lo:hi].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    reach = half if halves else 1
+    first_of_pair = (lane < lane0 + half if halves
+                     else (lane & 1) == (lane0 & 1))
+    partner = jnp.where(first_of_pair, pltpu.roll(x, _LANE - reach, 1),
+                        pltpu.roll(x, reach, 1))
+    turned = (x * cos_ref[...] + partner * sin_ref[...]).astype(out_ref.dtype)
+    scaled = (lambda q: q) if scale is None else (lambda q: q * scale)
+    if lo:
+        out_ref[:, :lo] = scaled(q_ref[0, :, :lo])
+    out_ref[:, lo:hi] = scaled(turned)
+    if hi < out_ref.shape[1]:
+        out_ref[:, hi:] = scaled(q_ref[0, :, hi:])
+
+
 def _masked_blocks(n_tokens: int, dtype) -> tuple:
     """(block_q, block_kv) of the masked forward: K/V streamed in chunks of
     512 (a window of 512 then lies in two chunks of a 512-row q block), both
@@ -1553,8 +1606,8 @@ _WALK_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
-                     bkv, causal, window, interpret):
+def _fwd_masked_call(q, k, v, keep=None, *tables, rep, lanes, scale, n_valid,
+                     bq, bkv, causal, window, interpret, turn=None):
     """The masked launch (``fwd_masked``), or with ``keep`` — an int8 ``(rows,
     tokens⁺, tokens⁺)`` selection in whole ``(bq, bkv)`` tiles, one for all
     the heads — the selected one (``fwd_selected``: the same body, a name of
@@ -1565,31 +1618,50 @@ def _fwd_masked_call(q, k, v, keep=None, *, rep, lanes, scale, n_valid, bq,
     chunks)``: the last axis is as long as the most chunks any q block sees
     (two for a window of 512 at these blocks; all of them under a causal
     mask), and a block that sees fewer re-addresses its last chunk, which is
-    not fetched again, and skips the fold."""
+    not fetched again, and skips the fold. ``turn`` with ``tables``, the
+    float32 ``(tokens⁺, 128)`` cos and sin of the rotated lane group in whole
+    q blocks: q is unturned and the launch turns it
+    (:func:`_fwd_masked_kernel`), a ``(bq, 128)`` block of each table a q
+    block and one more scratch, the turned block; the grid is then ``(rows, q
+    blocks, H, visited chunks)``, the heads INSIDE the q blocks, so that a q
+    block's tables are fetched once for all its heads and not once a head
+    (0.6 GB a launch at 9,217 tokens × 64 heads, under steps that have no
+    time to hide it); every other block is fetched as often either way."""
     rows, tokens, width = q.shape
     geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
                     window=window)
     n_q, n_kv, chunk = _chunk_walk(tokens, geometry)
+    grid = [rows, width // lanes, n_q, n_kv]
+    at = lambda index_map: index_map  # written over (b, h, i, j)
+    if turn is not None:
+        grid[1:3] = grid[2], grid[1]
+        at = lambda index_map: lambda b, i, h, j: index_map(b, h, i, j)
 
     def kv_map(b, h, i, j):
         return (b, chunk(i, j), h // rep)
 
-    q_spec = pl.BlockSpec((1, bq, lanes), lambda b, h, i, j: (b, i, h))
-    kv_spec = pl.BlockSpec((1, bkv, lanes), kv_map)
+    q_spec = pl.BlockSpec((1, bq, lanes), at(lambda b, h, i, j: (b, i, h)))
+    kv_spec = pl.BlockSpec((1, bkv, lanes), at(kv_map))
     name, operands, in_specs = "fwd_masked", (q, k, v), [q_spec, kv_spec, kv_spec]
     if keep is not None:
         name, operands = "fwd_selected", (q, k, v, keep)
         in_specs.append(pl.BlockSpec(
-            (1, bq, bkv), lambda b, h, i, j: (b, i, kv_map(b, h, i, j)[1])))
+            (1, bq, bkv), at(lambda b, h, i, j: (b, i, kv_map(b, h, i, j)[1]))))
+    scratch = _walk_scratch(bq, lanes)
+    if turn is not None:
+        operands += tables
+        in_specs += [pl.BlockSpec((bq, _LANE),
+                                  at(lambda b, h, i, j: (i, 0)))] * 2
+        scratch.append(pltpu.VMEM((bq, lanes), q.dtype))  # the turned q block
     with profiling.scope(f"flash_attention/{name}"):
         return pl.pallas_call(
             functools.partial(_fwd_masked_kernel, scale=scale, n_kv=n_kv,
-                              selected=keep is not None, **geometry),
-            grid=(rows, width // lanes, n_q, n_kv),
+                              selected=keep is not None, turn=turn, **geometry),
+            grid=tuple(grid),
             in_specs=in_specs,
             out_specs=q_spec,
             out_shape=_sds(q.shape, q.dtype, q),
-            scratch_shapes=_walk_scratch(bq, lanes),
+            scratch_shapes=scratch,
             compiler_params=_WALK_PARAMS,
             interpret=interpret,
             name=name,
@@ -1625,11 +1697,12 @@ def _check_shared_heads(q, k, v) -> None:
                          "query heads must divide into the K/V heads")
 
 
-def _masked_forward(q, k, v, keep, scale, causal, window):
+def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
     """The launch of :func:`flash_attention_masked` (``keep`` None) or
     :func:`flash_attention_selected` on ``(B, N, heads, D)`` operands: heads
     zero-padded to whole lanes where ``D`` does not fill them, blocks from
-    the shape, one launch a device under a mesh."""
+    the shape, one launch a device under a mesh. ``rotary``: q is unturned
+    and the launch turns it, by tables made here for every device."""
     B, N, H, D = q.shape
     KV = k.shape[2]
     lanes = tiling.round_up(D, _LANE)
@@ -1641,12 +1714,19 @@ def _masked_forward(q, k, v, keep, scale, causal, window):
                 v.reshape(B, N, KV * lanes))
     if keep is not None:
         operands += (keep,)
+    specs, turn = (spec,) * len(operands), None
+    if rotary is not None:
+        turn = _turn_geometry(rotary)
+        operands += rotary_tables(
+            tiling.round_up(N, bq), _LANE, rotary.inv_freq, rotary.scale,
+            pairing=rotary.pairing, first=turn[1])
+        specs += (P(), P())
     out = per_device(
         functools.partial(
             _fwd_masked_call, rep=H // KV, lanes=lanes, scale=scale, n_valid=N,
             bq=bq, bkv=bkv, causal=causal, window=window,
-            interpret=kernel_interpret()),
-        (spec,) * len(operands), spec,
+            interpret=kernel_interpret(), turn=turn),
+        specs, spec,
     )(*operands)
     return out.reshape(B, N, H, lanes)[..., :D]
 
@@ -1679,7 +1759,8 @@ def masked_attention(q, k, v, scale: float, *, causal: bool = True,
                                    window=window)
 
 
-def flash_attention_selected(q, k, v, scale: float, keep) -> jax.Array:
+def flash_attention_selected(q, k, v, scale: float, keep,
+                             rotary: Rotary | None = None) -> jax.Array:
     """The causal forward over a per-query SET of keys, as its own launch
     (``pallas_call(name="fwd_selected")``, ``%fwd_selected`` in a device
     trace; ``%fwd_masked`` keeps reading the launch without a selection).
@@ -1690,8 +1771,20 @@ def flash_attention_selected(q, k, v, scale: float, keep) -> jax.Array:
     Token t attends to ``{s ≤ t : keep[t, s]}``, which must not be empty (the
     ``top`` best of the visible keys never is). Every chunk at or below the
     diagonal is multiplied and masked by its tile: with a scattered set there
-    is no chunk to skip, and a gather a row is 2,048 descriptors a query. No
-    backward yet: the VJP raises by name."""
+    is no chunk to skip, and a gather a row is 2,048 descriptors a query.
+
+    ``rotary``: q comes UNTURNED, as its projection wrote it, and this is the
+    rotation of every query head it still needs (k comes turned). Where a
+    head's rotated dims lie inside one group of 128 lanes (a head of ``[nope
+    192 | rot 64]``: its second group) the launch turns the q block it
+    already holds, once a (head, q block), in VMEM: two more operands, the
+    float32 ``(N⁺, 128)`` cos and sin of that lane group (1 and 0 on the
+    lanes that pass through), made here by ``apply_rotary``'s own expressions
+    — float32 products, one rounding to q's dtype before the MXU, as there,
+    and no pass over q in HBM. Any other head is turned by ``apply_rotary``
+    and launched as without. ``kernels.flash_fwd_rotary`` counts which,
+    ``kernel`` or ``xla``, once a trace. No backward yet: the VJP raises by
+    name."""
     _check_shared_heads(q, k, v)
     B, N = q.shape[:2]
     bq, bkv = _masked_blocks(N, q.dtype)
@@ -1700,7 +1793,18 @@ def flash_attention_selected(q, k, v, scale: float, keep) -> jax.Array:
         raise ValueError(f"keep {keep.dtype}{keep.shape}: the selection of "
                          f"{N} tokens is int8{want}")
     _kernels.inc("kernels.flash_fwd_mask", key="selected")
-    return _masked_forward(q, k, v, keep, scale, True, None)
+    if rotary is not None:
+        in_launch = _turn_geometry(rotary) is not None
+        _kernels.inc("kernels.flash_fwd_rotary",
+                     key="kernel" if in_launch else "xla")
+        if not in_launch:
+            q, rotary = _turned_by_xla(q, rotary), None
+    return _masked_forward(q, k, v, keep, scale, True, None, rotary)
+
+
+def _turned_by_xla(q, rotary: Rotary):
+    B, N, H, D = q.shape
+    return rotary.apply(q.reshape(B, N, H * D), H).reshape(q.shape)
 
 
 def selected_attention_xla(q, k, v, scale: float, keep) -> jax.Array:
@@ -1720,9 +1824,9 @@ def selected_attention_xla(q, k, v, scale: float, keep) -> jax.Array:
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _selected_no_vjp(q, k, v, keep, scale):
-    return flash_attention_selected(q, k, v, scale, keep)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _selected_no_vjp(q, k, v, keep, scale, rotary=None):
+    return flash_attention_selected(q, k, v, scale, keep, rotary)
 
 
 def _selected_no_vjp_fwd(*args):
@@ -1736,13 +1840,20 @@ def _selected_no_vjp_fwd(*args):
 _selected_no_vjp.defvjp(_selected_no_vjp_fwd, lambda *a: None)
 
 
-def selected_attention(q, k, v, scale: float, keep) -> jax.Array:
+def selected_attention(q, k, v, scale: float, keep,
+                       rotary: Rotary | None = None) -> jax.Array:
     """Causal attention over the per-query key sets ``keep``
     (``ops.sparse_select.select``), shapes as
-    :func:`flash_attention_selected`; the backend decides what runs: that
-    kernel on the TPU, :func:`selected_attention_xla` anywhere else."""
+    :func:`flash_attention_selected`, q unturned where ``rotary`` says what
+    still turns it; the backend decides what runs: that kernel on the TPU,
+    which turns q itself where it can; anywhere else ``apply_rotary``
+    (``kernels.flash_fwd_rotary`` counts ``xla``) and
+    :func:`selected_attention_xla`."""
     if jax.default_backend() == "tpu":
-        return _selected_no_vjp(q, k, v, keep, scale)
+        return _selected_no_vjp(q, k, v, keep, scale, rotary)
+    if rotary is not None:
+        _kernels.inc("kernels.flash_fwd_rotary", key="xla")
+        q = _turned_by_xla(q, rotary)
     return selected_attention_xla(q, k, v, scale, keep)
 
 

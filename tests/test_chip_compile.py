@@ -712,22 +712,31 @@ def test_the_selection_lowers_at_the_published_shapes_without_a_sort(chip):
     assert not re.search(r"\bsort\(|\btopk\(|TopK", text)
 
 
-def test_fwd_selected_lowers_at_the_published_shapes_and_keeps_its_name(chip):
+@pytest.mark.parametrize("pairing", [None, "interleave", "rotate_half"])
+def test_fwd_selected_lowers_at_the_published_shapes_and_keeps_its_name(
+        pairing, chip):
     """The attention forward over the selection at 9,217 tokens, 64 heads of
     256 read in place: ONE ``tpu_custom_call``, named ``%fwd_selected``
     (``benchmark/layer_metrics/flash_selected_fwd_roofline.py`` matches it by
-    that name), which the ``%fwd_masked`` and ``%fwd`` readers do not match."""
+    that name), which the ``%fwd_masked`` and ``%fwd`` readers do not match.
+    Handed an unturned q and the rotation of each head's last 64 dims
+    (``pairing``), the SAME one launch under the same name, which turns q
+    itself: beside it the program makes the two ``(9728, 128)`` float32
+    tables and nothing of q's size, in float32 or any other type."""
     from benchmark.layer_metrics import flash_fwd_roofline
     from benchmark.layer_metrics import flash_masked_fwd_roofline
     from benchmark.layer_metrics import flash_selected_fwd_roofline as reader
     from ddim_cold_tpu.ops import sparse_select as ss
+    from ddim_cold_tpu.ops.rotary import Rotary
 
     sds = _struct(SingleDeviceSharding(chip[0]))
     n, L, H, hd = (GLM[k] for k in ("n", "L", "heads", "hd"))
     length = ss.mask_length(L, jnp.bfloat16)
     head = sds((n, L, H, hd), jnp.bfloat16)
+    rotary = pairing and Rotary(
+        8000000.0 ** (-np.arange(0, 64, 2) / 64), 1.0, pairing, hd - 64)
     text = jax.jit(lambda q, k, v, keep: fa.selected_attention(
-        q, k, v, hd ** -0.5, keep)).lower(
+        q, k, v, hd ** -0.5, keep, rotary)).lower(
         head, head, head, sds((n, length, length), jnp.int8)
     ).compile().as_text()
     calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
@@ -737,6 +746,34 @@ def test_fwd_selected_lowers_at_the_published_shapes_and_keeps_its_name(chip):
     assert m and [int(g) for g in m.groups()[1:]] == [n, L, H * hd]
     assert not flash_masked_fwd_roofline.NAME.match(calls[0])
     assert not flash_fwd_roofline.NAME.match(calls[0])
+    made = set(re.findall(r"= (\w+\[[\d,]+\])", text))
+    assert {a for a in made if a.endswith(f"[{n},{L},{H * hd}]")} == {
+        f"bf16[{n},{L},{H * hd}]"}  # the launch's result
+    assert (f"f32[{length},128]" in made) == bool(pairing)
+
+
+def test_the_glm_forward_traced_for_the_tpu_turns_q_in_the_launch(chip):
+    """One trace of the cell's whole forward (``glm52_ep16_px1536`` as the
+    benchmark builds it, shapes only) on the TPU's path: every one of its five
+    attention layers hands ``fwd_selected`` an unturned q and the launch
+    turns it (``kernels.flash_fwd_rotary`` = ``kernel``)."""
+    import json
+
+    from benchmark.drivers import sample_closed_glm
+    from ddim_cold_tpu.obs import metrics
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/glm52_ep16_px1536.json")) as f:
+        config = json.load(f)
+    model = sample_closed_glm.build_model(config)
+    x = jnp.zeros((1, *config["img_size"], 3), jnp.float32)
+    t = jnp.zeros((1,), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x, t)
+    metrics.reset()
+    jax.eval_shape(model.apply, params, x, t)
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"kernel": 5}
+    assert fa._kernels.by_key("kernels.flash_fwd_mask") == {"selected": 5}
+    metrics.reset()
 
 
 # --- the latent forward at openPangu-Ultra-MoE's shapes ----------------------

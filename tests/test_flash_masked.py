@@ -3,14 +3,21 @@ in interpreter mode, against dense float32 attention and against
 ``blockwise_attention_xla`` (its stand-in off the TPU): causal and window
 masks; 1, 6 and 9 query heads a K/V head; token counts that are and are not
 multiples of the 512-token chunk; a window smaller and larger than a chunk;
-which chunks a q block visits; the counter; the unmasked path untouched."""
+which chunks a q block visits; the counter; the unmasked path untouched. And
+the option of the same body that ``fwd_selected`` takes: an UNTURNED q turned
+inside the launch, once a q block, against ``apply_rotary`` before it."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
+from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import flash_attention as fa
+from ddim_cold_tpu.ops import sparse_select as ss
+from ddim_cold_tpu.ops.rotary import Rotary, apply_rotary, rotary_tables
 
 
 def _qkv(N, H, KV, D, B=1, seed=0, dtype=jnp.float32):
@@ -133,3 +140,209 @@ def test_masked_attention_differentiates_off_the_chip_and_the_kernel_says_it_can
                                                   "backward"):
         jax.grad(lambda q: jnp.sum(fa._masked_no_vjp(
             q, k, v, 0.25, True, 8) ** 2))(q)
+
+
+# --- q turned inside the launch ----------------------------------------------
+
+def _rotary(rot, pairing, first):
+    """``rot`` dims from ``first`` on, the ``default`` frequencies."""
+    inv = 8000000.0 ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    return Rotary(inv, 1.0, pairing, first)
+
+
+def _selection(B, N, dtype, seed=9):
+    """A third of the visible keys and every query's own: int8, whole blocks."""
+    length = ss.mask_length(N, dtype)
+    keep = jax.random.uniform(jax.random.PRNGKey(seed), (B, length, length)) < 0.3
+    return (keep | jnp.eye(length, dtype=bool)).astype(jnp.int8)
+
+
+def _turned(q, rotary):
+    """q turned as the parent turned it: the whole array, in XLA, one jit."""
+    B, N, H, D = q.shape
+    return jax.jit(lambda q: rotary.apply(q.reshape(B, N, H * D), H))(
+        q).reshape(q.shape)
+
+
+def _ulps(a, b):
+    """Distance in representable values of the arrays' dtype."""
+    bits = {2: np.int16, 4: np.int32}[a.dtype.itemsize]
+    ordered = lambda x: (lambda i: np.where(i < 0, np.iinfo(bits).min - i, i))(
+        np.asarray(x).view(bits).astype(np.int64))
+    return np.abs(ordered(a) - ordered(b))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("pairing,D,first,rot", [
+    ("interleave", 256, 192, 64),   # the published head: its second lane group
+    ("rotate_half", 256, 192, 64),
+    ("rotate_half", 128, 0, 128),   # every dim of a one-group head turns
+    ("interleave", 384, 136, 48),   # inside the middle group of three
+])
+def test_a_q_block_turned_in_vmem_is_apply_rotarys(pairing, D, first, rot, dtype):
+    """:func:`_turn_q_block` over every block of a q of 300 tokens x 2 heads:
+    the lanes that pass through bit for bit, the lanes that turn to ONE unit
+    in the last place of q's dtype — the same float32 products and one
+    rounding; on the CPU LLVM contracts ``x·cos + partner·sin`` into a fused
+    multiply-add one way inside the interpreted body and another in XLA's own
+    fusion, which moves the last bit of a few elements in 100,000 (on the
+    chip: PERF.md section 6, PR 42)."""
+    N, H, bq = 300, 2, 128
+    rotary = _rotary(rot, pairing, first)
+    geometry = fa._turn_geometry(rotary)
+    assert geometry == (first // 128, first % 128, rot // 2,
+                        pairing == "rotate_half")
+    q = jax.random.normal(jax.random.PRNGKey(2), (1, N, H, D), dtype)
+    cos, sin = rotary_tables(384, 128, rotary.inv_freq, 1.0, pairing=pairing,
+                             first=geometry[1])
+
+    def kernel(q_ref, cos_ref, sin_ref, o_ref):
+        fa._turn_q_block(q_ref, cos_ref, sin_ref, o_ref.at[0], None, *geometry)
+
+    block = pl.BlockSpec((1, bq, D), lambda i, h: (0, i, h))
+    table = pl.BlockSpec((bq, 128), lambda i, h: (i, 0))
+    got = pl.pallas_call(
+        kernel, grid=(pl.cdiv(N, bq), H), in_specs=[block, table, table],
+        out_specs=block, out_shape=jax.ShapeDtypeStruct((1, N, H * D), dtype),
+        interpret=True)(q.reshape(1, N, H * D), cos, sin).reshape(q.shape)
+    want = _turned(q, rotary)
+    passes = np.ones(D, bool)
+    passes[first:first + rot] = False
+    np.testing.assert_array_equal(got[..., passes], q[..., passes])
+    np.testing.assert_array_equal(want[..., passes], q[..., passes])
+    apart = _ulps(got, want)
+    assert apart.max() <= 1 and (apart > 0).mean() < 1e-3
+    assert np.abs(np.asarray(got - q, np.float32))[:, 1:, :, ~passes].max() > 0.1
+
+
+@pytest.mark.parametrize("pairing,N,B,KV,D,first,rot,scale,dtype", [
+    # the published head, 2 images, a ragged 2nd q block, 1/16 folded into q
+    ("interleave", 600, 2, 2, 256, 192, 64, 256 ** -0.5, jnp.bfloat16),
+    # a scale that does not fold: the scores are scaled, the scratch is not
+    ("interleave", 600, 1, 2, 256, 192, 64, 0.07, jnp.bfloat16),
+    ("rotate_half", 600, 1, 1, 256, 192, 64, 256 ** -0.5, jnp.bfloat16),
+    ("rotate_half", 1030, 1, 2, 256, 192, 64, 0.07, jnp.float32),  # 3 blocks
+    ("interleave", 530, 1, 2, 128, 0, 128, 0.125, jnp.float32),    # all dims
+    ("interleave", 37, 2, 4, 32, 24, 8, 0.2, jnp.float32),  # the toy: padded
+])
+def test_the_selected_launch_turns_an_unturned_q(pairing, N, B, KV, D, first,
+                                                 rot, scale, dtype):
+    """``fwd_selected`` with the tables on q as its projection wrote it,
+    against the same launch without them on ``apply_rotary(q, first=…)``, and
+    against ``selected_attention_xla`` of that q. To one unit in the last
+    place of the result's dtype where q is bfloat16 (the turned q is
+    ``apply_rotary``'s to that, see above; bit for bit on the chip), to
+    float32 rounding otherwise."""
+    H = 4 if D == 32 else 2
+    rotary = _rotary(rot, pairing, first)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (B, N, H, D), dtype)
+    k, v = (jax.random.normal(kk, (B, N, KV, D), dtype) for kk in ks[1:])
+    keep = _selection(B, N, dtype)
+    got = fa.flash_attention_selected(q, k, v, scale, keep, rotary)
+    turned = _turned(q, rotary)
+    launch = fa.flash_attention_selected(turned, k, v, scale, keep)
+    oracle = fa.selected_attention_xla(
+        *(x.astype(jnp.float32) for x in (turned, k, v)), scale, keep)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    f32 = lambda x: np.asarray(x, np.float32)
+    if dtype == jnp.bfloat16:
+        np.testing.assert_allclose(f32(got), f32(launch), atol=2 ** -8, rtol=2 ** -8)
+        np.testing.assert_allclose(f32(got), f32(oracle), atol=2e-2)
+    else:
+        np.testing.assert_allclose(got, launch, rtol=2e-6, atol=2e-6)
+        np.testing.assert_allclose(got, oracle, rtol=2e-5, atol=2e-5)
+    # and it is the TURNED q that was attended with
+    assert np.abs(f32(got) - f32(fa.flash_attention_selected(
+        q, k, v, scale, keep))).max() > 1e-2
+
+
+def test_a_head_whose_rotated_dims_straddle_a_lane_group_is_turned_before_the_launch():
+    """Dims 96..159 of a 256 head lie in two lane groups: no roll inside one
+    group reaches every partner, so ``apply_rotary`` turns q and the launch
+    is the one without tables — bit for bit that, and counted ``xla``."""
+    rotary = _rotary(64, "rotate_half", 96)
+    assert fa._turn_geometry(rotary) is None
+    assert fa._turn_geometry(_rotary(64, "rotate_half", 64)) == (0, 64, 32, True)
+    q, k, v = _qkv(300, 2, 2, 256)
+    keep = _selection(1, 300, q.dtype)
+    metrics.reset()
+    got = fa.flash_attention_selected(q, k, v, 0.0625, keep, rotary)
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"xla": 1}
+    want = fa.flash_attention_selected(
+        apply_rotary(q.reshape(1, 300, 512), 2, rotary.inv_freq, 1.0,
+                     first=96).reshape(q.shape), k, v, 0.0625, keep)
+    np.testing.assert_array_equal(got, want)
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"xla": 1}
+    metrics.reset()
+
+
+def _launch(fn, *args):
+    """The one ``pallas_call`` equation in the jaxpr of ``fn(*args)``."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    (eqn,) = found
+    scratch = eqn.params["grid_mapping"].num_scratch_operands
+    shapes = [(v.aval.shape, v.aval.dtype) for v in eqn.params["jaxpr"].invars]
+    return (eqn.params["name"], len(eqn.invars),
+            shapes[len(shapes) - scratch:])
+
+
+def test_the_launches_without_the_option_are_the_parents():
+    """``fwd_masked``, ``fwd_latent`` and ``fwd_selected`` without a rotation
+    to run: the operands, the scratch (accumulator, running max, running
+    denominator — and a head's half of q_r for the latent launch) and the
+    names they had; with it ``fwd_selected`` keeps its name and takes two
+    tables and one more scratch, the turned q block."""
+    f32 = jnp.dtype("float32")
+    walk = lambda bq, lanes: [((bq, lanes), f32), ((bq, 128), f32),
+                              ((bq, 128), f32)]
+    q, k, v = _qkv(40, 2, 1, 256)
+    assert _launch(lambda q, k, v: fa.flash_attention_masked(
+        q, k, v, 0.1, window=16), q, k, v) == ("fwd_masked", 3, walk(40, 256))
+    keep = _selection(1, 40, q.dtype)
+    assert _launch(lambda q, k, v, m: fa.flash_attention_selected(
+        q, k, v, 0.1, m), q, k, v, keep) == ("fwd_selected", 4, walk(40, 256))
+    qn, kn, vv = (jnp.ones((1, 40, 2, 128)) for _ in range(3))
+    qr, kr = jnp.ones((1, 40, 2, 64)), jnp.ones((1, 40, 64))
+    assert _launch(lambda *a: fa.flash_attention_latent(*a, 0.1),
+                   qn, qr, kn, kr, vv) == (
+        "fwd_latent", 5, walk(40, 128) + [((1, 40, 128), f32)])
+    rotary = _rotary(64, "interleave", 192)
+    assert _launch(lambda q, k, v, m: fa.flash_attention_selected(
+        q, k, v, 0.1, m, rotary), q, k, v, keep) == (
+        "fwd_selected", 6, walk(40, 256) + [((40, 256), f32)])
+
+
+def test_the_rotary_counter_says_where_q_was_turned():
+    """``kernels.flash_fwd_rotary``: ``kernel`` where the launch turns q (the
+    TPU's path; here interpreted), ``xla`` off the TPU and for a head the
+    launch cannot address; nothing where no rotation is handed on; and its
+    row in the table of ``obs/metrics.py``."""
+    assert "kernels.flash_fwd_rotary" in {name for name, *_ in metrics.METRICS}
+    q, k, v = _qkv(40, 2, 2, 256)
+    keep = _selection(1, 40, q.dtype)
+    rotary = _rotary(64, "interleave", 192)
+    count = lambda: fa._kernels.by_key("kernels.flash_fwd_rotary")
+    metrics.reset()
+    fa.flash_attention_selected(q, k, v, 0.1, keep)
+    fa.selected_attention(q, k, v, 0.1, keep)
+    assert count() == {}
+    jax.make_jaxpr(lambda q: fa.flash_attention_selected(
+        q, k, v, 0.1, keep, rotary))(q)
+    assert count() == {"kernel": 1}
+    got = fa.selected_attention(q, k, v, 0.1, keep, rotary)  # off the TPU
+    assert count() == {"kernel": 1, "xla": 1}
+    np.testing.assert_array_equal(got, fa.selected_attention_xla(
+        apply_rotary(q.reshape(1, 40, 512), 2, rotary.inv_freq, 1.0,
+                     pairing="interleave", first=192).reshape(q.shape),
+        k, v, 0.1, keep))
+    metrics.reset()

@@ -6,10 +6,12 @@ top-3 under a sigmoid with a selection bias, experts 0-7 held; 16x16 px patch
 4) on seeded weights, against the plain reference
 (``benchmark/reference/glm.py``, which imports nothing of the program): the
 forward, borrowed selections, the interleaved rotary pairing, the DDIM
-trajectory, causality, serving, refusals, scopes and counters; and how k and
+trajectory, causality, serving, refusals, scopes and counters; how k and
 v reach the attention launch: written once each by ``kv_b_proj``'s own GEMMs,
-bit for bit the concatenate and the slice they replace."""
+bit for bit the concatenate and the slice they replace; and how q does:
+unturned, its rotation handed on, off the TPU bit for bit the parent's."""
 
+import flax.linen as nn
 import jax
 import jax.extend
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from benchmark.reference import lowprec
 from ddim_cold_tpu import serve
 from ddim_cold_tpu.models import glm, hybrid, laguna
 from ddim_cold_tpu.obs import metrics
+from ddim_cold_tpu.ops import flash_attention as fa
 from ddim_cold_tpu.ops import sampling
 
 PUBLISHED = dict(
@@ -181,6 +184,52 @@ def test_k_and_v_leave_the_gemms_bitwise_what_concatenate_and_slice_built(
             np.asarray(want, np.float32))
 
 
+class _ParentsLatentAttention(nn.Module):
+    """``glm.LatentAttention`` as PR 41 had it: q turned as a whole array by
+    ``apply_rotary(first=nope)`` before ``selected_attention``; the same
+    parameter names, so the same tree."""
+
+    trunk: dict
+    indexer: bool
+
+    @nn.compact
+    def __call__(self, y, keep):
+        c = self.trunk
+        n, L, width = y.shape
+        H, nope, rot = (c["num_attention_heads"], c["qk_nope_head_dim"],
+                        c["qk_rope_head_dim"])
+        hd = c["qk_head_dim"]
+        kw = dict(dtype=jnp.float32, param_dtype=jnp.float32)
+        rope = laguna.rotary_frequencies(c["rope_parameters"], rot)
+        pairing = glm._pairing(c.get("rope_interleave", False))
+        c_q, q, k_r, c_kv = glm.latent_paths(c, y, rope, pairing, **kw)
+        q = laguna.apply_rotary(q, H, *rope, pairing=pairing, first=nope)
+        k, v = glm._KeysAndValuesInPlace(H, nope, hd, name="kv_b_proj", **kw)(
+            c_kv, k_r)
+        if self.indexer:
+            keep = glm.Indexer(c, name="indexer", **kw)(y, c_q)
+        out = fa.selected_attention(
+            q.reshape(n, L, H, hd), k.reshape(n, L, H, hd),
+            v.reshape(n, L, H, hd), hd ** -0.5, keep)
+        return glm._dense(width, "o_proj", **kw)(out.reshape(n, L, H * hd)), keep
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_off_the_tpu_the_attention_is_the_parents_bit_for_bit(interleave):
+    """q handed on unturned and turned by ``apply_rotary`` inside
+    ``selected_attention`` (the path off the TPU) is the whole-array rotation
+    before it that the parent ran: the same operations on the same values."""
+    trunk = dict(TRUNK, rope_interleave=interleave)
+    _, params = model_and_params("float32")
+    p = {"params": params["layers_0"]["self_attn"]}  # a full layer: an indexer
+    y = jax.random.normal(jax.random.PRNGKey(5), (2, 17, 64))
+    got, keep = glm.LatentAttention(trunk, True).apply(p, y, None)
+    want, keep2 = _ParentsLatentAttention(trunk, True).apply(p, y, None)
+    np.testing.assert_array_equal(keep, keep2)
+    np.testing.assert_array_equal(got, want)
+    assert np.abs(np.asarray(got)).max() > 0
+
+
 def _equations_before(jaxpr, wanted):
     """Every equation of ``jaxpr`` (and of the closed jaxprs inside those)
     that the variables ``wanted`` are computed from."""
@@ -208,15 +257,27 @@ def test_nothing_of_a_head_wise_keys_size_is_moved_between_kv_b_proj_and_the_lau
     def the_launch(q, k, v, keep):
         return q + k + v
 
-    monkeypatch.setattr(glm, "selected_attention",
-                        lambda q, k, v, scale, keep: the_launch(q, k, v, keep))
+    turn_before = False  # the stand-in takes q as the launch does: unturned
+
+    def stand_in(q, k, v, scale, keep, rotary):
+        if turn_before:
+            q = rotary.apply(q.reshape(*q.shape[:2], -1), H).reshape(q.shape)
+        return the_launch(q, k, v, keep)
+
+    monkeypatch.setattr(glm, "selected_attention", stand_in)
     layer = glm.GlmLayer(model.trunk, 1)  # published layer 3: shared, sparse
     n, L, H, nope = 3, 17, 4, 24
     x = jnp.zeros((n, L, 64))
-    jaxpr = jax.make_jaxpr(lambda p, x, keep: layer.apply({"params": p}, x, keep))(
-        params["layers_1"], x, jnp.ones((n, 24, 24), jnp.int8)).jaxpr
-    (call,) = [e for e in jaxpr.eqns if e.params.get("name") == "the_launch"]
-    q, k, v, _ = call.invars
+
+    def traced():
+        jaxpr = jax.make_jaxpr(
+            lambda p, x, keep: layer.apply({"params": p}, x, keep))(
+            params["layers_1"], x, jnp.ones((n, 24, 24), jnp.int8)).jaxpr
+        (call,) = [e for e in jaxpr.eqns
+                   if e.params.get("name") == "the_launch"]
+        return jaxpr, call.invars
+
+    jaxpr, (q, k, v, _) = traced()
     assert k.aval.shape == v.aval.shape == (n, L, H, 32)
     before = _equations_before(jaxpr, {k, v})
     names = {e.primitive.name for e in before}
@@ -227,11 +288,16 @@ def test_nothing_of_a_head_wise_keys_size_is_moved_between_kv_b_proj_and_the_lau
              for var in (*e.invars, *e.outvars)
              if np.prod(var.aval.shape) >= n * L * H * nope]
     assert not moved, moved
-    # the walk does tell: q, rotated as a whole array, is rolled by slices
-    rolled = [e for e in _equations_before(jaxpr, {q})
-              if e.primitive.name in ("slice", "concatenate")
-              and np.prod(e.outvars[0].aval.shape) >= n * L * H * nope]
-    assert rolled
+    # nor is q: it reaches the launch as q_b_proj wrote it, and is turned there
+    rolls = lambda jaxpr, q: [
+        e for e in _equations_before(jaxpr, {q})
+        if e.primitive.name in ("slice", "concatenate")
+        and np.prod(e.outvars[0].aval.shape) >= n * L * H * nope]
+    assert not rolls(jaxpr, q)
+    # the walk does tell: a q rotated as a whole array is rolled by slices
+    turn_before = True
+    jaxpr, (q, *_) = traced()
+    assert rolls(jaxpr, q)
 
 
 def test_the_parameter_tree_keeps_the_published_names_shapes_and_column_order():
@@ -380,8 +446,8 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 def test_the_named_scopes_and_counters_of_a_trace():
     """``trunk/mla | dsa_index | moe | mlp`` in the lowered text; one count a
-    traced layer by indexer kind, one a selection and three an expert layer
-    by path."""
+    traced layer by indexer kind and by where q's rotation runs, one a
+    selection and three an expert layer by path."""
     model, params = model_and_params("float32")
     x, t = inputs()
     metrics.reset()
@@ -393,11 +459,13 @@ def test_the_named_scopes_and_counters_of_a_trace():
     for series in metrics.snapshot().values():
         for name in ("kernels.dsa_indexer_layers", "kernels.dsa_select_schedule",
                      "kernels.moe_gmm_schedule",
-                     "kernels.moe_gate_up_schedule"):
+                     "kernels.moe_gate_up_schedule",
+                     "kernels.flash_fwd_rotary"):
             for key, count in series.get(name + "/by_key", {}).items():
                 by_key[name, key] = by_key.get((name, key), 0) + count
     assert by_key == {("kernels.dsa_indexer_layers", "full"): 2,
                       ("kernels.dsa_indexer_layers", "shared"): 3,
+                      ("kernels.flash_fwd_rotary", "xla"): 5,  # off the TPU
                       ("kernels.dsa_select_schedule", "xla"): 2,
                       ("kernels.moe_gmm_schedule", "xla"): 12,
                       ("kernels.moe_gate_up_schedule", "xla"): 4}

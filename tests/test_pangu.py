@@ -20,6 +20,7 @@ from ddim_cold_tpu import serve
 from ddim_cold_tpu.models import glm, hybrid, pangu
 from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import sampling
+from ddim_cold_tpu.ops.rotary import apply_rotary
 
 PUBLISHED = dict(
     model_type="pangu_ultra_moe", hidden_size=64, intermediate_size=128,
@@ -292,6 +293,8 @@ def test_the_latent_projections_are_one_piece_of_code_for_both_stacks():
     c_q2, q, k_r2, kv = Both().apply({"params": published}, y, False)
     np.testing.assert_array_equal(c_q, c_q2)
     np.testing.assert_array_equal(k_r, k_r2)
+    # the published path hands q on unturned, for its reader's launch to turn
+    q = apply_rotary(q, 2, *pangu._rope(TRUNK), first=128)
     q, kv = q.reshape(1, 5, 2, 192), kv.reshape(1, 5, 2, 256)
     close = lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     close(q_nope.reshape(1, 5, 2, 128), q[..., :128])
