@@ -25,8 +25,10 @@ per-layer lists (``layer_types``, ``mlp_layer_types``,
   head_dim^−½`` under the mask j ≤ t (``full_attention``) or t −
   ``sliding_window`` < j ≤ t (``sliding_attention``), softmax in float32;
   ``o_h = g_h · Σ_j p_hj v_j``; out ``= concat_h(o_h) W_o``.
-  ``ops.flash_attention.masked_attention`` computes it: the ``fwd_masked``
-  kernel on the TPU, blockwise XLA elsewhere.
+  ``ops.flash_attention.masked_attention`` computes it, handed q UNTURNED
+  with its rotation (``ops.rotary.Rotary``): on the TPU the ``fwd_masked``
+  kernel, which turns each q block where it holds it; elsewhere
+  ``apply_rotary`` and blockwise XLA. k is turned here, by ``apply_rotary``.
 * ``FFN_i``: ``mlp_layer_types[i] == "dense"``: the gated SiLU MLP at
   ``intermediate_size``; ``"sparse"``: ``moe.HeldExpertsMlp``, softmax router
   over ``num_experts_routed`` outputs, ``num_experts_per_tok`` a token,
@@ -58,7 +60,7 @@ from ddim_cold_tpu.models.hybrid import GatedMlp, RMSNorm
 from ddim_cold_tpu.models.init import trunc_normal
 from ddim_cold_tpu.models.moe import HeldExpertsMlp
 from ddim_cold_tpu.ops.flash_attention import masked_attention
-from ddim_cold_tpu.ops.rotary import apply_rotary
+from ddim_cold_tpu.ops.rotary import Rotary, apply_rotary
 
 Dtype = Any
 
@@ -155,7 +157,9 @@ class GatedAttention(nn.Module):
             param_dtype=self.param_dtype, kernel_init=trunc_normal(std=0.02),
             name=name)
         rope = rotary_frequencies(c["rope_parameters"][self.layer_type], hd)
-        q = apply_rotary(dense(heads * hd, "q_proj")(x), heads, *rope)
+        # q goes on as q_proj wrote it: the attention turns it where it holds
+        # it; k, a sixth to a ninth of q's width, is turned here
+        q = dense(heads * hd, "q_proj")(x)
         k = apply_rotary(dense(kv * hd, "k_proj")(x), kv, *rope)
         v = dense(kv * hd, "v_proj")(x)
         gate = jax.nn.sigmoid(dense(heads, "g_proj")(x).astype(jnp.float32))
@@ -163,7 +167,8 @@ class GatedAttention(nn.Module):
                   if self.layer_type == "sliding_attention" else None)
         out = masked_attention(
             q.reshape(n, L, heads, hd), k.reshape(n, L, kv, hd),
-            v.reshape(n, L, kv, hd), hd ** -0.5, causal=True, window=window)
+            v.reshape(n, L, kv, hd), hd ** -0.5, causal=True, window=window,
+            rotary=Rotary(*rope))
         # a head's gate on each of its lanes, token-major as the context is
         out = (out.reshape(n, L, heads * hd).astype(jnp.float32)
                * jnp.repeat(gate, hd, axis=-1)).astype(self.dtype)

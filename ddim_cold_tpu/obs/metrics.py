@@ -115,7 +115,8 @@ METRICS = (
     ("kernels.flash_fwd_mask", "counter",
      "flash forward traces by mask (key: none|causal|window|selected)"),
     ("kernels.flash_fwd_rotary", "counter",
-     "selected-forward traces handed an unturned q, by where its rotation "
+     "selected- and masked-forward traces handed an unturned q, by where "
+     "its rotation "
      "runs (key: kernel, in the launch on the q block it holds; xla, "
      "apply_rotary before it)"),
     ("kernels.flash_latent_schedule", "counter",

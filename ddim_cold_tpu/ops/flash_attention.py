@@ -1606,27 +1606,30 @@ _WALK_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _fwd_masked_call(q, k, v, keep=None, *tables, rep, lanes, scale, n_valid,
+def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
                      bq, bkv, causal, window, interpret, turn=None):
-    """The masked launch (``fwd_masked``), or with ``keep`` — an int8 ``(rows,
-    tokens⁺, tokens⁺)`` selection in whole ``(bq, bkv)`` tiles, one for all
-    the heads — the selected one (``fwd_selected``: the same body, a name of
-    its own in a device trace). ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
+    """The masked launch (``fwd_masked``), or ``selected``, with ``keep``
+    first in ``more`` — an int8 ``(rows, tokens⁺, tokens⁺)`` selection in
+    whole ``(bq, bkv)`` tiles, one for all the heads — the selected one
+    (``fwd_selected``: the same body, a name of its own in a device trace).
+    ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
     ``(rows, tokens, H/rep·lanes)``, query head ``h`` reading K/V column
     block ``h // rep``; all three where the projections wrote them, the token
     axis ending inside the last block. Grid ``(rows, H, q blocks, visited
     chunks)``: the last axis is as long as the most chunks any q block sees
     (two for a window of 512 at these blocks; all of them under a causal
     mask), and a block that sees fewer re-addresses its last chunk, which is
-    not fetched again, and skips the fold. ``turn`` with ``tables``, the
-    float32 ``(tokens⁺, 128)`` cos and sin of the rotated lane group in whole
-    q blocks: q is unturned and the launch turns it
+    not fetched again, and skips the fold. ``turn`` with two tables last in
+    ``more``, the float32 ``(tokens⁺, 128)`` cos and sin of the rotated lane
+    group in whole q blocks: q is unturned and the launch turns it
     (:func:`_fwd_masked_kernel`), a ``(bq, 128)`` block of each table a q
     block and one more scratch, the turned block; the grid is then ``(rows, q
     blocks, H, visited chunks)``, the heads INSIDE the q blocks, so that a q
     block's tables are fetched once for all its heads and not once a head
-    (0.6 GB a launch at 9,217 tokens × 64 heads, under steps that have no
-    time to hide it); every other block is fetched as often either way."""
+    (0.6 GB a ``fwd_selected`` launch at 9,217 tokens × 64 heads, 0.7 GB a
+    ``fwd_masked`` one at 4 × 4,097 × 72, under steps that have no time to
+    hide it); every other block is fetched as often either way."""
+    keep, tables = (more[0], more[1:]) if selected else (None, more)
     rows, tokens, width = q.shape
     geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
                     window=window)
@@ -1669,7 +1672,8 @@ def _fwd_masked_call(q, k, v, keep=None, *tables, rep, lanes, scale, n_valid,
 
 
 def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
-                           window: int | None = None) -> jax.Array:
+                           window: int | None = None,
+                           rotary: Rotary | None = None) -> jax.Array:
     """The forward under a mask and on shared K/V heads, as its own launch
     (``pallas_call(name="fwd_masked")``, ``%fwd_masked`` in a device trace, so
     that what reads ``%fwd`` keeps reading the unmasked kernel).
@@ -1683,10 +1687,14 @@ def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
     other head size is zero-padded to the lanes first (a copy in HBM on each
     side). K/V chunks that lie wholly outside the mask of a q block are
     neither fetched nor computed (:func:`_fwd_masked_call`). Blocks come from
-    the shape. No backward yet: the VJP raises by name (ROADMAP Reach)."""
+    the shape. ``rotary``: q comes UNTURNED and the launch turns the q block
+    it holds, as :func:`flash_attention_selected` says (a head of 128 whose
+    every dim turns, or whose first half does: its one lane group). No
+    backward yet: the VJP raises by name (ROADMAP Reach)."""
     _check_shared_heads(q, k, v)
     _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, window))
-    return _masked_forward(q, k, v, None, scale, causal, window)
+    q, rotary = _turned_where_it_must_be(q, rotary)
+    return _masked_forward(q, k, v, None, scale, causal, window, rotary)
 
 
 def _check_shared_heads(q, k, v) -> None:
@@ -1723,17 +1731,18 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
         specs += (P(), P())
     out = per_device(
         functools.partial(
-            _fwd_masked_call, rep=H // KV, lanes=lanes, scale=scale, n_valid=N,
-            bq=bq, bkv=bkv, causal=causal, window=window,
-            interpret=kernel_interpret(), turn=turn),
+            _fwd_masked_call, selected=keep is not None, rep=H // KV,
+            lanes=lanes, scale=scale, n_valid=N, bq=bq, bkv=bkv, causal=causal,
+            window=window, interpret=kernel_interpret(), turn=turn),
         specs, spec,
     )(*operands)
     return out.reshape(B, N, H, lanes)[..., :D]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _masked_no_vjp(q, k, v, scale, causal, window):
-    return flash_attention_masked(q, k, v, scale, causal=causal, window=window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _masked_no_vjp(q, k, v, scale, causal, window, rotary=None):
+    return flash_attention_masked(q, k, v, scale, causal=causal, window=window,
+                                  rotary=rotary)
 
 
 def _masked_no_vjp_fwd(*args):
@@ -1748,15 +1757,18 @@ _masked_no_vjp.defvjp(_masked_no_vjp_fwd, lambda *a: None)
 
 
 def masked_attention(q, k, v, scale: float, *, causal: bool = True,
-                     window: int | None = None) -> jax.Array:
+                     window: int | None = None,
+                     rotary: Rotary | None = None) -> jax.Array:
     """Attention under a causal mask or a causal window on shared K/V heads,
-    shapes as :func:`flash_attention_masked`; the backend decides what runs:
-    that kernel on the TPU, :func:`blockwise_attention_xla` (plain JAX,
-    differentiable) anywhere else."""
+    shapes as :func:`flash_attention_masked`, q unturned where ``rotary`` says
+    what still turns it; the backend decides what runs: that kernel on the
+    TPU, which turns q itself where it can; anywhere else ``apply_rotary``
+    (``kernels.flash_fwd_rotary`` counts ``xla``) and
+    :func:`blockwise_attention_xla` (plain JAX, differentiable)."""
     if jax.default_backend() == "tpu":
-        return _masked_no_vjp(q, k, v, scale, causal, window)
-    return blockwise_attention_xla(q, k, v, scale, causal=causal,
-                                   window=window)
+        return _masked_no_vjp(q, k, v, scale, causal, window, rotary)
+    return blockwise_attention_xla(_turned_by_xla(q, rotary), k, v, scale,
+                                   causal=causal, window=window)
 
 
 def flash_attention_selected(q, k, v, scale: float, keep,
@@ -1793,18 +1805,28 @@ def flash_attention_selected(q, k, v, scale: float, keep,
         raise ValueError(f"keep {keep.dtype}{keep.shape}: the selection of "
                          f"{N} tokens is int8{want}")
     _kernels.inc("kernels.flash_fwd_mask", key="selected")
-    if rotary is not None:
-        in_launch = _turn_geometry(rotary) is not None
-        _kernels.inc("kernels.flash_fwd_rotary",
-                     key="kernel" if in_launch else "xla")
-        if not in_launch:
-            q, rotary = _turned_by_xla(q, rotary), None
+    q, rotary = _turned_where_it_must_be(q, rotary)
     return _masked_forward(q, k, v, keep, scale, True, None, rotary)
 
 
-def _turned_by_xla(q, rotary: Rotary):
+def _turned_by_xla(q, rotary: Rotary | None):
+    """``apply_rotary`` on ``(B, N, H, D)``, counted; q as it is where no
+    rotation came with it."""
+    if rotary is None:
+        return q
     B, N, H, D = q.shape
+    _kernels.inc("kernels.flash_fwd_rotary", key="xla")
     return rotary.apply(q.reshape(B, N, H * D), H).reshape(q.shape)
+
+
+def _turned_where_it_must_be(q, rotary: Rotary | None) -> tuple:
+    """``(q, rotary)`` as :func:`_masked_forward` takes them: a rotation the
+    launch can run itself (:func:`_turn_geometry`) handed on beside the
+    unturned q and counted ``kernel``; any other applied here, by XLA."""
+    if rotary is None or _turn_geometry(rotary) is None:
+        return _turned_by_xla(q, rotary), None
+    _kernels.inc("kernels.flash_fwd_rotary", key="kernel")
+    return q, rotary
 
 
 def selected_attention_xla(q, k, v, scale: float, keep) -> jax.Array:
@@ -1851,10 +1873,7 @@ def selected_attention(q, k, v, scale: float, keep,
     :func:`selected_attention_xla`."""
     if jax.default_backend() == "tpu":
         return _selected_no_vjp(q, k, v, keep, scale, rotary)
-    if rotary is not None:
-        _kernels.inc("kernels.flash_fwd_rotary", key="xla")
-        q = _turned_by_xla(q, rotary)
-    return selected_attention_xla(q, k, v, scale, keep)
+    return selected_attention_xla(_turned_by_xla(q, rotary), k, v, scale, keep)
 
 
 # ---------------------------------------------------------------------------

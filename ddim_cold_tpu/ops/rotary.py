@@ -1,8 +1,9 @@
 """Rotary positions on token-major arrays: the per-lane tables, the rotation
 in XLA (:func:`apply_rotary`), and :class:`Rotary`, a rotation handed on
 unapplied to a launch that can turn the block it already holds
-(``ops.flash_attention.selected_attention``). The frequencies come from the
-stacks' configurations (``models.laguna.rotary_frequencies``)."""
+(``ops.flash_attention.selected_attention`` and ``masked_attention``). The
+frequencies come from the stacks' configurations
+(``models.laguna.rotary_frequencies``)."""
 
 from __future__ import annotations
 
@@ -49,16 +50,20 @@ def apply_rotary(x, heads: int, inv_freq, scale: float, *,
     with dim j + rot/2) or ``interleave`` (dim 2j with dim 2j + 1). Written on
     the token-major array as the projection left it — per-lane tables and two
     lane rolls, no ``(n, L, heads, head_dim)`` view — so that q and k reach the
-    attention kernel in the layout it reads (a 4-d view costs a copy of q on
-    each side of the rotation: 0.6 GB in float32 at 4 x 4,097 x 9,216).
+    attention kernel in the layout it reads (a 4-d view costs a copy of the
+    array on each side of the rotation).
 
     Every lane of ``x`` goes through float32, the ones that pass through too
-    (times 1, plus 0): right where every dim turns (the ``laguna`` stack's q
-    and k), cheap on a narrow array (the latent stacks' ``k_r`` and ``q_r``
-    apart, the indexer's q and k), and what :class:`Rotary` is there to avoid
-    on the ``glm`` stack's q, a quarter of whose columns turn. With ``first``
-    it is called by :meth:`Rotary.apply` alone: off the TPU, and for a head
-    the ``fwd_selected`` launch cannot turn."""
+    (times 1, plus 0), in passes over the whole array in HBM — the convert,
+    two rolls, a select, two products, an add. Cheap on a narrow array: the
+    ``laguna`` stack's k (8 K/V heads, a sixth to a ninth of q), the latent
+    stacks' ``k_r`` and ``q_r`` apart, the indexer's q and k. On a wide one
+    it is what :class:`Rotary` is there to avoid: the ``glm`` stack's q (64
+    heads of 256, a quarter of whose columns turn) and the ``laguna`` stack's
+    (48 or 72 heads of 128, every dim or the first half) reach their launch
+    unturned, and this function turns them only off the TPU and for a head
+    the launch cannot turn (:meth:`Rotary.apply`, its one caller with
+    ``first``)."""
     n, L, W = x.shape
     hd, half = W // heads, len(inv_freq)
     cos, sin = rotary_tables(L, hd, inv_freq, scale, pairing=pairing,

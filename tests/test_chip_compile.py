@@ -627,6 +627,83 @@ def test_fwd_masked_lowers_at_the_published_shapes_and_keeps_its_name(
     assert not flash_fwd_roofline.NAME.match(calls[0])
 
 
+def _bench_config(name):
+    """A configuration of the benchmark, as its drivers read it."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, f"benchmark/configs/{name}.json")) as f:
+        return json.load(f)
+
+
+def test_the_laguna_forward_traced_for_the_tpu_turns_q_in_the_launch(chip):
+    """One trace of the cell's whole forward (``laguna_s21_ep2_px1024`` as the
+    benchmark builds it, shapes only) on the TPU's path: every one of its
+    five attention layers hands ``fwd_masked`` the q that ``q_proj`` wrote and
+    the launch turns it (``kernels.flash_fwd_rotary`` = ``kernel``)."""
+    from benchmark.drivers import sample_closed_moe
+    from ddim_cold_tpu.obs import metrics
+
+    config = _bench_config("laguna_s21_ep2_px1024")
+    model = sample_closed_moe.build_model(config)
+    x = jnp.zeros((1, *config["img_size"], 3), jnp.float32)
+    t = jnp.zeros((1,), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x, t)
+    metrics.reset()
+    jax.eval_shape(model.apply, params, x, t)
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"kernel": 5}
+    assert fa._kernels.by_key("kernels.flash_fwd_mask") == {
+        "causal": 2, "window": 3}
+    metrics.reset()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("kind,heads", [("sliding_attention", 72),
+                                        ("full_attention", 48)])
+def test_laguna_attention_compiled_for_the_chip_turns_q_in_the_launch(
+        kind, heads, dtype, chip):
+    """``models/laguna.GatedAttention`` at the cell's shape (4 x 4,097 tokens,
+    72 heads under the window, 48 under the causal mask, 8 K/V heads of 128),
+    compiled: ONE launch, named ``%fwd_masked``, whose q operand is
+    ``q_proj``'s own GEMM in the model's dtype — no pass over q between
+    them. In float32 the model still compiles, and turns q the same way. The
+    float32 values of q's size that stay are the GATE's (``jnp.repeat(gate,
+    128)`` and its product with the context, both sides of this change have
+    them): none is ``q_proj``'s and none is a roll's slice, which is what
+    ``apply_rotary`` left of q (k, a ninth to a sixth as wide, keeps them)."""
+    from benchmark.layer_metrics import flash_masked_fwd_roofline as reader
+    from ddim_cold_tpu.models.laguna import GatedAttention
+
+    config = _bench_config("laguna_s21_ep2_px1024")
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, hd = LAGUNA["n"], LAGUNA["L"], LAGUNA["hd"]
+    module = GatedAttention(config, kind, heads, dtype=dtype, param_dtype=dtype)
+    x = jnp.zeros((n, L, config["hidden_size"]), dtype)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    text = jax.jit(module.apply).lower(
+        jax.tree.map(lambda a: sds(a.shape, a.dtype), params),
+        sds(x.shape, x.dtype)).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    made = {m.group(1): m for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\((.*)$",
+        entry, flags=re.M)}
+    calls = [m for m in made.values() if "tpu_custom_call" in m.group(5)]
+    assert len(calls) == 1 and reader.NAME.match(calls[0].group(0).strip())
+    wide = f"{n},{L},{heads * hd}"
+    short = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    # the launch's first operand, through bitcasts, is q_proj's GEMM
+    source = made[re.match(r"(%[\w.\-]+)", calls[0].group(5)).group(1)]
+    while source.group(4) == "bitcast":
+        source = made[re.match(r"(%[\w.\-]+)", source.group(5)).group(1)]
+    assert "q_proj/dot_general" in source.group(5), source.group(0)
+    assert (source.group(2), source.group(3)) == (short, wide)
+    k_width = config["num_key_value_heads"] * hd
+    for m in re.finditer(r"= f32\[([\d,]+)\][^\n]*", text):
+        if int(m.group(1).split(",")[-1]) > k_width:  # wider than k is
+            assert "_roll_static" not in m.group(0), m.group(0)
+            assert dtype == jnp.float32 or "q_proj" not in m.group(0), m.group(0)
+
+
 @pytest.mark.parametrize("rows,K,N", [
     (81940, 3072, 1024),    # the rows routed here on average: gate, up
     (163968, 3072, 1024),   # the expert layer's buffer: every assignment
@@ -757,14 +834,10 @@ def test_the_glm_forward_traced_for_the_tpu_turns_q_in_the_launch(chip):
     benchmark builds it, shapes only) on the TPU's path: every one of its five
     attention layers hands ``fwd_selected`` an unturned q and the launch
     turns it (``kernels.flash_fwd_rotary`` = ``kernel``)."""
-    import json
-
     from benchmark.drivers import sample_closed_glm
     from ddim_cold_tpu.obs import metrics
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark/configs/glm52_ep16_px1536.json")) as f:
-        config = json.load(f)
+    config = _bench_config("glm52_ep16_px1536")
     model = sample_closed_glm.build_model(config)
     x = jnp.zeros((1, *config["img_size"], 3), jnp.float32)
     t = jnp.zeros((1,), jnp.int32)

@@ -4,8 +4,9 @@ in interpreter mode, against dense float32 attention and against
 masks; 1, 6 and 9 query heads a K/V head; token counts that are and are not
 multiples of the 512-token chunk; a window smaller and larger than a chunk;
 which chunks a q block visits; the counter; the unmasked path untouched. And
-the option of the same body that ``fwd_selected`` takes: an UNTURNED q turned
-inside the launch, once a q block, against ``apply_rotary`` before it."""
+the option of the same body that ``fwd_selected`` and ``fwd_masked`` take: an
+UNTURNED q turned inside the launch, once a q block, against ``apply_rotary``
+before it."""
 
 import jax
 import jax.extend
@@ -277,6 +278,102 @@ def test_a_head_whose_rotated_dims_straddle_a_lane_group_is_turned_before_the_la
     metrics.reset()
 
 
+#: Laguna-S-2.1's two entries of ``rope_parameters`` at a head of 128: every
+#: dim turns, partner 64 lanes away (window layers); the first 64 dims turn by
+#: YaRN's frequencies, its factor in the tables (full layers)
+LAGUNA_ROPE = {
+    "window": {"rope_type": "default", "rope_theta": 10000,
+               "partial_rotary_factor": 1},
+    "causal": {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+               "original_max_position_embeddings": 8192, "beta_slow": 1,
+               "beta_fast": 32, "attention_factor": 1.4852030263919618,
+               "partial_rotary_factor": 0.5}}
+
+
+def _laguna_rotary(kind, head_dim=128):
+    from ddim_cold_tpu.models.laguna import rotary_frequencies
+
+    return Rotary(*rotary_frequencies(LAGUNA_ROPE[kind], head_dim))
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("rep", [6, 9])
+@pytest.mark.parametrize("kind,window,half", [("causal", None, 32),
+                                              ("window", 512, 64)])
+def test_the_masked_launch_turns_an_unturned_q(kind, window, half, rep, rows):
+    """``fwd_masked`` with the tables on q as ``q_proj`` wrote it against the
+    same launch without them on ``apply_rotary``'s q: Laguna-S-2.1's two
+    rotations (``half`` pairs of a 128 head), 6 and 9 query heads a K/V head,
+    1 and 4 rows, 600 tokens — the second q block ends inside its 512 — and
+    the model's scale, which is no power of two and so stays on the scores.
+    On bfloat16 operands BIT FOR BIT: here the interpreted body and XLA's
+    fusion round the rotated lanes alike."""
+    rotary, scale = _laguna_rotary(kind), 128 ** -0.5
+    assert fa._turn_geometry(rotary) == (0, 0, half, True)
+    assert not fa._scale_folds_into_q(scale)
+    q, k, v = _qkv(600, rep, 1, 128, B=rows, seed=rep + rows,
+                   dtype=jnp.bfloat16)
+    got = fa.flash_attention_masked(q, k, v, scale, window=window,
+                                    rotary=rotary)
+    want = fa.flash_attention_masked(_turned(q, rotary), k, v, scale,
+                                     window=window)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_array_equal(got, want)
+    # and it is the TURNED q that was attended with
+    f32 = lambda x: np.asarray(x, np.float32)
+    assert np.abs(f32(got) - f32(fa.flash_attention_masked(
+        q, k, v, scale, window=window))).max() > 0.5
+
+
+@pytest.mark.parametrize("kind,window", [("causal", None), ("window", 8)])
+def test_the_masked_launch_turns_a_float32_q_in_padded_heads(kind, window):
+    """The toy trunk's shape in float32 — heads of 16 zero-padded to the
+    lanes, 37 tokens in one block, 2 and 3 query heads a K/V head, 3 rows:
+    the launch turns the first 8 (full) or all 16 (window) dims of each
+    padded head, to float32 rounding of ``apply_rotary`` then the launch and
+    of dense attention."""
+    rotary = _laguna_rotary(kind, 16)
+    assert fa._turn_geometry(rotary) == (0, 0, 4 if window is None else 8, True)
+    q, k, v = _qkv(37, 6 if window else 4, 2, 16, B=3, seed=5)
+    got = fa.flash_attention_masked(q, k, v, 0.25, window=window,
+                                    rotary=rotary)
+    turned = _turned(q, rotary)
+    np.testing.assert_allclose(got, fa.flash_attention_masked(
+        turned, k, v, 0.25, window=window), rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(got, _dense(turned, k, v, 0.25, True, window),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_off_the_tpu_masked_attention_turns_q_before_the_oracle():
+    """``masked_attention(..., rotary=)`` on the CPU: ``apply_rotary`` then
+    ``blockwise_attention_xla``, bit for bit, counted ``xla``; called
+    directly, the launch counts ``kernel``, and ``xla`` for a head it cannot
+    turn, which it is handed turned; no rotation, no count."""
+    q, k, v = _qkv(40, 6, 2, 128)
+    rotary = _laguna_rotary("causal")
+    count = lambda: fa._kernels.by_key("kernels.flash_fwd_rotary")
+    metrics.reset()
+    fa.masked_attention(q, k, v, 0.1, window=16)
+    fa.flash_attention_masked(q, k, v, 0.1, window=16)
+    assert count() == {}
+    got = fa.masked_attention(q, k, v, 0.1, window=16, rotary=rotary)
+    assert count() == {"xla": 1}
+    np.testing.assert_array_equal(got, fa.blockwise_attention_xla(
+        apply_rotary(q.reshape(1, 40, 768), 6, *rotary[:2]).reshape(q.shape),
+        k, v, 0.1, causal=True, window=16))
+    jax.make_jaxpr(lambda q: fa.flash_attention_masked(
+        q, k, v, 0.1, rotary=rotary))(q)
+    assert count() == {"xla": 1, "kernel": 1}
+    straddling = _rotary(64, "rotate_half", 96)
+    q2, k2, v2 = _qkv(40, 2, 1, 256)
+    got = fa.flash_attention_masked(q2, k2, v2, 0.1, rotary=straddling)
+    assert count() == {"xla": 2, "kernel": 1}
+    np.testing.assert_array_equal(got, fa.flash_attention_masked(
+        straddling.apply(q2.reshape(1, 40, 512), 2).reshape(q2.shape),
+        k2, v2, 0.1))
+    metrics.reset()
+
+
 def _launch(fn, *args):
     """The one ``pallas_call`` equation in the jaxpr of ``fn(*args)``."""
     found = []
@@ -300,8 +397,8 @@ def test_the_launches_without_the_option_are_the_parents():
     """``fwd_masked``, ``fwd_latent`` and ``fwd_selected`` without a rotation
     to run: the operands, the scratch (accumulator, running max, running
     denominator — and a head's half of q_r for the latent launch) and the
-    names they had; with it ``fwd_selected`` keeps its name and takes two
-    tables and one more scratch, the turned q block."""
+    names they had; with it ``fwd_selected`` and ``fwd_masked`` keep their
+    names and take two tables and one more scratch, the turned q block."""
     f32 = jnp.dtype("float32")
     walk = lambda bq, lanes: [((bq, lanes), f32), ((bq, 128), f32),
                               ((bq, 128), f32)]
@@ -320,6 +417,9 @@ def test_the_launches_without_the_option_are_the_parents():
     assert _launch(lambda q, k, v, m: fa.flash_attention_selected(
         q, k, v, 0.1, m, rotary), q, k, v, keep) == (
         "fwd_selected", 6, walk(40, 256) + [((40, 256), f32)])
+    assert _launch(lambda q, k, v: fa.flash_attention_masked(
+        q, k, v, 0.1, window=16, rotary=rotary), q, k, v) == (
+        "fwd_masked", 5, walk(40, 256) + [((40, 256), f32)])
 
 
 def test_the_rotary_counter_says_where_q_was_turned():

@@ -88,6 +88,31 @@ def test_forward_matches_the_reference_in_float32():
                                rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("precision", ["float32", "bfloat16"])
+def test_forward_through_the_launch_that_turns_q(precision, monkeypatch):
+    """What the TPU's path does to q, interpreted: every layer hands
+    ``fwd_masked`` the q that ``q_proj`` wrote with its rotation beside it
+    (``kernels.flash_fwd_rotary`` = ``kernel``, once a layer) and the launch
+    turns it, in the padded lanes of a 16-dim head. Against the reference, as
+    the forward that turns q with ``apply_rotary`` is held above."""
+    from ddim_cold_tpu.obs import metrics
+    from ddim_cold_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(laguna, "masked_attention", fa.flash_attention_masked)
+    model, params = model_and_params(precision)
+    x, t = inputs()
+    metrics.reset()
+    got = model.apply({"params": params}, x, t)
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"kernel": 5}
+    metrics.reset()
+    want = reference_forward(params, x, t)
+    if precision == "float32":
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+    else:
+        assert rms(got, want) < BF16_FORWARD_RMS, rms(got, want)
+
+
 #: rms of one bfloat16 forward against the float32 reference on the same
 #: bfloat16 tree: bfloat16 rounding through 5 layers reads 1e-3 on outputs of
 #: rms 0.16 (a row whose top-3 flips to another expert included); the float8
@@ -558,8 +583,10 @@ def test_x004_every_spelling_is_refused_whatever_the_stack():
 
 def test_the_named_scopes_and_counters_of_a_trace():
     """``trunk/attn_full | attn_window | moe | mlp`` in the lowered text, and
-    one count a trace on each of the two kernels' counters."""
+    one count a trace on each of the two kernels' counters and on the one
+    that says where q was turned."""
     from ddim_cold_tpu.obs import metrics
+    from ddim_cold_tpu.ops import flash_attention as fa
 
     model, params = model_and_params("float32")
     x, t = inputs()
@@ -575,4 +602,6 @@ def test_the_named_scopes_and_counters_of_a_trace():
         first_half.update(series.get("kernels.moe_gate_up_schedule/by_key", {}))
     assert by_key == {"xla": 12}  # 4 sparse layers x gate, up, down
     assert first_half == {"xla": 4}  # gate and up: one call a layer
+    # off the TPU every layer's q is turned by apply_rotary, before the oracle
+    assert fa._kernels.by_key("kernels.flash_fwd_rotary") == {"xla": 5}
     metrics.reset()
