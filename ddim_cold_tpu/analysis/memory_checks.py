@@ -260,44 +260,3 @@ def run_memory_checks(serve_traces: dict | None = None,
                                   tokens=meta["tokens"],
                                   device_kind=device_kind)
     return findings
-
-
-def budget_report(kernel_traces: dict | None = None,
-                  device_kind: str = DEVICE_KIND) -> dict:
-    """JSON-ready static budget summary: per-200px-program peak HBM GiB and
-    per-kernel VMEM MiB, worst-case rollups first."""
-    from ddim_cold_tpu.analysis import entries, kernel_checks
-    from ddim_cold_tpu.utils import flops
-
-    if kernel_traces is None:
-        kernel_traces = entries.kernel_traces()
-    programs: dict = {}
-    kernels: dict = {}
-    findings: list[Finding] = []
-    for name in sorted(kernel_traces):
-        e, closed = kernel_traces[name]
-        meta = e.meta or {}
-        if meta.get("memory"):
-            programs[name] = round(peak_live_bytes(closed) / 2**30, 3)
-            findings += check_program(closed, name, e.path,
-                                      tokens=meta["tokens"],
-                                      device_kind=device_kind)
-        seen = 0
-        for call in kernel_checks.iter_kernel_calls(closed, e.path):
-            seen += 1
-            key = f"{name}:{call.name}#{seen}"
-            kernels[key] = round(call.vmem_bytes() / 2**20, 3)
-        findings += kernel_checks.check_program(
-            closed, name, e.path, logical=meta.get("tokens"),
-            device_kind=device_kind)
-    return {
-        "device_kind": device_kind,
-        "hbm_budget_gib": round((flops.hbm_bytes(device_kind) or 0) / 2**30),
-        "vmem_budget_mib": round(
-            (flops.vmem_bytes(device_kind) or 0) / 2**20),
-        "peak_hbm_gb": max(programs.values()) if programs else None,
-        "max_kernel_vmem_mb": max(kernels.values()) if kernels else None,
-        "programs": programs,
-        "kernels": kernels,
-        "findings": [f.render() for f in findings],
-    }

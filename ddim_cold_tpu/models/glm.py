@@ -239,20 +239,18 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
 
 
 def latent_projections(c: Mapping[str, Any], y, rope, pairing: str, dtype,
-                       param_dtype, *, apart: bool = False):
-    """:func:`latent_paths` and the key/value up-projection ``kv_b_proj`` in
-    the same column order: ``(c_q, q, k_r, kv)``. Published (``apart``
-    false): ``kv (n, L, H·(nope + vd))``, a head's ``[k_nope, v]`` side by
-    side. ``apart``: the kernel's columns as all the k_nope then all the v,
-    ``kv = (k_nope (n, L, H·nope), v (n, L, H·vd))``, an array each."""
+                       param_dtype):
+    """:func:`latent_paths` with the columns ``apart`` and the key/value
+    up-projection ``kv_b_proj`` in the same column order: ``(c_q, (q_nope,
+    q_r), k_r, (k_nope (n, L, H·nope), v (n, L, H·vd)))``, the kernel's
+    columns as all the k_nope then all the v, an array each. (The published
+    order's reader is :class:`_KeysAndValuesInPlace`.)"""
     H, nope, vd = (c["num_attention_heads"], c["qk_nope_head_dim"],
                    c["v_head_dim"])
     kw = dict(dtype=dtype, param_dtype=param_dtype)
-    c_q, q, k_r, c_kv = latent_paths(c, y, rope, pairing, apart=apart, **kw)
-    kv = (tuple(_DenseByColumnSets((H * nope, H * vd), name="kv_b_proj",
-                                   **kw)(c_kv)) if apart
-          else _dense(H * (nope + vd), "kv_b_proj", **kw)(c_kv))
-    return c_q, q, k_r, kv
+    c_q, q, k_r, c_kv = latent_paths(c, y, rope, pairing, apart=True, **kw)
+    return c_q, q, k_r, tuple(_DenseByColumnSets(
+        (H * nope, H * vd), name="kv_b_proj", **kw)(c_kv))
 
 
 class _KeysAndValuesInPlace(nn.Module):

@@ -50,7 +50,7 @@ columns are all the heads' nope parts, then all their rotated parts
 the ``k_nope``, then all the ``v`` (published: a head's ``[k_nope, v]``), so
 that every operand of the attention is an array of its own on whole lanes
 (:func:`published_columns` is the permutation; ``glm.latent_projections``
-with ``apart``).
+is the code).
 
 **The share**, as the ``glm_moe_dsa`` and ``laguna`` stacks have it:
 ``n_routed_experts`` is how many experts THIS chip holds,
@@ -131,7 +131,7 @@ class DenseLatentAttention(nn.Module):
                             c["qk_rope_head_dim"], c["v_head_dim"])
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         _, (q_nope, q_r), k_r, (k_nope, v) = latent_projections(
-            c, y, _rope(c), "rotate_half", apart=True, **kw)
+            c, y, _rope(c), "rotate_half", **kw)
         out = flash_attention.latent_attention(
             q_nope.reshape(n, L, H, nope), q_r.reshape(n, L, H, rot),
             k_nope.reshape(n, L, H, nope), k_r, v.reshape(n, L, H, vd),

@@ -47,15 +47,18 @@ import pytest  # noqa: E402
 # crash becomes an ordinary test failure and the rest of the suite survives.
 # The same corruption occasionally DEADLOCKS the child instead of crashing
 # it; the subprocess timeout below exists to turn that wedge into the same
-# ordinary failure before it eats the tier-1 wall budget (ROADMAP's 870 s
-# outer timeout), so it must stay well under budget/2.
+# ordinary failure before it eats the tier-1 wall budget: 150 s a child
+# against the 1,470 s the gate's run is cut at (six workers, a file a worker;
+# the command is .github/workflows/ci.yml's). A child of a healthy run takes
+# 8–87 s under that load (PR 44), so a wedged one costs its worker ~100 s.
 _ISOLATED_CHILD_ENV = "DDIM_COLD_TPU_ISOLATED_CHILD"
 _ISOLATED_TIMEOUT_S = float(os.environ.get("DDIM_COLD_ISOLATED_TIMEOUT_S", "150"))
 # Suite-wide cap on signal-death retries. A single flaky crash gets its one
 # retry; a host where the native crash is DETERMINISTIC (dozens of isolated
-# tests die every run) must not pay 2× child runtime per crash — that alone
-# can blow the 870 s tier-1 budget. Once the budget is spent, further signal
-# deaths fail immediately, exactly as before the retry existed.
+# tests die every run) must not pay 2× child runtime per crash — 26 children
+# twice over is most of a worker's share of the 1,470 s. Once the budget is
+# spent, further signal deaths fail immediately, exactly as before the retry
+# existed.
 _retry_budget = int(os.environ.get("DDIM_COLD_ISOLATED_RETRIES", "3"))
 
 

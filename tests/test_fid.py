@@ -73,6 +73,14 @@ def small_variables():
     return inception.init_variables(jax.random.PRNGKey(0))
 
 
+@pytest.fixture(scope="module")
+def extractor(small_variables):
+    """``(feature_fn, dim)`` of the seeded-random extractor, ONE a module:
+    the 94-conv graph is initialised, traced and compiled once for every
+    case that pushes (4, 32, 32, 3) batches through it."""
+    return fid.make_feature_fn(*small_variables)
+
+
 def test_inception_forward_shape(small_variables):
     import jax.numpy as jnp
 
@@ -131,7 +139,7 @@ def test_torch_conversion_roundtrip(small_variables):
         np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
 
 
-def test_fid_between_images(rng):
+def test_fid_between_images(rng, extractor):
     """End-to-end on tiny images with the random-init extractor: a stream
     compared against itself gives (near-)zero; against noise it does not.
     (Small batches: each 299×299 InceptionV3 forward is ~seconds on CPU —
@@ -139,9 +147,7 @@ def test_fid_between_images(rng):
     suite's wall time.)"""
     imgs = rng.rand(8, 32, 32, 3).astype(np.float32)
     other = rng.rand(4, 32, 32, 3).astype(np.float32) * 0.2
-    import jax
-
-    feature_fn, dim = fid.make_feature_fn(*inception.init_variables(jax.random.PRNGKey(1)))
+    feature_fn, dim = extractor
     a = fid.stats_for_batches([imgs[:4], imgs[4:]], feature_fn, dim)
     b = fid.stats_for_batches([imgs[:4], imgs[4:]], feature_fn, dim)
     c = fid.stats_for_batches([other], feature_fn, dim)
@@ -181,14 +187,13 @@ def test_fid_trend_collect_points(tmp_path):
     assert [p[0] for p in mod.collect_points(str(empty), 4)] == ["random"]
 
 
-def test_random_extractor_features_do_not_collapse(rng):
+def test_random_extractor_features_do_not_collapse(rng, extractor):
     """Regression: with default lecun conv init the 94-conv stack attenuates
     activations to ~1e-4 std and every FID computes as ≈0; init_variables
     applies the √2 ReLU gain so seeded-random features stay discriminative."""
-    import jax
     import jax.numpy as jnp
 
-    feature_fn, _ = fid.make_feature_fn(*inception.init_variables(jax.random.PRNGKey(0)))
+    feature_fn, _ = extractor
     imgs = rng.rand(4, 32, 32, 3).astype(np.float32)
     feats = np.asarray(feature_fn(jnp.asarray(imgs)))
     assert feats.std() > 0.05, f"collapsed features: std={feats.std()}"

@@ -177,50 +177,6 @@ def test_forward_schedule_is_chosen_from_the_shape(monkeypatch):
     assert set(grids) == {"fwd", "dqkv"}
 
 
-@pytest.mark.parametrize("with_lse", [False, True], ids=["primal", "lse"])
-@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
-@pytest.mark.parametrize("blocks", [(None, None), (64, 128)],
-                         ids=["resident", "streamed"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("N", [8, 257, 300])
-@pytest.mark.parametrize("D", [32, 64, 128])
-def test_in_place_forward_is_bitwise_the_head_major_forward(
-        D, N, dtype, blocks, packed, with_lse, monkeypatch):
-    """The forward reading q, k, v where the projection wrote them — several
-    heads on the 128 lanes, the token axis ending inside the last block (the
-    interpreter fills what lies past it with NaN) — against the same shape
-    transposed and zero-padded to head-major first: the context BITWISE, and
-    (``with_lse``) the log-sum-exp of every true row. Handed over as three
-    arrays (``flash_attention``'s form) or as the one packed projection
-    (``flash_attention_qkv``'s)."""
-    from ddim_cold_tpu.ops import flash_attention as fa
-
-    H = 2 * 128 // D  # two lane groups
-    q, k, v = (x.astype(dtype) for x in _rand_qkv(41, 2, N, H, D))
-    scale = D ** -0.5
-    before = fa._kernels.by_key("kernels.flash_fwd_layout")
-    ours, lse = _forward(q, k, v, scale, *blocks, with_lse=with_lse,
-                         packed=packed)
-    after = fa._kernels.by_key("kernels.flash_fwd_layout")
-    assert after["in_place"] - before.get("in_place", 0) == 1
-    assert after.get("head_major", 0) == before.get("head_major", 0)
-    with monkeypatch.context() as patch:  # the rule says no: as before
-        patch.setattr(fa, "_heads_per_lane_group", lambda heads, head_dim: None)
-        want, want_lse = _forward(q, k, v, scale, *blocks, with_lse=with_lse)
-    assert fa._kernels.by_key("kernels.flash_fwd_layout")["head_major"] == (
-        before.get("head_major", 0) + 1)
-    assert ours.dtype == q.dtype and np.isfinite(
-        np.asarray(ours, np.float32)).all()
-    np.testing.assert_array_equal(np.asarray(ours, np.float32),
-                                  np.asarray(want, np.float32))
-    if with_lse:
-        assert lse.shape == want_lse.shape == (2 * H, lse.shape[1])
-        np.testing.assert_array_equal(np.asarray(lse[:, :N]),
-                                      np.asarray(want_lse[:, :N]))
-    else:
-        assert lse is None and want_lse is None
-
-
 @pytest.mark.parametrize("H,D,layout", [
     (4, 64, "in_place"),     # the 200px trunk: two heads a lane group
     (12, 32, "in_place"),    # vit_tiny: four heads a lane group
@@ -349,69 +305,6 @@ def _two_launches(monkeypatch):
     real = fa._bwd_vmem_bytes
     monkeypatch.setattr(fa, "_bwd_vmem_bytes", lambda kernel, *a: (
         1 << 40 if kernel == "dqkv" else real(kernel, *a)))
-
-
-@pytest.mark.parametrize("packed", [False, True], ids=["qkv_apart", "packed"])
-@pytest.mark.parametrize("blocks", [(None, None), (512, 512), (64, 128)],
-                         ids=["fused", "resident", "streamed"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("N", [8, 257, 300])
-@pytest.mark.parametrize("D", [32, 64, 128])
-def test_in_place_backward_is_bitwise_the_head_major_backward(
-        D, N, dtype, blocks, packed, monkeypatch):
-    """The backward — the one ``dqkv`` launch where the blocks are left to it,
-    ``dq`` and ``dkv`` where they are given — reading q, k, v and the
-    cotangent where the model holds them and writing the gradients where the
-    qkv GEMM's backward reads them — several heads on the 128 lanes, the
-    token axis ending inside the last block (the interpreter fills what lies
-    past it with NaN), the packed gradient written block by block by the one
-    launch, or begun by ``dq`` and completed by ``dkv`` — against the same
-    shape transposed and zero-padded to head-major first, at equal blocks:
-    dq, dk and dv BITWISE (``dqkv``'s dq, where heads share the lanes, to the
-    order of an f32 sum)."""
-    from ddim_cold_tpu.ops import flash_attention as fa
-
-    H = 2 * 128 // D  # two lane groups
-    q, k, v, g = (x.astype(dtype) for x in (
-        *_rand_qkv(47, 2, N, H, D), _rand_qkv(48, 2, N, H, D)[0]))
-    scale = D ** -0.5
-    chosen, real = [], fa._bwd_blocks
-
-    def spy(*args):
-        chosen.append(real(*args))
-        return chosen[-1]
-
-    monkeypatch.setattr(fa, "_bwd_blocks", spy)
-    before = fa._kernels.by_key("kernels.flash_bwd_layout")
-    ours = _backward(q, k, v, g, scale, *blocks, packed=packed)
-    after = fa._kernels.by_key("kernels.flash_bwd_layout")
-    assert after["in_place"] - before.get("in_place", 0) == 1
-    assert after.get("head_major", 0) == before.get("head_major", 0)
-    with monkeypatch.context() as patch:  # the rule says no: laid out first
-        patch.setattr(fa, "_heads_per_lane_group", lambda heads, head_dim: None)
-        want = _backward(q, k, v, g, scale, *blocks, packed=packed)
-    assert fa._kernels.by_key("kernels.flash_bwd_layout")["head_major"] == (
-        before.get("head_major", 0) + 1)
-    assert chosen[0] == chosen[1], chosen  # equal blocks, or nothing is shown
-    if blocks[0] is None:
-        assert set(chosen[0]) == {"dqkv"}
-    else:
-        streamed = blocks[0] < N and N > 128
-        assert (chosen[0]["dq"][1] < N) == streamed  # dq: K/V chunks
-        assert (chosen[0]["dkv"][0] < N) == streamed  # dkv: q chunks
-    assert ours.dtype == q.dtype and np.isfinite(
-        np.asarray(ours, np.float32)).all()
-    for name, got, ref in zip(("dq", "dk", "dv"), ours, want):
-        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
-        if name == "dq" and blocks[0] is None and D < 128:
-            # dqkv multiplies a head's OWN head_dim rows of kᵀ into its rows
-            # of dqᵀ; head-major, padded to the lanes, all 128: the same
-            # products, which the CPU's dot sums in another order
-            np.testing.assert_allclose(got, ref, err_msg=name, **(
-                dict(rtol=0, atol=2e-6) if dtype == "float32"
-                else dict(rtol=2 ** -7, atol=1e-6)))
-        else:
-            np.testing.assert_array_equal(got, ref, err_msg=name)
 
 
 @pytest.mark.parametrize("dtype,tol", [

@@ -196,17 +196,3 @@ def test_clean_in_tree_memory(kernel_traces, monkeypatch):
     # the few-step scan holds one sampler state, not k of them — its peak
     # stays in family with the stride sampler at the same dtype
     assert peaks["ns200_fewstep4_bf16"] <= peaks["ns200_bf16"] * 1.05
-
-
-def test_budget_report_rollups(kernel_traces):
-    """The report's shape: the two worst-case rollups, every program and
-    kernel entry, no finding."""
-    report = memory_checks.budget_report(kernel_traces=kernel_traces)
-    assert report["findings"] == []
-    assert 0 < report["peak_hbm_gb"] <= report["hbm_budget_gib"]
-    assert 0 < report["max_kernel_vmem_mb"] <= report["vmem_budget_mib"]
-    assert set(report["programs"]) == {"ns200_f32", "ns200_bf16",
-                                       "ns200_w8a16", "ns200_w8a16_fused",
-                                       "ns200_w8a8_fused",
-                                       "ns200_fewstep4_bf16"}
-    assert len(report["kernels"]) >= 10

@@ -263,9 +263,9 @@ def test_moe_aux_loss_layout_parity():
     np.testing.assert_allclose(float(aux_b), float(aux_a), rtol=1e-6)
 
 
-@pytest.mark.isolated
 def test_expert_mesh_axis_validated(tmp_path, synthetic_image_dir):
-    """An 'expert' mesh axis without (divisible) num_experts fails fast."""
+    """An 'expert' mesh axis without (divisible) num_experts fails fast: the
+    first thing _train checks, so nothing `isolated` contains is reached."""
     from ddim_cold_tpu.config import load_config
     from ddim_cold_tpu.train.trainer import run
     from tests.test_train import _write_config

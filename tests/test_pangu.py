@@ -268,39 +268,52 @@ def test_the_named_scopes_and_counters_of_a_trace():
 
 
 def test_the_latent_projections_are_one_piece_of_code_for_both_stacks():
-    """``glm.LatentAttention`` and ``pangu.DenseLatentAttention`` call
-    ``glm.latent_projections``: the same six leaves, and from one latent the
-    two column orders give the same heads."""
+    """``pangu.DenseLatentAttention`` calls ``glm.latent_projections``, GLM's
+    ``LatentAttention`` ``glm.latent_paths`` and its own in-place
+    ``kv_b_proj``: the same six leaves, and from one latent the two column
+    orders give the same heads."""
     import flax.linen as nn
 
-    class Both(nn.Module):
+    rope = pangu._rope(TRUNK)
+    kw = dict(dtype=jnp.float32, param_dtype=jnp.float32)
+
+    class Apart(nn.Module):  # what DenseLatentAttention runs
         @nn.compact
-        def __call__(self, y, apart):
-            rope = pangu._rope(TRUNK)
-            return glm.latent_projections(TRUNK, y, rope, "rotate_half",
-                                          jnp.float32, jnp.float32, apart=apart)
+        def __call__(self, y):
+            return glm.latent_projections(TRUNK, y, rope, "rotate_half", **kw)
+
+    class Published(nn.Module):  # what glm.LatentAttention runs
+        @nn.compact
+        def __call__(self, y):
+            c_q, q, k_r, c_kv = glm.latent_paths(TRUNK, y, rope, "rotate_half",
+                                                 **kw)
+            return c_q, q, k_r, glm._KeysAndValuesInPlace(
+                2, 128, 128, name="kv_b_proj", **kw)(c_kv, k_r)
 
     _, params = model_and_params("float32")
     p = params["layers_0"]["self_attn"]
     six = {k: v for k, v in p.items() if k != "o_proj"}
     y = jax.random.normal(jax.random.PRNGKey(8), (1, 5, 64))
-    c_q, (q_nope, q_r), k_r, (k_nope, v) = Both().apply({"params": six}, y, True)
+    c_q, (q_nope, q_r), k_r, (k_nope, v) = Apart().apply({"params": six}, y)
     # the same weights in the published order, through the published path
     undo = lambda w, a, b: w[:, np.argsort(pangu.published_columns(2, a, b))]
     published = dict(six,
                      q_b_proj={"kernel": undo(p["q_b_proj"]["kernel"], 128, 64)},
                      kv_b_proj={"kernel": undo(p["kv_b_proj"]["kernel"], 128, 128)})
-    c_q2, q, k_r2, kv = Both().apply({"params": published}, y, False)
+    c_q2, q, k_r2, (k, v2) = Published().apply({"params": published}, y)
     np.testing.assert_array_equal(c_q, c_q2)
     np.testing.assert_array_equal(k_r, k_r2)
     # the published path hands q on unturned, for its reader's launch to turn
-    q = apply_rotary(q, 2, *pangu._rope(TRUNK), first=128)
-    q, kv = q.reshape(1, 5, 2, 192), kv.reshape(1, 5, 2, 256)
+    q = apply_rotary(q, 2, *rope, first=128)
+    q, k = q.reshape(1, 5, 2, 192), k.reshape(1, 5, 2, 192)
     close = lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     close(q_nope.reshape(1, 5, 2, 128), q[..., :128])
     close(q_r.reshape(1, 5, 2, 64), q[..., 128:])
-    close(k_nope.reshape(1, 5, 2, 128), kv[..., :128])
-    close(v.reshape(1, 5, 2, 128), kv[..., 128:])
+    close(k_nope.reshape(1, 5, 2, 128), k[..., :128])
+    # every key head ends in the ONE shared k_r, placed there bit for bit
+    np.testing.assert_array_equal(
+        k[..., 128:], np.broadcast_to(np.asarray(k_r)[:, :, None], (1, 5, 2, 64)))
+    close(v, v2)
 
 
 def test_build_model_builds_the_trunk_from_a_yaml(tmp_path):
