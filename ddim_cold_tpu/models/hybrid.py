@@ -18,7 +18,11 @@ the query/key heads, a second norm on every sub-layer's result) or
 ``nemotron_h`` (``models/nemotron.py``: one sub-layer a layer, its kind read
 from a pattern string: Mamba-2 mixers whose matrix state is scanned over
 chunks, attention without a position term, ungated experts in a latent of
-their own width). One wrapper
+their own width) or ``kimi_linear`` (``models/kimi.py``: the mixer kind read
+from two published lists of layer numbers: delta attention, whose matrix
+state decays by a vector and is corrected by the delta rule, or latent
+attention with no rotation and no query latent; sigmoid-scored experts). One
+wrapper
 serves all: what the samplers and the engine read of a
 model, ``clone``, the refusals and ``__call__`` below.
 
@@ -86,8 +90,8 @@ REFUSED = {
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
                  "layers are dense XLA attention, the laguna, glm_moe_dsa, "
-                 "pangu_ultra_moe and nemotron_h stacks run their flash "
-                 "forwards wherever the backend is a TPU",
+                 "pangu_ultra_moe, nemotron_h and kimi_linear stacks run "
+                 "their flash forwards wherever the backend is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -361,9 +365,13 @@ def stack_of(trunk: Mapping[str, Any]) -> tuple:
         from ddim_cold_tpu.models import nemotron
 
         return nemotron.check_trunk, nemotron.layer
+    if model_type == "kimi_linear":
+        from ddim_cold_tpu.models import kimi
+
+        return kimi.check_trunk, kimi.layer
     raise ValueError(f"no layer stack for model_type {model_type!r}: 'jamba', "
-                     "'laguna', 'glm_moe_dsa', 'pangu_ultra_moe' and "
-                     "'nemotron_h' are written")
+                     "'laguna', 'glm_moe_dsa', 'pangu_ultra_moe', "
+                     "'nemotron_h' and 'kimi_linear' are written")
 
 
 def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:

@@ -140,6 +140,10 @@ METRICS = (
     ("kernels.ssd_schedule", "counter",
      "state-space-dual (chunked matrix-state) scan traces by path (key: "
      "kernel|xla)"),
+    # -- kernels (ops/kda.py, counted once a trace) -------------------------
+    ("kernels.kda_schedule", "counter",
+     "gated delta-rule (per-channel decay) scan traces by path (key: "
+     "kernel|xla)"),
     # -- kernels (models/vit.Block, counted once a trace) -----------------
     ("kernels.block_tokenwise", "counter",
      "ViT block traces by the path of the token-wise half (key: kernel|xla)"),

@@ -962,3 +962,37 @@ def test_moe_gmm_lowers_at_the_latent_experts_shapes_and_keeps_its_name(
     shapes = [[int(g) for g in reader.NAME.match(call).groups()[1:]]
               for call in calls]
     assert shapes == [[rows, N]] + [[rows, K]] * whole
+
+
+# --- the gated delta-rule scan at Kimi-Linear's shapes -----------------------
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_chunk_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip):
+    """``ops/kda.py`` at 16,385 tokens (128 chunks of 128 and one token: the
+    sequence ends inside the last block, nothing is padded), 32 heads of a
+    128 x 128 state: ONE ``tpu_custom_call``, named ``%kda_chunk``
+    (``benchmark/layer_metrics/kda_chunk_roofline.py`` matches it by that name
+    and tells a launch that applies the output gate by its operands: this one
+    has five), result ``[images, tokens, heads x channels]``; q, k and v
+    reach it as they are."""
+    from benchmark.layer_metrics import kda_chunk_roofline as reader
+    from ddim_cold_tpu.ops import kda
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, H, d = 1, 16385, 32, 128
+    assert L == 128 * kda.CHUNK + 1 and kda.kernel_admits(H, d)
+    wide = sds((n, L, H * d), dtype)
+    text = jax.jit(lambda *a: kda.kda_scan(*a, d ** -0.5)).lower(
+        wide, wide, wide, sds((n, L, H * d), jnp.float32),
+        sds((n, L, H), jnp.float32)).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and int(m.group(2)) == n
+    assert f"[{n},{L},{H * d}]" in calls[0].split(" custom-call(")[0]
+    if dtype == jnp.bfloat16:  # q, k, v as handed
+        assert all(f"%a_{i}_" in calls[0] for i in range(3))
+    trace = types.SimpleNamespace(devices={0: {"ops": [(0, 1000, calls[0])]}})
+    (images, gated, _), = reader.events(types.SimpleNamespace(trace=trace))
+    assert (images, gated) == (n, False)

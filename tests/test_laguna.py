@@ -242,9 +242,46 @@ def _ungated_experts_in_a_latent():
     return tree, whole, layer
 
 
+def _kimi_experts_over_two_chips():
+    """Kimi-Linear's expert layer (the benchmark's toy configuration with all
+    16 experts held): a sigmoid a router output, the top 3 of score + bias
+    chosen, weighed by the renormalised scores x 2.446, gated experts and one
+    shared expert at 24; the uncut layer is ``reference/kimi.py``'s, under
+    that configuration's own key names."""
+    import json
+    import os
+
+    from benchmark import weights_kimi
+    from benchmark.reference import kimi as kimi_ref
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "tests", "fixtures_kimi", "benchmark", "configs",
+                           "toy_kimi.json")) as f:
+        toy = dict(json.load(f), precision="float32", num_experts=16)
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (16,))
+    tree = dict(weights_kimi.make(toy, 3)["layers_1"]["mlp"],
+                e_score_correction_bias=bias)
+    # at a width of 24 weights of std 0.02 leave the routed part at 1e-5
+    # beside a shared expert of 1e-4: louder, so that the sum is tested
+    tree.update({k: 6 * tree[k] for k in ref.BANKS})
+    cfg = weights_kimi.trunk_of(toy)
+    whole = lambda share, y: kimi_ref.sparse_mlp(
+        _share(tree, *share), y,
+        dict(cfg, experts_held_from=share[0], num_experts=share[1]))
+    layer = lambda first, held: moe.HeldExpertsMlp(
+        num_routed=16, top_k=3, first_held=first, num_held=held,
+        hidden_features=24, shared_features=24, scaling=2.446,
+        score="sigmoid", selection_bias=True)
+    y = jax.random.normal(jax.random.PRNGKey(2), (34, 64))
+    assert tree["gate_proj"].shape == (16, 64, 24)
+    assert float(jnp.abs(whole((0, 16), y) - whole((0, 8), y)).max()) > 1e-3
+    return tree, whole, layer
+
+
 @pytest.mark.parametrize("router,chips", [
     (_softmax_router, 2), (_sigmoid_router_with_a_selection_bias, 16),
-    (_sigmoid_router_without_a_bias, 4), (_ungated_experts_in_a_latent, 4)])
+    (_sigmoid_router_without_a_bias, 4), (_ungated_experts_in_a_latent, 4),
+    (_kimi_experts_over_two_chips, 2)])
 def test_the_shares_add_up_to_the_uncut_layer(router, chips):
     """The 16 experts over ``chips`` chips (0-7 and 8-15; one each; or four
     each), the shared expert — and nothing else — computed by all and counted
