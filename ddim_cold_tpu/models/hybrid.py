@@ -37,7 +37,10 @@ SiLU MLP, RMSNorm throughout. With x ∈ R^{L×hidden_size}, ε =
   attn_layer_period == attn_layer_offset``, else Mamba.
 * Mamba-1 mixer (d = ``mamba_expand``·hidden, s = ``mamba_d_state``,
   k = ``mamba_d_conv``, r = ``mamba_dt_rank``): ``[u, z] = x W_in``;
-  ``u_t ← SiLU(b_c + Σ_j w_j ⊙ u_{t−k+1+j})`` (depthwise, causal);
+  ``u_t ← SiLU(b_c + Σ_j w_j ⊙ u_{t−k+1+j})`` (depthwise, causal:
+  ``causal_conv_silu`` below, which all three state-space stacks call; the
+  arithmetic is ``ops/short_conv.py``'s, one launch ``causal_conv`` on the
+  TPU);
   ``[δ, B, C] = u W_x`` (r + s + s), each RMSNormed (the ``jamba`` modelling
   code's ``dt_layernorm``, ``b_layernorm``, ``c_layernorm``);
   ``Δ = softplus(δ W_dt + b_dt)``; ``A = −exp(A_log)``; then
@@ -75,6 +78,7 @@ import jax.numpy as jnp
 from ddim_cold_tpu.models import vit
 from ddim_cold_tpu.models.init import torch_default_uniform, trunc_normal
 from ddim_cold_tpu.ops.selective_scan import selective_scan
+from ddim_cold_tpu.ops.short_conv import causal_conv
 
 Dtype = Any
 
@@ -166,22 +170,21 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.broadcast_to(jnp.log(states), shape).astype(dtype)
 
 
-def causal_conv_silu(module: nn.Module, u, taps: int, bias: bool):
+def causal_conv_silu(module: nn.Module, u, taps: int, bias: bool,
+                     l2_head_dim: int | None = None):
     """``u_t ← SiLU(b + Σ_j w_j ⊙ u_{t−taps+1+j})`` over ``u (n, L, d)``:
-    the depthwise causal convolution of a Mamba mixer, in float32, result in
-    the module's ``dtype``; ``conv1d_kernel (taps, d)`` and, with ``bias``,
-    ``conv1d_bias (d,)`` are ``module``'s parameters (called from its
-    compact ``__call__``)."""
-    d, L = u.shape[-1], u.shape[1]
+    the depthwise causal convolution of a mixer, in float32, result in the
+    module's ``dtype``, with ``l2_head_dim`` each run of that many channels
+    L2-normed behind it (``ops/short_conv.py``: one launch on the TPU, the
+    written-out taps elsewhere); ``conv1d_kernel (taps, d)`` and, with
+    ``bias``, ``conv1d_bias (d,)`` are ``module``'s parameters (called from
+    its compact ``__call__``)."""
+    d = u.shape[-1]
     w = module.param("conv1d_kernel", torch_default_uniform(taps), (taps, d),
-                     module.param_dtype).astype(jnp.float32)
-    past = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    conv = sum(w[j] * past[:, j:j + L] for j in range(taps))
-    if bias:
-        conv = conv + module.param(
-            "conv1d_bias", nn.initializers.zeros_init(), (d,),
-            module.param_dtype).astype(jnp.float32)
-    return jax.nn.silu(conv).astype(module.dtype)
+                     module.param_dtype)
+    b = module.param("conv1d_bias", nn.initializers.zeros_init(), (d,),
+                     module.param_dtype) if bias else None
+    return causal_conv(u, w, b, l2_head_dim=l2_head_dim, dtype=module.dtype)
 
 
 class MambaMixer(nn.Module):

@@ -8,10 +8,11 @@ latent), and whose MLP is dense in the leading layers and sigmoid-scored top-k
 routed experts plus a shared one after them. The wrapper, the input and output
 stage, ``RMSNorm``, ``GatedMlp`` and the depthwise causal convolution
 (``causal_conv_silu``: one piece of code with the ``jamba`` and ``nemotron_h``
-stacks' mixers) are ``hybrid``'s; the attention launch
-``ops.flash_attention.latent_attention`` with the ``pangu_ultra_moe`` stack;
-the projections written a column set at a time ``glm._DenseByColumnSets``;
-the expert layer ``moe.HeldExpertsMlp``.
+stacks' mixers, its arithmetic ``ops/short_conv.py``'s: on the TPU the one
+launch ``causal_conv``, which also norms q and k) are ``hybrid``'s; the
+attention launch ``ops.flash_attention.latent_attention`` with the
+``pangu_ultra_moe`` stack; the projections written a column set at a time
+``glm._DenseByColumnSets``; the expert layer ``moe.HeldExpertsMlp``.
 
 Sizes come from ``trunk``, a mapping under the keys of the published
 ``config.json`` (``model_type: kimi_linear``), letter for letter, its nested
@@ -86,10 +87,6 @@ from ddim_cold_tpu.ops.kda import kda_scan
 
 Dtype = Any
 
-#: under the root of the squares' sum in q's and k's normalisation
-L2_EPS = 1e-6
-
-
 def layer_kind(c: Mapping[str, Any], i: int) -> str:
     """``"kda"`` or ``"mla"`` of layer i of the slice, by its published number
     ``layers_from + i + 1`` in the two lists; a refusal that names the layer
@@ -134,16 +131,19 @@ def check_trunk(c: Mapping[str, Any]) -> None:
 
 
 class _ShortConv(nn.Module):
-    """One depthwise causal convolution and its SiLU, without bias: a module
-    of its own so that the three of a mixer each hold a ``conv1d_kernel``."""
+    """One depthwise causal convolution and its SiLU, without bias, and with
+    ``l2_head_dim`` (q's and k's) each head's channels over ``sqrt(Σx² +
+    1e-6)`` behind it, in the same launch: a module of its own so that the
+    three of a mixer each hold a ``conv1d_kernel``."""
 
     taps: int
+    l2_head_dim: int | None = None
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, u):
-        return causal_conv_silu(self, u, self.taps, False)
+        return causal_conv_silu(self, u, self.taps, False, self.l2_head_dim)
 
 
 class HeadwiseGatedRMSNorm(nn.Module):
@@ -178,19 +178,14 @@ class DeltaAttention(nn.Module):
         c, lin = self.trunk, self.trunk["linear_attn_config"]
         H, d, taps = (lin["num_heads"], lin["head_dim"],
                       lin["short_conv_kernel_size"])
-        n, L, width = y.shape
+        width = y.shape[-1]
         f32 = jnp.float32
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         dense = lambda feats, name: _dense(feats, name, **kw)
-        q, k, v = (_ShortConv(taps, name=f"{part}_conv1d", **kw)(
+        # q and k leave their convolution L2-normed a head, v as it is
+        q, k, v = (_ShortConv(taps, d if part in "qk" else None,
+                              name=f"{part}_conv1d", **kw)(
             dense(H * d, f"{part}_proj")(y)) for part in "qkv")
-
-        def l2(x):
-            heads = x.astype(f32).reshape(n, L, H, d)
-            heads = heads * jax.lax.rsqrt(
-                jnp.sum(heads * heads, -1, keepdims=True) + L2_EPS)
-            return heads.reshape(n, L, H * d).astype(self.dtype)
-
         dt_bias = self.param("dt_bias", _dt_bias_init(), (H * d,),
                              self.param_dtype)
         rate = jnp.exp(self.param("A_log", _a_log_init, (H,),
@@ -199,7 +194,7 @@ class DeltaAttention(nn.Module):
             dense(H * d, "f_b_proj")(dense(d, "f_a_proj")(y)).astype(f32)
             + dt_bias.astype(f32))
         beta = jax.nn.sigmoid(dense(H, "b_proj")(y).astype(f32))
-        out = kda_scan(l2(q), l2(k), v, g, beta, d ** -0.5)
+        out = kda_scan(q, k, v, g, beta, d ** -0.5)
         gate = dense(H * d, "g_b_proj")(dense(d, "g_a_proj")(y))
         out = HeadwiseGatedRMSNorm(d, c["rms_norm_eps"], name="o_norm", **kw)(
             out, gate)

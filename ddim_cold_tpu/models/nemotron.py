@@ -7,7 +7,8 @@ that are ungated squared-ReLU MLPs in a latent narrower than the residual
 stream, beside a shared expert on the full width. The wrapper, the input and
 output stage, ``RMSNorm``, ``SquaredReluMlp`` and the mixers' depthwise causal
 convolution (``causal_conv_silu``: one piece of code with the ``jamba``
-stack's Mamba-1 mixer) are ``hybrid``'s; the attention launch
+stack's Mamba-1 mixer, its arithmetic ``ops/short_conv.py``'s: on the TPU
+the one launch ``causal_conv``) are ``hybrid``'s; the attention launch
 ``ops.flash_attention.masked_attention``; the expert layer
 ``moe.HeldExpertsMlp``.
 

@@ -144,6 +144,10 @@ METRICS = (
     ("kernels.kda_schedule", "counter",
      "gated delta-rule (per-channel decay) scan traces by path (key: "
      "kernel|xla)"),
+    # -- kernels (ops/short_conv.py, counted once a trace) ------------------
+    ("kernels.causal_conv_schedule", "counter",
+     "short causal convolution (taps, SiLU, head-wise L2 norm) traces by "
+     "path (key: kernel|xla)"),
     # -- kernels (models/vit.Block, counted once a trace) -----------------
     ("kernels.block_tokenwise", "counter",
      "ViT block traces by the path of the token-wise half (key: kernel|xla)"),

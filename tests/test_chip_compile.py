@@ -996,3 +996,47 @@ def test_kda_chunk_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip)
     trace = types.SimpleNamespace(devices={0: {"ops": [(0, 1000, calls[0])]}})
     (images, gated, _), = reader.events(types.SimpleNamespace(trace=trace))
     assert (images, gated) == (n, False)
+
+
+# --- the short convolution at the three state-space stacks' shapes -----------
+
+@pytest.mark.parametrize("shape,bias,l2_head_dim,dtype", [
+    ((1, 16385, 4096), False, None, jnp.bfloat16),    # Kimi's v
+    ((1, 16385, 4096), False, 128, jnp.bfloat16),     # Kimi's q and k
+    ((1, 16385, 10240), True, None, jnp.bfloat16),    # Nemotron's xBC
+    ((4, 1025, 5120), True, None, jnp.bfloat16),      # Jamba's u
+    ((1, 16385, 4096), False, 128, jnp.float32),      # a float32 model's
+], ids=["kimi_v", "kimi_qk", "nemotron", "jamba", "float32"])
+def test_causal_conv_lowers_at_the_published_shapes_and_keeps_its_name(
+        shape, bias, l2_head_dim, dtype, chip):
+    """``ops/short_conv.py`` through its dispatcher: ONE ``tpu_custom_call``,
+    named ``%causal_conv``, whose first operand is ``u`` as it is handed (no
+    float32 copy, nothing padded: 16,385 and 1,025 tokens end inside the last
+    block) and whose result has ``u``'s shape and dtype; the counter reads
+    ``kernel``."""
+    from ddim_cold_tpu.obs import metrics
+    from ddim_cold_tpu.ops import short_conv
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, d = shape
+    assert short_conv.kernel_admits(d, 4, l2_head_dim)
+    args = [sds(shape, dtype), sds((4, d), dtype)] + [sds((d,), dtype)] * bias
+    metrics.reset()
+    text = jax.jit(lambda u, w, b=None: short_conv.causal_conv(
+        u, w, b, l2_head_dim=l2_head_dim)).lower(*args).compile().as_text()
+    by_key = {}
+    for series in metrics.snapshot().values():
+        by_key.update(series.get("kernels.causal_conv_schedule/by_key", {}))
+    assert by_key == {"kernel": 1}
+    metrics.reset()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].startswith("%causal_conv")
+    kind = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    head, operands = calls[0].split(" custom-call(")
+    assert f"{kind}[{n},{L},{d}]" in head
+    if n == 1 and dtype == jnp.bfloat16:
+        # the parameter itself. (Alone, XLA lays a (4, 1,025, ·) parameter
+        # out images-minor and copies it for the launch; inside a mixer's
+        # program the projection writes the launch's layout: PERF.md, PR 46.)
+        assert operands.startswith("%u")

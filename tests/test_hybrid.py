@@ -104,6 +104,27 @@ def test_ddim_sample_follows_the_reference_trajectory():
     assert rms(got, want) < 2e-5, rms(got, want)
 
 
+def test_a_trace_counts_one_convolution_and_one_scan_a_mamba_layer():
+    """Three of the toy's four layers are Mamba mixers: three traces of the
+    short convolution and three of the scan, off the TPU on the XLA forms."""
+    from ddim_cold_tpu.obs import metrics
+
+    model, params = model_and_params("float32")
+    x, t = inputs()
+    metrics.reset()
+    jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(params)
+    by_key = {}
+    for series in metrics.snapshot().values():
+        for name in ("kernels.causal_conv_schedule",
+                     "kernels.ssm_scan_schedule", "kernels.ssd_schedule",
+                     "kernels.kda_schedule"):
+            for key, count in series.get(name + "/by_key", {}).items():
+                by_key[name, key] = by_key.get((name, key), 0) + count
+    assert by_key == {("kernels.causal_conv_schedule", "xla"): 3,
+                      ("kernels.ssm_scan_schedule", "xla"): 3}
+    metrics.reset()
+
+
 def test_gradient_matches_the_references():
     """What a training step differentiates: the scan's XLA path."""
     model, params = model_and_params("float32")

@@ -291,7 +291,8 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 def test_the_named_scopes_and_counters_of_a_trace():
     """``trunk/kda | mla | mlp | moe`` in the lowered text; one count a traced
-    scan and a traced latent attention, three products an expert layer."""
+    scan and a traced latent attention, three convolutions a delta layer,
+    three products an expert layer."""
     model, params = model_and_params("float32")
     x, t = inputs()
     metrics.reset()
@@ -302,10 +303,12 @@ def test_the_named_scopes_and_counters_of_a_trace():
     by_key = {}
     for series in metrics.snapshot().values():
         for name in ("kernels.kda_schedule", "kernels.flash_latent_schedule",
-                     "kernels.moe_gmm_schedule", "kernels.ssd_schedule"):
+                     "kernels.moe_gmm_schedule", "kernels.ssd_schedule",
+                     "kernels.causal_conv_schedule"):
             for key, count in series.get(name + "/by_key", {}).items():
                 by_key[name, key] = by_key.get((name, key), 0) + count
     assert by_key == {("kernels.kda_schedule", "xla"): 3,
+                      ("kernels.causal_conv_schedule", "xla"): 9,
                       ("kernels.flash_latent_schedule", "xla"): 1,
                       ("kernels.moe_gmm_schedule", "xla"): 9}
     metrics.reset()
@@ -314,7 +317,9 @@ def test_the_named_scopes_and_counters_of_a_trace():
 def test_the_three_convolutions_are_the_other_mixers_piece_of_code():
     """``DeltaAttention``'s three short convolutions call
     ``hybrid.causal_conv_silu`` without a bias: one ``conv1d_kernel`` each and
-    the written-out taps."""
+    the written-out taps; q's and k's hand it their head size, and what comes
+    back is the plain convolution's result L2-normed a head as the reference
+    norms it."""
     import inspect
 
     u = jax.random.normal(jax.random.PRNGKey(3), (2, 9, 6))
@@ -325,8 +330,13 @@ def test_the_three_convolutions_are_the_other_mixers_piece_of_code():
     np.testing.assert_allclose(got, want / (1 + np.exp(-want)), rtol=1e-5,
                                atol=1e-6)
     np.testing.assert_allclose(ref.conv_silu(w, u), got, rtol=1e-5, atol=1e-6)
-    assert "causal_conv_silu(self, u, self.taps, False)" in inspect.getsource(
-        kimi._ShortConv)
+    assert ("causal_conv_silu(self, u, self.taps, False, self.l2_head_dim)"
+            in inspect.getsource(kimi._ShortConv))
+    normed = kimi._ShortConv(4, 3).apply({"params": {"conv1d_kernel": w}}, u)
+    heads = np.asarray(got).reshape(2, 9, 2, 3)
+    np.testing.assert_allclose(
+        normed, (heads / np.sqrt((heads * heads).sum(-1, keepdims=True) + 1e-6)
+                 ).reshape(2, 9, 6), rtol=1e-5, atol=1e-6)
 
 
 def test_build_model_builds_the_trunk_from_a_yaml(tmp_path):

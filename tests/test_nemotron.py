@@ -288,10 +288,12 @@ def test_the_named_scopes_and_counters_of_a_trace():
     for series in metrics.snapshot().values():
         for name in ("kernels.ssd_schedule", "kernels.ssm_scan_schedule",
                      "kernels.moe_gmm_schedule",
-                     "kernels.moe_gate_up_schedule"):
+                     "kernels.moe_gate_up_schedule",
+                     "kernels.causal_conv_schedule"):
             for key, count in series.get(name + "/by_key", {}).items():
                 by_key[name, key] = by_key.get((name, key), 0) + count
     assert by_key == {("kernels.ssd_schedule", "xla"): 1,
+                      ("kernels.causal_conv_schedule", "xla"): 1,
                       ("kernels.moe_gmm_schedule", "xla"): 4}
     metrics.reset()
 
