@@ -39,16 +39,23 @@ therefore keep every exponent they take ≤ 0:
   factors ≤ 1, and is a product on the MXU (the rows of a sub-block against
   the keys before it, scaled for that sub-block). A pair inside ONE
   sub-block is formed channel by channel on the vector unit, the eight
-  keys of a sub-block one after the other against its rows.
-  ``(I + Diag(β) A)⁻¹`` is built in float32 by doubling: the inverse of the
-  ``2b``-blocks from that of the ``b``-blocks, ``X ← X − X N_b X`` with
-  ``N_b`` the part of ``Diag(β) A`` that joins the two halves, exact for a
-  triangular matrix (no series, nothing that grows), six levels from 2 to
-  the chunk's 128, each product in three bfloat16 passes (both operands'
-  leading pieces and each one's remainder against the other's lead: 16 bits
-  of product; float32 operands multiply as float32). γ is a product with a
-  triangle of ones, g taken in three bfloat16 pieces that add up to its
-  float32, so the sums are float32's.
+  keys of a sub-block one after the other against its rows, a column of
+  the chunk each.
+  ``(I + Diag(β) A)⁻¹`` is built in float32 in two stages, both exact for a
+  triangular matrix (no series, nothing that grows). The sixteen 8 × 8
+  diagonal blocks by elimination on the vector unit, from those columns as
+  they come: starting from the identity, ``X[r] −= N[r, i] · X[i]`` for the
+  rows r after key i of the same sub-block, seven steps. Above them by
+  doubling: the inverse of the ``2b``-blocks from that of the ``b``-blocks,
+  ``X ← X − X N_b X`` with ``N_b`` the part of ``Diag(β) A`` that joins the
+  two halves, four levels from 8 to the chunk's 128, each product in three
+  bfloat16 passes (both operands' leading pieces and each one's remainder
+  against the other's lead: 16 bits of product; float32 operands multiply
+  as float32), each matrix split into those pieces once: doubling from any
+  lower would multiply the zeros between smaller blocks at the price of
+  whole 128³ passes. γ is a product with a triangle of ones, g taken in
+  three bfloat16 pieces that add up to its float32, so the sums are
+  float32's.
 
 Layout in the launch: q, k, v and the result token-major ``(n, L, H·d)`` as
 the projections leave and read them, g the same in float32, β ``(n, L, H)``
@@ -80,15 +87,17 @@ from ddim_cold_tpu.ops.flash_attention import (
     kernel_interpret, per_device, rows_spec)
 
 #: tokens a chunk: the launch's choice and no part of the model. On the chip
-#: (PERF.md, PR 45) a launch at the published shape took 15.7 ms at 64, 10.5
-#: at 128 and 20.9 at 256: a chunk's fixed work (the inverse, the products
-#: with the state) is shared by more tokens, until its pairs outgrow that
+#: (PERF.md, PR 47) a launch at the published shape took 11.7 ms at 64, 8.3
+#: at 128 and 16.4 at 256: a chunk's fixed work (the doubling levels, the
+#: products with the state) is shared by more tokens, until its pairs and
+#: its levels outgrow that
 CHUNK = 128
-#: tokens a sub-block of the launch: one float32 sublane tile
+#: tokens a sub-block of the launch: one float32 sublane tile, and the
+#: diagonal blocks that the inverse eliminates on the vector unit
 SUB = 8
 #: heads one program of the launch walks, their chains of small products
-#: independent of one another for the scheduler to interleave (10.7, 10.5 and
-#: 10.2 ms a launch at 1, 2 and 4; four compile three times as long)
+#: independent of one another for the scheduler to interleave (8.7, 8.3 and
+#: 8.0 ms a launch at 1, 2 and 4; four compile three times as long)
 HEADS_A_PROGRAM = 2
 
 #: which path each trace of the scan took (``kernels.kda_schedule``)
@@ -162,7 +171,9 @@ def _one_head(q, k, v, g, beta, St, *, dtype):
     """One head's chunk. ``q`` (scaled), ``k``, ``v``, ``g``: ``(C, d)``
     float32, zeros past the sequence; ``beta``: ``(C, 1)``; ``St``: ``(d,
     d)`` float32, the state handed in, value channel × key channel. Returns
-    ``(o (C, d) float32, the state handed on)``."""
+    ``(o (C, d) float32, the state handed on)``. MXU products at ``C`` 128 in
+    bfloat16: 3 for γ, 15 for the pairs in two sub-blocks, 24 for the
+    inverse's four levels, 5 at the end (``tests/test_kda.py`` counts them)."""
     f32 = jnp.float32
     C, d = q.shape
     nb = C // SUB
@@ -209,41 +220,60 @@ def _one_head(q, k, v, g, beta, St, *, dtype):
     A = jnp.where(col < first, jnp.concatenate(rows_a, axis=0), 0.0)
     B = jnp.where(col < first, jnp.concatenate(rows_b, axis=0), 0.0)
     # pairs inside one sub-block: its i-th key against its rows, channel by
-    # channel, into column ``first + i``; what a key's own row and the rows
-    # before it read there (an exponent above 0, maybe inf) is cut by the
-    # triangles below and never multiplied
-    A_in, B_in = jnp.zeros((C, C), f32), jnp.zeros((C, C), f32)
+    # channel, a ``(C, 1)`` column: row r's pair with key ``first + i``. q's go
+    # into column ``first + i`` of B. k's, times β, are column i of every
+    # sub-block's part of ``Diag(β) A``, and eliminate it at once: from X = I,
+    # ``X[r] −= N[r, first + i] · X[first + i]`` for the rows after the key
+    # (row i of each sublane tile broadcast down it), seven steps to the
+    # exact float32 inverse of the sixteen 8 × 8 diagonal blocks (forward
+    # elimination applied to the identity: no series, nothing that grows).
+    # What a column holds at the key's own row and the rows before it (an
+    # exponent above 0, maybe inf) is cut by a select before any multiply
+    B_in = jnp.zeros((C, C), f32)
+    X = (row == col).astype(f32)
+    place = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) % SUB
     for i in range(SUB):
         pair = (jnp.exp(gam3 - gam3[:, i:i + 1, :])
                 * k3[:, i:i + 1, :]).reshape(C, d)
-        here = col - first == i
-        A_in = jnp.where(here, jnp.sum(pair * k, axis=-1, keepdims=True), A_in)
-        B_in = jnp.where(here, jnp.sum(pair * q, axis=-1, keepdims=True), B_in)
-    inside = col >= first
-    A = jnp.where(inside & (col < row), A_in, A)
-    B = jnp.where(inside & (col <= row), B_in, B)
+        B_in = jnp.where(col - first == i,
+                         jnp.sum(pair * q, axis=-1, keepdims=True), B_in)
+        if i < SUB - 1:  # no row comes after a sub-block's last key
+            n_i = jnp.where(
+                place > i,
+                beta * jnp.sum(pair * k, axis=-1, keepdims=True), 0.0)
+            X3 = X.reshape(nb, SUB, C)
+            X = X - n_i * jnp.broadcast_to(
+                X3[:, i:i + 1, :], X3.shape).reshape(C, C)
+    B = jnp.where((col >= first) & (col <= row), B_in, B)
 
-    # X = (I + Diag(β) A)⁻¹ by doubling, in float32
-    N = beta * A
-    same = lambda b: (row // b) == (col // b)
+    # X = (I + Diag(β) A)⁻¹ from the sub-blocks' by doubling, in float32: with
+    # X the inverse of the b-blocks and N the pairs in two sub-blocks (all
+    # that is left of Diag(β) A), ``X − (X N restricted to where two b-blocks
+    # join into one 2b-block) X`` is the inverse of the 2b-blocks. X being
+    # block-diagonal, restricting the product is restricting N, term for
+    # term. Each float32 matrix is split into its bfloat16 pieces once: N a
+    # chunk, X a level (it is an operand of both products), X·N where used
     if dtype == f32:
-        mm = lambda a, b: jnp.dot(a, b, precision=_HIGHEST,
-                                  preferred_element_type=f32)
+        pieces = lambda a: (a,)
+        times = lambda a, b: jnp.dot(a[0], b[0], precision=_HIGHEST,
+                                     preferred_element_type=f32)
     else:
-        def mm(a, b):
+        def pieces(a):
+            lead = a.astype(jnp.bfloat16)
+            return lead, (a - lead.astype(f32)).astype(jnp.bfloat16)
+
+        def times(a, b):
             # three bfloat16 passes: both operands' leading pieces and each
             # one's remainder against the other's lead (16 bits of product)
-            bf = jnp.bfloat16
-            ah, bh = a.astype(bf), b.astype(bf)
-            al = (a - ah.astype(f32)).astype(bf)
-            bl = (b - bh.astype(f32)).astype(bf)
             d3 = lambda x, y: jnp.dot(x, y, preferred_element_type=f32)
-            return d3(ah, bh) + (d3(ah, bl) + d3(al, bh))
-    X = (row == col).astype(f32) - jnp.where(same(2), N, 0.0)
-    b = 2
+            return d3(a[0], b[0]) + (d3(a[0], b[1]) + d3(a[1], b[0]))
+    N = pieces(beta * A)
+    apart = row ^ col     # in [b, 2b): one 2b-block, two b-blocks
+    b = SUB
     while b < C:
-        joins = jnp.where(same(2 * b) & ~same(b), N, 0.0)
-        X = X - mm(mm(X, joins), X)
+        Xp = pieces(X)
+        XN = jnp.where((apart & -b) == b, times(Xp, N), 0.0)
+        X = X - times(pieces(XN), Xp)
         b *= 2
 
     eg, last = jnp.exp(gam), gam[C - 1:C, :]

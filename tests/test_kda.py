@@ -2,8 +2,11 @@
 Pallas launch (interpreter mode here) against both, at lengths that end on,
 one past and far inside a chunk, with the step size at 0 and near 1 and with
 a decay of 20 a token and channel, where an exponent split across a product
-overflows; causality, the shapes the launch admits, the counter of which path
-a trace took, and what it says when asked for a gradient."""
+overflows, and with keys that repeat inside a sub-block of 8, where the
+diagonal blocks the launch inverts by elimination are far from the identity;
+how many products one head's chunk hands the MXU; causality, the shapes the
+launch admits, the counter of which path a trace took, and what it says when
+asked for a gradient."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +15,7 @@ import pytest
 
 from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import kda
+from tests.test_flash_attention import _iter_eqns
 
 #: two heads of 128 channels, one program of the launch, over chunks of 64:
 #: half the chunk the program ships (its own, 128, has a case below)
@@ -19,16 +23,20 @@ H, D, C = 2, 128, 64
 SCALE = 1.0  # the launch takes any; 1 keeps the outputs at order one
 
 
-def operands(n, L, dtype, seed=0, heads=H, head_dim=D, g=None, beta=None):
+def operands(n, L, dtype, seed=0, heads=H, head_dim=D, g=None, beta=None,
+             repeats=False):
     """q and k normed a head (k with a mean, as after a SiLU), v of order
     one, a decay of 0.03 to 3 a token by the channel, β spread over (0, 1);
-    ``g`` and ``beta`` put one number everywhere instead."""
+    ``g`` and ``beta`` put one number everywhere instead; with ``repeats``
+    every token of a sub-block of 8 has the sub-block's first key."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     l2 = lambda x: x / jnp.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
     wide = (n, L, heads * head_dim)
     q = l2(jax.random.normal(ks[0], (n, L, heads, head_dim))).reshape(wide)
     k = l2(0.5 + jax.random.normal(ks[1], (n, L, heads, head_dim))
            ).reshape(wide)
+    if repeats:
+        k = k[:, np.arange(L) - np.arange(L) % kda.SUB]
     v = 4.0 * jax.random.normal(ks[2], wide)
     if g is None:
         decay = -jnp.exp(jax.random.uniform(
@@ -68,6 +76,12 @@ TOLERANCE = {jnp.float32: dict(rtol=2e-4, atol=2e-5),
 
 CASES = {"beta_0": dict(beta=0.0), "beta_near_1": dict(beta=0.999),
          "g_minus_20": dict(g=-20.0)}
+#: β near 1 and one key a sub-block: ``Diag(β) A`` reads 0.76 beside the
+#: diagonal of its 8 x 8 blocks and 0.38 in their corners (the operands'
+#: own decay is all that lowers it). At the chunks at which the launch
+#: doubles once, three times and (its own) four times above those blocks
+CASES.update({f"repeats_chunk_{c}": dict(beta=0.999, repeats=True, chunk=c)
+              for c in (16, 64, 128)})
 
 
 def run_kernel(*args, chunk=C):
@@ -98,23 +112,49 @@ def test_chunked_form_kernel_and_recurrence_agree(L, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("case", ["beta_0", "beta_near_1", "g_minus_20"])
+@pytest.mark.parametrize("case", list(CASES))
 def test_both_forms_hold_at_the_ends_of_the_step_and_of_the_decay(case, dtype):
     """β = 0 writes nothing (the output is zero); β near 1 replaces what the
     state says about a key; g = −20 a token and channel loses e^{−1280} over
     a chunk of 64, and ``exp(γ_r) · exp(−γ_i)`` would be 0 · inf: both forms stay
     finite and equal the recurrence, which is then all but the token's own
-    ``β (q·k) v``."""
-    args = operands(2, C + 1, dtype, seed=3, **CASES[case])
+    ``β (q·k) v``. A key that repeats through its sub-block is written once
+    and then found there: the seven later tokens correct almost nothing, which
+    the chunked forms only get from an inverse that is right in every entry of
+    its diagonal blocks (the sequence ends inside the second chunk's second
+    sub-block)."""
+    case = dict(CASES[case])
+    chunk = case.pop("chunk", C)
+    args = operands(2 if chunk == C else 1, chunk + kda.SUB + 1, dtype, seed=3,
+                    **case)
     want = recurrence(*args, SCALE)
-    for got in (run_xla(*args, SCALE), run_kernel(*args, SCALE)):
+    for got in (run_xla(*args, SCALE, chunk=chunk),
+                run_kernel(*args, SCALE, chunk=chunk)):
         got = np.asarray(got, np.float32)
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got, want, **TOLERANCE[dtype])
-    if case == "beta_0":
+    if case.get("beta") == 0.0:
         assert not want.any()
     else:
         assert np.abs(want).mean() > 0.02
+
+
+@pytest.mark.parametrize("chunk,dtype,products", [
+    (128, jnp.bfloat16, 3 + 15 + 24 + 5), (64, jnp.bfloat16, 3 + 7 + 18 + 5),
+    (16, jnp.bfloat16, 3 + 1 + 6 + 5), (128, jnp.float32, 3 + 15 + 8 + 5)],
+    ids=["128_bfloat16", "64_bfloat16", "16_bfloat16", "128_float32"])
+def test_one_head_launches_no_product_on_the_sub_blocks(chunk, dtype, products):
+    """What one head's chunk hands the MXU, counted in its jaxpr: 3 for γ,
+    one for each sub-block after the first, two a doubling level FROM 8 (in
+    three bfloat16 passes each; float32 operands in one) and 5 at the end.
+    PR 45's launch doubled from 2 (59 at 128 in bfloat16): a level put back
+    under the sub-blocks fails here and not only in a benchmark."""
+    rows = jax.ShapeDtypeStruct((chunk, D), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *a: kda._one_head(*a, dtype=dtype))(
+        rows, rows, rows, rows, jax.ShapeDtypeStruct((chunk, 1), jnp.float32),
+        jax.ShapeDtypeStruct((D, D), jnp.float32))
+    assert sum(eqn.primitive.name == "dot_general"
+               for eqn in _iter_eqns(jaxpr.jaxpr)) == products
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
