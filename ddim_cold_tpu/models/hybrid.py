@@ -21,8 +21,11 @@ chunks, attention without a position term, ungated experts in a latent of
 their own width) or ``kimi_linear`` (``models/kimi.py``: the mixer kind read
 from two published lists of layer numbers: delta attention, whose matrix
 state decays by a vector and is corrected by the delta rule, or latent
-attention with no rotation and no query latent; sigmoid-scored experts). One
-wrapper
+attention with no rotation and no query latent; sigmoid-scored experts) or
+``smallthinker`` (``models/smallthinker.py``: a router that reads the layer's
+input before attention while its ReLU-gated experts, with no shared one, read
+the normed stream after it; rotary window layers between position-free full
+ones). One wrapper
 serves all: what the samplers and the engine read of a
 model, ``clone``, the refusals and ``__call__`` below.
 
@@ -67,6 +70,7 @@ whatever the stack: ``quant``, ``fused``, the step caches, ``scan_blocks``,
 
 from __future__ import annotations
 
+import importlib
 import math
 from typing import Any, Mapping, Sequence
 
@@ -94,8 +98,9 @@ REFUSED = {
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
                  "layers are dense XLA attention, the laguna, glm_moe_dsa, "
-                 "pangu_ultra_moe, nemotron_h and kimi_linear stacks run "
-                 "their flash forwards wherever the backend is a TPU",
+                 "pangu_ultra_moe, nemotron_h, kimi_linear and smallthinker "
+                 "stacks run their flash forwards wherever the backend is a "
+                 "TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -346,35 +351,23 @@ def norm_eps(trunk: Mapping[str, Any]) -> float:
             else trunk["layer_norm_epsilon"])
 
 
+#: ``model_type`` → the module under ``ddim_cold_tpu.models`` that holds the
+#: stack's ``check_trunk`` and ``layer`` (``hybrid``: this one)
+STACKS = {"jamba": "hybrid", "laguna": "laguna", "glm_moe_dsa": "glm",
+          "pangu_ultra_moe": "pangu", "nemotron_h": "nemotron",
+          "kimi_linear": "kimi", "smallthinker": "smallthinker"}
+
+
 def stack_of(trunk: Mapping[str, Any]) -> tuple:
     """``(check_trunk, layer)`` of ``trunk``'s layer stack, by the published
-    ``model_type``."""
+    ``model_type`` (:data:`STACKS`)."""
     model_type = trunk.get("model_type", "jamba")
-    if model_type == "jamba":
-        return check_trunk, layer
-    if model_type == "laguna":
-        from ddim_cold_tpu.models import laguna
-
-        return laguna.check_trunk, laguna.layer
-    if model_type == "glm_moe_dsa":
-        from ddim_cold_tpu.models import glm
-
-        return glm.check_trunk, glm.layer
-    if model_type == "pangu_ultra_moe":
-        from ddim_cold_tpu.models import pangu
-
-        return pangu.check_trunk, pangu.layer
-    if model_type == "nemotron_h":
-        from ddim_cold_tpu.models import nemotron
-
-        return nemotron.check_trunk, nemotron.layer
-    if model_type == "kimi_linear":
-        from ddim_cold_tpu.models import kimi
-
-        return kimi.check_trunk, kimi.layer
-    raise ValueError(f"no layer stack for model_type {model_type!r}: 'jamba', "
-                     "'laguna', 'glm_moe_dsa', 'pangu_ultra_moe', "
-                     "'nemotron_h' and 'kimi_linear' are written")
+    if model_type not in STACKS:
+        raise ValueError(f"no layer stack for model_type {model_type!r}: "
+                         f"{', '.join(map(repr, STACKS))} are written")
+    stack = importlib.import_module(
+        "ddim_cold_tpu.models." + STACKS[model_type])
+    return stack.check_trunk, stack.layer
 
 
 def _frozen(trunk: Mapping[str, Any]) -> flax.core.FrozenDict:

@@ -34,7 +34,10 @@ per-layer lists (``layer_types``, ``mlp_layer_types``,
   over ``num_experts_routed`` outputs, ``num_experts_per_tok`` a token,
   weights renormalised (``norm_topk_prob``) and scaled by
   ``moe_routed_scaling_factor``, experts and the shared expert at
-  ``moe_intermediate_size`` / ``shared_expert_intermediate_size``.
+  ``moe_intermediate_size`` / ``shared_expert_intermediate_size``. SiLU is
+  this stack's gate everywhere: ``HeldExpertsMlp`` is left at its default
+  ``hidden_act`` (the experts' gate is a choice since the ``smallthinker``
+  stack, whose experts are ReLU-gated).
 
 **The share.** ``num_experts`` is how many experts THIS chip holds,
 ``experts_held_from`` (default 0) the first of them, ``num_experts_routed``

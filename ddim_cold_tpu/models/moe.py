@@ -41,6 +41,7 @@ Two dispatch implementations, selectable per config (``moe_dispatch``):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -48,11 +49,15 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ddim_cold_tpu.models.init import trunc_normal
+from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import tiling
 from ddim_cold_tpu.ops.grouped_matmul import grouped_mlp
 from ddim_cold_tpu.ops.quant import gelu_exact
 
 Dtype = Any
+
+#: what the held experts' router read (``kernels.moe_route_source``)
+_kernels = metrics.scope("kernels")
 
 
 class SwitchMlp(nn.Module):
@@ -161,9 +166,10 @@ class SwitchMlp(nn.Module):
 
 
 class HeldExpertsMlp(nn.Module):
-    """Top-k routed experts (gated SiLU, or ungated squared ReLU) plus a
-    shared one, at the residual width or in a latent of their own, computed
-    by a chip
+    """Top-k routed experts (gated SiLU or gated ReLU, or ungated squared
+    ReLU), with or without a shared one beside them, at the residual width or
+    in a latent of their own, routed from the rows they read or from another
+    tensor, computed by a chip
     that is TOLD WHICH EXPERTS IT HOLDS: of ``num_routed`` experts, the
     ``num_held`` from ``first_held`` on. The expert-parallel share of a layer,
     without its exchange.
@@ -180,6 +186,21 @@ class HeldExpertsMlp(nn.Module):
     left out: the shares of all the chips, the shared expert counted once,
     sum to the whole layer (tested).
 
+    ``__call__(y, route_from=None)``: with ``route_from`` (y's leading shape,
+    any width) the router reads IT, ``r = softmax_f32(route_from W_r)``, and
+    only the router: the experts and the shared expert still read y. A stack
+    whose router sits a sub-layer before its experts hands the earlier tensor
+    here (``models/smallthinker.py``: the layer's input, before attention);
+    everything after the logits is the same code.
+
+    ``shared_features=0``: no shared expert — no parameters, no product, the
+    sum starts from zeros; the shares then sum to the whole layer with nothing
+    counted once.
+
+    ``hidden_act="relu"``: gated like ``"silu"``, ``relu`` in the place of
+    ``SiLU``: ``W_down(relu(W_gate y) ⊙ W_up y)``. No zero of the ReLU is
+    skipped: the products are dense. Refused beside a shared expert, which no
+    configuration has ReLU-gated: ``hybrid.GatedMlp`` is SiLU's.
     ``hidden_act="relu2"``: ``Shared`` and every ``E_e`` the UNGATED MLP
     ``W_down relu(W_up ·)²``. ``latent_features``: the routed experts live in
     a latent of that width, narrower than the residual stream: ``ℓ = y W_ℓin``
@@ -190,20 +211,27 @@ class HeldExpertsMlp(nn.Module):
 
     No capacity, no dropped assignment: every (row, expert, weight) triple of
     the ``rows · top_k`` routed is kept in a buffer of exactly that many rows
-    (the worst case, every row routed to held experts only), sorted by expert
+    (the worst case, every row routed to held experts only — which is every
+    run's case where ``num_held == num_routed``), sorted by expert
     with the assignments to experts held elsewhere last, in a null group that
     has no weights and costs no product: ``ops.grouped_matmul`` skips the row
     tiles past the last held group and writes them as zeros. The sorted rows
     are gathered once and go through ``ops.grouped_matmul.grouped_mlp``: two
-    launches on the TPU, one for gate, up and ``SiLU(g) ⊙ u`` (both products
-    and the SiLU in float32, one rounding; ungated: up and ``relu(u)²``), one
+    launches on the TPU, one for gate, up and ``act(g) ⊙ u`` (both products
+    and the activation in float32, one rounding; ungated: up and
+    ``relu(u)²``), one
     for down. Each row then adds
     up its own ``top_k`` results by position (a gather by the inverse
     permutation, weighted and summed in float32: no scatter).
 
-    Parameters: ``router (hidden, num_routed)``; ``gate_proj``,
+    Counters, +1 a trace: ``kernels.moe_route_source`` (``layer_input`` with
+    ``route_from``, ``expert_input`` without).
+
+    Parameters: ``router (width of what it reads, num_routed)``;
+    ``gate_proj``,
     ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
-    width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp``;
+    width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp`` (none with
+    ``shared_features=0``);
     ``e_score_correction_bias (num_routed,)`` with ``selection_bias``.
     Ungated: no ``gate_proj``, ``shared_expert`` a ``hybrid.SquaredReluMlp``.
     In a latent: ``fc1_latent_proj``, ``fc2_latent_proj`` (a Dense each), and
@@ -225,58 +253,82 @@ class HeldExpertsMlp(nn.Module):
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array,
+                 route_from: jax.Array | None = None) -> jax.Array:
         from ddim_cold_tpu.models.hybrid import GatedMlp, SquaredReluMlp
 
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {self.score!r}: 'softmax' and 'sigmoid' "
                              "are written")
-        if self.hidden_act not in ("silu", "relu2"):
-            raise ValueError(f"hidden_act {self.hidden_act!r}: 'silu' (gated) "
-                             "and 'relu2' (ungated) are written")
-        gated = self.hidden_act == "silu"
+        if self.hidden_act not in ("silu", "relu", "relu2"):
+            raise ValueError(f"hidden_act {self.hidden_act!r}: 'silu', 'relu' "
+                             "(gated) and 'relu2' (ungated) are written")
+        if self.hidden_act == "relu" and self.shared_features:
+            raise ValueError("hidden_act 'relu' with a shared expert "
+                             f"(shared_features {self.shared_features}): the "
+                             "shared expert is written SiLU-gated or ungated")
+        gated = self.hidden_act != "relu2"
         *lead, D = x.shape
         y = x.reshape(-1, D)
+        if route_from is not None and route_from.shape[:-1] != x.shape[:-1]:
+            raise ValueError(f"route_from {route_from.shape} names other rows "
+                             f"than the experts read {x.shape}")
+        _kernels.inc("kernels.moe_route_source", key=(
+            "expert_input" if route_from is None else "layer_input"))
+        routed_on = y if route_from is None else route_from.reshape(
+            -1, route_from.shape[-1])
         T, k, G, F = y.shape[0], self.top_k, self.num_held, self.hidden_features
         if not 0 <= self.first_held <= self.num_routed - G or k > self.num_routed:
             raise ValueError(
                 f"experts {self.first_held}..{self.first_held + G - 1} held, "
                 f"{k} a token, of {self.num_routed} routed")
-        shared = (GatedMlp if gated else SquaredReluMlp)(
-            {"hidden_size": D, "intermediate_size": self.shared_features},
-            self.dtype, self.param_dtype, name="shared_expert")(y)
+        shared = None
+        if self.shared_features:
+            shared = (GatedMlp if gated else SquaredReluMlp)(
+                {"hidden_size": D, "intermediate_size": self.shared_features},
+                self.dtype, self.param_dtype, name="shared_expert")(y)
 
         param = lambda name, shape: self.param(
             name, trunc_normal(std=0.02), shape, self.param_dtype
         ).astype(self.dtype)
-        logits = jnp.dot(y, param("router", (D, self.num_routed)),
-                         preferred_element_type=jnp.float32)
-        r = (jax.nn.softmax(logits, axis=-1) if self.score == "softmax"
-             else jax.nn.sigmoid(logits))
-        if self.selection_bias:
-            bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros_init(),
-                              (self.num_routed,), self.param_dtype)
-            _, top_e = jax.lax.top_k(r + bias.astype(jnp.float32), k)
-            top_r = jnp.take_along_axis(r, top_e, axis=-1)
-        else:
-            top_r, top_e = jax.lax.top_k(r, k)  # (T, k); ties to the lower index
-        if self.norm_topk:
-            top_r = top_r / jnp.sum(top_r, axis=-1, keepdims=True)
-        weight = self.scaling * top_r
+        # the routing — logits, the top k, the sort and the group bounds —
+        # depends on what the router reads alone: handed another tensor than
+        # the experts' it is traced under a scope of its own, and XLA may run
+        # it as early as that tensor exists
+        with (jax.named_scope("trunk/route") if route_from is not None
+              else contextlib.nullcontext()):
+            logits = jnp.dot(
+                routed_on,
+                param("router", (routed_on.shape[-1], self.num_routed)),
+                preferred_element_type=jnp.float32)
+            r = (jax.nn.softmax(logits, axis=-1) if self.score == "softmax"
+                 else jax.nn.sigmoid(logits))
+            if self.selection_bias:
+                bias = self.param("e_score_correction_bias",
+                                  nn.initializers.zeros_init(),
+                                  (self.num_routed,), self.param_dtype)
+                _, top_e = jax.lax.top_k(r + bias.astype(jnp.float32), k)
+                top_r = jnp.take_along_axis(r, top_e, axis=-1)
+            else:
+                # (T, k); ties to the lower index
+                top_r, top_e = jax.lax.top_k(r, k)
+            if self.norm_topk:
+                top_r = top_r / jnp.sum(top_r, axis=-1, keepdims=True)
+            weight = self.scaling * top_r
 
-        # assignment a = (row a // k, its (a % k)-th expert); key: the held
-        # expert's index here, or G for an expert held elsewhere
-        local = top_e - self.first_held
-        held = (local >= 0) & (local < G)
-        key = jnp.where(held, local, G).reshape(T * k).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True)
-        bounds = jnp.searchsorted(key[order], jnp.arange(G + 1, dtype=jnp.int32))
-        group_sizes = jnp.diff(bounds)
-        # whole row tiles, so that the product pads nothing; rows past the
-        # assignments read row 0 and lie past every group
-        M = tiling.round_up(T * k, 128)
-        source = jnp.pad(order // k, (0, M - T * k))
+            # assignment a = (row a // k, its (a % k)-th expert); key: the held
+            # expert's index here, or G for an expert held elsewhere
+            local = top_e - self.first_held
+            held = (local >= 0) & (local < G)
+            key = jnp.where(held, local, G).reshape(T * k).astype(jnp.int32)
+            order = jnp.argsort(key, stable=True)
+            bounds = jnp.searchsorted(key[order],
+                                      jnp.arange(G + 1, dtype=jnp.int32))
+            group_sizes = jnp.diff(bounds)
+            # whole row tiles, so that the product pads nothing; rows past the
+            # assignments read row 0 and lie past every group
+            M = tiling.round_up(T * k, 128)
+            source = jnp.pad(order // k, (0, M - T * k))
         latent = self.latent_features is not None
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=self.dtype,
@@ -289,15 +341,19 @@ class HeldExpertsMlp(nn.Module):
 
         out = grouped_mlp(rows, param("gate_proj", (G, K, F)) if gated else None,
                           param("up_proj", (G, K, F)),
-                          param("down_proj", (G, F, K)), group_sizes)
+                          param("down_proj", (G, F, K)), group_sizes,
+                          **({"act": self.hidden_act} if gated else {}))
 
         where = jnp.argsort(order).reshape(T, k)  # a's place among the sorted
         weight = jnp.where(held, weight, 0.0)
-        total = (jnp.zeros((T, K), jnp.float32) if latent
+        total = (jnp.zeros((T, K), jnp.float32) if latent or shared is None
                  else shared.astype(jnp.float32))
         for j in range(k):
             total += weight[:, j, None] * out[where[:, j]].astype(jnp.float32)
         if latent:
-            total = shared.astype(jnp.float32) + dense(D, "fc2_latent_proj")(
+            beside = None if shared is None else shared.astype(jnp.float32)
+            total = dense(D, "fc2_latent_proj")(
                 total.astype(self.dtype)).astype(jnp.float32)
+            if beside is not None:
+                total = beside + total
         return total.astype(self.dtype).reshape(*lead, D)

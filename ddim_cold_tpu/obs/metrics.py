@@ -133,6 +133,11 @@ METRICS = (
     ("kernels.moe_gate_up_schedule", "counter",
      "expert MLP first-half traces by path (key: fused, the one gate-up "
      "launch; xla, two ragged_dots and the product)"),
+    # -- kernels (models/moe.HeldExpertsMlp, counted once a trace) --------
+    ("kernels.moe_route_source", "counter",
+     "held-expert layer traces by what the router read (key: expert_input, "
+     "the rows the experts read; layer_input, another tensor handed in: "
+     "route_from)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

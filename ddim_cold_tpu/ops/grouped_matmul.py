@@ -8,10 +8,12 @@ which is ``jax.lax.ragged_dot``'s contract (:func:`grouped_matmul`), and the
 first half of the experts' gated MLP as ONE such pass over the rows
 (:func:`grouped_gate_up`),
 
-    h[r] = SiLU(rows[r] @ w_gate[g]) * (rows[r] @ w_up[g])     (0 past the groups)
+    h[r] = act(rows[r] @ w_gate[g]) * (rows[r] @ w_up[g])      (0 past the groups)
 
-both products accumulated, and the SiLU and the product between them taken, in
-float32: one rounding, on the store. An UNGATED expert's first half
+``act`` the gate's activation, a static choice of the same launch: ``"silu"``
+(the default) or ``"relu"``. Both products are accumulated, and the activation
+and the product between them taken, in float32: one rounding, on the store. An
+UNGATED expert's first half
 (:func:`grouped_relu2`) is the one product under a squared ReLU,
 
     h[r] = relu(rows[r] @ w_up[g])²                            (0 past the groups)
@@ -80,6 +82,9 @@ from ddim_cold_tpu.ops.flash_attention import (
 _kernels = metrics.scope("kernels")
 
 _TILE_M = 128
+#: the gate's activation in the gated first half, by its name in a
+#: configuration: on the float32 product, in the launch and in XLA alike
+GATE_ACTS = {"silu": jax.nn.silu, "relu": lambda g: jnp.maximum(g, 0.0)}
 #: the most scoped VMEM a launch asks for (``vmem_limit_bytes``): half of the
 #: 128 MiB a v5e core has; ``_SCOPED_VMEM_BYTES`` is what it gets unasked
 _VMEM_CEILING_BYTES = 64 << 20
@@ -93,13 +98,14 @@ def grouped_matmul_xla(rows, w, group_sizes):
                               ).astype(rows.dtype)
 
 
-def grouped_gate_up_xla(rows, w_gate, w_up, group_sizes):
-    """Two ``ragged_dot``s and ``SiLU(g) * u`` between them in float32, one
-    rounding to ``rows``' dtype. ``w_gate``, ``w_up``: ``(G, K, F)``."""
+def grouped_gate_up_xla(rows, w_gate, w_up, group_sizes, act: str = "silu"):
+    """Two ``ragged_dot``s and ``act(g) * u`` between them in float32, one
+    rounding to ``rows``' dtype. ``w_gate``, ``w_up``: ``(G, K, F)``; ``act``
+    of :data:`GATE_ACTS`."""
     g, u = (jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
                                preferred_element_type=jnp.float32)
             for w in (w_gate, w_up))
-    return (jax.nn.silu(g) * u).astype(rows.dtype)
+    return (GATE_ACTS[act](g) * u).astype(rows.dtype)
 
 
 def grouped_relu2_xla(rows, w_up, group_sizes):
@@ -182,10 +188,11 @@ def _work_items(group_sizes, *, n_rows: int, tile_m: int):
 
 
 def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
-                x_ref, *refs, n_groups: int, zero_tail: bool, relu2: bool):
+                x_ref, *refs, n_groups: int, zero_tail: bool, relu2: bool,
+                act: str):
     """One (column tile, work item) program; see the module docstring. One
     weight operand: the product, or with ``relu2`` the square of its ReLU.
-    Two, gate then up: ``SiLU(g) * u`` of the two float32 products."""
+    Two, gate then up: ``act(g) * u`` of the two float32 products."""
     del read_ref  # the index maps' business
     *w_refs, o_ref = refs
     item = pl.program_id(1)
@@ -205,7 +212,7 @@ def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
                                 preferred_element_type=jnp.float32)
                         for w_ref in w_refs]
             if up:
-                acc = jax.nn.silu(acc) * up[0]
+                acc = GATE_ACTS[act](acc) * up[0]
             elif relu2:
                 acc = jnp.square(jnp.maximum(acc, 0.0))
             o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), kept)
@@ -217,7 +224,7 @@ def _gmm_kernel(group_ref, tile_ref, read_ref, bounds_ref, used_ref,
 
 
 def _launch(rows, ws, group_sizes, *, tiles, vmem_limit=None, zero_tail=True,
-            relu2=False, interpret=None):
+            relu2=False, act="silu", interpret=None):
     """``pallas_call(name="moe_gmm")`` of ``rows (M, K)`` against the weight
     operands ``ws``, each ``(G, K, N)``, at ``tiles`` (tile_m, tile_n). Rows
     are padded to whole tiles when they are not (the expert layer sizes its
@@ -237,7 +244,7 @@ def _launch(rows, ws, group_sizes, *, tiles, vmem_limit=None, zero_tail=True,
         (1, K, tn), lambda n, i, grp, *_: (jnp.minimum(grp[i], G - 1), 0, n))
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, n_groups=G, zero_tail=zero_tail,
-                          relu2=relu2),
+                          relu2=relu2, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(N // tn, m_pad // tm + G),
@@ -269,10 +276,12 @@ def grouped_matmul_kernel(rows, w, group_sizes, *, tiles=None, interpret=None):
     return _launch(rows, (w,), group_sizes, tiles=tiles, interpret=interpret)
 
 
-def grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, *, zero_tail=True,
-                           tiles=None, vmem_limit=None, interpret=None):
+def grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, *, act="silu",
+                           zero_tail=True, tiles=None, vmem_limit=None,
+                           interpret=None):
     """The Pallas path of :func:`grouped_gate_up`: ONE launch, both products
-    over the row tile while it sits in VMEM. ``zero_tail=False`` leaves the
+    over the row tile while it sits in VMEM, ``act`` of :data:`GATE_ACTS` on
+    the gate's. ``zero_tail=False`` leaves the
     row tiles past the last group unwritten (:func:`grouped_mlp`). ``tiles``,
     ``vmem_limit`` and ``interpret`` are for the tests and a sweep on the
     chip; the program leaves them to the shape and the backend."""
@@ -280,7 +289,7 @@ def grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes, *, zero_tail=True,
         *tiles, vmem_limit = _gate_up_tiles(*rows.shape, w_gate.shape[2],
                                             rows.dtype)
     return _launch(rows, (w_gate, w_up), group_sizes, tiles=tiles,
-                   vmem_limit=vmem_limit, zero_tail=zero_tail,
+                   vmem_limit=vmem_limit, zero_tail=zero_tail, act=act,
                    interpret=interpret)
 
 
@@ -311,14 +320,16 @@ def _no_vjp(kernel, differentiable: str):
 
 
 _kernel_no_vjp = _no_vjp(grouped_matmul_kernel, "grouped_matmul_xla")
-_gate_up_no_vjp = _no_vjp(grouped_gate_up_kernel, "grouped_gate_up_xla")
-_gate_up_open_tail_no_vjp = _no_vjp(
-    functools.partial(grouped_gate_up_kernel, zero_tail=False),
-    "grouped_gate_up_xla")
-_relu2_no_vjp = _no_vjp(grouped_relu2_kernel, "grouped_relu2_xla")
-_relu2_open_tail_no_vjp = _no_vjp(
-    functools.partial(grouped_relu2_kernel, zero_tail=False),
-    "grouped_relu2_xla")
+#: the first half's launches by (the gate's activation, or None for the
+#: ungated half; whether the tail is written)
+_first_half_no_vjp = {
+    (act, zero_tail): _no_vjp(
+        functools.partial(grouped_relu2_kernel, zero_tail=zero_tail)
+        if act is None else
+        functools.partial(grouped_gate_up_kernel, act=act,
+                          zero_tail=zero_tail),
+        "grouped_relu2_xla" if act is None else "grouped_gate_up_xla")
+    for act in (None, *GATE_ACTS) for zero_tail in (True, False)}
 
 
 def grouped_matmul(rows, w, group_sizes):
@@ -333,47 +344,53 @@ def grouped_matmul(rows, w, group_sizes):
     return grouped_matmul_xla(rows, w, group_sizes)
 
 
-def _first_half(launch, rows, ws, group_sizes):
-    """``launch`` on the TPU, the XLA composition elsewhere, of the weight
-    operands ``ws``: (gate, up), counted once as a gated first half and twice
-    as a product, or (up,) alone, the ungated half: one product."""
+def _first_half(rows, ws, group_sizes, act, zero_tail=True):
+    """The launch on the TPU, the XLA composition elsewhere, of the weight
+    operands ``ws``: (gate, up) under the gate's ``act``, counted once as a
+    gated first half and twice as a product, or (up,) alone with ``act``
+    None, the ungated half: one product."""
     use_kernel = jax.default_backend() == "tpu"
-    if len(ws) == 2:
+    if act is not None:
+        if act not in GATE_ACTS:
+            raise ValueError(f"gate activation {act!r}: {sorted(GATE_ACTS)} "
+                             "are written")
         _kernels.inc("kernels.moe_gate_up_schedule",
                      key="fused" if use_kernel else "xla")
     _kernels.inc("kernels.moe_gmm_schedule", len(ws),
                  key="kernel" if use_kernel else "xla")
     if use_kernel:
         with jax.named_scope("moe_gmm"):
-            return launch(rows, *ws, group_sizes)
-    xla = grouped_gate_up_xla if len(ws) == 2 else grouped_relu2_xla
-    return xla(rows, *ws, group_sizes)
+            return _first_half_no_vjp[act, zero_tail](rows, *ws, group_sizes)
+    if act is None:
+        return grouped_relu2_xla(rows, *ws, group_sizes)
+    return grouped_gate_up_xla(rows, *ws, group_sizes, act)
 
 
-def grouped_gate_up(rows, w_gate, w_up, group_sizes):
+def grouped_gate_up(rows, w_gate, w_up, group_sizes, act: str = "silu"):
     """``h`` of the module docstring's contract, ``(M, F)`` in ``rows``'
-    dtype: float32 accumulation, SiLU and product on either path, one launch
-    on the TPU."""
-    return _first_half(_gate_up_no_vjp, rows, (w_gate, w_up), group_sizes)
+    dtype: float32 accumulation, activation and product on either path, one
+    launch on the TPU."""
+    return _first_half(rows, (w_gate, w_up), group_sizes, act)
 
 
 def grouped_relu2(rows, w_up, group_sizes):
     """The ungated ``h`` of the module docstring's contract, ``(M, F)`` in
     ``rows``' dtype: float32 accumulation, ReLU and square on either path, one
     launch on the TPU."""
-    return _first_half(_relu2_no_vjp, rows, (w_up,), group_sizes)
+    return _first_half(rows, (w_up,), group_sizes, None)
 
 
-def grouped_mlp(rows, w_gate, w_up, w_down, group_sizes):
+def grouped_mlp(rows, w_gate, w_up, w_down, group_sizes, act: str = "silu"):
     """The experts' whole MLP, ``out[r] = h[r] @ w_down[g]`` with ``h`` of
-    the module docstring's contract, the gated one or, where ``w_gate`` is
-    None, the ungated: ``(M, K)``, zero past the last group. Two launches on
+    the module docstring's contract, the gated one under the gate's ``act``
+    or, where ``w_gate`` is None, the ungated: ``(M, K)``, zero past the last
+    group. Two launches on
     the TPU, and because ``h`` lives only between them, the first leaves the
     row tiles past the last group unwritten: the second reads no row tile
     beyond the last group's (:func:`_work_items`)."""
     if w_gate is None:
-        h = _first_half(_relu2_open_tail_no_vjp, rows, (w_up,), group_sizes)
+        h = _first_half(rows, (w_up,), group_sizes, None, zero_tail=False)
     else:
-        h = _first_half(_gate_up_open_tail_no_vjp, rows, (w_gate, w_up),
-                        group_sizes)
+        h = _first_half(rows, (w_gate, w_up), group_sizes, act,
+                        zero_tail=False)
     return grouped_matmul(h, w_down, group_sizes)

@@ -16,6 +16,7 @@ is keyed by the local device kind, both of which are the CPU's here, so the
 tests steer them — not an option of the program.
 """
 
+import functools
 import os
 import re
 import types
@@ -1040,3 +1041,76 @@ def test_causal_conv_lowers_at_the_published_shapes_and_keeps_its_name(
         # out images-minor and copies it for the launch; inside a mixer's
         # program the projection writes the launch's layout: PERF.md, PR 46.)
         assert operands.startswith("%u")
+
+
+# --- the masked forward and the ReLU-gated expert product at
+# --- SmallThinker-21BA3B-Instruct's shapes --------------------------------
+
+SMALLTHINKER = dict(n=1, L=16130, heads=28, kv=4, hd=128, window=4096,
+                    theta=1.5e6, hidden=2560, width=768, experts=64,
+                    rows=96896)  # 16,130 x 6 assignments in whole tiles
+
+
+@pytest.mark.parametrize("windowed", [True, False])
+def test_fwd_masked_lowers_at_the_smallthinker_shapes_and_says_its_kind(
+        windowed, chip):
+    """The masked forward at 16,130 tokens, 28 query heads on 4 K/V heads of
+    128 (7 a K/V head): a window layer's (window 4,096 = eight chunks of 512,
+    q turned in the launch by the default rotary, θ = 1.5 M) and a full
+    layer's (causal, ``rotary=None``: no table, no turn). ONE
+    ``tpu_custom_call`` each, named ``%fwd_masked``, the same result shape —
+    and ``flash_masked_mixed_roofline`` tells them apart by their operands,
+    five against three."""
+    from benchmark.layer_metrics import flash_masked_mixed_roofline as reader
+    from ddim_cold_tpu.models.laguna import rotary_frequencies
+    from ddim_cold_tpu.ops.rotary import Rotary
+
+    s = SMALLTHINKER
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    rotary = (Rotary(*rotary_frequencies({"rope_theta": s["theta"]}, s["hd"]))
+              if windowed else None)
+    text = jax.jit(lambda q, k, v: fa.masked_attention(
+        q, k, v, s["hd"] ** -0.5, causal=True,
+        window=s["window"] if windowed else None, rotary=rotary)).lower(
+        sds((s["n"], s["L"], s["heads"], s["hd"]), jnp.bfloat16),
+        *[sds((s["n"], s["L"], s["kv"], s["hd"]), jnp.bfloat16)] * 2,
+        ).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    view = types.SimpleNamespace(trace=types.SimpleNamespace(
+        devices={0: {"ops": [(0, 1000, calls[0])]}}))
+    assert [e[:2] for e in reader.events(view)] == [(s["n"], windowed)]
+
+
+@pytest.mark.parametrize("launch", ["gate_up_relu", "down", "whole_relu"])
+def test_moe_gmm_lowers_at_the_smallthinker_shapes_and_keeps_its_name(
+        launch, chip):
+    """The experts' two launches over all 64 groups of a layer, 96,896 buffer
+    rows: gate, up and ``relu(g) * u`` at K 2,560, F 768 as ONE launch, down
+    at K 768, N 2,560, and the whole MLP as those two; every one named
+    ``%moe_gmm`` with result ``[buffer rows, N]``, as ``moe_gmm_roofline``'s
+    events (which ``moe_gmm_reglu_roofline`` reads) match it."""
+    from benchmark.layer_metrics import moe_gmm_roofline as reader
+    from ddim_cold_tpu.ops import grouped_matmul as gm
+
+    s = SMALLTHINKER
+    rows, K, F, G = s["rows"], s["hidden"], s["width"], s["experts"]
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    up, down = sds((G, K, F), jnp.bfloat16), sds((G, F, K), jnp.bfloat16)
+    sizes = sds((G,), jnp.int32)
+    if launch == "down":
+        fn, args, want = gm.grouped_matmul, (sds((rows, F), jnp.bfloat16),
+                                             down, sizes), [[rows, K]]
+    elif launch == "gate_up_relu":
+        fn = functools.partial(gm.grouped_gate_up, act="relu")
+        args, want = (sds((rows, K), jnp.bfloat16), up, up, sizes), [[rows, F]]
+    else:
+        fn = functools.partial(gm.grouped_mlp, act="relu")
+        args = (sds((rows, K), jnp.bfloat16), up, up, down, sizes)
+        want = [[rows, F], [rows, K]]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [[int(g) for g in reader.NAME.match(call).groups()[1:]]
+            for call in calls] == want

@@ -1,9 +1,11 @@
 """ops/grouped_matmul.py: the ``moe_gmm`` kernel in interpreter mode against
 ``jax.lax.ragged_dot`` — empty, one-row and tile-straddling groups, rows past
 the last group — as the one product and as the expert MLP's first half (gate
-and up in one launch, ``SiLU(g) * u``; or ungated, ``relu(u)²`` of the one
-product); its work list, its tiles, its counters and its refusal to
-differentiate."""
+and up in one launch, ``act(g) * u`` with ``act`` SiLU or ReLU; or ungated,
+``relu(u)²`` of the one product); its work list, its tiles, its counters and
+its refusal to differentiate."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,14 +23,15 @@ def _operands(M, sizes, K=32, N=64, dtype=jnp.float32, seed=1, banks=1):
             jnp.array(sizes, jnp.int32))
 
 
-def _first_half(rows, w_gate, w_up, group_sizes, dtype=jnp.float32):
-    """``SiLU(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)``, each step
+def _first_half(rows, w_gate, w_up, group_sizes, dtype=jnp.float32,
+                act=jax.nn.silu):
+    """``act(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)``, each step
     rounded to ``dtype``: in bfloat16 the three-step composition the expert
     layer ran before the launch was fused."""
     g, u = (jax.lax.ragged_dot(rows.astype(dtype), w.astype(dtype), group_sizes,
                                preferred_element_type=jnp.float32).astype(dtype)
             for w in (w_gate, w_up))
-    return jax.nn.silu(g) * u
+    return act(g) * u
 
 
 GROUPS = [
@@ -253,9 +256,10 @@ def test_counter_says_which_path_a_trace_took_and_the_cpu_takes_ragged_dot(
 def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot(
         banks, ungated):
     rows, *ws, group_sizes = _operands(40, [5, 0, 1, 20, 7], banks=banks)
-    public, launch = ((gm.grouped_relu2, gm._relu2_no_vjp) if ungated
-                      else (gm.grouped_matmul, gm._kernel_no_vjp) if banks == 1
-                      else (gm.grouped_gate_up, gm._gate_up_no_vjp))
+    public, launch = (
+        (gm.grouped_relu2, gm._first_half_no_vjp[None, True]) if ungated
+        else (gm.grouped_matmul, gm._kernel_no_vjp) if banks == 1
+        else (gm.grouped_gate_up, gm._first_half_no_vjp["silu", True]))
     grads = jax.grad(lambda r, *ws: jnp.sum(public(r, *ws, group_sizes) ** 2),
                      argnums=tuple(range(1 + banks)))(rows, *ws)
     assert all(np.isfinite(np.asarray(g)).all() and np.asarray(g).any()
@@ -264,3 +268,112 @@ def test_product_differentiates_off_the_chip_and_the_kernel_says_it_cannot(
     with pytest.raises(NotImplementedError, match="moe_gmm kernel has no "
                                                   "backward"):
         jax.grad(lambda r: jnp.sum(launch(r, *ws, group_sizes)))(rows)
+
+
+# ------------------------------------------------ the gate's activation
+
+def _relu_first_half(rows, w_gate, w_up, group_sizes, dtype=jnp.float32):
+    """:func:`_first_half` under a ReLU written as a select."""
+    return _first_half(rows, w_gate, w_up, group_sizes, dtype,
+                       act=lambda g: jnp.where(g > 0, g, 0.0).astype(dtype))
+
+
+@pytest.mark.parametrize("sizes,M,tiles", GROUPS)
+def test_relu_gated_launch_matches_its_xla_form(sizes, M, tiles):
+    """``act="relu"`` is the same launch with ``relu`` in SiLU's place: the
+    kernel in the interpreter against ``grouped_gate_up_xla(..., "relu")``,
+    that against the composition written out, zeros past the groups — and it
+    is not the SiLU launch's result."""
+    rows, w_gate, w_up, group_sizes = _operands(M, sizes, banks=2)
+    rows = rows * 0.25
+    want = gm.grouped_gate_up_xla(rows, w_gate, w_up, group_sizes, "relu")
+    np.testing.assert_array_equal(
+        want, _relu_first_half(rows, w_gate, w_up, group_sizes))
+    got = gm.grouped_gate_up_kernel(
+        rows, w_gate, w_up, group_sizes, act="relu",
+        tiles=tiles or gm._tiles(M, 32, 64, jnp.float32))
+    assert got.shape == want.shape == (M, 64)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert not np.asarray(got[sum(sizes):]).any()
+    if sum(sizes):
+        silu = gm.grouped_gate_up_xla(rows, w_gate, w_up, group_sizes)
+        assert float(jnp.abs(silu - want).max()) > 0.05
+        # a ReLU's zeros are written as zeros, where SiLU leaves a tail
+        assert (np.asarray(got[:sum(sizes)]) == 0).mean() > 0.3
+        # the default is SiLU, bit for bit
+        np.testing.assert_array_equal(
+            silu, gm.grouped_gate_up_xla(rows, w_gate, w_up, group_sizes, "silu"))
+
+
+def test_relu_gated_launch_in_bfloat16_rounds_once():
+    bf16 = jnp.bfloat16
+    rows, w_gate, w_up, group_sizes = _operands(
+        256, [100, 28, 60], K=256, N=128, dtype=bf16, banks=2)
+    rows = rows * 0.0625
+    got = gm.grouped_gate_up_kernel(rows, w_gate, w_up, group_sizes,
+                                    act="relu", tiles=(128, 128))
+    assert got.dtype == bf16
+    exact = _relu_first_half(rows, w_gate, w_up, group_sizes)
+    steps = _relu_first_half(rows, w_gate, w_up, group_sizes, bf16)
+    err = lambda h: float(jnp.sqrt(jnp.mean((h.astype(jnp.float32) - exact) ** 2)))
+    assert err(got) < 0.75 * err(steps)
+    np.testing.assert_allclose(got.astype(jnp.float32), exact,
+                               rtol=1e-2, atol=1e-2)
+    # the XLA form rounds once too: the two agree to a last bit of bfloat16
+    # where their float32 sums were added in another order
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), gm.grouped_gate_up_xla(
+            rows, w_gate, w_up, group_sizes, "relu").astype(jnp.float32),
+        rtol=2 ** -7, atol=1e-6)
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_grouped_mlp_passes_the_gates_activation_on(act):
+    """The whole MLP under either activation, off the chip the XLA forms:
+    down of ``act(g) * u``; the traced program holds the activation asked
+    for and not the other, and the first half's path is counted as before."""
+    from ddim_cold_tpu.obs import metrics
+
+    metrics.reset()
+    rows, w_gate, w_up, group_sizes = _operands(40, [5, 0, 1, 20, 7], N=32,
+                                                banks=2)
+    w_down = jax.random.normal(jax.random.PRNGKey(5), (5, 32, 32)) / 8
+    got = gm.grouped_mlp(rows, w_gate, w_up, w_down, group_sizes, act=act)
+    half = (_relu_first_half if act == "relu" else _first_half)(
+        rows, w_gate, w_up, group_sizes)
+    np.testing.assert_allclose(
+        got, gm.grouped_matmul_xla(half, w_down, group_sizes),
+        rtol=1e-6, atol=1e-6)
+    counted = {}
+    for series in metrics.snapshot().values():
+        for name in ("kernels.moe_gate_up_schedule",
+                     "kernels.moe_gmm_schedule"):
+            counted.update({name: series[name + "/by_key"]}
+                           if name + "/by_key" in series else {})
+    assert counted == {"kernels.moe_gate_up_schedule": {"xla": 1},
+                       "kernels.moe_gmm_schedule": {"xla": 3}}
+    metrics.reset()
+    traced = str(jax.make_jaxpr(functools.partial(gm.grouped_mlp, act=act))(
+        rows, w_gate, w_up, w_down, group_sizes))
+    assert ("logistic" in traced, " max " in traced) == (
+        act == "silu", act == "relu"), traced
+
+
+def test_an_activation_the_launch_does_not_know_is_refused_by_name():
+    from ddim_cold_tpu.obs import metrics
+
+    metrics.reset()
+    rows, w_gate, w_up, group_sizes = _operands(40, [5, 0, 1, 20, 7], banks=2)
+    with pytest.raises(ValueError, match=r"gate activation 'gelu'.*'relu', 'silu'"):
+        gm.grouped_gate_up(rows, w_gate, w_up, group_sizes, "gelu")
+    counted = lambda: {name for series in metrics.snapshot().values()
+                       for name in series if name.startswith("kernels.moe_")}
+    assert counted() == set()  # refused before anything is counted
+    # the ungated half counts its product and no gate
+    gm.grouped_relu2(rows, w_up, group_sizes)
+    assert counted() == {"kernels.moe_gmm_schedule",
+                         "kernels.moe_gmm_schedule/by_key"}
+    # a launch for each (activation or none, tail written or left)
+    assert set(gm._first_half_no_vjp) == {
+        (act, tail) for act in (None, "relu", "silu") for tail in (False, True)}
+    metrics.reset()

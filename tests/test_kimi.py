@@ -271,7 +271,7 @@ def test_gradients_flow_off_the_chip():
     (dict(qk_rope_head_dim=32), "rot 32"),
     (dict(num_attention_heads=3), "an even number of heads"),
     (dict(experts_held_from=9), "held of 16 routed"),
-    (dict(model_type="llama"), "'nemotron_h' and 'kimi_linear'"),
+    (dict(model_type="llama"), "'nemotron_h', 'kimi_linear'"),
 ])
 def test_what_the_stack_cannot_run_is_refused_at_construction(change, match):
     with pytest.raises(ValueError, match=match):
