@@ -114,6 +114,10 @@ METRICS = (
      "flash backward traces by operand layout (key: in_place|head_major)"),
     ("kernels.flash_fwd_mask", "counter",
      "flash forward traces by mask (key: none|causal|window|selected)"),
+    ("kernels.flash_fwd_fold", "counter",
+     "masked- and selected-forward traces by the query heads of a K/V head "
+     "ONE program folds a fetched chunk into (key: 1, or a divisor of the "
+     "query heads a K/V head)"),
     ("kernels.flash_fwd_rotary", "counter",
      "selected- and masked-forward traces handed an unturned q, by where "
      "its rotation "

@@ -95,7 +95,12 @@ and ask for dq and dk/dv.
 Beside the unmasked pair above, three forward launches of their own, for the
 decoder stacks under ``models/hybrid.py`` (sampled only: none has a backward
 yet, and each says so by name): ``fwd_masked`` (causal and window masks on
-shared K/V heads, the chunks outside a q block's mask skipped in the grid),
+shared K/V heads, the chunks outside a q block's mask skipped in the grid;
+ONE program folds a fetched chunk into several query heads of its K/V head,
+as independent chains — grid ``(rows, groups of heads, q blocks, visited
+chunks)``, the heads a program and its q block chosen from the shape under a
+budget on the compiled body, the launch's trace kept for the layers that
+launch the same shapes: :func:`_masked_fold`, :func:`_fwd_masked_call`),
 ``fwd_selected`` (the same body under a per-query key selection) and
 ``fwd_latent`` (the same body over a score that is the SUM of two products,
 the second over a key part all the heads share, with the value head at its
@@ -1420,14 +1425,29 @@ class _Ints:
 def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
                        n_kv: int, causal: bool, window: int | None,
                        selected: bool = False, parts: int = 1,
-                       turn: tuple | None = None):
-    """One (image, query head, q block, visited chunk) program of the masked
-    forward: one head on the block's lanes. ``parts``: the score is the sum of
+                       turn: tuple | None = None, heads: int = 1):
+    """One (image, group of ``heads`` query heads, q block, visited chunk)
+    program of the masked forward. ``heads`` 1 — every launch but
+    ``fwd_masked`` on shared K/V heads — is one head on the block's lanes.
+    More (:func:`_masked_fold`): the q and result blocks are ``(1, bq, heads ·
+    lanes)``, adjacent query heads of ONE K/V head side by side where
+    ``q_proj`` wrote them, K and V the ``(1, bkv, lanes)`` chunk they share,
+    and each head has its own columns of every scratch. What does not depend
+    on the head — K, V with a ragged chunk's stale rows zeroed, the element
+    mask — is made once, by the first head; then each head's score GEMM,
+    float32 softmax and value GEMM follow as the one-head program's own
+    operations in their own order, so a head's context is bit for bit what a
+    program of its own gives. The heads' chains stand in ONE basic block with
+    no dependence between them: the scheduler lays one head's ``exp`` under
+    another's MXU passes, which a program a head cannot (its one chain waits
+    for the vector unit and back).
+
+    ``parts`` (one head a program): the score is the sum of
     that many products, ``parts`` q blocks then ``parts`` k blocks before v,
     laid side by side on the lanes into ONE contraction (the latent forward's
     ``q_nope·k_nope + q_r·k_r``: :func:`_fwd_latent_kernel`); the value head,
     and with it the accumulator and the result, has its own width.
-    ``selected``: one more operand after v,
+    ``selected`` (one head a program): one more operand after v,
     the ``(bq, bkv)`` int8 tile of a per-query key selection
     (``ops/sparse_select.py``), comes before the result; a pair is then kept
     where the tile is not 0 AND the mask lets it through, so every visited
@@ -1440,9 +1460,9 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     takes the unmasked fold. ``turn`` (:func:`_turn_geometry`; one part): q
     comes as its projection wrote it and two more operands before the result,
     the ``(bq, 128)`` float32 cos and sin tables of the lane group that holds
-    the head's rotated dims, turn it HERE, once a q block, into one more
-    scratch after the softmax's (:func:`_turn_q_block`), which every fold then
-    reads in place of the q block.
+    a head's rotated dims, turn it HERE, once a q block and head, into one
+    more scratch after the softmax's (:func:`_turn_q_block`), which every
+    fold then reads in place of the q block.
 
     A row whose first visited chunk is wholly masked for it (a window's far
     chunk, for the block's last rows) holds m = −1e30 and garbage l, acc until
@@ -1451,6 +1471,7 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
     rest = refs[2 * parts + 1:]
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    turned_ref = None
     if turn is not None:
         cos_ref, sin_ref, *rest, turned_ref = rest
     o_ref, acc_ref, m_ref, l_ref = rest
@@ -1462,54 +1483,74 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
 
     fold_scale = _scale_folds_into_q(scale)
 
+    def of_head(f: int, ref):
+        """Head ``f``'s columns of a block or scratch ``heads`` heads wide;
+        the ref itself in the one-head program."""
+        if heads == 1 or ref is None:
+            return ref
+        width = ref.shape[-1] // heads
+        return ref.at[..., pl.ds(f * width, width)]
+
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if turn is not None:
-            _turn_q_block(q_refs[0], cos_ref, sin_ref, turned_ref,
-                          scale if fold_scale else None, *turn)
+            for f in range(heads):
+                _turn_q_block(of_head(f, q_refs[0]), cos_ref, sin_ref,
+                              of_head(f, turned_ref),
+                              scale if fold_scale else None, *turn)
 
     def fold(masked: bool):
-        qs, ks, v = [r[0] for r in q_refs], [r[0] for r in k_refs], v_ref[0]
-        if turn is not None:  # turned, and scaled where the scale folds
-            qs = [turned_ref[...]]
-        elif fold_scale:
-            qs = [q * scale for q in qs]
-        q, k = (x[0] if parts == 1 else jnp.concatenate(x, axis=1)
-                for x in (qs, ks))
-        if masked and n_valid % bkv:
-            # rows of a ragged last chunk hold whatever the buffer held; their
-            # p is an exact 0, and 0 × garbage is NaN
-            vrow = c * bkv + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-            v = jnp.where(vrow < n_valid, v, jnp.zeros_like(v))
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (bq, bkv)
-        if not fold_scale:
-            logits = logits * scale
-        if masked:
-            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-            col = c * bkv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-            keep = col < n_valid
-            if causal:
-                keep &= col <= row
-            if window is not None:
-                keep &= col > row - window
-            if selected:
-                keep &= keep_ref[0].astype(jnp.int32) != 0
-            logits = jnp.where(keep, logits, _NEG_INF)
-        m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (bq, 1)
-        l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for f in range(heads):
+            acc_f, m_f, l_f = (of_head(f, r) for r in (acc_ref, m_ref, l_ref))
+            qs = [of_head(f, r)[0] for r in q_refs]
+            if f == 0:  # the group's one chunk
+                ks, v = [r[0] for r in k_refs], v_ref[0]
+            if turn is not None:  # turned, and scaled where the scale folds
+                qs = [of_head(f, turned_ref)[...]]
+            elif fold_scale:
+                qs = [q * scale for q in qs]
+            q = qs[0] if parts == 1 else jnp.concatenate(qs, axis=1)
+            if f == 0:
+                k = ks[0] if parts == 1 else jnp.concatenate(ks, axis=1)
+                if masked and n_valid % bkv:
+                    # rows of a ragged last chunk hold whatever the buffer
+                    # held; their p is an exact 0, and 0 × garbage is NaN
+                    vrow = c * bkv + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 0)
+                    v = jnp.where(vrow < n_valid, v, jnp.zeros_like(v))
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (bq, bkv)
+            if not fold_scale:
+                logits = logits * scale
+            if masked:
+                if f == 0:  # one element mask for all the heads
+                    row = i * bq + jax.lax.broadcasted_iota(
+                        jnp.int32, logits.shape, 0)
+                    col = c * bkv + jax.lax.broadcasted_iota(
+                        jnp.int32, logits.shape, 1)
+                    keep = col < n_valid
+                    if causal:
+                        keep &= col <= row
+                    if window is not None:
+                        keep &= col > row - window
+                    if selected:
+                        keep &= keep_ref[0].astype(jnp.int32) != 0
+                logits = jnp.where(keep, logits, _NEG_INF)
+            m_prev = jnp.max(m_f[...], axis=-1, keepdims=True)  # (bq, 1)
+            l_prev = jnp.max(l_f[...], axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_f[...] = acc_f[...] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_f[...] = jnp.broadcast_to(m_new, m_f.shape)
+            l_f[...] = jnp.broadcast_to(l_new, l_f.shape)
 
     # every token of the block sees the whole chunk
     whole = (c + 1) * bkv <= n_valid
@@ -1524,8 +1565,10 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
 
     @pl.when(j == n_kv - 1)
     def _emit():
-        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for f in range(heads):
+            o_f, acc_f, l_f = (of_head(f, r) for r in (o_ref, acc_ref, l_ref))
+            l = jnp.max(l_f[...], axis=-1, keepdims=True)
+            o_f[0] = (acc_f[...] / l).astype(o_f.dtype)
 
 
 def _turn_geometry(rotary: Rotary) -> tuple | None:
@@ -1574,6 +1617,41 @@ def _masked_blocks(n_tokens: int, dtype) -> tuple:
     return block, block
 
 
+#: q rows ONE program of ``fwd_masked`` may hold over all the heads it folds,
+#: at 128 lanes a head (a head of 256 lanes counts twice): 9 × 256. Mosaic
+#: unrolls the body over every (8, 128) tile of every head's (bq, bkv) scores,
+#: so what a process's FIRST set-up pays — the body's compile, the executable
+#: it stores once a call site — grows with heads × rows (1.0–1.9 MB and
+#: 1.1–1.9 s a launch at this budget, 0.45–0.9 MB and 0.4–1.1 s a head a
+#: program; twice the rows, 1.6–2.1 MB and 2.5–3.5 s). The same row keeps the
+#: blocks and scratch of the widest group inside the default scoped VMEM, in
+#: float32 too (tests/test_chip_compile.py compiles the edge). A WARM set-up
+#: pays for neither: it pays for tracing and lowering the body, ``heads``
+#: chains of Python whatever the rows — which is why the launch keeps its
+#: trace (:func:`_fwd_masked_call`).
+_FOLD_ROWS = 2304
+
+
+def _masked_fold(rep: int, n_tokens: int, lanes: int, dtype) -> tuple:
+    """``(heads, block_q)`` of ``fwd_masked``: how many of the ``rep`` query
+    heads that share a K/V head ONE program folds a fetched chunk into, and
+    its q block. From the call's shapes alone: q blocks of 256 rows wherever
+    a program folds more than one head — half :func:`_masked_blocks`'s, for
+    half the compiled body a head, at 6 % of the kernel's time in
+    SmallThinker and none in Laguna or Nemotron — and the largest divisor of
+    ``rep`` whose heads × rows stay within :data:`_FOLD_ROWS`: 7 of
+    SmallThinker's 7, 6 and 9 of Laguna's, 8 of Nemotron's 16. ``rep`` 1, or no divisor but 1 (a
+    prime above 9), is the one-head program at :func:`_masked_blocks`'s q
+    block."""
+    bq = tiling.legal_block(256, tiling.round_up(n_tokens, 8), dtype)
+    rows = _FOLD_ROWS * _LANE // lanes
+    heads = max(f for f in range(1, rep + 1) if rep % f == 0 and
+                (f == 1 or f * bq <= rows))
+    if heads == 1:
+        return 1, _masked_blocks(n_tokens, dtype)[0]
+    return heads, bq
+
+
 def _chunk_walk(tokens: int, geometry: dict) -> tuple:
     """(q blocks, steps of the last grid axis, ``chunk(i, j)``) of a launch
     that walks only the K/V chunks a q block's mask lets it see: the axis is
@@ -1591,23 +1669,29 @@ def _chunk_walk(tokens: int, geometry: dict) -> tuple:
     return n_q, n_kv, chunk
 
 
-def _walk_scratch(bq: int, lanes: int) -> list:
-    """The online softmax's state across a q block's visited chunks."""
+def _walk_scratch(bq: int, lanes: int, heads: int = 1) -> list:
+    """The online softmax's state across a q block's visited chunks, of every
+    one of the ``heads`` query heads a program folds: head ``f`` holds columns
+    ``f · lanes`` on of the accumulator, ``f · 128`` on of the statistics."""
     return [
-        pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
-        pltpu.VMEM((bq, _LANE), jnp.float32),  # running max
-        pltpu.VMEM((bq, _LANE), jnp.float32),  # running denominator
+        pltpu.VMEM((bq, heads * lanes), jnp.float32),  # output accumulator
+        pltpu.VMEM((bq, heads * _LANE), jnp.float32),  # running max
+        pltpu.VMEM((bq, heads * _LANE), jnp.float32),  # running denominator
     ]
 
 
-#: grid (rows, heads, q blocks, visited chunks): the state is carried along
-#: the last axis alone
+#: grid (rows, heads or groups of them, q blocks, visited chunks): the state
+#: is carried along the last axis alone
 _WALK_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("selected", "rep", "lanes", "scale", "n_valid", "bq",
+                     "bkv", "causal", "window", "interpret", "turn", "heads"))
 def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
-                     bq, bkv, causal, window, interpret, turn=None):
+                     bq, bkv, causal, window, interpret, turn=None, heads=1):
     """The masked launch (``fwd_masked``), or ``selected``, with ``keep``
     first in ``more`` — an int8 ``(rows, tokens⁺, tokens⁺)`` selection in
     whole ``(bq, bkv)`` tiles, one for all the heads — the selected one
@@ -1615,51 +1699,66 @@ def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
     ``q``: ``(rows, tokens, H·lanes)``; ``k``, ``v``:
     ``(rows, tokens, H/rep·lanes)``, query head ``h`` reading K/V column
     block ``h // rep``; all three where the projections wrote them, the token
-    axis ending inside the last block. Grid ``(rows, H, q blocks, visited
-    chunks)``: the last axis is as long as the most chunks any q block sees
-    (two for a window of 512 at these blocks; all of them under a causal
-    mask), and a block that sees fewer re-addresses its last chunk, which is
-    not fetched again, and skips the fold. ``turn`` with two tables last in
-    ``more``, the float32 ``(tokens⁺, 128)`` cos and sin of the rotated lane
-    group in whole q blocks: q is unturned and the launch turns it
-    (:func:`_fwd_masked_kernel`), a ``(bq, 128)`` block of each table a q
-    block and one more scratch, the turned block; the grid is then ``(rows, q
-    blocks, H, visited chunks)``, the heads INSIDE the q blocks, so that a q
-    block's tables are fetched once for all its heads and not once a head
-    (0.6 GB a ``fwd_selected`` launch at 9,217 tokens × 64 heads, 0.7 GB a
-    ``fwd_masked`` one at 4 × 4,097 × 72, under steps that have no time to
-    hide it); every other block is fetched as often either way."""
+    axis ending inside the last block. ``heads`` (a divisor of ``rep``:
+    :func:`_masked_fold`; 1 under a selection) consecutive query heads, all of
+    ONE K/V head, are one program's: group ``g``'s q and result blocks are
+    ``(1, bq, heads·lanes)`` at column block ``g`` and its K and V blocks
+    ``(1, bkv, lanes)`` at column block ``g // (rep // heads)``, a chunk
+    fetched once a (group, q block, chunk) and folded into every head of the
+    group (:func:`_fwd_masked_kernel`), scratch a head. Grid ``(rows, H /
+    heads, q blocks, visited chunks)``: the last axis is as long as the most
+    chunks any q block sees (two for a window of 512 at blocks of 512; all of
+    them under a causal mask), and a block that sees fewer re-addresses its
+    last chunk, which is not fetched again, and skips the fold. ``turn`` with
+    two tables last in ``more``, the float32 ``(tokens⁺, 128)`` cos and sin of
+    the rotated lane group in whole q blocks: q is unturned and the launch
+    turns it (:func:`_fwd_masked_kernel`), a ``(bq, 128)`` block of each table
+    a q block and one more scratch, the turned block of every head; the grid
+    is then ``(rows, q blocks, H / heads, visited chunks)``, the groups INSIDE
+    the q blocks, so that a q block's tables are fetched once for all its
+    heads and not once a group (0.6 GB a ``fwd_selected`` launch at 9,217
+    tokens × 64 heads, under steps that have no time to hide it); every other
+    block is fetched as often either way.
+
+    An inline ``jit``: the launch lands in the caller's program as it would
+    without (no call, the caller's named scopes on it), but JAX keeps its
+    trace, so the layers of a stack that launch the same shapes trace the
+    kernel's body ONCE — a body that holds ``heads`` chains is ``heads`` times
+    the Python to trace, and a warm set-up pays for tracing in full (two
+    traces for SmallThinker's eight launches, two for Laguna's five)."""
     keep, tables = (more[0], more[1:]) if selected else (None, more)
     rows, tokens, width = q.shape
     geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
                     window=window)
     n_q, n_kv, chunk = _chunk_walk(tokens, geometry)
-    grid = [rows, width // lanes, n_q, n_kv]
-    at = lambda index_map: index_map  # written over (b, h, i, j)
+    wide = heads * lanes
+    grid = [rows, width // wide, n_q, n_kv]
+    at = lambda index_map: index_map  # written over (b, g, i, j)
     if turn is not None:
         grid[1:3] = grid[2], grid[1]
-        at = lambda index_map: lambda b, i, h, j: index_map(b, h, i, j)
+        at = lambda index_map: lambda b, i, g, j: index_map(b, g, i, j)
 
-    def kv_map(b, h, i, j):
-        return (b, chunk(i, j), h // rep)
+    def kv_map(b, g, i, j):
+        return (b, chunk(i, j), g // (rep // heads))
 
-    q_spec = pl.BlockSpec((1, bq, lanes), at(lambda b, h, i, j: (b, i, h)))
+    q_spec = pl.BlockSpec((1, bq, wide), at(lambda b, g, i, j: (b, i, g)))
     kv_spec = pl.BlockSpec((1, bkv, lanes), at(kv_map))
     name, operands, in_specs = "fwd_masked", (q, k, v), [q_spec, kv_spec, kv_spec]
     if keep is not None:
         name, operands = "fwd_selected", (q, k, v, keep)
         in_specs.append(pl.BlockSpec(
-            (1, bq, bkv), at(lambda b, h, i, j: (b, i, kv_map(b, h, i, j)[1]))))
-    scratch = _walk_scratch(bq, lanes)
+            (1, bq, bkv), at(lambda b, g, i, j: (b, i, kv_map(b, g, i, j)[1]))))
+    scratch = _walk_scratch(bq, lanes, heads)
     if turn is not None:
         operands += tables
         in_specs += [pl.BlockSpec((bq, _LANE),
-                                  at(lambda b, h, i, j: (i, 0)))] * 2
-        scratch.append(pltpu.VMEM((bq, lanes), q.dtype))  # the turned q block
+                                  at(lambda b, g, i, j: (i, 0)))] * 2
+        scratch.append(pltpu.VMEM((bq, wide), q.dtype))  # the turned q blocks
     with profiling.scope(f"flash_attention/{name}"):
         return pl.pallas_call(
             functools.partial(_fwd_masked_kernel, scale=scale, n_kv=n_kv,
-                              selected=keep is not None, turn=turn, **geometry),
+                              selected=keep is not None, turn=turn,
+                              heads=heads, **geometry),
             grid=tuple(grid),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -1686,8 +1785,17 @@ def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
     group, and the context written where the output projection reads it; any
     other head size is zero-padded to the lanes first (a copy in HBM on each
     side). K/V chunks that lie wholly outside the mask of a q block are
-    neither fetched nor computed (:func:`_fwd_masked_call`). Blocks come from
-    the shape. ``rotary``: q comes UNTURNED and the launch turns the q block
+    neither fetched nor computed (:func:`_fwd_masked_call`), and a chunk that
+    is fetched is fetched once for all the query heads ONE program folds it
+    into: the largest divisor of ``H / KV`` whose heads × 256 q rows stay
+    within 2,304 (:func:`_masked_fold`: 7 of 7 at SmallThinker's shape, 6 of 6
+    and 9 of 9 at Laguna's, 8 of 16 at Nemotron's), their chains independent
+    in one program so that one head's softmax runs under another's GEMMs;
+    each head's context is bit for bit what a program of its own gives, and
+    ``H == KV`` is that program itself at q blocks of 512.
+    ``kernels.flash_fwd_fold`` counts the heads a program folds, +1 a trace.
+    Blocks and heads a program come from the shapes alone. ``rotary``: q
+    comes UNTURNED and the launch turns the q block
     it holds, as :func:`flash_attention_selected` says (a head of 128 whose
     every dim turns, or whose first half does: its one lane group). No
     backward yet: the VJP raises by name (ROADMAP Reach)."""
@@ -1708,8 +1816,9 @@ def _check_shared_heads(q, k, v) -> None:
 def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
     """The launch of :func:`flash_attention_masked` (``keep`` None) or
     :func:`flash_attention_selected` on ``(B, N, heads, D)`` operands: heads
-    zero-padded to whole lanes where ``D`` does not fill them, blocks from
-    the shape, one launch a device under a mesh. ``rotary``: q is unturned
+    zero-padded to whole lanes where ``D`` does not fill them, blocks and the
+    heads a program folds from the shape (:func:`_masked_fold`; one head under
+    a selection), one launch a device under a mesh. ``rotary``: q is unturned
     and the launch turns it, by tables made here for every device."""
     B, N, H, D = q.shape
     KV = k.shape[2]
@@ -1717,6 +1826,10 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
     if lanes != D:
         q, k, v = (_pad_to(x, 3, _LANE) for x in (q, k, v))
     bq, bkv = _masked_blocks(N, q.dtype)
+    heads = 1  # a selection's tile is (bq, bkv) of these blocks
+    if keep is None:
+        heads, bq = _masked_fold(H // KV, N, lanes, q.dtype)
+    _kernels.inc("kernels.flash_fwd_fold", key=str(heads))
     spec = rows_spec(B)
     operands = (q.reshape(B, N, H * lanes), k.reshape(B, N, KV * lanes),
                 v.reshape(B, N, KV * lanes))
@@ -1733,7 +1846,8 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
         functools.partial(
             _fwd_masked_call, selected=keep is not None, rep=H // KV,
             lanes=lanes, scale=scale, n_valid=N, bq=bq, bkv=bkv, causal=causal,
-            window=window, interpret=kernel_interpret(), turn=turn),
+            window=window, interpret=kernel_interpret(), turn=turn,
+            heads=heads),
         specs, spec,
     )(*operands)
     return out.reshape(B, N, H, lanes)[..., :D]

@@ -602,30 +602,70 @@ def test_ssm_scan_lowers_at_the_published_shape_and_keeps_its_name(dtype, chip):
 LAGUNA = dict(n=4, L=4097, kv=8, hd=128, window=512)
 
 
-@pytest.mark.parametrize("heads,window", [(72, LAGUNA["window"]), (48, None)])
+#: NVIDIA-Nemotron-3-Super's one attention layer at the cell's 2,048 px: 32
+#: query heads on 2 (16 a K/V head), 16,385 tokens, causal, no position term
+NEMOTRON_ATTENTION = dict(n=1, L=16385, kv=2, hd=128)
+
+
+def _one_launch(text):
+    """The one ``tpu_custom_call`` line of a compiled module's text."""
+    (call,) = [line.strip().removeprefix("ROOT ")
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    return call
+
+
+def _as_a_trace(call):
+    """A readers' view whose trace holds that one launch, for a millisecond."""
+    return types.SimpleNamespace(
+        config={"head_dim": 128},
+        trace=types.SimpleNamespace(devices={0: {"ops": [(0, 10 ** 6, call)]}}))
+
+
+def _operands(call):
+    """How many operands the launch's line names."""
+    inside = call[call.index("custom-call(") + len("custom-call("):
+                  call.index("custom_call_target")]
+    return len(re.findall(r"%[\w.\-]+", inside))
+
+
+@pytest.mark.parametrize("shape,heads,window,fold", [
+    (LAGUNA, 72, LAGUNA["window"], 9), (LAGUNA, 48, None, 6),
+    (NEMOTRON_ATTENTION, 32, None, 8)],
+    ids=["laguna72", "laguna48", "nemotron32"])
 def test_fwd_masked_lowers_at_the_published_shapes_and_keeps_its_name(
-        heads, window, chip):
+        shape, heads, window, fold, chip):
     """``ops/flash_attention.py``'s masked forward at 4 x 4,097 tokens, 72
-    (window 512) and 48 (full) query heads of 128 on 8 K/V heads, blocks from
-    the shape: ONE ``tpu_custom_call``, named ``%fwd_masked``
+    (window 512) and 48 (full) query heads of 128 on 8 K/V heads, and at
+    Nemotron's 16,385 tokens, 32 heads on 2; blocks and the heads a program
+    folds from the shape (9, 6, 8 at q blocks of 256:
+    ``kernels.flash_fwd_fold``), inside the default scoped VMEM: ONE
+    ``tpu_custom_call``, named ``%fwd_masked``, three operands, the result
+    where ``o_proj`` reads it
     (``benchmark/layer_metrics/flash_masked_fwd_roofline.py`` matches it by
-    that name and reads the head count off its width), which the ``%fwd``
-    reader does not match."""
+    that name and reads the head count off its width,
+    ``flash_masked_mixed_roofline.py`` its kind off the operands), which the
+    ``%fwd`` reader does not match."""
     from benchmark.layer_metrics import flash_fwd_roofline
     from benchmark.layer_metrics import flash_masked_fwd_roofline as reader
+    from benchmark.layer_metrics import flash_masked_mixed_roofline as mixed
+    from ddim_cold_tpu.obs import metrics
 
     sds = _struct(SingleDeviceSharding(chip[0]))
-    n, L, kv, hd = (LAGUNA[k] for k in ("n", "L", "kv", "hd"))
-    text = jax.jit(lambda q, k, v: fa.masked_attention(
+    n, L, kv, hd = (shape[k] for k in ("n", "L", "kv", "hd"))
+    metrics.reset()
+    call = _one_launch(jax.jit(lambda q, k, v: fa.masked_attention(
         q, k, v, hd ** -0.5, causal=True, window=window)).lower(
         sds((n, L, heads, hd), jnp.bfloat16), sds((n, L, kv, hd), jnp.bfloat16),
-        sds((n, L, kv, hd), jnp.bfloat16)).compile().as_text()
-    calls = [line.strip() for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 1
-    m = reader.NAME.match(calls[0])
+        sds((n, L, kv, hd), jnp.bfloat16)).compile().as_text())
+    assert fa._kernels.by_key("kernels.flash_fwd_fold") == {str(fold): 1}
+    metrics.reset()
+    m = reader.NAME.match(call)
     assert m and [int(g) for g in m.groups()[1:]] == [n, L, heads * hd]
-    assert not flash_fwd_roofline.NAME.match(calls[0])
+    assert _operands(call) == 3
+    assert not flash_fwd_roofline.NAME.match(call)
+    assert [e[:2] for e in reader.events(_as_a_trace(call))] == [(n, heads)]
+    assert [e[:2] for e in mixed.events(_as_a_trace(call))] == [(n, False)]
 
 
 def _bench_config(name):
@@ -690,7 +730,13 @@ def test_laguna_attention_compiled_for_the_chip_turns_q_in_the_launch(
         entry, flags=re.M)}
     calls = [m for m in made.values() if "tpu_custom_call" in m.group(5)]
     assert len(calls) == 1 and reader.NAME.match(calls[0].group(0).strip())
+    # the launch the readers know: q, k, v and the two tables, the result as
+    # wide as q — whatever group of heads a program of it folds
+    launch = calls[0].group(0).strip().removeprefix("ROOT ")
+    assert _operands(launch) == 5
+    assert [e[:2] for e in reader.events(_as_a_trace(launch))] == [(n, heads)]
     wide = f"{n},{L},{heads * hd}"
+    assert calls[0].group(3) == wide
     short = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
     # the launch's first operand, through bitcasts, is q_proj's GEMM
     source = made[re.match(r"(%[\w.\-]+)", calls[0].group(5)).group(1)]
@@ -1060,27 +1106,131 @@ def test_fwd_masked_lowers_at_the_smallthinker_shapes_and_says_its_kind(
     layer's (causal, ``rotary=None``: no table, no turn). ONE
     ``tpu_custom_call`` each, named ``%fwd_masked``, the same result shape —
     and ``flash_masked_mixed_roofline`` tells them apart by their operands,
-    five against three."""
+    five against three. Either kind's program folds all seven heads of a K/V
+    head at q blocks of 256 (``kernels.flash_fwd_fold``)."""
+    from benchmark.layer_metrics import flash_masked_fwd_roofline as by_width
     from benchmark.layer_metrics import flash_masked_mixed_roofline as reader
     from ddim_cold_tpu.models.laguna import rotary_frequencies
+    from ddim_cold_tpu.obs import metrics
     from ddim_cold_tpu.ops.rotary import Rotary
 
     s = SMALLTHINKER
     sds = _struct(SingleDeviceSharding(chip[0]))
     rotary = (Rotary(*rotary_frequencies({"rope_theta": s["theta"]}, s["hd"]))
               if windowed else None)
-    text = jax.jit(lambda q, k, v: fa.masked_attention(
+    metrics.reset()
+    call = _one_launch(jax.jit(lambda q, k, v: fa.masked_attention(
         q, k, v, s["hd"] ** -0.5, causal=True,
         window=s["window"] if windowed else None, rotary=rotary)).lower(
         sds((s["n"], s["L"], s["heads"], s["hd"]), jnp.bfloat16),
         *[sds((s["n"], s["L"], s["kv"], s["hd"]), jnp.bfloat16)] * 2,
-        ).compile().as_text()
-    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 1
-    view = types.SimpleNamespace(trace=types.SimpleNamespace(
-        devices={0: {"ops": [(0, 1000, calls[0])]}}))
-    assert [e[:2] for e in reader.events(view)] == [(s["n"], windowed)]
+        ).compile().as_text())
+    assert fa._kernels.by_key("kernels.flash_fwd_fold") == {"7": 1}
+    metrics.reset()
+    assert _operands(call) == (5 if windowed else 3)
+    assert [e[:2] for e in reader.events(_as_a_trace(call))] == [
+        (s["n"], windowed)]
+    m = by_width.NAME.match(call)
+    assert [int(g) for g in m.groups()[1:]] == [s["n"], s["L"],
+                                                s["heads"] * s["hd"]]
+
+
+@pytest.mark.parametrize("rep,lanes,tokens,turned,dtype,fold", [
+    (9, 128, 4097, True, jnp.float32, 9),    # the budget's 2,304 rows, f32
+    (18, 128, 8200, True, jnp.bfloat16, 9),
+    (4, 256, 9217, True, jnp.float32, 4),    # heads of 256: half the rows
+    (4, 256, 9217, False, jnp.bfloat16, 4),
+    (16, 128, 16385, False, jnp.float32, 8),
+])
+def test_the_fold_budget_admits_only_what_compiles(rep, lanes, tokens, turned,
+                                                   dtype, fold, chip):
+    """What :func:`_masked_fold` chooses at the edge of its row budget —
+    nine heads of 128, four of 256, float32 too, with the in-launch turn's
+    tables and scratch — compiles under the default scoped VMEM."""
+    from ddim_cold_tpu.models.laguna import rotary_frequencies
+    from ddim_cold_tpu.ops.rotary import Rotary
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    rotary = (Rotary(*rotary_frequencies({"rope_theta": 1e6}, 128))
+              if turned else None)
+    assert fa._masked_fold(rep, tokens, lanes, dtype) == (fold, 256)
+    q, kv = (sds((1, tokens, heads, lanes), dtype) for heads in (rep * 2, 2))
+    _one_launch(jax.jit(lambda q, k, v: fa.masked_attention(
+        q, k, v, 0.1, rotary=rotary)).lower(q, kv, kv).compile().as_text())
+
+
+def _without_locations(text):
+    """(sha256 of the lowered module with every Mosaic body taken out, sha256
+    of each body as MLIR text without its source locations): what a launch
+    lowers to, whatever line of ``flash_attention.py`` its code stands on."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    sha = lambda t: hashlib.sha256(t.encode()).hexdigest()[:12]
+    config = re.compile(r'backend_config = "((?:[^"\\]|\\.)*)"')
+    bodies = []
+    for found in config.finditer(text):
+        body = json.loads(found.group(1).replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        context = jax_mlir.make_ir_context()
+        context.allow_unregistered_dialects = True  # stable_mosaic's version
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            bodies.append(sha(module.operation.get_asm(enable_debug_info=False)))
+    return sha(config.sub('backend_config = "..."', text)), bodies
+
+
+#: :func:`_without_locations` of the launches that keep one head a program,
+#: lowered for the described chip on be8ed9d, the parent of the PR that let
+#: ``fwd_masked`` fold a group of heads. A change that MEANS to alter one of
+#: these programs pins it anew; the fold must not.
+ONE_HEAD_LAUNCHES = {
+    "selected": ("af383f7c3848", ["de5cd8e035df"]),
+    "selected_turned": ("2596f1c5065d", ["47337572572d"]),
+    "latent": ("8c2ffd4dec3c", ["f319f13093e2"]),
+    "masked_a_head_a_kv_head": ("369e4dba0d96", ["c9ba812f86b8"]),
+}
+
+
+@pytest.mark.parametrize("launch", sorted(ONE_HEAD_LAUNCHES))
+def test_one_head_launches_lower_to_the_text_they_had(launch, chip):
+    """``fwd_selected`` (GLM's shape, with and without the in-launch turn),
+    ``fwd_latent`` (Pangu's) and ``fwd_masked`` with a K/V head a query head,
+    lowered for the TPU: the module and the Mosaic body, source locations
+    apart, are what they were before a program of ``fwd_masked`` could hold
+    a group of heads."""
+    from ddim_cold_tpu.ops import sparse_select as ss
+    from ddim_cold_tpu.ops.rotary import Rotary
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    bf = jnp.bfloat16
+    if launch.startswith("selected"):
+        n, L, H, hd = (GLM[k] for k in ("n", "L", "heads", "hd"))
+        length = ss.mask_length(L, bf)
+        rotary = None if launch == "selected" else Rotary(
+            8000000.0 ** (-np.arange(0, 64, 2) / 64), 1.0, "interleave",
+            hd - 64)
+        head = sds((n, L, H, hd), bf)
+        lowered = jax.jit(lambda q, k, v, keep: fa.selected_attention(
+            q, k, v, hd ** -0.5, keep, rotary)).lower(
+            head, head, head, sds((n, length, length), jnp.int8))
+    elif launch == "latent":
+        n, L, H, nope, rot, vd = (PANGU[k] for k in
+                                  ("n", "L", "heads", "nope", "rot", "vd"))
+        lowered = jax.jit(lambda qn, qr, kn, kr, v: fa.latent_attention(
+            qn, qr, kn, kr, v, (nope + rot) ** -0.5)).lower(
+            sds((n, L, H, nope), bf), sds((n, L, H, rot), bf),
+            sds((n, L, H, nope), bf), sds((n, L, rot), bf),
+            sds((n, L, H, vd), bf))
+    else:
+        q = sds((1, 16385, 8, 128), bf)
+        lowered = jax.jit(lambda q, k, v: fa.masked_attention(
+            q, k, v, 0.1)).lower(q, q, q)
+    assert _without_locations(lowered.as_text()) == ONE_HEAD_LAUNCHES[launch]
 
 
 @pytest.mark.parametrize("launch", ["gate_up_relu", "down", "whole_relu"])

@@ -6,7 +6,12 @@ multiples of the 512-token chunk; a window smaller and larger than a chunk;
 which chunks a q block visits; the counter; the unmasked path untouched. And
 the option of the same body that ``fwd_selected`` and ``fwd_masked`` take: an
 UNTURNED q turned inside the launch, once a q block, against ``apply_rotary``
-before it."""
+before it. And the fold: ONE program of ``fwd_masked`` holding a K/V chunk for
+several query heads of its K/V head, bit for bit a program a head, the heads
+and the q block from the shape under a row budget; ``fwd_selected`` and
+``fwd_latent`` the programs they were."""
+
+import hashlib
 
 import jax
 import jax.extend
@@ -374,7 +379,7 @@ def test_off_the_tpu_masked_attention_turns_q_before_the_oracle():
     metrics.reset()
 
 
-def _launch(fn, *args):
+def _pallas_call(fn, *args):
     """The one ``pallas_call`` equation in the jaxpr of ``fn(*args)``."""
     found = []
 
@@ -387,6 +392,12 @@ def _launch(fn, *args):
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     (eqn,) = found
+    return eqn
+
+
+def _launch(fn, *args):
+    """Name, operand count and scratch shapes of that launch."""
+    eqn = _pallas_call(fn, *args)
     scratch = eqn.params["grid_mapping"].num_scratch_operands
     shapes = [(v.aval.shape, v.aval.dtype) for v in eqn.params["jaxpr"].invars]
     return (eqn.params["name"], len(eqn.invars),
@@ -398,11 +409,13 @@ def test_the_launches_without_the_option_are_the_parents():
     to run: the operands, the scratch (accumulator, running max, running
     denominator — and a head's half of q_r for the latent launch) and the
     names they had; with it ``fwd_selected`` and ``fwd_masked`` keep their
-    names and take two tables and one more scratch, the turned q block."""
+    names and take two tables and one more scratch, the turned q block. (A
+    K/V head a query head, so that ``fwd_masked`` too holds one head a
+    program; the group's program is further down.)"""
     f32 = jnp.dtype("float32")
     walk = lambda bq, lanes: [((bq, lanes), f32), ((bq, 128), f32),
                               ((bq, 128), f32)]
-    q, k, v = _qkv(40, 2, 1, 256)
+    q, k, v = _qkv(40, 2, 2, 256)
     assert _launch(lambda q, k, v: fa.flash_attention_masked(
         q, k, v, 0.1, window=16), q, k, v) == ("fwd_masked", 3, walk(40, 256))
     keep = _selection(1, 40, q.dtype)
@@ -446,3 +459,243 @@ def test_the_rotary_counter_says_where_q_was_turned():
                      pairing="interleave", first=192).reshape(q.shape),
         k, v, 0.1, keep))
     metrics.reset()
+
+
+# --- the query heads of a K/V head folded by ONE program ---------------------
+
+@pytest.mark.parametrize("rep,KV,N,window,turned,fold", [
+    (7, 2, 600, None, False, (7, 256)),   # full; a ragged last chunk
+    (7, 2, 1024, 700, True, (7, 256)),    # window over chunks, with the turn
+    (7, 1, 1024, 200, False, (7, 256)),   # the far chunk wholly masked for
+                                          # the last rows of q block 2
+    (6, 2, 600, None, True, (6, 256)),
+    (9, 1, 1030, 512, True, (9, 256)),    # three chunks, two visited a block
+    (9, 2, 600, 600, False, (9, 256)),
+    (16, 1, 600, None, False, (8, 256)),  # 8 of the 16: two groups a K/V head
+    (16, 1, 1024, 300, True, (8, 256)),
+    (2, 2, 37, 8, False, (2, 48)),        # one short block
+    (11, 1, 600, None, False, (1, 512)),  # a prime above the budget: one head
+    (1, 3, 600, 200, True, (1, 512)),     # a K/V head a query head
+], ids=lambda x: None if isinstance(x, bool) else str(x).replace(" ", ""))
+def test_the_fold_is_bit_for_bit_a_program_a_head(rep, KV, N, window, turned,
+                                                  fold):
+    """``rep`` query heads on each of ``KV`` K/V heads, bfloat16, the model's
+    scale on the scores: the launch whose programs fold a chunk into ``fold``
+    = (heads, q rows) against the SAME call with K and V repeated to a head
+    each, which the rule can only give the one-head program at 512 rows —
+    bit for bit; and against ``blockwise_attention_xla`` within bfloat16's
+    rounding of the result."""
+    rotary, scale = (_laguna_rotary("causal") if turned else None), 128 ** -0.5
+    q, k, v = _qkv(N, rep * KV, KV, 128, seed=rep, dtype=jnp.bfloat16)
+    assert fa._masked_fold(rep, N, 128, q.dtype) == fold
+    masked = lambda q, k, v: fa.flash_attention_masked(
+        q, k, v, scale, window=window, rotary=rotary)
+    mapping = _pallas_call(masked, q, k, v).params["grid_mapping"]
+    heads, bq = fold
+    assert sorted(mapping.grid[1:3]) == sorted((rep * KV // heads,
+                                                -(-N // bq)))
+    got = masked(q, k, v)
+    a_head = masked(q, *(jnp.repeat(x, rep, axis=2) for x in (k, v)))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_array_equal(got, a_head)
+    f32 = lambda x: np.asarray(x, np.float32)
+    xla = fa.blockwise_attention_xla(
+        *(x.astype(jnp.float32) for x in (fa._turned_by_xla(q, rotary), k, v)),
+        scale, causal=True, window=window)
+    np.testing.assert_allclose(f32(got), f32(xla), rtol=3e-2, atol=3e-2)
+
+
+def test_the_fold_in_float32_padded_heads_and_several_rows():
+    """The toy trunk's shape: heads of 16 zero-padded to the lanes, float32,
+    3 rows, 7 query heads a K/V head, a scale that folds into q — against
+    dense attention to float32 rounding and the one-head program bit for
+    bit."""
+    q, k, v = _qkv(40, 14, 2, 16, B=3, seed=3)
+    assert fa._scale_folds_into_q(0.25)
+    got = fa.flash_attention_masked(q, k, v, 0.25, window=8)
+    np.testing.assert_array_equal(got, fa.flash_attention_masked(
+        q, *(jnp.repeat(x, 7, axis=2) for x in (k, v)), 0.25, window=8))
+    np.testing.assert_allclose(got, _dense(q, k, v, 0.25, True, 8),
+                               rtol=2e-5, atol=2e-6)
+
+
+def _grid_and_blocks(fn, *args):
+    """Grid and block shapes of the one ``pallas_call`` of ``fn(*args)``."""
+    mapping = _pallas_call(fn, *args).params["grid_mapping"]
+    return tuple(mapping.grid), [
+        tuple(getattr(d, "block_size", d) for d in b.block_shape)
+        for b in mapping.block_mappings]
+
+
+def test_a_group_is_one_program_and_a_selection_keeps_one_head():
+    """Four query heads on ONE K/V head: one program, its q, result and
+    scratch four heads wide, K and V one; turned, the q blocks outside the
+    groups and the turned blocks four wide too. The same shape under a
+    selection, and the latent launch, stay one head a program."""
+    f32 = jnp.dtype("float32")
+    q, k, v = _qkv(40, 4, 1, 128)
+    masked = lambda q, k, v: fa.flash_attention_masked(q, k, v, 0.1, window=16)
+    assert _launch(masked, q, k, v) == (
+        "fwd_masked", 3, [((40, 512), f32)] * 3)
+    assert _grid_and_blocks(masked, q, k, v) == (
+        (1, 1, 1, 1), [(1, 40, 512), (1, 40, 128), (1, 40, 128), (1, 40, 512)])
+    turned = lambda q, k, v: fa.flash_attention_masked(
+        q, k, v, 0.1, rotary=_laguna_rotary("window"))
+    assert _launch(turned, q, k, v) == (
+        "fwd_masked", 5, [((40, 512), f32)] * 4)
+    q8, k8, v8 = _qkv(600, 16, 2, 128)  # 8 heads a K/V head at 256 rows
+    grid, blocks = _grid_and_blocks(turned, q8, k8, v8)
+    assert grid == (1, 3, 2, 2)  # rows, q blocks, groups, chunks
+    assert blocks == [(1, 256, 1024), (1, 512, 128), (1, 512, 128),
+                      (256, 128), (256, 128), (1, 256, 1024)]
+    keep = _selection(1, 40, q.dtype)
+    selected = lambda q, k, v, m: fa.flash_attention_selected(q, k, v, 0.1, m)
+    assert _launch(selected, q, k, v, keep) == (
+        "fwd_selected", 4, [((40, 128), f32)] * 3)
+    assert _grid_and_blocks(selected, q, k, v, keep) == (
+        (1, 4, 1, 1), [(1, 40, 128)] * 3 + [(1, 40, 40), (1, 40, 128)])
+    qn, kn, vv = (jnp.ones((1, 40, 2, 128)) for _ in range(3))
+    qr, kr = jnp.ones((1, 40, 2, 64)), jnp.ones((1, 40, 64))
+    grid, blocks = _grid_and_blocks(
+        lambda *a: fa.flash_attention_latent(*a, 0.1), qn, qr, kn, kr, vv)
+    assert grid == (1, 2, 1, 1) and blocks == [(1, 40, 128)] * 6
+
+
+#: sha256 (16 hex digits) of ``str(jax.make_jaxpr(...))`` of the launches that
+#: hold one head a program, taken on be8ed9d, the parent of the PR that let
+#: ``fwd_masked`` fold a group: the kernel's body, grid, blocks and scratch,
+#: operation for operation, under ``tests/conftest.py``'s matmul precision. A
+#: change that MEANS to alter one of these programs pins its text anew; the
+#: fold must not.
+ONE_HEAD_PROGRAMS = {
+    "masked_rep1_window": "14d14bd5754449c8",
+    "masked_rep1_causal_turned": "385b7bfa2d9c52c9",
+    "selected_rep7": "c46b5ac6c12c107e",
+    "selected_rep7_turned": "8d86da8e0ea5da86",
+    "latent": "cc9134dac65e7eaa",
+    "masked_rep11_prime": "dcb338040cbbc123",
+}
+
+
+@pytest.mark.parametrize("launch", sorted(ONE_HEAD_PROGRAMS))
+def test_one_head_launches_lower_to_the_text_they_had(launch):
+    """``rep`` 1, a ``rep`` the budget cannot divide, any selection and the
+    latent launch: the jaxpr — the ``pallas_call`` with its body — is letter
+    for letter the one of the tree before a program could hold a group."""
+    ones = lambda *shape: jnp.ones(shape, jnp.bfloat16)
+    qkv = lambda N, H, KV: (ones(1, N, H, 128), ones(1, N, KV, 128),
+                            ones(1, N, KV, 128))
+    rotary = Rotary(tuple(10000.0 ** (-i / 64) for i in range(64)), 1.0,
+                    "rotate_half", 0)
+    keep = jnp.ones((1, 1024, 1024), jnp.int8)
+    fn, args = {
+        "masked_rep1_window": (lambda q, k, v: fa.flash_attention_masked(
+            q, k, v, 0.1, window=200), qkv(600, 4, 4)),
+        "masked_rep1_causal_turned": (lambda q, k, v: fa.flash_attention_masked(
+            q, k, v, 128 ** -0.5, rotary=rotary), qkv(600, 4, 4)),
+        "selected_rep7": (lambda q, k, v, m: fa.flash_attention_selected(
+            q, k, v, 0.1, m), qkv(600, 14, 2) + (keep,)),
+        "selected_rep7_turned": (lambda q, k, v, m: fa.flash_attention_selected(
+            q, k, v, 0.1, m, rotary), qkv(600, 14, 2) + (keep,)),
+        "latent": (lambda *a: fa.flash_attention_latent(*a, 0.1),
+                   (ones(1, 600, 2, 128), ones(1, 600, 2, 64),
+                    ones(1, 600, 2, 128), ones(1, 600, 64),
+                    ones(1, 600, 2, 128))),
+        "masked_rep11_prime": (lambda q, k, v: fa.flash_attention_masked(
+            q, k, v, 0.1), qkv(600, 11, 1)),
+    }[launch]
+    text = str(jax.make_jaxpr(fn)(*args))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        ONE_HEAD_PROGRAMS[launch]
+
+
+def test_the_fold_rule_gives_the_published_shapes_their_pairs():
+    """:func:`_masked_fold` at the three configurations that launch
+    ``fwd_masked`` (heads of 128, bfloat16): SmallThinker 28 / 4 at 16,130
+    tokens, Laguna 48 | 72 / 8 at 4,097, Nemotron 32 / 2 at 16,385; and over
+    every ``rep`` to 64, both lane counts, short and long sequences, the
+    heads divide ``rep``, are the most the row budget admits at the q block
+    chosen, and more than one head never holds more than the budget."""
+    fold = lambda rep, tokens: fa._masked_fold(rep, tokens, 128, jnp.bfloat16)
+    assert fold(28 // 4, 16130) == (7, 256)
+    assert fold(48 // 8, 4097) == (6, 256)
+    assert fold(72 // 8, 4097) == (9, 256)
+    assert fold(32 // 2, 16385) == (8, 256)
+    assert fa._FOLD_ROWS == 2304
+    for lanes in (128, 256):
+        rows = fa._FOLD_ROWS * 128 // lanes
+        for dtype in (jnp.bfloat16, jnp.float32):
+            for tokens in (16385, 4097, 600, 200, 37):
+                full = fa._masked_blocks(tokens, dtype)[0]
+                for rep in range(1, 65):
+                    heads, bq = fa._masked_fold(rep, tokens, lanes, dtype)
+                    assert rep % heads == 0
+                    if heads == 1:
+                        assert bq == full
+                        continue
+                    assert bq == min(256, full) and heads * bq <= rows
+                    assert not any(rep % f == 0 and f * bq <= rows
+                                   for f in range(heads + 1, rep + 1))
+
+
+def test_the_fold_counter_says_how_many_heads_a_program_held():
+    """``kernels.flash_fwd_fold``, +1 a trace of ``fwd_masked`` or
+    ``fwd_selected``, key = the heads ONE program folds a chunk into: the
+    rule's choice for the shape, 1 under a selection; nothing for the
+    launches that have no K/V head to share (``fwd``, ``fwd_latent``) or off
+    the TPU's path; and its row in the table of ``obs/metrics.py``."""
+    assert "kernels.flash_fwd_fold" in {name for name, *_ in metrics.METRICS}
+    count = lambda: fa._kernels.by_key("kernels.flash_fwd_fold")
+    metrics.reset()
+    for rep, tokens in ((1, 40), (7, 40), (7, 600), (16, 600), (6, 40)):
+        q, k, v = _qkv(tokens, rep, 1, 128, dtype=jnp.bfloat16)
+        jax.make_jaxpr(lambda q, k, v: fa.flash_attention_masked(
+            q, k, v, 0.1, window=16))(q, k, v)
+    assert count() == {"1": 1, "7": 2, "8": 1, "6": 1}
+    q, k, v = _qkv(40, 4, 2, 256)
+    fa.flash_attention_selected(q, k, v, 0.1, _selection(1, 40, q.dtype))
+    assert count()["1"] == 2
+    before = count()
+    fa.flash_attention(q, q, q, 0.1)
+    fa.masked_attention(q, k, v, 0.1)  # off the TPU: blockwise XLA
+    qn = jnp.ones((1, 40, 2, 128))
+    fa.flash_attention_latent(qn, jnp.ones((1, 40, 2, 64)), qn,
+                              jnp.ones((1, 40, 64)), qn, 0.1)
+    assert count() == before
+    metrics.reset()
+
+
+def test_layers_that_launch_the_same_shapes_trace_the_body_once(monkeypatch):
+    """The launch is an inline ``jit``: three layers at one shape and one at
+    another, in one program, trace ``_fwd_masked_kernel`` twice — a folded
+    body is ``heads`` chains of Python to trace, and a warm set-up pays for
+    every trace — while the program holds all four launches, each under its
+    own caller's scope."""
+    traced = []
+    body = fa._fwd_masked_kernel
+
+    def counting(*refs, **geometry):
+        traced.append(geometry["window"])
+        return body(*refs, **geometry)
+
+    monkeypatch.setattr(fa, "_fwd_masked_kernel", counting)
+    q, k, v = _qkv(48, 14, 2, 128, seed=9, dtype=jnp.bfloat16)
+    scale = 0.0884  # no other test's static arguments: a trace of its own
+
+    def stack(q, k, v):
+        for layer, window in enumerate((16, 16, None, 16)):
+            with jax.named_scope(f"layer{layer}"):
+                q = fa.flash_attention_masked(q, k, v, scale, window=window)
+        return q
+
+    jaxpr = jax.make_jaxpr(stack)(q, k, v)
+    assert traced == [16, None]
+    launches = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [str(e.source_info.name_stack).split("/")[0] for e in launches] == [
+        "layer0", "layer1", "layer2", "layer3"]
+    want = q
+    for window in (16, 16, None, 16):
+        want = fa.flash_attention_masked(
+            want, *(jnp.repeat(x, 7, axis=2) for x in (k, v)), scale,
+            window=window)
+    np.testing.assert_array_equal(jax.jit(stack)(q, k, v), want)
