@@ -1,0 +1,10 @@
+"""Share of the device's busy time in the traced window under ``trunk/moe``
+less ``trunk/route``: ``moe_gmm``, the gather, combine and shared expert
+around it. Layer: samplers. Source: device trace joined with the program's
+scope map (``scope_record``)."""
+
+from benchmark.layer_metrics import scope_record
+
+
+def read(view):
+    return scope_record.share(view, "experts")

@@ -348,11 +348,11 @@ class GlmLayer(nn.Module):
             out, keep = LatentAttention(c, kind == "full", name="self_attn",
                                         **kw)(norm("input_layernorm")(x), keep)
             x = x + out
-        y = norm("post_attention_layernorm")(x)
-        if c["mlp_layer_types"][layer] == "dense":
-            with jax.named_scope("trunk/mlp"):
+        dense = c["mlp_layer_types"][layer] == "dense"
+        with jax.named_scope("trunk/mlp" if dense else "trunk/moe"):
+            y = norm("post_attention_layernorm")(x)
+            if dense:
                 return x + GatedMlp(c, name="mlp", **kw)(y), keep
-        with jax.named_scope("trunk/moe"):
             return x + HeldExpertsMlp(
                 num_routed=c.get("n_experts_routed", c["n_routed_experts"]),
                 top_k=c["num_experts_per_tok"],

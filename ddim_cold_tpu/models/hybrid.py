@@ -307,13 +307,16 @@ class HybridLayer(nn.Module):
                   param_dtype=self.param_dtype)
         norm = lambda name: RMSNorm(self.trunk["rms_norm_eps"], self.dtype,
                                     self.param_dtype, name=name)
-        y = norm("input_layernorm")(x)
+        # the sub-layer's own norm inside its scope: obs.scopes puts every
+        # instruction of a layer in it
         if self.attention:
             with jax.named_scope("trunk/attn"):
-                x = x + CausalAttention(**kw, name="self_attn")(y)
+                x = x + CausalAttention(**kw, name="self_attn")(
+                    norm("input_layernorm")(x))
         else:
             with jax.named_scope("trunk/mamba"):
-                x = x + MambaMixer(**kw, name="mamba")(y)
+                x = x + MambaMixer(**kw, name="mamba")(
+                    norm("input_layernorm")(x))
         with jax.named_scope("trunk/mlp"):
             return x + GatedMlp(**kw, name="feed_forward")(
                 norm("pre_ff_layernorm")(x))

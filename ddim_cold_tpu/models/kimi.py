@@ -243,11 +243,11 @@ class KimiLayer(nn.Module):
         with jax.named_scope(f"trunk/{kind}"):
             x = x + mixer(c, name="self_attn", **kw)(
                 norm("input_layernorm")(x))
-        y = norm("post_attention_layernorm")(x)
-        if published_index(c, self.index) < c["first_k_dense_replace"]:
-            with jax.named_scope("trunk/mlp"):
+        dense = published_index(c, self.index) < c["first_k_dense_replace"]
+        with jax.named_scope("trunk/mlp" if dense else "trunk/moe"):
+            y = norm("post_attention_layernorm")(x)
+            if dense:
                 return x + GatedMlp(c, name="mlp", **kw)(y)
-        with jax.named_scope("trunk/moe"):
             return x + HeldExpertsMlp(
                 num_routed=c.get("num_experts_routed", c["num_experts"]),
                 top_k=c["num_experts_per_token"],

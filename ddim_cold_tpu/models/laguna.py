@@ -196,11 +196,11 @@ class LagunaLayer(nn.Module):
             x = x + GatedAttention(
                 c, layer_type, c["num_attention_heads_per_layer"][i],
                 name="self_attn", **kw)(norm("input_layernorm")(x))
-        y = norm("post_attention_layernorm")(x)
-        if c["mlp_layer_types"][i] == "dense":
-            with jax.named_scope("trunk/mlp"):
+        dense = c["mlp_layer_types"][i] == "dense"
+        with jax.named_scope("trunk/mlp" if dense else "trunk/moe"):
+            y = norm("post_attention_layernorm")(x)
+            if dense:
                 return x + GatedMlp(c, name="mlp", **kw)(y)
-        with jax.named_scope("trunk/moe"):
             return x + HeldExpertsMlp(
                 num_routed=c.get("num_experts_routed", c["num_experts"]),
                 top_k=c["num_experts_per_tok"],

@@ -41,7 +41,6 @@ Two dispatch implementations, selectable per config (``moe_dispatch``):
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any
 
 import jax
@@ -292,11 +291,10 @@ class HeldExpertsMlp(nn.Module):
             name, trunc_normal(std=0.02), shape, self.param_dtype
         ).astype(self.dtype)
         # the routing — logits, the top k, the sort and the group bounds —
-        # depends on what the router reads alone: handed another tensor than
-        # the experts' it is traced under a scope of its own, and XLA may run
-        # it as early as that tensor exists
-        with (jax.named_scope("trunk/route") if route_from is not None
-              else contextlib.nullcontext()):
+        # depends on what the router reads alone and is traced under a scope
+        # of its own (``obs.scopes``' ``route`` layer): handed another tensor
+        # than the experts', XLA may run it as early as that tensor exists
+        with jax.named_scope("trunk/route"):
             logits = jnp.dot(
                 routed_on,
                 param("router", (routed_on.shape[-1], self.num_routed)),

@@ -212,8 +212,8 @@ class NemotronLayer(nn.Module):
     def __call__(self, x):
         c, kind = self.trunk, layer_kind(self.trunk, self.index)
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        y = RMSNorm(c["layer_norm_epsilon"], name="norm", **kw)(x)
         with jax.named_scope(KINDS[kind]):
+            y = RMSNorm(c["layer_norm_epsilon"], name="norm", **kw)(x)
             if kind == "M":
                 return x + Mamba2Mixer(c, name="mixer", **kw)(y)
             if kind == "*":

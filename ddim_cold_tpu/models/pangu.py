@@ -154,12 +154,12 @@ class PanguLayer(nn.Module):
             out = DenseLatentAttention(c, name="self_attn", **kw)(
                 norm("input_layernorm")(x))
             x = x + norm("post_attention_layernorm")(out)
-        y = norm("pre_mlp_layernorm")(x)
-        if layer < c["first_k_dense_replace"]:
-            with jax.named_scope("trunk/mlp"):
+        dense = layer < c["first_k_dense_replace"]
+        with jax.named_scope("trunk/mlp" if dense else "trunk/moe"):
+            y = norm("pre_mlp_layernorm")(x)
+            if dense:
                 out = GatedMlp(c, name="mlp", **kw)(y)
                 return x + norm("post_mlp_layernorm")(out)
-        with jax.named_scope("trunk/moe"):
             out = HeldExpertsMlp(
                 num_routed=c.get("n_experts_routed", c["n_routed_experts"]),
                 top_k=c["num_experts_per_tok"],

@@ -539,18 +539,23 @@ class Block(nn.Module):
                 self.dim, self.qkv_bias, name="attn")()
             (w_fc1, b_fc1), (w_fc2, b_fc2) = _MlpParams(
                 hidden, self.dim, name="mlp")()
-            packed = block_kernels.ln_qkv(
-                x, *_NormParams(name="norm1")(self.dim), w_qkv, b_qkv,
-                1e-5, rows)
-            ctx = _attend_packed(
-                packed, self.num_heads,
-                self.qk_scale or (self.dim // self.num_heads) ** -0.5,
-                self.use_flash, self.flash_blocks, self.dtype)
-            return block_kernels.block_tail(
-                ctx, x, w_proj, b_proj, *_NormParams(name="norm2")(self.dim),
-                w_fc1, b_fc1, w_fc2, b_fc2, 1e-5, rows)
+            with jax.named_scope("trunk/attn"):
+                packed = block_kernels.ln_qkv(
+                    x, *_NormParams(name="norm1")(self.dim), w_qkv, b_qkv,
+                    1e-5, rows)
+                ctx = _attend_packed(
+                    packed, self.num_heads,
+                    self.qk_scale or (self.dim // self.num_heads) ** -0.5,
+                    self.use_flash, self.flash_blocks, self.dtype)
+            # block_tail also holds attention's output projection and its
+            # residual: one launch, under the layer most of it belongs to
+            with jax.named_scope("trunk/mlp"):
+                return block_kernels.block_tail(
+                    ctx, x, w_proj, b_proj,
+                    *_NormParams(name="norm2")(self.dim),
+                    w_fc1, b_fc1, w_fc2, b_fc2, 1e-5, rows)
         ln = lambda name: nn.LayerNorm(epsilon=1e-5, dtype=self.dtype, name=name)
-        y, attn = Attention(
+        attention = Attention(
             dim=self.dim,
             num_heads=self.num_heads,
             qkv_bias=self.qkv_bias,
@@ -571,8 +576,10 @@ class Block(nn.Module):
             quant=self.quant,
             fused=self.fused,
             name="attn",
-        )(ln("norm1")(x), deterministic=deterministic,
-          need_weights=return_attention)
+        )
+        with jax.named_scope("trunk/attn"):
+            y, attn = attention(ln("norm1")(x), deterministic=deterministic,
+                                need_weights=return_attention)
         if return_attention:
             return attn
 
@@ -593,7 +600,8 @@ class Block(nn.Module):
                     self.make_rng("dropout"), keep, (y.shape[0], 1, 1))
                 return jnp.where(mask, y / keep, jnp.zeros_like(y)).astype(y.dtype)
 
-        x = x + residual(y)
+        with jax.named_scope("trunk/attn"):
+            x = x + residual(y)
         if self.num_experts > 1:
             from ddim_cold_tpu.models.moe import SwitchMlp
 
@@ -617,8 +625,10 @@ class Block(nn.Module):
                 fused=self.fused,
                 name="mlp",
             )
-        y = mlp(ln("norm2")(x), deterministic=deterministic)
-        x = x + residual(y)
+        with jax.named_scope("trunk/moe" if self.num_experts > 1
+                             else "trunk/mlp"):
+            y = mlp(ln("norm2")(x), deterministic=deterministic)
+            x = x + residual(y)
         return x
 
 

@@ -13,6 +13,12 @@ registry, and on-device step telemetry decoding.
 * :mod:`ddim_cold_tpu.obs.device` — static-shaped sampler-scan aux
   (adaptive-gate decisions, drift) decoded into per-ticket summaries.
 
+* :mod:`ddim_cold_tpu.obs.scopes` — which layer every compiled instruction
+  belongs to: ``note`` at the dispatch sites keeps a program's shapes,
+  ``scope_map()`` builds the map from the compiled module on demand. It
+  imports jax and is not imported here: ``from ddim_cold_tpu.obs import
+  scopes`` where a program is dispatched or a trace is reduced.
+
 ``spans`` and ``metrics`` are host-only (jax-free, graftcheck A004);
 ``device`` imports jax lazily, so ``import ddim_cold_tpu.obs`` is cheap
 anywhere the router/fleet layer runs.

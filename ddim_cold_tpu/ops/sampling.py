@@ -31,7 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ddim_cold_tpu.obs import spans
+from ddim_cold_tpu.obs import scopes, spans
 from ddim_cold_tpu.obs.device import StepTelemetry
 from ddim_cold_tpu.ops import schedule, step_cache
 from ddim_cold_tpu.utils import profiling
@@ -60,6 +60,14 @@ def _host_call(sampler: str, scan_steps: int, **attrs):
     returns; the device runs on after it)."""
     return spans.layer("sampler/call", sampler=sampler,
                        scan_steps=scan_steps, **attrs)
+
+
+def _dispatch(scan, *args, **kwargs):
+    """The jitted scan's call inside ``sampler/dispatch``, with the program
+    noted for ``obs.scopes.scope_map`` (shapes and statics, no buffer; a
+    flatten of the arguments and a lookup once the program is known)."""
+    scopes.note("sampler/" + scan.__name__, scan, args, kwargs)
+    return scan(*args, **kwargs)
 
 
 def _start(call, model, rng, x_init, mesh, draw, *, copy: bool,
@@ -326,8 +334,8 @@ def ddim_sample_fewstep(
             if cached:
                 fn = (_ddim_scan_fewstep_cached_seq if return_sequence
                       else _ddim_scan_fewstep_cached)
-                out, _ = fn(
-                    model, params, x_init, noise_rng, cache,
+                out, _ = _dispatch(
+                    fn, model, params, x_init, noise_rng, cache,
                     steps=steps, t_start=t_start, eta=eta,
                     cache_interval=cache_interval, cache_mode=cache_mode,
                     cache_threshold=cache_threshold,
@@ -335,8 +343,9 @@ def ddim_sample_fewstep(
                 return out
             fn = (_ddim_scan_fewstep_seq if return_sequence
                   else _ddim_scan_fewstep)
-            return fn(model, params, x_init, noise_rng, steps=steps,
-                      t_start=t_start, eta=eta, sequence=return_sequence)
+            return _dispatch(fn, model, params, x_init, noise_rng,
+                             steps=steps, t_start=t_start, eta=eta,
+                             sequence=return_sequence)
 
 
 def _cached_spec(model, n_steps: int, cache_interval: int, cache_mode: str,
@@ -681,7 +690,8 @@ def ddim_sample(
             cache_mode=cache_mode if cached else None)
         with spans.layer("sampler/dispatch"):
             if telemetry:
-                out, _, (br, drift) = _ddim_scan_cached_tel(
+                out, _, (br, drift) = _dispatch(
+                    _ddim_scan_cached_tel,
                     model, params, x_init, noise_rng, cache,
                     k=k, t_start=t_start, eta=eta,
                     cache_interval=cache_interval, cache_mode=cache_mode,
@@ -691,18 +701,16 @@ def ddim_sample(
             if cached:
                 fn = (_ddim_scan_cached_seq if return_sequence
                       else _ddim_scan_cached)
-                out, _ = fn(
-                    model, params, x_init, noise_rng, cache,
+                out, _ = _dispatch(
+                    fn, model, params, x_init, noise_rng, cache,
                     k=k, t_start=t_start, eta=eta,
                     cache_interval=cache_interval, cache_mode=cache_mode,
                     cache_threshold=cache_threshold,
                     cache_tokens=cache_tokens, sequence=return_sequence)
                 return out
-            if return_sequence:
-                return _ddim_scan_sequence(model, params, x_init, noise_rng,
-                                           k=k, t_start=t_start, eta=eta)
-            return _ddim_scan_last(model, params, x_init, noise_rng,
-                                   k=k, t_start=t_start, eta=eta)
+            fn = _ddim_scan_sequence if return_sequence else _ddim_scan_last
+            return _dispatch(fn, model, params, x_init, noise_rng,
+                             k=k, t_start=t_start, eta=eta)
 
 
 def sample_from(model, params, x_init: jax.Array, t_start: int, k: int = 10,
@@ -921,13 +929,13 @@ def cold_sample(
             if cached:
                 fn = (_cold_scan_cached_seq if return_sequence
                       else _cold_scan_cached)
-                out, _ = fn(
-                    model, params, x_init, cache,
+                out, _ = _dispatch(
+                    fn, model, params, x_init, cache,
                     levels=levels, return_sequence=return_sequence,
                     cache_interval=cache_interval, cache_mode=cache_mode,
                     cache_threshold=cache_threshold,
                     cache_tokens=cache_tokens)
                 return out
             fn = _cold_scan_seq if return_sequence else _cold_scan
-            return fn(model, params, x_init, levels=levels,
-                      return_sequence=return_sequence)
+            return _dispatch(fn, model, params, x_init, levels=levels,
+                             return_sequence=return_sequence)

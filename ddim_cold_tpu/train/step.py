@@ -24,8 +24,9 @@ import jax.numpy as jnp
 import optax
 from flax.training import train_state
 
+from ddim_cold_tpu.obs import scopes
 from ddim_cold_tpu.ops.losses import smooth_l1
-from ddim_cold_tpu.utils import profiling  # noqa: F401 — its compile listener, before the first compile
+from ddim_cold_tpu.utils import profiling  # also: its compile listener, before the first compile
 
 
 class EmaTrainState(train_state.TrainState):
@@ -201,21 +202,27 @@ def make_train_step(model, apply_fn: Optional[Callable] = None,
                  jnp.arange(grad_accum)))
             grads = jax.tree.map(lambda g: g / grad_accum, gsum)
             loss = lsum / grad_accum
-        new_state = state.apply_gradients(grads=grads)
-        if ema_decay:
-            if state.ema_params is None:  # trace-time: silently training
-                raise ValueError(  # with no shadow would surface only when
-                    # bestloss_ema is missing at the end of the run
-                    "ema_decay > 0 but the state carries no ema_params — "
-                    "create it with create_train_state(..., ema_decay=...) "
-                    "or seed state.replace(ema_params=...)")
-            new_state = new_state.replace(ema_params=optax.incremental_update(
-                new_state.params, state.ema_params,
-                step_size=1.0 - ema_decay))
+        # clip, AdamW, the parameters' update and the EMA shadow's: the
+        # ``optimizer`` layer of obs.scopes
+        with profiling.scope("train/optimizer"):
+            new_state = state.apply_gradients(grads=grads)
+            if ema_decay:
+                if state.ema_params is None:  # trace-time: silently training
+                    raise ValueError(  # with no shadow would surface only
+                        # when bestloss_ema is missing at the end of the run
+                        "ema_decay > 0 but the state carries no ema_params — "
+                        "create it with create_train_state(..., "
+                        "ema_decay=...) or seed "
+                        "state.replace(ema_params=...)")
+                new_state = new_state.replace(
+                    ema_params=optax.incremental_update(
+                        new_state.params, state.ema_params,
+                        step_size=1.0 - ema_decay))
         return new_state, loss, loss_rec * 0.99 + loss * 0.01
 
     if steps_per_dispatch == 1:
-        return partial(jax.jit, donate_argnums=(0, 3))(step_body)
+        return scopes.noted("train/step", partial(
+            jax.jit, donate_argnums=(0, 3))(step_body))
 
     @partial(jax.jit, donate_argnums=(0, 3))
     def multi_step(state: EmaTrainState, stacked_batch, rng: jax.Array,
@@ -230,7 +237,7 @@ def make_train_step(model, apply_fn: Optional[Callable] = None,
             length=steps_per_dispatch)
         return state, losses.mean(), loss_rec
 
-    return multi_step
+    return scopes.noted("train/multi_step", multi_step)
 
 
 def make_eval_step(model, apply_fn: Optional[Callable] = None,

@@ -22,6 +22,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import traceback
 from dataclasses import dataclass
 from typing import Optional
 
@@ -34,6 +35,7 @@ from ddim_cold_tpu.data import ColdDownSampleDataset, DiffusionDataset, ShardedL
 from ddim_cold_tpu.data.loader import device_prefetch, group_batches
 from ddim_cold_tpu.ops import degrade
 from ddim_cold_tpu.models import DiffusionViT
+from ddim_cold_tpu.obs import scopes
 from ddim_cold_tpu.parallel import ambient, make_mesh, shard_batch, shard_train_state
 from ddim_cold_tpu.parallel.layout import layout_for_mesh
 from ddim_cold_tpu.train.step import create_train_state, make_eval_step, make_train_step
@@ -549,6 +551,14 @@ def _train(config: ExperimentConfig, mesh, saved_dir: str, run_dir: str,
                 if profiling_until and steps >= profiling_until and jax.process_index() == 0:
                     float(loss_rec_dev)  # drain the device before the trace stops
                     profiling.stop_trace()
+                    # beside the trace: which layer each of its instructions
+                    # belongs to (built now, once, from the step's own
+                    # caches). The run does not depend on it.
+                    try:
+                        scopes.write(os.path.join(run_dir, "scopes.json"))
+                    except Exception:  # noqa: BLE001 — reported, training goes on
+                        print_log("scopes.json was not written:\n"
+                                  + traceback.format_exc(), log)
                     profiling_until = 0
                 if crossed and jax.process_index() == 0:
                     loss_rec = float(loss_rec_dev)  # the only per-step host sync

@@ -4,8 +4,10 @@ The reference has no profiler — only wall-clock prints (per-100-step
 ``time_cost`` and per-sampler-step elapsed, multi_gpu_trainer.py:135-138,
 ViT.py:222-235). Here the equivalents are structural:
 
-* ``trace(dir)`` — a ``jax.profiler`` trace context; view in TensorBoard or
-  Perfetto. Wrap any train/sample region.
+* ``start_trace(dir)`` / ``stop_trace()`` — a step-bounded ``jax.profiler``
+  session (the trainer's ``profile_steps``; view in TensorBoard or Perfetto).
+  ``obs/scopes.py``'s ``write`` puts ``scopes.json`` beside it: which layer
+  each instruction of the timeline belongs to.
 * the mirror — while a profiler session is live, every layer span
   ``obs/spans.py`` opens is also written into it as a ``ddim/<name>`` TraceAnnotation, on the
   host plane of the same ``.xplane.pb`` as the device's ops.
@@ -42,13 +44,6 @@ import jax
 import numpy as np
 
 from ddim_cold_tpu.obs import metrics, spans
-
-
-def trace(log_dir: str):
-    """Capture a device trace (``.xplane.pb``) into ``log_dir`` —
-    ``jax.profiler.trace`` is already a context manager with stop-in-finally
-    semantics; pass through."""
-    return jax.profiler.trace(log_dir)
 
 
 def start_trace(log_dir: str) -> None:
@@ -132,19 +127,6 @@ def scope(name: str):
     Metadata-only: the printed jaxpr and its J006 signature hash are
     untouched, and numerics are bit-identical with or without it."""
     return jax.named_scope(name)
-
-
-def span_trace(log_dir: str, span=None):
-    """A ``jax.profiler`` trace session keyed to an obs span: the capture
-    lands in ``log_dir/trace_<trace_id>_<span_id>`` (or ``log_dir`` when no
-    span / tracing disabled), so a slow request's profiler timeline is
-    findable from its span ids."""
-    import os
-
-    ctx = getattr(span, "ctx", None)
-    if ctx is not None:
-        log_dir = os.path.join(log_dir, f"trace_{ctx.trace_id}_{ctx.span_id}")
-    return trace(log_dir)
 
 
 def enable_nan_checks(enable: bool = True) -> None:

@@ -445,7 +445,7 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 
 def test_the_named_scopes_and_counters_of_a_trace():
-    """``trunk/mla | dsa_index | moe | mlp`` in the lowered text; one count a
+    """``trunk/mla | dsa_index | moe | route | mlp`` in the lowered text; one count a
     traced layer by indexer kind and by where q's rotation runs, one a
     selection and three an expert layer by path."""
     model, params = model_and_params("float32")
@@ -453,7 +453,8 @@ def test_the_named_scopes_and_counters_of_a_trace():
     metrics.reset()
     text = jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(
         params).as_text(debug_info=True)
-    for scope in ("trunk/mla", "trunk/dsa_index", "trunk/moe", "trunk/mlp"):
+    for scope in ("trunk/mla", "trunk/dsa_index", "trunk/moe", "trunk/mlp",
+                  "trunk/route"):
         assert scope in text, scope
     by_key = {}
     for series in metrics.snapshot().values():

@@ -290,7 +290,7 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 
 def test_the_named_scopes_and_counters_of_a_trace():
-    """``trunk/kda | mla | mlp | moe`` in the lowered text; one count a traced
+    """``trunk/kda | mla | mlp | moe | route`` in the lowered text; one count a traced
     scan and a traced latent attention, three convolutions a delta layer,
     three products an expert layer."""
     model, params = model_and_params("float32")
@@ -298,7 +298,8 @@ def test_the_named_scopes_and_counters_of_a_trace():
     metrics.reset()
     text = jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(
         params).as_text(debug_info=True)
-    for scope in ("trunk/kda", "trunk/mla", "trunk/mlp", "trunk/moe"):
+    for scope in ("trunk/kda", "trunk/mla", "trunk/mlp", "trunk/moe",
+                  "trunk/route"):
         assert scope in text, scope
     by_key = {}
     for series in metrics.snapshot().values():

@@ -619,7 +619,7 @@ def test_x004_every_spelling_is_refused_whatever_the_stack():
 
 
 def test_the_named_scopes_and_counters_of_a_trace():
-    """``trunk/attn_full | attn_window | moe | mlp`` in the lowered text, and
+    """``trunk/attn_full | attn_window | moe | route | mlp`` in the lowered text, and
     one count a trace on each of the two kernels' counters and on the one
     that says where q was turned."""
     from ddim_cold_tpu.obs import metrics
@@ -631,7 +631,7 @@ def test_the_named_scopes_and_counters_of_a_trace():
     text = jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(
         params).as_text(debug_info=True)
     for scope in ("trunk/attn_full", "trunk/attn_window", "trunk/moe",
-                  "trunk/mlp"):
+                  "trunk/mlp", "trunk/route"):
         assert scope in text, scope
     by_key, first_half = {}, {}
     for series in metrics.snapshot().values():

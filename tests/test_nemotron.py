@@ -274,7 +274,7 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 
 def test_the_named_scopes_and_counters_of_a_trace():
-    """``trunk/mamba2 | attn | moe`` in the lowered text; one count a traced
+    """``trunk/mamba2 | attn | moe | route`` in the lowered text; one count a traced
     scan, two products an expert layer (up under its squared ReLU, down) and
     no gated first half."""
     model, params = model_and_params("float32")
@@ -282,7 +282,7 @@ def test_the_named_scopes_and_counters_of_a_trace():
     metrics.reset()
     text = jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(
         params).as_text(debug_info=True)
-    for scope in ("trunk/mamba2", "trunk/attn", "trunk/moe"):
+    for scope in ("trunk/mamba2", "trunk/attn", "trunk/moe", "trunk/route"):
         assert scope in text, scope
     by_key = {}
     for series in metrics.snapshot().values():

@@ -441,15 +441,3 @@ def test_health_last_stage_and_timeout_message(model_and_params):
     with pytest.raises(TimeoutError, match="last seen at stage"):
         t2.result(timeout=0.01)
     eng.drain(timeout=5)
-
-
-def test_span_trace_dir_is_span_keyed(tmp_path):
-    with spans.tracing():
-        sp = spans.begin("bench.obs")
-        ctx = profiling.span_trace(str(tmp_path), sp)
-        with ctx:
-            jnp.zeros((2, 2)).block_until_ready()
-        sub = tmp_path / f"trace_{sp.ctx.trace_id}_{sp.ctx.span_id}"
-        assert sub.exists()
-        sp.end()
-    spans.clear()

@@ -245,14 +245,14 @@ def test_the_stack_is_chosen_by_model_type_and_refuses_blocks_options():
 
 
 def test_the_named_scopes_and_counters_of_a_trace():
-    """``trunk/mla | moe | mlp`` in the lowered text; one count a traced
+    """``trunk/mla | moe | route | mlp`` in the lowered text; one count a traced
     attention by path and by mask, three products an expert layer."""
     model, params = model_and_params("float32")
     x, t = inputs()
     metrics.reset()
     text = jax.jit(lambda p: model.apply({"params": p}, x, t)).lower(
         params).as_text(debug_info=True)
-    for scope in ("trunk/mla", "trunk/moe", "trunk/mlp"):
+    for scope in ("trunk/mla", "trunk/moe", "trunk/mlp", "trunk/route"):
         assert scope in text, scope
     by_key = {}
     for series in metrics.snapshot().values():

@@ -1,6 +1,7 @@
 """Training-layer tests: config derivation rules, end-to-end CPU training,
 checkpoint/resume, converter round-trips (SURVEY.md §4 integration plan)."""
 
+import json
 import os
 
 import numpy as np
@@ -617,6 +618,12 @@ def test_profile_steps_writes_trace(tmp_path, synthetic_image_dir):
     trace_dir = os.path.join(result.run_dir, "trace")
     assert os.path.isdir(trace_dir)
     assert any(f for _, _, fs in os.walk(trace_dir) for f in fs), "empty trace"
+    # beside it, what reduces that timeline by layer: the step's scope map
+    with open(os.path.join(result.run_dir, "scopes.json")) as f:
+        doc = json.load(f)
+    assert [p["name"] for p in doc["programs"]] == ["train/step"]
+    assert {"attention", "mlp", "optimizer", "outside"} <= {
+        e["layer"] for e in doc["map"].values()}
 
 
 @pytest.mark.isolated
