@@ -118,6 +118,10 @@ METRICS = (
      "masked- and selected-forward traces by the query heads of a K/V head "
      "ONE program folds a fetched chunk into (key: 1, or a divisor of the "
      "query heads a K/V head)"),
+    ("kernels.flash_fwd_tail", "counter",
+     "masked-, selected- and latent-forward traces by the rows the LAST q "
+     "block's folds run on (key: <rows>/<q block> where the program holds "
+     "the short folds; whole where that block's folds run on every row)"),
     ("kernels.flash_fwd_rotary", "counter",
      "selected- and masked-forward traces handed an unturned q, by where "
      "its rotation "

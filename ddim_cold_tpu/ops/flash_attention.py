@@ -1422,10 +1422,47 @@ class _Ints:
     minimum, maximum = staticmethod(min), staticmethod(max)
 
 
+def _whole_chunk(i, c, *, bq: int, bkv: int, n_valid: int, causal: bool,
+                 window: int | None):
+    """Whether every token of query block ``i`` sees the whole of chunk ``c``
+    (it then takes the unmasked fold); traced scalars or Python ints."""
+    whole = (c + 1) * bkv <= n_valid
+    if causal:
+        whole &= (c + 1) * bkv - 1 <= i * bq
+    if window is not None:
+        whole &= c * bkv > i * bq + bq - 1 - window
+    return whole
+
+
+#: the rows of the last q block's short folds come in whole groups of this
+#: many: the sublanes one packed int8 tile of a selection holds (16 would do
+#: for bfloat16 operands alone)
+_TAIL_ROWS = 32
+
+
+def _tail_rows(n_valid: int, bq: int) -> int | None:
+    """Rows the LAST q block's folds run on, or None where they run on all
+    ``bq``: the rows that block holds — ``n_valid`` less the blocks before it
+    — in whole groups of :data:`_TAIL_ROWS`, where that is at most half the
+    block. Every sequence the samplers make is ``k² + 1`` tokens, so the last
+    block of a launch at their shapes holds ONE row (32 of 1,024 rows); a
+    sequence that ends on a block boundary, or whose last block is more than
+    half full, has no short folds and lowers to the program without."""
+    held = n_valid - (pl.cdiv(n_valid, bq) - 1) * bq
+    rows = tiling.round_up(held, _TAIL_ROWS)
+    return rows if 2 * rows <= bq else None
+
+
+def _tail_key(tail: int | None, bq: int) -> str:
+    """The key of ``kernels.flash_fwd_tail``."""
+    return "whole" if tail is None else f"{tail}/{bq}"
+
+
 def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
                        n_kv: int, causal: bool, window: int | None,
                        selected: bool = False, parts: int = 1,
-                       turn: tuple | None = None, heads: int = 1):
+                       turn: tuple | None = None, heads: int = 1,
+                       tail: int | None = None):
     """One (image, group of ``heads`` query heads, q block, visited chunk)
     program of the masked forward. ``heads`` 1 — every launch but
     ``fwd_masked`` on shared K/V heads — is one head on the block's lanes.
@@ -1441,6 +1478,22 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     no dependence between them: the scheduler lays one head's ``exp`` under
     another's MXU passes, which a program a head cannot (its one chain waits
     for the vector unit and back).
+
+    ``tail`` (:func:`_tail_rows`; any ``heads``, ``parts``, ``turn``,
+    ``selected``): the LAST q block holds at most that many rows of the
+    sequence — one, at every shape the samplers launch — and its folds run on
+    the first ``tail`` rows alone: a second pair of folds (unmasked, masked:
+    the same chains, whatever the heads) that reads q, the turned q, the
+    selection's tile and the softmax's state at that static row slice, and
+    that the last q block is sent to where every other block takes the folds
+    on ``bq`` rows. Rows are independent in both GEMMs and in the softmax, so
+    a valid row's context is bit for bit what the whole block's fold gives
+    it; the chunks, their fetches and the grid are what they are without, and
+    what runs once a block (the state's reset, the turn, the result's
+    division) stays on the whole block: rows past ``n_valid`` are outside the
+    result and are not written either way. A short fold is traced only where
+    the last block can reach it (no unmasked one where it sees no chunk
+    whole). None: the program letter for letter without the option.
 
     ``parts`` (one head a program): the score is the sum of
     that many products, ``parts`` q blocks then ``parts`` k blocks before v,
@@ -1477,8 +1530,9 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
     o_ref, acc_ref, m_ref, l_ref = rest
     # the grid of a launch that turns q has the q blocks outside the heads
     i, j = pl.program_id(2 if turn is None else 1), pl.program_id(3)
-    lo, hi = _visible_chunks(i, bq=bq, bkv=bkv, n_valid=n_valid,
-                             causal=causal, window=window)
+    geometry = dict(bq=bq, bkv=bkv, n_valid=n_valid, causal=causal,
+                    window=window)
+    lo, hi = _visible_chunks(i, **geometry)
     c = lo + j
 
     fold_scale = _scale_folds_into_q(scale)
@@ -1502,14 +1556,18 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
                               of_head(f, turned_ref),
                               scale if fold_scale else None, *turn)
 
-    def fold(masked: bool):
+    def fold(masked: bool, rows: int | None = None):
+        # the block's rows in an operand's block and in a scratch: all, or
+        # the last block's first ``rows``
+        block, held = (0, ...) if rows is None else (
+            (0, slice(rows)), slice(rows))
         for f in range(heads):
             acc_f, m_f, l_f = (of_head(f, r) for r in (acc_ref, m_ref, l_ref))
-            qs = [of_head(f, r)[0] for r in q_refs]
+            qs = [of_head(f, r)[block] for r in q_refs]
             if f == 0:  # the group's one chunk
                 ks, v = [r[0] for r in k_refs], v_ref[0]
             if turn is not None:  # turned, and scaled where the scale folds
-                qs = [of_head(f, turned_ref)[...]]
+                qs = [of_head(f, turned_ref)[held]]
             elif fold_scale:
                 qs = [q * scale for q in qs]
             q = qs[0] if parts == 1 else jnp.concatenate(qs, axis=1)
@@ -1538,30 +1596,40 @@ def _fwd_masked_kernel(*refs, scale: float, n_valid: int, bq: int, bkv: int,
                     if window is not None:
                         keep &= col > row - window
                     if selected:
-                        keep &= keep_ref[0].astype(jnp.int32) != 0
+                        keep &= keep_ref[block].astype(jnp.int32) != 0
                 logits = jnp.where(keep, logits, _NEG_INF)
-            m_prev = jnp.max(m_f[...], axis=-1, keepdims=True)  # (bq, 1)
-            l_prev = jnp.max(l_f[...], axis=-1, keepdims=True)
+            m_prev = jnp.max(m_f[held], axis=-1, keepdims=True)  # (bq, 1)
+            l_prev = jnp.max(l_f[held], axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev,
                                 jnp.max(logits, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(logits - m_new)
             l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            acc_f[...] = acc_f[...] * alpha + jnp.dot(
+            acc_f[held] = acc_f[held] * alpha + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_f[...] = jnp.broadcast_to(m_new, m_f.shape)
-            l_f[...] = jnp.broadcast_to(l_new, l_f.shape)
+            stats = (logits.shape[0], m_f.shape[1])
+            m_f[held] = jnp.broadcast_to(m_new, stats)
+            l_f[held] = jnp.broadcast_to(l_new, stats)
 
-    # every token of the block sees the whole chunk
-    whole = (c + 1) * bkv <= n_valid
-    if causal:
-        whole &= (c + 1) * bkv - 1 <= i * bq
-    if window is not None:
-        whole &= c * bkv > i * bq + bq - 1 - window
+    whole = _whole_chunk(i, c, **geometry)
     if selected:
         whole = False
-    pl.when((c <= hi) & whole)(lambda: fold(False))
-    pl.when((c <= hi) & jnp.logical_not(whole))(lambda: fold(True))
+    if tail is None:  # as written before the option: the pinned programs
+        pl.when((c <= hi) & whole)(lambda: fold(False))
+        pl.when((c <= hi) & jnp.logical_not(whole))(lambda: fold(True))
+    else:
+        last = pl.cdiv(n_valid, bq) - 1
+        first, end = _visible_chunks(last, lib=_Ints, **geometry)
+        a_whole_one = any(_whole_chunk(last, chunk, **geometry)
+                          for chunk in range(first, end + 1))
+        visited = c <= hi
+        for rows, mine, unmasked in ((None, i != last, True),
+                                     (tail, i == last, a_whole_one)):
+            if unmasked and not selected:
+                pl.when(visited & mine & whole)(
+                    functools.partial(fold, False, rows))
+            pl.when(visited & mine & jnp.logical_not(whole))(
+                functools.partial(fold, True, rows))
 
     @pl.when(j == n_kv - 1)
     def _emit():
@@ -1689,9 +1757,11 @@ _WALK_PARAMS = pltpu.CompilerParams(
 @functools.partial(
     jax.jit, inline=True,
     static_argnames=("selected", "rep", "lanes", "scale", "n_valid", "bq",
-                     "bkv", "causal", "window", "interpret", "turn", "heads"))
+                     "bkv", "causal", "window", "interpret", "turn", "heads",
+                     "tail"))
 def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
-                     bq, bkv, causal, window, interpret, turn=None, heads=1):
+                     bq, bkv, causal, window, interpret, turn=None, heads=1,
+                     tail=None):
     """The masked launch (``fwd_masked``), or ``selected``, with ``keep``
     first in ``more`` — an int8 ``(rows, tokens⁺, tokens⁺)`` selection in
     whole ``(bq, bkv)`` tiles, one for all the heads — the selected one
@@ -1718,7 +1788,11 @@ def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
     the q blocks, so that a q block's tables are fetched once for all its
     heads and not once a group (0.6 GB a ``fwd_selected`` launch at 9,217
     tokens × 64 heads, under steps that have no time to hide it); every other
-    block is fetched as often either way.
+    block is fetched as often either way. ``tail``: the rows the last q
+    block's folds run on (:func:`_tail_rows` of ``n_valid`` and ``bq``, the
+    caller's to compute; None: all of them, the program without the short
+    folds) — that block still takes a grid step for every chunk it sees, each
+    multiplying ``tail`` rows where it multiplied ``bq``.
 
     An inline ``jit``: the launch lands in the caller's program as it would
     without (no call, the caller's named scopes on it), but JAX keeps its
@@ -1758,7 +1832,7 @@ def _fwd_masked_call(q, k, v, *more, selected, rep, lanes, scale, n_valid,
         return pl.pallas_call(
             functools.partial(_fwd_masked_kernel, scale=scale, n_kv=n_kv,
                               selected=keep is not None, turn=turn,
-                              heads=heads, **geometry),
+                              heads=heads, tail=tail, **geometry),
             grid=tuple(grid),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -1794,9 +1868,13 @@ def flash_attention_masked(q, k, v, scale: float, *, causal: bool = True,
     each head's context is bit for bit what a program of its own gives, and
     ``H == KV`` is that program itself at q blocks of 512.
     ``kernels.flash_fwd_fold`` counts the heads a program folds, +1 a trace.
-    Blocks and heads a program come from the shapes alone. ``rotary``: q
-    comes UNTURNED and the launch turns the q block
-    it holds, as :func:`flash_attention_selected` says (a head of 128 whose
+    The last q block's folds run on the rows it holds, in whole groups of 32,
+    where those are at most half a block (:func:`_tail_rows`; one row of 256
+    or 512 at the samplers' ``k² + 1`` tokens), bit for bit the folds on the
+    whole block; ``kernels.flash_fwd_tail`` counts ``<rows>/<q block>`` or
+    ``whole``, +1 a trace. Blocks, heads a program and those rows come from
+    the shapes alone. ``rotary``: q comes UNTURNED and the launch turns the q
+    block it holds, as :func:`flash_attention_selected` says (a head of 128 whose
     every dim turns, or whose first half does: its one lane group). No
     backward yet: the VJP raises by name (ROADMAP Reach)."""
     _check_shared_heads(q, k, v)
@@ -1818,8 +1896,10 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
     :func:`flash_attention_selected` on ``(B, N, heads, D)`` operands: heads
     zero-padded to whole lanes where ``D`` does not fill them, blocks and the
     heads a program folds from the shape (:func:`_masked_fold`; one head under
-    a selection), one launch a device under a mesh. ``rotary``: q is unturned
-    and the launch turns it, by tables made here for every device."""
+    a selection), the rows of the last q block's folds from the length
+    (:func:`_tail_rows`; ``kernels.flash_fwd_tail``), one launch a device
+    under a mesh. ``rotary``: q is unturned and the launch turns it, by tables
+    made here for every device."""
     B, N, H, D = q.shape
     KV = k.shape[2]
     lanes = tiling.round_up(D, _LANE)
@@ -1830,6 +1910,8 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
     if keep is None:
         heads, bq = _masked_fold(H // KV, N, lanes, q.dtype)
     _kernels.inc("kernels.flash_fwd_fold", key=str(heads))
+    tail = _tail_rows(N, bq)
+    _kernels.inc("kernels.flash_fwd_tail", key=_tail_key(tail, bq))
     spec = rows_spec(B)
     operands = (q.reshape(B, N, H * lanes), k.reshape(B, N, KV * lanes),
                 v.reshape(B, N, KV * lanes))
@@ -1847,7 +1929,7 @@ def _masked_forward(q, k, v, keep, scale, causal, window, rotary=None):
             _fwd_masked_call, selected=keep is not None, rep=H // KV,
             lanes=lanes, scale=scale, n_valid=N, bq=bq, bkv=bkv, causal=causal,
             window=window, interpret=kernel_interpret(), turn=turn,
-            heads=heads),
+            heads=heads, tail=tail),
         specs, spec,
     )(*operands)
     return out.reshape(B, N, H, lanes)[..., :D]
@@ -1898,6 +1980,8 @@ def flash_attention_selected(q, k, v, scale: float, keep,
     ``top`` best of the visible keys never is). Every chunk at or below the
     diagonal is multiplied and masked by its tile: with a scattered set there
     is no chunk to skip, and a gather a row is 2,048 descriptors a query.
+    The last q block's folds run on the rows it holds and read the tile at
+    those rows, as :func:`flash_attention_masked` says.
 
     ``rotary``: q comes UNTURNED, as its projection wrote it, and this is the
     rotation of every query head it still needs (k comes turned). Where a
@@ -2076,8 +2160,12 @@ def _latent_blocks(n_tokens: int, nope: int, vd: int, dtype) -> tuple:
     return bq, bkv
 
 
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("heads", "scale", "n_valid", "bq", "bkv", "causal",
+                     "interpret", "tail"))
 def _fwd_latent_call(q_nope, q_r, k_nope, k_r, v, *, heads, scale, n_valid,
-                     bq, bkv, causal, interpret):
+                     bq, bkv, causal, interpret, tail=None):
     """The latent launch (``fwd_latent``). ``q_nope``, ``k_nope``: ``(rows,
     tokens, H·nope)``; ``v``: ``(rows, tokens, H·vd)``; ``q_r``: ``(rows,
     tokens, H·rot)``; ``k_r``: ``(rows, tokens, 128)``, the one rotated key
@@ -2085,7 +2173,11 @@ def _fwd_latent_call(q_nope, q_r, k_nope, k_r, v, *, heads, scale, n_valid,
     projection wrote it, the token axis ending inside the last block. Grid
     and chunk walk as :func:`_fwd_masked_call`'s: head ``h`` reads column
     block ``h`` of q_nope, k_nope and v, the lane group of q_r its rotated
-    part lies in, and column block 0 of k_r."""
+    part lies in, and column block 0 of k_r. ``tail`` as there: the rows the
+    last q block's folds run on, the head's half of q_r read at the same
+    slice. An inline ``jit`` as that launch is, so that the attention layers
+    of a stack, which all launch one shape, trace the body once (five sites a
+    Pangu forward)."""
     rows, tokens, _ = q_nope.shape
     nope, vd = q_nope.shape[2] // heads, v.shape[2] // heads
     share = heads * _LANE // q_r.shape[2]  # heads a lane group of q_r
@@ -2103,7 +2195,7 @@ def _fwd_latent_call(q_nope, q_r, k_nope, k_r, v, *, heads, scale, n_valid,
     with profiling.scope("flash_attention/fwd_latent"):
         return pl.pallas_call(
             functools.partial(_fwd_latent_kernel, scale=scale, n_kv=n_kv,
-                              **geometry),
+                              tail=tail, **geometry),
             grid=(rows, heads, n_q, n_kv),
             in_specs=[q_spec(nope), q_spec(_LANE, share), k_spec(nope),
                       k_spec(_LANE, one=True), k_spec(vd)],
@@ -2137,17 +2229,26 @@ def flash_attention_latent(q_nope, q_r, k_nope, k_r, v, scale: float, *,
     address are refused by name (:func:`latent_sizes`). K/V chunks above
     the diagonal are neither fetched nor multiplied
     (:func:`_fwd_masked_call`'s walk); blocks from the shape and a VMEM row
-    (:func:`_latent_blocks`). No backward yet: the VJP raises by name."""
+    (:func:`_latent_blocks`). Where the LAST q block holds few rows of the
+    sequence — one of 1,024 at the ``k² + 1`` tokens the samplers make — its
+    folds run on those rows in whole groups of 32 and not on the block, by
+    the one rule of the three launches of this body (:func:`_tail_rows`: up
+    to half a block; past it, and on a block boundary, the program is the one
+    without); a row's context is bit for bit the same either way, and
+    ``kernels.flash_fwd_tail`` counts which, ``<rows>/<q block>`` or
+    ``whole``, +1 a trace. No backward yet: the VJP raises by name."""
     B, N, H, nope, rot, vd = latent_sizes(q_nope, q_r, k_nope, k_r, v)
     _kernels.inc("kernels.flash_fwd_mask", key=mask_kind(causal, None))
     if rot < _LANE:
         k_r = jnp.concatenate([k_r, k_r], axis=-1)
     bq, bkv = _latent_blocks(N, nope, vd, v.dtype)
+    tail = _tail_rows(N, bq)
+    _kernels.inc("kernels.flash_fwd_tail", key=_tail_key(tail, bq))
     spec = rows_spec(B)
     out = per_device(
         functools.partial(
             _fwd_latent_call, heads=H, scale=scale, n_valid=N, bq=bq, bkv=bkv,
-            causal=causal, interpret=kernel_interpret()),
+            causal=causal, interpret=kernel_interpret(), tail=tail),
         (spec,) * 5, spec,
     )(q_nope.reshape(B, N, H * nope), q_r.reshape(B, N, H * rot),
       k_nope.reshape(B, N, H * nope), k_r, v.reshape(B, N, H * vd))

@@ -1185,14 +1185,22 @@ def _without_locations(text):
 
 
 #: :func:`_without_locations` of the launches that keep one head a program,
-#: lowered for the described chip on be8ed9d, the parent of the PR that let
-#: ``fwd_masked`` fold a group of heads. A change that MEANS to alter one of
-#: these programs pins it anew; the fold must not.
+#: lowered for the described chip. A change that MEANS to alter one of these
+#: programs pins it anew; the fold must not. At the cells' 9,217 and 16,385
+#: tokens the last q block holds one row, and the four were pinned anew by the
+#: PR that gave that block folds on 32 rows; ``_whole``: the same launches one
+#: token shorter, a whole number of blocks, taken on 533c891, that PR's
+#: parent — a sequence without a short last block lowers to the module and
+#: the Mosaic body it had.
 ONE_HEAD_LAUNCHES = {
-    "selected": ("af383f7c3848", ["de5cd8e035df"]),
-    "selected_turned": ("2596f1c5065d", ["47337572572d"]),
-    "latent": ("8c2ffd4dec3c", ["f319f13093e2"]),
-    "masked_a_head_a_kv_head": ("369e4dba0d96", ["c9ba812f86b8"]),
+    "selected": ("af383f7c3848", ["f2087488c5ad"]),
+    "selected_turned": ("2596f1c5065d", ["e69550338ded"]),
+    "latent": ("8c2ffd4dec3c", ["8d3d1c3f1055"]),
+    "masked_a_head_a_kv_head": ("369e4dba0d96", ["e3f951bdb1e9"]),
+    "selected_whole": ("8c17a76ab9ba", ["a4888bc20b26"]),
+    "selected_turned_whole": ("8d89f7fbc8e9", ["17f17afb0cbc"]),
+    "latent_whole": ("5aa2ce0adb1b", ["2b89314f7db7"]),
+    "masked_a_head_a_kv_head_whole": ("7dcad43ebb04", ["b1f9557a15b8"]),
 }
 
 
@@ -1201,15 +1209,18 @@ def test_one_head_launches_lower_to_the_text_they_had(launch, chip):
     """``fwd_selected`` (GLM's shape, with and without the in-launch turn),
     ``fwd_latent`` (Pangu's) and ``fwd_masked`` with a K/V head a query head,
     lowered for the TPU: the module and the Mosaic body, source locations
-    apart, are what they were before a program of ``fwd_masked`` could hold
-    a group of heads."""
+    apart, are the pinned ones — at a whole number of blocks, what they were
+    before the last q block had folds of its own."""
     from ddim_cold_tpu.ops import sparse_select as ss
     from ddim_cold_tpu.ops.rotary import Rotary
 
     sds = _struct(SingleDeviceSharding(chip[0]))
     bf = jnp.bfloat16
+    pinned, less = ONE_HEAD_LAUNCHES[launch], launch.endswith("_whole")
+    launch = launch.removesuffix("_whole")
     if launch.startswith("selected"):
         n, L, H, hd = (GLM[k] for k in ("n", "L", "heads", "hd"))
+        L -= less
         length = ss.mask_length(L, bf)
         rotary = None if launch == "selected" else Rotary(
             8000000.0 ** (-np.arange(0, 64, 2) / 64), 1.0, "interleave",
@@ -1221,16 +1232,59 @@ def test_one_head_launches_lower_to_the_text_they_had(launch, chip):
     elif launch == "latent":
         n, L, H, nope, rot, vd = (PANGU[k] for k in
                                   ("n", "L", "heads", "nope", "rot", "vd"))
+        L -= less
         lowered = jax.jit(lambda qn, qr, kn, kr, v: fa.latent_attention(
             qn, qr, kn, kr, v, (nope + rot) ** -0.5)).lower(
             sds((n, L, H, nope), bf), sds((n, L, H, rot), bf),
             sds((n, L, H, nope), bf), sds((n, L, rot), bf),
             sds((n, L, H, vd), bf))
     else:
-        q = sds((1, 16385, 8, 128), bf)
+        q = sds((1, 16385 - less, 8, 128), bf)
         lowered = jax.jit(lambda q, k, v: fa.masked_attention(
             q, k, v, 0.1)).lower(q, q, q)
-    assert _without_locations(lowered.as_text()) == ONE_HEAD_LAUNCHES[launch]
+    assert _without_locations(lowered.as_text()) == pinned
+
+
+@pytest.mark.parametrize("driver,config,tail,bodies", [
+    ("sample_closed_pangu", "pangu_ultra_ep32_px1536", {"32/1024": 5}, 1),
+    ("sample_closed_kimi", "kimi_linear_ep2_px2048", {"32/1024": 1}, 1),
+    ("sample_closed_glm", "glm52_ep16_px1536", {"32/512": 5}, 1),
+    ("sample_closed_moe", "laguna_s21_ep2_px1024", {"32/256": 5}, 2),
+    ("sample_closed_smallthinker", "smallthinker_21b_l8_px2032",
+     {"32/256": 8}, 2),
+    ("sample_closed_nemotron", "nemotron3_super_ep4_px2048", {"32/256": 1}, 1),
+])
+def test_a_cells_forward_folds_its_last_q_block_on_32_rows(
+        driver, config, tail, bodies, chip, monkeypatch):
+    """One trace of each sampler cell's whole forward that launches the
+    masked body (shapes only, the TPU's path): every launch's last q block
+    holds one row of the ``k² + 1`` tokens (two of SmallThinker's 16,130) and
+    folds on 32 — ``kernels.flash_fwd_tail`` reads ``32/<q block>`` once a
+    launch and never ``whole`` — and the body is traced once a distinct
+    launch, the latent launch keeping its trace as the masked one does (five
+    sites a Pangu forward, one trace)."""
+    import importlib
+
+    from ddim_cold_tpu.obs import metrics
+
+    traced = []
+    body = fa._fwd_masked_kernel
+    monkeypatch.setattr(
+        fa, "_fwd_masked_kernel",
+        lambda *refs, **kw: traced.append(kw["tail"]) or body(*refs, **kw))
+    config = _bench_config(config)
+    model = importlib.import_module(
+        f"benchmark.drivers.{driver}").build_model(config)
+    x = jnp.zeros((1, *config["img_size"], 3), jnp.float32)
+    t = jnp.zeros((1,), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x, t)
+    jax.clear_caches()  # the launches' kept traces: ``init``'s among them
+    traced.clear()
+    metrics.reset()
+    jax.eval_shape(model.apply, params, x, t)
+    assert fa._kernels.by_key("kernels.flash_fwd_tail") == tail
+    assert traced == [32] * bodies
+    metrics.reset()
 
 
 @pytest.mark.parametrize("launch", ["gate_up_relu", "down", "whole_relu"])

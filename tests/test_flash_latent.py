@@ -4,8 +4,9 @@ own width) in interpreter mode, against dense float32 attention written out
 head by head on the assembled ``[k_nope, k_r]`` and against
 ``latent_attention_xla`` (its stand-in off the TPU): the three head-size
 triples the launch addresses, a ragged length and one of several chunks, the
-blocks and the VMEM row, what it refuses, the counters, and that the masked
-forward it shares its body with is untouched."""
+blocks and the VMEM row, what it refuses, the counters, that the masked
+forward it shares its body with is untouched, and the last q block's folds
+on the rows it holds, bit for bit the folds on the whole block."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,8 @@ import numpy as np
 import pytest
 
 from ddim_cold_tpu.ops import flash_attention as fa
+from tests.test_flash_masked import (EXTRAS, tail_key,
+                                     with_and_without_short_folds)
 
 
 def _operands(N, H, nope, rot, vd, B=1, seed=0, dtype=jnp.float32):
@@ -91,6 +94,32 @@ def test_blocks_come_from_the_shape_and_the_vmem_row():
     n_q, n_kv, _ = fa._chunk_walk(9217, dict(
         bq=1024, bkv=512, n_valid=9217, causal=True, window=None))
     assert (n_q, n_kv) == (10, 19)
+
+
+@pytest.mark.parametrize("extra", EXTRAS)
+@pytest.mark.parametrize("rot", [64, 128])
+def test_the_last_blocks_short_folds_are_bit_for_bit_the_whole_blocks(
+        rot, extra, monkeypatch):
+    """``fwd_latent`` at one q block of 1,024 rows and a few tokens more, two
+    heads on one lane group of q_r (``rot`` 64: the head's half of the block
+    is read at the short slice too) and a group each: as
+    ``tests/test_flash_masked.py``'s test of the same name says — the short
+    folds' result bit for bit the whole block's, ``kernels.flash_fwd_tail``
+    ``<rows>/1024`` up to half a block and ``whole`` past it and on a block
+    boundary — and the float32 dense reference within bfloat16's rounding."""
+    bq, _ = fa._latent_blocks(2048, 128, 128, jnp.bfloat16)
+    N = bq + EXTRAS[extra](bq)
+    ops = _operands(N, 2, 128, rot, 128, seed=rot, dtype=jnp.bfloat16)
+    scale = (128 + rot) ** -0.5
+    got, whole = with_and_without_short_folds(
+        lambda: fa.flash_attention_latent(*ops, scale), monkeypatch,
+        tail_key(N - bq, bq))
+    assert bq == 1024 and got.shape == (1, N, 2, 128)
+    np.testing.assert_array_equal(got, whole)
+    np.testing.assert_allclose(
+        got.astype(jnp.float32),
+        _dense(*(x.astype(jnp.float32) for x in ops), scale),
+        rtol=3e-2, atol=3e-2)
 
 
 @pytest.mark.parametrize("nope,rot,vd,H,match", [

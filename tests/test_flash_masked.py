@@ -9,7 +9,9 @@ UNTURNED q turned inside the launch, once a q block, against ``apply_rotary``
 before it. And the fold: ONE program of ``fwd_masked`` holding a K/V chunk for
 several query heads of its K/V head, bit for bit a program a head, the heads
 and the q block from the shape under a row budget; ``fwd_selected`` and
-``fwd_latent`` the programs they were."""
+``fwd_latent`` the programs they were. And the last q block's folds on the
+rows it holds: bit for bit the folds on the whole block, at the lengths that
+have such a block and only there."""
 
 import hashlib
 
@@ -562,18 +564,29 @@ def test_a_group_is_one_program_and_a_selection_keeps_one_head():
 
 
 #: sha256 (16 hex digits) of ``str(jax.make_jaxpr(...))`` of the launches that
-#: hold one head a program, taken on be8ed9d, the parent of the PR that let
-#: ``fwd_masked`` fold a group: the kernel's body, grid, blocks and scratch,
+#: hold one head a program: the kernel's body, grid, blocks and scratch,
 #: operation for operation, under ``tests/conftest.py``'s matmul precision. A
 #: change that MEANS to alter one of these programs pins its text anew; the
-#: fold must not.
+#: fold must not. At 600 tokens the five launches at q blocks of 512 hold 88
+#: rows in their last block, whose folds run on 96 rows since the PR that
+#: gave the body its short folds (which pinned them anew); ``latent``, one
+#: block of 600 rows, is as taken on be8ed9d, the parent of the PR that let
+#: ``fwd_masked`` fold a group. ``_1024``: the same launches at two whole
+#: blocks, taken on 533c891, the parent of the short folds' PR — a sequence
+#: without a short last block lowers to the text it had.
 ONE_HEAD_PROGRAMS = {
-    "masked_rep1_window": "14d14bd5754449c8",
-    "masked_rep1_causal_turned": "385b7bfa2d9c52c9",
-    "selected_rep7": "c46b5ac6c12c107e",
-    "selected_rep7_turned": "8d86da8e0ea5da86",
+    "masked_rep1_window": "ee558bc49b65afcc",
+    "masked_rep1_causal_turned": "ccc76d0242ae1af8",
+    "selected_rep7": "41dce0031576b19f",
+    "selected_rep7_turned": "6e891755474613ff",
     "latent": "cc9134dac65e7eaa",
-    "masked_rep11_prime": "dcb338040cbbc123",
+    "masked_rep11_prime": "63569fa1f191b721",
+    "masked_rep1_window_1024": "bc5c890064df000c",
+    "masked_rep1_causal_turned_1024": "6f9acf1f8941384d",
+    "selected_rep7_1024": "847d7f7bef87bbef",
+    "selected_rep7_turned_1024": "49a1d9ee34a5243c",
+    "latent_1024": "d18c70ed9d187532",
+    "masked_rep11_prime_1024": "a4cd76a22ce540d6",
 }
 
 
@@ -581,32 +594,143 @@ ONE_HEAD_PROGRAMS = {
 def test_one_head_launches_lower_to_the_text_they_had(launch):
     """``rep`` 1, a ``rep`` the budget cannot divide, any selection and the
     latent launch: the jaxpr — the ``pallas_call`` with its body — is letter
-    for letter the one of the tree before a program could hold a group."""
+    for letter the pinned one; at a whole number of blocks, the one of the
+    tree before the last block had folds of its own."""
     ones = lambda *shape: jnp.ones(shape, jnp.bfloat16)
-    qkv = lambda N, H, KV: (ones(1, N, H, 128), ones(1, N, KV, 128),
-                            ones(1, N, KV, 128))
+    tokens = 1024 if launch.endswith("_1024") else 600
+    qkv = lambda H, KV: (ones(1, tokens, H, 128), ones(1, tokens, KV, 128),
+                         ones(1, tokens, KV, 128))
     rotary = Rotary(tuple(10000.0 ** (-i / 64) for i in range(64)), 1.0,
                     "rotate_half", 0)
     keep = jnp.ones((1, 1024, 1024), jnp.int8)
     fn, args = {
         "masked_rep1_window": (lambda q, k, v: fa.flash_attention_masked(
-            q, k, v, 0.1, window=200), qkv(600, 4, 4)),
+            q, k, v, 0.1, window=200), qkv(4, 4)),
         "masked_rep1_causal_turned": (lambda q, k, v: fa.flash_attention_masked(
-            q, k, v, 128 ** -0.5, rotary=rotary), qkv(600, 4, 4)),
+            q, k, v, 128 ** -0.5, rotary=rotary), qkv(4, 4)),
         "selected_rep7": (lambda q, k, v, m: fa.flash_attention_selected(
-            q, k, v, 0.1, m), qkv(600, 14, 2) + (keep,)),
+            q, k, v, 0.1, m), qkv(14, 2) + (keep,)),
         "selected_rep7_turned": (lambda q, k, v, m: fa.flash_attention_selected(
-            q, k, v, 0.1, m, rotary), qkv(600, 14, 2) + (keep,)),
+            q, k, v, 0.1, m, rotary), qkv(14, 2) + (keep,)),
         "latent": (lambda *a: fa.flash_attention_latent(*a, 0.1),
-                   (ones(1, 600, 2, 128), ones(1, 600, 2, 64),
-                    ones(1, 600, 2, 128), ones(1, 600, 64),
-                    ones(1, 600, 2, 128))),
+                   (ones(1, tokens, 2, 128), ones(1, tokens, 2, 64),
+                    ones(1, tokens, 2, 128), ones(1, tokens, 64),
+                    ones(1, tokens, 2, 128))),
         "masked_rep11_prime": (lambda q, k, v: fa.flash_attention_masked(
-            q, k, v, 0.1), qkv(600, 11, 1)),
-    }[launch]
+            q, k, v, 0.1), qkv(11, 1)),
+    }[launch.removesuffix("_1024")]
     text = str(jax.make_jaxpr(fn)(*args))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         ONE_HEAD_PROGRAMS[launch]
+
+
+# --- the last q block's folds on the rows it holds ---------------------------
+
+#: tokens past the last full q block, at a q block of ``bq`` rows
+EXTRAS = {"0": lambda bq: 0, "1": lambda bq: 1, "2": lambda bq: 2,
+          "31": lambda bq: 31, "33": lambda bq: 33,
+          "half": lambda bq: bq // 2, "half+8": lambda bq: bq // 2 + 8}
+
+
+def tail_key(extra, bq):
+    """The key of ``kernels.flash_fwd_tail`` at ``extra`` tokens past the last
+    full block of ``bq`` rows, written out: short folds on the rows that
+    block holds in whole groups of 32, up to half a block."""
+    rows = -(-extra // 32) * 32
+    return f"{rows}/{bq}" if 0 < rows <= bq // 2 else "whole"
+
+
+def with_and_without_short_folds(launch, monkeypatch, want):
+    """``launch()`` as the rule has it, and with the last block's folds on
+    every row (the ``tail`` of the ``_call`` None) — the counter's two keys
+    checked on the way."""
+    count = lambda: fa._kernels.by_key("kernels.flash_fwd_tail")
+    assert "kernels.flash_fwd_tail" in {name for name, *_ in metrics.METRICS}
+    metrics.reset()
+    got = launch()
+    assert count() == {want: 1}
+    with monkeypatch.context() as patch:
+        patch.setattr(fa, "_tail_rows", lambda n_valid, bq: None)
+        whole = launch()
+    assert count() == ({"whole": 2} if want == "whole" else
+                       {want: 1, "whole": 1})
+    metrics.reset()
+    return got, whole
+
+
+TAIL_LAUNCHES = {
+    # (rep, KV, full q blocks, window, turned, dtype): the last block sees a
+    # whole chunk and the diagonal's
+    "causal": (1, 2, 1, None, False, jnp.bfloat16),
+    # no chunk whole: no unmasked short fold in the program
+    "window": (1, 1, 2, 300, False, jnp.bfloat16),
+    "window_float32": (1, 1, 2, 300, False, jnp.float32),
+    "group7": (7, 1, 1, None, False, jnp.bfloat16),
+    "group6_window_turned": (6, 1, 2, 300, True, jnp.bfloat16),
+    "turned": (1, 2, 1, None, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("extra", EXTRAS)
+@pytest.mark.parametrize("launch", TAIL_LAUNCHES)
+def test_the_last_blocks_short_folds_are_bit_for_bit_the_whole_blocks(
+        launch, extra, monkeypatch):
+    """``fwd_masked`` at ``k · bq`` tokens and a few more — one head a program
+    and a folded group, causal and under a window, with and without the
+    in-launch turn: where the rows the last block holds, in whole groups of
+    32, are at most half a block, its folds run on those rows, and every row
+    of the result is bit for bit the one of the launch whose folds run on the
+    whole block; past half a block and on a block boundary the launch IS that
+    one (``kernels.flash_fwd_tail``: ``whole``). Against the dense reference
+    at the tolerances of the tests above. In float32 to a unit in the last
+    place: the CPU's float32 GEMM sums a product of 32 rows in another order
+    than one of 512 (the MXU has one order)."""
+    rep, KV, blocks, window, turned, dtype = TAIL_LAUNCHES[launch]
+    _, bq = fa._masked_fold(rep, 2048, 128, dtype)
+    N = blocks * bq + EXTRAS[extra](bq)
+    want = tail_key(N - blocks * bq, bq)
+    assert fa._tail_key(fa._tail_rows(N, bq), bq) == want
+    rotary, scale = (_laguna_rotary("causal") if turned else None), 128 ** -0.5
+    q, k, v = _qkv(N, rep * KV, KV, 128, seed=blocks, dtype=dtype)
+    got, whole = with_and_without_short_folds(
+        lambda: fa.flash_attention_masked(q, k, v, scale, window=window,
+                                          rotary=rotary),
+        monkeypatch, want)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, whole, rtol=2e-6, atol=3e-7)
+        np.testing.assert_allclose(got, _dense(q, k, v, scale, True, window),
+                                   rtol=2e-5, atol=2e-6)
+    else:
+        np.testing.assert_array_equal(got, whole)
+        f32 = lambda x: np.asarray(x, np.float32)
+        xla = fa.blockwise_attention_xla(
+            *(x.astype(jnp.float32)
+              for x in (fa._turned_by_xla(q, rotary), k, v)),
+            scale, causal=True, window=window)
+        np.testing.assert_allclose(f32(got), f32(xla), rtol=3e-2, atol=3e-2)
+
+
+def test_the_tail_rule_gives_the_published_shapes_their_rows():
+    """:func:`_tail_rows` at the cells' lengths and q blocks: every sampled
+    sequence is ``k² + 1`` tokens (SmallThinker's ``k² + 1`` and one more), so
+    the last block holds one row or two and its folds run on 32; and over
+    every length to three blocks, the rows are the block's held rows in whole
+    groups of 32, at most half a block, else None."""
+    assert fa._TAIL_ROWS == 32
+    for tokens, bq in ((9217, 1024), (16385, 1024), (9217, 512), (4097, 256),
+                       (16130, 256), (16385, 256)):
+        assert fa._tail_rows(tokens, bq) == 32
+    for bq in (256, 512, 1024):
+        for tokens in range(1, 3 * bq + 1):
+            held = (tokens - 1) % bq + 1
+            rows = fa._tail_rows(tokens, bq)
+            if rows is None:
+                assert -(-held // 32) * 32 > bq // 2
+            else:
+                assert held <= rows < held + 32 and rows % 32 == 0
+                assert rows <= bq // 2
+    assert fa._tail_rows(40, 40) is None and fa._tail_rows(600, 600) is None
 
 
 def test_the_fold_rule_gives_the_published_shapes_their_pairs():

@@ -3,7 +3,9 @@ of ops/flash_attention.py: the threshold selection against ``lax.top_k`` on
 seeded scores and on scores with deliberate ties (all kept), the two
 selection launches (``dsa_index``, ``dsa_select``) in interpreter mode against
 the XLA path, the attention launch against a dense masked softmax and its XLA
-stand-in, the counters, and what has no backward saying so by name."""
+stand-in, the counters, what has no backward saying so by name, and the last
+q block's folds on the rows it holds, bit for bit the folds on the whole
+block."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,8 @@ import pytest
 from ddim_cold_tpu.obs import metrics
 from ddim_cold_tpu.ops import flash_attention as fa
 from ddim_cold_tpu.ops import sparse_select as ss
+from tests.test_flash_masked import (EXTRAS, _rotary, tail_key,
+                                     with_and_without_short_folds)
 
 
 def _index_inputs(n, L, J, D, seed=0, dtype=jnp.float32):
@@ -180,6 +184,33 @@ def test_selected_forward_in_bfloat16():
     got = fa.flash_attention_selected(q, k, v, 256 ** -0.5, keep)
     want = _dense(*(x.astype(jnp.float32) for x in (q, k, v)), 256 ** -0.5, keep)
     assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2)
+
+
+@pytest.mark.parametrize("extra", EXTRAS)
+@pytest.mark.parametrize("turned", [False, True], ids=["turned_before", "turned"])
+def test_the_last_blocks_short_folds_are_bit_for_bit_the_whole_blocks(
+        turned, extra, monkeypatch):
+    """``fwd_selected`` at one q block of 512 rows and a few tokens more, the
+    published head of 256 (with and without the in-launch turn of its last 64
+    dims), two query heads on one K/V head: the int8 selection's tile is read
+    at the short slice — 32 rows are one packed tile — and the result is bit
+    for bit the whole block's, as ``tests/test_flash_masked.py``'s test of the
+    same name says; against the dense float32 reference within bfloat16's
+    rounding."""
+    bq, _ = fa._masked_blocks(2048, jnp.bfloat16)
+    N = bq + EXTRAS[extra](bq)
+    q, k, v = _qkv(N, 2, 1, 256, seed=5, dtype=jnp.bfloat16)
+    keep = _keep(1, N, 200, q.dtype)
+    rotary = _rotary(64, "interleave", 192) if turned else None
+    got, whole = with_and_without_short_folds(
+        lambda: fa.flash_attention_selected(q, k, v, 256 ** -0.5, keep, rotary),
+        monkeypatch, tail_key(N - bq, bq))
+    assert bq == 512 and got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_array_equal(got, whole)
+    want = _dense(*(x.astype(jnp.float32)
+                    for x in (fa._turned_by_xla(q, rotary), k, v)),
+                  256 ** -0.5, keep)
     np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2)
 
 
