@@ -346,7 +346,40 @@ def build_entries(ctx: Context) -> list[Entry]:
     entries.append(Entry(
         "train_step", TRAIN, make_train_step(m),
         (state, (noisy, noisy, t), key, loss_rec), donates=True))
-    return entries
+    return entries + _longcat_entries(key)
+
+
+#: ``models/longcat.py`` at the width of its tests: a double layer whose
+#: expert layer (8 + 4 router outputs, 4 of them identities, top-3, experts
+#: 0-3 held) is a shortcut round two rescaled-latent attentions and two
+#: dense MLPs
+LONGCAT_TOY = dict(
+    model_type="longcat_flash", hidden_size=64, ffn_hidden_size=96,
+    expert_ffn_hidden_size=32, num_layers=1, num_attention_heads=2,
+    attention_bias=False, rms_norm_eps=1e-5, q_lora_rank=32, kv_lora_rank=16,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    rope_theta=10000000, attention_method="MLA", mla_scale_q_lora=True,
+    mla_scale_kv_lora=True, n_routed_experts=4, n_experts_routed=8,
+    zero_expert_num=4, zero_expert_type="identity", moe_topk=3,
+    routed_scaling_factor=6)
+
+
+def _longcat_entries(key) -> list[Entry]:
+    """The newest ``HybridDenoiser`` stack's forward and gradient, off the
+    TPU: the plain-JAX paths of the latent attention and the grouped expert
+    product, the identity term and the shortcut among what is traced."""
+    from ddim_cold_tpu.models.hybrid import HybridDenoiser
+
+    PATH = "ddim_cold_tpu/models/longcat.py"
+    model = HybridDenoiser(trunk=LONGCAT_TOY, img_size=(16, 16), patch_size=4)
+    x = jax.ShapeDtypeStruct((2, 16, 16, 3), jnp.float32)
+    t = jax.ShapeDtypeStruct((2,), jnp.int32)
+    params = jax.eval_shape(model.init, key, x, t)["params"]
+    forward = jax.jit(lambda p, x, t: model.apply({"params": p}, x, t))
+    grad = jax.jit(jax.grad(
+        lambda p, x, t: jnp.sum(model.apply({"params": p}, x, t) ** 2)))
+    return [Entry("longcat_forward", PATH, forward, (params, x, t)),
+            Entry("longcat_grad", PATH, grad, (params, x, t))]
 
 
 def run_entry_checks(max_const_bytes: int = 1 << 20,

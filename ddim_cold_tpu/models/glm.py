@@ -71,6 +71,7 @@ is plain JAX and differentiates (the selection itself is piecewise constant).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Mapping
 
 import flax.linen as nn
@@ -199,6 +200,16 @@ class _DenseByColumnSets(nn.Module):
             kernel, np.cumsum(self.widths)[:-1], axis=1)]
 
 
+def latent_multipliers(c: Mapping[str, Any]) -> tuple:
+    """``(a_q, a_kv)``: what the query latent and the key/value latent are
+    multiplied by after their norms, ``sqrt(hidden_size / rank)`` where the
+    trunk says ``mla_scale_q_lora`` / ``mla_scale_kv_lora``, else 1."""
+    return tuple(
+        math.sqrt(c["hidden_size"] / c[rank]) if c.get(flag) else 1.0
+        for flag, rank in (("mla_scale_q_lora", "q_lora_rank"),
+                           ("mla_scale_kv_lora", "kv_lora_rank")))
+
+
 def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
                  param_dtype, *, apart: bool = False):
     """The low-rank paths of latent attention up to the key/value latent, for
@@ -209,6 +220,18 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
     ``y (n, L, hidden)``, the ONE shared ``k_r (n, L, rot)`` rotated by
     ``rope`` (:func:`laguna.rotary_frequencies`) under ``pairing``, ``c_kv
     (n, L, kv_lora_rank)`` normed.
+
+    **The rescaled latents** (``mla_scale_q_lora``, ``mla_scale_kv_lora``
+    true in the trunk; absent or false: 1, and no operation is emitted): the
+    published layer multiplies q by ``a_q = sqrt(hidden_size / q_lora_rank)``
+    behind ``q_b_proj`` and the normed key/value latent by ``a_kv =
+    sqrt(hidden_size / kv_lora_rank)`` before ``kv_b_proj``; ``k_r`` is not
+    rescaled. Both are applied here behind the latent's own RMSNorm, on its
+    rounded result (:func:`latent_multipliers`): ``c_q = RMSNorm(y W_qa) ·
+    a_q``, ``c_kv = RMSNorm(c_kv) · a_kv``, the published order for ``c_kv``.
+    ``q_b_proj`` is linear, so ``(c_q · a_q) W_qb = (c_q W_qb) · a_q`` — bit
+    for bit where a_q is a power of two (2 at 6,144 / 1,536). ``c_q`` is
+    returned rescaled.
 
     q comes in the column order its reader wants. Published (``apart``
     false): a head's parts side by side, ``q (n, L, H·(nope + rot))``, as
@@ -226,7 +249,10 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
     dense = lambda feats, name: _dense(feats, name, **kw)
     norm = lambda name: RMSNorm(c["rms_norm_eps"], name=name, **kw)
 
+    a_q, a_kv = latent_multipliers(c)
     c_q = norm("q_a_layernorm")(dense(c["q_lora_rank"], "q_a_proj")(y))
+    if a_q != 1.0:
+        c_q = c_q * a_q
     if apart:
         q_nope, q_r = _DenseByColumnSets((H * nope, H * rot), name="q_b_proj",
                                          **kw)(c_q)
@@ -235,7 +261,10 @@ def latent_paths(c: Mapping[str, Any], y, rope, pairing: str, dtype,
         q = dense(H * (nope + rot), "q_b_proj")(c_q)
     kv_a = dense(rank + rot, "kv_a_proj_with_mqa")(y)
     k_r = apply_rotary(kv_a[..., rank:], 1, *rope, pairing=pairing)
-    return c_q, q, k_r, norm("kv_a_layernorm")(kv_a[..., :rank])
+    c_kv = norm("kv_a_layernorm")(kv_a[..., :rank])
+    if a_kv != 1.0:
+        c_kv = c_kv * a_kv
+    return c_q, q, k_r, c_kv
 
 
 def latent_projections(c: Mapping[str, Any], y, rope, pairing: str, dtype,

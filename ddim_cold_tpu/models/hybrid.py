@@ -25,7 +25,11 @@ attention with no rotation and no query latent; sigmoid-scored experts) or
 ``smallthinker`` (``models/smallthinker.py``: a router that reads the layer's
 input before attention while its ReLU-gated experts, with no shared one, read
 the normed stream after it; rotary window layers between position-free full
-ones). One wrapper
+ones) or ``longcat_flash`` (``models/longcat.py``: a double layer — two latent
+attentions with rank-rescaled latents and two dense MLPs — whose experts read
+the stream after the first attention and are added after the second MLP; a
+softmax router wider than the experts that have weights, the outputs past
+them identities). One wrapper
 serves all: what the samplers and the engine read of a
 model, ``clone``, the refusals and ``__call__`` below.
 
@@ -98,9 +102,9 @@ REFUSED = {
     "sp_mode": "the scan and the causal masks are sequential in the tokens",
     "use_flash": "a stack picks its attention itself: the jamba stack's two "
                  "layers are dense XLA attention, the laguna, glm_moe_dsa, "
-                 "pangu_ultra_moe, nemotron_h, kimi_linear and smallthinker "
-                 "stacks run their flash forwards wherever the backend is a "
-                 "TPU",
+                 "pangu_ultra_moe, nemotron_h, kimi_linear, smallthinker "
+                 "and longcat_flash stacks run their flash forwards wherever "
+                 "the backend is a TPU",
 }
 #: further spellings of the above, as the model, the sampler and the yaml have
 #: them, each mapped to the option it is refused under
@@ -347,6 +351,13 @@ def layer(trunk, i: int, dtype, param_dtype, name: str) -> nn.Module:
                        name=name)
 
 
+def depth_of(trunk: Mapping[str, Any]) -> int:
+    """How many layers the stack has here, under the key its ``config.json``
+    has: ``num_hidden_layers``, or ``longcat_flash``'s ``num_layers``."""
+    return (trunk["num_hidden_layers"] if "num_hidden_layers" in trunk
+            else trunk["num_layers"])
+
+
 def norm_eps(trunk: Mapping[str, Any]) -> float:
     """ε of the stack's RMSNorms under the key its ``config.json`` has:
     ``rms_norm_eps``, or ``nemotron_h``'s ``layer_norm_epsilon``."""
@@ -358,7 +369,8 @@ def norm_eps(trunk: Mapping[str, Any]) -> float:
 #: stack's ``check_trunk`` and ``layer`` (``hybrid``: this one)
 STACKS = {"jamba": "hybrid", "laguna": "laguna", "glm_moe_dsa": "glm",
           "pangu_ultra_moe": "pangu", "nemotron_h": "nemotron",
-          "kimi_linear": "kimi", "smallthinker": "smallthinker"}
+          "kimi_linear": "kimi", "smallthinker": "smallthinker",
+          "longcat_flash": "longcat"}
 
 
 def stack_of(trunk: Mapping[str, Any]) -> tuple:
@@ -416,7 +428,7 @@ class HybridDenoiser(nn.Module):
 
     @property
     def depth(self) -> int:
-        return self.trunk["num_hidden_layers"]
+        return depth_of(self.trunk)
 
     @property
     def num_patches(self) -> int:

@@ -208,6 +208,23 @@ class HeldExpertsMlp(nn.Module):
     W_ℓout``; the router and the shared expert stay on the full width.
     ``W_ℓout`` has no bias, so the shares still sum to the whole layer.
 
+    ``zero_experts`` (default 0: nothing below exists, and the traced program
+    is the one without the field): the router is WIDER than the experts that
+    have weights. It has ``num_routed + zero_experts`` outputs; ``r``, the
+    bias ``b`` and the top k run over all of them; outputs ``num_routed`` and
+    up are zero-compute experts of type identity, ``E_e(y) = y``. With
+    ``Z = {e ∈ S : e ≥ num_routed}``: out ``= Shared(y) + Σ_{e ∈ S ∩ held} w_e
+    E_e(y) + (Σ_{e ∈ Z} w_e) · y``. A pick in Z goes to the null group (no row
+    of it is multiplied) and its weight times y is added in the float32
+    combine. A row computes between 0 and ``top_k`` expert MLPs, the weights
+    of its picks with weights sum to no fixed number (``norm_topk`` normalises
+    over all of S, identities included), and the identity term belongs to no
+    chip: every chip of a layer computes it alike for its own rows, so the
+    shares of all the chips sum to the whole layer with the identity term —
+    as the shared expert — counted ONCE (tested). Refused in a latent
+    (``latent_features``): ``y`` and the experts' results would differ in
+    width, and no configuration has both.
+
     No capacity, no dropped assignment: every (row, expert, weight) triple of
     the ``rows · top_k`` routed is kept in a buffer of exactly that many rows
     (the worst case, every row routed to held experts only — which is every
@@ -224,14 +241,17 @@ class HeldExpertsMlp(nn.Module):
     permutation, weighted and summed in float32: no scatter).
 
     Counters, +1 a trace: ``kernels.moe_route_source`` (``layer_input`` with
-    ``route_from``, ``expert_input`` without).
+    ``route_from``, ``expert_input`` without); ``kernels.moe_zero_experts``
+    (``identity`` with ``zero_experts``, ``none`` without).
 
-    Parameters: ``router (width of what it reads, num_routed)``;
+    Parameters: ``router (width of what it reads, num_routed +
+    zero_experts)``;
     ``gate_proj``,
     ``up_proj`` ``(num_held, hidden, width)``, ``down_proj`` ``(num_held,
     width, hidden)``; ``shared_expert`` a ``hybrid.GatedMlp`` (none with
     ``shared_features=0``);
-    ``e_score_correction_bias (num_routed,)`` with ``selection_bias``.
+    ``e_score_correction_bias (num_routed + zero_experts,)`` with
+    ``selection_bias``.
     Ungated: no ``gate_proj``, ``shared_expert`` a ``hybrid.SquaredReluMlp``.
     In a latent: ``fc1_latent_proj``, ``fc2_latent_proj`` (a Dense each), and
     the banks' ``hidden`` is ``latent_features``."""
@@ -248,6 +268,7 @@ class HeldExpertsMlp(nn.Module):
     selection_bias: bool = False
     hidden_act: str = "silu"
     latent_features: int | None = None
+    zero_experts: int = 0
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -266,6 +287,11 @@ class HeldExpertsMlp(nn.Module):
             raise ValueError("hidden_act 'relu' with a shared expert "
                              f"(shared_features {self.shared_features}): the "
                              "shared expert is written SiLU-gated or ungated")
+        if self.zero_experts and self.latent_features is not None:
+            raise ValueError(
+                f"zero_experts {self.zero_experts} with latent_features "
+                f"{self.latent_features}: an identity expert returns the "
+                "rows at the residual width, not in the experts' latent")
         gated = self.hidden_act != "relu2"
         *lead, D = x.shape
         y = x.reshape(-1, D)
@@ -274,13 +300,19 @@ class HeldExpertsMlp(nn.Module):
                              f"than the experts read {x.shape}")
         _kernels.inc("kernels.moe_route_source", key=(
             "expert_input" if route_from is None else "layer_input"))
+        _kernels.inc("kernels.moe_zero_experts",
+                     key="identity" if self.zero_experts else "none")
         routed_on = y if route_from is None else route_from.reshape(
             -1, route_from.shape[-1])
         T, k, G, F = y.shape[0], self.top_k, self.num_held, self.hidden_features
-        if not 0 <= self.first_held <= self.num_routed - G or k > self.num_routed:
+        # the router's outputs: the experts with weights, then the identities
+        R = self.num_routed + self.zero_experts
+        if not 0 <= self.first_held <= self.num_routed - G or k > R:
             raise ValueError(
                 f"experts {self.first_held}..{self.first_held + G - 1} held, "
-                f"{k} a token, of {self.num_routed} routed")
+                f"{k} a token, of {self.num_routed} routed"
+                + (f" and {self.zero_experts} zero-compute"
+                   if self.zero_experts else ""))
         shared = None
         if self.shared_features:
             shared = (GatedMlp if gated else SquaredReluMlp)(
@@ -297,14 +329,14 @@ class HeldExpertsMlp(nn.Module):
         with jax.named_scope("trunk/route"):
             logits = jnp.dot(
                 routed_on,
-                param("router", (routed_on.shape[-1], self.num_routed)),
+                param("router", (routed_on.shape[-1], R)),
                 preferred_element_type=jnp.float32)
             r = (jax.nn.softmax(logits, axis=-1) if self.score == "softmax"
                  else jax.nn.sigmoid(logits))
             if self.selection_bias:
                 bias = self.param("e_score_correction_bias",
                                   nn.initializers.zeros_init(),
-                                  (self.num_routed,), self.param_dtype)
+                                  (R,), self.param_dtype)
                 _, top_e = jax.lax.top_k(r + bias.astype(jnp.float32), k)
                 top_r = jnp.take_along_axis(r, top_e, axis=-1)
             else:
@@ -315,7 +347,8 @@ class HeldExpertsMlp(nn.Module):
             weight = self.scaling * top_r
 
             # assignment a = (row a // k, its (a % k)-th expert); key: the held
-            # expert's index here, or G for an expert held elsewhere
+            # expert's index here, or G for an expert held elsewhere or a
+            # zero-compute one (router outputs num_routed and up)
             local = top_e - self.first_held
             held = (local >= 0) & (local < G)
             key = jnp.where(held, local, G).reshape(T * k).astype(jnp.int32)
@@ -348,6 +381,12 @@ class HeldExpertsMlp(nn.Module):
                  else shared.astype(jnp.float32))
         for j in range(k):
             total += weight[:, j, None] * out[where[:, j]].astype(jnp.float32)
+        if self.zero_experts:
+            # (Σ_{e ∈ Z} w_e) · y: what each row's identity picks weigh
+            passed = jnp.where(top_e >= self.num_routed,
+                               self.scaling * top_r, 0.0)
+            total += (jnp.sum(passed, axis=-1, keepdims=True)
+                      * y.astype(jnp.float32))
         if latent:
             beside = None if shared is None else shared.astype(jnp.float32)
             total = dense(D, "fc2_latent_proj")(

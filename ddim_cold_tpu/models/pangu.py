@@ -119,9 +119,14 @@ def published_columns(heads: int, first: int, second: int) -> np.ndarray:
 
 
 class DenseLatentAttention(nn.Module):
+    """``Attn`` of the module docstring on the layer's normed input; the
+    rotary pairing is the stack's (``rotate_half`` here, ``interleave`` in
+    ``models/longcat.py``, which runs this module over rescaled latents)."""
+
     trunk: Mapping[str, Any]
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
+    pairing: str = "rotate_half"
 
     @nn.compact
     def __call__(self, y):
@@ -131,7 +136,7 @@ class DenseLatentAttention(nn.Module):
                             c["qk_rope_head_dim"], c["v_head_dim"])
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         _, (q_nope, q_r), k_r, (k_nope, v) = latent_projections(
-            c, y, _rope(c), "rotate_half", **kw)
+            c, y, _rope(c), self.pairing, **kw)
         out = flash_attention.latent_attention(
             q_nope.reshape(n, L, H, nope), q_r.reshape(n, L, H, rot),
             k_nope.reshape(n, L, H, nope), k_r, v.reshape(n, L, H, vd),

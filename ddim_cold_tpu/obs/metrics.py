@@ -146,6 +146,10 @@ METRICS = (
      "held-expert layer traces by what the router read (key: expert_input, "
      "the rows the experts read; layer_input, another tensor handed in: "
      "route_from)"),
+    ("kernels.moe_zero_experts", "counter",
+     "held-expert layer traces by the router's zero-compute outputs (key: "
+     "identity, outputs past the experts with weights whose pick returns the "
+     "row times its weight; none, a router as wide as its experts)"),
     # -- kernels (ops/selective_scan.py, counted once a trace) ------------
     ("kernels.ssm_scan_schedule", "counter",
      "selective-scan traces by path (key: kernel|xla)"),

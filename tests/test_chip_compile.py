@@ -1253,6 +1253,7 @@ def test_one_head_launches_lower_to_the_text_they_had(launch, chip):
     ("sample_closed_smallthinker", "smallthinker_21b_l8_px2032",
      {"32/256": 8}, 2),
     ("sample_closed_nemotron", "nemotron3_super_ep4_px2048", {"32/256": 1}, 1),
+    ("sample_closed_longcat", "longcat_flash_omni_l4_px1536", {"32/1024": 8}, 1),
 ])
 def test_a_cells_forward_folds_its_last_q_block_on_32_rows(
         driver, config, tail, bodies, chip, monkeypatch):
@@ -1311,6 +1312,68 @@ def test_moe_gmm_lowers_at_the_smallthinker_shapes_and_keeps_its_name(
         args, want = (sds((rows, K), jnp.bfloat16), up, up, sizes), [[rows, F]]
     else:
         fn = functools.partial(gm.grouped_mlp, act="relu")
+        args = (sds((rows, K), jnp.bfloat16), up, up, down, sizes)
+        want = [[rows, F], [rows, K]]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [[int(g) for g in reader.NAME.match(call).groups()[1:]]
+            for call in calls] == want
+
+
+# --- LongCat-Flash-Omni's shapes ---------------------------------------------
+
+LONGCAT = dict(n=1, L=9217, heads=64, nope=128, rot=64, vd=128, hidden=6144,
+               width=2048, held=16, rows=110720)  # 9,217 x 12 in whole tiles
+
+
+def test_fwd_latent_lowers_at_the_longcat_shapes_and_keeps_its_name(chip):
+    """The two-part-score forward at 9,217 tokens and 64 heads of 128 + 64 /
+    128 — between Kimi's 32 and Pangu's 128 at Pangu's length — bf16: ONE
+    ``tpu_custom_call`` named ``%fwd_latent`` with result ``[1, 9217, 64 x
+    128]``, as ``flash_latent_fwd_roofline`` matches it."""
+    from benchmark.layer_metrics import flash_latent_fwd_roofline as reader
+
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    n, L, H, nope, rot, vd = (LONGCAT[k] for k in
+                              ("n", "L", "heads", "nope", "rot", "vd"))
+    bf = jnp.bfloat16
+    text = jax.jit(lambda qn, qr, kn, kr, v: fa.latent_attention(
+        qn, qr, kn, kr, v, (nope + rot) ** -0.5)).lower(
+        sds((n, L, H, nope), bf), sds((n, L, H, rot), bf),
+        sds((n, L, H, nope), bf), sds((n, L, rot), bf), sds((n, L, H, vd), bf)
+    ).compile().as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    m = reader.NAME.match(calls[0])
+    assert m and [int(g) for g in m.groups()[1:]] == [n, L, H * vd]
+
+
+@pytest.mark.parametrize("launch", ["gate_up", "down", "whole"])
+def test_moe_gmm_lowers_at_the_longcat_shapes_and_keeps_its_name(launch, chip):
+    """The experts' two launches over the 16 held groups of a layer, 110,720
+    buffer rows (9,217 tokens x 12 picks in whole tiles, ~2,304 of them
+    held): gate, up and ``SiLU(g) * u`` at K 6,144, F 2,048 as ONE launch,
+    down at K 2,048, N 6,144, and the whole MLP as those two; every one named
+    ``%moe_gmm`` with result ``[buffer rows, N]``, as ``moe_gmm_roofline``'s
+    events (which ``moe_gmm_zero_roofline`` reads) match it."""
+    from benchmark.layer_metrics import moe_gmm_roofline as reader
+    from ddim_cold_tpu.ops import grouped_matmul as gm
+
+    c = LONGCAT
+    rows, K, F, G = c["rows"], c["hidden"], c["width"], c["held"]
+    sds = _struct(SingleDeviceSharding(chip[0]))
+    up, down = sds((G, K, F), jnp.bfloat16), sds((G, F, K), jnp.bfloat16)
+    sizes = sds((G,), jnp.int32)
+    if launch == "down":
+        fn, args, want = gm.grouped_matmul, (sds((rows, F), jnp.bfloat16),
+                                             down, sizes), [[rows, K]]
+    elif launch == "gate_up":
+        fn = gm.grouped_gate_up
+        args, want = (sds((rows, K), jnp.bfloat16), up, up, sizes), [[rows, F]]
+    else:
+        fn = gm.grouped_mlp
         args = (sds((rows, K), jnp.bfloat16), up, up, down, sizes)
         want = [[rows, F], [rows, K]]
     text = jax.jit(fn).lower(*args).compile().as_text()

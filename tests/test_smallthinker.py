@@ -330,7 +330,7 @@ def test_gradients_flow_off_the_chip():
     (dict(num_key_value_heads=4), "num_key_value_heads 4"),
     (dict(experts_held_from=1), "experts 1..8 held of 8 routed"),
     (dict(moe_num_primary_experts_routed=4), "held of 4 routed"),
-    (dict(model_type="llama"), "'kimi_linear', 'smallthinker' are written"),
+    (dict(model_type="llama"), "'kimi_linear', 'smallthinker', "),
 ])
 def test_what_the_stack_cannot_run_is_refused_at_construction(change, match):
     with pytest.raises(ValueError, match=match):
@@ -341,7 +341,7 @@ def test_the_stack_is_chosen_by_model_type_from_one_table():
     model, _ = model_and_params("float32")
     assert hybrid.stack_of(model.trunk) == (smallthinker.check_trunk,
                                             smallthinker.layer)
-    assert list(hybrid.STACKS) == [
+    assert list(hybrid.STACKS)[:7] == [
         "jamba", "laguna", "glm_moe_dsa", "pangu_ultra_moe", "nemotron_h",
         "kimi_linear", "smallthinker"]
     # every entry of the table is a module with the two names, the default
@@ -385,7 +385,8 @@ def test_the_named_scopes_and_counters_of_a_trace():
     assert by_key == {("kernels.flash_fwd_rotary", "xla"): 3,
                       ("kernels.moe_gmm_schedule", "xla"): 15,
                       ("kernels.moe_gate_up_schedule", "xla"): 5,
-                      ("kernels.moe_route_source", "layer_input"): 5}
+                      ("kernels.moe_route_source", "layer_input"): 5,
+                      ("kernels.moe_zero_experts", "none"): 5}
     metrics.reset()
 
 
